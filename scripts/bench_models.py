@@ -5,6 +5,10 @@ and prints one JSON line per model:
     {"model": ..., "examples_per_sec": N, "batch_size": B, ...}
 
 Usage:  python scripts/bench_models.py [--cpu] [--batch-log2 N]
+
+Needs an accelerator and fails without one; ``--cpu`` asks for the CPU
+explicitly (rows then say ``"backend": "cpu"``).  A model that raises
+fails its child, and the parent exits non-zero after the last model.
 """
 
 from __future__ import annotations
@@ -20,8 +24,8 @@ from bench import (  # noqa: E402
     build,
     make_batches,
     prepare_real_data,
-    probe_accelerator,
     real_batches,
+    run,
 )
 
 
@@ -35,8 +39,8 @@ def model_cfgs(base_b: int, accel: bool):
     FM/MVM: v_dim=10 (ftrl.h:16).  FFM: per-field latent D=4.
     max_fields=39 everywhere — the bench data is Criteo-shaped with
     fgids 0..38 (gen_synth.FIELDS); a smaller cap would silently mask
-    fields out of the field-aware models.  Sizes shrink on the CPU
-    fallback to keep runtime bounded.
+    fields out of the field-aware models.  Sizes shrink under --cpu
+    to keep runtime bounded.
 
     Hot geometries are the measured per-model optima (docs/PERF.md
     round-4 sweeps).  The wide-row models (FM/MVM, D=10) profit from a
@@ -128,20 +132,26 @@ def model_cfgs(base_b: int, accel: bool):
 
 def run_one(name: str, args) -> None:
     """Bench a single model in THIS process (child mode)."""
-    backend = None if args.cpu else probe_accelerator()
     import jax
 
-    if backend is None:
+    from xflow_tpu.utils.compile_cache import enable_compile_cache
+
+    if args.cpu:
         jax.config.update("jax_platforms", "cpu")
-        devices = jax.devices("cpu")
-    else:
-        devices = [d for d in jax.devices() if d.platform != "cpu"]
-    accel = backend is not None
+    enable_compile_cache()
+    devices = jax.devices()
+    backend = devices[0].platform
+    accel = backend != "cpu"
+    if not accel and not args.cpu:
+        raise SystemExit(
+            "bench_models: JAX found no accelerator (platform 'cpu'); "
+            "pass --cpu to ask for the CPU explicitly"
+        )
     iters = args.iters if accel else max(2, args.iters // 3)
 
     cfg = dict(model_cfgs(1 << args.batch_log2, accel))[name]
-    # geometry overrides for hot-head scaling sweeps (VERDICT r4 #4:
-    # find each D>1 model's mass-vs-h2*D-traffic optimum)
+    # geometry overrides for hot-head scaling sweeps (find each D>1
+    # model's mass-vs-h2*D-traffic optimum)
     over = {}
     if args.hot_log2 is not None:
         over["hot_size_log2"] = args.hot_log2
@@ -157,57 +167,34 @@ def run_one(name: str, args) -> None:
         over["cold_consolidate"] = True
     if over:
         cfg = cfg.replace(**over)
-    csr = remap = None
-    if not args.synthetic:
-        try:
-            _, csr, remap, _ = prepare_real_data(
-                cfg, 2_000_000 if accel else 200_000
-            )
-        except Exception as e:
-            print(
-                json.dumps({"real_data_error": f"{type(e).__name__}: {e}"}),
-                flush=True,
-            )
-    try:
-        from bench import run
-
-        step, state = build(devices, cfg)
+    step, state = build(devices, cfg)
+    if args.synthetic:
         source = "synthetic"
-        batches = None
-        batch_err = None
-        if csr is not None:
-            try:
-                batches, _ = real_batches(
-                    cfg, csr, remap if cfg.hot_size else None, 2
-                )
-                source = "zipf-cache"
-            except Exception as e:  # e.g. batch too large for cache
-                batch_err = f"{type(e).__name__}: {e}"
-        if batches is None:
-            batches, _ = make_batches(cfg, 2)
-        t0 = time.time()
-        _, eps = run(step, state, batches, iters=iters, warmup=2)
-        row = {
-            "model": name,
-            "examples_per_sec": round(eps, 1),
-            "batch_size": cfg.batch_size,
-            "table_size_log2": cfg.table_size_log2,
-            "hot": f"2^{cfg.hot_size_log2}x{cfg.hot_nnz}+cold{cfg.max_nnz}"
-            if cfg.hot_size else "off",
-            "cold_consolidate": cfg.cold_consolidate,
-            "hot_dtype": cfg.hot_dtype,
-            "backend": backend or "cpu",
-            "batch_source": source,
-            "wall_s": round(time.time() - t0, 1),
-        }
-        if batch_err is not None:
-            row["real_batch_error"] = batch_err
-        print(json.dumps(row), flush=True)
-    except Exception as e:
-        print(
-            json.dumps({"model": name, "error": f"{type(e).__name__}: {e}"}),
-            flush=True,
+        batches, _ = make_batches(cfg, 2)
+    else:
+        source = "zipf-cache"
+        _, csr, remap, _ = prepare_real_data(
+            cfg, 2_000_000 if accel else 200_000
         )
+        batches, _ = real_batches(
+            cfg, csr, remap if cfg.hot_size else None, 2
+        )
+    t0 = time.time()
+    _, eps = run(step, state, batches, iters=iters, warmup=2)
+    print(json.dumps({
+        "model": name,
+        "examples_per_sec": round(eps, 1),
+        "batch_size": cfg.batch_size,
+        "table_size_log2": cfg.table_size_log2,
+        "hot": f"2^{cfg.hot_size_log2}x{cfg.hot_nnz}+cold{cfg.max_nnz}"
+        if cfg.hot_size else "off",
+        "cold_consolidate": cfg.cold_consolidate,
+        "hot_dtype": cfg.hot_dtype,
+        "backend": backend,
+        "device_kind": devices[0].device_kind,
+        "batch_source": source,
+        "wall_s": round(time.time() - t0, 1),
+    }), flush=True)
 
 
 def main() -> None:
@@ -257,6 +244,11 @@ def main() -> None:
     # is exactly what happened when all models shared one process
     # (round-4 log: FFM's 31 GB table OOM'd, then wide_deep — fine in
     # isolation — reported RESOURCE_EXHAUSTED too).
+    #
+    # A chip belongs to one process at a time, so this parent must
+    # never call jax.devices() or build an array: a parent that holds
+    # the chip makes every child fail or hang.  Importing bench and
+    # calling model_cfgs() initializes no backend — keep it so.
     import subprocess
 
     names = [n for n, _ in model_cfgs(1 << args.batch_log2, True)]
@@ -267,6 +259,7 @@ def main() -> None:
         passthrough.append("--synthetic")
     passthrough += ["--batch-log2", str(args.batch_log2),
                     "--iters", str(args.iters)]
+    failed = []
     for name in names:
         proc = subprocess.run(
             [sys.executable, __file__, "--model", name, *passthrough],
@@ -276,10 +269,9 @@ def main() -> None:
         if out:
             print(out, flush=True)
         if proc.returncode != 0:
-            print(
-                json.dumps({"model": name, "error": f"exit {proc.returncode}"}),
-                flush=True,
-            )
+            failed.append(f"{name} (exit {proc.returncode})")
+    if failed:
+        raise SystemExit("bench_models: failed: " + ", ".join(failed))
 
 
 if __name__ == "__main__":

@@ -38,12 +38,17 @@ def main():
     p.add_argument("--iters", type=int, default=20)
     args = p.parse_args()
 
+    from xflow_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
 
-    accel = [d for d in jax.devices() if d.platform != "cpu"]
-    if not accel:
-        print(json.dumps({"error": "no accelerator"}))
-        return
+    accel = jax.devices()
+    if accel[0].platform == "cpu":
+        raise SystemExit(
+            "bench_northstar: JAX found no accelerator (platform 'cpu'); "
+            "the T=2^28 shape is a chip measurement"
+        )
 
     # shared data prep (synth shard + CSR cache, both disk-cached)
     probe_cfg = Config(
@@ -84,22 +89,20 @@ def main():
                 remap = freq.build_remap(counts, cfg.hot_size)
             r = remap
             mass = freq.hot_mass(counts, r, cfg.hot_size)
-        try:
-            batches, trunc = bench.real_batches(cfg, csr, r, NBATCH)
-            step, state = bench.build(accel, cfg)
-            t0 = time.time()
-            _, eps = bench.run(step, state, batches, iters=args.iters)
-            row = {
-                "config": name,
-                "table_size_log2": T_LOG2,
-                "examples_per_sec": round(eps, 0),
-                "truncated_frac": round(trunc, 5),
-                "hot_mass": None if mass is None else round(mass, 4),
-                "compile_plus_run_secs": round(time.time() - t0, 1),
-            }
-        except Exception as e:
-            row = {"config": name, "error": f"{type(e).__name__}: {e}"}
-        print(json.dumps(row), flush=True)
+        batches, trunc = bench.real_batches(cfg, csr, r, NBATCH)
+        step, state = bench.build(accel, cfg)
+        t0 = time.time()
+        _, eps = bench.run(step, state, batches, iters=args.iters)
+        print(json.dumps({
+            "config": name,
+            "table_size_log2": T_LOG2,
+            "examples_per_sec": round(eps, 0),
+            "truncated_frac": round(trunc, 5),
+            "hot_mass": None if mass is None else round(mass, 4),
+            "compile_plus_run_secs": round(time.time() - t0, 1),
+            "backend": accel[0].platform,
+            "device_kind": accel[0].device_kind,
+        }), flush=True)
 
 
 if __name__ == "__main__":

@@ -104,9 +104,8 @@ def main():
     p.add_argument("--out", default="/tmp/xflow_conv/convergence.json")
     p.add_argument(
         "--platform",
-        help="force the JAX backend (e.g. cpu — convergence results are "
-        "device-independent; pin before any backend query or the "
-        "accelerator plugin hijacks selection)",
+        help="force the JAX backend, like JAX_PLATFORMS (e.g. cpu — "
+        "convergence results are device-independent)",
     )
     args = p.parse_args()
     if args.platform:

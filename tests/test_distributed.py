@@ -67,7 +67,7 @@ def test_two_process_training(toy_dataset, tmp_path, hot):
         "--table-size-log2", "14",
         "--max-nnz", "24",
         "--num-devices", "2",
-        "--platform", "cpu",  # env alone is overridden by TPU plugins
+        "--platform", "cpu",
         "--coordinator", f"localhost:{port}",
         "--num-processes", "2",
     ]

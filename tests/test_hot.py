@@ -94,3 +94,48 @@ def test_jit_and_grad_flow():
 
     g = jax.grad(f)(w)
     assert float(g.sum()) == 200.0  # each of 100 keys contributes d=2 ones
+
+
+def _dot_precisions(jaxpr):
+    """``precision`` of every dot_general in a jaxpr, scan bodies
+    included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if hasattr(sub, "jaxpr"):
+                    out += _dot_precisions(sub.jaxpr)
+                elif hasattr(sub, "eqns"):
+                    out += _dot_precisions(sub)
+    return out
+
+
+@pytest.mark.parametrize(
+    "dtype,want",
+    [
+        (jnp.float32, (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)),
+        (jnp.bfloat16, None),
+    ],
+)
+def test_float32_contractions_ask_for_highest_precision(dtype, want):
+    """A TPU's default-precision float32 dot rounds its operands to
+    bfloat16 (measured on a v5e: gather off by 7.7e-3 — ops/hot.py
+    docstring).  A CPU dot is exact either way, so this pins the
+    ARGUMENT: every contraction of the float32 mode carries
+    Precision.HIGHEST, and the bfloat16 mode stays the one-pass fast
+    path.  chip_smoke.py Phase 2 checks the effect on the chip."""
+    w = jnp.zeros((256, 10), jnp.float32)
+    keys = jnp.zeros((100,), jnp.int32)
+    grads = jnp.zeros((100, 10), jnp.float32)
+    gather = jax.make_jaxpr(lambda w, k: hot_gather(w, k, dtype=dtype))(
+        w, keys
+    )
+    scatter = jax.make_jaxpr(
+        lambda k, g: hot_scatter(k, g, 256, dtype=dtype)
+    )(keys, grads)
+    for jaxpr in (gather.jaxpr, scatter.jaxpr):
+        precisions = _dot_precisions(jaxpr)
+        assert precisions, "no dot_general found — did the lowering change?"
+        assert all(p == want for p in precisions), precisions

@@ -53,7 +53,6 @@ def test_sigterm_checkpoints_and_resume_completes(big_dataset, tmp_path):
         "--num-devices", "1",
         "--checkpoint-dir", str(ck),
         "--checkpoint-every-steps", "5",
-        "--platform", "cpu",  # env alone is overridden by TPU plugins
     ]
     proc = subprocess.Popen(
         cmd, env=env, stderr=subprocess.PIPE, text=True, cwd=os.getcwd()

@@ -380,8 +380,11 @@ class Config:
     # frequencies for the remap.
     freq_sample_mib: int = 64
     # Matmul input dtype for the hot path: "float32" = exact gather,
-    # order-only scatter difference; "bfloat16" = ~2x faster, rounds
-    # table/grad values to bf16 inside the hot path only.
+    # order-only scatter difference — on a TPU because ops/hot.py asks
+    # for Precision.HIGHEST there (a default-precision f32 dot on the
+    # v5e rounds to bf16: measured PR 21, chip_smoke.py Phase 2);
+    # "bfloat16" = the fast mode, rounds table/grad values to bf16
+    # inside the hot path only.
     hot_dtype: str = "float32"
 
     # -- precision --

@@ -183,6 +183,7 @@ def remap_batch(
     remap: np.ndarray | None,
     hot_size: int,
     hot_nnz: int,
+    cold_nnz: int | None = None,
 ) -> Batch:
     """Bring an externally built Batch (raw hash-space keys) into a
     hot-table model's key space: apply the frequency remap (io/freq.py)
@@ -193,17 +194,30 @@ def remap_batch(
     and the serving engine so the two paths cannot drift.
 
     No-op when ``remap`` is None (model trained without a hot table).
+
+    ``cold_nnz`` fixes the cold capacity after the re-steer: a batch of
+    total width ``cold_nnz + hot_nnz`` then comes out exactly as the
+    loader's ``pack_batch`` would have built it, cold overflow truncated
+    the same way (the serving engine's canonical path).  None keeps the
+    full incoming width as cold capacity, so nothing is truncated.
     """
     if remap is None:
         return batch
     # merge any existing hot section back, remap, then re-steer (a
     # remapped key may cross the hot/cold boundary in either direction);
-    # pad by hot_nnz columns so the post-split cold capacity equals the
-    # full incoming width — even if every incoming entry lands cold,
-    # nothing is truncated on re-steer
+    # by default pad by hot_nnz columns so the post-split cold capacity
+    # equals the full incoming width — even if every incoming entry
+    # lands cold, nothing is truncated on re-steer
     b = batch.batch_size
-    pad_i = np.zeros((b, hot_nnz), np.int32)
-    pad_f = np.zeros((b, hot_nnz), np.float32)
+    width = batch.hot_nnz + batch.max_nnz
+    pad = hot_nnz if cold_nnz is None else cold_nnz + hot_nnz - width
+    if pad < 0:
+        raise ValueError(
+            f"remap_batch: batch width {width} exceeds cold_nnz + "
+            f"hot_nnz = {cold_nnz + hot_nnz}"
+        )
+    pad_i = np.zeros((b, pad), np.int32)
+    pad_f = np.zeros((b, pad), np.float32)
     keys = np.concatenate([batch.hot_keys, batch.keys, pad_i], axis=1)
     slots = np.concatenate([batch.hot_slots, batch.slots, pad_i], axis=1)
     vals = np.concatenate([batch.hot_vals, batch.vals, pad_f], axis=1)

@@ -3,14 +3,18 @@ plain C ABI + ctypes, the same "embed as a library" shape the
 reference's C API intended, c_api.h:26-41).
 
 The shared library is built on demand with g++ (see build.py) and
-cached next to the sources.  Everything degrades gracefully: if no
-toolchain is available, ``available()`` is False and callers fall back
-to the pure-Python parser.
+cached next to the sources.  If it cannot be built or loaded (no
+toolchain), ``available()`` is False and callers fall back to the
+pure-Python parser — roughly 100x slower, so the failure is reported
+once on stderr with the compiler's own error; ``chip_smoke.py`` treats
+it as a failure, because every recorded rate assumes the native parser.
 """
 
 from __future__ import annotations
 
 import ctypes
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -34,8 +38,15 @@ def load_library() -> ctypes.CDLL | None:
             path = build_if_needed()
             lib = ctypes.CDLL(str(path))
             _bind(lib)
-        except Exception:
+        except (OSError, AttributeError, subprocess.CalledProcessError) as e:
             _load_failed = True
+            detail = (getattr(e, "stderr", None) or str(e)).strip()
+            print(
+                "xflow_tpu.native: parser library unavailable, using the "
+                f"pure-Python parser (~100x slower): {type(e).__name__}: "
+                f"{detail}",
+                file=sys.stderr,
+            )
             return None
         _lib = lib
         return _lib

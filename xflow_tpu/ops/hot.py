@@ -12,17 +12,25 @@ one-hot matmuls that ride the MXU:
     gather:  rows = ((onehot_hi @ W) . reshape  *  onehot_lo) sum over lo
     scatter: W'   = onehot_hi^T @ (g * onehot_lo)
 
-Traffic is M*(h1 + h2*D) one-hot elements instead of M DMA descriptors;
-measured ~2x (f32, exact) to ~4x (bf16) over the DMA path for the hot
-fraction on v5e (scripts/probe_hot2.py; docs/PERF.md "The win").
+Traffic is M*(h1 + h2*D) one-hot elements instead of M DMA descriptors
+(docs/PERF.md "The win" has the v5e rates of the rounds that built it).
 
 One-hot intermediates are built in chunks under ``lax.scan`` so the
 [C, h2*D] temporaries stay within a few MiB regardless of M or D.
 
 Numerics: with ``dtype=float32`` the gather is *exact* (each one-hot row
 selects a single W element; no accumulation), and the scatter differs
-from ``.at[].add`` only in summation order.  ``bfloat16`` trades W/g
-mantissa for ~2x more speed; the default is float32.
+from ``.at[].add`` only in summation order — but only because every
+contraction below asks for ``Precision.HIGHEST``.  The TPU's default
+precision for a float32 dot rounds both operands to bfloat16 on the way
+into the MXU: measured on a v5e (PR 21, H=4096, N(0,1) weights) the
+default-precision gather was off by up to 7.7e-3 absolute and the
+scatter by 1.7e-3 relative, while HIGHEST is bitwise equal to
+``w_hot[keys]`` and within 2.6e-7 of the segment-sum.  A CPU dot is
+exact either way, so tests pin the argument (tests/test_hot.py)
+and ``chip_smoke.py`` Phase 2 pins the effect.  ``bfloat16`` is the
+fast, rounded mode: it trades W/g mantissa for MXU passes; the default
+is float32.
 
 Sentinel behavior: any key outside [0, H) produces an all-zero onehot_hi
 row, so out-of-range/padding keys gather a zero row and scatter nothing
@@ -47,6 +55,13 @@ def hot_factors(hot_size: int) -> tuple[int, int]:
         raise ValueError(f"hot_size must be a power of two, got {hot_size}")
     h1 = 1 << ((log2 + 1) // 2)
     return h1, hot_size // h1
+
+
+def _precision(dtype) -> jax.lax.Precision | None:
+    """Contraction precision for matmul inputs of ``dtype``: float32
+    must not be rounded to bfloat16 in the MXU (module docstring);
+    bfloat16 inputs are already rounded, one pass is exact for them."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _chunk(h1: int, h2: int, d: int, m: int) -> int:
@@ -104,16 +119,20 @@ def hot_gather(
     wr = w_hot.reshape(h1, h2 * d).astype(dtype)
     ar1 = jnp.arange(h1, dtype=kp.dtype)
     ar2 = jnp.arange(h2, dtype=kp.dtype)
+    prec = _precision(dtype)
 
     def body(_, k):
         hi = k // h2
         lo = k % h2
         oh_hi = (hi[:, None] == ar1[None, :]).astype(dtype)  # [C, h1]
         rows = jnp.dot(
-            oh_hi, wr, preferred_element_type=jnp.float32
+            oh_hi, wr, precision=prec, preferred_element_type=jnp.float32
         ).reshape(c, h2, d)
         oh_lo = (lo[:, None] == ar2[None, :]).astype(jnp.float32)  # [C, h2]
-        return None, jnp.einsum("chd,ch->cd", rows, oh_lo)
+        # level 2 selects among level-1 rows, which hold float32 values
+        # in float32 mode and bfloat16-exact ones in bfloat16 mode, so
+        # the same precision is lossless for it
+        return None, jnp.einsum("chd,ch->cd", rows, oh_lo, precision=prec)
 
     _, out = jax.lax.scan(body, None, kp.reshape(-1, c))
     return out.reshape(m_pad, d)[:m]
@@ -157,6 +176,7 @@ def hot_scatter(
     gp = _pad_to(grads, m_pad, 0)
     ar1 = jnp.arange(h1, dtype=kp.dtype)
     ar2 = jnp.arange(h2, dtype=kp.dtype)
+    prec = _precision(dtype)
 
     def body(acc, xs):
         k, g = xs
@@ -167,7 +187,8 @@ def hot_scatter(
         glo = (g[:, :, None] * oh_lo[:, None, :]).reshape(c, d * h2)
         # accumulate in f32 regardless of input dtype
         acc = acc + jnp.dot(
-            oh_hi.T, glo.astype(dtype), preferred_element_type=jnp.float32
+            oh_hi.T, glo.astype(dtype), precision=prec,
+            preferred_element_type=jnp.float32,
         )
         return acc, None
 

@@ -127,7 +127,11 @@ def init_state(model: Model, optimizer: Optimizer, cfg: Config, mesh) -> State:
             lambda a: jax.device_put(a, replicated(mesh)),
             model.dense_init(jax.random.fold_in(rng, 1000)),
         )
-    return {"tables": tables, "dense": dense, "step": jnp.zeros((), jnp.int32)}
+    # placed like the step's own output (replicated over the mesh): an
+    # uncommitted scalar here made the second train call a jit cache
+    # miss, compiling the first shape bucket twice
+    step = jax.device_put(jnp.zeros((), jnp.int32), replicated(mesh))
+    return {"tables": tables, "dense": dense, "step": step}
 
 
 def batch_to_arrays(batch: Batch) -> BatchArrays:

@@ -65,6 +65,8 @@ import time
 
 import numpy as np
 
+from xflow_tpu.utils.compile_cache import enable_compile_cache
+
 
 def _buckets(text: str | None) -> tuple[int, ...] | None:
     if not text:
@@ -779,6 +781,7 @@ def main(argv: list[str] | None = None) -> int:
     pl.add_argument("--seed", type=int, default=0)
 
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     if args.cmd == "score":
         return cmd_score(args)

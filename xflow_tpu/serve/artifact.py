@@ -291,7 +291,7 @@ def export_item_index(
         raise ValueError(
             f"export_item_index: {n} rows but {len(ids)} item_ids"
         )
-    k = engine.cfg.max_nnz
+    k = engine.row_nnz  # what item_embeddings' featurize kept
     keys = np.zeros((n, k), np.int64)
     slots = np.zeros((n, k), np.int32)
     vals = np.zeros((n, k), np.float32)

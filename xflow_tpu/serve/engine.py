@@ -189,6 +189,16 @@ class PredictEngine:
         return len(self._compiled)
 
     @property
+    def row_nnz(self) -> int:
+        """Features one row may carry: the training loader's per-row
+        capacity (io/batch.py::pack_batch) — the cold section plus,
+        with a hot table, the hot section.  Rows featurized to this
+        width and steered by ``_prepare`` are the batches the loader
+        would have built, so served and trained scores agree."""
+        cfg = self.cfg
+        return cfg.max_nnz + (cfg.hot_nnz if cfg.hot_size else 0)
+
+    @property
     def servable_step(self) -> int:
         """Train step of the served state (one cached scalar fetch,
         booked — XF002)."""
@@ -389,7 +399,7 @@ class PredictEngine:
         return self.warm_seconds
 
     def _empty_batch(self, rows: int) -> Batch:
-        k = self.cfg.max_nnz
+        k = self.row_nnz
         return Batch(
             keys=np.zeros((rows, k), np.int32),
             slots=np.zeros((rows, k), np.int32),
@@ -414,9 +424,9 @@ class PredictEngine:
         feed it to ``predict`` (which remaps/pads).  Each row is either
         a 1-D key array or a ``(keys, slots, vals)`` tuple (slots/vals
         may be None → 0 / 1.0, the hash-mode convention).  Features
-        beyond ``max_nnz`` are truncated, like the training loader."""
+        beyond ``row_nnz`` are truncated, like the training loader."""
         n = len(rows)
-        k = self.cfg.max_nnz
+        k = self.row_nnz
         keys = np.zeros((n, k), np.int32)
         slots = np.zeros((n, k), np.int32)
         vals = np.zeros((n, k), np.float32)
@@ -483,7 +493,7 @@ class PredictEngine:
         cap = self.buckets[-1]
         for s in range(0, n, cap):
             e = min(s + cap, n)
-            raw = pack_batch(block, s, e, e - s, self.cfg.max_nnz)
+            raw = pack_batch(block, s, e, e - s, self.row_nnz)
             out.append(self.predict(raw))
         return np.concatenate(out)
 
@@ -667,27 +677,30 @@ class PredictEngine:
 
     def _prepare(self, batch: Batch) -> Batch:
         """Canonicalize an external raw-key-space batch: widen the cold
-        section so the total feature width matches the training
-        geometry (narrower batches get zero-mask columns — no new
-        compile shapes), then apply the hot remap + steering.
+        section so the total feature width is the loader's per-row
+        capacity ``row_nnz`` (narrower batches get zero-mask columns —
+        no new compile shapes), then apply the hot remap + steering
+        with the training geometry's cold capacity, so the prepared
+        batch is the one the loader would have packed.
 
-        Batches WIDER than the training geometry keep their width
-        (truncating would silently drop features the training path
-        kept) and compile one extra executable per distinct width —
-        counted in ``serve.noncanonical_shape``.  The batcher/featurize
-        tier only ever produces canonical widths, so the no-recompile
-        guarantee holds for serving traffic; a direct ``predict``
-        caller who wants it too must match ``cfg.max_nnz``."""
+        Batches WIDER than ``row_nnz`` keep their width (truncating
+        would silently drop features the caller passed) and compile
+        one extra executable per distinct width — counted in
+        ``serve.noncanonical_shape``.  The batcher/featurize tier only
+        ever produces canonical widths, so the no-recompile guarantee
+        holds for serving traffic; a direct ``predict`` caller who
+        wants it too must stay within ``row_nnz``."""
         cfg = self.cfg
         if batch.hot_nnz and not cfg.hot_size:
             raise ValueError(
                 "batch carries hot planes but the model has no hot table"
             )
         total = batch.hot_nnz + batch.max_nnz
-        if total > cfg.max_nnz:
+        wide = total > self.row_nnz
+        if wide:
             self.obs.counter("serve.noncanonical_shape")
-        if total < cfg.max_nnz:
-            pad = cfg.max_nnz - total
+        elif total < self.row_nnz:
+            pad = self.row_nnz - total
             b = batch.batch_size
             z_i = np.zeros((b, pad), np.int32)
             z_f = np.zeros((b, pad), np.float32)
@@ -703,7 +716,10 @@ class PredictEngine:
                 hot_vals=batch.hot_vals,
                 hot_mask=batch.hot_mask,
             )
-        return remap_batch(batch, self.remap, cfg.hot_size, cfg.hot_nnz)
+        return remap_batch(
+            batch, self.remap, cfg.hot_size, cfg.hot_nnz,
+            cold_nnz=None if wide else cfg.max_nnz,
+        )
 
     def predict(self, batch: Batch) -> np.ndarray:
         """pctr for one externally built Batch (raw hash key space —

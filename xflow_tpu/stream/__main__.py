@@ -22,6 +22,7 @@ import sys
 from xflow_tpu.config import Config
 from xflow_tpu.stream.driver import StreamDriver
 from xflow_tpu.train import build_parser, config_from_args
+from xflow_tpu.utils.compile_cache import enable_compile_cache
 
 
 def _stream_parser() -> argparse.ArgumentParser:
@@ -83,6 +84,7 @@ def main(argv: list[str] | None = None) -> int:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    enable_compile_cache()
     cfg = config_from_args(args)
     driver = StreamDriver(
         cfg,

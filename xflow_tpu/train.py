@@ -24,6 +24,7 @@ import sys
 
 from xflow_tpu.config import Config
 from xflow_tpu.trainer import Trainer
+from xflow_tpu.utils.compile_cache import enable_compile_cache
 
 _MODEL_BY_INDEX = {"0": "lr", "1": "fm", "2": "mvm"}  # main.cc:27-45
 
@@ -268,8 +269,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--platform",
         choices=["tpu", "cpu", "gpu"],
-        help="force the JAX backend (overrides plugin auto-selection; "
-        "needed e.g. to run the distributed path on CPU processes)",
+        help="force the JAX backend, like JAX_PLATFORMS in the "
+        "environment (e.g. cpu, to run the distributed path as CPU "
+        "processes on a machine that has a chip)",
     )
     p.add_argument(
         "--coordinator",
@@ -302,11 +304,11 @@ def config_from_args(args: argparse.Namespace) -> Config:
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
     if args.platform:
-        # must precede any backend initialization (the env var alone can
-        # be overridden by platform plugins registered at site import)
+        # must precede any backend initialization
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    enable_compile_cache()
     if args.coordinator:
         import jax
 

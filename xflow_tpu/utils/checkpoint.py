@@ -408,11 +408,13 @@ def load_checkpoint(
                 f"checkpoint dense {dname} shape {host.shape} != {arr.shape}"
             )
         new_dense[dname] = jax.device_put(host, arr.sharding)
-    import jax.numpy as jnp
-
     new_state = {
         "tables": new_tables,
         "dense": new_dense,
-        "step": jnp.asarray(manifest["step"], jnp.int32),
+        # on the template's sharding, like every other leaf (a resumed
+        # run must not retrace when its own output comes back in)
+        "step": jax.device_put(
+            np.int32(manifest["step"]), state["step"].sharding
+        ),
     }
     return new_state, manifest["cursor"]
