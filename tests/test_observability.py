@@ -882,3 +882,192 @@ def test_doctor_shed_storm_and_canary_stuck(tmp_path, capsys):
     text = capsys.readouterr().out
     # finding-code form: the tmp dir name itself contains "shed_storm"
     assert "shed_storm:" not in text and "canary_stuck:" not in text
+
+
+# -- ISSUE 24: the program's names on the profiler's timeline ----------------
+
+SCOPES = {
+    "xf.wire_decode", "xf.gather", "xf.forward_backward", "xf.scatter",
+    "xf.optimizer", "xf.metrics",
+}
+
+
+def _host_event_names(trace_dir) -> dict[str, set[int]]:
+    """name -> the host lines (threads) that carry an event of that name,
+    from the one xplane the profiler wrote under ``trace_dir``."""
+    import jax
+
+    (path,) = list(trace_dir.rglob("*.xplane.pb"))
+    found: dict[str, set[int]] = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for thread, line in enumerate(plane.lines):
+                for ev in line.events:
+                    found.setdefault(ev.name, set()).add(thread)
+    return found
+
+
+@pytest.fixture(scope="module")
+def profiled_phases(tmp_path_factory):
+    """One profiler session over a live Obs: a phase on the main thread, a
+    phase on a worker thread, an enclosing span."""
+    import threading
+
+    import jax
+
+    from xflow_tpu.obs import make_obs
+
+    obs = make_obs()
+    trace_dir = tmp_path_factory.mktemp("xf_spans")
+
+    def worker():
+        with obs.phase("on_worker"):
+            time.sleep(0.002)
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        with obs.span("enclosing"):
+            with obs.phase("on_main"):
+                time.sleep(0.002)
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join()
+    finally:
+        jax.profiler.stop_trace()
+    return obs, _host_event_names(trace_dir)
+
+
+@pytest.mark.parametrize("name", ["on_main", "on_worker", "enclosing"])
+def test_phase_is_a_profiler_span(profiled_phases, name):
+    """Every phase and span of a live Obs is an ``xf.<name>`` event on the
+    JAX profiler's own timeline, from whichever thread ran it."""
+    _, events = profiled_phases
+    assert "xf." + name in events, sorted(n for n in events if n[:3] == "xf.")
+    if name == "on_worker":
+        assert events["xf.on_worker"].isdisjoint(events["xf.on_main"])
+
+
+def test_profiled_phase_still_books_its_counter(profiled_phases):
+    from xflow_tpu.obs import NULL_OBS
+    from xflow_tpu.obs.trace import NULL_SPAN
+
+    obs, _ = profiled_phases
+    phases = obs.registry.snapshot().phase_seconds()
+    assert phases["on_main"] >= 0.002 and phases["on_worker"] >= 0.002
+    assert "enclosing" not in phases  # a span books no seconds
+    # the disabled facade is untouched: one shared no-op, no annotation
+    assert NULL_OBS.phase("x") is NULL_SPAN and NULL_OBS.span("x") is NULL_SPAN
+
+
+@pytest.fixture(scope="module")
+def packed_run(toy_dataset, tmp_path_factory):
+    """Two epochs from packed shards with a live Obs: the metrics rows."""
+    from xflow_tpu.io import packed
+
+    root = tmp_path_factory.mktemp("xf_packed")
+    out = str(root / "pk")
+    assert packed.main([
+        "--train", toy_dataset.train_prefix, "--out", out,
+        "--batch-size", "64", "--max-nnz", "24",
+        "--table-size-log2", "14", "--block-mib", "0.01",
+    ]) == 0
+    metrics = root / "m.jsonl"
+    cfg = _toy_cfg(toy_dataset, train_path=out, metrics_out=str(metrics))
+    with Trainer(cfg) as t:
+        t.train()
+    return [json.loads(line) for line in metrics.read_text().splitlines()]
+
+
+def test_packed_epoch_books_shard_opens_overlapped(packed_run):
+    """The shard opens run on stream threads: their seconds are
+    ``overlapped``, and ``phases`` still sums to (at most) ``seconds``."""
+    epochs = [r for r in packed_run if r["kind"] == "train_epoch"]
+    assert len(epochs) == 2
+    for e in epochs:
+        assert {"shard_open", "remap_digest"} <= set(e["overlapped"])
+        assert not {"shard_open", "remap_digest"} & set(e["phases"])
+        assert e["overlapped"]["remap_digest"] <= e["overlapped"]["shard_open"]
+        assert e["shard_opens"] == 3  # the toy set's three shards
+        assert sum(e["phases"].values()) <= e["seconds"] * 1.01
+
+
+def test_first_batch_wait_is_part_of_input_stall(packed_run):
+    for e in (r for r in packed_run if r["kind"] == "train_epoch"):
+        assert 0.0 < e["first_batch_wait_s"] <= e["phases"]["input_stall"]
+
+
+def test_scopes_row_once_per_new_shape(packed_run):
+    """The instruction -> scope map is logged by the epoch that first met
+    a train shape and by no later one; the file passes the schema."""
+    from xflow_tpu.obs.schema import validate_rows
+
+    assert validate_rows(packed_run) == []
+    rows = [r for r in packed_run if r["kind"] == "scopes"]
+    assert [r["epoch"] for r in rows] == [0]
+    scopes = {scope for _, _, scope in rows[0]["ops"]}
+    assert {"xf.scatter", "xf.optimizer", "xf.metrics"} <= scopes <= SCOPES | {""}
+    first = next(r for r in packed_run if r["kind"] == "train_epoch")
+    assert first["phases"]["op_scopes"] > 0.0 and "_scopes" not in first
+
+
+@pytest.mark.parametrize("wire_mode, wire_dedup, wire", [
+    ("auto", "auto", "dict"), ("compact", "off", "compact"),
+    ("full", "off", "full"),
+])
+def test_op_scopes_names_all_six(toy_dataset, wire_mode, wire_dedup, wire):
+    """A tiny LR step with a hot head (the MXU form, whose scans keep
+    their instructions apart on any backend): every instruction is given
+    its name, its type as a profiler prints it, and the first xf.* of its
+    path; all six scopes appear, the wire's decode among them."""
+    import re
+
+    cfg = _toy_cfg(
+        toy_dataset, hot_size_log2=6, hot_nnz=8, hot_impl="mxu",
+        wire_mode=wire_mode, wire_dedup=wire_dedup,
+    )
+    with Trainer(cfg) as t:
+        assert t.step.wire_format == wire
+        batch, _, _ = next(iter(t.iter_train_batches(0, 0)))
+        rows = t.step.op_scopes(t.state, t.step.put_batch(batch))
+        # lowered from shapes: the state was neither donated nor held
+        assert not t.state["tables"]["w"]["param"].is_deleted()
+    assert {scope for _, _, scope in rows} == SCOPES | {""}
+    for name, type_, _ in rows:
+        assert name and " " not in name and not name.startswith("%")
+        assert type_ == "" or re.fullmatch(r"[a-z0-9]+\[[0-9,]*\]", type_)
+
+
+def test_null_obs_maps_no_scopes_and_annotates_nothing(toy_dataset, monkeypatch):
+    """With metrics_out, obs_trace_out, obs_flight_out and obs_watchdog
+    unset the trainer never calls op_scopes and creates no
+    TraceAnnotation; a live Obs does both."""
+    import xflow_tpu.obs as obs_mod
+    from xflow_tpu.parallel.step import TrainStep
+
+    made: list[str] = []
+    real = obs_mod.TraceAnnotation
+
+    def counting(name, **kw):
+        made.append(name)
+        return real(name, **kw)
+
+    mapped: list[int] = []
+    real_op_scopes = TrainStep.op_scopes
+
+    def counting_op_scopes(self, state, arrays):
+        mapped.append(1)
+        return real_op_scopes(self, state, arrays)
+
+    monkeypatch.setattr(obs_mod, "TraceAnnotation", counting)
+    monkeypatch.setattr(TrainStep, "op_scopes", counting_op_scopes)
+    with Trainer(_toy_cfg(toy_dataset)) as t:
+        assert not t.obs.enabled
+        stats = t.train_epoch()
+    assert made == [] and mapped == []
+    assert "_scopes" not in stats and "first_batch_wait_s" not in stats
+
+    with Trainer(_toy_cfg(toy_dataset, obs_flight_out="unused")) as t:
+        first = t.train_epoch()
+        second = t.train_epoch()
+    assert "xf.input_stall" in made and "xf.train_epoch" in made
+    assert len(mapped) >= 1 and "_scopes" in first and "_scopes" not in second

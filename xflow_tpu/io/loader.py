@@ -363,19 +363,25 @@ class ShardLoader:
         baked-in batch geometry must match this loader exactly."""
         from xflow_tpu.io import packed
 
-        f.seek(0)
-        meta, _ = packed.read_header(f)
-        packed.check_compat(
-            meta,
-            batch_size=self.batch_size,
-            cold_nnz=self.max_nnz,
-            hot_nnz=self.hot_nnz if self.hot_size else 0,
-            hot_size=self.hot_size,
-            table_size=self.table_size,
-            hash_mode=self.hash_mode,
-            hash_seed=self.hash_seed,
-            remap=self.remap,
-        )
+        # runs on a stream thread before the shard's first batch can be
+        # read: the epoch record books it under ``overlapped``
+        self.obs.counter("loader.shard_opens")
+        with self.obs.phase("shard_open"):
+            f.seek(0)
+            meta, _ = packed.read_header(f)
+            with self.obs.phase("remap_digest"):
+                digest = packed.remap_digest(self.remap)
+            packed.check_compat(
+                meta,
+                batch_size=self.batch_size,
+                cold_nnz=self.max_nnz,
+                hot_nnz=self.hot_nnz if self.hot_size else 0,
+                hot_size=self.hot_size,
+                table_size=self.table_size,
+                hash_mode=self.hash_mode,
+                hash_seed=self.hash_seed,
+                remap_sha256=digest,
+            )
         flight = self.obs.flight
         if self.emit_compact and meta.get("version", 1) == 2:
             records = packed.iter_compact_batches(f, start_offset)

@@ -122,9 +122,12 @@ def check_compat(
     table_size: int,
     hash_mode: bool,
     hash_seed: int,
-    remap: np.ndarray | None,
+    remap_sha256: str | None,
 ) -> None:
-    """Raise unless the cache was built for exactly this batch config."""
+    """Raise unless the cache was built for exactly this batch config.
+    ``remap_sha256`` is ``remap_digest`` of the loader's remap: the
+    caller computes it, so that it can time it (at 2^28 rows the digest
+    is the whole cost of a shard open)."""
     want = {
         "batch_size": batch_size,
         "cold_nnz": cold_nnz,
@@ -132,7 +135,7 @@ def check_compat(
         "hot_size": hot_size,
         "table_size": table_size,
         "hash_mode": bool(hash_mode),
-        "remap_sha256": remap_digest(remap),
+        "remap_sha256": remap_sha256,
     }
     for key, val in want.items():
         if meta.get(key) != val:

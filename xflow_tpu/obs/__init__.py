@@ -23,6 +23,8 @@ from __future__ import annotations
 import time
 from typing import Any, Callable
 
+from jax.profiler import TraceAnnotation
+
 from xflow_tpu.obs.registry import (
     NULL_REGISTRY,
     MetricsRegistry,
@@ -43,24 +45,58 @@ __all__ = [
 ]
 
 
-class _Phase:
-    """Times a block and books it BOTH as a ``phase.<name>`` counter
-    (wall-second accounting) and as a trace span."""
+# Every name the program gives to its own work appears on the JAX
+# profiler's timeline under this prefix: host phases and spans through
+# TraceAnnotation (here), device operations through jax.named_scope
+# (parallel/step.py, ops/hot.py).  An annotation outside a profiler
+# session is one flag check.  ``xfb:`` is the benchmark harness's.
+PROFILER_PREFIX = "xf."
 
-    __slots__ = ("_obs", "_name", "_t0")
+
+class _Phase:
+    """Times a block and books it as a ``phase.<name>`` counter
+    (wall-second accounting), as a trace span, and as an ``xf.<name>``
+    span on the JAX profiler's clock, where a device gap can be laid
+    against it."""
+
+    __slots__ = ("_obs", "_name", "_t0", "_annotation", "seconds")
 
     def __init__(self, obs: "Obs", name: str):
         self._obs = obs
         self._name = name
+        self._annotation = TraceAnnotation(PROFILER_PREFIX + name)
 
     def __enter__(self) -> "_Phase":
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> None:
-        dt = time.perf_counter() - self._t0
+        dt = self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
         self._obs.registry.counter_add("phase." + self._name, dt)
         self._obs.tracer.add_complete(self._name, self._t0, dt)
+        return None
+
+
+class _ProfiledSpan:
+    """A tracer span that is also an ``xf.<name>`` span on the JAX
+    profiler's timeline (no phase counter)."""
+
+    __slots__ = ("_span", "_annotation")
+
+    def __init__(self, span, name: str):
+        self._span = span
+        self._annotation = TraceAnnotation(PROFILER_PREFIX + name)
+
+    def __enter__(self) -> "_ProfiledSpan":
+        self._annotation.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
         return None
 
 
@@ -100,7 +136,7 @@ class Obs:
         """Trace-only span (no phase counter) — for enclosing scopes
         like a whole epoch, where counting the seconds would double the
         inner phases."""
-        return self.tracer.span(name, tags)
+        return _ProfiledSpan(self.tracer.span(name, tags), name)
 
     def counter(self, name: str, v: float = 1.0) -> None:
         self.registry.counter_add(name, v)

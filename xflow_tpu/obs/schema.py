@@ -129,6 +129,18 @@ SCHEMA: dict[str, dict[str, Any]] = {
         "wire_bytes_per_example": (int, float),
         "compaction_ratio": (int, float),
     },
+    # one per epoch that met a train shape for the first time, live Obs
+    # only (trainer.train_epoch -> TrainStep.op_scopes): for every
+    # instruction of the compiled train program(s) its name, its result
+    # type as a profiler prints it, and the xf.* scope the source gave
+    # it ("" = none) — the map a profile's operation events are joined
+    # with (docs/OBSERVABILITY.md "Scopes and spans")
+    "scopes": {
+        "t": (int, float),
+        "kind": str,
+        "epoch": int,
+        "ops": list,
+    },
     # one per training epoch under store_mode='tiered': hierarchical
     # parameter-store accounting (store/tiered.py; docs/STORE.md).
     # hot_hit_rate is occurrence-weighted (feature occurrences the HBM
@@ -381,6 +393,13 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "transfer_ahead_depth_mean": (int, float),
         # loaders that report parse phase bytes only
         "parse_mb_per_sec": (int, float),
+        # seconds the loop waited for the epoch's first batch (the first
+        # input_stall: shard opens and pipeline fill); at least one step
+        "first_batch_wait_s": (int, float),
+        # packed shards opened this epoch (io/loader.py::_iter_packed);
+        # their seconds are overlapped["shard_open"], of which
+        # overlapped["remap_digest"] hashed the hot remap
+        "shard_opens": int,
     },
     # fleet-mode rows only (serve/fleet.py pools N replicas into one
     # registry; rows written before the production tier predate these

@@ -83,6 +83,7 @@ def _pad_to(x: jax.Array, m_pad: int, fill) -> jax.Array:
     return jnp.concatenate([x, jnp.full(pad_shape, fill, x.dtype)])
 
 
+@jax.named_scope("xf.gather")
 def hot_gather(
     w_hot: jax.Array,
     keys: jax.Array,
@@ -138,6 +139,7 @@ def hot_gather(
     return out.reshape(m_pad, d)[:m]
 
 
+@jax.named_scope("xf.scatter")
 def hot_scatter(
     keys: jax.Array,
     grads: jax.Array,

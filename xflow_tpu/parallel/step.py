@@ -19,6 +19,8 @@ real example count (lr_worker.cc:116-118).
 from __future__ import annotations
 
 import functools
+import os
+import re
 from typing import Any
 
 import jax
@@ -39,6 +41,21 @@ from xflow_tpu.optim.base import Optimizer
 from xflow_tpu.parallel.mesh import batch_sharding, table_sharding
 from xflow_tpu.utils.metrics import logloss, logloss_sum, sigmoid_ref
 
+# One instruction of a compiled module's text: its name and, as
+# benchmarks/harness/trace_reduce.py::short_name prints it, its (first)
+# result type without layout — the pair a profiler's operation event
+# carries on a TPU.
+_HLO_INSTRUCTION_RE = re.compile(
+    r"^\s+(?:ROOT )?%?(?P<op>[^ ]+) = \(?(?P<type>[a-z0-9]+\[[0-9,]*\])?"
+)
+_HLO_NEVER_RUNS_RE = re.compile(
+    r" (?:parameter|constant|get-tuple-element|tuple)\("
+)
+_HLO_FUSED_RE = re.compile(r"\bfusion\(.*\bcalls=%?([^ ,)]+)")
+_HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([^ ]+) \(.*\{$")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_SCOPE_RE = re.compile(r"xf\.[A-Za-z0-9_]+")
+
 # State pytree:
 # {"tables": {name: {"param": [T,D], <aux>: [T,D]...}},
 #  "dense": {name: array} (replicated; {} for table-only models),
@@ -46,6 +63,17 @@ from xflow_tpu.utils.metrics import logloss, logloss_sum, sigmoid_ref
 State = dict[str, Any]
 
 
+def abstract_like(tree):
+    """``tree`` with every array replaced by its shape, dtype and
+    sharding: what a program can be lowered from without holding (or
+    donating) a buffer."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        tree,
+    )
+
+
+@jax.named_scope("xf.forward_backward")
 def grads_from_rows(model, rows: dict, dense: dict, mbatch: BatchArrays,
                     num_real: jax.Array):
     """pctr + per-occurrence gradients, rows already gathered: the ONE
@@ -87,6 +115,7 @@ def grads_from_rows(model, rows: dict, dense: dict, mbatch: BatchArrays,
     return pctr, occ_grads, None
 
 
+@jax.named_scope("xf.optimizer")
 def apply_dense_sgd(dense: dict, grad_dense, lr: float) -> dict:
     """Dense (MLP) params take plain SGD regardless of the table
     optimizer (models/wide_deep.py rationale) — the ONE copy of that
@@ -610,6 +639,66 @@ class TrainStep:
                 return self._dispatch_tiered(state, arrays)
             return self.train(state, arrays)
 
+    def op_scopes(self, state: State, arrays: BatchArrays) -> list[list[str]]:
+        """``[name, type, scope]`` for every instruction of the train
+        program compiled for these shapes: the map from what a profiler
+        calls a device operation to the ``xf.*`` scope the source gave
+        it (docs/OBSERVABILITY.md "Scopes and spans").  ``scope`` is the
+        first ``xf.<name>`` anywhere in the instruction's ``op_name``
+        (autodiff and scan wrap path components), ``""`` where the path
+        has none.  Instructions inside a fusion, parameters, constants
+        and tuple plumbing never run on their own and are left out.
+
+        The text is NOT the running program's: JAX's persistent
+        compilation cache leaves source metadata out of its key, so the
+        program that runs may have been compiled from an older source
+        and carry that source's scopes, or none.  Instruction names do
+        not depend on metadata, so a compile of this source for the
+        same shapes names the same instructions, and it is keyed WITH
+        its metadata (file names relative to the checkout): a cache hit
+        only when this very source was mapped before, from any
+        checkout.  Lowered from abstract shapes through a function object
+        of its own (the running program's cached lowering would hand
+        back the running executable), so nothing is held or donated;
+        the second executable is dropped before this returns."""
+
+        def _train_impl(state, batch):
+            return self._train_impl(state, batch)
+
+        checkout = os.path.abspath(__file__).rsplit(os.sep, 3)[0]
+        flags = {
+            "jax_compilation_cache_include_metadata_in_key": True,
+            "jax_hlo_source_file_canonicalization_regex": re.escape(checkout),
+        }
+        before = {flag: getattr(jax.config, flag) for flag in flags}
+        try:
+            for flag, value in flags.items():
+                jax.config.update(flag, value)
+            text = jax.jit(_train_impl, donate_argnums=0).lower(
+                abstract_like(state), abstract_like(arrays)
+            ).compile().as_text()
+        finally:
+            for flag, value in before.items():
+                jax.config.update(flag, value)
+        fused = set(_HLO_FUSED_RE.findall(text))
+        rows = []
+        inside_fusion = False
+        for line in text.splitlines():
+            head = _HLO_COMPUTATION_RE.match(line)
+            if head:
+                inside_fusion = head.group(1) in fused
+                continue
+            m = None if inside_fusion else _HLO_INSTRUCTION_RE.match(line)
+            if not m or _HLO_NEVER_RUNS_RE.search(line):
+                continue
+            path = _HLO_OP_NAME_RE.search(line)
+            scope = _SCOPE_RE.search(path.group(1)) if path else None
+            rows.append([
+                m.group("op"), m.group("type") or "",
+                scope.group(0) if scope else "",
+            ])
+        return rows
+
     def _dispatch_tiered(
         self, state: State, arrays: BatchArrays
     ) -> tuple[State, dict[str, jax.Array]]:
@@ -770,6 +859,7 @@ class TrainStep:
             out["hot_mask"] = hmask
         return out
 
+    @jax.named_scope("xf.wire_decode")
     def _expand_wire(self, batch: BatchArrays) -> BatchArrays:
         """Inverse of batch_to_compact, inside the jitted step: padding
         is key == -1; real entries have val = mask = 1 (hash mode);
@@ -816,6 +906,7 @@ class TrainStep:
             out["hot_mask"] = hmask
         return out
 
+    @jax.named_scope("xf.gather")
     def _gather_model_rows(
         self, tables: dict[str, dict[str, jax.Array]], batch: BatchArrays
     ) -> dict[str, jax.Array]:
@@ -850,6 +941,7 @@ class TrainStep:
             out[name] = jnp.concatenate([hot, cold[name]], axis=1)
         return out
 
+    @jax.named_scope("xf.wire_decode")
     def _model_view(self, batch: BatchArrays) -> BatchArrays:
         """Batch as the model sees it: hot + cold sections concatenated
         along the feature axis (models are permutation-invariant over a
@@ -873,6 +965,7 @@ class TrainStep:
 
     # -- compiled bodies ---------------------------------------------------
 
+    @jax.named_scope("xf.forward_backward")
     def _logit(
         self, rows: dict[str, jax.Array], batch: BatchArrays, dense: dict
     ) -> jax.Array:
@@ -934,6 +1027,7 @@ class TrainStep:
             batch["mask"] > 0, batch["keys"], jnp.int32(self.cfg.table_size)
         ).reshape(-1)
 
+    @jax.named_scope("xf.scatter")
     def _cold_accumulate(
         self, gbuf: jax.Array, keys_eff: jax.Array, occ: jax.Array, plan
     ) -> jax.Array:
@@ -948,6 +1042,7 @@ class TrainStep:
             return gbuf.at[ukeys].add(gsum, mode="drop")
         return gbuf.at[keys_eff].add(occ, mode="drop")
 
+    @jax.named_scope("xf.scatter")
     def _scatter_grads(
         self,
         tables: dict,
@@ -1065,8 +1160,7 @@ class TrainStep:
             # hot planes, when present, take _sparse_update's hybrid
             # path (dense [H, D] head update, overflow fold)
             new_tables = self._sparse_update(tables, batch, occ_grads)
-            ll = logloss(batch["labels"], pctr, batch["weights"])
-            cnt = jnp.sum(batch["weights"])
+            ll, cnt = self._batch_logloss(batch, pctr)
             return self._finish_step(
                 state, new_tables, dense, grad_dense, ll, cnt
             )
@@ -1076,12 +1170,7 @@ class TrainStep:
         # the recurrence runs elementwise over the full table — no sort,
         # no row gather/scatter.  Untouched rows see g=0, for which
         # FTRL/SGD are idempotent (optim docstrings).
-        gbufs = {
-            # the [T, D] buffer IS dense mode's design (small-table
-            # form; 'sparse' is the 2^28 form) — budgeted in
-            # memory-budget.json, justified here (xf: ignore[XF010])
-            name: jnp.zeros_like(t["param"]) for name, t in tables.items()
-        }
+        gbufs = self._zero_gbufs(tables)
         s = cfg.microbatch
         if s == 1:
             pctr, occ_grads, grad_dense = self._forward_grads(
@@ -1090,8 +1179,7 @@ class TrainStep:
             gbufs = self._scatter_grads(
                 tables, batch, occ_grads, gbufs, dict_plan=dict_plan
             )
-            ll = logloss(batch["labels"], pctr, batch["weights"])
-            cnt = jnp.sum(batch["weights"])
+            ll, cnt = self._batch_logloss(batch, pctr)
         else:
             # Gradient accumulation (Config.microbatch): scan over batch
             # slices so every [B-slice, nnz, D] intermediate is 1/s the
@@ -1125,11 +1213,38 @@ class TrainStep:
             ll = nll_sum / jnp.maximum(cnt, 1.0)
 
         new_tables = {
-            name: self.optimizer.update_rows(table, gbufs[name])
+            name: self._optimizer_pass(table, gbufs[name])
             for name, table in tables.items()
         }
         return self._finish_step(
             state, new_tables, dense, grad_dense, ll, cnt
+        )
+
+    @jax.named_scope("xf.scatter")
+    def _zero_gbufs(self, tables: dict) -> dict:
+        """The zeroed [T, D] gradient buffers of the dense update (one
+        per table), booked with the scatter that fills them."""
+        return {
+            # the [T, D] buffer IS dense mode's design (small-table
+            # form; 'sparse' is the 2^28 form) — budgeted in
+            # memory-budget.json, justified here (xf: ignore[XF010])
+            name: jnp.zeros_like(t["param"]) for name, t in tables.items()
+        }
+
+    @jax.named_scope("xf.optimizer")
+    def _optimizer_pass(self, table: dict, g: jax.Array) -> dict:
+        """The optimizer recurrence over whole arrays: the dense [T, D]
+        pass, and the [H, D] head of the hot sequential inner."""
+        return self.optimizer.update_rows(table, g)
+
+    @jax.named_scope("xf.metrics")
+    def _batch_logloss(
+        self, batch: BatchArrays, pctr: jax.Array
+    ) -> tuple[jax.Array, jax.Array]:
+        """(mean logloss, real count) of one whole batch."""
+        return (
+            logloss(batch["labels"], pctr, batch["weights"]),
+            jnp.sum(batch["weights"]),
         )
 
     def _hot_keys_eff(self, batch: BatchArrays) -> jax.Array:
@@ -1144,6 +1259,7 @@ class TrainStep:
             jnp.int32(self.cfg.hot_size),
         ).reshape(-1)
 
+    @jax.named_scope("xf.optimizer")
     def _apply_touched_rows(
         self, table: dict, ukeys: jax.Array, gsum: jax.Array
     ) -> dict:
@@ -1159,6 +1275,7 @@ class TrainStep:
             k: scatter_rows(table[k], ukeys, new_rows[k]) for k in table
         }
 
+    @jax.named_scope("xf.optimizer")
     def _sparse_update(
         self, tables: dict, batch: BatchArrays, occ_grads: dict
     ) -> dict:
@@ -1296,19 +1413,14 @@ class TrainStep:
                 # viable inner at T=2^28 (config.sequential_inner)
                 new_tables = self._sparse_update(tables_c, bslice, occ_s)
             else:
-                gbufs = {
-                    # dense inner: full-table pass per slice BY CHOICE
-                    # (config.sequential_inner documents the cost; the
-                    # sparse/hot inners are the 2^28 forms) — budgeted
-                    # in memory-budget.json (xf: ignore[XF010])
-                    name: jnp.zeros_like(t["param"])
-                    for name, t in tables_c.items()
-                }
+                # dense inner: full-table pass per slice BY CHOICE
+                # (config.sequential_inner documents the cost; the
+                # sparse/hot inners are the 2^28 forms)
                 gbufs = self._scatter_grads(
-                    tables_c, bslice, occ_s, gbufs
+                    tables_c, bslice, occ_s, self._zero_gbufs(tables_c)
                 )
                 new_tables = {
-                    name: self.optimizer.update_rows(table, gbufs[name])
+                    name: self._optimizer_pass(table, gbufs[name])
                     for name, table in tables_c.items()
                 }
             new_dense = self._apply_dense_sgd(dense_c, gd)
@@ -1382,9 +1494,11 @@ class TrainStep:
         # hoisted out of the scan.  Padding slots read row 0 and are
         # masked out of every reduction downstream (same convention as
         # _gather_model_rows).
-        cold_rows = {
-            name: t["param"][batch["keys"]] for name, t in tables.items()
-        }
+        with jax.named_scope("xf.gather"):
+            cold_rows = {
+                name: t["param"][batch["keys"]]
+                for name, t in tables.items()
+            }
         heads0 = {
             name: {k: arr[:h] for k, arr in t.items()}
             for name, t in tables.items()
@@ -1427,7 +1541,7 @@ class TrainStep:
                     hot_keys_eff, hot_g, h,
                     dtype=self._hot_dtype, impl=self._hot_impl,
                 )
-                new_heads[name] = self.optimizer.update_rows(head, ghot)
+                new_heads[name] = self._optimizer_pass(head, ghot)
             new_dense = self._apply_dense_sgd(dense_c, gd)
             nll_c = nll_c + logloss_sum(
                 bslice["labels"], pctr_s, bslice["weights"]
@@ -1492,7 +1606,7 @@ class TrainStep:
                 occ,
                 plan,
             )
-            new_tables[name] = self.optimizer.update_rows(merged, gbuf)
+            new_tables[name] = self._optimizer_pass(merged, gbuf)
         ll = nll_sum / jnp.maximum(cnt, 1.0)
         return {
             "tables": new_tables,
@@ -1510,12 +1624,13 @@ class TrainStep:
     def _finish_step(self, state, new_tables, dense, grad_dense, ll, cnt):
         """Shared step tail for the non-sequential update modes."""
         new_dense = self._apply_dense_sgd(dense, grad_dense)
-        metrics = {"logloss": ll, "count": cnt}
-        return {
-            "tables": new_tables,
-            "dense": new_dense,
-            "step": state["step"] + 1,
-        }, metrics
+        with jax.named_scope("xf.metrics"):
+            metrics = {"logloss": ll, "count": cnt}
+            return {
+                "tables": new_tables,
+                "dense": new_dense,
+                "step": state["step"] + 1,
+            }, metrics
 
     def _predict_impl(self, state: State, batch: BatchArrays) -> jax.Array:
         """pctr per example (reference calculate_pctr, lr_worker.cc:46-61)."""

@@ -34,13 +34,19 @@ from xflow_tpu.models import make_model
 from xflow_tpu.obs import NULL_OBS
 from xflow_tpu.optim import make_optimizer
 from xflow_tpu.parallel.mesh import make_mesh
-from xflow_tpu.parallel.step import TrainStep, init_state
+from xflow_tpu.parallel.step import TrainStep, abstract_like, init_state
 from xflow_tpu.utils.checkpoint import (
     latest_checkpoint,
     load_checkpoint,
     save_checkpoint,
 )
 from xflow_tpu.utils.metrics import AucAccumulator
+
+
+# Loader phases that run on stream/prefetch worker threads: the epoch
+# record books them under ``overlapped`` (they hide behind input_stall),
+# never under ``phases``, which sums to the epoch's wall seconds.
+WORKER_PHASES = ("parse", "pack", "shard_open", "remap_digest")
 
 
 def _ring_workers(depth: int) -> int:
@@ -104,6 +110,9 @@ class Trainer:
         self.host = jax.process_index()
         self.num_hosts = jax.process_count()
         self._global_steps = 0  # across epochs; drives the profile trigger
+        # train shapes whose program's instruction -> scope map has been
+        # logged (TrainStep.op_scopes; live Obs only)
+        self._scoped_shapes: set = set()
         # Live loader prefetch iterators (io/loader.py::_PrefetchIter),
         # closed explicitly by close() so abandoned producer threads
         # (crash, preemption, consumer break) never outlive the Trainer.
@@ -840,6 +849,11 @@ class Trainer:
         ckpt_seconds = 0.0
         preempted = False
         device_metrics = []  # fetched once at epoch end to keep dispatch async
+        first_wait = None  # seconds the loop waited for its first batch
+        # shapes met for the first time this epoch (live Obs only): their
+        # programs are mapped instruction -> scope at the epoch's end
+        map_scopes = obs.enabled and self.step.store is None
+        new_shapes: list = []
         profiling = False
         self._preempt_agreed = False
         last_cursor = (start_shard, start_offset)
@@ -869,10 +883,12 @@ class Trainer:
                     # h2d all hide behind this wait; whatever doesn't
                     # overlap device time surfaces here
                     self._pulse("input_stall")
-                    with obs.phase("input_stall"):
+                    with obs.phase("input_stall") as stall:
                         batch, shard_idx, resume = next(it)
                 except StopIteration:
                     break
+                if steps == 0 and obs.enabled:
+                    first_wait = stall.seconds
                 self._pulse("dispatch")
                 last_cursor = (shard_idx, resume)
                 if (
@@ -885,6 +901,13 @@ class Trainer:
                     profiling = True
                     profile_end = self._global_steps + cfg.profile_steps
                 arrays = batch if ahead else self.step.put_batch(batch)
+                if map_scopes:
+                    shape = tuple(sorted(
+                        (k, a.shape, a.dtype.name) for k, a in arrays.items()
+                    ))
+                    if shape not in self._scoped_shapes:
+                        self._scoped_shapes.add(shape)
+                        new_shapes.append(abstract_like(arrays))
                 self.state, metrics = self.step.dispatch_train(
                     self.state, arrays
                 )
@@ -929,6 +952,16 @@ class Trainer:
             self._pulse("device_block")
             with obs.phase("device_block"):
                 host_metrics = jax.device_get(device_metrics)
+            scope_rows = None
+            if new_shapes:
+                # one compile (or persistent-cache load) per new shape,
+                # in the epoch that first met it and never again
+                with obs.phase("op_scopes"):
+                    scope_rows = sorted({
+                        tuple(row)
+                        for shapes in new_shapes
+                        for row in self.step.op_scopes(self.state, shapes)
+                    })
             self._pulse("idle")  # epoch compute over — silence is benign
         if ahead:
             # no-op when the stream ran dry; on a preemption break it
@@ -941,9 +974,16 @@ class Trainer:
             sum(m["logloss"] * m["count"] for m in host_metrics)
         )
         dt = time.time() - t0
-        return self._epoch_stats(
+        stats = self._epoch_stats(
             seen, ll_sum, steps, dt, ckpt_seconds, preempted, ahead
         )
+        if first_wait is not None:
+            stats["first_batch_wait_s"] = round(first_wait, 6)
+        if scope_rows is not None:
+            stats["_scopes"] = {
+                "epoch": self.epoch, "ops": [list(r) for r in scope_rows],
+            }
+        return stats
 
     def _epoch_stats(
         self,
@@ -966,7 +1006,7 @@ class Trainer:
         phases = snap.phase_seconds()
         overlapped = {
             k: round(phases.pop(k), 6)
-            for k in ("parse", "pack") if k in phases
+            for k in WORKER_PHASES if k in phases
         }
         if ahead and "h2d" in phases:
             overlapped["h2d"] = round(phases.pop("h2d"), 6)
@@ -1042,6 +1082,8 @@ class Trainer:
                     6,
                 ),
             }
+        if "loader.shard_opens" in snap.counters:
+            stats["shard_opens"] = int(snap.counters["loader.shard_opens"])
         if "loader.parse_bytes" in snap.counters:
             stats["parse_mb_per_sec"] = round(
                 snap.counters["loader.parse_bytes"] / 2**20
@@ -1070,6 +1112,7 @@ class Trainer:
                 stats = self.train_epoch(start_shard, start_offset)
                 wire_stats = stats.pop("_wire", None)
                 store_stats = stats.pop("_store", None)
+                scope_stats = stats.pop("_scopes", None)
                 history.append(stats)
                 if self.metrics_logger is not None:
                     self.metrics_logger.log("train_epoch", stats)
@@ -1077,6 +1120,8 @@ class Trainer:
                         self.metrics_logger.log("wire", wire_stats)
                     if store_stats is not None:
                         self.metrics_logger.log("store", store_stats)
+                    if scope_stats is not None:
+                        self.metrics_logger.log("scopes", scope_stats)
                 self._log_device_mem()
                 if self.epoch % 30 == 0 or self.epoch == self.cfg.epochs - 1:
                     self._log(
@@ -1359,7 +1404,7 @@ class Trainer:
         # inline here — same exclusive/overlapped split as train_epoch
         overlapped = {
             k: round(phases.pop(k), 6)
-            for k in ("parse", "pack") if k in phases
+            for k in WORKER_PHASES if k in phases
         }
         result = {
             "epoch": self.epoch,
