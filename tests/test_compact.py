@@ -359,6 +359,242 @@ def test_dict_wire_matches_plain_wire(model, cold_consolidate):
     np.testing.assert_allclose(pctr_off, pctr_on, rtol=1e-5, atol=1e-6)
 
 
+# -- the device decode against the host's inverse -----------------------------
+
+_DECODE_DATA = ("all_padding", "full_rows", "odd_caps", "zipf")
+
+
+def _decode_case(data, hot, key_bytes):
+    """(Batch, table_size, hot_size, dict_cap) for one decode case.  The
+    batch is in wire order already: left-compacted rows, hot ids
+    < hot_size in the hot section, everything else cold."""
+    rng = np.random.default_rng(
+        _DECODE_DATA.index(data) * 7 + key_bytes + len(hot)
+    )
+    table = 1 << (20 if key_bytes == 3 else 26)
+    hot_size = {"none": 0, "u12": 1 << 10, "u16": 1 << 14}[hot]
+    # odd_caps: B*K is no multiple of 128 and nearly every slot is real,
+    # so the occurrence planes are capped at B*K (259 cold, 185 hot)
+    b, kc, kh = (37, 7, 5) if data == "odd_caps" else (48, 9, 11)
+    if not hot_size:
+        kh = 0
+    if data == "all_padding":
+        cc, hc = np.zeros(b, np.int64), np.zeros(b, np.int64)
+    elif data == "odd_caps":
+        cc, hc = np.full(b, kc), np.full(b, kh)
+        cc[:2] -= 1
+        hc[:2] = np.maximum(hc[:2] - 1, 0)
+    else:
+        cc = rng.integers(0, kc + 1, b)
+        hc = rng.integers(0, kh + 1, b)
+        cc[3], hc[3] = kc, kh  # a row at full K
+        cc[5], hc[5] = 0, 0    # and an empty one
+    cm = np.arange(kc)[None, :] < cc[:, None]
+    hm = np.arange(kh)[None, :] < hc[:, None]
+    if data == "zipf":  # duplicates: a dictionary tier AND a tail
+        cold = hot_size + np.minimum(
+            rng.zipf(1.3, (b, kc)), table - hot_size - 1
+        )
+    else:
+        cold = rng.integers(hot_size, table, (b, kc))
+    # both hot tiers: ids < 256 and above
+    hot_ids = np.where(
+        rng.random((b, kh)) < 0.5,
+        rng.integers(0, 256, (b, kh)),
+        rng.integers(0, max(hot_size, 1), (b, kh)),
+    )
+    f32 = np.float32
+    batch = make_batch(
+        np.where(cm, cold, 0).astype(np.int32),
+        np.where(cm, rng.integers(0, 200, (b, kc)), 0).astype(np.int32),
+        cm.astype(f32), cm.astype(f32),
+        (rng.random(b) < 0.4).astype(f32),
+        (rng.random(b) < 0.9).astype(f32),
+    )
+    batch.hot_keys = np.where(hm, hot_ids, 0).astype(np.int32)
+    batch.hot_slots = np.where(
+        hm, rng.integers(0, 200, (b, kh)), 0
+    ).astype(np.int32)
+    batch.hot_vals, batch.hot_mask = hm.astype(f32), hm.astype(f32)
+    return batch, table, hot_size, (24 if data == "zipf" else DICT_CAP)
+
+
+def _decode_step(model, table, hot_size, b, kc, kh):
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh
+    from xflow_tpu.parallel.step import TrainStep
+
+    cfg = Config(
+        model=model, batch_size=b, max_nnz=kc,
+        table_size_log2=table.bit_length() - 1, num_devices=1,
+        hot_size_log2=hot_size.bit_length() - 1 if hot_size else 0,
+        hot_nnz=kh, wire_dedup="on",
+    )
+    return TrainStep(make_model(cfg), make_optimizer(cfg), cfg, make_mesh(1))
+
+
+@pytest.mark.parametrize("data", _DECODE_DATA)
+@pytest.mark.parametrize("model", ["lr", "mvm"])  # slots not shipped / shipped
+@pytest.mark.parametrize("key_bytes", [3, 4])
+@pytest.mark.parametrize("hot", ["none", "u12", "u16"])
+def test_device_decode_equals_host_expand(hot, key_bytes, model, data):
+    """The jitted decode of the cw_* planes equals CompactBatch.expand()
+    plane for plane, bit for bit, and the shipped consolidation plan
+    (cold_uidx / cold_tail_keys / cold_dict_keys) reproduces the cold
+    keys: every wire variant one device can meet."""
+    batch, table, hot_size, dict_cap = _decode_case(data, hot, key_bytes)
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
+    assert (cb.key_bytes, cb.hx16) == (key_bytes, hot == "u16")
+    if data == "zipf":
+        assert 0 < cb.n_dict_occ < cb.n_cold  # both cold tiers in play
+    if data == "odd_caps":
+        assert len(cb.cs) % 128 and (not hot_size or len(cb.hs) % 128)
+    b, kc, kh = batch.batch_size, batch.max_nnz, batch.hot_nnz
+    step = _decode_step(model, table, hot_size, b, kc, kh)
+    assert step.dict_wire
+    ship = model == "mvm"
+    wire = cb.wire(step._ship_slots)
+    assert ("cw_cs" in wire) == ship
+    got = jax.device_get(jax.jit(step._expand_wire)(wire))
+    want = cb.expand()
+    batches_equal(want, batch)  # the host's inverse is exact
+    cold = {
+        "keys": want.keys, "vals": want.vals, "mask": want.mask,
+        "slots": want.slots if ship else np.zeros_like(want.slots),
+        "labels": want.labels, "weights": want.weights,
+    }
+    hot_planes = {
+        "hot_keys": want.hot_keys, "hot_vals": want.hot_vals,
+        "hot_mask": want.hot_mask,
+        "hot_slots": (
+            want.hot_slots if ship else np.zeros_like(want.hot_slots)
+        ),
+    } if hot_size else {}
+    assert set(got) == set(cold) | set(hot_planes) | {
+        "cold_uidx", "cold_tail_keys", "cold_dict_keys"
+    }
+    for name, plane in {**cold, **hot_planes}.items():
+        assert got[name].dtype == plane.dtype, name
+        np.testing.assert_array_equal(got[name], plane, err_msg=name)
+    # the consolidation plan: a dictionary occurrence points at its key,
+    # a tail occurrence carries it, padding is inert (dump slot, sentinel)
+    cap_d = len(cb.cu)
+    uidx, tail = got["cold_uidx"], got["cold_tail_keys"]
+    dkeys = got["cold_dict_keys"]
+    assert dkeys.shape == (cap_d,) and (dkeys[cb.n_dict:] == table).all()
+    in_dict = uidx < cap_d
+    valid = want.mask > 0
+    assert not (in_dict & ~valid).any()
+    assert (tail[in_dict | ~valid] == table).all()
+    rebuilt = np.where(
+        in_dict, np.append(dkeys, 0)[np.minimum(uidx, cap_d)], tail
+    )
+    np.testing.assert_array_equal(rebuilt[valid], want.keys[valid])
+    assert int(in_dict.sum()) == cb.n_dict_occ
+
+
+@pytest.mark.parametrize("data", _DECODE_DATA)
+@pytest.mark.parametrize("hot", ["none", "u12", "u16"])
+def test_device_decode_tpu_form_interpreted(hot, data):
+    """The decode as a TPU traces it (the Mosaic lane shuffle of
+    ops/window.py, run here by the Pallas TPU interpreter) equals the
+    form this backend runs, plane for plane."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from xflow_tpu.ops import window
+    from xflow_tpu.parallel.step import expand_dict_wire
+
+    batch, table, hot_size, dict_cap = _decode_case(data, hot, 4)
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
+    step = _decode_step(
+        "mvm", table, hot_size, batch.batch_size, batch.max_nnz,
+        batch.hot_nnz,
+    )
+    wire = cb.wire(True)
+    want = jax.device_get(jax.jit(step._expand_wire)(wire))
+    with pltpu.force_tpu_interpret_mode():
+        got = jax.device_get(jax.jit(
+            lambda w: expand_dict_wire(step.cfg, window.lane_select_tpu, w)
+        )(wire))
+    assert set(got) == set(want)
+    for name, plane in want.items():
+        assert got[name].dtype == plane.dtype, name
+        np.testing.assert_array_equal(got[name], plane, err_msg=name)
+
+
+def _element_gathers(jaxpr, found):
+    """Every XLA ``gather`` whose slices are single elements, through
+    all nested jaxprs but a Mosaic kernel's (there the gather is a lane
+    shuffle inside one vreg, not a DMA): (index count, operand shape,
+    whether only the minor axis is indexed)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "gather" and all(
+            s == 1 for s in eqn.params["slice_sizes"]
+        ):
+            operand, indices = (v.aval for v in eqn.invars[:2])
+            dn = eqn.params["dimension_numbers"]
+            found.append((
+                int(np.prod(indices.shape[:-1])), operand.shape,
+                tuple(dn.start_index_map) == (operand.ndim - 1,),
+            ))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _element_gathers(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("data", ["zipf", "odd_caps"])
+def test_device_decode_has_no_padded_size_element_gather(data):
+    """The mechanism of PR 25, pinned: no padded [B, K] plane is rebuilt
+    by a gather of one-element slices (a gather costs the TPU one DMA
+    descriptor per slice: 335 ms of a 407 ms step when the decode
+    gathered elements).  ONE element gather is allowed, by name: the
+    dictionary resolve ``cu[ci]`` (operand: the dictionary plane), one
+    index per entry of the ``cw_ci`` occurrence plane.  That plane is
+    smaller than B*K only by the data (plane_cap caps it AT B*K, which
+    the odd_caps case reaches: every slot a dictionary hit), so the
+    resolve is pinned as the exception and not by a size.  Beside it
+    the TPU form has no element gather at all; the form other backends
+    run gathers elements only inside a 256-wide window row
+    (ops/window.py::lane_select_xla), never from a stream."""
+    from xflow_tpu.ops import window
+    from xflow_tpu.parallel.step import expand_dict_wire
+
+    batch, table, hot_size, dict_cap = _decode_case(data, "u12", 4)
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
+    b, kc, kh = batch.batch_size, batch.max_nnz, batch.hot_nnz
+    step = _decode_step("mvm", table, hot_size, b, kc, kh)
+    wire = cb.wire(True)
+    if data == "odd_caps":
+        assert len(cb.ci) == b * kc  # plane_cap's ceiling, reached
+    else:
+        assert len(cb.ci) < b * kc
+    cap_d = len(cb.cu)
+    assert cap_d not in (0, 2 * window.LANES)
+
+    def others(lane_select):
+        """The decode's element gathers but the resolve, which must be
+        there exactly once."""
+        found = _element_gathers(
+            jax.make_jaxpr(
+                lambda w: expand_dict_wire(step.cfg, lane_select, w)
+            )(wire).jaxpr, []
+        )
+        resolve = [g for g in found if g[1] == (cap_d,)]
+        assert resolve == [(len(cb.ci), (cap_d,), True)], found
+        return [g for g in found if g[1] != (cap_d,)]
+
+    assert step._lane_select is window.lane_select_xla  # this backend's
+    lane_selects = others(window.lane_select_xla)
+    assert lane_selects and all(
+        shape[-1] == 2 * window.LANES and minor_only
+        for _, shape, minor_only in lane_selects
+    ), lane_selects
+    assert others(window.lane_select_tpu) == []  # what a TPU traces
+
+
 def test_dict_wire_eligibility_gates():
     common = dict(batch_size=64, table_size_log2=14, num_devices=1)
     from xflow_tpu.models import make_model

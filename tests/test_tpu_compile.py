@@ -1,0 +1,90 @@
+"""Compiles for a DESCRIBED v5e, with no chip: what the TPU's compiler
+refuses (a Mosaic kernel's tiling, fast memory, a program that does not
+fit) shows here and costs no chip time.  Nothing runs, so nothing here
+says a word about results or times.
+
+One file on purpose, and the topology only inside a fixture: one process
+at a time may load the TPU's library, so under several test workers only
+the worker that is given this file describes the chip."""
+
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache and cannot be read back without the device: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# rows of 128 outputs: the flagship's hot and cold planes (B=131072 x 28 /
+# 12), one that no block size divides, one smaller than a block
+@pytest.mark.parametrize("rows", [28672, 12288, 1000, 37])
+def test_lane_select_kernel_compiles_for_v5e(one_chip, no_compile_cache, rows):
+    from xflow_tpu.ops import window
+
+    win = jax.ShapeDtypeStruct((rows, 256), jnp.int32, sharding=one_chip)
+    local = jax.ShapeDtypeStruct((rows, 128), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(window.lane_select_tpu).lower(win, local).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_dict_wire_decode_compiles_for_v5e_at_flagship(
+    one_chip, no_compile_cache
+):
+    """The whole decode as a TPU traces it, at the plane capacities of one
+    real batch of the benchmark's train cell (T=2^28, B=131072, 12 + 28):
+    it compiles, holds its Mosaic kernels, and its temporaries (432 MiB,
+    most of it [B, K] int32 planes padded to 128 lanes) stay under the
+    1 GiB gradient buffer beside it: a materialised one-hot would not."""
+    from xflow_tpu.ops import window
+    from xflow_tpu.parallel.step import expand_dict_wire
+
+    u8, u16, u32 = np.uint8, np.uint16, np.uint32
+    shapes = {
+        "cw_cu": ((53248,), u32), "cw_cun": ((1,), np.int32),
+        "cw_ci": ((1228800,), u16), "cw_ct": ((294912,), u32),
+        "cw_cf": ((184320,), u8), "cw_cc": ((131072,), u8),
+        "cw_lb": ((16384,), u8), "cw_wb": ((16384,), u8),
+        "cw_h8": ((2293760,), u8), "cw_hx": ((1490944,), u8),
+        "cw_hxh": ((745472,), u8), "cw_hf": ((458752,), u8),
+        "cw_hc": ((131072,), u8),
+    }
+    wire = {
+        k: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for k, (shape, dtype) in shapes.items()
+    }
+    cfg = types.SimpleNamespace(max_nnz=12, hot_nnz=28, table_size=1 << 28)
+    compiled = jax.jit(
+        lambda w: expand_dict_wire(cfg, window.lane_select_tpu, w)
+    ).lower(wire).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 7
+    assert compiled.memory_analysis().temp_size_in_bytes < 768 << 20

@@ -31,7 +31,16 @@ by where its information actually lives:
   ranks < H; ids < 256 (~61% of hot occurrences at the flagship remap)
   ship as u8, the rest as packed u12 (H <= 2^12) or u16.
 * **padding never ships.**  Real entries stream flat in row-major
-  order with per-row u8 counts; [B, K] geometry is rebuilt on device.
+  order with per-row u8 counts; [B, K] geometry is rebuilt on device
+  (parallel/step.py::expand_dict_wire).  The FORM of that rebuild is
+  what it costs: a TPU gather pays a DMA descriptor per slice, so the
+  first decode, five one-element gathers per padded [B, K] slot, was
+  335 ms of the flagship's 407 ms step on a v5e; because every index
+  it needs is a running count over the streams (steps of 0 or 1), the
+  planes now come from row gathers of 128-lane windows plus a lane
+  shuffle (ops/window.py) in 12 ms, 9 of them the one true random
+  access and the one element gather left, the dictionary resolve over
+  the ci plane (at most B * K entries; PERF.md section 6, PR 25).
 * **labels/weights ship as bitmaps** (eligibility requires the 0/1
   hash-mode invariant, like the plain compact wire).
 
@@ -425,7 +434,7 @@ class CompactBatch:
 
     def wire(self, ship_slots: bool) -> dict[str, np.ndarray]:
         """The numpy planes that cross the link, keyed by the cw_*
-        names parallel/step.py::_expand_dict_wire decodes.  Slots ship
+        names parallel/step.py::expand_dict_wire decodes.  Slots ship
         (clamped to the u8 ignored-range convention of
         compact_wire_np) only when the model reads them."""
         out = {

@@ -37,6 +37,11 @@ from xflow_tpu.ops.sparse import (
     gather_rows,
     scatter_rows,
 )
+from xflow_tpu.ops.window import (
+    lane_select_tpu,
+    lane_select_xla,
+    monotone_take,
+)
 from xflow_tpu.optim.base import Optimizer
 from xflow_tpu.parallel.mesh import batch_sharding, table_sharding
 from xflow_tpu.utils.metrics import logloss, logloss_sum, sigmoid_ref
@@ -309,6 +314,155 @@ def _interleaved_slices(batch: BatchArrays, s: int) -> BatchArrays:
     }
 
 
+def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
+    """Inverse of CompactBatch.wire (io/compact.py), inside the
+    jitted step: rebuild the padded [B, K] planes from the flat
+    tiered streams, and keep the host-computed dictionary indices
+    as ``cold_uidx``/``cold_dict_keys``/``cold_tail_keys`` so
+    _scatter_grads can consolidate WITHOUT a device argsort.
+
+    ``cfg`` gives max_nnz, hot_nnz and table_size; ``lane_select`` is
+    the in-window shuffle of ops/window.py that the caller's platform
+    runs (TrainStep picks it from its mesh).
+
+    No padded ([B, K]) plane is rebuilt by gathering single elements
+    (a gather costs a DMA descriptor per slice on the TPU, so the
+    first form of this decode, five scalar gathers per plane, was 335
+    of the flagship's 407 ms step: PERF.md section 6, PR 25).  Every
+    index the rebuild needs is a running count over the padded
+    positions in row-major order: entries before this one (``pos``),
+    tier-A entries before it, tier-B entries before it.  Running
+    counts are non-decreasing with steps of 0 or 1, so each plane is
+    one ops/window.py::monotone_take: a row gather per 128 outputs
+    and a lane shuffle inside the window.
+
+    ONE element gather is left, by name: the cold dictionary resolve
+    ``cu[ci]``, the decode's only random access.  It runs over the
+    flat occurrence plane ``cw_ci``, whose length is the plane_cap
+    bucket of the batch's dictionary occurrences: at most B * max_nnz
+    by plane_cap's ceiling and as much as that in a batch whose cold
+    entries are nearly all dictionary hits, so it is smaller than a
+    padded plane by the data, not by construction (flagship: 1 228 800
+    of 1 572 864, 8.8 of the decode's 12.3 ms).
+
+    Every plane capacity is static (plane_cap bucketing), so one
+    steady batch geometry is one compiled program; the per-batch
+    real counts arrive as the cc/hc count planes and the cw_cun
+    scalar."""
+    kc = cfg.max_nnz
+    b = w["cw_cc"].shape[0]
+    t_sent = jnp.int32(cfg.table_size)
+    take = functools.partial(monotone_take, lane_select=lane_select)
+
+    def bits(plane: jax.Array, n: int) -> jax.Array:
+        p = plane.astype(jnp.int32)[:, None]
+        shifts = jnp.arange(8, dtype=jnp.int32)[None, :]
+        return ((p >> shifts) & 1).reshape(-1)[:n]
+
+    def keys_plane(plane: jax.Array) -> jax.Array:
+        if plane.ndim == 1:  # u32
+            return plane.astype(jnp.int32)
+        p = plane.astype(jnp.int32)  # [n, 3] u24 little-endian
+        return p[:, 0] | (p[:, 1] << 8) | (p[:, 2] << 16)
+
+    def section(counts_plane, flags_plane, width):
+        """Where each padded position of a [B, width] section reads
+        the flat streams.  All flat over B*width, row-major:
+        ``valid``; ``pos``, the real entries before this position
+        (its own index in the flat occurrence stream where valid);
+        ``is_a``/``is_b``, valid and flagged tier A (bit 1) / tier
+        B; ``a_idx``/``b_idx``, the tier's entries before it."""
+        counts = counts_plane.astype(jnp.int32)[:, None]
+        colj = jnp.arange(width, dtype=jnp.int32)[None, :]
+        valid = (colj < counts).reshape(-1)
+        row_start = jnp.cumsum(counts, axis=0) - counts
+        pos = (row_start + jnp.minimum(colj, counts)).reshape(-1)
+        # the flag BYTE index pos >> 3 is a running count too
+        fbyte = take(pos >> 3, flags_plane.astype(jnp.int32))
+        flag = (fbyte >> (pos & 7)) & 1
+        is_a = valid & (flag == 1)
+        is_b = valid & (flag == 0)
+        a = is_a.astype(jnp.int32)
+        a_idx = jnp.cumsum(a) - a
+        return valid, pos, is_a, is_b, a_idx, pos - a_idx
+
+    def slots_plane(plane, pos, valid, width):
+        s = take(pos, plane.astype(jnp.int32))
+        return jnp.where(valid, s, 0).reshape(b, width)
+
+    # cold: tier A = dictionary indices (resolved through cw_cu on
+    # the flat occurrence stream), tier B = raw tail keys
+    cvalid, cpos, is_dict, is_tail, di_idx, tail_idx = section(
+        w["cw_cc"], w["cw_cf"], kc
+    )
+    cu = keys_plane(w["cw_cu"])
+    cap_d = cu.shape[0]
+    ci = w["cw_ci"].astype(jnp.int32)
+    if cap_d:
+        dict_key_flat = jnp.take(cu, ci, mode="clip")
+        dict_keys_eff = jnp.where(
+            jnp.arange(cap_d) < w["cw_cun"][0], cu, t_sent
+        )
+    else:
+        dict_key_flat = jnp.zeros_like(ci)
+        dict_keys_eff = cu
+    di = take(di_idx, ci)
+    dict_key = take(di_idx, dict_key_flat)
+    tail = take(tail_idx, keys_plane(w["cw_ct"]))
+    cmask = cvalid.astype(jnp.float32).reshape(b, kc)
+    keys2d = jnp.where(
+        is_dict, dict_key, jnp.where(is_tail, tail, 0)
+    ).reshape(b, kc)
+    out = {
+        "keys": keys2d,
+        "slots": (
+            slots_plane(w["cw_cs"], cpos, cvalid, kc)
+            if "cw_cs" in w
+            else jnp.zeros_like(keys2d)
+        ),
+        "vals": cmask,
+        "mask": cmask,
+        "labels": bits(w["cw_lb"], b).astype(jnp.float32),
+        "weights": bits(w["cw_wb"], b).astype(jnp.float32),
+        # the host-computed consolidation plan (Config.wire_dedup):
+        # occurrence -> dictionary slot (cap_d = dump for padding
+        # and tail), tail occurrences sentinel-coded for a direct
+        # drop-mode scatter, dictionary slot -> table row
+        "cold_uidx": jnp.where(is_dict, di, cap_d).reshape(b, kc),
+        "cold_tail_keys": jnp.where(is_tail, tail, t_sent).reshape(
+            b, kc
+        ),
+        "cold_dict_keys": dict_keys_eff,
+    }
+    if "cw_hc" in w:
+        kh = cfg.hot_nnz
+        hvalid, hpos, is_h8, is_hx, h8_idx, hx_idx = section(
+            w["cw_hc"], w["cw_hf"], kh
+        )
+        hx_vals = w["cw_hx"].astype(jnp.int32)
+        if w["cw_hxh"].shape[0]:  # u12 tier: u8 lows + nibble highs
+            hib = w["cw_hxh"].astype(jnp.int32)
+            hi = jnp.stack(
+                [hib & 0xF, hib >> 4], axis=1
+            ).reshape(-1)[: hx_vals.shape[0]]
+            hx_vals = hx_vals | (hi << 8)
+        h8 = take(h8_idx, w["cw_h8"].astype(jnp.int32))
+        hx = take(hx_idx, hx_vals)
+        hot2d = jnp.where(
+            is_h8, h8, jnp.where(is_hx, hx, 0)
+        ).reshape(b, kh)
+        hmask = hvalid.astype(jnp.float32).reshape(b, kh)
+        out["hot_keys"] = hot2d
+        out["hot_slots"] = (
+            slots_plane(w["cw_hs"], hpos, hvalid, kh)
+            if "cw_hs" in w
+            else jnp.zeros_like(hot2d)
+        )
+        out["hot_vals"] = hmask
+        out["hot_mask"] = hmask
+    return out
+
+
 class TrainStep:
     """Holds the compiled train/predict functions for one (model,
     optimizer, config, mesh) combination."""
@@ -372,6 +526,12 @@ class TrainStep:
             cfg.hot_impl
             if cfg.hot_impl != "auto"
             else ("mxu" if platform == "tpu" else "seg")
+        )
+        # In-window lane shuffle of the dictionary-wire decode
+        # (ops/window.py): Mosaic's one-vreg dynamic_gather on the TPU,
+        # the plain minor-axis gather elsewhere.
+        self._lane_select = (
+            lane_select_tpu if platform == "tpu" else lane_select_xla
         )
         # Window-end form for the hot sequential inner
         # (Config.hot_windowend): the dense [T, D] cold-tail pass is
@@ -711,163 +871,15 @@ class TrainStep:
         self.store.defer_complete(plan, miss_out)
         return new_state, metrics
 
-    def _expand_dict_wire(self, w: BatchArrays) -> BatchArrays:
-        """Inverse of CompactBatch.wire (io/compact.py), inside the
-        jitted step: rebuild the padded [B, K] planes from the flat
-        tiered streams, and keep the host-computed dictionary indices
-        as ``cold_uidx``/``cold_dict_keys``/``cold_tail_keys`` so
-        _scatter_grads can consolidate WITHOUT a device argsort.
-
-        Every plane capacity is static (plane_cap bucketing), so one
-        steady batch geometry is one compiled program; the per-batch
-        real counts arrive as the cc/hc count planes and the cw_cun
-        scalar."""
-        cfg = self.cfg
-        kc = cfg.max_nnz
-        b = w["cw_cc"].shape[0]
-        t_sent = jnp.int32(cfg.table_size)
-
-        def bits(plane: jax.Array, n: int) -> jax.Array:
-            i = jnp.arange(n, dtype=jnp.int32)
-            return (
-                plane[i >> 3].astype(jnp.int32) >> (i & 7)
-            ) & 1
-
-        def keys_plane(plane: jax.Array) -> jax.Array:
-            if plane.ndim == 1:  # u32
-                return plane.astype(jnp.int32)
-            p = plane.astype(jnp.int32)  # [n, 3] u24 little-endian
-            return p[:, 0] | (p[:, 1] << 8) | (p[:, 2] << 16)
-
-        def tiered(
-            counts, flags_plane, a_plane, b_vals, width
-        ):
-            """Rebuild a [B, width] id plane from two flat tier streams:
-            per-entry flag bit 1 -> stream ``a_plane``, 0 -> ``b_vals``
-            (already decoded [capB] i32).  Returns (ids2d, valid,
-            a_pos2d, is_a, is_b, entry2d) for consumers that also need
-            the tier ranks (the cold consolidation)."""
-            rp = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32), jnp.cumsum(counts)]
-            )
-            colj = jnp.arange(width, dtype=jnp.int32)[None, :]
-            entry = rp[:-1, None] + colj
-            valid = colj < counts[:, None]
-            cap = flags_plane.shape[0] * 8
-            e = jnp.clip(entry, 0, max(cap - 1, 0))
-            if cap == 0:
-                z = jnp.zeros((b, width), jnp.int32)
-                return z, valid, z, z > 0, z > 0
-            f = bits(flags_plane, cap)
-            a_pos = jnp.cumsum(f) - 1
-            b_pos = jnp.cumsum(1 - f) - 1
-            fe = f[e]
-            cap_a = a_plane.shape[0]
-            cap_b = b_vals.shape[0]
-            av = (
-                a_plane[jnp.clip(a_pos[e], 0, cap_a - 1)].astype(
-                    jnp.int32
-                )
-                if cap_a
-                else jnp.zeros((b, width), jnp.int32)
-            )
-            bv = (
-                b_vals[jnp.clip(b_pos[e], 0, cap_b - 1)]
-                if cap_b
-                else jnp.zeros((b, width), jnp.int32)
-            )
-            is_a = valid & (fe == 1)
-            is_b = valid & (fe == 0)
-            ids = jnp.where(is_a, av, jnp.where(is_b, bv, 0))
-            return ids, valid, av, is_a, is_b
-
-        def flat_slots(plane, counts, width):
-            rp = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32), jnp.cumsum(counts)]
-            )
-            colj = jnp.arange(width, dtype=jnp.int32)[None, :]
-            valid = colj < counts[:, None]
-            cap = plane.shape[0]
-            if cap == 0:
-                return jnp.zeros((b, width), jnp.int32)
-            e = jnp.clip(rp[:-1, None] + colj, 0, cap - 1)
-            return jnp.where(valid, plane[e].astype(jnp.int32), 0)
-
-        cc = w["cw_cc"].astype(jnp.int32)
-        tail_keys = keys_plane(w["cw_ct"])
-        # cold: tier A = dictionary indices (resolved through cw_cu),
-        # tier B = raw tail keys
-        di2d, cvalid, di_raw, is_dict, is_tail = tiered(
-            cc, w["cw_cf"], w["cw_ci"], tail_keys, kc
-        )
-        cu = keys_plane(w["cw_cu"])
-        cap_d = cu.shape[0]
-        nd = w["cw_cun"][0]
-        if cap_d:
-            dict_key2d = cu[jnp.clip(di_raw, 0, cap_d - 1)]
-            keys2d = jnp.where(
-                is_dict, dict_key2d, jnp.where(is_tail, di2d, 0)
-            )
-            dict_keys_eff = jnp.where(
-                jnp.arange(cap_d) < nd, cu, t_sent
-            )
-        else:
-            keys2d = jnp.where(is_tail, di2d, 0)
-            dict_keys_eff = cu
-        cmask = cvalid.astype(jnp.float32)
-        out = {
-            "keys": keys2d,
-            "slots": (
-                flat_slots(w["cw_cs"], cc, kc)
-                if "cw_cs" in w
-                else jnp.zeros_like(keys2d)
-            ),
-            "vals": cmask,
-            "mask": cmask,
-            "labels": bits(w["cw_lb"], b).astype(jnp.float32),
-            "weights": bits(w["cw_wb"], b).astype(jnp.float32),
-            # the host-computed consolidation plan (Config.wire_dedup):
-            # occurrence -> dictionary slot (cap_d = dump for padding
-            # and tail), tail occurrences sentinel-coded for a direct
-            # drop-mode scatter, dictionary slot -> table row
-            "cold_uidx": jnp.where(is_dict, di_raw, cap_d),
-            "cold_tail_keys": jnp.where(is_tail, di2d, t_sent),
-            "cold_dict_keys": dict_keys_eff,
-        }
-        if "cw_hc" in w:
-            kh = cfg.hot_nnz
-            hc = w["cw_hc"].astype(jnp.int32)
-            if w["cw_hxh"].shape[0]:  # u12 tier: u8 lows + nibble highs
-                hib = w["cw_hxh"].astype(jnp.int32)
-                hi = jnp.stack(
-                    [hib & 0xF, hib >> 4], axis=1
-                ).reshape(-1)[: w["cw_hx"].shape[0]]
-                hx_vals = w["cw_hx"].astype(jnp.int32) | (hi << 8)
-            else:
-                hx_vals = w["cw_hx"].astype(jnp.int32)
-            hot2d, hvalid, _, _, _ = tiered(
-                hc, w["cw_hf"], w["cw_h8"], hx_vals, kh
-            )
-            hmask = hvalid.astype(jnp.float32)
-            out["hot_keys"] = hot2d
-            out["hot_slots"] = (
-                flat_slots(w["cw_hs"], hc, kh)
-                if "cw_hs" in w
-                else jnp.zeros_like(hot2d)
-            )
-            out["hot_vals"] = hmask
-            out["hot_mask"] = hmask
-        return out
-
     @jax.named_scope("xf.wire_decode")
     def _expand_wire(self, batch: BatchArrays) -> BatchArrays:
         """Inverse of batch_to_compact, inside the jitted step: padding
         is key == -1; real entries have val = mask = 1 (hash mode);
         slots widen from the u8 plane when the model reads them, else
         reconstruct as zeros.  Dictionary-wire batches (cw_* planes,
-        Config.wire_dedup) decode through _expand_dict_wire instead."""
+        Config.wire_dedup) decode through expand_dict_wire instead."""
         if "cw_cc" in batch:
-            return self._expand_dict_wire(batch)
+            return expand_dict_wire(self.cfg, self._lane_select, batch)
         if "ckeys" not in batch:
             return batch
         ckeys = batch["ckeys"]
