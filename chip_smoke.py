@@ -20,7 +20,8 @@ generated here from a seed:
               ``loadgen`` single-row traffic through ReplicaFleet and
               MicroBatcher
   4  mesh     Phase 1's geometry row-sharded over four chips, when the
-              machine has them
+              machine has them, and three steps of FM (v_dim=10) on the
+              same mesh against one chip
 
 Any failed check raises: the exit code is non-zero and no result line
 is printed.  On success stdout ends with two JSON lines.  The one before
@@ -397,6 +398,59 @@ def phase_train(
     return ll
 
 
+def synthetic_batches(cfg, rng, count: int, head_share: float) -> list:
+    """``count`` batches of 39 binary features a row at ``cfg``'s geometry:
+    ``head_share`` of the keys in the hot head, the rest anywhere in the
+    table."""
+    import numpy as np
+
+    from xflow_tpu.io.batch import make_batch
+
+    k = cfg.max_nnz + cfg.hot_nnz
+    batches = []
+    for _ in range(count):
+        keys = rng.integers(0, cfg.table_size, (cfg.batch_size, k))
+        head = rng.integers(0, cfg.hot_size, (cfg.batch_size, k))
+        keys = np.where(rng.random(keys.shape) < head_share, head, keys)
+        mask = np.zeros((cfg.batch_size, k), np.float32)
+        mask[:, :39] = 1.0
+        batches.append(make_batch(
+            keys.astype(np.int32),
+            np.broadcast_to(np.arange(k, dtype=np.int32), keys.shape).copy(),
+            np.ones(keys.shape, np.float32), mask,
+            rng.integers(0, 2, cfg.batch_size).astype(np.float32),
+            np.ones(cfg.batch_size, np.float32),
+            cfg.hot_size, cfg.hot_nnz,
+        ))
+    return batches
+
+
+def run_steps(cfg, devices: list, batches: list) -> tuple:
+    """One TrainStep over a mesh of ``devices`` from a fresh state through
+    ``batches``: (the hot implementation it chose, each step's logloss,
+    every table's parameters on the host)."""
+    import jax
+    import numpy as np
+
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh
+    from xflow_tpu.parallel.step import TrainStep, init_state
+
+    mesh = make_mesh(len(devices), devices=devices)
+    model, opt = make_model(cfg), make_optimizer(cfg)
+    step = TrainStep(model, opt, cfg, mesh)
+    state = init_state(model, opt, cfg, mesh)
+    lls = []
+    for batch in batches:
+        state, metrics = step.train(state, step.put_batch(batch))
+        lls.append(float(jax.device_get(metrics["logloss"])))
+    return step._hot_impl, lls, {
+        name: np.asarray(jax.device_get(t["param"]))
+        for name, t in state["tables"].items()
+    }
+
+
 def phase_parity(geom: dict, rehearsal: bool, out: dict) -> None:
     """(a) ops/hot.py's float32 promise, on the device: the MXU gather
     is bitwise ``w_hot[keys]``, the MXU scatter equals the segment-sum
@@ -407,12 +461,7 @@ def phase_parity(geom: dict, rehearsal: bool, out: dict) -> None:
     import numpy as np
 
     from xflow_tpu.config import Config
-    from xflow_tpu.io.batch import make_batch
-    from xflow_tpu.models import make_model
     from xflow_tpu.ops.hot import hot_gather, hot_scatter
-    from xflow_tpu.optim import make_optimizer
-    from xflow_tpu.parallel.mesh import make_mesh
-    from xflow_tpu.parallel.step import TrainStep, init_state
 
     rng = np.random.default_rng(SEED)
     h = 1 << geom["hot_size_log2"]
@@ -454,40 +503,15 @@ def phase_parity(geom: dict, rehearsal: bool, out: dict) -> None:
         max_nnz=geom["max_nnz"], hot_size_log2=geom["hot_size_log2"],
         hot_nnz=geom["hot_nnz"], num_devices=1,
     )
-    k = cfg.max_nnz + cfg.hot_nnz
-    batches = []
-    for _ in range(2):
-        keys = rng.integers(0, cfg.table_size, (cfg.batch_size, k))
-        head = rng.integers(0, cfg.hot_size, (cfg.batch_size, k))
-        keys = np.where(rng.random(keys.shape) < 0.6, head, keys)
-        mask = np.zeros((cfg.batch_size, k), np.float32)
-        mask[:, :39] = 1.0
-        batches.append(make_batch(
-            keys.astype(np.int32),
-            np.broadcast_to(np.arange(k, dtype=np.int32), keys.shape).copy(),
-            np.ones(keys.shape, np.float32), mask,
-            rng.integers(0, 2, cfg.batch_size).astype(np.float32),
-            np.ones(cfg.batch_size, np.float32),
-            cfg.hot_size, cfg.hot_nnz,
-        ))
+    batches = synthetic_batches(cfg, rng, 2, head_share=0.6)
     runs = {}
     for name, dev in (("device", jax.devices()[0]),
                       ("cpu", jax.devices("cpu")[0])):
         if name == "cpu" and dev == jax.devices()[0]:
             runs["cpu"] = runs["device"]  # rehearsal: one and the same
             continue
-        mesh = make_mesh(1, devices=[dev])
-        model, opt = make_model(cfg), make_optimizer(cfg)
-        step = TrainStep(model, opt, cfg, mesh)
-        state = init_state(model, opt, cfg, mesh)
-        lls = []
-        for batch in batches:
-            state, metrics = step.train(state, step.put_batch(batch))
-            lls.append(float(jax.device_get(metrics["logloss"])))
-        runs[name] = (
-            step._hot_impl, lls,
-            np.asarray(jax.device_get(state["tables"]["w"]["param"])),
-        )
+        impl, lls, tables = run_steps(cfg, [dev], batches)
+        runs[name] = (impl, lls, tables["w"])
     (impl, ll_d, w_d), (_, ll_c, w_c) = runs["device"], runs["cpu"]
     ll_err = max(abs(a - b) for a, b in zip(ll_d, ll_c))
     w_err = float(np.abs(w_d - w_c).max())
@@ -637,7 +661,45 @@ def phase_mesh(
         "last_logloss": round(ll[-1], 6),
         "max_logloss_gap_vs_one_chip": float(f"{band:.3g}"),
         "rows_per_device": t_rows // n,
+        "fm": mesh_fm_leg(geom, n),
     })
+
+
+def mesh_fm_leg(geom: dict, n: int) -> dict:
+    """Three steps of FM (v_dim=10: the D>1 rows of the exchange) on
+    the n-device mesh against the same three on ONE device of the same
+    backend: logloss and every row of both tables.  (Not against the
+    CPU backend: over 10^7 entries one can sit on FTRL's "no gradient
+    yet" branch on one backend and off it on the other — seen on the
+    v5e, one entry, PR 27 — which says nothing about the mesh.)"""
+    import jax
+    import numpy as np
+
+    from xflow_tpu.config import Config
+
+    toy = geom["table_size_log2"] <= 16
+    cfg = Config(
+        model="fm", optimizer="ftrl", table_size_log2=14 if toy else 20,
+        batch_size=1024 if toy else 16384, max_nnz=8,
+        hot_size_log2=geom["hot_size_log2"], hot_nnz=32, seed=SEED,
+    )
+    batches = synthetic_batches(
+        cfg, np.random.default_rng(SEED), 3, head_share=0.8
+    )
+    _, ll_m, rows_m = run_steps(cfg, jax.devices()[:n], batches)
+    _, ll_1, rows_1 = run_steps(cfg, jax.devices()[:1], batches)
+    ll_err = max(abs(a - b) for a, b in zip(ll_m, ll_1))
+    check(ll_err < 1e-5, f"FM logloss mesh vs one device: {ll_m} vs {ll_1}")
+    rows_err = 0.0
+    for name, want in rows_1.items():
+        err = float(np.abs(rows_m[name] - want).max() / np.abs(want).max())
+        check(err < 1e-5, f"FM table {name} mesh vs one device: relative {err}")
+        rows_err = max(rows_err, err)
+    return {
+        "steps": len(ll_m),
+        "logloss_err": float(f"{ll_err:.3g}"),
+        "rows_rel_err": float(f"{rows_err:.3g}"),
+    }
 
 
 def main(argv: list[str] | None = None) -> int:

@@ -1037,6 +1037,40 @@ def test_op_scopes_names_all_six(toy_dataset, wire_mode, wire_dedup, wire):
         assert type_ == "" or re.fullmatch(r"[a-z0-9]+\[[0-9,]*\]", type_)
 
 
+@pytest.mark.parametrize("devices", [1, 4])
+def test_op_scopes_reads_the_running_program(toy_dataset, tmp_path, devices):
+    """With a live Obs the process keys its compiles by source before the
+    first one, and the epoch that maps a new shape compiles nothing more:
+    the text is the running executable's (one device, and the mesh's
+    program with its exchange)."""
+    import jax
+    import jax.monitoring as mon
+    from xflow_tpu.parallel.step import abstract_like
+
+    compiled: list[float] = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(secs)
+
+    mon.register_event_duration_secs_listener(on_duration)
+    cfg = _toy_cfg(
+        toy_dataset, num_devices=devices, metrics_out=str(tmp_path / "m.jsonl")
+    )
+    with Trainer(cfg) as t:
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        batch, _, _ = next(iter(t.iter_train_batches(0, 0)))
+        arrays = t.step.put_batch(batch)
+        shapes = abstract_like(arrays)
+        t.state, _ = t.step.train(t.state, arrays)
+        before = len(compiled)
+        rows = t.step.op_scopes(t.state, shapes)
+        assert len(compiled) == before
+    scopes = {scope for _, _, scope in rows}
+    assert "xf.optimizer" in scopes
+    assert ("xf.exchange" in scopes) == (devices > 1)
+
+
 def test_null_obs_maps_no_scopes_and_annotates_nothing(toy_dataset, monkeypatch):
     """With metrics_out, obs_trace_out, obs_flight_out and obs_watchdog
     unset the trainer never calls op_scopes and creates no

@@ -18,16 +18,20 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -88,3 +92,63 @@ def test_dict_wire_decode_compiles_for_v5e_at_flagship(
     ).lower(wire).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 7
     assert compiled.memory_analysis().temp_size_in_bytes < 768 << 20
+
+
+def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
+    topo, no_compile_cache
+):
+    """The FM train step over the described 2x2 as the TPU's compiler
+    leaves it (parallel/exchange.py; the benchmark cell's widths, a table
+    and a batch cut to keep the compile short): every collective is the
+    program's or a scalar's, none is issued by a loop's iterations, none
+    has a block's rows.  The TPU runs a reduce-scatter as an all-reduce
+    and a slice, and may continue an all-gather inside a neighbouring
+    loop (``pieces`` > 1): collectives_in counts such a chain once."""
+    from xflow_tpu.config import Config
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.exchange import collectives_in
+    from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
+    from xflow_tpu.parallel.step import TrainStep
+
+    cfg = Config(
+        model="fm", optimizer="ftrl", v_dim=10, table_size_log2=22,
+        batch_size=16384, max_nnz=8, hot_size_log2=14, hot_nnz=32,
+        num_devices=4,
+    )
+    mesh = make_mesh(4, devices=list(topo.devices))
+    model = make_model(cfg)
+    step = TrainStep(model, make_optimizer(cfg), cfg, mesh)
+    assert step._hot_impl == "mxu" and step.wire_format == "compact"
+
+    def shaped(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    state = {
+        "tables": {
+            spec.name: {
+                name: shaped(
+                    (cfg.table_size, spec.dim), jnp.float32, table_sharding(mesh)
+                )
+                for name in ("param", "n", "z")
+            }
+            for spec in model.tables()
+        },
+        "dense": {},
+        "step": shaped((), jnp.int32, replicated(mesh)),
+    }
+    b = cfg.batch_size
+    arrays = {
+        "ckeys": shaped((b, cfg.max_nnz), jnp.int32, step._bsharding),
+        "hot_ckeys_u16": shaped((b, cfg.hot_nnz), jnp.uint16, step._bsharding),
+        "labels_u8": shaped((b,), jnp.uint8, step._bsharding),
+        "weights_u8": shaped((b,), jnp.uint8, step._bsharding),
+    }
+    text = step.train.lower(state, arrays).compile().as_text()
+    found = collectives_in(text)
+    assert " while(" in text and found
+    assert not [c for c in found if c["in_loop"]], found
+    slots = b * cfg.max_nnz
+    assert slots < cfg.table_size // 4
+    assert max(c["rows"] for c in found) <= slots + 1024, found  # + padding
+    assert len(found) <= 4 * 2 + 2 + 3 + 2, found  # + the reduce-scatters' fix-ups

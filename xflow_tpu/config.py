@@ -89,6 +89,16 @@ class Config:
     # -- parallelism --
     # Devices in the 1-D mesh ('data' axis).  0 = use all available.
     num_devices: int = 0
+    # Contiguous row blocks every table and its optimizer state are cut
+    # into: ps-lite's servers (DMLC_NUM_SERVER in the reference's
+    # scripts/local.sh:8-19), where num_devices is its workers.  The
+    # 1-D mesh holds one block on each device, so 0 (the default) means
+    # one a device, and a deployment whose memory and exchange were
+    # sized for a layout states it: the trainer refuses a mesh that
+    # would cut the tables otherwise (num_devices=0 on a larger host)
+    # instead of quietly training another layout.  It chooses no code
+    # path: the step observes the mesh (parallel/exchange.py).
+    table_shards: int = 0
 
     # -- observability (SURVEY §5: reference has stdout only) --
     # JSONL file receiving structured records (schema: obs/schema.py,
@@ -513,6 +523,16 @@ class Config:
             raise ValueError(f"unknown update_mode {self.update_mode!r}")
         if not 10 <= self.table_size_log2 <= 30:
             raise ValueError("table_size_log2 must be in [10, 30]")
+        if self.table_shards < 0:
+            raise ValueError("table_shards must be >= 0")
+        if self.table_shards and self.num_devices not in (
+            0, self.table_shards
+        ):
+            raise ValueError(
+                f"table_shards {self.table_shards} != num_devices "
+                f"{self.num_devices}: the mesh holds one row block of "
+                "each table on each device"
+            )
         if self.microbatch < 1:
             raise ValueError("microbatch must be >= 1")
         if self.microbatch > 1:

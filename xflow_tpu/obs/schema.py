@@ -388,6 +388,12 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "hostname": str,
         "pid": int,
     },
+    "wire": {
+        # meshes of more than one device only: bytes the train step's
+        # pull and push handed between the chips, a step, from shapes
+        # (parallel/step.py::exchange_bytes)
+        "exchange_bytes_per_step": (int, float),
+    },
     "train_epoch": {
         # single-host runs under trainer._transfer_ahead only
         "transfer_ahead_depth_mean": (int, float),

@@ -19,12 +19,12 @@ real example count (lr_worker.cc:116-118).
 from __future__ import annotations
 
 import functools
-import os
 import re
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from xflow_tpu.config import Config
 from xflow_tpu.io.batch import Batch
@@ -43,7 +43,9 @@ from xflow_tpu.ops.window import (
     monotone_take,
 )
 from xflow_tpu.optim.base import Optimizer
-from xflow_tpu.parallel.mesh import batch_sharding, table_sharding
+from xflow_tpu.parallel import exchange
+from xflow_tpu.parallel.mesh import DATA_AXIS, batch_sharding, table_sharding
+from xflow_tpu.utils.compile_cache import key_by_source
 from xflow_tpu.utils.metrics import logloss, logloss_sum, sigmoid_ref
 
 # One instruction of a compiled module's text: its name and, as
@@ -59,6 +61,10 @@ _HLO_NEVER_RUNS_RE = re.compile(
 _HLO_FUSED_RE = re.compile(r"\bfusion\(.*\bcalls=%?([^ ,)]+)")
 _HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([^ ]+) \(.*\{$")
 _HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS_RE = re.compile(r"\bcalls=%?([^ ,)]+)")
+_HLO_COLLECTIVE_RE = re.compile(
+    rf" (?:{exchange.COLLECTIVE_OPS})(?:-start|-done)?\("
+)
 _SCOPE_RE = re.compile(r"xf\.[A-Za-z0-9_]+")
 
 # State pytree:
@@ -473,6 +479,11 @@ class TrainStep:
         self.cfg = cfg
         self.mesh = mesh
         self._bsharding = batch_sharding(mesh)
+        # More than one device: the step writes its own pull and push
+        # between the batch's shards and the table's row blocks
+        # (parallel/exchange.py).  Observed, like the platform below;
+        # no Config field chooses it.
+        self._sharded = mesh.devices.size > 1
         self._hot_dtype = (
             jnp.bfloat16 if cfg.hot_dtype == "bfloat16" else jnp.float32
         )
@@ -797,7 +808,37 @@ class TrainStep:
         with self.obs.phase("dispatch"):
             if self.store is not None:
                 return self._dispatch_tiered(state, arrays)
+            if self._sharded and self.obs.enabled:
+                rows = next(iter(arrays.values())).shape[0]
+                self.obs.counter("exchange.bytes", self.exchange_bytes(rows))
             return self.train(state, arrays)
+
+    def exchange_bytes(self, batch_rows: int) -> int:
+        """Bytes the exchange's collectives hand over in one dense train
+        step of ``batch_rows`` examples at the loader's geometry, from
+        shapes (parallel/exchange.py): the all-gathered key planes,
+        once for the pull and once for the push; per table the pulled
+        rows (the reduce-scatter's operand) and the pushed gradient
+        rows (the all-gather's result); the head's rows from every chip
+        and its summed gradient.  Payload, not link traffic: a chip
+        sends and receives about (n - 1) / n of it.  0 on one device."""
+        if not self._sharded:
+            return 0
+        cfg = self.cfg
+        n = self.mesh.devices.size
+        kh = cfg.hot_nnz if cfg.hot_size else 0
+        # a table out of the MXU head (TableSpec.hot=False) moves its
+        # hot slots, and their key plane, like cold ones
+        dma_hot = kh if not all(self._mxu_hot.values()) else 0
+        total = 2 * batch_rows * (cfg.max_nnz + dma_hot) * 4
+        for spec in self.model.tables():
+            if kh and self._mxu_hot[spec.name]:
+                slots = batch_rows * cfg.max_nnz
+                total += (n + 1) * cfg.hot_size * spec.dim * 4
+            else:
+                slots = batch_rows * (cfg.max_nnz + kh)
+            total += 2 * slots * spec.dim * 4
+        return total
 
     def op_scopes(self, state: State, arrays: BatchArrays) -> list[list[str]]:
         """``[name, type, scope]`` for every instruction of the train
@@ -806,41 +847,38 @@ class TrainStep:
         it (docs/OBSERVABILITY.md "Scopes and spans").  ``scope`` is the
         first ``xf.<name>`` anywhere in the instruction's ``op_name``
         (autodiff and scan wrap path components), ``""`` where the path
-        has none.  Instructions inside a fusion, parameters, constants
-        and tuple plumbing never run on their own and are left out.
+        has none; on a mesh of more than one device a collective that
+        the compiler left without one is ``xf.exchange``'s (the rule is
+        in the loop below).  Instructions inside a fusion, parameters,
+        constants and tuple plumbing never run on their own and are
+        left out.
 
-        The text is NOT the running program's: JAX's persistent
-        compilation cache leaves source metadata out of its key, so the
-        program that runs may have been compiled from an older source
-        and carry that source's scopes, or none.  Instruction names do
-        not depend on metadata, so a compile of this source for the
-        same shapes names the same instructions, and it is keyed WITH
-        its metadata (file names relative to the checkout): a cache hit
-        only when this very source was mapped before, from any
-        checkout.  Lowered from abstract shapes through a function object
-        of its own (the running program's cached lowering would hand
-        back the running executable), so nothing is held or donated;
-        the second executable is dropped before this returns."""
-
-        def _train_impl(state, batch):
-            return self._train_impl(state, batch)
-
-        checkout = os.path.abspath(__file__).rsplit(os.sep, 3)[0]
-        flags = {
-            "jax_compilation_cache_include_metadata_in_key": True,
-            "jax_hlo_source_file_canonicalization_regex": re.escape(checkout),
-        }
-        before = {flag: getattr(jax.config, flag) for flag in flags}
-        try:
-            for flag, value in flags.items():
-                jax.config.update(flag, value)
-            text = jax.jit(_train_impl, donate_argnums=0).lower(
-                abstract_like(state), abstract_like(arrays)
-            ).compile().as_text()
-        finally:
-            for flag, value in before.items():
-                jax.config.update(flag, value)
+        The text is the running program's own: lowered from abstract
+        shapes through ``self.train``, whose cache hands back the
+        executable that these shapes already run: nothing is held or
+        donated, and nothing compiles unless the shapes have not run
+        yet.  JAX's persistent compilation
+        cache leaves source metadata out of its key by default, so a
+        loaded program could carry an older source's scopes, or none:
+        from here on this process keys its compiles WITH their metadata
+        (``compile_cache.key_by_source``; the trainer calls it before
+        its first compile whenever its Obs is live).  A program this
+        process compiled or loaded before that call keeps the scopes it
+        came with."""
+        key_by_source()
+        text = self.train.lower(
+            abstract_like(state), abstract_like(arrays)
+        ).compile().as_text()
         fused = set(_HLO_FUSED_RE.findall(text))
+        # computations that hold a collective (see the exchange's rule
+        # below)
+        holds_collective = set()
+        for line in text.splitlines():
+            head = _HLO_COMPUTATION_RE.match(line)
+            if head:
+                current = head.group(1)
+            elif _HLO_COLLECTIVE_RE.search(line):
+                holds_collective.add(current)
         rows = []
         inside_fusion = False
         for line in text.splitlines():
@@ -853,10 +891,22 @@ class TrainStep:
                 continue
             path = _HLO_OP_NAME_RE.search(line)
             scope = _SCOPE_RE.search(path.group(1)) if path else None
-            rows.append([
-                m.group("op"), m.group("type") or "",
-                scope.group(0) if scope else "",
-            ])
+            name = scope.group(0) if scope else ""
+            if not name and self._sharded:
+                # On a mesh every collective of the step is the
+                # exchange's, bar the all-reduces of a few scalars that
+                # the partitioner adds, and the TPU's compiler rewrites
+                # them without their metadata (a reduce-scatter becomes
+                # an all-reduce and a slice, or a fusion of its own; an
+                # asynchronous one a start/done pair).  So a collective
+                # with no scope, or an instruction that calls a
+                # computation holding one, is booked to the exchange.
+                callee = _HLO_CALLS_RE.search(line)
+                if _HLO_COLLECTIVE_RE.search(line) or (
+                    callee and callee.group(1) in holds_collective
+                ):
+                    name = exchange.SCOPE
+            rows.append([m.group("op"), m.group("type") or "", name])
         return rows
 
     def _dispatch_tiered(
@@ -918,8 +968,89 @@ class TrainStep:
             out["hot_mask"] = hmask
         return out
 
-    @jax.named_scope("xf.gather")
     def _gather_model_rows(
+        self, tables: dict[str, dict[str, jax.Array]], batch: BatchArrays
+    ) -> dict[str, jax.Array]:
+        """[B, K, D] parameter rows of the batch's keys, per table, hot
+        section first: a local gather on one device, the exchange's
+        pull on a mesh."""
+        if self._sharded:
+            return self._pull_model_rows(tables, batch)
+        return self._gather_local_rows(tables, batch)
+
+    def _pull_model_rows(
+        self, tables: dict[str, dict[str, jax.Array]], batch: BatchArrays
+    ) -> dict[str, jax.Array]:
+        """_gather_local_rows across a mesh (parallel/exchange.py): per
+        table ONE all-gather of the batch's cold keys (shared by the
+        tables), a gather of the whole batch's slots from this chip's
+        own row block with out-of-block keys reading zeros, and ONE
+        reduce-scatter that hands each chip its batch shard's rows (a
+        row lives in one block, so the sum adds exact zeros to it).
+        The head [0, H) is read once as a replicated block and the
+        one-hot scans of hot_gather run on the chip's batch shard: no
+        collective inside them."""
+        from xflow_tpu.ops.hot import hot_gather
+
+        h = self.cfg.hot_size
+
+        def pull(block: jax.Array, idx: jax.Array) -> jax.Array:
+            with jax.named_scope("xf.gather"):
+                rows = block.at[idx].get(mode="fill", fill_value=0.0)
+            return exchange.to_batch_shards(rows)
+
+        def body(params: dict, keys: jax.Array, hot_keys):
+            block_rows = next(iter(params.values())).shape[0]
+            idx = exchange.block_index(exchange.all_batch(keys), block_rows)
+            if hot_keys is None:
+                return {n: pull(p, idx) for n, p in params.items()}
+            b, kh = hot_keys.shape
+            hot_idx = None
+            out = {}
+            for name, block in params.items():
+                if self._mxu_hot[name]:
+                    head = exchange.read_head(block, h)
+                    with jax.named_scope("xf.gather"):
+                        hot = hot_gather(
+                            head,
+                            hot_keys.reshape(-1),
+                            dtype=self._hot_dtype,
+                            impl=self._hot_impl,
+                        ).reshape(b, kh, block.shape[-1])
+                else:
+                    # opted-out table (TableSpec.hot=False): its hot
+                    # occurrences are ordinary table rows, pulled like
+                    # the cold ones
+                    if hot_idx is None:
+                        hot_idx = exchange.block_index(
+                            exchange.all_batch(hot_keys), block_rows
+                        )
+                    hot = pull(block, hot_idx)
+                cold = pull(block, idx)
+                with jax.named_scope("xf.gather"):
+                    out[name] = jnp.concatenate([hot, cold], axis=1)
+            return out
+
+        return self._per_chip(body)(
+            {name: t["param"] for name, t in tables.items()},
+            batch["keys"],
+            batch.get("hot_keys"),
+        )
+
+    def _per_chip(self, body):
+        """``body`` run on every chip of the mesh over that chip's block
+        of each argument's leading axis (the table's rows, the batch's
+        examples), its results blocks of the same.  The varying-axis
+        check is off: the one-hot scans of ops/hot.py start their
+        carries from constants, which it refuses inside a manual
+        region, and nothing differentiates through an exchange."""
+        return jax.shard_map(
+            body, mesh=self.mesh, in_specs=P(DATA_AXIS),
+            out_specs=P(DATA_AXIS), check_vma=False,
+        )
+
+    @jax.named_scope("xf.gather")
+    def _gather_local_rows(
         self, tables: dict[str, dict[str, jax.Array]], batch: BatchArrays
     ) -> dict[str, jax.Array]:
         # Forward gather uses raw keys; padding entries read row 0 but are
@@ -1054,8 +1185,92 @@ class TrainStep:
             return gbuf.at[ukeys].add(gsum, mode="drop")
         return gbuf.at[keys_eff].add(occ, mode="drop")
 
-    @jax.named_scope("xf.scatter")
     def _scatter_grads(
+        self,
+        tables: dict,
+        batch: BatchArrays,
+        occ_grads: dict,
+        gbufs: dict,
+        dict_plan: dict | None = None,
+    ) -> dict:
+        """Per-occurrence grads summed into the dense [T, D] buffers
+        (one per table): a local scatter-add on one device, the
+        exchange's push on a mesh (which never rides the dict wire, so
+        there is no ``dict_plan`` to honor there)."""
+        if self._sharded:
+            return self._push_grads(batch, occ_grads, gbufs)
+        return self._scatter_local_grads(
+            tables, batch, occ_grads, gbufs, dict_plan=dict_plan
+        )
+
+    def _push_grads(
+        self, batch: BatchArrays, occ_grads: dict, gbufs: dict
+    ) -> dict:
+        """_scatter_local_grads across a mesh (parallel/exchange.py):
+        ONE all-gather of the batch's cold keys (shared by the tables)
+        and, per table, ONE of its gradient rows; every chip
+        scatter-adds the whole batch's slots into its own block of the
+        buffer, out-of-block keys dropped.  The head's gradient is
+        summed over each chip's batch shard by hot_scatter (no
+        collective inside its scan), then over the chips in ONE
+        all-reduce of [H, D], and joins rows [0, H) of the block(s)
+        that hold them."""
+        from xflow_tpu.ops.hot import hot_scatter
+
+        cfg = self.cfg
+        kh = batch["hot_keys"].shape[1] if "hot_keys" in batch else 0
+        with jax.named_scope("xf.scatter"):
+            planes = {"cold": self._cold_keys_eff(batch)}
+            if kh:
+                planes["hot"] = self._hot_keys_eff(batch)
+                if not all(self._mxu_hot.values()):
+                    planes["hot_dma"] = self._hot_keys_eff_dma(batch)
+
+        def body(gbufs: dict, planes: dict, occ_grads: dict) -> dict:
+            block_rows = next(iter(gbufs.values())).shape[0]
+            idx = exchange.block_index(
+                exchange.all_batch(planes["cold"]), block_rows
+            )
+            plan = (
+                consolidate_plan(idx, block_rows)
+                if cfg.cold_consolidate
+                else None
+            )
+            if "hot_dma" in planes:
+                hot_idx = exchange.block_index(
+                    exchange.all_batch(planes["hot_dma"]), block_rows
+                )
+            out = {}
+            for name, gbuf in gbufs.items():
+                d = gbuf.shape[-1]
+                occ = occ_grads[name]
+                with jax.named_scope("xf.scatter"):
+                    if kh:
+                        hot_g = occ[:, :kh].reshape(-1, d)
+                        occ = occ[:, kh:]
+                    occ = occ.reshape(-1, d)
+                gbuf = self._cold_accumulate(
+                    gbuf, idx, exchange.all_batch(occ), plan
+                )
+                if kh and self._mxu_hot[name]:
+                    ghot = exchange.sum_head(hot_scatter(
+                        planes["hot"], hot_g, cfg.hot_size,
+                        dtype=self._hot_dtype, impl=self._hot_impl,
+                    ))
+                    part = exchange.head_part(ghot, block_rows)
+                    with jax.named_scope("xf.scatter"):
+                        gbuf = gbuf.at[: part.shape[0]].add(part)
+                elif kh:
+                    hot_all = exchange.all_batch(hot_g)
+                    with jax.named_scope("xf.scatter"):
+                        gbuf = gbuf.at[hot_idx].add(hot_all, mode="drop")
+                out[name] = gbuf
+            return out
+
+        return self._per_chip(body)(gbufs, planes, occ_grads)
+
+    @jax.named_scope("xf.scatter")
+    def _scatter_local_grads(
         self,
         tables: dict,
         batch: BatchArrays,

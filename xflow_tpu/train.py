@@ -89,6 +89,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--sgd-lr", type=float, dest="sgd_lr")
     p.add_argument("--seed", type=int)
     p.add_argument("--num-devices", type=int, dest="num_devices")
+    p.add_argument(
+        "--table-shards", type=int, dest="table_shards",
+        help="row blocks the tables are cut into (0 = one a device); a "
+        "mesh that would cut them otherwise is refused",
+    )
     p.add_argument("--no-hash", action="store_true", help="numeric fids, keep values")
     p.add_argument(
         "--hot-size-log2", type=int, dest="hot_size_log2",

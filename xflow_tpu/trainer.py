@@ -88,6 +88,12 @@ class Trainer:
             raise ValueError(
                 f"table_size {cfg.table_size} not divisible by {ndev} devices"
             )
+        if cfg.table_shards not in (0, ndev):
+            raise ValueError(
+                f"table_shards {cfg.table_shards} stated, but this mesh of "
+                f"{ndev} device(s) cuts every table into {ndev} row "
+                "block(s)"
+            )
         self.model = make_model(cfg)
         self.optimizer = make_optimizer(cfg)
         self.step = TrainStep(self.model, self.optimizer, cfg, self.mesh)
@@ -142,7 +148,13 @@ class Trainer:
             or cfg.obs_watchdog
         ):
             from xflow_tpu.obs import make_obs
+            from xflow_tpu.utils.compile_cache import key_by_source
 
+            # the epoch that first meets a train shape reads the xf.*
+            # scopes off its running program (TrainStep.op_scopes): that
+            # program must be this source's, not an older one's from
+            # the persistent cache
+            key_by_source()
             self.obs = make_obs(
                 trace=bool(cfg.obs_trace_out),
                 trace_capacity=cfg.obs_trace_capacity,
@@ -954,8 +966,8 @@ class Trainer:
                 host_metrics = jax.device_get(device_metrics)
             scope_rows = None
             if new_shapes:
-                # one compile (or persistent-cache load) per new shape,
-                # in the epoch that first met it and never again
+                # once per new shape, in the epoch that first met it:
+                # the text of the program that just ran, no compile
                 with obs.phase("op_scopes"):
                     scope_rows = sorted({
                         tuple(row)
@@ -1052,6 +1064,13 @@ class Trainer:
                     occ_in / touched if touched else 1.0, 3
                 ),
             }
+            if "exchange.bytes" in snap.counters:
+                # a mesh of more than one device: what the step's pull
+                # and push moved between the chips, from shapes
+                # (TrainStep.exchange_bytes)
+                stats["_wire"]["exchange_bytes_per_step"] = round(
+                    snap.counters["exchange.bytes"] / max(steps, 1)
+                )
         if "store.hit_occ" in snap.counters or (
             "store.miss_occ" in snap.counters
         ):

@@ -21,6 +21,7 @@ multi-kilobyte machine-feature warning for every CPU entry it loads.
 from __future__ import annotations
 
 import os
+import re
 
 _CHECKOUT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -44,3 +45,21 @@ def enable_compile_cache() -> str | None:
     # the chip and would be paid again by every process
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return jax.config.jax_compilation_cache_dir
+
+
+def key_by_source() -> None:
+    """From here on this process keys its compiles WITH their source
+    metadata (``op_name`` paths, file names relative to the checkout).
+    JAX leaves metadata out of the key by default, so a program loaded
+    from the persistent cache carries the ``xf.*`` scopes of whichever
+    source first compiled it, or none.  A run that reads those scopes
+    off its running program (``TrainStep.op_scopes``; the trainer calls
+    this when its Obs is live, before its first compile) must run what
+    THIS source compiles: a hit only when this very source ran keyed
+    like this before, from any checkout.  Idempotent; never undone."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex", re.escape(_CHECKOUT)
+    )
