@@ -110,6 +110,10 @@ class ShardLoader:
         io_retries: int = 2,  # transient read/parse retries per block
         io_retry_backoff_s: float = 0.05,
         max_quarantined_frac: float = 0.05,  # quarantine budget
+        # packed.RemapDigest of ``remap``, shared by whoever holds the
+        # remap (Trainer: one hash for all its loaders); None = this
+        # loader hashes for itself, once
+        remap_digest=None,
     ):
         self.path = path
         self.batch_size = batch_size
@@ -124,6 +128,11 @@ class ShardLoader:
             )
         self.parse_fn = parse_fn
         self.remap = remap
+        if remap_digest is None:
+            from xflow_tpu.io import packed
+
+            remap_digest = packed.RemapDigest(remap)
+        self._remap_digest = remap_digest
         self.hot_size = hot_size
         self.hot_nnz = hot_nnz
         # With emit_compact, v2 packed shards (io/packed.py) yield
@@ -369,8 +378,10 @@ class ShardLoader:
         with self.obs.phase("shard_open"):
             f.seek(0)
             meta, _ = packed.read_header(f)
+            # the remap's one sha256, or the wait for the stream that
+            # is computing it, or (every later open) a lookup
             with self.obs.phase("remap_digest"):
-                digest = packed.remap_digest(self.remap)
+                digest = self._remap_digest.get(self.obs)
             packed.check_compat(
                 meta,
                 batch_size=self.batch_size,

@@ -52,6 +52,7 @@ from __future__ import annotations
 import hashlib
 import os
 import struct
+import threading
 from typing import BinaryIO, Iterator
 
 import numpy as np
@@ -59,6 +60,7 @@ import numpy as np
 from xflow_tpu.chaos import failpoint
 from xflow_tpu.io import container
 from xflow_tpu.io.batch import Batch
+from xflow_tpu.obs import NULL_OBS
 
 MAGIC = b"XFPB0001"
 
@@ -76,11 +78,41 @@ _REC_HEADER = struct.Struct("<8q")  # n_real n_cold n_dict n_dict_occ
 
 
 def remap_digest(remap: np.ndarray | None) -> str | None:
+    """sha256 of the remap as contiguous int32, the ``remap_sha256`` of
+    a shard's header.  Hashes the array's own buffer: no second copy of
+    a GiB remap (an input of another dtype or layout is converted first)."""
     if remap is None:
         return None
     return hashlib.sha256(
-        np.ascontiguousarray(remap, np.int32).tobytes()
+        memoryview(np.ascontiguousarray(remap, np.int32))
     ).hexdigest()
+
+
+class RemapDigest:
+    """``remap_digest`` of one remap, hashed at most once.  It lives
+    with whoever holds the remap (a ``Trainer``; a ``ShardLoader`` built
+    without one) and is only right while nobody writes the array.  At
+    2^28 rows a hash is seconds of host time: concurrent first callers
+    wait under the lock for the one that hashes."""
+
+    def __init__(self, remap: np.ndarray | None):
+        self._remap = remap
+        self._lock = threading.Lock()
+        self._hashed = remap is None  # no remap: the digest is None
+        self._hex: str | None = None
+
+    def get(self, obs=NULL_OBS) -> str | None:
+        """The digest; ``obs`` counts ``loader.remap_hashes`` for the
+        caller that computed it."""
+        first = False
+        with self._lock:
+            if not self._hashed:
+                self._hex = remap_digest(self._remap)
+                self._hashed = first = True
+            digest = self._hex
+        if first:
+            obs.counter("loader.remap_hashes")
+        return digest
 
 
 def is_packed_shard(path: str) -> bool:
@@ -126,8 +158,9 @@ def check_compat(
 ) -> None:
     """Raise unless the cache was built for exactly this batch config.
     ``remap_sha256`` is ``remap_digest`` of the loader's remap: the
-    caller computes it, so that it can time it (at 2^28 rows the digest
-    is the whole cost of a shard open)."""
+    caller obtains it (``RemapDigest``: hashed once per holder of the
+    remap, seconds at 2^28 rows, then a lookup), so that it can time
+    the wait."""
     want = {
         "batch_size": batch_size,
         "cold_nnz": cold_nnz,
@@ -602,12 +635,15 @@ def convert_shard(
     remap: np.ndarray | None = None,
     parse_fn=None,
     fmt: str = "auto",
+    remap_sha256: str | None = None,
 ) -> dict:
     """Pack one shard (text or CSR-binary — ShardLoader sniffs) into
     device-ready batches.  ``fmt``: "v1" = padded-array records, "v2" =
     compacted records (io/compact.py — smaller and pre-compacted for
     the dict wire), "auto" = v2 whenever the compaction invariants hold
-    (hash mode; u8 per-row counts; hot ids fit the tiered encoding)."""
+    (hash mode; u8 per-row counts; hot ids fit the tiered encoding).
+    ``remap_sha256``: ``remap_digest(remap)`` where the caller already
+    has it (one hash for many shards); None = hashed here."""
     from xflow_tpu.io.loader import ShardLoader
 
     loader = ShardLoader(
@@ -632,7 +668,9 @@ def convert_shard(
         "table_size": table_size,
         "hash_mode": bool(hash_mode),
         "hash_seed": int(hash_seed),
-        "remap_sha256": remap_digest(remap),
+        "remap_sha256": (
+            remap_digest(remap) if remap_sha256 is None else remap_sha256
+        ),
     }
     if fmt not in ("auto", "v1", "v2"):
         raise ValueError(f"unknown packed format {fmt!r}")
@@ -684,6 +722,7 @@ def main(argv=None) -> int:
     remap = freq.load_remap(a.remap) if a.remap else None
     if a.hot_size_log2 and remap is None:
         p.error("--hot-size-log2 requires --remap (trainer's remap.npy)")
+    digest = remap_digest(remap)  # once, for every shard of the loop
     for i, src in enumerate(find_shards(a.train)):
         dst = f"{a.out}-{i:05d}" if src != a.train else a.out
         meta = convert_shard(
@@ -699,6 +738,7 @@ def main(argv=None) -> int:
             block_mib=a.block_mib,
             remap=remap,
             fmt=a.format,
+            remap_sha256=digest,
         )
         print(
             f"{src} -> {dst}: {meta['examples']} examples in "

@@ -404,8 +404,12 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "first_batch_wait_s": (int, float),
         # packed shards opened this epoch (io/loader.py::_iter_packed);
         # their seconds are overlapped["shard_open"], of which
-        # overlapped["remap_digest"] hashed the hot remap
+        # overlapped["remap_digest"] obtained the hot remap's sha256:
+        # the trainer's one hash, the wait for it, or a lookup
         "shard_opens": int,
+        # sha256s of the hot remap computed this epoch, written beside
+        # shard_opens: 1 in a trainer's first packed epoch, 0 after
+        "remap_hashes": int,
     },
     # fleet-mode rows only (serve/fleet.py pools N replicas into one
     # registry; rows written before the production tier predate these

@@ -30,6 +30,7 @@ import jax
 from xflow_tpu.config import Config
 from xflow_tpu.io.batch import Batch
 from xflow_tpu.io.loader import ShardLoader, make_parse_fn, shard_path
+from xflow_tpu.io.packed import RemapDigest
 from xflow_tpu.models import make_model
 from xflow_tpu.obs import NULL_OBS
 from xflow_tpu.optim import make_optimizer
@@ -294,6 +295,8 @@ class Trainer:
         self.remap = None
         if cfg.hot_size_log2:
             self._init_remap()
+            # never written after this: its digest is taken once
+            self.remap.flags.writeable = False
         else:
             # guard the reverse of _init_remap's table_size check: a
             # checkpoint trained WITH a hot table stores rows in the
@@ -306,6 +309,9 @@ class Trainer:
                         "with a hot table; set hot_size_log2 to match "
                         "(or use a fresh checkpoint_dir)"
                     )
+        # what a packed shard's header is checked against: hashed at the
+        # first packed open, once for every loader of this trainer
+        self._remap_digest = RemapDigest(self.remap)
 
     # -- observability lifecycle -------------------------------------------
 
@@ -529,6 +535,7 @@ class Trainer:
             io_retries=cfg.io_retries,
             io_retry_backoff_s=cfg.io_retry_backoff_s,
             max_quarantined_frac=cfg.max_quarantined_frac,
+            remap_digest=self._remap_digest,
         )
 
     def _tracked_prefetch(self, loader: ShardLoader, depth, offset, workers):
@@ -1103,6 +1110,9 @@ class Trainer:
             }
         if "loader.shard_opens" in snap.counters:
             stats["shard_opens"] = int(snap.counters["loader.shard_opens"])
+            stats["remap_hashes"] = int(
+                snap.counters.get("loader.remap_hashes", 0)
+            )
         if "loader.parse_bytes" in snap.counters:
             stats["parse_mb_per_sec"] = round(
                 snap.counters["loader.parse_bytes"] / 2**20
