@@ -541,12 +541,6 @@ def main() -> None:
         hot_size_log2=12,
         hot_nnz=32,
         num_devices=1,
-        # cold_consolidate stays OFF: the dict wire ships the cold
-        # head's consolidation plan for free (no device argsort), but
-        # for LR's scalar (D=1) scatters even the free plan loses to
-        # the direct scatter-add (measured +15% step time on CPU) —
-        # consolidation pays for multi-lane tables (fm/mvm/ffm), see
-        # docs/PERF.md "Wire format and compaction"
     )
 
     # Real zipf-distributed batches off the CSR cache (production

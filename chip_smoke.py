@@ -457,7 +457,6 @@ def phase_parity(geom: dict, rehearsal: bool, out: dict) -> None:
     to summation order.  (b) two steps of one TrainStep on the device
     and on the CPU backend agree."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from xflow_tpu.config import Config
@@ -475,7 +474,7 @@ def phase_parity(geom: dict, rehearsal: bool, out: dict) -> None:
         keys[::97] = h + 5  # out-of-range: zero row, nothing scattered
         g = rng.normal(0, 1, (m, d)).astype(np.float32)
         got = np.asarray(jax.jit(
-            lambda w, k: hot_gather(w, k, impl="mxu", dtype=jnp.float32)
+            lambda w, k: hot_gather(w, k, impl="mxu")
         )(w, keys))
         ref = np.where((keys < h)[:, None], w[np.clip(keys, 0, h - 1)], 0)
         ref = ref.astype(np.float32)
@@ -485,7 +484,7 @@ def phase_parity(geom: dict, rehearsal: bool, out: dict) -> None:
             f"max abs err {np.abs(got - ref).max()}",
         )
         mxu = np.asarray(jax.jit(
-            lambda k, g: hot_scatter(k, g, h, impl="mxu", dtype=jnp.float32)
+            lambda k, g: hot_scatter(k, g, h, impl="mxu")
         )(keys, g))
         seg = np.asarray(jax.jit(
             lambda k, g: hot_scatter(k, g, h, impl="seg")

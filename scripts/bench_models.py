@@ -159,12 +159,8 @@ def run_one(name: str, args) -> None:
         over["hot_nnz"] = args.hot_nnz
     if args.cold_nnz is not None:
         over["max_nnz"] = args.cold_nnz
-    if args.hot_dtype is not None:
-        over["hot_dtype"] = args.hot_dtype
     if args.microbatch is not None:
         over["microbatch"] = args.microbatch
-    if args.cold_consolidate:
-        over["cold_consolidate"] = True
     if over:
         cfg = cfg.replace(**over)
     step, state = build(devices, cfg)
@@ -188,8 +184,6 @@ def run_one(name: str, args) -> None:
         "table_size_log2": cfg.table_size_log2,
         "hot": f"2^{cfg.hot_size_log2}x{cfg.hot_nnz}+cold{cfg.max_nnz}"
         if cfg.hot_size else "off",
-        "cold_consolidate": cfg.cold_consolidate,
-        "hot_dtype": cfg.hot_dtype,
         "backend": backend,
         "device_kind": devices[0].device_kind,
         "batch_source": source,
@@ -216,21 +210,17 @@ def main() -> None:
     ap.add_argument("--hot-nnz", type=int, default=None)
     ap.add_argument("--cold-nnz", type=int, default=None,
                     help="override max_nnz (cold capacity)")
-    ap.add_argument("--hot-dtype", default=None,
-                    choices=["float32", "bfloat16"])
     ap.add_argument("--microbatch", type=int, default=None)
-    ap.add_argument("--cold-consolidate", action="store_true",
-                    dest="cold_consolidate")
     args = ap.parse_args()
 
     if args.model is not None:
         run_one(args.model, args)
         return
 
-    if args.cold_consolidate or any(
+    if any(
         v is not None
         for v in (args.hot_log2, args.hot_nnz, args.cold_nnz,
-                  args.hot_dtype, args.microbatch)
+                  args.microbatch)
     ):
         # geometry overrides are per-model sweep knobs; applied fleet-
         # wide they'd also rewrite the *_nohot control rows (making the

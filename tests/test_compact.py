@@ -313,11 +313,9 @@ def _train_once(cfg, batch):
 
 
 @pytest.mark.parametrize("model", ["lr", "mvm"])
-@pytest.mark.parametrize("cold_consolidate", [False, True])
-def test_dict_wire_matches_plain_wire(model, cold_consolidate):
+def test_dict_wire_matches_plain_wire(model):
     """One train step + predict over the dict wire equals the plain
-    compact wire to float tolerance, with and without the shipped
-    consolidation plan (cold_consolidate arms the indexed scatter)."""
+    compact wire to float tolerance."""
     rng = np.random.default_rng(11)
     b, k = 64, 24
     nnz = rng.integers(1, k, b)
@@ -339,7 +337,6 @@ def test_dict_wire_matches_plain_wire(model, cold_consolidate):
     kw = dict(
         model=model, batch_size=b, table_size_log2=14, max_nnz=16,
         max_fields=8, num_devices=1, hot_size_log2=8, hot_nnz=8,
-        cold_consolidate=cold_consolidate,
     )
     step_off, tables_off, pctr_off = _train_once(
         Config(wire_dedup="off", **kw), batch
@@ -440,9 +437,8 @@ def _decode_step(model, table, hot_size, b, kc, kh):
 @pytest.mark.parametrize("hot", ["none", "u12", "u16"])
 def test_device_decode_equals_host_expand(hot, key_bytes, model, data):
     """The jitted decode of the cw_* planes equals CompactBatch.expand()
-    plane for plane, bit for bit, and the shipped consolidation plan
-    (cold_uidx / cold_tail_keys / cold_dict_keys) reproduces the cold
-    keys: every wire variant one device can meet."""
+    plane for plane, bit for bit: every wire variant one device can
+    meet."""
     batch, table, hot_size, dict_cap = _decode_case(data, hot, key_bytes)
     cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
     assert (cb.key_bytes, cb.hx16) == (key_bytes, hot == "u16")
@@ -471,27 +467,10 @@ def test_device_decode_equals_host_expand(hot, key_bytes, model, data):
             want.hot_slots if ship else np.zeros_like(want.hot_slots)
         ),
     } if hot_size else {}
-    assert set(got) == set(cold) | set(hot_planes) | {
-        "cold_uidx", "cold_tail_keys", "cold_dict_keys"
-    }
+    assert set(got) == set(cold) | set(hot_planes)
     for name, plane in {**cold, **hot_planes}.items():
         assert got[name].dtype == plane.dtype, name
         np.testing.assert_array_equal(got[name], plane, err_msg=name)
-    # the consolidation plan: a dictionary occurrence points at its key,
-    # a tail occurrence carries it, padding is inert (dump slot, sentinel)
-    cap_d = len(cb.cu)
-    uidx, tail = got["cold_uidx"], got["cold_tail_keys"]
-    dkeys = got["cold_dict_keys"]
-    assert dkeys.shape == (cap_d,) and (dkeys[cb.n_dict:] == table).all()
-    in_dict = uidx < cap_d
-    valid = want.mask > 0
-    assert not (in_dict & ~valid).any()
-    assert (tail[in_dict | ~valid] == table).all()
-    rebuilt = np.where(
-        in_dict, np.append(dkeys, 0)[np.minimum(uidx, cap_d)], tail
-    )
-    np.testing.assert_array_equal(rebuilt[valid], want.keys[valid])
-    assert int(in_dict.sum()) == cb.n_dict_occ
 
 
 @pytest.mark.parametrize("data", _DECODE_DATA)
@@ -593,6 +572,58 @@ def test_device_decode_has_no_padded_size_element_gather(data):
         for _, shape, minor_only in lane_selects
     ), lane_selects
     assert others(window.lane_select_tpu) == []  # what a TPU traces
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_dict_wire_train_step_scatters_each_table_once():
+    """The dense step's cold scatter has one form: per table ONE
+    scatter-add of the B * max_nnz cold slots into its [T, D] buffer,
+    and no sort or segment-sum ahead of it (merging duplicates first
+    lost on the chip: docs/PERF.md "Cold consolidation").  Traced with
+    the MXU head, whose gradient is matmuls plus one add of rows
+    [0, H); the "seg" head other backends run is a segment-sum."""
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh
+    from xflow_tpu.parallel.step import TrainStep, init_state
+
+    batch, table, hot_size, _ = _decode_case("zipf", "u12", 4)
+    b, kc = batch.batch_size, batch.max_nnz
+    cfg = Config(
+        model="fm", batch_size=b, max_nnz=kc,
+        table_size_log2=table.bit_length() - 1, num_devices=1,
+        hot_size_log2=hot_size.bit_length() - 1, hot_nnz=batch.hot_nnz,
+        wire_dedup="on", hot_impl="mxu",
+    )
+    mesh = make_mesh(1)
+    model, opt = make_model(cfg), make_optimizer(cfg)
+    step = TrainStep(model, opt, cfg, mesh)
+    assert step.dict_wire
+    state = init_state(model, opt, cfg, mesh)
+    eqns = list(_eqns(jax.make_jaxpr(step._train_impl)(
+        state, step.put_batch(batch)
+    ).jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "sort"]
+    shapes = {
+        name: t["param"].shape for name, t in state["tables"].items()
+    }
+    assert len(shapes) == 2  # w [T, 1] and v [T, D]
+    adds = [
+        tuple(v.aval.shape for v in e.invars[:2])
+        for e in eqns if e.primitive.name == "scatter-add"
+    ]
+    # nothing accumulates anywhere but in a table's gradient buffer
+    assert {operand for operand, _ in adds} == set(shapes.values()), adds
+    for shape in shapes.values():
+        cold = [i for o, i in adds if o == shape and i[0] == b * kc]
+        assert len(cold) == 1, (shape, adds)
 
 
 def test_dict_wire_eligibility_gates():

@@ -282,6 +282,26 @@ def test_config_validation():
     assert cfg.transfer_ahead_depth == 5
 
 
+@pytest.mark.parametrize("key,default,other", [
+    ("cold_consolidate", False, True),
+    ("hot_dtype", "float32", "bfloat16"),
+    ("param_dtype", "float32", "bfloat16"),
+])
+@pytest.mark.parametrize("at_default", [True, False])
+def test_retired_config_keys_in_old_manifests(key, default, other, at_default):
+    """Manifests written before PR 29 embed three fields that are gone
+    (docs/MIGRATION.md): at its old default a key asked for what every
+    run does now and is dropped; any other value asked for a path that
+    no longer exists, and is refused by name."""
+    old = json.loads(Config(table_size_log2=20).to_json())
+    old[key] = default if at_default else other
+    if at_default:
+        assert Config.from_json(json.dumps(old)) == Config(table_size_log2=20)
+    else:
+        with pytest.raises(ValueError, match=f"{key}.*MIGRATION.md"):
+            Config.from_json(json.dumps(old))
+
+
 # -- packed-v2 shard splitting ----------------------------------------------
 
 

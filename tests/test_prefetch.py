@@ -3,9 +3,11 @@ thread must die on explicit close() — including when the consumer
 abandons the iterator mid-shard — not whenever the GC notices, and
 Trainer.close() must close every prefetch it spawned."""
 
+import threading
 import time
 
 import numpy as np
+import pytest
 
 from xflow_tpu.config import Config
 from xflow_tpu.io.loader import ShardLoader, _PrefetchIter
@@ -69,6 +71,60 @@ def test_prefetch_exception_propagates(tmp_path):
     except RuntimeError:
         pass
     assert _wait_dead(it)
+
+
+class _Abandon(Exception):
+    pass
+
+
+@pytest.mark.parametrize("how", ["queue_full", "source_raised", "exhausted"])
+def test_prefetch_close_unwinds_the_source(how):
+    """However the iteration ends, close() leaves the source generator
+    unwound (its ``finally`` ran, its ``with`` blocks released what
+    they held: in iter_batches the shard file and the parse pool) even
+    while a traceback still references the iterator.  Before the
+    producer closed its source, an abort on a full queue left the
+    generator suspended inside its pool until the garbage collector
+    found it, and the pool's thread outlived Trainer.close()."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    unwound = []
+
+    def source():
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            try:
+                for i in range(8):
+                    if how == "source_raised" and i == 1:
+                        raise RuntimeError("source exploded")
+                    yield pool.submit(int, i).result()
+            finally:
+                unwound.append(True)
+
+    def consume(it):
+        # a pytest.raises traceback keeps this frame, hence ``it``
+        try:
+            if how == "exhausted":
+                assert list(it) == list(range(8))
+            else:
+                assert next(it) == 0
+                if how == "source_raised":
+                    next(it)
+            raise _Abandon()
+        finally:
+            it.close()
+
+    before = set(threading.enumerate())
+    it = _PrefetchIter(source(), depth=1)
+    expected = RuntimeError if how == "source_raised" else _Abandon
+    with pytest.raises(expected) as excinfo:
+        consume(it)
+    assert excinfo.traceback  # still holds consume's frame
+    assert not it.alive
+    assert unwound == [True]
+    assert not [
+        t.name for t in set(threading.enumerate()) - before
+        if t.name.startswith("ThreadPoolExecutor")
+    ]
 
 
 def test_prefetch_leak_surfaced_on_join_timeout(tmp_path):

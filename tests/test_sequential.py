@@ -344,38 +344,6 @@ def test_sequential_hot_inner_singleton_cold_equals_dense_inner(model):
             )
 
 
-def test_sequential_hot_inner_consolidate_matches_plain():
-    """cold_consolidate under the hot inner routes the window-end
-    scatter through consolidate_plan/apply — same result as the plain
-    scatter-add on duplicate-heavy cold traffic."""
-    rng = np.random.default_rng(37)
-    keys, slots, vals, mask, labels, weights = rand_batch(rng, B)
-    # duplicate-heavy cold keys: draw from a tiny cold range >= H
-    keys[:, 1::2] = (
-        (1 << 8) + rng.integers(0, 32, (B, K // 2))
-    ).astype(np.int32)
-    raw = (keys, slots, vals, mask, labels, weights)
-    out = {}
-    for consolidate in (False, True):
-        cfg = base_cfg(
-            "lr",
-            update_mode="sequential",
-            microbatch=M,
-            sequential_inner="hot",
-            hot_size_log2=8,
-            hot_nnz=6,
-            cold_consolidate=consolidate,
-        )
-        step, state = build("lr", cfg)
-        state, _ = step.train(
-            state, step.put_batch(make_batch(*raw, 1 << 8, 6))
-        )
-        out[consolidate] = np.asarray(
-            jax.device_get(state["tables"]["w"]["param"])
-        )
-    np.testing.assert_allclose(out[False], out[True], rtol=1e-5, atol=1e-7)
-
-
 def test_sequential_hot_inner_sharded_matches_single():
     rng = np.random.default_rng(29)
     keys, slots, vals, mask, labels, weights = rand_batch(rng, B)

@@ -392,7 +392,14 @@ def test_compare_transports_gate_two_legs(lr_served, tmp_path):
     leg and a pipelined binary leg log transport-tagged serve_bench
     rows, and check_serve_slo.py --compare-transports requires the
     binary leg to beat HTTP on achieved QPS with a p99 no worse.  A
-    file missing a leg is a usage error, not a pass."""
+    file missing a leg is a usage error, not a pass.
+
+    The gate's verdicts are decided on the two legs' rows with their
+    ``achieved_qps`` and ``e2e_p99`` WRITTEN, once with the binary leg
+    ahead and once behind: which transport wins a one-second race on
+    a CPU shared with the other test workers says nothing about the
+    wiring (the speed statement is a chip cell's, PERF.md section 7:
+    ``lr_tb.serve_xfb1``)."""
     from xflow_tpu.obs.schema import load_jsonl, validate_rows
     from xflow_tpu.serve.binary import BinaryTier
     from xflow_tpu.serve.engine import PredictEngine
@@ -420,9 +427,6 @@ def test_compare_transports_gate_two_legs(lr_served, tmp_path):
     tier = ServeTier(fleet, port=0, poll_s=0.05).start()
     btier = BinaryTier(fleet, port=0, poll_s=0.02).start()
     table = int(engine.cfg.table_size)
-    # offer more than the synchronous-per-worker HTTP client can carry
-    # so the legs separate: HTTP achieves its closed-loop ceiling,
-    # the pipelined binary leg rides the open-loop schedule
     kw = dict(
         offered_qps=1200, duration_s=1.0, concurrency=4, nnz=6,
         seed=11, drain_timeout_s=60.0, table_size=table,
@@ -444,23 +448,40 @@ def test_compare_transports_gate_two_legs(lr_served, tmp_path):
     assert http_sum["transport"] == "http"
     assert bin_sum["transport"] == "binary"
     assert bin_sum["errors"] == 0 and bin_sum["outstanding"] == 0
-    assert bin_sum["achieved_qps"] > http_sum["achieved_qps"], (
-        http_sum, bin_sum,
-    )
 
-    proc = subprocess.run(
-        [
-            sys.executable, gate, str(metrics),
-            "--compare-transports", "--max-shed-frac", "0.5",
-        ],
-        capture_output=True, text=True, timeout=120,
-    )
+    rows = [json.loads(l) for l in open(metrics) if l.strip()]
+
+    def verdict(name, written):
+        """The gate's run over the live rows with each leg's
+        serve_bench row carrying ``written[transport]``."""
+        path = tmp_path / f"{name}.jsonl"
+        path.write_text("".join(
+            json.dumps(
+                {**r, **written[r["transport"]]}
+                if r.get("kind") == "serve_bench" else r
+            ) + "\n"
+            for r in rows
+        ))
+        return subprocess.run(
+            [
+                sys.executable, gate, str(path),
+                "--compare-transports", "--max-shed-frac", "0.5",
+            ],
+            capture_output=True, text=True, timeout=120,
+        )
+
+    fast = {"achieved_qps": 1100.0, "e2e_p99": 0.004}
+    slow = {"achieved_qps": 400.0, "e2e_p99": 0.020}
+    proc = verdict("binary_ahead", {"binary": fast, "http": slow})
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "transport_qps" in proc.stdout
     assert "transport_p99" in proc.stdout
+    proc = verdict("binary_behind", {"binary": slow, "http": fast})
+    assert proc.returncode == 1, proc.stdout + proc.stderr
+    assert "FAIL transport_qps" in proc.stdout
+    assert "FAIL transport_p99" in proc.stdout
 
     # one-leg file: usage error (exit 2), never a vacuous pass
-    rows = [json.loads(l) for l in open(metrics) if l.strip()]
     solo = [
         r for r in rows
         if not (

@@ -3,7 +3,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from xflow_tpu.ops.sparse import consolidate, gather_rows, scatter_rows
 
@@ -67,53 +66,6 @@ def test_consolidate_single_unique_key():
     assert real.sum() == 1
     np.testing.assert_allclose(gsum[real][0], np.full(d, m - 4.0))
     np.testing.assert_array_equal(gsum[~real], 0.0)
-
-
-@pytest.mark.parametrize("dist", ["random", "zipf"])
-def test_host_compact_matches_device_consolidate(dist):
-    """Parity between the host compaction kernel (io/compact.py
-    dictionary + consolidate_indexed) and the device's sort-based
-    consolidate: identical per-row gradient sums into a dense table,
-    the dictionary tier collapsing its duplicates exactly like the
-    argsort plan does."""
-    rng = np.random.default_rng(9)
-    m, d = 4096, 3
-    if dist == "random":
-        keys = rng.integers(0, TABLE, m).astype(np.int32)
-    else:
-        keys = np.minimum(rng.zipf(1.3, m) - 1, TABLE - 1).astype(np.int32)
-    keys[rng.random(m) < 0.1] = TABLE  # padding sentinels
-    grads = rng.normal(size=(m, d)).astype(np.float32)
-    grads[keys == TABLE] = 0.0
-
-    # device reference: sort + segment-sum consolidation
-    ukeys, gsum = consolidate(jnp.asarray(keys), jnp.asarray(grads), TABLE)
-    dense_dev = np.zeros((TABLE, d), np.float32)
-    np.add.at(dense_dev, np.minimum(np.asarray(ukeys), TABLE - 1),
-              np.where((np.asarray(ukeys) < TABLE)[:, None],
-                       np.asarray(gsum), 0.0))
-
-    # host plan: dictionary codes -> consolidate_indexed + tail scatter
-    from xflow_tpu.io.compact import dedup_select
-    from xflow_tpu.ops.sparse import consolidate_indexed
-
-    real = keys < TABLE
-    uniq, codes = dedup_select(keys[real].astype(np.int64), dict_cap=64)
-    nd = len(uniq)
-    uidx = np.full(m, nd, np.int32)  # dump slot: padding + tail
-    covered = codes != 0xFFFFFFFF
-    uidx[np.flatnonzero(real)[covered]] = codes[covered].astype(np.int32)
-    gsum_dict = np.asarray(
-        consolidate_indexed(jnp.asarray(grads), jnp.asarray(uidx), nd)
-    )
-    dense_host = np.zeros((TABLE, d), np.float32)
-    np.add.at(dense_host, uniq.astype(np.int64), gsum_dict)
-    tail = real & ~np.isin(
-        np.arange(m), np.flatnonzero(real)[covered]
-    )
-    np.add.at(dense_host, keys[tail].astype(np.int64), grads[tail])
-
-    np.testing.assert_allclose(dense_host, dense_dev, atol=1e-4)
 
 
 def test_gather_scatter_sentinel_dropped():

@@ -86,11 +86,12 @@ def test_dict_wire_decode_compiles_for_v5e_at_flagship(
         k: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for k, (shape, dtype) in shapes.items()
     }
-    cfg = types.SimpleNamespace(max_nnz=12, hot_nnz=28, table_size=1 << 28)
+    cfg = types.SimpleNamespace(max_nnz=12, hot_nnz=28)
     compiled = jax.jit(
         lambda w: expand_dict_wire(cfg, window.lane_select_tpu, w)
     ).lower(wire).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 7
+    # one monotone_take per flag plane and per tier of each section
+    assert compiled.as_text().count("tpu_custom_call") >= 6
     assert compiled.memory_analysis().temp_size_in_bytes < 768 << 20
 
 

@@ -111,46 +111,6 @@ def test_microbatch_equals_full_batch(toy_dataset, model, kw):
         )
 
 
-@pytest.mark.parametrize(
-    "model,kw",
-    [
-        ("fm", {}),
-        ("mvm", {}),
-        ("fm", {"hot_size_log2": 8, "hot_nnz": 8}),
-        ("wide_deep", {"emb_dim": 4, "hidden_dim": 8}),
-        ("lr", {"update_mode": "sequential", "microbatch": 4}),
-    ],
-)
-def test_cold_consolidate_equals_plain(toy_dataset, model, kw, tmp_path):
-    """Config.cold_consolidate merges duplicate cold keys before the
-    scatter-add — purely an execution-strategy change, same gradients
-    (a [M] scatter of per-occurrence grads vs a [U] scatter of
-    segment-summed grads over the same keys)."""
-    kw = dict(kw)  # parametrize dicts are shared across invocations
-    mode = kw.pop("update_mode", "dense")
-    if kw.get("hot_size_log2"):
-        kw.update(freq_sample_mib=1, checkpoint_dir=str(tmp_path / "ck"))
-    t_plain = Trainer(cfg_for(toy_dataset, mode, model, **kw))
-    t_plain.train()
-    t_cons = Trainer(
-        cfg_for(toy_dataset, mode, model, cold_consolidate=True, **kw)
-    )
-    t_cons.train()
-    for name in t_plain.state["tables"]:
-        for part in t_plain.state["tables"][name]:
-            np.testing.assert_allclose(
-                np.asarray(
-                    jax.device_get(t_plain.state["tables"][name][part])
-                ),
-                np.asarray(
-                    jax.device_get(t_cons.state["tables"][name][part])
-                ),
-                rtol=1e-5,
-                atol=1e-7,
-                err_msg=f"{model}:{name}/{part}",
-            )
-
-
 @pytest.mark.parametrize("mb", [1, 4])
 def test_dense_sharded_matches_single(toy_dataset, mb):
     t1 = Trainer(cfg_for(toy_dataset, "dense", num_devices=1))

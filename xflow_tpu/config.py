@@ -14,6 +14,15 @@ import json
 from typing import Any
 
 
+# Fields old manifests may still carry, with the default each had
+# (Config.from_json).
+_RETIRED_FIELDS = {
+    "cold_consolidate": False,
+    "hot_dtype": "float32",
+    "param_dtype": "float32",
+}
+
+
 @dataclasses.dataclass
 class Config:
     # -- model selection (reference: main.cc:27-45, argv[3] '0'/'1'/'2';
@@ -361,24 +370,12 @@ class Config:
     # reshard per slice.
     microbatch: int = 1
 
-    # Consolidate duplicate cold-section keys (one shared argsort +
-    # per-table segment-sums) before the dense-mode scatter-add.  Zipf
-    # batches duplicate heavily even after hot steering (measured 53%
-    # duplicate cold occurrences at the FM flagship geometry, 90%
-    # hot-off — docs/PERF.md "Cold consolidation"), and multi-lane
-    # (D>1) scatter-add costs ~85-107 ns/slice, so collapsing
-    # duplicates removes most of those slices.  Worth it for D>1
-    # models (fm/mvm/wide_deep/ffm) at large batch; LR's scalar
-    # scatters are too cheap for the sort to pay.  dense/sequential
-    # modes only (sparse mode already consolidates).
-    cold_consolidate: bool = False
-
     # -- hot table (frequency-partitioned head; docs/PERF.md "The win") --
     # log2 of the hot-table row count H (0 = off).  CTR key distributions
     # are zipfian; the top-H keys by frequency are permuted into table
     # rows [0, H) (io/freq.py) and their gather/scatter runs as two-level
     # one-hot MXU matmuls (ops/hot.py) instead of per-slice DMA —
-    # measured ~2x (f32) to ~4x (bf16) on the hot fraction on v5e.
+    # measured ~2x on the hot fraction on v5e.
     # Requires update_mode="dense" or "sequential".
     hot_size_log2: int = 0
     # Static hot-key slots per sample (extra capacity on top of max_nnz;
@@ -389,18 +386,6 @@ class Config:
     # deterministically — identical on every host) to estimate key
     # frequencies for the remap.
     freq_sample_mib: int = 64
-    # Matmul input dtype for the hot path: "float32" = exact gather,
-    # order-only scatter difference — on a TPU because ops/hot.py asks
-    # for Precision.HIGHEST there (a default-precision f32 dot on the
-    # v5e rounds to bf16: measured PR 21, chip_smoke.py Phase 2);
-    # "bfloat16" = the fast mode, rounds table/grad values to bf16
-    # inside the hot path only.
-    hot_dtype: str = "float32"
-
-    # -- precision --
-    # Parameter/optimizer state dtype. float32 default; bf16 is not used
-    # for FTRL state (z accumulates small increments).
-    param_dtype: str = "float32"
 
     # -- host->device wire format --
     # "full": ship keys/slots/vals/mask/labels/weights as-is.
@@ -559,14 +544,6 @@ class Config:
             raise ValueError(
                 f"unknown hot_windowend {self.hot_windowend!r}"
             )
-        if self.cold_consolidate and self.update_mode not in (
-            "dense",
-            "sequential",
-        ):
-            raise ValueError(
-                "cold_consolidate requires update_mode='dense' or "
-                "'sequential' (sparse mode already consolidates)"
-            )
         if self.hot_size_log2:
             if self.update_mode not in ("dense", "sequential"):
                 raise ValueError(
@@ -578,8 +555,6 @@ class Config:
                 )
             if self.hot_nnz <= 0:
                 raise ValueError("hot_nnz must be > 0 when hot table is on")
-        if self.hot_dtype not in ("float32", "bfloat16"):
-            raise ValueError(f"unknown hot_dtype {self.hot_dtype!r}")
         if self.pred_style not in ("single", "per_block"):
             raise ValueError(f"unknown pred_style {self.pred_style!r}")
         if self.wire_mode not in ("auto", "full", "compact"):
@@ -758,6 +733,14 @@ class Config:
         # `transfer_ahead`
         if "transfer_ahead" in raw and "transfer_ahead_depth" not in raw:
             raw["transfer_ahead_depth"] = raw.pop("transfer_ahead")
+        # retired fields (docs/MIGRATION.md): a manifest that carries
+        # one at its old default asked for what every run now does
+        for key, default in _RETIRED_FIELDS.items():
+            if raw.pop(key, default) != default:
+                raise ValueError(
+                    f"config key {key!r} was retired and only its "
+                    f"default {default!r} still loads (docs/MIGRATION.md)"
+                )
         fields = {f.name for f in dataclasses.fields(cls)}
         unknown = set(raw) - fields
         if unknown:

@@ -20,9 +20,9 @@ by where its information actually lives:
   occurrences ship as u16 indices into it.  The near-unique zipf TAIL
   ships as raw u24/u32 values — measured on the zipf-cache workload the
   dictionary covers ~57% of cold occurrences with ~53k entries, so
-  dictionary-tier occurrences cost 2 bytes instead of 4 AND the device
-  scatter for them collapses to U unique rows (parallel/step.py
-  consumes the indices directly; ops/sparse.py::consolidate_indexed).
+  dictionary-tier occurrences cost 2 bytes instead of 4; on the
+  device the indices are resolved back to keys and nothing more
+  (parallel/step.py::expand_dict_wire).
   A full dictionary would LOSE bytes here: at the measured 2.9x cold
   duplication, unique keys are ~35% of occurrences and shipping them
   all costs more than the index plane saves.  Dedup where the

@@ -144,18 +144,21 @@ class ShardStreamPool:
             # measured directly (never wall minus stall — that
             # difference cancels catastrophically for fast readers)
             it = loader.iter_batches(offset, parse_workers)
-            while True:
-                t = time.perf_counter()
-                try:
-                    batch, resume = next(it)
-                except StopIteration:
-                    break
-                if transform is not None:
-                    batch = transform(batch)
-                read_s += time.perf_counter() - t
-                yield _ITEM, shard_idx, batch, resume
-                batches += 1
-                examples += batch.num_real()
+            try:
+                while True:
+                    t = time.perf_counter()
+                    try:
+                        batch, resume = next(it)
+                    except StopIteration:
+                        break
+                    if transform is not None:
+                        batch = transform(batch)
+                    read_s += time.perf_counter() - t
+                    yield _ITEM, shard_idx, batch, resume
+                    batches += 1
+                    examples += batch.num_real()
+            finally:
+                it.close()  # this stream closed mid-shard
             yield _DONE, shard_idx, {
                 "batches": batches,
                 "examples": examples,

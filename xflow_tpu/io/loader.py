@@ -559,6 +559,15 @@ class _PrefetchIter:
             self._put_or_abort(_SENTINEL)
         except BaseException as e:  # propagate to consumer
             self._put_or_abort(e)
+        finally:
+            # an abandoned generator stays suspended inside its `with`
+            # blocks (iter_batches: the open shard file, the parse
+            # pool's threads) for as long as anything references this
+            # iterator, a traceback included: unwind it here, on the
+            # thread that ran it
+            close = getattr(self._source, "close", None)
+            if close is not None:
+                close()
 
     def __iter__(self) -> "_PrefetchIter":
         return self

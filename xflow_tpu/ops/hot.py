@@ -18,19 +18,17 @@ Traffic is M*(h1 + h2*D) one-hot elements instead of M DMA descriptors
 One-hot intermediates are built in chunks under ``lax.scan`` so the
 [C, h2*D] temporaries stay within a few MiB regardless of M or D.
 
-Numerics: with ``dtype=float32`` the gather is *exact* (each one-hot row
-selects a single W element; no accumulation), and the scatter differs
-from ``.at[].add`` only in summation order — but only because every
-contraction below asks for ``Precision.HIGHEST``.  The TPU's default
+Numerics: the gather is *exact* (each one-hot row selects a single W
+element; no accumulation), and the scatter differs from ``.at[].add``
+only in summation order — but only because every contraction below
+asks for ``Precision.HIGHEST``.  The TPU's default
 precision for a float32 dot rounds both operands to bfloat16 on the way
 into the MXU: measured on a v5e (PR 21, H=4096, N(0,1) weights) the
 default-precision gather was off by up to 7.7e-3 absolute and the
 scatter by 1.7e-3 relative, while HIGHEST is bitwise equal to
 ``w_hot[keys]`` and within 2.6e-7 of the segment-sum.  A CPU dot is
 exact either way, so tests pin the argument (tests/test_hot.py)
-and ``chip_smoke.py`` Phase 2 pins the effect.  ``bfloat16`` is the
-fast, rounded mode: it trades W/g mantissa for MXU passes; the default
-is float32.
+and ``chip_smoke.py`` Phase 2 pins the effect.
 
 Sentinel behavior: any key outside [0, H) produces an all-zero onehot_hi
 row, so out-of-range/padding keys gather a zero row and scatter nothing
@@ -57,11 +55,10 @@ def hot_factors(hot_size: int) -> tuple[int, int]:
     return h1, hot_size // h1
 
 
-def _precision(dtype) -> jax.lax.Precision | None:
-    """Contraction precision for matmul inputs of ``dtype``: float32
-    must not be rounded to bfloat16 in the MXU (module docstring);
-    bfloat16 inputs are already rounded, one pass is exact for them."""
-    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+# Every contraction's precision: a default-precision float32 dot on the
+# v5e rounds its operands to bfloat16 in the MXU (module docstring,
+# PR 21).
+_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _chunk(h1: int, h2: int, d: int, m: int) -> int:
@@ -88,7 +85,6 @@ def hot_gather(
     w_hot: jax.Array,
     keys: jax.Array,
     *,
-    dtype=jnp.float32,
     impl: str = "mxu",
 ) -> jax.Array:
     """Gather rows of the hot table via two-level one-hot matmuls.
@@ -96,13 +92,11 @@ def hot_gather(
     Args:
       w_hot: [H, D] hot-table rows (H a power of two).
       keys: int32 [M]; entries outside [0, H) yield zero rows.
-      dtype: matmul input dtype (float32 exact, bfloat16 fast).
       impl: "mxu" — the one-hot matmul path (the TPU win this module
         exists for); "seg" — a plain clip-gather with zero fill.  Same
-        contract, exact in float32 either way; "seg" is the CPU-fast
-        form (one-hot matmuls are an MXU trick — measured 3.3x slower
-        than the gather on the CPU backend, docs/PERF.md "Wire format
-        and compaction") and ignores ``dtype`` (always exact).
+        contract, exact either way; "seg" is the CPU-fast form (one-hot
+        matmuls are an MXU trick — measured 3.3x slower than the gather
+        on the CPU backend, docs/PERF.md "Wire format and compaction").
         TrainStep picks per platform via Config.hot_impl.
 
     Returns: [M, D] gathered rows, float32.
@@ -117,23 +111,22 @@ def hot_gather(
     c = _chunk(h1, h2, d, m)
     m_pad = ((m + c - 1) // c) * c
     kp = _pad_to(keys, m_pad, h)  # sentinel: all-zero one-hot
-    wr = w_hot.reshape(h1, h2 * d).astype(dtype)
+    wr = w_hot.reshape(h1, h2 * d)
     ar1 = jnp.arange(h1, dtype=kp.dtype)
     ar2 = jnp.arange(h2, dtype=kp.dtype)
-    prec = _precision(dtype)
 
     def body(_, k):
         hi = k // h2
         lo = k % h2
-        oh_hi = (hi[:, None] == ar1[None, :]).astype(dtype)  # [C, h1]
+        oh_hi = (hi[:, None] == ar1[None, :]).astype(jnp.float32)  # [C, h1]
         rows = jnp.dot(
-            oh_hi, wr, precision=prec, preferred_element_type=jnp.float32
+            oh_hi, wr, precision=_PRECISION,
+            preferred_element_type=jnp.float32,
         ).reshape(c, h2, d)
         oh_lo = (lo[:, None] == ar2[None, :]).astype(jnp.float32)  # [C, h2]
-        # level 2 selects among level-1 rows, which hold float32 values
-        # in float32 mode and bfloat16-exact ones in bfloat16 mode, so
-        # the same precision is lossless for it
-        return None, jnp.einsum("chd,ch->cd", rows, oh_lo, precision=prec)
+        return None, jnp.einsum(
+            "chd,ch->cd", rows, oh_lo, precision=_PRECISION
+        )
 
     _, out = jax.lax.scan(body, None, kp.reshape(-1, c))
     return out.reshape(m_pad, d)[:m]
@@ -145,7 +138,6 @@ def hot_scatter(
     grads: jax.Array,
     hot_size: int,
     *,
-    dtype=jnp.float32,
     impl: str = "mxu",
 ) -> jax.Array:
     """Sum per-occurrence gradients into a dense [H, D] buffer via
@@ -156,7 +148,6 @@ def hot_scatter(
       keys: int32 [M]; entries outside [0, H) are dropped.
       grads: float [M, D].
       hot_size: H (power of two).
-      dtype: matmul input dtype for the [h1, M]@[M, h2*D] contraction.
       impl: "mxu" (one-hot matmuls) or "seg" (segment-sum into the
         [H, D] buffer — the CPU-fast form; same sums, summation order
         differs like the MXU path differs from ``.at[].add``).
@@ -178,18 +169,16 @@ def hot_scatter(
     gp = _pad_to(grads, m_pad, 0)
     ar1 = jnp.arange(h1, dtype=kp.dtype)
     ar2 = jnp.arange(h2, dtype=kp.dtype)
-    prec = _precision(dtype)
 
     def body(acc, xs):
         k, g = xs
         hi = k // h2
         lo = k % h2
-        oh_hi = (hi[:, None] == ar1[None, :]).astype(dtype)  # [C, h1]
+        oh_hi = (hi[:, None] == ar1[None, :]).astype(jnp.float32)  # [C, h1]
         oh_lo = (lo[:, None] == ar2[None, :]).astype(g.dtype)  # [C, h2]
         glo = (g[:, :, None] * oh_lo[:, None, :]).reshape(c, d * h2)
-        # accumulate in f32 regardless of input dtype
         acc = acc + jnp.dot(
-            oh_hi.T, glo.astype(dtype), precision=prec,
+            oh_hi.T, glo, precision=_PRECISION,
             preferred_element_type=jnp.float32,
         )
         return acc, None

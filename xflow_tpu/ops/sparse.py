@@ -67,12 +67,11 @@ def consolidate_plan(
     returns (order [M], seg [M], ukeys [M]).  Apply per table with
     ``consolidate_apply``.
 
-    Motivation (docs/PERF.md "Cold consolidation"): zipf batches carry
-    heavy duplication even after hot steering — measured 53% duplicate
-    cold occurrences at the FM flagship geometry, 90% hot-off — and
-    multi-lane (D>1) scatter-add costs ~85-107 ns/slice, so collapsing
-    duplicates ahead of the scatter removes over half its slices at the
-    price of one shared argsort."""
+    For the touched-rows updates (TrainStep._sparse_update, the
+    sequential-hot sparse window end, store/hot.py), which must apply
+    the optimizer once per unique row.  Ahead of the dense step's
+    scatter-add it loses: the chip pays a scatter per slot, live or
+    dropped (docs/PERF.md "Cold consolidation")."""
     m = keys.shape[0]
     order = jnp.argsort(keys)
     sk = jnp.take(keys, order)
@@ -97,25 +96,6 @@ def consolidate_apply(
     their segment head)."""
     sg = jnp.take(grads, order, axis=0)
     return jax.ops.segment_sum(sg, seg, num_segments=order.shape[0])
-
-
-def consolidate_indexed(
-    grads: jax.Array, uidx: jax.Array, num_slots: int
-) -> jax.Array:
-    """Consolidation with the plan computed on the HOST: sum [M, D]
-    per-occurrence gradients into ``num_slots`` unique-key slots via a
-    precomputed u32 index (io/compact.py's dictionary codes, shipped
-    on the wire).  Entries carrying ``uidx == num_slots`` (padding /
-    tail-tier occurrences) are dropped.
-
-    This is ``consolidate_plan`` + ``consolidate_apply`` minus the
-    device argsort — the dedup moved to the host, where it is free
-    relative to the link (docs/PERF.md "Wire format and compaction").
-    Slot i pairs with the wire's dictionary key i.
-    """
-    return jax.ops.segment_sum(
-        grads, uidx, num_segments=num_slots + 1
-    )[:num_slots]
 
 
 def gather_rows(table: jax.Array, ukeys: jax.Array) -> jax.Array:

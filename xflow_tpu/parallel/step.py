@@ -32,7 +32,6 @@ from xflow_tpu.models.base import BatchArrays, Model
 from xflow_tpu.obs import NULL_OBS
 from xflow_tpu.ops.sparse import (
     consolidate_apply,
-    consolidate_indexed,
     consolidate_plan,
     gather_rows,
     scatter_rows,
@@ -323,11 +322,14 @@ def _interleaved_slices(batch: BatchArrays, s: int) -> BatchArrays:
 def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
     """Inverse of CompactBatch.wire (io/compact.py), inside the
     jitted step: rebuild the padded [B, K] planes from the flat
-    tiered streams, and keep the host-computed dictionary indices
-    as ``cold_uidx``/``cold_dict_keys``/``cold_tail_keys`` so
-    _scatter_grads can consolidate WITHOUT a device argsort.
+    tiered streams.  The padded planes are all it returns.  The wire
+    also carries the host's dictionary (``cw_cu``, of ``cw_cun`` real
+    entries) and each dictionary occurrence's index into it
+    (``cw_ci``): a consolidation plan for the cold scatter that costs
+    no device sort, should a probe on the chip ever say that merging
+    duplicates pays (docs/PERF.md "Cold consolidation": it did not).
 
-    ``cfg`` gives max_nnz, hot_nnz and table_size; ``lane_select`` is
+    ``cfg`` gives max_nnz and hot_nnz; ``lane_select`` is
     the in-window shuffle of ops/window.py that the caller's platform
     runs (TrainStep picks it from its mesh).
 
@@ -353,11 +355,9 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
 
     Every plane capacity is static (plane_cap bucketing), so one
     steady batch geometry is one compiled program; the per-batch
-    real counts arrive as the cc/hc count planes and the cw_cun
-    scalar."""
+    real counts arrive as the cc/hc count planes."""
     kc = cfg.max_nnz
     b = w["cw_cc"].shape[0]
-    t_sent = jnp.int32(cfg.table_size)
     take = functools.partial(monotone_take, lane_select=lane_select)
 
     def bits(plane: jax.Array, n: int) -> jax.Array:
@@ -402,17 +402,11 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
         w["cw_cc"], w["cw_cf"], kc
     )
     cu = keys_plane(w["cw_cu"])
-    cap_d = cu.shape[0]
     ci = w["cw_ci"].astype(jnp.int32)
-    if cap_d:
+    if cu.shape[0]:
         dict_key_flat = jnp.take(cu, ci, mode="clip")
-        dict_keys_eff = jnp.where(
-            jnp.arange(cap_d) < w["cw_cun"][0], cu, t_sent
-        )
     else:
         dict_key_flat = jnp.zeros_like(ci)
-        dict_keys_eff = cu
-    di = take(di_idx, ci)
     dict_key = take(di_idx, dict_key_flat)
     tail = take(tail_idx, keys_plane(w["cw_ct"]))
     cmask = cvalid.astype(jnp.float32).reshape(b, kc)
@@ -430,15 +424,6 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
         "mask": cmask,
         "labels": bits(w["cw_lb"], b).astype(jnp.float32),
         "weights": bits(w["cw_wb"], b).astype(jnp.float32),
-        # the host-computed consolidation plan (Config.wire_dedup):
-        # occurrence -> dictionary slot (cap_d = dump for padding
-        # and tail), tail occurrences sentinel-coded for a direct
-        # drop-mode scatter, dictionary slot -> table row
-        "cold_uidx": jnp.where(is_dict, di, cap_d).reshape(b, kc),
-        "cold_tail_keys": jnp.where(is_tail, tail, t_sent).reshape(
-            b, kc
-        ),
-        "cold_dict_keys": dict_keys_eff,
     }
     if "cw_hc" in w:
         kh = cfg.hot_nnz
@@ -484,9 +469,6 @@ class TrainStep:
         # (parallel/exchange.py).  Observed, like the platform below;
         # no Config field chooses it.
         self._sharded = mesh.devices.size > 1
-        self._hot_dtype = (
-            jnp.bfloat16 if cfg.hot_dtype == "bfloat16" else jnp.float32
-        )
         # Compact wire eligibility (Config.wire_mode): requires binary
         # vals (hash mode).  Slot-reading models additionally need
         # max_fields <= 255 so the u8 slots plane's clamp stays inside
@@ -1014,7 +996,6 @@ class TrainStep:
                         hot = hot_gather(
                             head,
                             hot_keys.reshape(-1),
-                            dtype=self._hot_dtype,
                             impl=self._hot_impl,
                         ).reshape(b, kh, block.shape[-1])
                 else:
@@ -1073,7 +1054,6 @@ class TrainStep:
                 hot = hot_gather(
                     t["param"][:h],
                     batch["hot_keys"].reshape(-1),
-                    dtype=self._hot_dtype,
                     impl=self._hot_impl,
                 ).reshape(b, kh, d)
             else:
@@ -1172,17 +1152,12 @@ class TrainStep:
 
     @jax.named_scope("xf.scatter")
     def _cold_accumulate(
-        self, gbuf: jax.Array, keys_eff: jax.Array, occ: jax.Array, plan
+        self, gbuf: jax.Array, keys_eff: jax.Array, occ: jax.Array
     ) -> jax.Array:
         """Accumulate per-occurrence cold grads [M, D] into a [T, D]
         buffer under the ONE sentinel/drop convention (pad keys carry
-        index T, dropped by mode='drop'), via the consolidate plan
-        when one is supplied.  Shared by _scatter_grads and the hot
-        inner's window-end pass so the two cannot drift."""
-        if plan is not None:
-            order, seg, ukeys = plan
-            gsum = consolidate_apply(occ, order, seg)
-            return gbuf.at[ukeys].add(gsum, mode="drop")
+        index T, dropped by mode='drop').  Shared by _scatter_grads
+        and the hot inner's window-end pass so the two cannot drift."""
         return gbuf.at[keys_eff].add(occ, mode="drop")
 
     def _scatter_grads(
@@ -1191,17 +1166,13 @@ class TrainStep:
         batch: BatchArrays,
         occ_grads: dict,
         gbufs: dict,
-        dict_plan: dict | None = None,
     ) -> dict:
         """Per-occurrence grads summed into the dense [T, D] buffers
         (one per table): a local scatter-add on one device, the
-        exchange's push on a mesh (which never rides the dict wire, so
-        there is no ``dict_plan`` to honor there)."""
+        exchange's push on a mesh."""
         if self._sharded:
             return self._push_grads(batch, occ_grads, gbufs)
-        return self._scatter_local_grads(
-            tables, batch, occ_grads, gbufs, dict_plan=dict_plan
-        )
+        return self._scatter_local_grads(tables, batch, occ_grads, gbufs)
 
     def _push_grads(
         self, batch: BatchArrays, occ_grads: dict, gbufs: dict
@@ -1231,11 +1202,6 @@ class TrainStep:
             idx = exchange.block_index(
                 exchange.all_batch(planes["cold"]), block_rows
             )
-            plan = (
-                consolidate_plan(idx, block_rows)
-                if cfg.cold_consolidate
-                else None
-            )
             if "hot_dma" in planes:
                 hot_idx = exchange.block_index(
                     exchange.all_batch(planes["hot_dma"]), block_rows
@@ -1250,12 +1216,12 @@ class TrainStep:
                         occ = occ[:, kh:]
                     occ = occ.reshape(-1, d)
                 gbuf = self._cold_accumulate(
-                    gbuf, idx, exchange.all_batch(occ), plan
+                    gbuf, idx, exchange.all_batch(occ)
                 )
                 if kh and self._mxu_hot[name]:
                     ghot = exchange.sum_head(hot_scatter(
                         planes["hot"], hot_g, cfg.hot_size,
-                        dtype=self._hot_dtype, impl=self._hot_impl,
+                        impl=self._hot_impl,
                     ))
                     part = exchange.head_part(ghot, block_rows)
                     with jax.named_scope("xf.scatter"):
@@ -1276,39 +1242,13 @@ class TrainStep:
         batch: BatchArrays,
         occ_grads: dict,
         gbufs: dict,
-        dict_plan: dict | None = None,
     ) -> dict:
         """Accumulate per-occurrence grads into dense [T, D] buffers
         (one per table): scatter-add for the cold section, two-level
-        one-hot MXU matmuls for the hot section (ops/hot.py).
-
-        With ``dict_plan`` (the dict wire's host-computed dictionary,
-        Config.wire_dedup + cold_consolidate) the duplicated cold HEAD
-        consolidates by segment-sum over the shipped u16 indices — U
-        unique big-table slices instead of one per occurrence, and no
-        device argsort — while the near-unique tail keeps the direct
-        drop-mode scatter (consolidating it would cost more than it
-        collapses; io/compact.py)."""
+        one-hot MXU matmuls for the hot section (ops/hot.py)."""
         cfg = self.cfg
         kh = batch["hot_keys"].shape[1] if "hot_keys" in batch else 0
-        use_dict = (
-            dict_plan is not None
-            and "cold_uidx" in dict_plan
-            and cfg.cold_consolidate
-        )
-        plan = None
-        if use_dict:
-            uidx = dict_plan["cold_uidx"].reshape(-1)
-            tail_eff = dict_plan["cold_tail_keys"].reshape(-1)
-            dict_keys_eff = dict_plan["cold_dict_keys"]
-            cap_d = dict_keys_eff.shape[0]
-            keys_eff = None
-        else:
-            keys_eff = self._cold_keys_eff(batch)
-            if cfg.cold_consolidate:
-                # one shared argsort over the cold keys; every table's
-                # gradients ride the same permutation/segments
-                plan = consolidate_plan(keys_eff, cfg.table_size)
+        keys_eff = self._cold_keys_eff(batch)
         if kh:
             from xflow_tpu.ops.hot import hot_scatter
 
@@ -1322,22 +1262,14 @@ class TrainStep:
                 # buffer; cold grads keep the DMA scatter path.
                 hot_g = occ[:, :kh].reshape(-1, d)
                 occ = occ[:, kh:]
-            if use_dict:
-                occ_flat = occ.reshape(-1, d)
-                gsum = consolidate_indexed(occ_flat, uidx, cap_d)
-                gbuf = gbufs[name].at[dict_keys_eff].add(
-                    gsum, mode="drop"
-                )
-                gbuf = gbuf.at[tail_eff].add(occ_flat, mode="drop")
-            else:
-                gbuf = self._cold_accumulate(
-                    gbufs[name], keys_eff, occ.reshape(-1, d), plan
-                )
+            gbuf = self._cold_accumulate(
+                gbufs[name], keys_eff, occ.reshape(-1, d)
+            )
             if kh:
                 if self._mxu_hot[name]:
                     ghot = hot_scatter(
                         hot_keys_eff, hot_g, cfg.hot_size,
-                        dtype=self._hot_dtype, impl=self._hot_impl,
+                        impl=self._hot_impl,
                     )
                     gbuf = gbuf.at[: cfg.hot_size].add(ghot)
                 else:
@@ -1352,15 +1284,6 @@ class TrainStep:
     ) -> tuple[State, dict[str, jax.Array]]:
         cfg = self.cfg
         batch = self._expand_wire(batch)
-        # The dict wire's host consolidation plan has no batch leading
-        # axis, so it cannot ride _interleaved_slices; only the plain
-        # dense whole-batch scatter consumes it (via _scatter_grads) —
-        # every other path trains on the reconstructed key planes.
-        dict_plan = {
-            k: batch.pop(k)
-            for k in ("cold_uidx", "cold_tail_keys", "cold_dict_keys")
-            if k in batch
-        }
         if cfg.update_mode == "sequential" and cfg.microbatch > 1:
             return self._train_sequential(state, batch)
 
@@ -1403,9 +1326,7 @@ class TrainStep:
             pctr, occ_grads, grad_dense = self._forward_grads(
                 tables, dense, batch, num_real
             )
-            gbufs = self._scatter_grads(
-                tables, batch, occ_grads, gbufs, dict_plan=dict_plan
-            )
+            gbufs = self._scatter_grads(tables, batch, occ_grads, gbufs)
             ll, cnt = self._batch_logloss(batch, pctr)
         else:
             # Gradient accumulation (Config.microbatch): scan over batch
@@ -1580,7 +1501,7 @@ class TrainStep:
             if kh:
                 ghot = hot_scatter(
                     hot_keys_eff, hot_g, hsize,
-                    dtype=self._hot_dtype, impl=self._hot_impl,
+                    impl=self._hot_impl,
                 )
                 # non-hot slots carry index H -> dropped; no mask needed
                 ghot = ghot.at[ukeys_hotpart].add(gsum, mode="drop")
@@ -1747,7 +1668,6 @@ class TrainStep:
                 hot = hot_gather(
                     head["param"],
                     bslice["hot_keys"].reshape(-1),
-                    dtype=self._hot_dtype,
                     impl=self._hot_impl,
                 ).reshape(b, kh, d)
                 rows[name] = jnp.concatenate(
@@ -1766,7 +1686,7 @@ class TrainStep:
                 cold_occ[name] = g[:, kh:]
                 ghot = hot_scatter(
                     hot_keys_eff, hot_g, h,
-                    dtype=self._hot_dtype, impl=self._hot_impl,
+                    impl=self._hot_impl,
                 )
                 new_heads[name] = self._optimizer_pass(head, ghot)
             new_dense = self._apply_dense_sgd(dense_c, gd)
@@ -1793,11 +1713,8 @@ class TrainStep:
         # way, spill grads (cold-plane keys < H) land on the
         # written-back head rows here, exactly once.
         keys_eff = self._cold_keys_eff(batch)
-        plan = (
-            consolidate_plan(keys_eff, cfg.table_size)
-            if self._windowend == "sparse" or cfg.cold_consolidate
-            else None
-        )
+        if self._windowend == "sparse":
+            order, seg, ukeys = consolidate_plan(keys_eff, cfg.table_size)
         new_tables = {}
         for name, table in tables.items():
             d = table["param"].shape[-1]
@@ -1818,7 +1735,6 @@ class TrainStep:
                 # sentinel slots gather-clip and scatter-drop
                 # (ops/sparse.py module docstring;
                 # tests/test_sequential.py equivalence)
-                order, seg, ukeys = plan
                 gsum = consolidate_apply(occ, order, seg)
                 new_tables[name] = self._apply_touched_rows(
                     merged, ukeys, gsum
@@ -1831,7 +1747,6 @@ class TrainStep:
                 jnp.zeros_like(table["param"]),
                 keys_eff,
                 occ,
-                plan,
             )
             new_tables[name] = self._optimizer_pass(merged, gbuf)
         ll = nll_sum / jnp.maximum(cnt, 1.0)
@@ -1862,8 +1777,6 @@ class TrainStep:
     def _predict_impl(self, state: State, batch: BatchArrays) -> jax.Array:
         """pctr per example (reference calculate_pctr, lr_worker.cc:46-61)."""
         batch = self._expand_wire(batch)
-        for k in ("cold_uidx", "cold_tail_keys", "cold_dict_keys"):
-            batch.pop(k, None)  # predict has no scatter to plan for
         rows = self._gather_model_rows(state["tables"], batch)
         return sigmoid_ref(
             self._logit(rows, self._model_view(batch), state["dense"])

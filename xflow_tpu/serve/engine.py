@@ -550,8 +550,6 @@ class PredictEngine:
         bucket like predict).  ``index`` [N, D] rides as an argument,
         so a rollout's new index needs zero recompiles."""
         batch = self.step._expand_wire(arrays)
-        for k in ("cold_uidx", "cold_tail_keys", "cold_dict_keys"):
-            batch.pop(k, None)  # no scatter to plan for
         rows = self.step._gather_model_rows(state["tables"], batch)
         u = self.model.user_embed(
             rows, self.step._model_view(batch), state["dense"]
@@ -563,8 +561,6 @@ class PredictEngine:
     def _item_embed_impl(self, state, arrays):
         """Item-tower pass [B, D] — export_item_index's batch leg."""
         batch = self.step._expand_wire(arrays)
-        for k in ("cold_uidx", "cold_tail_keys", "cold_dict_keys"):
-            batch.pop(k, None)
         rows = self.step._gather_model_rows(state["tables"], batch)
         return self.model.item_embed(
             rows, self.step._model_view(batch), state["dense"]

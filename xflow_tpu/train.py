@@ -105,9 +105,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="MiB of training data sampled to build the hot-key remap",
     )
     p.add_argument(
-        "--hot-dtype", choices=["float32", "bfloat16"], dest="hot_dtype"
-    )
-    p.add_argument(
         "--sequential-inner", dest="sequential_inner",
         choices=["dense", "sparse", "hot"],
         help="per-slice update strategy under --update-mode sequential: "
@@ -124,13 +121,6 @@ def build_parser() -> argparse.ArgumentParser:
         "sparse = consolidated touched-rows update, table-size "
         "independent (the 2^28 form; analysis rule XF010/XF014); "
         "auto = sparse from --table-size-log2 24 up",
-    )
-    p.add_argument(
-        "--cold-consolidate", action="store_true", default=None,
-        dest="cold_consolidate",
-        help="merge duplicate cold keys (shared argsort + segment-sum) "
-        "before the dense-mode scatter-add — pays off for D>1 models "
-        "on zipf batches (docs/PERF.md)",
     )
     p.add_argument(
         "--store-mode", choices=["dense", "tiered"], dest="store_mode",
