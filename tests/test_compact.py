@@ -358,7 +358,7 @@ def test_dict_wire_matches_plain_wire(model):
 
 # -- the device decode against the host's inverse -----------------------------
 
-_DECODE_DATA = ("all_padding", "full_rows", "odd_caps", "zipf")
+_DECODE_DATA = ("all_padding", "full_rows", "odd_caps", "zipf", "tail_only")
 
 
 def _decode_case(data, hot, key_bytes):
@@ -392,6 +392,8 @@ def _decode_case(data, hot, key_bytes):
         cold = hot_size + np.minimum(
             rng.zipf(1.3, (b, kc)), table - hot_size - 1
         )
+    elif data == "tail_only":  # no key repeats, more keys than dict_cap
+        cold = hot_size + 1 + 3 * rng.permutation(b * kc).reshape(b, kc)
     else:
         cold = rng.integers(hot_size, table, (b, kc))
     # both hot tiers: ids < 256 and above
@@ -413,7 +415,8 @@ def _decode_case(data, hot, key_bytes):
         hm, rng.integers(0, 200, (b, kh)), 0
     ).astype(np.int32)
     batch.hot_vals, batch.hot_mask = hm.astype(f32), hm.astype(f32)
-    return batch, table, hot_size, (24 if data == "zipf" else DICT_CAP)
+    dict_cap = {"zipf": 24, "tail_only": 4}.get(data, DICT_CAP)
+    return batch, table, hot_size, dict_cap
 
 
 def _decode_step(model, table, hot_size, b, kc, kh):
@@ -444,6 +447,8 @@ def test_device_decode_equals_host_expand(hot, key_bytes, model, data):
     assert (cb.key_bytes, cb.hx16) == (key_bytes, hot == "u16")
     if data == "zipf":
         assert 0 < cb.n_dict_occ < cb.n_cold  # both cold tiers in play
+    if data == "tail_only":
+        assert cb.n_dict == 0 == len(cb.cu) and cb.n_cold  # the tail alone
     if data == "odd_caps":
         assert len(cb.cs) % 128 and (not hot_size or len(cb.hs) % 128)
     b, kc, kh = batch.batch_size, batch.max_nnz, batch.hot_nnz
@@ -453,6 +458,10 @@ def test_device_decode_equals_host_expand(hot, key_bytes, model, data):
     wire = cb.wire(step._ship_slots)
     assert ("cw_cs" in wire) == ship
     got = jax.device_get(jax.jit(step._expand_wire)(wire))
+    plan = got.pop("cold_plan")  # its reader: the cold-row tests below
+    assert {k: len(plan[k]) for k in ("cu", "ci", "ct")} == {
+        "cu": len(cb.cu), "ci": len(cb.ci), "ct": len(cb.ct)
+    }
     want = cb.expand()
     batches_equal(want, batch)  # the host's inverse is exact
     cold = {
@@ -496,22 +505,205 @@ def test_device_decode_tpu_form_interpreted(hot, data):
         got = jax.device_get(jax.jit(
             lambda w: expand_dict_wire(step.cfg, window.lane_select_tpu, w)
         )(wire))
+    got, want = (
+        {**{k: v for k, v in planes.items() if k != "cold_plan"},
+         **{f"plan.{k}": v for k, v in planes["cold_plan"].items()}}
+        for planes in (got, want)
+    )
     assert set(got) == set(want)
     for name, plane in want.items():
         assert got[name].dtype == plane.dtype, name
         np.testing.assert_array_equal(got[name], plane, err_msg=name)
 
 
-def _element_gathers(jaxpr, found):
-    """Every XLA ``gather`` whose slices are single elements, through
+# -- the cold rows through the batch's dictionary ------------------------------
+
+
+def _cold_rows_case(data, hot, key_bytes, d, lane_select):
+    """(rows the dictionary route returns [B, K, D], param[keys], mask)
+    for one decode case and a random [T, D] table."""
+    from xflow_tpu.parallel.step import dict_cold_rows, expand_dict_wire
+
+    batch, table, hot_size, dict_cap = _decode_case(data, hot, key_bytes)
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
+    step = _decode_step(
+        "lr", table, hot_size, batch.batch_size, batch.max_nnz,
+        batch.hot_nnz,
+    )
+    rng = np.random.default_rng(d)
+    param = rng.standard_normal((table, d)).astype(np.float32)
+    param[0] = 7.0  # what a padding slot of param[keys] reads
+
+    def route(w, p):
+        plan = expand_dict_wire(step.cfg, lane_select, w)["cold_plan"]
+        return dict_cold_rows(plan, {"t": p}, lane_select)["t"]
+
+    got = np.asarray(jax.jit(route)(cb.wire(False), param))
+    return (
+        got.reshape(batch.keys.shape + (d,)), param[batch.keys],
+        batch.mask > 0,
+    )
+
+
+# u32 keys need a table above 2^24 rows: at D = 1 only (256 MiB a table)
+@pytest.mark.parametrize("key_bytes,d", [(3, 1), (4, 1), (3, 10)])
+@pytest.mark.parametrize("data", _DECODE_DATA)
+@pytest.mark.parametrize("hot", ["none", "u12"])
+def test_dict_cold_rows_equal_param_at_keys(hot, data, key_bytes, d):
+    """The cold rows fetched through the dictionary (the table read per
+    dictionary and tail entry, the occurrences resolved out of those)
+    equal ``param[keys]`` bit for bit on every unmasked slot, and are 0
+    on padding: no dictionary beside a tail, no tail, nothing at all,
+    rows at max_nnz, capacities that are no multiple of 128."""
+    from xflow_tpu.ops import window
+
+    got, want, real = _cold_rows_case(
+        data, hot, key_bytes, d, window.lane_select_xla
+    )
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(
+        got[real].view(np.uint32), want[real].view(np.uint32)
+    )
+    assert not got[~real].view(np.uint32).any()
+
+
+@pytest.mark.parametrize("d", [1, 10])
+@pytest.mark.parametrize("data", _DECODE_DATA)
+def test_dict_cold_rows_tpu_form_interpreted(data, d):
+    """The same through the float32 rows' Mosaic lane shuffle, as a TPU
+    traces the route (run here by the Pallas TPU interpreter)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from xflow_tpu.ops import window
+
+    with pltpu.force_tpu_interpret_mode():
+        got, want, real = _cold_rows_case(
+            data, "u12", 3, d, window.lane_select_tpu
+        )
+    np.testing.assert_array_equal(
+        got[real].view(np.uint32), want[real].view(np.uint32)
+    )
+    assert not got[~real].view(np.uint32).any()
+
+
+def _dict_and_expanded_states(model, steps, hot, **mode):
+    """Train state after ``steps`` train steps on one zipf batch that
+    rides the dictionary wire (cold rows through the dictionary), and
+    after the same steps on its ``CompactBatch.expand()`` over the plain
+    compact wire (a row per padded slot: the parent's form)."""
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh
+    from xflow_tpu.parallel.step import TrainStep, init_state
+
+    batch, table, hot_size, dict_cap = _decode_case("zipf", hot, 3)
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
+    out = []
+    for dedup, fed in (("on", cb), ("off", cb.expand())):
+        cfg = Config(
+            model=model, batch_size=batch.batch_size, max_nnz=batch.max_nnz,
+            table_size_log2=table.bit_length() - 1, num_devices=1,
+            hot_size_log2=hot_size.bit_length() - 1 if hot_size else 0,
+            hot_nnz=batch.hot_nnz, wire_dedup=dedup, **mode,
+        )
+        mesh = make_mesh(1)
+        m, opt = make_model(cfg), make_optimizer(cfg)
+        step = TrainStep(m, opt, cfg, mesh)
+        assert step.dict_wire == (dedup == "on")
+        state = init_state(m, opt, cfg, mesh)
+        for _ in range(steps):
+            state, metrics = step.train(state, step.put_batch(fed))
+        pctr = step.predict(state, step.put_batch(fed))
+        out.append(jax.device_get((state, metrics, pctr)))
+    return out
+
+
+@pytest.mark.parametrize("model", ["lr", "fm"])  # D = 1; w beside v at D = 8
+@pytest.mark.parametrize("mode", ["dense", "sparse", "sequential_hot"])
+def test_dict_route_leaves_the_state_of_the_expanded_batch(mode, model):
+    """Three dense steps, one update_mode='sparse' step and one window
+    of the hot sequential inner (its window-start gather) on a
+    dictionary-wire batch leave every table, the metrics and the
+    trainer's eval bit-equal to the same steps on the expanded batch:
+    the rows are the same float32 values by another route."""
+    steps, hot, kw = {
+        "dense": (3, "u12", {}),
+        # the touched-rows update runs without a hot table
+        "sparse": (1, "none", {"update_mode": "sparse"}),
+        "sequential_hot": (1, "u12", {
+            "update_mode": "sequential", "sequential_inner": "hot",
+            "microbatch": 4,
+        }),
+    }[mode]
+    got, want = _dict_and_expanded_states(model, steps, hot, **kw)
+    jax.tree.map(
+        lambda a, c: np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint32), np.asarray(c).view(np.uint32)
+        ),
+        got, want,
+    )
+
+
+def _table_gathers(step, state, fed):
+    """Index count of every gather out of a [T, D] table in the traced
+    train step."""
+    shapes = {t["param"].shape for t in state["tables"].values()}
+    return sorted(
+        int(np.prod(e.invars[1].aval.shape[:-1]))
+        for e in _eqns(jax.make_jaxpr(step._train_impl)(
+            state, step.put_batch(fed)
+        ).jaxpr)
+        if e.primitive.name == "gather" and e.invars[0].aval.shape in shapes
+    )
+
+
+@pytest.mark.parametrize("model", ["lr", "fm"])
+def test_dict_wire_step_reads_the_table_per_dictionary_and_tail_entry(model):
+    """The mechanism of PR 30, pinned on the traced step: on a
+    dictionary-wire batch every gather out of a [T, D] table has the
+    dictionary's or the tail's index count (cap(cu) + cap(ct) a table,
+    under B * max_nnz by the wire's construction), never a padded
+    plane's; the same rows over the plain compact wire still gather a
+    row per padded slot (the parent's form, and every other batch's)."""
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh
+    from xflow_tpu.parallel.step import TrainStep, init_state
+
+    batch, table, hot_size, dict_cap = _decode_case("zipf", "u12", 3)
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
+    b, kc = batch.batch_size, batch.max_nnz
+    assert 0 < len(cb.cu) + len(cb.ct) < b * kc
+    counts = {}
+    for dedup, fed in (("on", cb), ("off", cb.expand())):
+        cfg = Config(
+            model=model, batch_size=b, max_nnz=kc,
+            table_size_log2=table.bit_length() - 1, num_devices=1,
+            hot_size_log2=hot_size.bit_length() - 1, hot_nnz=batch.hot_nnz,
+            wire_dedup=dedup, hot_impl="mxu",
+        )
+        mesh = make_mesh(1)
+        m, opt = make_model(cfg), make_optimizer(cfg)
+        step = TrainStep(m, opt, cfg, mesh)
+        counts[dedup] = _table_gathers(
+            step, init_state(m, opt, cfg, mesh), fed
+        )
+    tables = 2 if model == "fm" else 1
+    assert counts["on"] == sorted([len(cb.cu), len(cb.ct)] * tables)
+    assert counts["off"] == [b * kc] * tables
+
+
+def _narrow_gathers(jaxpr, found):
+    """Every XLA ``gather`` whose slices are single elements, or rows
+    of a source two columns wide (ops/window.py::wide_take), through
     all nested jaxprs but a Mosaic kernel's (there the gather is a lane
     shuffle inside one vreg, not a DMA): (index count, operand shape,
     whether only the minor axis is indexed)."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             continue
-        if eqn.primitive.name == "gather" and all(
-            s == 1 for s in eqn.params["slice_sizes"]
+        if eqn.primitive.name == "gather" and (
+            int(np.prod(eqn.params["slice_sizes"])) <= 2
         ):
             operand, indices = (v.aval for v in eqn.invars[:2])
             dn = eqn.params["dimension_numbers"]
@@ -520,7 +712,7 @@ def _element_gathers(jaxpr, found):
                 tuple(dn.start_index_map) == (operand.ndim - 1,),
             ))
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _element_gathers(sub, found)
+            _narrow_gathers(sub, found)
     return found
 
 
@@ -529,14 +721,17 @@ def test_device_decode_has_no_padded_size_element_gather(data):
     """The mechanism of PR 25, pinned: no padded [B, K] plane is rebuilt
     by a gather of one-element slices (a gather costs the TPU one DMA
     descriptor per slice: 335 ms of a 407 ms step when the decode
-    gathered elements).  ONE element gather is allowed, by name: the
-    dictionary resolve ``cu[ci]`` (operand: the dictionary plane), one
-    index per entry of the ``cw_ci`` occurrence plane.  That plane is
-    smaller than B*K only by the data (plane_cap caps it AT B*K, which
-    the odd_caps case reaches: every slot a dictionary hit), so the
-    resolve is pinned as the exception and not by a size.  Beside it
-    the TPU form has no element gather at all; the form other backends
-    run gathers elements only inside a 256-wide window row
+    gathered elements).  ONE gather with a free index is allowed, by
+    name: the dictionary resolve ``cu[ci]``, one index per entry of the
+    ``cw_ci`` occurrence plane, and since PR 30 it reads two-word rows
+    (operand: the dictionary plane beside a column of zeros;
+    ops/window.py::wide_take), which cost the chip 2.5 ns an index
+    where single elements cost 8.6.  That plane is smaller than B*K
+    only by the data (plane_cap caps it AT B*K, which the odd_caps case
+    reaches: every slot a dictionary hit), so the resolve is pinned as
+    the exception and not by a size.  Beside it the TPU form has no
+    element gather at all; the form other backends run gathers elements
+    only inside a 256-wide window row
     (ops/window.py::lane_select_xla), never from a stream."""
     from xflow_tpu.ops import window
     from xflow_tpu.parallel.step import expand_dict_wire
@@ -556,14 +751,15 @@ def test_device_decode_has_no_padded_size_element_gather(data):
     def others(lane_select):
         """The decode's element gathers but the resolve, which must be
         there exactly once."""
-        found = _element_gathers(
+        found = _narrow_gathers(
             jax.make_jaxpr(
                 lambda w: expand_dict_wire(step.cfg, lane_select, w)
             )(wire).jaxpr, []
         )
-        resolve = [g for g in found if g[1] == (cap_d,)]
-        assert resolve == [(len(cb.ci), (cap_d,), True)], found
-        return [g for g in found if g[1] != (cap_d,)]
+        assert not [g for g in found if g[1] == (cap_d,)], found
+        resolve = [g for g in found if g[1] == (cap_d, 2)]
+        assert resolve == [(len(cb.ci), (cap_d, 2), False)], found
+        return [g for g in found if g[1] != (cap_d, 2)]
 
     assert step._lane_select is window.lane_select_xla  # this backend's
     lane_selects = others(window.lane_select_xla)
@@ -624,6 +820,56 @@ def test_dict_wire_train_step_scatters_each_table_once():
     for shape in shapes.values():
         cold = [i for o, i in adds if o == shape and i[0] == b * kc]
         assert len(cold) == 1, (shape, adds)
+
+
+@pytest.mark.parametrize("wire,microbatch", [
+    ("dict", 1), ("dict", 4), ("compact", 1),
+])
+def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
+    toy_dataset, tmp_path, wire, microbatch
+):
+    """The epoch's ``wire`` row says how far the dictionary route
+    engages, from shapes: ``table_gather_indices_per_step`` beside
+    ``padded_cold_slots_per_step`` (B * max_nnz).  A step that reads
+    the dictionary wire's plan hands the table the dictionary's and the
+    tail's capacities; a plain-compact batch, and a dictionary-wire
+    batch cut into microbatch slices (which go without the plan), a row
+    per padded slot: ratio 1.0."""
+    from xflow_tpu.obs import schema
+    from xflow_tpu.trainer import Trainer
+
+    cfg = Config(
+        model="lr", train_path=toy_dataset.train_prefix, epochs=1,
+        batch_size=64, table_size_log2=14, max_nnz=24, num_devices=1,
+        wire_dedup="on" if wire == "dict" else "off",
+        microbatch=microbatch, metrics_out=str(tmp_path / "m.jsonl"),
+    )
+    trainer = Trainer(cfg)
+    try:
+        assert trainer.step.wire_format == wire
+        caps = []
+        book = trainer.step._book_wire
+
+        def spy(nbytes, examples, cb=None, cold_slots=0):
+            if cb is not None:
+                caps.append(len(cb.cu) + len(cb.ct))
+            book(nbytes, examples, cb=cb, cold_slots=cold_slots)
+
+        trainer.step._book_wire = spy
+        stats = trainer.train_epoch()
+    finally:
+        trainer.close()
+    row = stats["_wire"]
+    slots = row["padded_cold_slots_per_step"]
+    assert slots == 64 * 24
+    if wire == "dict" and microbatch == 1:
+        assert len(caps) == stats["steps"]
+        assert row["table_gather_indices_per_step"] == round(
+            sum(caps) / len(caps)
+        ) < slots
+    else:
+        assert row["table_gather_indices_per_step"] / slots == 1.0
+    assert not schema.validate_row({"t": 0.0, "kind": "wire", **row})
 
 
 def test_dict_wire_eligibility_gates():

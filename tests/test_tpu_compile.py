@@ -8,6 +8,7 @@ at a time may load the TPU's library, so under several test workers only
 the worker that is given this file describes the chip."""
 
 import os
+import re
 import types
 
 import jax
@@ -66,9 +67,11 @@ def test_dict_wire_decode_compiles_for_v5e_at_flagship(
 ):
     """The whole decode as a TPU traces it, at the plane capacities of one
     real batch of the benchmark's train cell (T=2^28, B=131072, 12 + 28):
-    it compiles, holds its Mosaic kernels, and its temporaries (432 MiB,
-    most of it [B, K] int32 planes padded to 128 lanes) stay under the
-    1 GiB gradient buffer beside it: a materialised one-hot would not."""
+    it compiles, holds its Mosaic kernels, and its temporaries (669 MiB:
+    [B, K] int32 planes padded to 128 lanes, and 600 MiB for the key
+    resolve's two-word rows, one to a 128-lane tile row) stay under the
+    1 GiB gradient buffer that the step allocates after them: a
+    materialised one-hot would not."""
     from xflow_tpu.ops import window
     from xflow_tpu.parallel.step import expand_dict_wire
 
@@ -93,6 +96,74 @@ def test_dict_wire_decode_compiles_for_v5e_at_flagship(
     # one monotone_take per flag plane and per tier of each section
     assert compiled.as_text().count("tpu_custom_call") >= 6
     assert compiled.memory_analysis().temp_size_in_bytes < 768 << 20
+
+
+def _gather_lines(compiled) -> list[str]:
+    return [
+        line.split("metadata")[0]
+        for line in compiled.as_text().splitlines() if " gather(" in line
+    ]
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_wide_take_stays_a_gather_of_two_word_rows_on_v5e(
+    one_chip, no_compile_cache, dtype
+):
+    """The occurrence resolve at the flagship's shapes (1 228 800 indices
+    into the dictionary's 53 248 keys, or rows): what the TPU's compiler
+    leaves of ops/window.py::wide_take is ONE gather of two-word rows.
+    It folds a column pick into the gather and a gather beside a constant
+    column into one of single elements, and either fold is the 8.6 ns an
+    index again (PERF.md section 6, PR 30)."""
+    from xflow_tpu.ops import window
+
+    src = jax.ShapeDtypeStruct((53248,), dtype, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((1228800,), jnp.int32, sharding=one_chip)
+    gathers = _gather_lines(jax.jit(window.wide_take).lower(src, idx).compile())
+    assert len(gathers) == 1 and "slice_sizes={1,2}" in gathers[0], gathers
+
+
+@pytest.mark.parametrize("rows,d", [(1 << 28, 1), (1 << 25, 10)])
+def test_dict_cold_rows_compile_for_v5e_at_flagship(
+    one_chip, no_compile_cache, rows, d
+):
+    """The cold rows through the dictionary at the plane capacities of
+    one real batch of the benchmark's train cell, LR's table and FM's
+    width: it compiles with its lane shuffles on float32 rows, the [T, D]
+    table (the only float32 operand of its height) is gathered per
+    dictionary and per tail entry and by nothing of a padded plane's
+    size, and the occurrence resolve reads rows at least two words wide."""
+    from xflow_tpu.ops import window
+    from xflow_tpu.parallel.step import dict_cold_rows
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cap_u, cap_i, cap_t, slots = 53248, 1228800, 294912, 131072 * 12
+    plan = {
+        "cu": shaped((cap_u,), jnp.int32), "ct": shaped((cap_t,), jnp.int32),
+        "ci": shaped((cap_i,), jnp.int32),
+        "is_dict": shaped((slots,), jnp.bool_),
+        "is_tail": shaped((slots,), jnp.bool_),
+        "di_idx": shaped((slots,), jnp.int32),
+        "tail_idx": shaped((slots,), jnp.int32),
+    }
+    compiled = jax.jit(
+        lambda p, pl: dict_cold_rows(pl, {"t": p}, window.lane_select_tpu)
+    ).lower(shaped((rows, d), jnp.float32), plan).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * d  # a take per stream, column
+    # beside the takes' window rows, three gathers: the table's rows of
+    # the tail and of the dictionary, and the occurrence resolve
+    shapes = sorted(
+        re.search(r"= f32\[([\d,]*)\]", g).group(1)
+        for g in _gather_lines(compiled) if "slice_sizes={1,256}" not in g
+    )
+    wide = f",{d}" if d > 1 else ""
+    assert shapes == sorted(
+        [f"{cap_t}{wide}", f"{cap_u}{wide}", f"{cap_i},{max(d, 2)}"]
+    ), shapes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1536 << 20
 
 
 def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(

@@ -21,8 +21,11 @@ by where its information actually lives:
   ships as raw u24/u32 values — measured on the zipf-cache workload the
   dictionary covers ~57% of cold occurrences with ~53k entries, so
   dictionary-tier occurrences cost 2 bytes instead of 4; on the
-  device the indices are resolved back to keys and nothing more
-  (parallel/step.py::expand_dict_wire).
+  device the indices resolve back to keys
+  (parallel/step.py::expand_dict_wire) and to parameter rows: the
+  big table is read once per dictionary entry and once per tail
+  entry, not once per padded slot (dict_cold_rows; PERF.md section 6,
+  PR 30).
   A full dictionary would LOSE bytes here: at the measured 2.9x cold
   duplication, unique keys are ~35% of occurrences and shipping them
   all costs more than the index plane saves.  Dedup where the
@@ -38,9 +41,10 @@ by where its information actually lives:
   335 ms of the flagship's 407 ms step on a v5e; because every index
   it needs is a running count over the streams (steps of 0 or 1), the
   planes now come from row gathers of 128-lane windows plus a lane
-  shuffle (ops/window.py) in 12 ms, 9 of them the one true random
-  access and the one element gather left, the dictionary resolve over
-  the ci plane (at most B * K entries; PERF.md section 6, PR 25).
+  shuffle (ops/window.py) in 12 ms (PR 25), 9 of them the one true
+  random access, the dictionary resolve over the ci plane (at most
+  B * K entries), which as a gather of two-word rows instead of
+  single elements takes 3 (PERF.md section 6, PR 30).
 * **labels/weights ship as bitmaps** (eligibility requires the 0/1
   hash-mode invariant, like the plain compact wire).
 

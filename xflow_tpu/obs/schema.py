@@ -393,6 +393,13 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # pull and push handed between the chips, a step, from shapes
         # (parallel/step.py::exchange_bytes)
         "exchange_bytes_per_step": (int, float),
+        # dense store only, a batch, from shapes: the indices the cold
+        # gather hands the [T, D] tables (the dictionary's and the
+        # tail's capacities where the step reads the dictionary wire's
+        # plan, else the padded slots) beside the padded cold slots
+        # B * max_nnz (parallel/step.py::_book_wire)
+        "table_gather_indices_per_step": (int, float),
+        "padded_cold_slots_per_step": (int, float),
     },
     "train_epoch": {
         # single-host runs under trainer._transfer_ahead only

@@ -97,3 +97,29 @@ def monotone_take(
     local = idx - (anchor << 7)[:, None]
     win = jnp.take(window_rows(src), anchor, axis=0, mode="clip")
     return lane_select(win, local).reshape(-1)[:m]
+
+
+def wide_take(src: jax.Array, idx: jax.Array) -> jax.Array:
+    """``src[idx]`` along axis 0 for any int32 ``idx`` into a
+    batch-sized source ``[n]`` or ``[n, D]``, never as a gather of
+    one-element slices: a single column rides beside a second one.
+    On the v5e an index whose slice is ONE element pays 8.6 ns, one
+    whose slice is a row of two to eleven 32-bit words 2.5-2.7 (PERF.md
+    section 6, PR 30: 1 228 800 indices into 53 248 rows).  The
+    compiler folds a column pick back into the gather, and a gather
+    beside a constant column back into the element gather: so the
+    second column counts the rows, and a barrier stands before the
+    pick.  Out-of-range indices clip; an empty source gives zeros
+    (like monotone_take: an index plane whose dictionary is empty has
+    no real entry)."""
+    if src.shape[0] == 0:
+        return jnp.zeros(idx.shape + src.shape[1:], src.dtype)
+    if src.ndim == 1:
+        return wide_take(src[:, None], idx)[:, 0]
+    if src.shape[1] > 1:
+        return jnp.take(src, idx, axis=0, mode="clip")
+    rows = jnp.arange(src.shape[0], dtype=src.dtype)[:, None]
+    two = jnp.take(
+        jnp.concatenate([src, rows], axis=1), idx, axis=0, mode="clip"
+    )
+    return jax.lax.optimization_barrier(two)[:, :1]

@@ -40,6 +40,7 @@ from xflow_tpu.ops.window import (
     lane_select_tpu,
     lane_select_xla,
     monotone_take,
+    wide_take,
 )
 from xflow_tpu.optim.base import Optimizer
 from xflow_tpu.parallel import exchange
@@ -312,22 +313,30 @@ def _interleaved_slices(batch: BatchArrays, s: int) -> BatchArrays:
     contiguous split would force (slice 0 = first B/s rows = one
     device's shard).  Both scan modes are composition-insensitive:
     accumulate is order-independent, and sequential's slice sequence
-    is an arbitrary partition of the dispatch window by design."""
+    is an arbitrary partition of the dispatch window by design.
+
+    A dictionary-wire batch's ``cold_plan`` (expand_dict_wire) indexes
+    the WHOLE batch's flat streams and has no batch axis to split: a
+    slice goes without it, and gathers a row per slot of its keys."""
     return {
         k: v.reshape((v.shape[0] // s, s) + v.shape[1:]).swapaxes(0, 1)
         for k, v in batch.items()
+        if k != "cold_plan"
     }
 
 
 def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
     """Inverse of CompactBatch.wire (io/compact.py), inside the
     jitted step: rebuild the padded [B, K] planes from the flat
-    tiered streams.  The padded planes are all it returns.  The wire
-    also carries the host's dictionary (``cw_cu``, of ``cw_cun`` real
-    entries) and each dictionary occurrence's index into it
-    (``cw_ci``): a consolidation plan for the cold scatter that costs
-    no device sort, should a probe on the chip ever say that merging
-    duplicates pays (docs/PERF.md "Cold consolidation": it did not).
+    tiered streams.  Beside the padded planes it hands on
+    ``cold_plan``: what the host's dedup knows about the batch's cold
+    keys, for the cold row gather and nothing else (dict_cold_rows).
+    The dictionary ``cu`` and the raw tail ``ct`` (int32 table rows,
+    at their plane capacities) are between them every distinct table
+    row the cold section reads; ``ci`` is each dictionary occurrence's
+    index into ``cu``; ``is_dict``/``is_tail`` and the running counts
+    ``di_idx``/``tail_idx`` say, per padded position, which flat
+    stream it reads and where.
 
     ``cfg`` gives max_nnz and hot_nnz; ``lane_select`` is
     the in-window shuffle of ops/window.py that the caller's platform
@@ -344,14 +353,16 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
     one ops/window.py::monotone_take: a row gather per 128 outputs
     and a lane shuffle inside the window.
 
-    ONE element gather is left, by name: the cold dictionary resolve
-    ``cu[ci]``, the decode's only random access.  It runs over the
-    flat occurrence plane ``cw_ci``, whose length is the plane_cap
+    ONE gather with a free index is left, by name: the cold dictionary
+    resolve ``cu[ci]``, the decode's only random access.  It runs over
+    the flat occurrence plane ``cw_ci``, whose length is the plane_cap
     bucket of the batch's dictionary occurrences: at most B * max_nnz
     by plane_cap's ceiling and as much as that in a batch whose cold
     entries are nearly all dictionary hits, so it is smaller than a
     padded plane by the data, not by construction (flagship: 1 228 800
-    of 1 572 864, 8.8 of the decode's 12.3 ms).
+    of 1 572 864).  It is an ops/window.py::wide_take: as a gather of
+    single elements it was 8.8 of the decode's 12.3 ms, as one of
+    two-word rows 3.1 (PERF.md section 6, PR 30).
 
     Every plane capacity is static (plane_cap bucketing), so one
     steady batch geometry is one compiled program; the per-batch
@@ -403,12 +414,9 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
     )
     cu = keys_plane(w["cw_cu"])
     ci = w["cw_ci"].astype(jnp.int32)
-    if cu.shape[0]:
-        dict_key_flat = jnp.take(cu, ci, mode="clip")
-    else:
-        dict_key_flat = jnp.zeros_like(ci)
-    dict_key = take(di_idx, dict_key_flat)
-    tail = take(tail_idx, keys_plane(w["cw_ct"]))
+    dict_key = take(di_idx, wide_take(cu, ci))
+    ct = keys_plane(w["cw_ct"])
+    tail = take(tail_idx, ct)
     cmask = cvalid.astype(jnp.float32).reshape(b, kc)
     keys2d = jnp.where(
         is_dict, dict_key, jnp.where(is_tail, tail, 0)
@@ -424,6 +432,11 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
         "mask": cmask,
         "labels": bits(w["cw_lb"], b).astype(jnp.float32),
         "weights": bits(w["cw_wb"], b).astype(jnp.float32),
+        "cold_plan": {
+            "cu": cu, "ct": ct, "ci": ci,
+            "is_dict": is_dict, "is_tail": is_tail,
+            "di_idx": di_idx, "tail_idx": tail_idx,
+        },
     }
     if "cw_hc" in w:
         kh = cfg.hot_nnz
@@ -451,6 +464,48 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
         )
         out["hot_vals"] = hmask
         out["hot_mask"] = hmask
+    return out
+
+
+def dict_cold_rows(
+    plan: dict, params: dict[str, jax.Array], lane_select
+) -> dict[str, jax.Array]:
+    """``{name: param[keys]}`` for a dictionary-wire batch's cold keys,
+    [B * max_nnz, D] flat, with the [T, D] tables read once per entry
+    of ``plan``'s dictionary and tail instead of once per padded slot:
+    ``plan`` is expand_dict_wire's ``cold_plan``.
+
+    A gather pays per index on the TPU, and an index into the big
+    table pays double one into a batch-sized array (PERF.md section
+    6, PR 30), while most padded slots repeat a dictionary key or are
+    padding.  So: one row per dictionary entry and per tail entry out
+    of the table; each dictionary occurrence's row out of THOSE
+    (``rows_u[ci]``, the route's one per-occurrence gather, an
+    ops/window.py::wide_take over a source of at most DICT_CAP rows);
+    and the padded layout from the two flat row streams by the same
+    running counts that lay out the key plane: one
+    ops/window.py::monotone_take per stream and column.
+    Unmasked slots hold ``param[key]`` bit for bit; padding slots hold
+    0 where ``param[keys]`` reads row 0 (masked in every reduction
+    either way)."""
+    take = functools.partial(monotone_take, lane_select=lane_select)
+    cu, ct, ci = plan["cu"], plan["ct"], plan["ci"]
+    out = {}
+    for name, param in params.items():
+        rows_t = param[ct]
+        rows_occ = wide_take(param[cu], ci)
+        out[name] = jnp.stack([
+            jnp.where(
+                plan["is_dict"],
+                take(plan["di_idx"], rows_occ[:, j]),
+                jnp.where(
+                    plan["is_tail"],
+                    take(plan["tail_idx"], rows_t[:, j]),
+                    0,
+                ),
+            )
+            for j in range(param.shape[-1])
+        ], axis=1)
     return out
 
 
@@ -562,6 +617,19 @@ class TrainStep:
             and cfg.wire_dedup != "off"
             and dict_ok
         )
+        # Whether the train program gathers the cold rows of a WHOLE
+        # batch (and so reads a dictionary-wire batch's cold_plan,
+        # _cold_rows) or of scan slices, which go without it
+        # (_interleaved_slices): sparse mode ignores microbatch, the hot
+        # sequential inner gathers at its window's start.
+        self._whole_batch_gather = (
+            cfg.microbatch == 1
+            or cfg.update_mode == "sparse"
+            or (
+                cfg.update_mode == "sequential"
+                and cfg.sequential_inner == "hot"
+            )
+        )
         # Hierarchical parameter store (Config.store_mode; store/):
         # under 'tiered' the table state is the store's hot tier + host
         # cold rows, the wire is the store's refs/miss format (the
@@ -618,17 +686,32 @@ class TrainStep:
             else "full"
         )
 
-    def _book_wire(self, nbytes: int, examples: int, cb=None) -> None:
+    def _book_wire(
+        self, nbytes: int, examples: int, cb=None, cold_slots: int = 0
+    ) -> None:
         """Wire accounting counters behind the trainer's per-epoch
         ``wire`` metrics row (obs/schema.py): bytes that crossed the
         link, examples they carried, and — dict wire — the cold
-        occurrence/unique-touch compaction the host performed."""
+        occurrence/unique-touch compaction the host performed.  Beside
+        them, from shapes, what the batch asks of the [T, D] tables:
+        its ``cold_slots`` padded cold slots (B * max_nnz) and the
+        indices its cold gather hands the table, which are the
+        capacities of the dictionary and the tail where the step reads
+        a dictionary-wire batch's ``cold_plan`` (_cold_rows) and the
+        padded slots everywhere else."""
         self.obs.counter("wire.bytes", nbytes)
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")
         if cb is not None:
             self.obs.counter("wire.cold_occ", cb.n_cold)
             self.obs.counter("wire.cold_touched", cb.cold_touched)
+        if cold_slots:
+            through_dict = cb is not None and self._whole_batch_gather
+            self.obs.counter("wire.cold_slots", cold_slots)
+            self.obs.counter(
+                "wire.table_gather_indices",
+                len(cb.cu) + len(cb.ct) if through_dict else cold_slots,
+            )
 
     def _dict_geometry_ok(self, batch) -> bool:
         """A batch rides the dict wire only at the loader geometry the
@@ -762,6 +845,7 @@ class TrainStep:
             sum(int(v.nbytes) for v in wire.values()),
             batch.num_real(),
             cb=cb,
+            cold_slots=batch.batch_size * batch.max_nnz,
         )
         arrays = {k: jnp.asarray(v) for k, v in wire.items()}
         if jax.process_count() > 1:
@@ -1031,12 +1115,32 @@ class TrainStep:
         )
 
     @jax.named_scope("xf.gather")
+    def _cold_rows(
+        self, tables: dict[str, dict[str, jax.Array]], batch: BatchArrays
+    ) -> dict[str, jax.Array]:
+        """[B, max_nnz, D] parameter rows of the batch's cold keys, per
+        table.  A row per index either way; a whole dictionary-wire
+        batch brings the shorter index list (its ``cold_plan``: the
+        table is read per dictionary and tail entry, dict_cold_rows),
+        every other batch has one index per padded slot.  Padding slots
+        are masked out of every reduction by batch["mask"]: they read
+        row 0 here and hold 0 there."""
+        params = {name: t["param"] for name, t in tables.items()}
+        if "cold_plan" not in batch:
+            return {n: p[batch["keys"]] for n, p in params.items()}
+        shape = batch["keys"].shape
+        return {
+            n: r.reshape(shape + r.shape[1:])
+            for n, r in dict_cold_rows(
+                batch["cold_plan"], params, self._lane_select
+            ).items()
+        }
+
+    @jax.named_scope("xf.gather")
     def _gather_local_rows(
         self, tables: dict[str, dict[str, jax.Array]], batch: BatchArrays
     ) -> dict[str, jax.Array]:
-        # Forward gather uses raw keys; padding entries read row 0 but are
-        # masked out of every reduction by batch["mask"].
-        cold = {name: t["param"][batch["keys"]] for name, t in tables.items()}
+        cold = self._cold_rows(tables, batch)
         if "hot_keys" not in batch:
             return cold
         # Hot section: two-level one-hot MXU gather over table rows
@@ -1639,14 +1743,9 @@ class TrainStep:
         s = cfg.microbatch
         h = cfg.hot_size
         # Window-start cold values: ONE batched gather per table,
-        # hoisted out of the scan.  Padding slots read row 0 and are
-        # masked out of every reduction downstream (same convention as
-        # _gather_model_rows).
-        with jax.named_scope("xf.gather"):
-            cold_rows = {
-                name: t["param"][batch["keys"]]
-                for name, t in tables.items()
-            }
+        # hoisted out of the scan (through the dictionary where the
+        # batch carries one, like _gather_local_rows).
+        cold_rows = self._cold_rows(tables, batch)
         heads0 = {
             name: {k: arr[:h] for k, arr in t.items()}
             for name, t in tables.items()

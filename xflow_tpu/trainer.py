@@ -1071,6 +1071,18 @@ class Trainer:
                     occ_in / touched if touched else 1.0, 3
                 ),
             }
+            if "wire.cold_slots" in snap.counters:
+                # what the batches asked of the [T, D] tables, a batch,
+                # from shapes: indices of the cold gather beside the
+                # padded cold slots (equal unless the step read the
+                # dictionary wire's plan: TrainStep._book_wire)
+                batches = max(snap.counters.get("wire.batches", 0), 1)
+                stats["_wire"]["table_gather_indices_per_step"] = round(
+                    snap.counters["wire.table_gather_indices"] / batches
+                )
+                stats["_wire"]["padded_cold_slots_per_step"] = round(
+                    snap.counters["wire.cold_slots"] / batches
+                )
             if "exchange.bytes" in snap.counters:
                 # a mesh of more than one device: what the step's pull
                 # and push moved between the chips, from shapes
