@@ -33,3 +33,12 @@ class Outcome:
     window_start: float  # time.perf_counter() at the window's first instant
     run: dict  # what per-layer metric readers read
     counts: dict  # what a rehearsal may print: counts, never rates
+    # each number ``checks`` compared, beside its limit and the sense of the
+    # comparison: the line's last key and the run's last lines on standard error
+    compared: dict[str, dict] = dataclasses.field(default_factory=dict)
+
+
+def beside(value, limit, op: str = "<=") -> dict:
+    """One entry of ``Outcome.compared``: the check holds where
+    ``value op limit`` does."""
+    return {"value": value, "op": op, "limit": limit}

@@ -25,13 +25,13 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 NAME_RE = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
 
-# The nine Config fields that choose a code path in the step, the wire or
+# The seven Config fields that choose a code path in the step, the wire or
 # the store (ROADMAP C1).  A configuration file never sets one: they stay at
 # the program's defaults, so a PR that changes what ``auto`` chooses is seen
 # by the cell.
 PATH_SELECTORS = frozenset({
-    "update_mode", "sequential_inner", "hot_windowend", "cold_consolidate",
-    "hot_impl", "hot_dtype", "wire_mode", "wire_dedup", "store_mode",
+    "update_mode", "sequential_inner", "hot_windowend", "hot_impl",
+    "wire_mode", "wire_dedup", "store_mode",
 })
 # keys of a configuration file that describe it and are not Config fields
 CONFIG_META = frozenset({

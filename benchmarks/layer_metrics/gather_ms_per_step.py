@@ -1,7 +1,9 @@
 """Device milliseconds a step under the program's scope ``xf.gather``
-(``parallel/step.py::_gather_model_rows``: the cold rows' gather from the
-table; ``ops/hot.py::hot_gather``: the hot head's one-hot matmuls) in the
-traced epoch (``harness/scope_times.py``)."""
+(``parallel/step.py::_gather_model_rows``, on a mesh ``_pull_model_rows``:
+``_cold_rows`` is ``param[keys]`` or, on a dictionary-wire batch,
+``dict_cold_rows``, the table's rows per dictionary and tail entry and the
+occurrence resolve of the ROWS; ``ops/hot.py::hot_gather``: the hot head's
+one-hot matmuls) in the traced epoch (``harness/scope_times.py``)."""
 
 from benchmarks.harness import scope_times
 

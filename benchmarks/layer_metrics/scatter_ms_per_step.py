@@ -1,6 +1,7 @@
 """Device milliseconds a step under the program's scope ``xf.scatter``
-(``parallel/step.py``: the zeroed gradient buffer, ``_scatter_grads``,
-``_cold_accumulate``; ``ops/hot.py::hot_scatter``) in the traced epoch
+(``parallel/step.py``: ``_zero_gbufs``, ``_scatter_grads`` and on a mesh
+``_push_grads``, both through ``_cold_accumulate``, the one form of the cold
+scatter-add; ``ops/hot.py::hot_scatter``) in the traced epoch
 (``harness/scope_times.py``)."""
 
 from benchmarks.harness import scope_times

@@ -10,8 +10,18 @@ table, no hot/cold split, no wire, no sharding.  It is handed
     idx     int32 [B, K]    which of the U rows each feature entry is
     x       float32 [B, K]  the entry's value: 1 for a feature, 0 for padding
     labels, weights  float32 [B]   (weight 0 marks a padding example)
+    slots   int32 [B, K]    the entry's field id, as the loader steered it
+    num_fields              how many fields the configuration counts (static)
 
 and returns the step's logloss and the U rows as the step leaves them.
+
+A family is a module with ``TABLES`` (table name -> row width),
+``logit(rows, x)`` and ``grad_logit(rows, x)``.  One that sets
+``USES_FIELDS = True`` reads the field ids and is called as
+``logit(rows, x, slots, num_fields)``, ``grad_logit(rows, x, slots,
+num_fields)``; for every other family the two arguments stay out of the
+compiled program.  Dense (replicated) parameters are not in the protocol:
+every parameter a family has is a row of a hashed table.
 """
 
 from __future__ import annotations
@@ -70,19 +80,24 @@ def ftrl_update(row: dict, g, hyper: dict) -> dict:
     return {"param": w_new, "n": n_new, "z": z_new}
 
 
-@functools.partial(jax.jit, static_argnames=("family", "hyper"))
-def train_step(family, rows, idx, x, labels, weights, hyper):
+@functools.partial(jax.jit, static_argnames=("family", "hyper", "num_fields"))
+def train_step(
+    family, rows, idx, x, labels, weights, hyper, slots=None, num_fields=0
+):
     """``family`` is a reference module (``logit``, ``grad_logit``);
-    ``hyper`` a hashable tuple of (name, value) FTRL settings."""
+    ``hyper`` a hashable tuple of (name, value) FTRL settings; ``slots`` and
+    ``num_fields`` go to a family that declares ``USES_FIELDS`` and to no
+    other."""
     h = dict(hyper)
+    fields = (slots, num_fields) if getattr(family, "USES_FIELDS", False) else ()
     with jax.default_matmul_precision("highest"):
         gathered = {t: r["param"][idx] for t, r in rows.items()}  # [B, K, D]
-        p = sigmoid_clamped(family.logit(gathered, x))
+        p = sigmoid_clamped(family.logit(gathered, x, *fields))
         ll = logloss(labels, p, weights)
         # lr_worker.cc:116-118: the gradient is the mean over the real rows
         residual = (p - labels) * weights / jnp.maximum(jnp.sum(weights), 1.0)
         new_rows = {}
-        for t, g in family.grad_logit(gathered, x).items():
+        for t, g in family.grad_logit(gathered, x, *fields).items():
             occ = (g * residual[:, None, None]).reshape(-1, g.shape[-1])
             pushed = jax.ops.segment_sum(
                 occ, idx.reshape(-1), num_segments=rows[t]["param"].shape[0]

@@ -7,8 +7,12 @@ started on, and prints as its last line of standard output one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
 metrics with ``--trace 0``, its per-layer metrics with ``--trace 1``) and
 ``device`` (with ``--trace 1`` also ``busy_s``, ``window_s`` and a
-``breakdown``).  Without a TPU, or with fewer chips than the cell asks for,
-it exits non-zero and prints no result.
+``breakdown``), and last ``compared``: each number ``correct`` rests on
+beside its limit (``value``, ``op``, ``limit``: the check holds where
+``value op limit`` does), which are also the run's last lines on standard
+error.
+Without a TPU, or with fewer chips than the cell asks for, it exits non-zero
+and prints no result.
 
 ``--rehearsal`` shrinks every size (the ``rehearsal`` blocks of the
 configuration and the mix), runs on whatever backend JAX finds, and ends in a
@@ -99,6 +103,7 @@ def main(argv: list[str] | None = None) -> int:
     with open(os.path.join(ROOT, ".bench_cache", f"{args.workload}.last.json"), "w") as f:
         json.dump({
             "args": vars(args), "checks": outcome.checks,
+            "compared": outcome.compared,
             "end_to_end": e2e, "per_layer": layer,
             "compiles": ctx.meter.snapshot(),
             "memory_stats": device.memory_stats(),
@@ -111,14 +116,21 @@ def main(argv: list[str] | None = None) -> int:
         )
     shutil.rmtree(ctx.work, ignore_errors=True)
     ctx.log(f"checks {outcome.checks}")
+    for name, pair in outcome.compared.items():
+        print(
+            f"compared {name} {pair['value']!r} {pair['op']} limit {pair['limit']!r}",
+            file=sys.stderr,
+        )
     if args.rehearsal:
         print(json.dumps({
             "rehearsal": True, "workload": args.workload, "backend": ctx.device,
             "checks": outcome.checks, "counts": outcome.counts,
             "end_to_end_reported": sorted(named & set(e2e)),
             "per_layer_reported": sorted(layer),
+            "compared": outcome.compared,
         }), flush=True)
         return 0 if correct else 1
+    result["compared"] = outcome.compared
     print(json.dumps(result), flush=True)
     return 0
 

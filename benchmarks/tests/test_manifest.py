@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from benchmarks.harness import manifest
+from benchmarks.harness import manifest, refcheck
 
 DOC = manifest.load()
 KEYS = {
@@ -150,6 +150,23 @@ def _rehearse(root: str, doc: dict, cell: str) -> dict:
     return last
 
 
+def _with_cell(doc: dict, config: str, cell: str, traffic: str) -> dict:
+    """``doc`` with a made-up configuration and its one training cell."""
+    doc = json.loads(json.dumps(doc))
+    doc["configs"].append({
+        "name": config, "source": "https://example.org/made-up",
+        "file": f"benchmarks/configs/{config}.json", "reduced": [], "why": "made up",
+    })
+    doc["workloads"].append({
+        "name": cell, "config": config, "traffic": traffic, "chips": 1,
+        "why": "made up",
+    })
+    for m in doc["end_to_end"]:
+        if m["name"] == "train_examples_per_s":
+            m["workloads"].append(cell)
+    return doc
+
+
 def test_a_later_cell_is_new_files_and_entries_only(tmp_path):
     """A made-up configuration, a made-up mix (of an existing kind, so it
     needs no code) and a made-up per-layer metric, added to a copy of the
@@ -176,18 +193,7 @@ def test_a_later_cell_is_new_files_and_entries_only(tmp_path):
             "def read(run):\n"
             '    return sum(e["steps"] for e in run.get("epochs", [])) or None\n'
         )
-    doc = json.loads(json.dumps(DOC))
-    doc["configs"].append({
-        "name": "made_up_lr", "source": "https://example.org/made-up",
-        "file": "benchmarks/configs/made_up_lr.json", "reduced": [], "why": "made up",
-    })
-    doc["workloads"].append({
-        "name": "made_up.train_lowskew", "config": "made_up_lr",
-        "traffic": "made_up_lowskew", "chips": 1, "why": "made up",
-    })
-    for m in doc["end_to_end"]:
-        if m["name"] == "train_examples_per_s":
-            m["workloads"].append("made_up.train_lowskew")
+    doc = _with_cell(DOC, "made_up_lr", "made_up.train_lowskew", "made_up_lowskew")
     doc["per_layer"].append({
         "name": "made_up_steps", "unit": "steps", "better": "higher",
         "source": "program_counter", "layer": "input",
@@ -208,6 +214,37 @@ def test_a_later_cell_is_new_files_and_entries_only(tmp_path):
     }
 
 
+def test_a_later_family_that_reads_field_ids_is_a_configuration_file(tmp_path):
+    """The room PR 31 made: a family whose forward reads which field an entry
+    belongs to (``reference/mvm.py``; the program's ``uses_slots`` models)
+    gets a cell as ONE new file and manifest entries, under a mix that is
+    there: the slots plane rides the wire, the reference step is handed the
+    field ids, and the rehearsal ends with ``steps_match_reference`` true."""
+    root, before = _copy_of_the_tree(tmp_path)
+    with open(os.path.join(root, "benchmarks", "configs", "made_up_mvm.json"), "w") as f:
+        json.dump({
+            "source": "https://example.org/made-up", "family": "mvm",
+            "deployment": "made up", "model": "mvm", "optimizer": "ftrl",
+            "v_dim": 10, "v_init_scale": 0.01, "max_fields": 40,
+            "table_size_log2": 25, "batch_size": 131072, "max_nnz": 8,
+            "hot_size_log2": 14, "hot_nnz": 32, "num_devices": 1,
+            "assumed": {}, "reduced": {},
+            "rehearsal": {
+                "table_size_log2": 14, "batch_size": 512, "hot_size_log2": 8,
+                "max_nnz": 40,
+            },
+        }, f)
+    doc = _with_cell(DOC, "made_up_mvm", "made_up_mvm.train_packed", "replay_packed_zipf")
+    last = _rehearse(root, doc, "made_up_mvm.train_packed")
+    assert last["checks"]["steps_match_reference"] is True
+    assert last["counts"]["reference_ok"] is True
+    rows = last["compared"]["rows_rel_err"]
+    assert rows["value"] <= rows["limit"] == refcheck.ROWS_RTOL
+    after = _digests(root)
+    assert {k: after[k] for k in before} == before  # no existing file edited
+    assert set(after) - set(before) == {"benchmarks/configs/made_up_mvm.json"}
+
+
 def _names(subdir: str, ext: str) -> set:
     return {
         f[: -len(ext)] for f in os.listdir(os.path.join(manifest.BENCH_DIR, subdir))
@@ -218,8 +255,9 @@ def _names(subdir: str, ext: str) -> set:
 def test_no_file_waits_for_a_cell():
     """A reader, a mix or a configuration that no cell uses comes with the
     PR that lists its cell.  The one kind without a cell, ``train_text``, is
-    code a data-only PR could not bring, and the made-up cell above runs it
-    (as ``test_reference.py`` runs the FM family, which has no cell either)."""
+    code a data-only PR could not bring, and the first made-up cell above
+    runs it.  (``reference/`` is not counted: a family's reference is code
+    too, and ``test_reference.py`` holds each to the program's step.)"""
     assert _names("layer_metrics", ".py") == {m["name"] for m in DOC["per_layer"]}
     mixes = {w["traffic"] for w in DOC["workloads"]}
     assert _names("traffic", ".json") == mixes

@@ -1,5 +1,6 @@
 """The plain references, and the check that holds the system to them."""
 
+import json
 import types
 
 import jax
@@ -7,8 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import control
 from benchmarks.harness import refcheck
-from benchmarks.reference import fm, ftrl, lr
+from benchmarks.reference import fm, ftrl, lr, mvm
 
 HYPER = {"alpha": 5e-2, "beta": 1.0, "lambda1": 5e-5, "lambda2": 10.0}
 
@@ -72,6 +74,82 @@ def test_fm_forward_has_no_half_and_its_backward_is_of_the_halved_form():
     assert np.allclose(explicit["v"], auto["v"], rtol=1e-4, atol=1e-5)
 
 
+def test_mvm_logit_is_the_product_over_fields_of_one_plus_the_field_sums():
+    """Nested loops in float64: a field's sum, one plus it, the product over
+    the fields, minus one, summed over the factors.  Entries whose field is
+    outside [0, F) count for nothing, an empty field for a factor 1."""
+    rng = np.random.default_rng(2)
+    b, k, f = 5, 9, 4
+    v = rng.normal(0, 0.3, (b, k, mvm.V_DIM))
+    x = rng.integers(0, 2, (b, k)).astype(np.float64)
+    slots = rng.integers(-1, f + 2, (b, k))
+    slots[0] = np.where(slots[0] == 2, 0, slots[0])  # a row with an empty field
+    want = np.zeros(b)
+    for r in range(b):
+        for d in range(mvm.V_DIM):
+            prod = 1.0
+            for field in range(f):
+                prod *= 1.0 + sum(
+                    v[r, i, d] * x[r, i] for i in range(k) if slots[r, i] == field
+                )
+            want[r] += prod - 1.0
+    got = mvm.logit(
+        {"v": jnp.asarray(v, jnp.float32)}, jnp.asarray(x, jnp.float32),
+        jnp.asarray(slots, jnp.int32), f,
+    )
+    assert np.allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_mvm_backward_is_the_gradient_of_the_uncentred_forward():
+    """The explicit gradient against autodiff of sum_d prod_f (1 + s_fd); the
+    guard: where an entry's own factor is 0 the reference (mvm_worker.cc:156)
+    gives 0, not the product of the other factors."""
+    rng = np.random.default_rng(3)
+    b, k, f = 6, 8, 3
+    rows = {"v": jnp.asarray(rng.normal(0, 0.3, (b, k, mvm.V_DIM)), jnp.float32)}
+    x = jnp.asarray(rng.integers(0, 2, (b, k)), jnp.float32)
+    slots = jnp.asarray(rng.integers(-1, f + 1, (b, k)), jnp.int32)
+
+    def uncentred(rows_):
+        return jnp.sum(mvm.logit(rows_, x, slots, f) + mvm.V_DIM)
+
+    auto = jax.grad(uncentred)(rows)["v"]
+    explicit = mvm.grad_logit(rows, x, slots, f)["v"]
+    assert np.allclose(explicit, auto, rtol=1e-4, atol=1e-6)
+    outside = np.asarray((slots < 0) | (slots >= f))
+    assert outside.any() and not np.asarray(explicit)[outside].any()
+
+    # one entry alone in its field with v = -1: its factor is exactly 0
+    lone = {"v": jnp.zeros((1, 2, mvm.V_DIM)).at[0, 0].set(-1.0).at[0, 1].set(0.5)}
+    g = mvm.grad_logit(lone, jnp.ones((1, 2)), jnp.asarray([[0, 1]], jnp.int32), 2)["v"]
+    assert not np.asarray(g[0, 0]).any()  # guarded: 0, where autodiff says 1.5
+    assert np.allclose(g[0, 1], 0.0)  # the product holds the zero factor
+
+
+def test_a_family_that_reads_no_fields_is_compiled_without_them():
+    """``train_step`` hands the field ids only to a family that declares
+    ``USES_FIELDS``: LR's program is the same text with and without them."""
+    rng = np.random.default_rng(4)
+    rows = {"w": {a: jnp.zeros((16, 1)) for a in ("param", "n", "z")}}
+    idx = jnp.asarray(rng.integers(0, 16, (8, 3)), jnp.int32)
+    x, ones = jnp.ones((8, 3)), jnp.ones(8)
+    hyper = tuple(HYPER.items())
+    plain = ftrl.train_step.lower(lr, rows, idx, x, ones, ones, hyper)
+    handed = ftrl.train_step.lower(lr, rows, idx, x, ones, ones, hyper, idx, 7)
+    assert handed.as_text() == plain.as_text()
+    assert not hasattr(lr, "USES_FIELDS") and not hasattr(fm, "USES_FIELDS")
+
+
+MAX_FIELDS = 4  # a dozen entries a row over four fields: every field sum has terms
+# How far the first step's logloss may lie from ln 2.  Tables that start at
+# zero give a logit of 0 (lr), or of products of two drawn v ~ 1e-2 (fm).
+# MVM's logit is of first order in its drawn rows: ~12 entries x 10 factors
+# of N(0, 1e-2) give it a deviation of 0.11 and each row's loss one of half
+# that, so the mean over 59 real rows has a deviation of 7e-3: three of them
+# (read: 0.6993 at hot_log2 0, 0.7034 at 5).
+FIRST_LOGLOSS_BAND = {"lr": 2e-3, "fm": 2e-3, "mvm": 2.2e-2}
+
+
 def _system(model: str, hot_log2: int):
     from xflow_tpu.config import Config
     from xflow_tpu.io.batch import make_batch
@@ -83,6 +161,7 @@ def _system(model: str, hot_log2: int):
     cfg = Config(
         model=model, optimizer="ftrl", table_size_log2=12, batch_size=64,
         max_nnz=6, hot_size_log2=hot_log2, hot_nnz=6, num_devices=1, seed=3,
+        max_fields=MAX_FIELDS,
     )
     mesh = make_mesh(1)
     mdl, opt = make_model(cfg), make_optimizer(cfg)
@@ -96,17 +175,21 @@ def _system(model: str, hot_log2: int):
         keys = rng.integers(0, cfg.table_size, (64, k))
         keys = np.where(rng.random(keys.shape) < 0.5, rng.integers(0, 40, keys.shape), keys)
         mask = (rng.random(keys.shape) < 0.7).astype(np.float32)
+        # field ids in [0, MAX_FIELDS), one in ten outside it, on both sides
+        slots = rng.integers(0, MAX_FIELDS, keys.shape)
+        outside = rng.choice([-1, MAX_FIELDS, MAX_FIELDS + 3], keys.shape)
+        slots = np.where(rng.random(keys.shape) < 0.1, outside, slots)
         weights = np.ones(64, np.float32)
         weights[-5:] = 0.0  # padding examples
         batches.append(make_batch(
-            keys.astype(np.int32), np.zeros(keys.shape, np.int32), mask.copy(),
+            keys.astype(np.int32), slots.astype(np.int32), mask.copy(),
             mask, rng.integers(0, 2, 64).astype(np.float32), weights,
             cfg.hot_size, cfg.hot_nnz,
         ))
     return system, batches, cfg
 
 
-@pytest.mark.parametrize("model, family", [("lr", lr), ("fm", fm)])
+@pytest.mark.parametrize("model, family", [("lr", lr), ("fm", fm), ("mvm", mvm)])
 @pytest.mark.parametrize("hot_log2", [0, 5])
 def test_system_step_agrees_with_the_reference(model, family, hot_log2):
     """xflow_tpu's train step — wire, hot/cold split, dense FTRL pass —
@@ -116,7 +199,7 @@ def test_system_step_agrees_with_the_reference(model, family, hot_log2):
     got = refcheck.check_train_steps(system, family, batches, cfg)
     assert got["ok"], got
     assert all(s["touched_rows"] > 100 for s in got["steps"])
-    assert got["steps"][0]["logloss"] == pytest.approx(np.log(2), abs=2e-3)
+    assert got["steps"][0]["logloss"] == pytest.approx(np.log(2), abs=FIRST_LOGLOSS_BAND[model])
 
 
 def test_the_check_can_fail():
@@ -127,3 +210,106 @@ def test_the_check_can_fail():
     got = refcheck.check_train_steps(system, lr, batches, off)
     assert not got["ok"]
     assert max(got["steps"][-1]["rows_rel_err"].values()) > refcheck.ROWS_RTOL
+
+
+def test_the_check_can_fail_on_field_ids(monkeypatch):
+    """The reference handed the batch's field ids shifted by one place within
+    each row, and all else as the loader steered it, is outside the
+    tolerance: which field an entry belongs to is part of what is checked."""
+    system, batches, cfg = _system("mvm", 5)
+    entries = refcheck.entries
+
+    def shifted(batch):
+        keys, x, slots = entries(batch)
+        return keys, x, np.roll(slots, 1, axis=1)
+
+    monkeypatch.setattr(refcheck, "entries", shifted)
+    got = refcheck.check_train_steps(system, mvm, batches, cfg)
+    assert not got["ok"]
+    assert max(got["steps"][0]["rows_rel_err"].values()) > 100 * refcheck.ROWS_RTOL
+
+
+def test_entries_carry_the_field_ids_hot_section_first():
+    _, batches, cfg = _system("mvm", 5)
+    keys, x, slots = refcheck.entries(batches[0])
+    assert keys.shape == x.shape == slots.shape == (64, cfg.hot_nnz + cfg.max_nnz)
+    assert slots.dtype == np.int32
+    assert (slots[:, : cfg.hot_nnz] == batches[0].hot_slots).all()
+    assert (slots[:, cfg.hot_nnz :] == batches[0].slots).all()
+    live = slots[x != 0]
+    assert ((live < 0) | (live >= cfg.max_fields)).any()  # some outside, kept as drawn
+
+
+@pytest.mark.parametrize("model, family", [("lr", lr), ("fm", fm), ("mvm", mvm)])
+@pytest.mark.parametrize("hot_log2", [0, 5])
+def test_the_control_is_outside_the_tolerance(model, family, hot_log2):
+    """The control (``benchmarks/control.py``: the reference with its gathered
+    rows rounded to bfloat16, which is what a default-precision float32
+    contraction on the TPU makes of its operands) in the reference's place,
+    from a state three steps old: not ``ok``, and the limit on the rows lies
+    between the sound steps' worst reading and the control's."""
+    system, batches, cfg = _system(model, hot_log2)
+    sound = refcheck.check_train_steps(system, family, batches, cfg)
+    got = refcheck.check_train_steps(system, control.InBfloat16(family), batches, cfg)
+
+    def worst(check):
+        return max(max(s["rows_rel_err"].values()) for s in check["steps"])
+
+    assert sound["ok"] and not got["ok"]
+    # toy size: sound <= 4.0e-7 (mvm), the control >= 2.8e-6 (lr, hot 2^5)
+    assert 2 * worst(sound) < refcheck.ROWS_RTOL < worst(got) / 2
+
+
+@pytest.mark.parametrize("cell, check", [
+    ("lr_tb.train_packed", "steps_match_reference"),
+    ("lr_tb.serve_rows", "answers_match_reference"),
+])
+def test_the_control_tool_fails_the_reference_check_and_no_other(cell, check, capsys):
+    """``benchmarks/control.py`` on a rehearsal of a cell of each kind: exit
+    0, the one failed check is the reference's, and what it patched is back."""
+    from benchmarks.drivers import serve_open_loop
+    from benchmarks.harness import manifest
+
+    before = manifest.reference, serve_open_loop.Served.__init__
+    argv = ["--workload", cell, "--rehearsal", "--seed", "6", "--seconds", "0.5"]
+    assert control.main(argv) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["checks_failed"] == [check] and "correct" not in line
+    assert (manifest.reference, serve_open_loop.Served.__init__) == before
+
+
+def test_a_run_with_the_step_broken_underneath_is_not_correct(monkeypatch, capsys):
+    """The whole of a run but its look for a chip (a rehearsal of the LR
+    train cell, in this process), with the program's ``put_batch`` leaving
+    every second example out of the batches the check hands it: ``correct``
+    comes out false on ``steps_match_reference``, and the numbers compared
+    are printed beside their limits."""
+    import dataclasses
+
+    from benchmarks import run
+    from xflow_tpu.io.batch import Batch
+    from xflow_tpu.parallel.step import TrainStep
+
+    real = TrainStep.put_batch
+
+    def half(self, batch, *args, **kwargs):
+        if isinstance(batch, Batch):
+            keep = (np.arange(len(batch.weights)) % 2).astype(batch.weights.dtype)
+            batch = dataclasses.replace(batch, weights=batch.weights * keep)
+        return real(self, batch, *args, **kwargs)
+
+    argv = ["--workload", "lr_tb.train_packed", "--rehearsal", "--seed", "6",
+            "--seconds", "0.3", "--trace", "0"]
+    assert run.main(argv) == 0
+    sound = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert all(sound["checks"].values())
+    monkeypatch.setattr(TrainStep, "put_batch", half)
+    assert run.main(argv) == 1
+    done = capsys.readouterr()
+    broken = json.loads(done.out.strip().splitlines()[-1])
+    assert [k for k, ok in broken["checks"].items() if not ok] == ["steps_match_reference"]
+    assert list(broken)[-1] == "compared"
+    rows = broken["compared"]["rows_rel_err"]
+    assert rows["value"] > 100 * rows["limit"] > sound["compared"]["rows_rel_err"]["value"]
+    assert f"compared rows_rel_err {rows['value']!r} <= limit {rows['limit']!r}" in done.err
+    assert rows["limit"] == refcheck.ROWS_RTOL
