@@ -365,15 +365,17 @@ def phase_train(
         mem and mem[0]["devices"][0]["platform"] == device["platform"],
         f"device_mem rows: {mem[:1]}",
     )
-    # compilations: one train program per wire-shape bucket, all of them
-    # inside the first epoch, none after
+    # compilations: at most one train program per wire-shape bucket
+    # (fewer where the step pads a shorter batch up to a length it has
+    # shipped: TrainStep._settle_planes), all of them inside the first
+    # epoch, none after
     buckets = wire_shape_buckets(paths["packed"])
     per_epoch = steps // EPOCHS
     check(
-        sum(probe.compiles[:per_epoch]) == buckets
+        1 <= sum(probe.compiles[:per_epoch]) <= buckets
         and not any(probe.compiles[per_epoch:]),
         f"train-step compiles per step {probe.compiles}: expected "
-        f"{buckets} in the first epoch and none after",
+        f"1 to {buckets} in the first epoch and none after",
     )
     check(
         os.path.exists(os.path.join(paths["ckpt"], "LATEST")),

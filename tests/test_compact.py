@@ -516,6 +516,74 @@ def test_device_decode_tpu_form_interpreted(hot, data):
         np.testing.assert_array_equal(got[name], plane, err_msg=name)
 
 
+@pytest.mark.parametrize("model", ["lr", "mvm"])
+def test_a_shorter_plane_takes_the_longest_length_the_step_has_shipped(model):
+    """Two batches of one geometry whose flat planes fall into different
+    plane_cap granules: once the step has shipped the longer form it
+    ships the shorter batch at that length, zero-padded
+    (TrainStep._settle_planes), the decode reads the same batch out of
+    it, and the train program is compiled for ONE set of shapes.  A step
+    that meets the shorter one first ships it as it is."""
+    from xflow_tpu.parallel.step import init_state
+
+    full, table, hot_size, _ = _decode_case("full_rows", "u16", 4)
+    sparse, *_ = _decode_case("all_padding", "u16", 4)  # no hot entry at all
+    sparse.keys[:4, :2], sparse.mask[:4, :2] = full.keys[:4, :2], 1.0
+    sparse.vals[:4, :2] = 1.0
+    b, kc, kh = full.batch_size, full.max_nnz, full.hot_nnz
+    step, fresh = (_decode_step(model, table, hot_size, b, kc, kh) for _ in range(2))
+    plain, _ = fresh.host_wire_np(sparse)
+    long, _ = step.host_wire_np(full)
+    settled, _ = step.host_wire_np(sparse)
+    assert {k: v.shape for k, v in settled.items()} == {
+        k: v.shape for k, v in long.items()
+    }
+    grown = [k for k in plain if len(plain[k]) < len(settled[k])]
+    assert {"cw_h8", "cw_hx", "cw_hf"} <= set(grown)
+    assert ("cw_hs" in grown) == (model == "mvm")
+    for k in grown:
+        assert not settled[k][len(plain[k]):].any()
+        np.testing.assert_array_equal(settled[k][: len(plain[k])], plain[k])
+    decode = jax.jit(step._expand_wire)
+    got, want = jax.device_get((decode(settled), jax.jit(fresh._expand_wire)(plain)))
+    got.pop("cold_plan"), want.pop("cold_plan")
+    for name, plane in want.items():
+        np.testing.assert_array_equal(got[name], plane, err_msg=name)
+    state = init_state(step.model, step.optimizer, step.cfg, step.mesh)
+    for batch in (full, sparse, full):
+        state, _ = step.train(state, step.put_batch(batch))
+    assert step.train._cache_size() == 1
+
+
+def test_a_train_program_reads_the_same_first_or_later():
+    """On one device the step hands the tables back replicated, and
+    init_state places them so: the program lowered for a batch's shapes
+    is the same text, so the same compile-cache key, whether those shapes
+    are the process's first or come after a step of other shapes."""
+    from jax.sharding import PartitionSpec
+    from xflow_tpu.parallel.step import abstract_like, init_state
+
+    full, table, hot_size, _ = _decode_case("full_rows", "u16", 4)
+    sparse, *_ = _decode_case("all_padding", "u16", 4)
+    b, kc, kh = full.batch_size, full.max_nnz, full.hot_nnz
+
+    def lowered(first):
+        step = _decode_step("mvm", table, hot_size, b, kc, kh)
+        state = init_state(step.model, step.optimizer, step.cfg, step.mesh)
+        assert state["tables"]["v"]["param"].sharding.spec == PartitionSpec()
+        if first is not None:
+            state, _ = step.train(state, step.put_batch(first))
+        arrays = {  # sparse's own shapes, whatever the step shipped before
+            k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=step._bsharding)
+            for k, v in _decode_step(
+                "mvm", table, hot_size, b, kc, kh
+            ).host_wire_np(sparse)[0].items()
+        }
+        return step.train.lower(abstract_like(state), arrays).as_text()
+
+    assert lowered(None) == lowered(full)
+
+
 # -- the cold rows through the batch's dictionary ------------------------------
 
 
@@ -850,10 +918,10 @@ def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
         caps = []
         book = trainer.step._book_wire
 
-        def spy(nbytes, examples, cb=None, cold_slots=0):
+        def spy(nbytes, examples, cb=None, **shapes):
             if cb is not None:
                 caps.append(len(cb.cu) + len(cb.ct))
-            book(nbytes, examples, cb=cb, cold_slots=cold_slots)
+            book(nbytes, examples, cb=cb, **shapes)
 
         trainer.step._book_wire = spy
         stats = trainer.train_epoch()

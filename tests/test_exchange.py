@@ -77,7 +77,7 @@ def _trained(model, devices, hot_log2, impl, table_log2=12, count=3):
 def test_fm_on_four_devices_agrees_with_the_reference(table_log2, hot_log2, impl):
     """Three steps running on a four-device mesh, each against
     benchmarks/reference/fm.py + ftrl.py on the rows the batch touches,
-    within refcheck's ROWS_RTOL 1e-5 and LOGLOSS_ATOL 1e-6."""
+    within refcheck's ROWS_RTOL and LOGLOSS_ATOL (1e-6 both, since PR 31)."""
     cfg, system = _system("fm", 4, hot_log2, impl, table_log2)
     got = refcheck.check_train_steps(system, fm, _batches(cfg), cfg)
     assert got["ok"], got

@@ -1105,3 +1105,41 @@ def test_null_obs_maps_no_scopes_and_annotates_nothing(toy_dataset, monkeypatch)
         second = t.train_epoch()
     assert "xf.input_stall" in made and "xf.train_epoch" in made
     assert len(mapped) >= 1 and "_scopes" in first and "_scopes" not in second
+
+
+@pytest.mark.parametrize("model, wire_mode, wire_dedup, wire, ships", [
+    ("mvm", "auto", "auto", "dict", True),
+    ("mvm", "compact", "off", "compact", True),
+    ("lr", "auto", "auto", "dict", False),
+    ("lr", "compact", "off", "compact", False),
+])
+def test_wire_row_says_what_the_field_ids_cost(
+    toy_dataset, tmp_path, model, wire_mode, wire_dedup, wire, ships
+):
+    """The epoch's ``wire`` row carries ``slots_bytes_per_example`` (counter
+    wire.slots_bytes, TrainStep._book_wire): the planes of field ids, which
+    the compact and dictionary wires ship for a model that reads them (MVM)
+    and not for LR.  It is part of wire_bytes_per_example, and the row
+    validates against the schema."""
+    from xflow_tpu.obs.schema import OPTIONAL, validate_rows
+
+    out = tmp_path / "m.jsonl"
+    cfg = _toy_cfg(
+        toy_dataset, model=model, epochs=1, metrics_out=str(out),
+        wire_mode=wire_mode, wire_dedup=wire_dedup,
+    )
+    with Trainer(cfg) as t:
+        assert t.step.wire_format == wire and t.step._ship_slots == ships
+        t.train()
+    rows = [json.loads(line) for line in out.read_text().splitlines()]
+    assert validate_rows(rows) == []
+    assert "slots_bytes_per_example" in OPTIONAL["wire"]
+    row = next(r for r in rows if r["kind"] == "wire")
+    if ships:
+        # a byte a padded slot on the compact wire (max_nnz 24 a row, the
+        # last batch's padding rows too); a byte a live entry, in plane
+        # capacities, on the dictionary wire
+        floor = 24.0 if wire == "compact" else 1.0
+        assert floor <= row["slots_bytes_per_example"] < row["wire_bytes_per_example"]
+    else:
+        assert row["slots_bytes_per_example"] == 0

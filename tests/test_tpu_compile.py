@@ -224,3 +224,84 @@ def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
     assert slots < cfg.table_size // 4
     assert max(c["rows"] for c in found) <= slots + 1024, found  # + padding
     assert len(found) <= 4 * 2 + 2 + 3 + 2, found  # + the reduce-scatters' fix-ups
+
+
+def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
+    topo, no_compile_cache
+):
+    """The MVM train step at the geometry of the benchmark's
+    mvm_tb.train_packed (benchmarks/configs/mvm_ftrl_criteo_tb.json: 2^25
+    rows x 10, B=131072, 8 + 32 slots, 40 fields, the dictionary wire's
+    plane capacities of one real batch, seed 1) for a described v5e.
+    Lowered: every contraction asks for float32 (Precision.HIGHEST), the
+    one-hot field contraction of models/blocks.py::field_contract among
+    them, [B, K, F] x [B, K, D] in the forward and again in the backward:
+    at default precision the TPU rounds its operands to bfloat16 and the
+    step misses the reference (PERF.md section 6, PR 31).  Compiled: the
+    program fits the chip with the room the file's ``reduced`` argues from
+    (9.22 GiB of 15.75; at 2^26 rows the compiler refuses it)."""
+    from benchmarks.harness import manifest
+    from xflow_tpu.config import Config
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
+    from xflow_tpu.parallel.step import TrainStep
+
+    doc = manifest.config_file("benchmarks/configs/mvm_ftrl_criteo_tb.json")
+    cfg = Config(**{
+        k: v for k, v in manifest.apply_rehearsal(doc, False).items()
+        if k not in manifest.CONFIG_META
+    })
+    mesh = make_mesh(1, devices=list(topo.devices))
+    model = make_model(cfg)
+    step = TrainStep(model, make_optimizer(cfg), cfg, mesh)
+    assert step.wire_format == "dict" and step._ship_slots
+    assert step._hot_impl == "mxu"
+
+    def shaped(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    u8, u16, u32 = np.uint8, np.uint16, np.uint32
+    planes = {
+        "cw_cu": (43008, u32), "cw_cun": (1, np.int32),
+        "cw_ci": (688128, u16), "cw_ct": (262144, u32),
+        "cw_cf": (118784, u8), "cw_cc": (131072, u8),
+        "cw_lb": (16384, u8), "cw_wb": (16384, u8),
+        "cw_h8": (2490368, u8), "cw_hx": (1835008, u16),
+        "cw_hxh": (0, u8), "cw_hf": (524288, u8), "cw_hc": (131072, u8),
+        "cw_cs": (950272, u8), "cw_hs": (4194304, u8),
+    }
+    state = {
+        "tables": {
+            spec.name: {
+                name: shaped(
+                    (cfg.table_size, spec.dim), jnp.float32, table_sharding(mesh)
+                )
+                for name in ("param", "n", "z")
+            }
+            for spec in model.tables()
+        },
+        "dense": {},
+        "step": shaped((), jnp.int32, replicated(mesh)),
+    }
+    batch = {
+        k: shaped((n,), dtype, step._bsharding) for k, (n, dtype) in planes.items()
+    }
+    lowered = step.train.lower(state, batch)
+    dots = [
+        line for line in lowered.as_text().splitlines() if "dot_general" in line
+    ]
+    b, k = cfg.batch_size, cfg.max_nnz + cfg.hot_nnz
+    onehot = f"tensor<{b}x{k}x{cfg.max_fields}xf32>"
+    by_field = [
+        line for line in dots
+        if f"({onehot}, tensor<{b}x{k}x{cfg.v_dim}xf32>)" in line
+    ]
+    assert len(by_field) == 2, dots  # the forward's, the backward's
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
+    ma = lowered.compile().memory_analysis()
+    peak = (
+        ma.argument_size_in_bytes + ma.temp_size_in_bytes
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    )
+    assert 9.0 * (1 << 30) < peak < 9.5 * (1 << 30), peak

@@ -19,8 +19,10 @@ ways:
 
 Bitwise discipline: each block body is the EXACT expression lifted
 from the incumbent model it came from (same ops, same order, same
-einsum strings).  A change here is a numerics change for every family
-at once — the no-regression tests exist to catch exactly that.
+contractions; the three one-hot field contractions share
+``field_contract``, which asks for float32 on a TPU).  A change here
+is a numerics change for every family at once — the no-regression
+tests exist to catch exactly that.
 
 Blocks take gathered rows / already-masked values, never a model
 instance: they are jit-safe pure functions over arrays, so any model's
@@ -63,6 +65,22 @@ def valid_fields(
     return (slots >= 0) & (slots < num_fields) & (mask > 0)
 
 
+def field_contract(onehot: jax.Array, rows: jax.Array) -> jax.Array:
+    """``sum_k onehot[b, k, f] * rows[b, k, e]`` -> [B, F, E]: a row's
+    entries summed by field, as one batch matmul contracting K.  In
+    float32 on every backend: the TPU runs a float32 dot at default
+    precision with both operands rounded to bfloat16 (as ops/hot.py
+    found for the head), and the field sums of reference MVM then miss
+    float32 by 2e-3 of the largest (PERF.md section 6, PR 31: the
+    benchmark's reference check read 7e-6 - 2.8e-5 against its 1e-6).
+    The one-hot is exact in bfloat16, so HIGHEST buys ``rows`` alone
+    its precision.  On the CPU precision changes nothing: the bitwise
+    pins of tests/test_models.py hold as they were."""
+    return jnp.einsum(
+        "bkf,bke->bfe", onehot, rows, precision=jax.lax.Precision.HIGHEST
+    )
+
+
 # -- embedding tower ----------------------------------------------------------
 
 
@@ -83,7 +101,7 @@ def field_sum_tower(
         slots, num_fields, dtype=x.dtype
     )  # [B, K, F]; out-of-range fields drop out
     embx = emb_rows * x[..., None]  # [B, K, E]
-    return jnp.einsum("bkf,bke->bfe", onehot, embx)  # [B, F, E]
+    return field_contract(onehot, embx)  # [B, F, E]
 
 
 def flatten_tower(field_emb: jax.Array) -> jax.Array:
@@ -196,7 +214,7 @@ def mvm_slot_terms(
         slots, num_fields, dtype=x.dtype
     )  # [B, K, S]; fgid >= num_fields rows are all-zero → feature ignored
     vx = v_rows * x[..., None]  # [B, K, D]
-    slotsum = jnp.einsum("bks,bkd->bsd", onehot, vx)  # [B, S, D]
+    slotsum = field_contract(onehot, vx)  # [B, S, D]
     one_plus = 1.0 + slotsum
     prod = jnp.prod(one_plus, axis=1)  # [B, D]
     return one_plus, prod
@@ -230,7 +248,7 @@ def ffm_field_interaction(
     # log: a D-minor operand gets T(8,128) lane padding — 32x memory)
     vx = v_rows * x_eff[:, :, None]  # [B, K, E]
     # field-aggregated sums: one batch matmul contracting K (MXU)
-    s = jnp.einsum("bkf,bke->bfe", onehot, vx)  # [B, F, E]
+    s = field_contract(onehot, vx)  # [B, F, E]
 
     # cross term sum_{f1,f2,d} S[b,f1,f2,d] * S[b,f2,f1,d]: stays an
     # elementwise fusion over s read twice — never a dot_general

@@ -400,6 +400,12 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # B * max_nnz (parallel/step.py::_book_wire)
         "table_gather_indices_per_step": (int, float),
         "padded_cold_slots_per_step": (int, float),
+        # of wire_bytes_per_example, the planes of field ids (slots_u8 /
+        # hot_slots_u8, the dictionary wire's cw_cs / cw_hs, the full
+        # wire's slots / hot_slots): 0 where none ships, as for a model
+        # whose uses_slots is false on the compact or dictionary wire;
+        # absent from files older than the counter wire.slots_bytes
+        "slots_bytes_per_example": (int, float),
     },
     "train_epoch": {
         # single-host runs under trainer._transfer_ahead only

@@ -20,10 +20,12 @@ from __future__ import annotations
 
 import functools
 import re
+import threading
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from xflow_tpu.config import Config
@@ -66,6 +68,20 @@ _HLO_COLLECTIVE_RE = re.compile(
     rf" (?:{exchange.COLLECTIVE_OPS})(?:-start|-done)?\("
 )
 _SCOPE_RE = re.compile(r"xf\.[A-Za-z0-9_]+")
+
+# The planes of field ids a batch can ship, by wire: the full wire's,
+# the compact wire's (compact_wire_np), the dictionary wire's
+# (io/compact.py::CompactBatch.wire).  The compact and dictionary wires
+# ship them only for a model that reads them (TrainStep._ship_slots).
+_SLOT_PLANES = frozenset({
+    "slots", "hot_slots", "slots_u8", "hot_slots_u8", "cw_cs", "cw_hs",
+})
+# The dictionary wire's planes whose length is a plane_cap capacity (the
+# others count the batch's rows): TrainStep._settle_planes.
+_CAPACITY_PLANES = frozenset({
+    "cw_cu", "cw_ci", "cw_ct", "cw_cf", "cw_cs",
+    "cw_h8", "cw_hx", "cw_hxh", "cw_hf", "cw_hs",
+})
 
 # State pytree:
 # {"tables": {name: {"param": [T,D], <aux>: [T,D]...}},
@@ -146,7 +162,16 @@ def init_state(model: Model, optimizer: Optimizer, cfg: Config, mesh) -> State:
     """
     from xflow_tpu.parallel.mesh import replicated
 
-    sharding = table_sharding(mesh)
+    # On one device the step hands the tables back replicated: the
+    # partitioner drops an axis of size 1.  Placed like that from the
+    # start (as the scalar below is), a train program's text, and with
+    # it its compile-cache key, is the same whether its shapes are the
+    # process's first or a later one; rows over "data" here made the
+    # first program of every run one that no other run's order of
+    # shapes could have cached (PERF.md section 6, PR 32).
+    sharding = (
+        table_sharding(mesh) if mesh.devices.size > 1 else replicated(mesh)
+    )
     rng = jax.random.PRNGKey(cfg.seed)
     tables: dict[str, dict[str, jax.Array]] = {}
     for i, spec in enumerate(model.tables()):
@@ -565,6 +590,10 @@ class TrainStep:
             )
         self.compact_wire = cfg.wire_mode != "full" and compact_ok
         self._compact_validated = False
+        # the longest each flat plane of the dictionary wire has been
+        # shipped at (_settle_planes)
+        self._plane_lengths: dict[tuple[int, str], int] = {}
+        self._plane_lengths_lock = threading.Lock()
         # Hot-path implementation (ops/hot.py): one-hot MXU matmuls on
         # TPU, gather + segment-sum elsewhere (Config.hot_impl) — the
         # MXU trick measured 3.3x SLOWER than the gather on the CPU
@@ -687,21 +716,27 @@ class TrainStep:
         )
 
     def _book_wire(
-        self, nbytes: int, examples: int, cb=None, cold_slots: int = 0
+        self, nbytes: int, examples: int, cb=None, cold_slots: int = 0,
+        slots_bytes: int = 0,
     ) -> None:
         """Wire accounting counters behind the trainer's per-epoch
         ``wire`` metrics row (obs/schema.py): bytes that crossed the
-        link, examples they carried, and — dict wire — the cold
+        link, examples they carried, how many of the bytes were the
+        planes of field ids (``slots_bytes``: _SLOT_PLANES, shipped for
+        a model that reads them), and — dict wire — the cold
         occurrence/unique-touch compaction the host performed.  Beside
         them, from shapes, what the batch asks of the [T, D] tables:
         its ``cold_slots`` padded cold slots (B * max_nnz) and the
         indices its cold gather hands the table, which are the
         capacities of the dictionary and the tail where the step reads
         a dictionary-wire batch's ``cold_plan`` (_cold_rows) and the
-        padded slots everywhere else."""
+        padded slots everywhere else.  (The batch's OWN capacities: a
+        batch that _settle_planes lengthened ships, and gathers, up to
+        a granule more of padding a plane.)"""
         self.obs.counter("wire.bytes", nbytes)
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")
+        self.obs.counter("wire.slots_bytes", slots_bytes)
         if cb is not None:
             self.obs.counter("wire.cold_occ", cb.n_cold)
             self.obs.counter("wire.cold_touched", cb.cold_touched)
@@ -712,6 +747,34 @@ class TrainStep:
                 "wire.table_gather_indices",
                 len(cb.cu) + len(cb.ct) if through_dict else cold_slots,
             )
+
+    def _settle_planes(self, wire: dict) -> dict:
+        """Zero-pad each flat plane of a dictionary-wire batch up to the
+        longest this step has shipped it at.  A plane's length is its
+        content rounded up to a granule (io/compact.py::plane_cap), and
+        a count that sits on a granule's edge flips between two lengths
+        from batch to batch: two train programs, each minutes of
+        compiling at a wide table (the tail plane of the benchmark's MVM
+        cell: 262 700 +- 900 entries on a 262 144 edge; PERF.md section
+        6, PR 32).  Entries beyond a plane's count are never used (the
+        decode walks the per-row counts, expand_dict_wire; the cold-row
+        gather reads the table's row 0 for a padded dictionary or tail
+        entry and no occurrence points at it), so a longer plane is the
+        same batch; once the longer form has been met every batch takes
+        it, and the step compiles for it alone."""
+        rows = len(wire["cw_cc"])  # a batch of another size: shapes of its own
+        with self._plane_lengths_lock:
+            for name in _CAPACITY_PLANES & wire.keys():
+                plane = wire[name]
+                longest = self._plane_lengths.get((rows, name), 0)
+                if len(plane) < longest:
+                    wire[name] = np.pad(
+                        plane,
+                        [(0, longest - len(plane))] + [(0, 0)] * (plane.ndim - 1),
+                    )
+                else:
+                    self._plane_lengths[rows, name] = len(plane)
+        return wire
 
     def _dict_geometry_ok(self, batch) -> bool:
         """A batch rides the dict wire only at the loader geometry the
@@ -761,14 +824,15 @@ class TrainStep:
             # pre-compacted (packed-cache v2 records): plane collection
             # only — zero per-batch host work
             if self.dict_wire and self._dict_geometry_ok(batch):
-                return batch.wire(self._ship_slots), batch
+                wire = batch.wire(self._ship_slots)
+                return self._settle_planes(wire), batch
             batch = batch.expand()
         if self.dict_wire and self._dict_geometry_ok(batch):
             cb = CompactBatch.from_batch(
                 batch, self.cfg.table_size, self.cfg.hot_size,
                 check=check,
             )
-            return cb.wire(self._ship_slots), cb
+            return self._settle_planes(cb.wire(self._ship_slots)), cb
         if self.compact_wire:
             return compact_wire_np(
                 _checked(batch, check),
@@ -846,6 +910,9 @@ class TrainStep:
             batch.num_real(),
             cb=cb,
             cold_slots=batch.batch_size * batch.max_nnz,
+            slots_bytes=sum(
+                int(v.nbytes) for k, v in wire.items() if k in _SLOT_PLANES
+            ),
         )
         arrays = {k: jnp.asarray(v) for k, v in wire.items()}
         if jax.process_count() > 1:

@@ -1070,6 +1070,13 @@ class Trainer:
                 "compaction_ratio": round(
                     occ_in / touched if touched else 1.0, 3
                 ),
+                # of those bytes, the planes of field ids (0 where the
+                # wire ships none: a model that reads no field)
+                "slots_bytes_per_example": round(
+                    snap.counters.get("wire.slots_bytes", 0)
+                    / max(snap.counters.get("wire.examples", 0), 1),
+                    2,
+                ),
             }
             if "wire.cold_slots" in snap.counters:
                 # what the batches asked of the [T, D] tables, a batch,
