@@ -14,6 +14,18 @@ sum, and the milliseconds of a call (``--calls`` chained calls closed by one
 fetch), forward and, for the families that differentiate through it, the
 transpose with respect to the rows.
 
+``--pick`` probes the contraction's transpose as MVM's backward uses it
+(PERF.md section 6, PR 33) and nothing else: each entry's own field's row
+of ``1 + s`` [B, F, E = 10], by ``take_along_axis`` (one index an entry)
+against ``models/blocks.py::field_pick`` (``"bkf,bfe->bke"`` at HIGHEST)
+and the same einsum at default precision: the milliseconds of a call and
+the largest absolute difference from ``take_along_axis`` (0 for HIGHEST:
+the one-hot is 0/1 and the bfloat16 pieces of the other operand add back
+to it; ~4e-3 at default, 8 bits of a number near 1).  Every tenth slot
+is outside [0, F): the gather clips it, the contraction reads 0, and the
+comparison is over the slots in range, as ``grad_logit`` masks the rest.
+Exits 1 where HIGHEST differs.
+
 A measurement path: exits 1 without a TPU; every line names the device.
 """
 
@@ -39,6 +51,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--seeds", type=int, default=3)
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--rows", type=int, default=16384)
+    ap.add_argument("--pick", action="store_true")
     args = ap.parse_args(argv)
 
     import jax
@@ -69,6 +82,9 @@ def main(argv: list[str] | None = None) -> int:
             carry = step(carry, *operands)
         carry.block_until_ready()
         return (time.perf_counter() - t0) / args.calls * 1e3
+
+    if args.pick:
+        return probe_pick(args, dev, timed)
 
     for block, e in SHAPES.items():
         for seed in range(1, args.seeds + 1):
@@ -103,6 +119,58 @@ def main(argv: list[str] | None = None) -> int:
                     )
             print(json.dumps(line), flush=True)
     return 0
+
+
+def probe_pick(args, dev, timed) -> int:
+    import jax
+    import jax.numpy as jnp
+
+    from xflow_tpu.models.blocks import field_pick
+
+    e = SHAPES["mvm_slot_terms"]
+
+    def onehot(slots, one_plus):
+        return jax.nn.one_hot(slots, F, dtype=one_plus.dtype)
+
+    def gather(slots, one_plus):
+        idx = jnp.clip(slots, 0, F - 1)
+        return jnp.take_along_axis(one_plus, idx[:, :, None], axis=1)
+
+    forms = {
+        "take_along_axis": gather,
+        "default": lambda s, o: jnp.einsum("bkf,bfe->bke", onehot(s, o), o),
+        "highest": lambda s, o: field_pick(onehot(s, o), o),
+    }
+    differs = False
+    for seed in range(1, args.seeds + 1):
+        rng = np.random.default_rng(seed)
+        slots = rng.integers(0, F, (args.rows, K)).astype(np.int32)
+        outside = rng.random((args.rows, K)) < 0.1
+        slots[outside] = rng.choice([-3, -1, F, F + 7], int(outside.sum()))
+        # 1 + a field's sum of a few N(0, 1) * 1e-2 rows
+        one_plus = (
+            1.0 + rng.normal(0.0, 2e-2, (args.rows, F, e))
+        ).astype(np.float32)
+        sl, op = jnp.asarray(slots), jnp.asarray(one_plus)
+        line = {
+            "platform": dev.platform, "kind": dev.device_kind,
+            "block": "mvm_pick", "shape": [args.rows, K, F, e], "seed": seed,
+        }
+        want = None
+        for name, fn in forms.items():
+            got = np.asarray(jax.jit(fn)(sl, op))
+            if want is None:
+                want, line[name] = got, {}
+            else:
+                line[name] = {
+                    "max_abs_diff": float(np.abs(got - want)[~outside].max()),
+                    "outside_max_abs": float(np.abs(got[outside]).max()),
+                }
+            if seed == 1:
+                line[name]["ms"] = timed(fn, sl, op)
+        differs |= line["highest"]["max_abs_diff"] != 0.0
+        print(json.dumps(line), flush=True)
+    return 1 if differs else 0
 
 
 if __name__ == "__main__":

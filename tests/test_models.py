@@ -135,6 +135,92 @@ def test_mvm_ignores_out_of_range_fields():
     )
 
 
+# -- MVM's backward picks by contraction: bit for bit the gather it replaced ---
+#
+# models/mvm.py::grad_logit selects each entry's own ``1 + slotsum`` by
+# contracting the forward's one-hot with it (blocks.field_pick), where
+# the frozen copy (tests/_legacy_models.py) gathers it by index
+# (take_along_axis).  A 0/1 operand makes the contraction a selection:
+# the same float32 number, so the same gradient, bit for bit.
+
+_PICK_B, _PICK_K = 48, 8 + 32  # the benchmark cell's 8 cold + 32 hot entries
+_PICK_CASES = ("in_range", "outside", "masked", "guard", "mixed")
+
+
+def _pick_case(case: str, fields: int):
+    rng = np.random.default_rng([fields, _PICK_CASES.index(case)])
+    slots = rng.integers(0, fields, (_PICK_B, _PICK_K)).astype(np.int32)
+    mask = np.ones((_PICK_B, _PICK_K), np.float32)
+    vals = rng.normal(1, 0.3, (_PICK_B, _PICK_K)).astype(np.float32)
+    v = rng.normal(0, 0.5, (_PICK_B, _PICK_K, 10)).astype(np.float32)
+    if case in ("outside", "mixed"):
+        outside = rng.random(slots.shape) < 0.3
+        slots[outside] = rng.choice(
+            [-7, -1, fields, fields + 1, 255], int(outside.sum())
+        )
+        assert (slots < 0).any() and (slots >= fields).any()
+    if case in ("masked", "mixed"):
+        mask = (rng.random(slots.shape) < 0.7).astype(np.float32)
+    if case in ("guard", "mixed"):
+        # a field whose only entry is v = -1 at x = 1: 1 + s is 0, under
+        # the 1e-12 guard, in every other row's first five factors
+        slots[slots == 3] = 4
+        slots[:, 0], vals[:, 0], mask[:, 0] = 3, 1.0, 1.0
+        v[::2, 0, :5] = -1.0
+    batch = {
+        "slots": jnp.asarray(slots), "vals": jnp.asarray(vals),
+        "mask": jnp.asarray(mask),
+    }
+    return batch, jnp.asarray(v)
+
+
+@pytest.mark.parametrize("fields", (32, 40))
+@pytest.mark.parametrize("case", _PICK_CASES)
+def test_mvm_grad_picks_by_contraction_bitwise(case, fields):
+    from tests._legacy_models import LegacyMVMModel
+
+    batch, v = _pick_case(case, fields)
+    new = MVMModel(v_dim=10, max_fields=fields)
+    old = LegacyMVMModel(v_dim=10, max_fields=fields)
+    want = np.asarray(jax.jit(old.grad_logit)({"v": v}, batch)["v"])
+    got = np.asarray(jax.jit(new.grad_logit)({"v": v}, batch)["v"])
+    bits = lambda a: np.asarray(a).view(np.uint32)  # noqa: E731
+    np.testing.assert_array_equal(bits(got), bits(want))
+    np.testing.assert_array_equal(
+        bits(new.grad_logit({"v": v}, batch)["v"]), bits(want)  # op by op
+    )
+    assert np.isfinite(want).all() and (want != 0).mean() > 0.3
+    slots = np.asarray(batch["slots"])
+    gone = (slots < 0) | (slots >= fields) | (np.asarray(batch["mask"]) == 0)
+    assert (got[gone] == 0).all()
+    if case in ("guard", "mixed"):
+        assert (got[::2, 0, :5] == 0).all()  # the guarded entry
+        assert (got[1::2, 0, :] != 0).all() and (got[::2, 0, 5:] != 0).all()
+        one_plus = np.asarray(new._slot_terms({"v": v}, batch)[0])
+        assert (np.abs(one_plus[::2, 3, :5]) < 1e-12).all()
+
+
+@pytest.mark.parametrize("fields", (32, 40))
+def test_field_pick_is_the_gather(fields):
+    """blocks.field_pick alone: the row of each entry's own field, 0 for
+    an all-zero one-hot row (a field id outside [0, F))."""
+    from xflow_tpu.models.blocks import field_pick
+
+    rng = np.random.default_rng(fields)
+    slots = rng.integers(-2, fields + 2, (_PICK_B, _PICK_K)).astype(np.int32)
+    per_field = (1.0 + rng.normal(0, 2e-2, (_PICK_B, fields, 10))).astype(
+        np.float32
+    )
+    onehot = jax.nn.one_hot(jnp.asarray(slots), fields, dtype=jnp.float32)
+    got = np.asarray(jax.jit(field_pick)(onehot, jnp.asarray(per_field)))
+    inside = (slots >= 0) & (slots < fields)
+    want = np.take_along_axis(
+        per_field, np.clip(slots, 0, fields - 1)[:, :, None], axis=1
+    )
+    np.testing.assert_array_equal(got[inside], want[inside])
+    assert (got[~inside] == 0).all() and (~inside).any()
+
+
 # -- blocks refactor: bitwise no-regression vs the frozen legacy oracles ------
 #
 # The refactor's contract (docs/SERVING.md cascade PR): expressing the

@@ -234,12 +234,21 @@ def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
     rows x 10, B=131072, 8 + 32 slots, 40 fields, the dictionary wire's
     plane capacities of one real batch, seed 1) for a described v5e.
     Lowered: every contraction asks for float32 (Precision.HIGHEST), the
-    one-hot field contraction of models/blocks.py::field_contract among
-    them, [B, K, F] x [B, K, D] in the forward and again in the backward:
-    at default precision the TPU rounds its operands to bfloat16 and the
-    step misses the reference (PERF.md section 6, PR 31).  Compiled: the
-    program fits the chip with the room the file's ``reduced`` argues from
-    (9.22 GiB of 15.75; at 2^26 rows the compiler refuses it)."""
+    one-hot field contractions of models/blocks.py among them:
+    ``field_contract`` [B, K, F] x [B, K, D] over K, in ``logit`` and
+    again in ``grad_logit``, and ``field_pick`` [B, K, F] x [B, F, D]
+    over F, the backward's pick of each entry's own factor (PR 33).  At
+    this geometry K = F = 40, so all three have the same operand types
+    and only their contracting dimensions tell them apart.  At default
+    precision the TPU rounds the operands to bfloat16: the field sums
+    miss the reference (PERF.md section 6, PR 31), and a picked
+    ``1 + s`` would keep 8 bits.  Compiled: XLA has not turned the pick
+    back into the gather it replaced (one index per entry, 5.2 M of
+    them, 112 ms of the parent's 390 ms step: no gather brings a
+    [B, K, D] result out of a [B, F, D] operand), and the program fits
+    the chip with the room the file's ``reduced`` argues from (9.19 GiB
+    of 15.75, 9.22 with the gather; at 2^26 rows the compiler refuses
+    it)."""
     from benchmarks.harness import manifest
     from xflow_tpu.config import Config
     from xflow_tpu.models import make_model
@@ -297,9 +306,19 @@ def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
         line for line in dots
         if f"({onehot}, tensor<{b}x{k}x{cfg.v_dim}xf32>)" in line
     ]
-    assert len(by_field) == 2, dots  # the forward's, the backward's
+    over_k = [line for line in by_field if "contracting_dims = [1] x [1]" in line]
+    over_f = [line for line in by_field if "contracting_dims = [2] x [1]" in line]
+    # the sum by field in logit and in grad_logit; grad_logit's pick
+    assert (len(by_field), len(over_k), len(over_f)) == (3, 2, 1), dots
     assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
-    ma = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    per_entry = (f"f32[{b * k},{cfg.v_dim}]", f"f32[{b},{k},{cfg.v_dim}]")
+    picks = [
+        line for line in _gather_lines(compiled)
+        if line.split("=", 1)[1].strip().startswith(per_entry)
+    ]
+    assert not picks, picks
+    ma = compiled.memory_analysis()
     peak = (
         ma.argument_size_in_bytes + ma.temp_size_in_bytes
         + ma.output_size_in_bytes - ma.alias_size_in_bytes

@@ -81,6 +81,23 @@ def field_contract(onehot: jax.Array, rows: jax.Array) -> jax.Array:
     )
 
 
+def field_pick(onehot: jax.Array, per_field: jax.Array) -> jax.Array:
+    """``sum_f onehot[b, k, f] * per_field[b, f, e]`` -> [B, K, E]: each
+    entry's own field's row of ``per_field``, as ``field_contract``'s
+    transpose: one batch matmul contracting F where a gather would take
+    one index per entry (the TPU prices a gather per INDEX: PERF.md
+    section 6, PR 33).  An entry whose one-hot row is all zero (a field
+    id outside [0, F)) gets 0.  HIGHEST makes this a pick and not an
+    approximation of one: the one-hot is exact in bfloat16, and the
+    bfloat16 pieces HIGHEST splits ``per_field`` into add back, in
+    float32, to the number itself: ``take_along_axis`` bit for bit (but
+    a -0.0 comes back +0.0).  At default precision the TPU rounds
+    ``per_field`` to bfloat16."""
+    return jnp.einsum(
+        "bkf,bfe->bke", onehot, per_field, precision=jax.lax.Precision.HIGHEST
+    )
+
+
 # -- embedding tower ----------------------------------------------------------
 
 
@@ -206,10 +223,13 @@ def mvm_slot_terms(
     x: jax.Array,
     slots: jax.Array,
     num_fields: int,
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """MVM per-factor view products: ``(1 + slotsum [B, S, D],
-    prod over S [B, D])`` in the fixed consistent 1+sum form
-    (models/mvm.py docstring; mvm_worker.cc:67-95)."""
+    prod over S [B, D], the one-hot [B, K, S] that summed them)`` in
+    the fixed consistent 1+sum form (models/mvm.py docstring;
+    mvm_worker.cc:67-95).  The one-hot goes back to the caller so the
+    backward picks with the one the forward summed with
+    (``field_pick``)."""
     onehot = jax.nn.one_hot(
         slots, num_fields, dtype=x.dtype
     )  # [B, K, S]; fgid >= num_fields rows are all-zero → feature ignored
@@ -217,7 +237,7 @@ def mvm_slot_terms(
     slotsum = field_contract(onehot, vx)  # [B, S, D]
     one_plus = 1.0 + slotsum
     prod = jnp.prod(one_plus, axis=1)  # [B, D]
-    return one_plus, prod
+    return one_plus, prod, onehot
 
 
 def ffm_field_interaction(

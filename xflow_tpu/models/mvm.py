@@ -35,6 +35,20 @@ fgid seen (mvm_worker.cc:225-243); under static shapes fields are fixed
 to ``max_fields`` and features with fgid >= max_fields are ignored
 (config.max_fields).  MVM uses only the v table (store 1,
 mvm_worker.h:38); v rows init N(0,1)*1e-2 like FM.
+
+The backward's ``1 + slotsum_{s(i),d}`` of each entry is selected by
+contracting the forward's one-hot [B, K, S] with ``1 + slotsum``
+[B, S, D] on the MXU (blocks.field_pick), not gathered by index: the
+TPU prices a gather per index, and B*K = 5.2 M of them were 112 of
+the benchmark cell's 390 ms step (PERF.md section 6, PR 33), where the
+contraction pays per field what the forward already pays.  It is the
+same float32 number, not a nearby one, because the contraction asks
+for Precision.HIGHEST: one operand is 0/1, so the bfloat16 pieces of
+the other add back to the value itself.  That precision is not
+optional: at the TPU's default a float32 dot rounds ``1 + s`` to
+bfloat16, 8 bits of a number that is 1.00x, and the gradient is
+garbage.  benchmarks/reference/mvm.py keeps its gather by index and
+judges this form on the chip.
 """
 
 from __future__ import annotations
@@ -45,7 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from xflow_tpu.models.base import BatchArrays, TableSpec
-from xflow_tpu.models.blocks import masked_x, mvm_slot_terms
+from xflow_tpu.models.blocks import field_pick, masked_x, mvm_slot_terms
 
 _GUARD_EPS = 1e-12
 
@@ -72,15 +86,16 @@ class MVMModel:
 
     def _slot_terms(
         self, rows: dict[str, jax.Array], batch: BatchArrays
-    ) -> tuple[jax.Array, jax.Array]:
-        """Returns (one_plus_slotsum [B, S, D], prod over S [B, D]) —
-        blocks.mvm_slot_terms, bitwise the pre-refactor expression."""
+    ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """Returns (one_plus_slotsum [B, S, D], prod over S [B, D],
+        the one-hot [B, K, S]) — blocks.mvm_slot_terms, bitwise the
+        pre-refactor expression."""
         return mvm_slot_terms(
             rows["v"], masked_x(batch), batch["slots"], self.max_fields
         )
 
     def logit(self, rows: dict[str, jax.Array], batch: BatchArrays) -> jax.Array:
-        _, prod = self._slot_terms(rows, batch)
+        _, prod, _ = self._slot_terms(rows, batch)
         # centered: remove the structural +v_dim baseline (docstring)
         return jnp.sum(prod - 1.0, axis=-1)
 
@@ -88,13 +103,10 @@ class MVMModel:
         self, rows: dict[str, jax.Array], batch: BatchArrays
     ) -> dict[str, jax.Array]:
         x = masked_x(batch)  # [B, K]
-        one_plus, prod = self._slot_terms(rows, batch)
-        slot_idx = jnp.clip(batch["slots"], 0, self.max_fields - 1)  # [B, K]
-        own = jnp.take_along_axis(
-            one_plus,
-            slot_idx[:, :, None],  # [B, K, 1] indexing axis 1 (S); broadcasts over D
-            axis=1,
-        )  # [B, K, D]
+        one_plus, prod, onehot = self._slot_terms(rows, batch)
+        # each entry's own field factor; 0 for a slot outside
+        # [0, max_fields), whose one-hot row is all zero
+        own = field_pick(onehot, one_plus)  # [B, K, D]
         safe = jnp.where(jnp.abs(own) < _GUARD_EPS, 1.0, own)
         grad_v = jnp.where(
             jnp.abs(own) < _GUARD_EPS,
@@ -103,9 +115,8 @@ class MVMModel:
         ) * x[..., None]
         # match the forward's one-hot semantics exactly: slots outside
         # [0, max_fields) contribute nothing there (zero one-hot row),
-        # so they must get zero gradient here too — without the >= 0
-        # arm, a negative slot was ignored in the forward but trained
-        # as field 0 (the clip above) in the backward
+        # so they must get zero gradient here too (the guard above
+        # already zeroes their all-zero pick; the mask says why)
         valid = (
             (batch["slots"] >= 0) & (batch["slots"] < self.max_fields)
         )[..., None]
