@@ -1143,3 +1143,57 @@ def test_wire_row_says_what_the_field_ids_cost(
         assert floor <= row["slots_bytes_per_example"] < row["wire_bytes_per_example"]
     else:
         assert row["slots_bytes_per_example"] == 0
+
+
+@pytest.mark.parametrize("model, widths, plain", [
+    ("lr", {"w": 1}, []),
+    ("fm", {"w": 1, "v": 10}, []),
+    ("mvm", {"v": 10}, []),
+    ("ffm", {"w": 1, "v": 16}, ["v"]),
+])
+def test_wire_row_counts_the_table_rows_a_step_moves(
+    toy_dataset, tmp_path, model, widths, plain
+):
+    """The epoch's ``wire`` row carries, a batch and from shapes
+    (TrainStep._book_wire): ``gather_row_bytes_per_step``, a row of every
+    table per index the cold gather hands it; ``scatter_row_bytes_per_step``,
+    a row read and a row written per padded cold slot; and in both the hot
+    slots of a table that opted out of the MXU head (FFM's v), which
+    ``plain_hot_slots_per_step`` counts: B x hot_nnz for FFM, 0 for the
+    families whose every table rides the head."""
+    from xflow_tpu.obs.schema import validate_rows
+
+    b, kc, kh = 64, 24, 8
+    out = tmp_path / "m.jsonl"
+    cfg = _toy_cfg(
+        toy_dataset, model=model, epochs=1, metrics_out=str(out),
+        batch_size=b, max_nnz=kc, hot_size_log2=6, hot_nnz=kh, max_fields=4,
+    )
+    with Trainer(cfg) as t:
+        assert t.step.wire_format == "dict"
+        assert {s.name: s.dim for s in t.step.model.tables()} == widths
+        assert [n for n, mxu in t.step._mxu_hot.items() if not mxu] == plain
+        caps = []
+        book = t.step._book_wire
+
+        def spy(nbytes, examples, cb=None, **shapes):
+            caps.append(len(cb.cu) + len(cb.ct))
+            book(nbytes, examples, cb=cb, **shapes)
+
+        t.step._book_wire = spy
+        t.train()
+    rows = [json.loads(line) for line in out.read_text().splitlines()]
+    assert validate_rows(rows) == []
+    row = next(r for r in rows if r["kind"] == "wire")
+    # by hand: float32 rows; the head's own traffic is not table rows
+    row_bytes = 4 * sum(widths.values())
+    plain_bytes = 4 * sum(widths[n] for n in plain)
+    assert row["plain_hot_slots_per_step"] == b * kh * len(plain)
+    assert row["padded_cold_slots_per_step"] == b * kc
+    assert row["scatter_row_bytes_per_step"] == 2 * (
+        b * kc * row_bytes + b * kh * plain_bytes
+    )
+    assert row["gather_row_bytes_per_step"] == round(
+        sum(caps) / len(caps) * row_bytes + b * kh * plain_bytes
+    )
+    assert row["gather_row_bytes_per_step"] < row["scatter_row_bytes_per_step"]

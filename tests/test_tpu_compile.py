@@ -226,6 +226,61 @@ def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
     assert len(found) <= 4 * 2 + 2 + 3 + 2, found  # + the reduce-scatters' fix-ups
 
 
+def _lowered_cell_step(topo, config: str, planes: dict):
+    """The train step of a one-chip benchmark configuration
+    (benchmarks/configs/<config>.json) lowered for a described v5e, its
+    dictionary-wire batch given as plane shapes (``planes``: name ->
+    (shape, dtype), the capacities of one real batch)."""
+    from benchmarks.harness import manifest
+    from xflow_tpu.config import Config
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel import mesh as meshes
+    from xflow_tpu.parallel.step import TrainStep
+
+    doc = manifest.config_file(f"benchmarks/configs/{config}.json")
+    cfg = Config(**{
+        k: v for k, v in manifest.apply_rehearsal(doc, False).items()
+        if k not in manifest.CONFIG_META
+    })
+    mesh = meshes.make_mesh(1, devices=list(topo.devices))
+    model = make_model(cfg)
+    step = TrainStep(model, make_optimizer(cfg), cfg, mesh)
+    assert step.wire_format == "dict" and step._ship_slots
+    assert step._hot_impl == "mxu"
+
+    def shaped(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    rows = meshes.table_sharding(mesh)
+    state = {
+        "tables": {
+            spec.name: {
+                name: shaped((cfg.table_size, spec.dim), jnp.float32, rows)
+                for name in ("param", "n", "z")
+            }
+            for spec in model.tables()
+        },
+        "dense": {},
+        "step": shaped((), jnp.int32, meshes.replicated(mesh)),
+    }
+    batch = {
+        k: shaped(shape, dtype, step._bsharding)
+        for k, (shape, dtype) in planes.items()
+    }
+    return cfg, step, step.train.lower(state, batch)
+
+
+def _program_peak(compiled) -> int:
+    """Bytes the program needs on the device: the donated state's outputs
+    take no room of their own."""
+    ma = compiled.memory_analysis()
+    return (
+        ma.argument_size_in_bytes + ma.temp_size_in_bytes
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    )
+
+
 def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
     topo, no_compile_cache
 ):
@@ -249,54 +304,17 @@ def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
     the chip with the room the file's ``reduced`` argues from (9.19 GiB
     of 15.75, 9.22 with the gather; at 2^26 rows the compiler refuses
     it)."""
-    from benchmarks.harness import manifest
-    from xflow_tpu.config import Config
-    from xflow_tpu.models import make_model
-    from xflow_tpu.optim import make_optimizer
-    from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
-    from xflow_tpu.parallel.step import TrainStep
-
-    doc = manifest.config_file("benchmarks/configs/mvm_ftrl_criteo_tb.json")
-    cfg = Config(**{
-        k: v for k, v in manifest.apply_rehearsal(doc, False).items()
-        if k not in manifest.CONFIG_META
-    })
-    mesh = make_mesh(1, devices=list(topo.devices))
-    model = make_model(cfg)
-    step = TrainStep(model, make_optimizer(cfg), cfg, mesh)
-    assert step.wire_format == "dict" and step._ship_slots
-    assert step._hot_impl == "mxu"
-
-    def shaped(shape, dtype, sharding):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
     u8, u16, u32 = np.uint8, np.uint16, np.uint32
-    planes = {
-        "cw_cu": (43008, u32), "cw_cun": (1, np.int32),
-        "cw_ci": (688128, u16), "cw_ct": (262144, u32),
-        "cw_cf": (118784, u8), "cw_cc": (131072, u8),
-        "cw_lb": (16384, u8), "cw_wb": (16384, u8),
-        "cw_h8": (2490368, u8), "cw_hx": (1835008, u16),
-        "cw_hxh": (0, u8), "cw_hf": (524288, u8), "cw_hc": (131072, u8),
-        "cw_cs": (950272, u8), "cw_hs": (4194304, u8),
-    }
-    state = {
-        "tables": {
-            spec.name: {
-                name: shaped(
-                    (cfg.table_size, spec.dim), jnp.float32, table_sharding(mesh)
-                )
-                for name in ("param", "n", "z")
-            }
-            for spec in model.tables()
-        },
-        "dense": {},
-        "step": shaped((), jnp.int32, replicated(mesh)),
-    }
-    batch = {
-        k: shaped((n,), dtype, step._bsharding) for k, (n, dtype) in planes.items()
-    }
-    lowered = step.train.lower(state, batch)
+    cfg, _, lowered = _lowered_cell_step(topo, "mvm_ftrl_criteo_tb", {
+        "cw_cu": ((43008,), u32), "cw_cun": ((1,), np.int32),
+        "cw_ci": ((688128,), u16), "cw_ct": ((262144,), u32),
+        "cw_cf": ((118784,), u8), "cw_cc": ((131072,), u8),
+        "cw_lb": ((16384,), u8), "cw_wb": ((16384,), u8),
+        "cw_h8": ((2490368,), u8), "cw_hx": ((1835008,), u16),
+        "cw_hxh": ((0,), u8), "cw_hf": ((524288,), u8),
+        "cw_hc": ((131072,), u8),
+        "cw_cs": ((950272,), u8), "cw_hs": ((4194304,), u8),
+    })
     dots = [
         line for line in lowered.as_text().splitlines() if "dot_general" in line
     ]
@@ -318,9 +336,60 @@ def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
         if line.split("=", 1)[1].strip().startswith(per_entry)
     ]
     assert not picks, picks
-    ma = compiled.memory_analysis()
-    peak = (
-        ma.argument_size_in_bytes + ma.temp_size_in_bytes
-        + ma.output_size_in_bytes - ma.alias_size_in_bytes
-    )
+    peak = _program_peak(compiled)
     assert 9.0 * (1 << 30) < peak < 9.5 * (1 << 30), peak
+
+
+def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
+    topo, no_compile_cache
+):
+    """The FFM train step at the geometry of the benchmark's
+    ffm_tb.train_packed (benchmarks/configs/ffm_ftrl_criteo_tb.json: 2^21
+    rows, w of one column and v of 40 fields x 4 = 160, B=16384, 8 + 32
+    slots, the dictionary wire's plane capacities of one real batch, seed
+    1) for a described v5e.  Lowered: the field contraction [B, K, F] x
+    [B, K, 160] over K (blocks.field_contract) and its transpose, which
+    autodiff writes
+    (grads_from_rows pulls the residual back through the logit), both ask
+    for float32 (Precision.HIGHEST), as does every other dot; w's hot
+    occurrences go through the MXU head (ops/hot.py: its one-hot matmuls,
+    no gather of w by the hot plane) and v's, whose table opts out of it
+    (TableSpec.hot=False), are one plain gather of table rows by the
+    [B, hot_nnz] plane.  Compiled: the program fits with the room the
+    file's ``reduced`` argues from, 13.94 GiB of 15.75 (at 2^22 rows the
+    compiler refuses it).  Most of that is layout (PERF.md section 6,
+    PR 34): v's state comes in rows-minor and is copied to columns-minor
+    and back inside the step, and the dictionary route lays a 160-column
+    row out as 160 padded [B, max_nnz, 1] planes."""
+    u8, u16 = np.uint8, np.uint16
+    cfg, step, lowered = _lowered_cell_step(topo, "ffm_ftrl_criteo_tb", {
+        "cw_cu": ((53248, 3), u8), "cw_cun": ((1,), np.int32),
+        "cw_ci": ((118784,), u16), "cw_ct": ((0, 3), u8),
+        "cw_cf": ((14848,), u8), "cw_cc": ((16384,), u8),
+        "cw_lb": ((2048,), u8), "cw_wb": ((2048,), u8),
+        "cw_h8": ((311296,), u8), "cw_hx": ((229376,), u16),
+        "cw_hxh": ((0,), u8), "cw_hf": ((65536,), u8),
+        "cw_hc": ((16384,), u8),
+        "cw_cs": ((118784,), u8), "cw_hs": ((524288,), u8),
+    })
+    assert step._mxu_hot == {"w": True, "v": False}
+    text = lowered.as_text().splitlines()
+    b, k, f = cfg.batch_size, cfg.max_nnz + cfg.hot_nnz, cfg.max_fields
+    t, e = cfg.table_size, f * cfg.ffm_v_dim
+    onehot, rows = f"tensor<{b}x{k}x{f}xf32>", f"tensor<{b}x{k}x{e}xf32>"
+    dots = [line for line in text if "dot_general" in line]
+    forward = [line for line in dots if f"({onehot}, {rows})" in line]
+    transposed = [line for line in dots if f"({rows}, {onehot})" in line]
+    head = [line for line in dots if f"x{e}x" not in line]
+    assert (len(forward), len(transposed)) == (1, 1), dots
+    assert len(head) == len(dots) - 2 >= 2, dots  # w's one-hot scans
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
+    by_hot_plane = [
+        line for line in text
+        if "stablehlo.gather" in line
+        and f"tensor<{b}x{cfg.hot_nnz}x1xi32>" in line
+    ]
+    assert len(by_hot_plane) == 1, by_hot_plane
+    assert f"(tensor<{t}x{e}xf32>, " in by_hot_plane[0]
+    peak = _program_peak(lowered.compile())
+    assert 13.5 * (1 << 30) < peak < 14.6 * (1 << 30), peak

@@ -13,7 +13,16 @@ max_fields contribute nothing (their one-hot row is zero), matching
 MVM's field handling.
 
 Pure autodiff model — no reference forward/backward quirks to
-reproduce.
+reproduce: the train step pulls the residual back through ``logit``
+(parallel/step.py::grads_from_rows).  What holds it to float32 on the
+TPU, where benchmarks/reference/ffm.py (the plain sum over pairs)
+judges it at libffm's widths (PERF.md section 6, PR 34): the one
+contraction of the forward, the field sums, asks for
+Precision.HIGHEST (blocks.field_contract), and so does its transpose,
+which autodiff writes with the forward's precision; the cross term
+and the diagonal are elementwise; and the residual is the step's own
+``sigmoid_ref(logit) - y``, not the derivative of a softplus loss,
+which the TPU computes to 7e-5 of the sigmoid.
 
 The pair interaction uses the field-aggregated identity (round-2
 restructure; the naive form materializes [B, K, K, D] pair tensors —

@@ -400,6 +400,14 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # B * max_nnz (parallel/step.py::_book_wire)
         "table_gather_indices_per_step": (int, float),
         "padded_cold_slots_per_step": (int, float),
+        # beside them, a batch and from shapes: the bytes of [T, D] table
+        # rows the step's gathers read and its scatter-adds read and
+        # write (the MXU head's own traffic left out), and the hot-plane
+        # slots, a table, that took the plain route because their table
+        # opted out of the head (TableSpec.hot=False: FFM's v)
+        "gather_row_bytes_per_step": (int, float),
+        "scatter_row_bytes_per_step": (int, float),
+        "plain_hot_slots_per_step": (int, float),
         # of wire_bytes_per_example, the planes of field ids (slots_u8 /
         # hot_slots_u8, the dictionary wire's cw_cs / cw_hs, the full
         # wire's slots / hot_slots): 0 where none ships, as for a model

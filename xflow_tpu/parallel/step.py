@@ -46,7 +46,12 @@ from xflow_tpu.ops.window import (
 )
 from xflow_tpu.optim.base import Optimizer
 from xflow_tpu.parallel import exchange
-from xflow_tpu.parallel.mesh import DATA_AXIS, batch_sharding, table_sharding
+from xflow_tpu.parallel.mesh import (
+    DATA_AXIS,
+    batch_sharding,
+    replicated,
+    table_sharding,
+)
 from xflow_tpu.utils.compile_cache import key_by_source
 from xflow_tpu.utils.metrics import logloss, logloss_sum, sigmoid_ref
 
@@ -111,29 +116,31 @@ def grads_from_rows(model, rows: dict, dense: dict, mbatch: BatchArrays,
     grad_dense_or_None); occ_grads are residual-scaled and divided by
     ``num_real``, the reference's mean-gradient semantics
     (lr_worker.cc:116-118)."""
-    if getattr(model, "autodiff", False):
-        # Autodiff path (FFM, wide&deep — no reference gradient
-        # quirks): stable BCE-with-logits; d/dlogit = sigmoid - y,
-        # the same residual semantics as the explicit path.
-        def loss_fn(rows_, dense_):
-            logit_ = model.logit(rows_, mbatch, dense_)
-            nll = jax.nn.softplus(logit_) - mbatch["labels"] * logit_
-            return (
-                jnp.sum(nll * mbatch["weights"]) / num_real,
-                logit_,
-            )
-
-        (_, logit), (grad_rows, grad_dense) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1), has_aux=True
-        )(rows, dense)
-        return sigmoid_ref(logit), grad_rows, (grad_dense or None)
-    logit = model.logit(rows, mbatch)
+    autodiff = getattr(model, "autodiff", False)
+    if autodiff:
+        # Autodiff arm (FFM, wide&deep, DCN, two-tower: no reference
+        # gradient quirks): the logit's pullback, handed the same
+        # residual as the written-out arm below.
+        logit, pullback = jax.vjp(
+            lambda rows_, dense_: model.logit(rows_, mbatch, dense_),
+            rows, dense,
+        )
+    else:
+        logit = model.logit(rows, mbatch)
     pctr = sigmoid_ref(logit)
     # Residual "loss" exactly as the reference names it
     # (lr_worker.cc:121-143): sigma(wx) - y, zeroed for pad
     # examples, pre-divided by batch size for the mean-gradient
-    # semantics.
+    # semantics.  ONE expression for both arms: the autodiff arm used
+    # to take it as the derivative of a softplus loss, which the TPU
+    # computes to 7e-5 of the sigmoid (rms 1.4e-5 over logits in
+    # [-3, 3]; this expression: 1.2e-6, rms 2.7e-7), so every gradient
+    # of the benchmark's FFM cell missed the reference by 1e-5 of its
+    # terms (PERF.md section 6, PR 34).
     residual = (pctr - mbatch["labels"]) * mbatch["weights"] / num_real
+    if autodiff:
+        grad_rows, grad_dense = pullback(residual)
+        return pctr, grad_rows, (grad_dense or None)
     grad_logit = model.grad_logit(rows, mbatch)
     occ_grads = {
         name: g * residual[:, None, None]
@@ -160,8 +167,6 @@ def init_state(model: Model, optimizer: Optimizer, cfg: Config, mesh) -> State:
     v-table random init reproduces the reference's lazy server-side
     N(0,1)*1e-2 (ftrl.h:113-120) eagerly; see optim/ftrl.py.
     """
-    from xflow_tpu.parallel.mesh import replicated
-
     # On one device the step hands the tables back replicated: the
     # partitioner drops an axis of size 1.  Placed like that from the
     # start (as the scalar below is), a train program's text, and with
@@ -562,6 +567,13 @@ class TrainStep:
         # Per-table MXU hot opt-out (TableSpec.hot): opted-out tables
         # keep their hot-plane occurrences on plain DMA gather/scatter.
         self._mxu_hot = {spec.name: spec.hot for spec in model.tables()}
+        # Bytes of one row of every table, and of one row of every
+        # opted-out table, of which there are _plain_hot_tables: what a
+        # slot moves (_book_wire).
+        self._row_bytes = sum(4 * spec.dim for spec in model.tables())
+        plain = [spec for spec in model.tables() if not spec.hot]
+        self._plain_hot_tables = len(plain)
+        self._plain_hot_row_bytes = sum(4 * spec.dim for spec in plain)
         # The hot-inner/opt-out conflict only exists when the hot inner
         # actually RUNS — update_mode must be 'sequential'.  In dense or
         # sparse mode sequential_inner is an unused knob (ffm + dense +
@@ -717,7 +729,7 @@ class TrainStep:
 
     def _book_wire(
         self, nbytes: int, examples: int, cb=None, cold_slots: int = 0,
-        slots_bytes: int = 0,
+        slots_bytes: int = 0, hot_slots: int = 0,
     ) -> None:
         """Wire accounting counters behind the trainer's per-epoch
         ``wire`` metrics row (obs/schema.py): bytes that crossed the
@@ -732,7 +744,17 @@ class TrainStep:
         a dictionary-wire batch's ``cold_plan`` (_cold_rows) and the
         padded slots everywhere else.  (The batch's OWN capacities: a
         batch that _settle_planes lengthened ships, and gathers, up to
-        a granule more of padding a plane.)"""
+        a granule more of padding a plane.)  And what those slots move,
+        in bytes of table rows: ``gather_row_bytes``, every index the
+        step's gathers hand a [T, D] table times that table's row, and
+        ``scatter_row_bytes``, a row read and a row written for every
+        padded slot its scatter-adds hand a [T, D] gradient buffer.
+        Both count the ``hot_slots`` (B * hot_nnz) of a table that opted
+        out of the MXU head (TableSpec.hot=False), whose hot occurrences
+        are plain table rows, and leave the head's own traffic out;
+        ``plain_hot_slots`` is those slots, a table: 0 where every table
+        rides the head.  A padded slot counts like a live one (the
+        gather reads row 0 for it; the scatter-add drops it)."""
         self.obs.counter("wire.bytes", nbytes)
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")
@@ -742,10 +764,19 @@ class TrainStep:
             self.obs.counter("wire.cold_touched", cb.cold_touched)
         if cold_slots:
             through_dict = cb is not None and self._whole_batch_gather
+            indices = len(cb.cu) + len(cb.ct) if through_dict else cold_slots
             self.obs.counter("wire.cold_slots", cold_slots)
+            self.obs.counter("wire.table_gather_indices", indices)
+            plain = hot_slots * self._plain_hot_row_bytes
             self.obs.counter(
-                "wire.table_gather_indices",
-                len(cb.cu) + len(cb.ct) if through_dict else cold_slots,
+                "wire.plain_hot_slots", hot_slots * self._plain_hot_tables
+            )
+            self.obs.counter(
+                "wire.gather_row_bytes", indices * self._row_bytes + plain
+            )
+            self.obs.counter(
+                "wire.scatter_row_bytes",
+                2 * (cold_slots * self._row_bytes + plain),
             )
 
     def _settle_planes(self, wire: dict) -> dict:
@@ -874,8 +905,6 @@ class TrainStep:
             + plan.miss_nbytes,
             batch.num_real(),
         )
-        from xflow_tpu.parallel.mesh import replicated
-
         # one direct host->device transfer per plane (a jnp.asarray
         # hop first would commit to the default device and pay a
         # second device-to-device reshard — on a path where the
@@ -910,6 +939,7 @@ class TrainStep:
             batch.num_real(),
             cb=cb,
             cold_slots=batch.batch_size * batch.max_nnz,
+            hot_slots=batch.batch_size * batch.hot_nnz,
             slots_bytes=sum(
                 int(v.nbytes) for k, v in wire.items() if k in _SLOT_PLANES
             ),

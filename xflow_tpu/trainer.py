@@ -1090,6 +1090,14 @@ class Trainer:
                 stats["_wire"]["padded_cold_slots_per_step"] = round(
                     snap.counters["wire.cold_slots"] / batches
                 )
+                # and what those slots move in bytes of table rows, with
+                # the hot slots of a table that opted out of the MXU head
+                for name in (
+                    "gather_row_bytes", "scatter_row_bytes", "plain_hot_slots"
+                ):
+                    stats["_wire"][f"{name}_per_step"] = round(
+                        snap.counters[f"wire.{name}"] / batches
+                    )
             if "exchange.bytes" in snap.counters:
                 # a mesh of more than one device: what the step's pull
                 # and push moved between the chips, from shapes
