@@ -1,15 +1,25 @@
 """Chip probe of the cold parameter gather (PERF.md section 6, PR 30): a
 row per padded slot out of the [T, D] table against a row per dictionary
 and tail entry plus a resolve out of the batch-sized rows, at the shapes
-of one real batch of ``lr_tb.train_packed``.
+of one real batch of a one-chip train cell.
 
     python scripts/probe_cold_gather.py [--seed N] [--calls 20]
+        [--config lr_ftrl_criteo_tb | ffm_ftrl_criteo_tb]
 
 The batch is the benchmark cell's own: its generator, remap and steering
 at ``--seed``, compacted by ``CompactBatch.from_batch``.  Each form is
 timed as ``--calls`` chained calls closed by one fetch: ms a call, ns an
-index.  The table is [2^28, 1] for D = 1 (the cell's) and [2^25, 10] for
-D = 10 (FM's width at a size one chip holds beside it; keys >> 3).
+index.  At the LR cell's geometry (the default) the table is [2^28, 1]
+for D = 1 (the cell's) and [2^25, 10] for D = 10 (FM's and MVM's width
+at a size one chip holds beside it; keys >> 3); at the FFM cell's, its
+own w [2^21, 1] and v [2^21, 160].
+
+The route lays the padded rows out of its two flat row streams column by
+column or row by row, by the row's width (step.py::dict_cold_rows,
+ROW_LAYOUT_MIN_COLUMNS).  The probe times the WHOLE route in both forms,
+each checked against ``param[keys]``, at the cell's widths and over a
+sweep of widths between them on a [2^20, D] table: where the two forms
+cross is where the constant belongs (PERF.md section 6, PR 35).
 
 A measurement path: exits 1 without a TPU, every row names the device it
 ran on, and a form whose result differs from ``param[keys]`` on an
@@ -31,20 +41,23 @@ sys.path.insert(0, ROOT)
 
 import numpy as np  # noqa: E402
 
-CELL_CONFIG = "benchmarks/configs/lr_ftrl_criteo_tb.json"
+CELL_CONFIG = "lr_ftrl_criteo_tb"
 CELL_TRAFFIC = "benchmarks/traffic/replay_packed_zipf.json"
 
 
-def cell_batch(seed: int):
-    """(fields, CompactBatch): the first batch of the cell's first shard
-    as ``io/packed.py`` would pack it, made with the benchmark's own
-    generator, remap and the program's steering."""
+def cell_batch(seed: int, config: str = CELL_CONFIG):
+    """(fields, CompactBatch): the first batch of the first shard of the
+    train cell of ``benchmarks/configs/<config>.json`` as ``io/packed.py``
+    would pack it, made with the benchmark's own generator, remap and
+    the program's steering."""
     from benchmarks.generators.rows import RowGenerator, RowSpec
     from benchmarks.harness import corpus
     from xflow_tpu.io.batch import make_batch
     from xflow_tpu.io.compact import CompactBatch
 
-    with open(os.path.join(ROOT, CELL_CONFIG)) as f:
+    with open(
+        os.path.join(ROOT, "benchmarks", "configs", f"{config}.json")
+    ) as f:
         fields = json.load(f)
     with open(os.path.join(ROOT, CELL_TRAFFIC)) as f:
         mix = json.load(f)
@@ -72,15 +85,17 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=3000000007)
     ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--config", default=CELL_CONFIG,
+                    help="the one-chip train cell whose batch and "
+                    "widths the probe takes (benchmarks/configs/)")
     args = ap.parse_args()
 
     import jax
     import jax.numpy as jnp
 
     from xflow_tpu.ops import window
-    from xflow_tpu.parallel.step import (
-        dict_cold_rows, expand_dict_wire,
-    )
+    from xflow_tpu.parallel import step
+    from xflow_tpu.parallel.step import expand_dict_wire
 
     device = jax.devices()[0]
     if device.platform != "tpu":
@@ -89,16 +104,36 @@ def main() -> int:
         return 1
     stamp = {"platform": device.platform, "device_kind": device.device_kind}
     t0 = time.perf_counter()
-    fields, cb = cell_batch(args.seed)
+    fields, cb = cell_batch(args.seed, args.config)
+    stamp["config"] = args.config
     print(f"batch made in {time.perf_counter() - t0:.1f} s: n_cold "
           f"{cb.n_cold}, dict {cb.n_dict} entries / {cb.n_dict_occ} "
           f"occurrences, caps cu {len(cb.cu)} ci {len(cb.ci)} ct "
           f"{len(cb.ct)}", flush=True)
     rows_out: list[dict] = []
 
-    def run(name: str, fn, *xs, indices: int):
-        f = jax.jit(fn)
-        out = jax.block_until_ready(f(*xs))  # compile + warm
+    def run(name: str, fn, *xs, indices: int, layout: str | None = None):
+        shipped = step.ROW_LAYOUT_MIN_COLUMNS
+        if layout:  # read while dict_cold_rows is traced, below
+            step.ROW_LAYOUT_MIN_COLUMNS = {"rows": 1, "columns": 1 << 30}[
+                layout
+            ]
+        try:
+            # a function of its own: jit's cache is keyed by the function,
+            # and a second layout of one function would find the first's
+            f = jax.jit(lambda *a: fn(*a))
+            out = jax.block_until_ready(f(*xs))  # compile + warm
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            # a finding, not a fault: the form does not fit the chip
+            rows_out.append({
+                "form": name, "refused": str(e).splitlines()[0], **stamp,
+            })
+            print(rows_out[-1], flush=True)
+            return None
+        finally:
+            step.ROW_LAYOUT_MIN_COLUMNS = shipped
         t0 = time.perf_counter()
         for _ in range(args.calls):
             out = f(*xs)
@@ -138,41 +173,69 @@ def main() -> int:
 
     key = jax.random.key(args.seed & 0x7FFFFFFF)
     t_rows = 1 << fields["table_size_log2"]
-    tables = {
-        1: (jax.random.normal(key, (t_rows, 1), jnp.float32), 0),
-        # FM's width beside it on one chip: 2^25 rows x 10, keys >> 3
-        10: (jax.random.normal(key, (t_rows >> 3, 10), jnp.float32), 3),
-    }
-    for d, (param, shift) in tables.items():
+    if args.config == CELL_CONFIG:
+        tables = {
+            1: (t_rows, 0),
+            # FM's width beside it on one chip: 2^25 rows x 10, keys >> 3
+            10: (t_rows >> 3, 3),
+        }
+    else:  # the cell's own tables: FFM's w, and v's row of a vector a field
+        tables = {
+            d: (t_rows, 0)
+            for d in (1, fields["max_fields"] * fields["ffm_v_dim"])
+        }
+    # between them a sweep of widths over a [2^20, D] table, the route in
+    # both layouts alone: the rest of the route is the same in both, so
+    # where they cross is where the layouts do
+    sweep = [d for d in (2, 4, 8, 10, 16, 32, 64, 128) if d not in tables]
+    tables.update(
+        {d: (1 << 20, fields["table_size_log2"] - 20) for d in sweep}
+    )
+
+    def route(p, pl):
+        return step.dict_cold_rows(
+            pl, {"t": p}, window.lane_select_tpu
+        )["t"].reshape(b, kc, -1)
+
+    for d, (n_rows, shift) in sorted(tables.items()):
         tag = f"D={d}"
+        param = jax.random.normal(key, (n_rows, d), jnp.float32)
         k2, u, t = keys >> shift, cu >> shift, ct >> shift
         want = run(
             f"{tag} param[keys], a row per padded slot (the parent's)",
             lambda p, k: p[k], param, k2, indices=slots)
-        run(f"{tag} param[cu] + param[ct]",
-            lambda p, a, c: (p[a], p[c]), param, u, t, indices=n_tab)
-        rows_u = param[u]
-        run(f"{tag} rows_u[ci], [{cu.shape[0]}, {d}] row gather",
-            lambda r, i: r[i], rows_u, ci, indices=ci.shape[0])
-        keyed = jnp.concatenate([
-            u[:, None], jax.lax.bitcast_convert_type(rows_u, jnp.int32)
-        ], axis=1)
-        run(f"{tag} keyed[ci], [{cu.shape[0]}, 1+{d}] int32 rows, the key "
-            "beside the row's bits", lambda r, i: r[i], keyed, ci,
-            indices=ci.shape[0])
+        if d not in sweep:
+            run(f"{tag} param[cu] + param[ct]",
+                lambda p, a, c: (p[a], p[c]), param, u, t, indices=n_tab)
+            rows_u = param[u]
+            run(f"{tag} rows_u[ci], [{cu.shape[0]}, {d}] row gather",
+                lambda r, i: r[i], rows_u, ci, indices=ci.shape[0])
+            keyed = jnp.concatenate([
+                u[:, None], jax.lax.bitcast_convert_type(rows_u, jnp.int32)
+            ], axis=1)
+            run(f"{tag} keyed[ci], [{cu.shape[0]}, 1+{d}] int32 rows, the "
+                "key beside the row's bits", lambda r, i: r[i], keyed, ci,
+                indices=ci.shape[0])
         shifted = {**plan, "cu": u, "ct": t}
-        got = run(
-            f"{tag} dict_cold_rows: the whole route (shipped)",
-            lambda p, pl: dict_cold_rows(
-                pl, {"t": p}, window.lane_select_tpu
-            )["t"].reshape(b, kc, -1),
-            param, shifted, indices=slots)
-        check(f"{tag} route == param[keys] on every unmasked slot",
-              np.array_equal(bits(got)[mask], bits(want)[mask]))
-        check(f"{tag} route gives 0 on every padding slot",
-              not bits(got)[~mask].any())
+        by_rows = d >= step.ROW_LAYOUT_MIN_COLUMNS
+        for layout in ("columns", "rows"):
+            ships = "shipped" if by_rows == (layout == "rows") else "not shipped"
+            got = run(
+                f"{tag} dict_cold_rows over [{n_rows}, {d}]: the whole "
+                f"route, laid out by {layout} ({ships})",
+                route, param, shifted, indices=slots, layout=layout)
+            if got is None:
+                continue
+            check(f"{tag} route by {layout} == param[keys] on every "
+                  "unmasked slot",
+                  np.array_equal(bits(got)[mask], bits(want)[mask]))
+            check(f"{tag} route by {layout} gives 0 on every padding slot",
+                  not bits(got)[~mask].any())
 
-    param = tables[1][0]
+    if args.config != CELL_CONFIG:
+        return finish(rows_out)
+    # the single forms behind PR 30, at the LR cell's D = 1
+    param = jax.random.normal(key, (t_rows, 1), jnp.float32)
     flat = param.reshape(-1)
     rows_u = flat[cu]
     run("D=1 rows_u[ci], element gather of a 1-D source",
@@ -229,8 +292,13 @@ def main() -> int:
           np.array_equal(bits(got)[mask.ravel()],
                          bits(flat[keys.reshape(-1)])[mask.ravel()]))
 
+    return finish(rows_out)
+
+
+def finish(rows_out: list[dict]) -> int:
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/probe_cold_gather.json", "w") as f:
+    name = f"probe_cold_gather.{rows_out[0]['config']}.json"
+    with open(os.path.join("chiprun_out", name), "w") as f:
         json.dump(rows_out, f, indent=1)
     return 0 if all(r.get("equal", True) for r in rows_out) else 1
 

@@ -22,6 +22,7 @@ from xflow_tpu.io.compact import (
     dedup_select,
     plane_cap,
 )
+from xflow_tpu.parallel.step import ROW_LAYOUT_MIN_COLUMNS
 
 from tests.test_binary import batches_equal, make_loader
 
@@ -361,14 +362,15 @@ def test_dict_wire_matches_plain_wire(model):
 _DECODE_DATA = ("all_padding", "full_rows", "odd_caps", "zipf", "tail_only")
 
 
-def _decode_case(data, hot, key_bytes):
+def _decode_case(data, hot, key_bytes, table_log2=None):
     """(Batch, table_size, hot_size, dict_cap) for one decode case.  The
     batch is in wire order already: left-compacted rows, hot ids
-    < hot_size in the hot section, everything else cold."""
+    < hot_size in the hot section, everything else cold.  The table has
+    2^20 rows (u24 keys) or 2^26 (u32) unless ``table_log2`` says."""
     rng = np.random.default_rng(
         _DECODE_DATA.index(data) * 7 + key_bytes + len(hot)
     )
-    table = 1 << (20 if key_bytes == 3 else 26)
+    table = 1 << (table_log2 or (20 if key_bytes == 3 else 26))
     hot_size = {"none": 0, "u12": 1 << 10, "u16": 1 << 14}[hot]
     # odd_caps: B*K is no multiple of 128 and nearly every slot is real,
     # so the occurrence planes are capped at B*K (259 cold, 185 hot)
@@ -592,14 +594,17 @@ def _cold_rows_case(data, hot, key_bytes, d, lane_select):
     for one decode case and a random [T, D] table."""
     from xflow_tpu.parallel.step import dict_cold_rows, expand_dict_wire
 
-    batch, table, hot_size, dict_cap = _decode_case(data, hot, key_bytes)
+    # a wide row over 2^16 rows: 2^20 of 160 columns are 640 MiB a case
+    batch, table, hot_size, dict_cap = _decode_case(
+        data, hot, key_bytes, table_log2=16 if d > 16 else None
+    )
     cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
     step = _decode_step(
         "lr", table, hot_size, batch.batch_size, batch.max_nnz,
         batch.hot_nnz,
     )
     rng = np.random.default_rng(d)
-    param = rng.standard_normal((table, d)).astype(np.float32)
+    param = rng.standard_normal((table, d), dtype=np.float32)
     param[0] = 7.0  # what a padding slot of param[keys] reads
 
     def route(w, p):
@@ -613,16 +618,28 @@ def _cold_rows_case(data, hot, key_bytes, d, lane_select):
     )
 
 
+# Widths on both sides of the route's layouts (dict_cold_rows: column by
+# column below ROW_LAYOUT_MIN_COLUMNS, row by row from it on): LR's one
+# column, FM's and MVM's ten, the widest row laid out by columns, the
+# narrowest laid out by rows, and FFM's 160 (benchmarks/configs/).
+_ROUTE_WIDTHS = sorted(
+    {1, 10, ROW_LAYOUT_MIN_COLUMNS - 1, ROW_LAYOUT_MIN_COLUMNS, 160}
+)
+
+
 # u32 keys need a table above 2^24 rows: at D = 1 only (256 MiB a table)
-@pytest.mark.parametrize("key_bytes,d", [(3, 1), (4, 1), (3, 10)])
+@pytest.mark.parametrize(
+    "key_bytes,d", [(4, 1)] + [(3, d) for d in _ROUTE_WIDTHS]
+)
 @pytest.mark.parametrize("data", _DECODE_DATA)
 @pytest.mark.parametrize("hot", ["none", "u12"])
 def test_dict_cold_rows_equal_param_at_keys(hot, data, key_bytes, d):
     """The cold rows fetched through the dictionary (the table read per
-    dictionary and tail entry, the occurrences resolved out of those)
-    equal ``param[keys]`` bit for bit on every unmasked slot, and are 0
-    on padding: no dictionary beside a tail, no tail, nothing at all,
-    rows at max_nnz, capacities that are no multiple of 128."""
+    dictionary and tail entry, the occurrences resolved out of those,
+    the padded layout by column takes or by row gathers as the width
+    says) equal ``param[keys]`` bit for bit on every unmasked slot, and
+    are 0 on padding: no dictionary beside a tail, no tail, nothing at
+    all, rows at max_nnz, capacities that are no multiple of 128."""
     from xflow_tpu.ops import window
 
     got, want, real = _cold_rows_case(
@@ -635,11 +652,12 @@ def test_dict_cold_rows_equal_param_at_keys(hot, data, key_bytes, d):
     assert not got[~real].view(np.uint32).any()
 
 
-@pytest.mark.parametrize("d", [1, 10])
+@pytest.mark.parametrize("d", _ROUTE_WIDTHS)
 @pytest.mark.parametrize("data", _DECODE_DATA)
 def test_dict_cold_rows_tpu_form_interpreted(data, d):
-    """The same through the float32 rows' Mosaic lane shuffle, as a TPU
-    traces the route (run here by the Pallas TPU interpreter)."""
+    """The same as a TPU traces the route: the decode's running counts
+    and a narrow row's columns through the Mosaic lane shuffle (run here
+    by the Pallas TPU interpreter), a wide row by row gathers."""
     from jax.experimental.pallas import tpu as pltpu
 
     from xflow_tpu.ops import window
@@ -686,8 +704,15 @@ def _dict_and_expanded_states(model, steps, hot, **mode):
     return out
 
 
-@pytest.mark.parametrize("model", ["lr", "fm"])  # D = 1; w beside v at D = 8
-@pytest.mark.parametrize("mode", ["dense", "sparse", "sequential_hot"])
+# D = 1; w beside v at D = 8; w on the column takes beside a v of 32
+# fields x 4 = 128 columns laid out by row gathers, off the MXU head
+@pytest.mark.parametrize("mode,model", [
+    (mode, model)
+    for model in ("lr", "fm", "ffm")
+    for mode in ("dense", "sparse", "sequential_hot")
+    # the hot sequential inner refuses a table that opted out of the head
+    if (mode, model) != ("sequential_hot", "ffm")
+])
 def test_dict_route_leaves_the_state_of_the_expanded_batch(mode, model):
     """Three dense steps, one update_mode='sparse' step and one window
     of the hot sequential inner (its window-start gather) on a

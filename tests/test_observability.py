@@ -1145,14 +1145,14 @@ def test_wire_row_says_what_the_field_ids_cost(
         assert row["slots_bytes_per_example"] == 0
 
 
-@pytest.mark.parametrize("model, widths, plain", [
-    ("lr", {"w": 1}, []),
-    ("fm", {"w": 1, "v": 10}, []),
-    ("mvm", {"v": 10}, []),
-    ("ffm", {"w": 1, "v": 16}, ["v"]),
+@pytest.mark.parametrize("model, widths, plain, by_rows", [
+    ("lr", {"w": 1}, [], []),
+    ("fm", {"w": 1, "v": 10}, [], []),
+    ("mvm", {"v": 10}, [], []),
+    ("ffm", {"w": 1, "v": 64}, ["v"], ["v"]),
 ])
 def test_wire_row_counts_the_table_rows_a_step_moves(
-    toy_dataset, tmp_path, model, widths, plain
+    toy_dataset, tmp_path, model, widths, plain, by_rows
 ):
     """The epoch's ``wire`` row carries, a batch and from shapes
     (TrainStep._book_wire): ``gather_row_bytes_per_step``, a row of every
@@ -1160,14 +1160,19 @@ def test_wire_row_counts_the_table_rows_a_step_moves(
     a row read and a row written per padded cold slot; and in both the hot
     slots of a table that opted out of the MXU head (FFM's v), which
     ``plain_hot_slots_per_step`` counts: B x hot_nnz for FFM, 0 for the
-    families whose every table rides the head."""
+    families whose every table rides the head.  And
+    ``cold_row_layout_slots_per_step``: the padded cold slots of every
+    table wide enough for the dictionary route to lay its rows out by row
+    gathers (dict_cold_rows): 0 for LR, B x max_nnz for FFM (v, not w)."""
     from xflow_tpu.obs.schema import validate_rows
+    from xflow_tpu.parallel.step import ROW_LAYOUT_MIN_COLUMNS
 
     b, kc, kh = 64, 24, 8
     out = tmp_path / "m.jsonl"
     cfg = _toy_cfg(
         toy_dataset, model=model, epochs=1, metrics_out=str(out),
         batch_size=b, max_nnz=kc, hot_size_log2=6, hot_nnz=kh, max_fields=4,
+        ffm_v_dim=16,
     )
     with Trainer(cfg) as t:
         assert t.step.wire_format == "dict"
@@ -1190,6 +1195,10 @@ def test_wire_row_counts_the_table_rows_a_step_moves(
     plain_bytes = 4 * sum(widths[n] for n in plain)
     assert row["plain_hot_slots_per_step"] == b * kh * len(plain)
     assert row["padded_cold_slots_per_step"] == b * kc
+    assert by_rows == [
+        n for n, d in widths.items() if d >= ROW_LAYOUT_MIN_COLUMNS
+    ]
+    assert row["cold_row_layout_slots_per_step"] == b * kc * len(by_rows)
     assert row["scatter_row_bytes_per_step"] == 2 * (
         b * kc * row_bytes + b * kh * plain_bytes
     )

@@ -123,23 +123,41 @@ def test_wide_take_stays_a_gather_of_two_word_rows_on_v5e(
     assert len(gathers) == 1 and "slice_sizes={1,2}" in gathers[0], gathers
 
 
-@pytest.mark.parametrize("rows,d", [(1 << 28, 1), (1 << 25, 10)])
+# plane capacities (dictionary, occurrences, tail) and padded cold slots
+# of one real batch of lr_tb.train_packed and of ffm_tb.train_packed,
+# whose batches ship no tail plane (PERF.md section 5), and FFM's beside
+# a tail
+_LR_PLANES = (53248, 1228800, 294912, 131072 * 12)
+_FFM_PLANES = (53248, 118784, 0, 16384 * 8)
+_FFM_PLANES_TAIL = (53248, 118784, 4096, 16384 * 8)
+
+
+@pytest.mark.parametrize("rows,d,planes", [
+    (1 << 28, 1, _LR_PLANES), (1 << 25, 10, _LR_PLANES),
+    (1 << 21, 160, _FFM_PLANES), (1 << 21, 160, _FFM_PLANES_TAIL),
+])
 def test_dict_cold_rows_compile_for_v5e_at_flagship(
-    one_chip, no_compile_cache, rows, d
+    one_chip, no_compile_cache, rows, d, planes
 ):
     """The cold rows through the dictionary at the plane capacities of
-    one real batch of the benchmark's train cell, LR's table and FM's
-    width: it compiles with its lane shuffles on float32 rows, the [T, D]
-    table (the only float32 operand of its height) is gathered per
-    dictionary and per tail entry and by nothing of a padded plane's
-    size, and the occurrence resolve reads rows at least two words wide."""
+    one real batch of a benchmark train cell: LR's table, FM's and MVM's
+    width beside it, FFM's v.  It compiles; the [T, D] table (the only
+    float32 operand of its height) is gathered per dictionary and per
+    tail entry and by nothing of a padded plane's size; the occurrence
+    resolve reads rows at least two words wide.  A narrow row is laid
+    out by its lane shuffles on float32 columns (two Mosaic calls a
+    column); a row of ROW_LAYOUT_MIN_COLUMNS or more by one gather of
+    WHOLE rows a stream, with no Mosaic call and no [slots, 1] column,
+    which (8,128) tiles pad 128 x once it is a [B, max_nnz, 1] plane
+    (FFM's 160 of them were 82 ms of a 202 ms step and what refused
+    B = 32768: PERF.md section 6, PR 34-35)."""
     from xflow_tpu.ops import window
-    from xflow_tpu.parallel.step import dict_cold_rows
+    from xflow_tpu.parallel.step import ROW_LAYOUT_MIN_COLUMNS, dict_cold_rows
 
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cap_u, cap_i, cap_t, slots = 53248, 1228800, 294912, 131072 * 12
+    cap_u, cap_i, cap_t, slots = planes
     plan = {
         "cu": shaped((cap_u,), jnp.int32), "ct": shaped((cap_t,), jnp.int32),
         "ci": shaped((cap_i,), jnp.int32),
@@ -152,18 +170,32 @@ def test_dict_cold_rows_compile_for_v5e_at_flagship(
         lambda p, pl: dict_cold_rows(pl, {"t": p}, window.lane_select_tpu)
     ).lower(shaped((rows, d), jnp.float32), plan).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 2 * d  # a take per stream, column
-    # beside the takes' window rows, three gathers: the table's rows of
-    # the tail and of the dictionary, and the occurrence resolve
+    by_rows = d >= ROW_LAYOUT_MIN_COLUMNS
+    # a take per stream and column, or none
+    assert text.count("tpu_custom_call") == (0 if by_rows else 2 * d)
+    assert (f"f32[{slots},1]" in text) == (not by_rows)
+    # beside the takes' window rows: the table's rows of the tail and of
+    # the dictionary, the occurrence resolve, and a wide row's layout, a
+    # stream (an empty tail plane has no rows to gather or to lay out)
+    gathers = [
+        g for g in _gather_lines(compiled) if "slice_sizes={1,256}" not in g
+    ]
     shapes = sorted(
-        re.search(r"= f32\[([\d,]*)\]", g).group(1)
-        for g in _gather_lines(compiled) if "slice_sizes={1,256}" not in g
+        re.search(r"= f32\[([\d,]*)\]", g).group(1) for g in gathers
     )
     wide = f",{d}" if d > 1 else ""
     assert shapes == sorted(
-        [f"{cap_t}{wide}", f"{cap_u}{wide}", f"{cap_i},{max(d, 2)}"]
+        [f"{cap_t}{wide}"] * (cap_t > 0)
+        + [f"{cap_u}{wide}", f"{cap_i},{max(d, 2)}"]
+        + [f"{slots},{d}"] * ((1 + (cap_t > 0)) * by_rows)
     ), shapes
-    assert compiled.memory_analysis().temp_size_in_bytes < 1536 << 20
+    if by_rows:
+        assert all(f"slice_sizes={{1,{d}}}" in g for g in gathers), gathers
+    # D = 160: 2 GiB of it is the table's copy to columns minor, which the
+    # step holds anyway (PERF.md section 7)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        (2304 if d > 128 else 1536) << 20
+    )
 
 
 def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
@@ -358,9 +390,12 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
     [B, hot_nnz] plane.  Compiled: the program fits with the room the
     file's ``reduced`` argues from, 13.94 GiB of 15.75 (at 2^22 rows the
     compiler refuses it).  Most of that is layout (PERF.md section 6,
-    PR 34): v's state comes in rows-minor and is copied to columns-minor
-    and back inside the step, and the dictionary route lays a 160-column
-    row out as 160 padded [B, max_nnz, 1] planes."""
+    PR 34-35): v's state comes in rows-minor and is copied to
+    columns-minor and back inside the step, six table-sized copies that
+    set the peak.  The dictionary route lays v's 160-column row out by
+    row gathers (dict_cold_rows): of the padded [B, max_nnz, 1] column
+    planes, 160 families of them until PR 35 (0.16 GiB of the peak and
+    82 ms of the step), one is left, w's single column."""
     u8, u16 = np.uint8, np.uint16
     cfg, step, lowered = _lowered_cell_step(topo, "ffm_ftrl_criteo_tb", {
         "cw_cu": ((53248, 3), u8), "cw_cun": ((1,), np.int32),
@@ -391,5 +426,12 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
     ]
     assert len(by_hot_plane) == 1, by_hot_plane
     assert f"(tensor<{t}x{e}xf32>, " in by_hot_plane[0]
-    peak = _program_peak(lowered.compile())
-    assert 13.5 * (1 << 30) < peak < 14.6 * (1 << 30), peak
+    compiled = lowered.compile()
+    # instructions whose result is a padded column plane of the cold
+    # slots: w's one column and no more (9; 667 with v's 160 columns)
+    planes = set(re.findall(
+        rf"(\S+) = f32\[{b},{cfg.max_nnz},1\]", compiled.as_text()
+    ))
+    assert len(planes) <= 16, sorted(planes)
+    peak = _program_peak(compiled)
+    assert 13.5 * (1 << 30) < peak < 14.0 * (1 << 30), peak

@@ -408,6 +408,11 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "gather_row_bytes_per_step": (int, float),
         "scatter_row_bytes_per_step": (int, float),
         "plain_hot_slots_per_step": (int, float),
+        # where the step read the dictionary wire's plan (one device):
+        # padded cold slots B * max_nnz times the tables wide enough for
+        # the route to lay their rows out by row gathers, 0 where every
+        # table goes column by column (step.py::dict_cold_rows)
+        "cold_row_layout_slots_per_step": (int, float),
         # of wire_bytes_per_example, the planes of field ids (slots_u8 /
         # hot_slots_u8, the dictionary wire's cw_cs / cw_hs, the full
         # wire's slots / hot_slots): 0 where none ships, as for a model

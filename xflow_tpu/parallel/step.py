@@ -497,6 +497,12 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
     return out
 
 
+# The narrowest table row that dict_cold_rows lays out by row gathers:
+# of the widths measured (its docstring) the narrowest at which the rows
+# win at both geometries.
+ROW_LAYOUT_MIN_COLUMNS = 64
+
+
 def dict_cold_rows(
     plan: dict, params: dict[str, jax.Array], lane_select
 ) -> dict[str, jax.Array]:
@@ -513,29 +519,57 @@ def dict_cold_rows(
     (``rows_u[ci]``, the route's one per-occurrence gather, an
     ops/window.py::wide_take over a source of at most DICT_CAP rows);
     and the padded layout from the two flat row streams by the same
-    running counts that lay out the key plane: one
-    ops/window.py::monotone_take per stream and column.
+    running counts that lay out the key plane.
+
+    How the layout reads the streams depends on the row's width, which
+    is all that differs between the tables.  A narrow row goes column
+    by column: one ops/window.py::monotone_take per stream and column,
+    no gather by index at all, but a [B * max_nnz] plane a column,
+    which inside the step becomes a [B, max_nnz, 1] plane that (8,128)
+    tiles pad 128 x.  A row of ROW_LAYOUT_MIN_COLUMNS or more goes
+    whole: the running counts index ROWS of the two streams, two
+    wide_takes a table, an index a padded slot and stream whatever the
+    width.  Measured on a v5e (scripts/probe_cold_gather.py, the whole
+    route; PERF.md section 6, PR 35), by columns / by rows.  D = 10 at
+    the geometry of lr_tb.train_packed (1 572 864 slots out of
+    1 228 800 occurrences and a tail of 294 912): 21.3 / 47.5 ms.
+    D = 160 at that of ffm_tb.train_packed (131 072 slots out of
+    118 784 occurrences, no tail): 22.9 / 8.2 ms alone, and in the
+    step, where the planes are padded, the whole of xf.gather reads
+    92.0 / 10.3 ms.  Between them the two geometries cross at
+    different widths (the small one from D = 8 on, 0.98 / 0.80 ms; the
+    large one between D = 32, 51 / 128 ms, and D = 64, 94 / 53 ms; at
+    D = 128 its column form no longer compiles), so the line stands at
+    the narrowest width measured at which the rows win at both.
+
     Unmasked slots hold ``param[key]`` bit for bit; padding slots hold
     0 where ``param[keys]`` reads row 0 (masked in every reduction
     either way)."""
     take = functools.partial(monotone_take, lane_select=lane_select)
     cu, ct, ci = plan["cu"], plan["ct"], plan["ci"]
+    is_dict, is_tail = plan["is_dict"], plan["is_tail"]
+    di_idx, tail_idx = plan["di_idx"], plan["tail_idx"]
     out = {}
     for name, param in params.items():
         rows_t = param[ct]
         rows_occ = wide_take(param[cu], ci)
-        out[name] = jnp.stack([
-            jnp.where(
-                plan["is_dict"],
-                take(plan["di_idx"], rows_occ[:, j]),
+        if param.shape[-1] >= ROW_LAYOUT_MIN_COLUMNS:
+            out[name] = jnp.where(
+                is_dict[:, None],
+                wide_take(rows_occ, di_idx),
                 jnp.where(
-                    plan["is_tail"],
-                    take(plan["tail_idx"], rows_t[:, j]),
-                    0,
+                    is_tail[:, None], wide_take(rows_t, tail_idx), 0
                 ),
             )
-            for j in range(param.shape[-1])
-        ], axis=1)
+        else:
+            out[name] = jnp.stack([
+                jnp.where(
+                    is_dict,
+                    take(di_idx, rows_occ[:, j]),
+                    jnp.where(is_tail, take(tail_idx, rows_t[:, j]), 0),
+                )
+                for j in range(param.shape[-1])
+            ], axis=1)
     return out
 
 
@@ -574,6 +608,10 @@ class TrainStep:
         plain = [spec for spec in model.tables() if not spec.hot]
         self._plain_hot_tables = len(plain)
         self._plain_hot_row_bytes = sum(4 * spec.dim for spec in plain)
+        # tables whose cold rows dict_cold_rows lays out by row gathers
+        self._row_layout_tables = sum(
+            spec.dim >= ROW_LAYOUT_MIN_COLUMNS for spec in model.tables()
+        )
         # The hot-inner/opt-out conflict only exists when the hot inner
         # actually RUNS — update_mode must be 'sequential'.  In dense or
         # sparse mode sequential_inner is an unused knob (ffm + dense +
@@ -754,7 +792,11 @@ class TrainStep:
         are plain table rows, and leave the head's own traffic out;
         ``plain_hot_slots`` is those slots, a table: 0 where every table
         rides the head.  A padded slot counts like a live one (the
-        gather reads row 0 for it; the scatter-add drops it)."""
+        gather reads row 0 for it; the scatter-add drops it).  Where the
+        step reads the ``cold_plan``, ``cold_row_layout_slots`` is the
+        padded cold slots of every table wide enough for dict_cold_rows
+        to lay its rows out by row gathers: 0 where every table goes
+        column by column."""
         self.obs.counter("wire.bytes", nbytes)
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")
@@ -767,6 +809,11 @@ class TrainStep:
             indices = len(cb.cu) + len(cb.ct) if through_dict else cold_slots
             self.obs.counter("wire.cold_slots", cold_slots)
             self.obs.counter("wire.table_gather_indices", indices)
+            if through_dict:
+                self.obs.counter(
+                    "wire.cold_row_layout_slots",
+                    cold_slots * self._row_layout_tables,
+                )
             plain = hot_slots * self._plain_hot_row_bytes
             self.obs.counter(
                 "wire.plain_hot_slots", hot_slots * self._plain_hot_tables

@@ -1098,6 +1098,13 @@ class Trainer:
                     stats["_wire"][f"{name}_per_step"] = round(
                         snap.counters[f"wire.{name}"] / batches
                     )
+                if "wire.cold_row_layout_slots" in snap.counters:
+                    # the step read the dictionary wire's plan: padded
+                    # cold slots of the tables whose rows it laid out by
+                    # row gathers (step.py::dict_cold_rows)
+                    stats["_wire"]["cold_row_layout_slots_per_step"] = round(
+                        snap.counters["wire.cold_row_layout_slots"] / batches
+                    )
             if "exchange.bytes" in snap.counters:
                 # a mesh of more than one device: what the step's pull
                 # and push moved between the chips, from shapes
