@@ -20,6 +20,8 @@ shared no-op context manager — no per-step allocation.
 
 from __future__ import annotations
 
+import collections
+import gc
 import time
 from typing import Any, Callable
 
@@ -37,6 +39,8 @@ __all__ = [
     "Obs",
     "NULL_OBS",
     "make_obs",
+    "profiler_span",
+    "GcPauses",
     "SpanTracer",
     "NullTracer",
     "MetricsRegistry",
@@ -53,6 +57,60 @@ __all__ = [
 PROFILER_PREFIX = "xf."
 
 
+def profiler_span(name: str, **args: Any) -> TraceAnnotation:
+    """An ``xf.<name>`` span on the JAX profiler's timeline and nothing
+    else: for a caller that keeps its own clocks and may hold no
+    ``Obs`` (the serve worker, whose fleet the caller loads bare).
+    ``args`` ride as the event's arguments inside a session."""
+    return TraceAnnotation(PROFILER_PREFIX + name, **args)
+
+
+class GcPauses:
+    """A ``gc.callbacks`` hook: every collection is an ``xf.gc`` span on
+    whichever thread it ran, and its pause is booked, with the
+    collection's generation, as ``<prefix>.gc_pause_seconds``.
+
+    A collection can start inside ANY allocation, the registry's own
+    under its lock among them, so the hook takes no lock: it appends
+    to a deque (collections never nest, appends are atomic) and
+    ``flush`` moves what has gathered into the registry from the
+    caller's thread."""
+
+    def __init__(self, registry: MetricsRegistry, prefix: str):
+        self._registry = registry
+        self._name = prefix + ".gc_pause_seconds"
+        self._pauses: collections.deque = collections.deque()
+        self._open: tuple[TraceAnnotation, float] | None = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            span = profiler_span("gc", generation=info["generation"])
+            span.__enter__()
+            self._open = (span, time.perf_counter())
+        elif self._open is not None:
+            span, t0 = self._open
+            self._open = None
+            self._pauses.append(
+                (info["generation"], time.perf_counter() - t0)
+            )
+            span.__exit__(None, None, None)
+
+    def install(self) -> None:
+        gc.callbacks.append(self)
+
+    def remove(self) -> None:
+        """Idempotent; what gathered since the last flush stays
+        flushable."""
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+
+    def flush(self) -> None:
+        while self._pauses:
+            generation, dt = self._pauses.popleft()
+            self._registry.observe(self._name, dt)
+            self._registry.counter_add(f"{self._name}.gen{generation}", dt)
+
+
 class _Phase:
     """Times a block and books it as a ``phase.<name>`` counter
     (wall-second accounting), as a trace span, and as an ``xf.<name>``
@@ -64,7 +122,7 @@ class _Phase:
     def __init__(self, obs: "Obs", name: str):
         self._obs = obs
         self._name = name
-        self._annotation = TraceAnnotation(PROFILER_PREFIX + name)
+        self._annotation = profiler_span(name)
 
     def __enter__(self) -> "_Phase":
         self._annotation.__enter__()
@@ -87,7 +145,7 @@ class _ProfiledSpan:
 
     def __init__(self, span, name: str):
         self._span = span
-        self._annotation = TraceAnnotation(PROFILER_PREFIX + name)
+        self._annotation = profiler_span(name)
 
     def __enter__(self) -> "_ProfiledSpan":
         self._annotation.__enter__()

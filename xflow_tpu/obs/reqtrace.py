@@ -16,7 +16,7 @@ boundaries:
     coalesce_wait   enqueued → batch sealed (micro-batch wait)
     swap_stall      batch sealed → engine captured (_swap_lock wait)
     featurize       rows → prepared Batch
-    device          h2d + execute + fetch
+    device          h2d + dispatch + fetch
 
 and the batcher emits ONE batch span fanning in its N request spans
 (same engine digest for every member by construction — the engine is

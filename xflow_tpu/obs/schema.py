@@ -172,7 +172,7 @@ SCHEMA: dict[str, dict[str, Any]] = {
     },
     # one per MicroBatcher flush/close: per-request latency percentiles
     # (queue = enqueue→dequeue, featurize = request→Batch assembly,
-    # device = h2d + execute + fetch) over the window since the last
+    # device = h2d + dispatch + fetch) over the window since the last
     # emission, plus coalescing effectiveness (requests/batches) and
     # the admission-control sheds booked against this window
     "serve_stats": {
@@ -453,6 +453,36 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "cache_evictions": int,
         "cache_invalidations": int,
         "cache_inserts_dropped": int,
+        # what the worker was doing, one observation a BATCH
+        # (serve/batcher.py, ISSUE 36; streams from before it lack
+        # them): the device call's three host legs, the worker's own
+        # bookkeeping after it, the coalescing hold, the seconds the
+        # ``workers`` (one a replica) spent inside batches and the
+        # longest of those, how long after its coalescing deadline a
+        # batch was sealed, and the collector's pauses.  A ``_max`` is
+        # the window's worst: a stall reads in the one it landed in
+        "h2d_p50": (int, float),
+        "h2d_p99": (int, float),
+        "h2d_max": (int, float),
+        "dispatch_p50": (int, float),
+        "dispatch_p99": (int, float),
+        "dispatch_max": (int, float),
+        "fetch_p50": (int, float),
+        "fetch_p99": (int, float),
+        "fetch_max": (int, float),
+        "resolve_p50": (int, float),
+        "resolve_p99": (int, float),
+        "resolve_max": (int, float),
+        "coalesce_p50": (int, float),
+        "workers": int,
+        "worker_busy_s": (int, float),
+        "batch_p99": (int, float),
+        "batch_max": (int, float),
+        "seal_late_p99": (int, float),
+        "seal_late_max": (int, float),
+        "gc_pauses": int,
+        "gc_pause_max": (int, float),
+        "gc_pause_total": (int, float),
     },
     # scored-and-returned count alongside admitted (completions lag
     # admissions by the in-flight window; rows from before the counter

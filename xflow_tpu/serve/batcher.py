@@ -11,10 +11,20 @@ latency is bounded by ``max_wait_ms`` + one device call; throughput
 approaches the bucketed batch rate as concurrency rises.
 
 Latency accounting (ISSUE 2): per-request queue (enqueue→dequeue),
-featurize (request→Batch assembly), and device (h2d+execute+fetch)
+featurize (request→Batch assembly), and device (h2d+dispatch+fetch)
 seconds land in obs registry histograms; ``emit_stats``/``close``
 flush a ``serve_stats`` JSONL row (obs/schema.py) with p50/p99 per
 phase and the coalescing ratio.
+
+What the WORKER is doing (ISSUE 36), per batch and never per row: on
+the JAX profiler's timeline it is inside exactly one of
+``xf.serve_wait`` (blocked on an empty queue), ``xf.serve_coalesce``
+(holding a batch open) and ``xf.serve_batch`` (featurize, the engine's
+h2d / dispatch / fetch, resolve), with or without an ``Obs``; the same
+boundaries are seconds in the registry, observed once a batch, beside
+``serve.batch_seconds`` (their sum is the worker's busy time) and
+``serve.seal_late_seconds``, how long after its coalescing deadline a
+batch was sealed.
 
 Hot swap: ``swap(new_engine)`` atomically replaces the engine between
 batches — the in-flight batch finishes on the old one, the next batch
@@ -36,21 +46,27 @@ from typing import Any
 import numpy as np
 
 from xflow_tpu.chaos import failpoint
+from xflow_tpu.obs import profiler_span
 from xflow_tpu.obs.registry import MetricsRegistry, Snapshot
 
 _STOP = object()
 
 
-def stats_row_from_snapshot(snap: Snapshot) -> dict:
+def stats_row_from_snapshot(snap: Snapshot, workers: int = 1) -> dict:
     """Build a ``serve_stats`` record body from one registry snapshot.
 
     Shared by ``MicroBatcher.emit_stats`` (one batcher, its own
     registry) and ``serve/fleet.py`` (N batchers pooling ONE registry —
     the fleet snapshots once and owns the row, so per-replica resets
-    never tear the window)."""
+    never tear the window; ``workers`` = N, over which
+    ``worker_busy_s`` is a sum)."""
 
     def pct(name: str, p: str) -> float:
         return round(snap.hists.get(name, {}).get(p, 0.0), 6)
+
+    def total(name: str) -> float:
+        h = snap.hists.get(name, {})
+        return round(h.get("mean", 0.0) * h.get("count", 0), 6)
 
     return {
         "requests": int(snap.counters.get("serve.requests", 0)),
@@ -66,6 +82,28 @@ def stats_row_from_snapshot(snap: Snapshot) -> dict:
         "featurize_p99": pct("serve.featurize_seconds", "p99"),
         "device_p50": pct("serve.device_seconds", "p50"),
         "device_p99": pct("serve.device_seconds", "p99"),
+        # the worker per BATCH (one observation a batch, where the
+        # fields above hold one a request).  A ``_max`` is the window's
+        # worst, not the ring's: where a stall landed
+        **{
+            f"{leg}_{p}": pct(f"serve.{leg}_seconds", p)
+            for leg in ("h2d", "dispatch", "fetch", "resolve")
+            for p in ("p50", "p99", "max")
+        },
+        "coalesce_p50": pct("serve.coalesce_seconds", "p50"),
+        "workers": workers,
+        # the seconds the workers spent inside batches
+        "worker_busy_s": total("serve.batch_seconds"),
+        "batch_p99": pct("serve.batch_seconds", "p99"),
+        "batch_max": pct("serve.batch_seconds", "max"),
+        "seal_late_p99": pct("serve.seal_late_seconds", "p99"),
+        "seal_late_max": pct("serve.seal_late_seconds", "max"),
+        # collector pauses (obs.GcPauses, hooked by a ReplicaFleet)
+        "gc_pauses": int(
+            snap.hists.get("serve.gc_pause_seconds", {}).get("count", 0)
+        ),
+        "gc_pause_max": pct("serve.gc_pause_seconds", "max"),
+        "gc_pause_total": total("serve.gc_pause_seconds"),
     }
 
 
@@ -329,10 +367,11 @@ class MicroBatcher:
     def _loop(self) -> None:
         stopping = False
         while not stopping:
-            # sentinel-drain worker loop: close() always enqueues _STOP
-            # (XF006-gated lifecycle), so the dequeue is never abandoned
-            # (xf: ignore[XF017])
-            item = self._q.get()
+            with profiler_span("serve_wait"):
+                # sentinel-drain worker loop: close() always enqueues
+                # _STOP (XF006-gated lifecycle), so the dequeue is never
+                # abandoned (xf: ignore[XF017])
+                item = self._q.get()
             if item is _STOP:
                 return
             # busy from the FIRST dequeue: a request riding the
@@ -345,28 +384,31 @@ class MicroBatcher:
                 if self._enq:
                     self._enq.popleft()
             try:
-                reqs = [item]
-                deadline = time.perf_counter() + self._max_wait
-                while len(reqs) < self._max_batch:
-                    timeout = deadline - time.perf_counter()
-                    if timeout <= 0:
-                        # deadline passed: take whatever is already
-                        # queued, but don't wait for more
-                        timeout = 0.0
-                    try:
-                        nxt = self._q.get(timeout=timeout) if timeout else (
-                            self._q.get_nowait()
-                        )
-                    except queue.Empty:
-                        break
-                    if nxt is _STOP:
-                        stopping = True
-                        break
-                    with self._submit_lock:
-                        if self._enq:
-                            self._enq.popleft()
-                    reqs.append(nxt)
-                self._run_batch(reqs)
+                t_first = time.perf_counter()
+                deadline = t_first + self._max_wait
+                with profiler_span("serve_coalesce"):
+                    reqs = [item]
+                    while len(reqs) < self._max_batch:
+                        timeout = deadline - time.perf_counter()
+                        if timeout <= 0:
+                            # deadline passed: take whatever is already
+                            # queued, but don't wait for more
+                            timeout = 0.0
+                        try:
+                            nxt = (
+                                self._q.get(timeout=timeout) if timeout
+                                else self._q.get_nowait()
+                            )
+                        except queue.Empty:
+                            break
+                        if nxt is _STOP:
+                            stopping = True
+                            break
+                        with self._submit_lock:
+                            if self._enq:
+                                self._enq.popleft()
+                        reqs.append(nxt)
+                self._run_batch(reqs, t_first, deadline)
             finally:
                 with self._submit_lock:
                     self._busy = False
@@ -381,7 +423,7 @@ class MicroBatcher:
                         else f"batch oldest_trace={tid:016x}"
                     )
 
-    def _run_batch(self, reqs: list) -> None:
+    def _run_batch(self, reqs: list, t_first: float, deadline: float) -> None:
         # the batch is SEALED here: no later arrival joins it.  The
         # engine is captured ONCE under the swap lock, so every member
         # scores on one digest — a batch span can never mix trace ids
@@ -389,6 +431,21 @@ class MicroBatcher:
         t_seal = time.perf_counter()
         with self._swap_lock:
             engine = self._engine
+        n = len(reqs)
+        with profiler_span(
+            "serve_batch", rows=n,
+            bucket=next((b for b in engine.buckets if b >= n), n),
+        ):
+            self._score_sealed(reqs, engine, t_seal)
+        reg = self.registry
+        reg.observe("serve.batch_seconds", time.perf_counter() - t_seal)
+        reg.observe("serve.coalesce_seconds", t_seal - t_first)
+        # a batch sealed by max_batch beats its deadline and reads 0;
+        # one sealed by the deadline reads how long after it the worker
+        # got to run (woken late, or still draining what had queued)
+        reg.observe("serve.seal_late_seconds", max(0.0, t_seal - deadline))
+
+    def _score_sealed(self, reqs: list, engine, t_seal: float) -> None:
         t_deq = time.perf_counter()
         reg = self.registry
         spans = [s for _, _, _, s in reqs if s is not None]
@@ -407,7 +464,8 @@ class MicroBatcher:
             # futures resolve with the error (below) and the fleet's
             # eviction policy takes it out of routing (serve/fleet.py)
             failpoint("serve.replica_score")
-            batch = engine.featurize([row for row, _, _, _ in reqs])
+            with profiler_span("serve_featurize"):
+                batch = engine.featurize([row for row, _, _, _ in reqs])
             t1 = time.perf_counter()
             for span in spans:
                 span.t_feat = t1
@@ -433,59 +491,62 @@ class MicroBatcher:
                     span.sink.complete(span, "error", detail=repr(e))
                 fut.set_exception(e)
             return
-        # featurize/device are shared per batch: every coalesced request
-        # EXPERIENCED the whole batch's featurize+device wall, so each
-        # observes the full value — that is its latency, not an
-        # amortized share.
-        feat, dev = t1 - t0, t2 - t1
-        # featurize padded onto ONE bucket, so the prepared batch's row
-        # count IS the bucket that served these requests — the
-        # per-bucket e2e histograms (queue+featurize+device) feed the
-        # load generator's p50/p99-per-bucket report (serve/loadgen.py)
-        bucket = getattr(batch, "batch_size", len(reqs))
-        if sink is not None:
-            phases = {"featurize": feat, "device": dev}
-            # engine's per-call device split (h2d vs execute) — same
-            # worker thread, so this is the call we just made
-            split = getattr(engine, "last_device_phases", None)
-            if split:
-                phases.update(split)
-            # batch span BEFORE the member resolutions: a caller that
-            # saw its result can already find the complete tree
-            sink.note_batch(
-                bid,
-                [s.trace_id for s in spans],
-                engine.digest,
-                bucket,
-                phases,
+        with profiler_span("serve_resolve"):
+            # featurize/device are shared per batch: every coalesced
+            # request EXPERIENCED the whole batch's featurize+device
+            # wall, so each observes the full value — that is its
+            # latency, not an amortized share.
+            feat, dev = t1 - t0, t2 - t1
+            # featurize padded onto ONE bucket, so the prepared batch's
+            # row count IS the bucket that served these requests — the
+            # per-bucket e2e histograms (queue+featurize+device) feed
+            # the load generator's p50/p99-per-bucket report
+            # (serve/loadgen.py)
+            bucket = getattr(batch, "batch_size", len(reqs))
+            # engine's per-call device split (h2d / dispatch / fetch) —
+            # same worker thread, so this is the call we just made
+            split = getattr(engine, "last_device_phases", None) or {}
+            if sink is not None:
+                # batch span BEFORE the member resolutions: a caller
+                # that saw its result can already find the complete tree
+                sink.note_batch(
+                    bid,
+                    [s.trace_id for s in spans],
+                    engine.digest,
+                    bucket,
+                    {"featurize": feat, "device": dev, **split},
+                )
+            cache = self._cache
+            cache_digest = (
+                getattr(engine, "servable_digest", None)
+                if cache is not None and not self._topk
+                else None
             )
-        cache = self._cache
-        cache_digest = (
-            getattr(engine, "servable_digest", None)
-            if cache is not None and not self._topk
-            else None
-        )
-        for i, (row, fut, t_enq, span) in enumerate(reqs):
-            reg.observe("serve.featurize_seconds", feat)
-            reg.observe("serve.device_seconds", dev)
-            reg.observe(f"serve.e2e.b{bucket}", t2 - t_enq)
-            if span is not None:
-                span.bucket = bucket
-                span.sink.complete(span)
-            if cache_digest is not None:
-                # insert BEFORE resolving the Future: a caller that
-                # saw its score can already hit the cache with it
-                cache.insert(cache_digest, *row, float(pctr[i]))
-            if self._topk:
-                # the scoring engine's index rides along: candidate
-                # ids are only meaningful against the index that
-                # produced them, and during a rollout canary different
-                # replicas serve different indexes — a consumer that
-                # read "the fleet's" index instead would resolve ids
-                # against the wrong catalog (serve/cascade.py)
-                fut.set_result((ids[i], scores[i], engine.item_index))
-            else:
-                fut.set_result(float(pctr[i]))
-        reg.counter_add("serve.requests", len(reqs))
-        reg.counter_add("serve.batches", 1.0)
-        reg.observe("serve.batch_size", float(len(reqs)))
+            for i, (row, fut, t_enq, span) in enumerate(reqs):
+                reg.observe("serve.featurize_seconds", feat)
+                reg.observe("serve.device_seconds", dev)
+                reg.observe(f"serve.e2e.b{bucket}", t2 - t_enq)
+                if span is not None:
+                    span.bucket = bucket
+                    span.sink.complete(span)
+                if cache_digest is not None:
+                    # insert BEFORE resolving the Future: a caller that
+                    # saw its score can already hit the cache with it
+                    cache.insert(cache_digest, *row, float(pctr[i]))
+                if self._topk:
+                    # the scoring engine's index rides along: candidate
+                    # ids are only meaningful against the index that
+                    # produced them, and during a rollout canary
+                    # different replicas serve different indexes — a
+                    # consumer that read "the fleet's" index instead
+                    # would resolve ids against the wrong catalog
+                    # (serve/cascade.py)
+                    fut.set_result((ids[i], scores[i], engine.item_index))
+                else:
+                    fut.set_result(float(pctr[i]))
+            reg.counter_add("serve.requests", len(reqs))
+            reg.counter_add("serve.batches", 1.0)
+            reg.observe("serve.batch_size", float(len(reqs)))
+            for leg, seconds in split.items():
+                reg.observe(f"serve.{leg}_seconds", seconds)
+        reg.observe("serve.resolve_seconds", time.perf_counter() - t2)

@@ -41,7 +41,7 @@ import jax
 from xflow_tpu.chaos import failpoint
 from xflow_tpu.config import Config
 from xflow_tpu.io.batch import Batch, pad_batch_rows, remap_batch
-from xflow_tpu.obs import NULL_OBS
+from xflow_tpu.obs import NULL_OBS, profiler_span
 from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
 
 DEFAULT_BUCKETS = (1, 8, 64, 512)
@@ -175,11 +175,11 @@ class PredictEngine:
         self.item_index: dict | None = None
         self._index_arr = None
         self.topk_k = 0
-        # per-call device split (ISSUE 16): {"h2d": s, "execute": s}
-        # of the LAST prepared call.  Written and read on the one
-        # batcher worker thread that drives this engine clone, so the
-        # batch span (obs/reqtrace.py) can carve its device phase
-        # without a lock.
+        # per-call device split: {"h2d": s, "dispatch": s, "fetch": s}
+        # of the LAST prepared call (_put_dispatch_fetch).  Written and
+        # read on the one batcher worker thread that drives this engine
+        # clone, so the batcher's registry and the batch span
+        # (obs/reqtrace.py) can carve the device phase without a lock.
         self.last_device_phases: dict | None = None
         if warm:
             self.warm()
@@ -566,37 +566,79 @@ class PredictEngine:
             rows, self.step._model_view(batch), state["dense"]
         )
 
-    def _run_aot(self, tag: str, jitted, batch: Batch, extra=()):
-        """Compile-once-per-bucket execution shared by the topk and
-        item-embed legs (predict_prepared keeps its own body — its
-        multi-host gather and compact re-validation don't apply
-        here).  ``extra`` arrays ride as leading executable arguments
-        after state."""
-        key = (tag, self.topk_k, batch.batch_size, batch.max_nnz,
-               batch.hot_nnz)
+    def _put_dispatch_fetch(
+        self, key, jitted, batch: Batch, extra=(), *, beat: str,
+        validate: bool = False, host_local: bool = False,
+    ):
+        """One bucketed device call in its three host legs, shared by
+        predict_prepared and the topk / item-embed legs.  Each leg is
+        an ``xf.serve_*`` span on the profiler's timeline (obs/__init__
+        .py::profiler_span: there with or without a live ``Obs``) and
+        a clock of ``last_device_phases``: ``h2d`` the transfer in
+        (with ``validate``, the compact-wire check of the request batch
+        before it), ``dispatch`` the executable's call until it RETURNS
+        (the host enqueueing the program), ``fetch`` the wait for the
+        device and the copy out.  Compiled once per ``key`` (``jitted``
+        lowered over state, ``extra`` leading arrays, the batch); a
+        compile is the phase ``serve_compile`` and no leg's.  ``host_local``
+        gathers a multi-host result to this host's rows first."""
         t_call = time.perf_counter()
-        arrays = self.step.put_batch(batch, predict=True)
-        t_h2d = time.perf_counter()
+        with profiler_span("serve_h2d"):
+            if validate:
+                # TrainStep validates compact-wire invariants only on
+                # its FIRST batch (fine for uniform loader traffic);
+                # serving traffic is heterogeneous, so a value-carrying
+                # request after warmup would otherwise have its vals
+                # silently replaced by 1.0 — validate every batch
+                # (O(B·K) numpy, noise next to the device call at
+                # serving batch sizes).
+                from xflow_tpu.parallel.step import validate_compact_batch
+
+                validate_compact_batch(batch)
+            # books the 'h2d' phase with a live Obs; ``predict``
+            # matters to a tiered store alone, and serving pins dense
+            arrays = self.step.put_batch(batch, predict=True)
+        t_run = t_h2d = time.perf_counter()
         exe = self._compiled.get(key)
         if exe is None:
             with self.obs.phase("serve_compile"):
-                exe = jitted.lower(
-                    self.state, *extra, arrays
-                ).compile()
+                exe = jitted.lower(self.state, *extra, arrays).compile()
             self._compiled[key] = exe
             self.obs.counter("serve.compiles")
-        with self.obs.phase("serve_execute"):
+            t_run = time.perf_counter()
+        with profiler_span("serve_dispatch"):
             out = exe(self.state, *extra, arrays)
-            out = jax.tree.map(
-                lambda a: np.asarray(jax.device_get(a)), out
-            )
+        t_ret = time.perf_counter()
+        with profiler_span("serve_fetch"):
+            if host_local and jax.process_count() > 1:
+                from jax.experimental import multihost_utils
+
+                out = multihost_utils.global_array_to_host_local_array(
+                    out, self.mesh, self.step._bsharding.spec
+                )
+            # booked: the leg's clock below is serve.fetch_seconds in
+            # the batcher's registry, Obs or no Obs (xf: ignore[XF002])
+            out = jax.tree.map(np.asarray, jax.device_get(out))
         self.last_device_phases = {
             "h2d": t_h2d - t_call,
-            "execute": time.perf_counter() - t_h2d,
+            "dispatch": t_ret - t_run,
+            "fetch": time.perf_counter() - t_ret,
         }
         if self.obs.flight is not None:
-            self.obs.flight.note_serve(f"{tag}:b{batch.batch_size}")
+            # serve-channel heartbeat (obs/flight.py): one device call
+            # completed — the watchdog's "is scoring moving?" signal,
+            # tagged with the bucket it ran in (forensics for "which
+            # shape was in flight when serving wedged")
+            self.obs.flight.note_serve(f"{beat}:b{batch.batch_size}")
         return out
+
+    def _run_aot(self, tag: str, jitted, batch: Batch, extra=()):
+        """Compile-once-per-bucket execution of the topk and item-embed
+        legs.  ``extra`` arrays ride as leading executable arguments
+        after state."""
+        key = (tag, self.topk_k, batch.batch_size, batch.max_nnz,
+               batch.hot_nnz)
+        return self._put_dispatch_fetch(key, jitted, batch, extra, beat=tag)
 
     def topk_prepared(
         self, batch: Batch
@@ -741,48 +783,9 @@ class PredictEngine:
         """Run one already-prepared, bucket-sized batch on the device;
         returns pctr for every row (padding included).  This is the
         'device' leg of the batcher's latency accounting: h2d +
-        execute + fetch."""
+        dispatch + fetch."""
         key = (batch.batch_size, batch.max_nnz, batch.hot_nnz)
-        if self.step.compact_wire:
-            # TrainStep validates compact-wire invariants only on its
-            # FIRST batch (fine for uniform loader traffic); serving
-            # traffic is heterogeneous, so a value-carrying request
-            # after warmup would otherwise have its vals silently
-            # replaced by 1.0 — validate every batch (O(B·K) numpy,
-            # noise next to the device call at serving batch sizes).
-            from xflow_tpu.parallel.step import validate_compact_batch
-
-            validate_compact_batch(batch)
-        t_call = time.perf_counter()
-        arrays = self.step.put_batch(batch)  # books the 'h2d' phase
-        t_h2d = time.perf_counter()
-        exe = self._compiled.get(key)
-        if exe is None:
-            with self.obs.phase("serve_compile"):
-                exe = (
-                    jax.jit(self.step._predict_impl)
-                    .lower(self.state, arrays)
-                    .compile()
-                )
-            self._compiled[key] = exe
-            self.obs.counter("serve.compiles")
-        with self.obs.phase("serve_execute"):
-            garr = exe(self.state, arrays)
-            if jax.process_count() > 1:
-                from jax.experimental import multihost_utils
-
-                garr = multihost_utils.global_array_to_host_local_array(
-                    garr, self.mesh, self.step._bsharding.spec
-                )
-            out = np.asarray(jax.device_get(garr))
-        self.last_device_phases = {
-            "h2d": t_h2d - t_call,
-            "execute": time.perf_counter() - t_h2d,
-        }
-        if self.obs.flight is not None:
-            # serve-channel heartbeat (obs/flight.py): one device call
-            # completed — the watchdog's "is scoring moving?" signal,
-            # tagged with the bucket it ran in (forensics for "which
-            # shape was in flight when serving wedged")
-            self.obs.flight.note_serve(f"execute:b{key[0]}")
-        return out
+        return self._put_dispatch_fetch(
+            key, self.step.predict, batch, beat="execute",
+            validate=self.step.compact_wire, host_local=True,
+        )

@@ -68,6 +68,7 @@ import time
 from concurrent.futures import Future
 from typing import Any, Sequence
 
+from xflow_tpu.obs import GcPauses
 from xflow_tpu.obs.registry import Histogram, MetricsRegistry
 from xflow_tpu.obs.schema import health_row
 from xflow_tpu.serve.batcher import MicroBatcher, stats_row_from_snapshot
@@ -284,6 +285,13 @@ class ReplicaFleet:
         if self.cache is not None:
             self.cache.registry = self.registry
             self.cache.set_current(self.servable)
+        # collector pauses, on whichever thread they ran: ``xf.gc``
+        # spans beside the workers' own, ``serve.gc_pause_seconds`` in
+        # the stats window — what tells a stalled window that was a
+        # collection from one that was not.  Hooked last, unhooked by
+        # close().
+        self._gc_pauses = GcPauses(self.registry, "serve")
+        self._gc_pauses.install()
 
     # -- construction -------------------------------------------------------
 
@@ -1010,8 +1018,9 @@ class ReplicaFleet:
         ``serve_shed`` row (admitted/shed per cause + live backlog).
         Window counters reset; returns ``{"stats": ..., "shed": ...}``.
         """
+        self._gc_pauses.flush()
         snap = self.registry.snapshot(reset=True)
-        row = stats_row_from_snapshot(snap)
+        row = stats_row_from_snapshot(snap, len(self.batchers))
         per_bucket = {}
         pre = "serve.e2e.b"
         for name, h in sorted(snap.hists.items()):
@@ -1067,6 +1076,7 @@ class ReplicaFleet:
         """Non-destructive live view (the /v1/stats endpoint): pooled
         registry snapshot WITHOUT reset + admission counters + rollout
         state."""
+        self._gc_pauses.flush()
         snap = self.registry.snapshot(reset=False)
         with self._lock:
             shed = self._shed_row_locked()
@@ -1075,7 +1085,7 @@ class ReplicaFleet:
             "digest": self.digest,
             "servable": self.servable,
             "replicas": self.replicas,
-            "stats": stats_row_from_snapshot(snap),
+            "stats": stats_row_from_snapshot(snap, len(self.batchers)),
             "shed": shed,
             "depth": self.depth(),
             "queue_age_s": round(self.queue_age_s(), 6),
@@ -1101,6 +1111,9 @@ class ReplicaFleet:
             first = not self._closed
             self._closed = True
         if first:
+            # first of all, so that no failure below leaves the hook in
+            # gc.callbacks; what it gathered still lands in the final row
+            self._gc_pauses.remove()
             try:
                 for b in self.batchers:
                     b.close()
