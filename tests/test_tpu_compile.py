@@ -7,6 +7,7 @@ One file on purpose, and the topology only inside a fixture: one process
 at a time may load the TPU's library, so under several test workers only
 the worker that is given this file describes the chip."""
 
+import contextlib
 import os
 import re
 import types
@@ -36,8 +37,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture()
-def no_compile_cache():
+@contextlib.contextmanager
+def _without_compile_cache():
     """A compile for a described device is written to the persistent
     cache and cannot be read back without the device: keep it out."""
     from jax.experimental.compilation_cache import compilation_cache
@@ -45,9 +46,17 @@ def no_compile_cache():
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def no_compile_cache():
+    with _without_compile_cache():
+        yield
 
 
 # rows of 128 outputs: the flagship's hot and cold planes (B=131072 x 28 /
@@ -198,6 +207,37 @@ def test_dict_cold_rows_compile_for_v5e_at_flagship(
     )
 
 
+def _optimizer_passes(text: str, elements: int) -> list[tuple[str, str]]:
+    """(results, body) of every fusion of a compiled program that the
+    source booked to xf.optimizer and that yields float32 arrays of
+    ``elements`` rows: the types on the left of ``fusion(``, and the text
+    of the computation it calls, whose parameters are its operands."""
+    bodies, current = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([^ ]+) \(.*\{$", line)
+        if head:
+            current = bodies.setdefault(head.group(1), [])
+        elif current is not None:
+            current.append(line)
+    out = []
+    for line in text.splitlines():
+        results, fusion, rest = line.partition(" fusion(")
+        if (
+            fusion and "xf.optimizer" in rest
+            and re.search(rf"f32\[{elements}[,\]]", results)
+        ):
+            called = re.search(r"calls=%?([^ ,)]+)", rest).group(1)
+            out.append((results.split(" = ", 1)[1], "\n".join(bodies[called])))
+    return out
+
+
+def _table_sized_copies(text: str, elements: int) -> list[str]:
+    return [
+        line.split("metadata")[0] for line in text.splitlines()
+        if re.search(rf"= \(?f32\[{elements}[,\]][^=]* copy(?:-start)?\(", line)
+    ]
+
+
 def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
     topo, no_compile_cache
 ):
@@ -249,6 +289,17 @@ def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
         "weights_u8": shaped((b,), jnp.uint8, step._bsharding),
     }
     text = step.train.lower(state, arrays).compile().as_text()
+    # a chip's block of w goes through the FTRL pass on its flat view,
+    # whole (8,128) tiles, sharded on its only axis; v's padded rows keep
+    # their shape (_optimizer_pass; PERF.md section 6, PR 37), and the
+    # collectives below are counted as before
+    block = cfg.table_size // 4
+    (v_out, v_body), (w_out, w_body) = sorted(_optimizer_passes(text, block))
+    # (a block this small may sit in another memory space: "S(1)")
+    assert w_out.count(f"f32[{block}]{{0:T(1024)") == 3, w_out
+    assert f"f32[{block},1]" not in w_out + w_body, (w_out, w_body)
+    assert v_out.count(f"f32[{block},10]{{0,1:T(8,128)}}") == 3, v_out
+    assert f"f32[{block * 10}]" not in v_body, v_body
     found = collectives_in(text)
     assert " while(" in text and found
     assert not [c for c in found if c["in_loop"]], found
@@ -258,11 +309,15 @@ def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
     assert len(found) <= 4 * 2 + 2 + 3 + 2, found  # + the reduce-scatters' fix-ups
 
 
-def _lowered_cell_step(topo, config: str, planes: dict):
+def _lowered_cell_step(
+    topo, config: str, planes: dict, ships_slots: bool = True
+):
     """The train step of a one-chip benchmark configuration
     (benchmarks/configs/<config>.json) lowered for a described v5e, its
     dictionary-wire batch given as plane shapes (``planes``: name ->
-    (shape, dtype), the capacities of one real batch)."""
+    (shape, dtype), the capacities of one real batch; ``ships_slots``:
+    whether the family reads field ids, so that its wire ships the slots
+    planes)."""
     from benchmarks.harness import manifest
     from xflow_tpu.config import Config
     from xflow_tpu.models import make_model
@@ -278,7 +333,7 @@ def _lowered_cell_step(topo, config: str, planes: dict):
     mesh = meshes.make_mesh(1, devices=list(topo.devices))
     model = make_model(cfg)
     step = TrainStep(model, make_optimizer(cfg), cfg, mesh)
-    assert step.wire_format == "dict" and step._ship_slots
+    assert step.wire_format == "dict" and step._ship_slots == ships_slots
     assert step._hot_impl == "mxu"
 
     def shaped(shape, dtype, sharding):
@@ -313,13 +368,32 @@ def _program_peak(compiled) -> int:
     )
 
 
-def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
-    topo, no_compile_cache
-):
+@pytest.fixture(scope="module")
+def mvm_cell_step(topo):
+    """(cfg, lowered, compiled): the MVM train step at the geometry of the
+    benchmark's mvm_tb.train_packed (benchmarks/configs/
+    mvm_ftrl_criteo_tb.json: 2^25 rows x 10, B=131072, 8 + 32 slots, 40
+    fields, the dictionary wire's plane capacities of one real batch,
+    seed 1) for a described v5e, compiled once (3 min here) for the tests
+    that read it."""
+    u8, u16, u32 = np.uint8, np.uint16, np.uint32
+    with _without_compile_cache():
+        cfg, _, lowered = _lowered_cell_step(topo, "mvm_ftrl_criteo_tb", {
+            "cw_cu": ((43008,), u32), "cw_cun": ((1,), np.int32),
+            "cw_ci": ((688128,), u16), "cw_ct": ((262144,), u32),
+            "cw_cf": ((118784,), u8), "cw_cc": ((131072,), u8),
+            "cw_lb": ((16384,), u8), "cw_wb": ((16384,), u8),
+            "cw_h8": ((2490368,), u8), "cw_hx": ((1835008,), u16),
+            "cw_hxh": ((0,), u8), "cw_hf": ((524288,), u8),
+            "cw_hc": ((131072,), u8),
+            "cw_cs": ((950272,), u8), "cw_hs": ((4194304,), u8),
+        })
+        return cfg, lowered, lowered.compile()
+
+
+def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(mvm_cell_step):
     """The MVM train step at the geometry of the benchmark's
-    mvm_tb.train_packed (benchmarks/configs/mvm_ftrl_criteo_tb.json: 2^25
-    rows x 10, B=131072, 8 + 32 slots, 40 fields, the dictionary wire's
-    plane capacities of one real batch, seed 1) for a described v5e.
+    mvm_tb.train_packed for a described v5e (``mvm_cell_step``).
     Lowered: every contraction asks for float32 (Precision.HIGHEST), the
     one-hot field contractions of models/blocks.py among them:
     ``field_contract`` [B, K, F] x [B, K, D] over K, in ``logit`` and
@@ -336,17 +410,7 @@ def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
     the chip with the room the file's ``reduced`` argues from (9.19 GiB
     of 15.75, 9.22 with the gather; at 2^26 rows the compiler refuses
     it)."""
-    u8, u16, u32 = np.uint8, np.uint16, np.uint32
-    cfg, _, lowered = _lowered_cell_step(topo, "mvm_ftrl_criteo_tb", {
-        "cw_cu": ((43008,), u32), "cw_cun": ((1,), np.int32),
-        "cw_ci": ((688128,), u16), "cw_ct": ((262144,), u32),
-        "cw_cf": ((118784,), u8), "cw_cc": ((131072,), u8),
-        "cw_lb": ((16384,), u8), "cw_wb": ((16384,), u8),
-        "cw_h8": ((2490368,), u8), "cw_hx": ((1835008,), u16),
-        "cw_hxh": ((0,), u8), "cw_hf": ((524288,), u8),
-        "cw_hc": ((131072,), u8),
-        "cw_cs": ((950272,), u8), "cw_hs": ((4194304,), u8),
-    })
+    cfg, lowered, compiled = mvm_cell_step
     dots = [
         line for line in lowered.as_text().splitlines() if "dot_general" in line
     ]
@@ -361,7 +425,6 @@ def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
     # the sum by field in logit and in grad_logit; grad_logit's pick
     assert (len(by_field), len(over_k), len(over_f)) == (3, 2, 1), dots
     assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
-    compiled = lowered.compile()
     per_entry = (f"f32[{b * k},{cfg.v_dim}]", f"f32[{b},{k},{cfg.v_dim}]")
     picks = [
         line for line in _gather_lines(compiled)
@@ -370,6 +433,67 @@ def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(
     assert not picks, picks
     peak = _program_peak(compiled)
     assert 9.0 * (1 << 30) < peak < 9.5 * (1 << 30), peak
+
+
+def test_mvm_pass_keeps_its_padded_rows_on_v5e(mvm_cell_step):
+    """The trap beside the flat pass (_optimizer_pass; PERF.md section 6,
+    PR 37): a [2^25, 10] table costs 16 columns in (8,128) tiles, so its
+    flat view is not the same bytes and would be a copy of the state.
+    MVM's one pass stays ONE fusion over the padded rows, and the program
+    holds no table-sized copy."""
+    cfg, _, compiled = mvm_cell_step
+    text = compiled.as_text()
+    t, d = cfg.table_size, cfg.v_dim
+    ((results, _),) = _optimizer_passes(text, t)
+    assert results.count(f"f32[{t},{d}]{{0,1:T(8,128)}}") == 3, results
+    assert f"f32[{t * d}]" not in text
+    assert not _table_sized_copies(text, t)
+    assert not _table_sized_copies(text, t * d)
+
+
+def test_lr_step_runs_its_pass_on_the_flat_view_and_fits_a_v5e(
+    topo, no_compile_cache
+):
+    """The LR train step at the geometry of the benchmark's
+    lr_tb.train_packed (benchmarks/configs/lr_ftrl_criteo_tb.json: 2^28
+    rows of one column, B=131072, 12 + 28 slots, the dictionary wire's
+    plane capacities of one real batch, seed 1) for a described v5e.  The
+    device's default for f32[2^28, 1] is tiles of ONE sublane
+    (``{0,1:T(1,128)}``), on which the FTRL pass ran at 348 GB/s; the
+    flat view of the same bytes has whole 8 x 128 tiles
+    (``{0:T(1024)}``), which the scatter beside the pass already reads
+    (_optimizer_pass; PERF.md section 6, PR 37).  Compiled: the one
+    table-sized fusion under xf.optimizer takes and yields the flat
+    arrays, nothing that runs in that scope is left on one-sublane tiles
+    (the views are bitcasts), no table-sized copy is made for it, and the
+    program's peak is the parent's 4.018 GiB."""
+    u8, u16, u32 = np.uint8, np.uint16, np.uint32
+    cfg, _, lowered = _lowered_cell_step(topo, "lr_ftrl_criteo_tb", {
+        "cw_cu": ((53248,), u32), "cw_cun": ((1,), np.int32),
+        "cw_ci": ((1228800,), u16), "cw_ct": ((294912,), u32),
+        "cw_cf": ((184320,), u8), "cw_cc": ((131072,), u8),
+        "cw_lb": ((16384,), u8), "cw_wb": ((16384,), u8),
+        "cw_h8": ((2293760,), u8), "cw_hx": ((1490944,), u8),
+        "cw_hxh": ((745472,), u8), "cw_hf": ((458752,), u8),
+        "cw_hc": ((131072,), u8),
+    }, ships_slots=False)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    t = cfg.table_size
+    flat, column = f"f32[{t}]{{0:T(1024)}}", f"f32[{t},1]"
+    ((results, body),) = _optimizer_passes(text, t)
+    assert results.count(flat) == 3 and column not in results, results
+    operands = [line for line in body.splitlines() if " parameter(" in line]
+    assert len(operands) == 4 and all(f"f32[{t}]" in o for o in operands)
+    assert column not in body, body
+    left = [
+        line.split("metadata")[0] for line in text.splitlines()
+        if "xf.optimizer" in line and column in line.split("metadata")[0]
+        and " bitcast(" not in line
+    ]
+    assert not left, left
+    assert not _table_sized_copies(text, t)
+    assert _program_peak(compiled) <= 1.01 * 4.018 * (1 << 30)
 
 
 def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(

@@ -413,6 +413,10 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # the route to lay their rows out by row gathers, 0 where every
         # table goes column by column (step.py::dict_cold_rows)
         "cold_row_layout_slots_per_step": (int, float),
+        # elements a step's whole-array optimizer passes ran on the flat
+        # [T] view: the tables of one column (step.py::_optimizer_pass),
+        # 0 where every table is wider or the update touches rows alone
+        "flat_pass_elements_per_step": (int, float),
         # of wire_bytes_per_example, the planes of field ids (slots_u8 /
         # hot_slots_u8, the dictionary wire's cw_cs / cw_hs, the full
         # wire's slots / hot_slots): 0 where none ships, as for a model

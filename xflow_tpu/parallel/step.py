@@ -709,6 +709,9 @@ class TrainStep:
                 and cfg.sequential_inner == "hot"
             )
         )
+        # elements a step's whole-array optimizer passes run on the flat
+        # view (_optimizer_pass: the tables of one column)
+        self._flat_pass_elements = self._count_flat_pass_elements()
         # Hierarchical parameter store (Config.store_mode; store/):
         # under 'tiered' the table state is the store's hot tier + host
         # cold rows, the wire is the store's refs/miss format (the
@@ -765,6 +768,29 @@ class TrainStep:
             else "full"
         )
 
+    def _count_flat_pass_elements(self) -> int:
+        """Elements that one train step's whole-array optimizer passes
+        (_optimizer_pass) run on the flat view, from shapes: every table
+        of one column, once per pass the update mode makes over it.
+        Dense mode makes one pass a step; the sequential dense inner one
+        a slice; the hot sequential inner one a slice over the [H, 1]
+        heads and, where the window ends dense, one over the tables; the
+        touched-rows modes none."""
+        cfg = self.cfg
+        one_column = sum(spec.dim == 1 for spec in self.model.tables())
+        sequential = cfg.update_mode == "sequential"
+        if cfg.update_mode == "sparse" or (
+            sequential and cfg.sequential_inner == "sparse"
+        ):
+            return 0
+        slices = cfg.microbatch if sequential else 1
+        if slices > 1 and cfg.sequential_inner == "hot":
+            return one_column * (
+                cfg.hot_size * slices
+                + cfg.table_size * (self._windowend == "dense")
+            )
+        return one_column * cfg.table_size * slices
+
     def _book_wire(
         self, nbytes: int, examples: int, cb=None, cold_slots: int = 0,
         slots_bytes: int = 0, hot_slots: int = 0,
@@ -796,7 +822,9 @@ class TrainStep:
         step reads the ``cold_plan``, ``cold_row_layout_slots`` is the
         padded cold slots of every table wide enough for dict_cold_rows
         to lay its rows out by row gathers: 0 where every table goes
-        column by column."""
+        column by column.  ``flat_pass_elements`` is what the step's
+        whole-array optimizer passes ran on the flat view
+        (_count_flat_pass_elements): 0 where no table has one column."""
         self.obs.counter("wire.bytes", nbytes)
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")
@@ -814,6 +842,9 @@ class TrainStep:
                     "wire.cold_row_layout_slots",
                     cold_slots * self._row_layout_tables,
                 )
+            self.obs.counter(
+                "wire.flat_pass_elements", self._flat_pass_elements
+            )
             plain = hot_slots * self._plain_hot_row_bytes
             self.obs.counter(
                 "wire.plain_hot_slots", hot_slots * self._plain_hot_tables
@@ -1630,8 +1661,33 @@ class TrainStep:
     @jax.named_scope("xf.optimizer")
     def _optimizer_pass(self, table: dict, g: jax.Array) -> dict:
         """The optimizer recurrence over whole arrays: the dense [T, D]
-        pass, and the [H, D] head of the hot sequential inner."""
-        return self.optimizer.update_rows(table, g)
+        pass, and the [H, D] head of the hot sequential inner.
+
+        A table of ONE column runs it on the flat [T] view of the same
+        bytes.  The TPU lays an f32[T, 1] out in tiles of one sublane
+        by 128 lanes (``{0,1:T(1,128)}``) and the flat view in whole
+        8 x 128 tiles (``{0:T(1024)}``), which the scatter beside the
+        pass reads already: a reshape between the two is a bitcast, and
+        the pass over one-sublane tiles ran at 348 GB/s where the same
+        fusion over whole tiles reads 670 (PERF.md section 6, PR 37).
+        The recurrence is elementwise, so XLA would cancel the reshapes
+        through it and put the fusion back on [T, 1]: the barriers on
+        both sides hold the view.  Same operations per element in the
+        same order: the new state is bit for bit update_rows(table, g).
+        A table of more columns keeps its shape, and must: its rows are
+        padded in memory (10 -> 16, 160 -> 256 columns), so its flat
+        view is a copy of the state, and [T / 1024, 1024] is a trap for
+        T x 1 too (reduces over the unit axis and three table-sized
+        copies: CHANGES.md, PR 37)."""
+        if g.ndim != 2 or g.shape[-1] != 1:
+            return self.optimizer.update_rows(table, g)
+        rows, flat_g = jax.lax.optimization_barrier(
+            ({k: a.reshape(-1) for k, a in table.items()}, g.reshape(-1))
+        )
+        new = jax.lax.optimization_barrier(
+            self.optimizer.update_rows(rows, flat_g)
+        )
+        return {k: a.reshape(g.shape) for k, a in new.items()}
 
     @jax.named_scope("xf.metrics")
     def _batch_logloss(

@@ -1091,9 +1091,12 @@ class Trainer:
                     snap.counters["wire.cold_slots"] / batches
                 )
                 # and what those slots move in bytes of table rows, with
-                # the hot slots of a table that opted out of the MXU head
+                # the hot slots of a table that opted out of the MXU head;
+                # and the elements of the one-column tables whose optimizer
+                # pass ran on the flat view (step.py::_optimizer_pass)
                 for name in (
-                    "gather_row_bytes", "scatter_row_bytes", "plain_hot_slots"
+                    "gather_row_bytes", "scatter_row_bytes", "plain_hot_slots",
+                    "flat_pass_elements",
                 ):
                     stats["_wire"][f"{name}_per_step"] = round(
                         snap.counters[f"wire.{name}"] / batches
