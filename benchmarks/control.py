@@ -7,9 +7,9 @@ holds the program to the reference and by no other.
 
     python3 benchmarks/control.py --workload lr_tb.train_packed --seed 7 [--seconds 5]
 
-A training cell's reference step is handed its gathered rows rounded; a
-serving cell's expected scores are summed from the artifact's weights
-rounded.  Everything else is the cell's own run: its corpus, its warm-up, a
+A training cell's reference step is handed its gathered rows, and the dense
+parameters of a family that owns any, rounded; a serving cell's expected
+scores are summed from the artifact's weights rounded.  Everything else is the cell's own run: its corpus, its warm-up, a
 short window, its check.  Prints one JSON line: the checks that failed and
 every number compared beside its limit; never a result line.  Exit 0 where
 the control failed as it must, 1 where it passed or another check failed.
@@ -36,21 +36,32 @@ REFERENCE_CHECKS = {"steps_match_reference", "answers_match_reference"}
 
 class InBfloat16:
     """A family of ``reference/`` whose forward and backward see the gathered
-    rows rounded to bfloat16; the FTRL recurrence stays in float32."""
+    rows, and its dense parameters where it has any, rounded to bfloat16 (a
+    gradient that autodiff takes through the rounding comes back rounded
+    too); the FTRL and SGD recurrences stay in float32."""
 
     def __init__(self, family):
-        self.family, self.TABLES = family, family.TABLES
-        self.USES_FIELDS = getattr(family, "USES_FIELDS", False)
+        self.family = family
+
+    def __getattr__(self, name):  # TABLES, USES_FIELDS, DENSE, matmuls
+        return getattr(self.family, name)
 
     @staticmethod
-    def _rounded(rows):
+    def _rounded(tree):
+        """Every float array of ``tree`` through bfloat16; field ids and
+        counts as they are."""
         import jax
         import jax.numpy as jnp
 
-        return jax.tree.map(lambda a: a.astype(jnp.bfloat16).astype(a.dtype), rows)
+        def one(a):
+            if not jnp.issubdtype(jnp.result_type(a), jnp.floating):
+                return a
+            return a.astype(jnp.bfloat16).astype(a.dtype)
 
-    def logit(self, rows, x, *fields):
-        return self.family.logit(self._rounded(rows), x, *fields)
+        return jax.tree.map(one, tree)
+
+    def logit(self, rows, x, *rest):
+        return self.family.logit(self._rounded(rows), x, *self._rounded(rest))
 
     def grad_logit(self, rows, x, *fields):
         return self.family.grad_logit(self._rounded(rows), x, *fields)

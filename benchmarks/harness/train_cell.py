@@ -120,9 +120,9 @@ def run(ctx: Ctx, build_corpus) -> Outcome:
             cfg, data, max(mix["reference_steps"], 4), ShardLoader,
             make_parse_fn(cfg.table_size, True, cfg.seed),
         )
+        family = manifest.reference(ctx.config_doc["family"])
         ref = refcheck.check_train_steps(
-            trainer, manifest.reference(ctx.config_doc["family"]),
-            batches[: mix["reference_steps"]], cfg,
+            trainer, family, batches[: mix["reference_steps"]], cfg,
         )
         checks["steps_match_reference"] = ref["ok"]
         dispatched += len(ref["steps"])
@@ -159,9 +159,10 @@ def run(ctx: Ctx, build_corpus) -> Outcome:
         "memory_peak_bytes": memory_peak,
         "held_bytes": held,
         "costs": costs.train_step(
-            fields, manifest.reference(ctx.config_doc["family"]).TABLES,
+            fields, family.TABLES,
             entries_per_step=kept,
             hot_share=float(np.mean([b.hot_mask.sum() for b in batches])) / kept,
+            matmuls=family.matmuls(ref["dense_shapes"]) if "dense_shapes" in ref else (),
         ),
         "peaks": ctx.peaks,
     }
@@ -191,6 +192,8 @@ def run(ctx: Ctx, build_corpus) -> Outcome:
             "short_epochs": beside(short_epochs, 0),
             # the last epoch's logloss less the first's
             "logloss_change": beside(loglosses[-1] - loglosses[0], 0.0, "<"),
+            # a family with dense parameters: each array beside its limit
+            **refcheck.dense_compared(ref["steps"]),
         },
     )
 

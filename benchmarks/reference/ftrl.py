@@ -12,27 +12,52 @@ table, no hot/cold split, no wire, no sharding.  It is handed
     labels, weights  float32 [B]   (weight 0 marks a padding example)
     slots   int32 [B, K]    the entry's field id, as the loader steered it
     num_fields              how many fields the configuration counts (static)
+    dense   {name: array}   the family's dense replicated parameters, by the
+                            program's names, as they were before the step
+    sgd_lr                  the rate of their plain SGD (static)
 
-and returns the step's logloss and the U rows as the step leaves them.
+and returns the step's logloss, the U rows and the dense parameters as the
+step leaves them (``{}`` for a family that has none).
 
 A family is a module with ``TABLES`` (table name -> row width),
 ``logit(rows, x)`` and ``grad_logit(rows, x)``.  One that sets
 ``USES_FIELDS = True`` reads the field ids and is called as
 ``logit(rows, x, slots, num_fields)``, ``grad_logit(rows, x, slots,
 num_fields)``; for every other family the two arguments stay out of the
-compiled program.  Dense (replicated) parameters are not in the protocol:
-every parameter a family has is a row of a hashed table.
+compiled program.
+
+A family that owns dense parameters (an MLP's weights, a cross stack) sets
+``DENSE = True`` and writes ``logit`` alone, called with the pytree last:
+``logit(rows, x[, slots, num_fields], dense)``.  Its gradients, of the
+gathered rows AND of ``dense``, are one ``jax.vjp`` of that definition at
+``highest`` matmul precision, handed the residual every family's rows get
+(``lr_worker.cc:116-118``: the mean over the real rows); the rows go through
+FTRL as every table's, the dense arrays take ``p - sgd_lr * g``, the
+program's plain SGD whatever the tables' optimizer
+(``parallel/step.py::apply_dense_sgd``).  The step runs over blocks of
+``DENSE_BLOCK`` examples, each block gathering its own rows and adding to the
+pushed gradients and to the dense gradient (a row's logit reads that row's
+entries alone), so that nothing of ``[B, K, D]`` is held for the whole batch
+and the step fits beside a live trainer.  Such a family also says which
+matmuls a step has to do, for the roofline's FLOPs
+(``harness/costs.py``): ``matmuls(shapes) -> [(k, n), ...]``, one ``[B, k] x
+[k, n]`` product each, from the shapes of the program's dense arrays by name
+(the widths are stated once, by the program's state).  For a family without
+``DENSE`` the two arguments stay out of the compiled program, which is the
+one it always was.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
 
 HYPER_KEYS = ("alpha", "beta", "lambda1", "lambda2")  # ftrl.h:17-20
+DENSE_BLOCK = 4096  # examples whose gathered rows a dense family's step holds at once
 
 
 def hyper_of(cfg) -> tuple[tuple[str, float], ...]:
@@ -80,17 +105,66 @@ def ftrl_update(row: dict, g, hyper: dict) -> dict:
     return {"param": w_new, "n": n_new, "z": z_new}
 
 
-@functools.partial(jax.jit, static_argnames=("family", "hyper", "num_fields"))
+def _dense_step(family, rows, idx, x, labels, weights, fields, dense, sgd_lr):
+    """The step of a family that owns dense parameters: (p [B], pushed
+    {table: [U, D]}, the dense arrays after ``p - sgd_lr * g``)."""
+    # the field ids go block by block with the entries; their count is static
+    planes, num_fields = (idx, x, labels, weights) + fields[:1], fields[1:]
+    num_real = jnp.maximum(jnp.sum(weights), 1.0)
+    block = math.gcd(x.shape[0], DENSE_BLOCK)
+
+    def blocks(a):
+        return a.reshape(-1, block, *a.shape[1:])
+
+    def one(carry, blk):
+        pushed, grad_dense = carry
+        idx_b, x_b, labels_b, weights_b, *slots_b = blk
+        gathered = {t: r["param"][idx_b] for t, r in rows.items()}  # [block, K, D]
+        logit, pullback = jax.vjp(
+            lambda g, d: family.logit(g, x_b, *slots_b, *num_fields, d),
+            gathered, dense,
+        )
+        p = sigmoid_clamped(logit)
+        grad_rows, grad_d = pullback((p - labels_b) * weights_b / num_real)
+        pushed = {
+            t: acc + jax.ops.segment_sum(
+                grad_rows[t].reshape(-1, acc.shape[-1]), idx_b.reshape(-1),
+                num_segments=acc.shape[0],
+            )
+            for t, acc in pushed.items()
+        }
+        return (pushed, jax.tree.map(jnp.add, grad_dense, grad_d)), p
+
+    zeros = (
+        {t: jnp.zeros_like(r["param"]) for t, r in rows.items()},
+        jax.tree.map(jnp.zeros_like, dense),
+    )
+    (pushed, grad_dense), p = jax.lax.scan(one, zeros, tuple(map(blocks, planes)))
+    new_dense = jax.tree.map(lambda a, g: a - sgd_lr * g, dense, grad_dense)
+    return p.reshape(-1), pushed, new_dense
+
+
+@functools.partial(
+    jax.jit, static_argnames=("family", "hyper", "num_fields", "sgd_lr")
+)
 def train_step(
-    family, rows, idx, x, labels, weights, hyper, slots=None, num_fields=0
+    family, rows, idx, x, labels, weights, hyper, slots=None, num_fields=0,
+    dense=None, sgd_lr=0.0,
 ):
-    """``family`` is a reference module (``logit``, ``grad_logit``);
-    ``hyper`` a hashable tuple of (name, value) FTRL settings; ``slots`` and
-    ``num_fields`` go to a family that declares ``USES_FIELDS`` and to no
-    other."""
+    """``family`` is a reference module (``logit`` and, unless it sets
+    ``DENSE``, ``grad_logit``); ``hyper`` a hashable tuple of (name, value)
+    FTRL settings; ``slots`` and ``num_fields`` go to a family that declares
+    ``USES_FIELDS`` and to no other, ``dense`` and ``sgd_lr`` to one that
+    declares ``DENSE`` and to no other.  Returns (logloss, rows, dense)."""
     h = dict(hyper)
     fields = (slots, num_fields) if getattr(family, "USES_FIELDS", False) else ()
     with jax.default_matmul_precision("highest"):
+        if getattr(family, "DENSE", False):
+            p, pushed, new_dense = _dense_step(
+                family, rows, idx, x, labels, weights, fields, dense, sgd_lr
+            )
+            new_rows = {t: ftrl_update(rows[t], g, h) for t, g in pushed.items()}
+            return logloss(labels, p, weights), new_rows, new_dense
         gathered = {t: r["param"][idx] for t, r in rows.items()}  # [B, K, D]
         p = sigmoid_clamped(family.logit(gathered, x, *fields))
         ll = logloss(labels, p, weights)
@@ -103,4 +177,4 @@ def train_step(
                 occ, idx.reshape(-1), num_segments=rows[t]["param"].shape[0]
             )
             new_rows[t] = ftrl_update(rows[t], pushed, h)
-    return ll, new_rows
+    return ll, new_rows, {}

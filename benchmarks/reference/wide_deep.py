@@ -1,0 +1,58 @@
+"""Wide & Deep (Cheng et al., "Wide & Deep Learning for Recommender Systems",
+DLRS 2016) as the program defines its family (``models/wide_deep.py``): a
+sparse linear term beside an embedding tower with one hidden layer.
+
+    tower[f, :] = sum over the entries i of the row with field f:  emb_i * x_i
+    h           = ReLU(flatten(tower) W1 + b1)          [F * E] -> [H]
+    logit       = sum_i w_i x_i  +  h W2 + b2           [H] -> 1
+
+An entry whose field is outside ``[0, num_fields)`` adds nothing to the tower
+and has gradient 0 there; its linear term stays.  ``w`` and ``emb`` are rows
+of hashed tables under FTRL; ``w1, b1, w2, b2`` are dense replicated
+parameters under plain SGD (``reference/ftrl.py``: the ``DENSE`` protocol,
+gradients by ``jax.vjp`` of this definition).  The dense widths are read off
+the arrays, so one file serves every ``hidden_dim``; ``EMB_DIM`` is the
+program's default, what ``TABLES`` states for the byte counts and what the
+check holds the program's table to.  This is the
+program's family as it stands, not a published configuration: no cell runs
+it yet.
+"""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+
+EMB_DIM = 8  # Config.emb_dim's default
+TABLES = {"w": 1, "emb": EMB_DIM}
+USES_FIELDS = True  # logit takes (slots, num_fields)
+DENSE = True  # ... and the dense pytree last; no grad_logit
+
+
+def tower(emb, x, slots, num_fields: int):
+    """emb [B, K, E] gathered rows, x [B, K], slots [B, K] -> [B, F * E]: each
+    inside entry's ``emb_i * x_i`` added to its row's and field's sum."""
+    inside = (slots >= 0) & (slots < num_fields)
+    field = jnp.where(inside, slots, 0)
+    ex = jnp.where(inside[..., None], emb * x[..., None], 0.0)
+    row = jnp.arange(x.shape[0])[:, None]
+    sums = jnp.zeros((x.shape[0], num_fields, emb.shape[-1]), emb.dtype)
+    return sums.at[row, field].add(ex).reshape(x.shape[0], -1)
+
+
+def relu(a):
+    """Gradient 0 at 0 and below, as ``jax.nn.relu``'s."""
+    return jnp.where(a > 0.0, a, 0.0)
+
+
+def logit(rows: dict, x, slots, num_fields: int, dense: dict):
+    """rows["w"] [B, K, 1], rows["emb"] [B, K, E] gathered rows; dense
+    ``w1 [F * E, H], b1 [H], w2 [H, 1], b2 [1]`` -> [B]."""
+    wide = jnp.sum(rows["w"][..., 0] * x, axis=-1)
+    h = relu(tower(rows["emb"], x, slots, num_fields) @ dense["w1"] + dense["b1"])
+    return wide + (h @ dense["w2"] + dense["b2"])[:, 0]
+
+
+def matmuls(shapes: dict) -> list[tuple[int, int]]:
+    """The ``[B, k] x [k, n]`` products of one forward pass, from the dense
+    arrays' shapes."""
+    return [tuple(shapes["w1"]), tuple(shapes["w2"])]

@@ -67,7 +67,9 @@ def test_every_cell_resolves_to_its_files(cell):
     mix = manifest.traffic(entry["traffic"])
     assert callable(manifest.driver(mix["kind"]).run)
     family = manifest.reference(config["family"])
-    assert callable(family.logit) and callable(family.grad_logit)
+    # a family with dense parameters writes its logit alone (reference/ftrl.py)
+    assert callable(family.logit)
+    assert callable(family.matmuls if hasattr(family, "DENSE") else family.grad_logit)
     assert config["num_devices"] == entry["chips"]
     assert not manifest.PATH_SELECTORS & set(config)
     listed = next(c for c in DOC["configs"] if c["name"] == entry["config"])
@@ -243,6 +245,46 @@ def test_a_later_family_that_reads_field_ids_is_a_configuration_file(tmp_path):
     after = _digests(root)
     assert {k: after[k] for k in before} == before  # no existing file edited
     assert set(after) - set(before) == {"benchmarks/configs/made_up_mvm.json"}
+
+
+def test_a_later_family_with_dense_parameters_is_a_configuration_file(tmp_path):
+    """The room PR 38 made: a family that owns dense replicated parameters
+    (``reference/wide_deep.py``: the ``DENSE`` protocol) gets a cell as ONE
+    new file and manifest entries, under a mix that is there: the reference
+    step is handed the program's dense arrays, each comes back in
+    ``compared`` beside its limit, the step's matmuls are counted, and the
+    rehearsal ends with ``steps_match_reference`` true."""
+    root, before = _copy_of_the_tree(tmp_path)
+    with open(os.path.join(root, "benchmarks", "configs", "made_up_wide_deep.json"), "w") as f:
+        json.dump({
+            "source": "https://example.org/made-up", "family": "wide_deep",
+            "deployment": "made up", "model": "wide_deep", "optimizer": "ftrl",
+            "emb_dim": 8, "hidden_dim": 64, "v_init_scale": 0.01, "max_fields": 40,
+            "table_size_log2": 24, "batch_size": 16384, "max_nnz": 8,
+            "hot_size_log2": 14, "hot_nnz": 32, "num_devices": 1,
+            "assumed": {}, "reduced": {},
+            "rehearsal": {
+                "table_size_log2": 14, "batch_size": 512, "hot_size_log2": 8,
+                "max_nnz": 40,
+            },
+        }, f)
+    doc = _with_cell(
+        DOC, "made_up_wide_deep", "made_up_wide_deep.train_packed", "replay_packed_zipf"
+    )
+    last = _rehearse(root, doc, "made_up_wide_deep.train_packed")
+    assert last["checks"]["steps_match_reference"] is True
+    compared = last["compared"]
+    for array in ("w1", "b1", "w2", "b2"):
+        one = compared[f"dense_rel_err.{array}"]
+        assert one["value"] <= one["limit"] == refcheck.DENSE_RTOL
+        assert compared[f"dense_update_ulps.{array}"]["value"] > 0.0
+    assert compared["dense_update_max"]["value"] > 0.0
+    with open(os.path.join(root, ".bench_cache", "made_up_wide_deep.train_packed.last.json")) as f:
+        costs = json.load(f)["run"]["costs"]
+    assert costs["flops"] == 6.0 * 512 * (320 * 64 + 64)
+    after = _digests(root)
+    assert {k: after[k] for k in before} == before  # no existing file edited
+    assert set(after) - set(before) == {"benchmarks/configs/made_up_wide_deep.json"}
 
 
 def _names(subdir: str, ext: str) -> set:

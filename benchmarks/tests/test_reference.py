@@ -1,5 +1,6 @@
 """The plain references, and the check that holds the system to them."""
 
+import hashlib
 import json
 import types
 
@@ -10,7 +11,7 @@ import pytest
 
 from benchmarks import control
 from benchmarks.harness import refcheck
-from benchmarks.reference import fm, ftrl, lr, mvm
+from benchmarks.reference import dcn, ffm, fm, ftrl, lr, mvm, wide_deep
 
 HYPER = {"alpha": 5e-2, "beta": 1.0, "lambda1": 5e-5, "lambda2": 10.0}
 
@@ -138,6 +139,50 @@ def test_a_family_that_reads_no_fields_is_compiled_without_them():
     handed = ftrl.train_step.lower(lr, rows, idx, x, ones, ones, hyper, idx, 7)
     assert handed.as_text() == plain.as_text()
     assert not hasattr(lr, "USES_FIELDS") and not hasattr(fm, "USES_FIELDS")
+    # nor dense parameters and their rate, where it declares none (PR 38)
+    dense = {"w1": jnp.ones((3, 2))}
+    handed = ftrl.train_step.lower(lr, rows, idx, x, ones, ones, hyper, idx, 7, dense, 0.5)
+    assert handed.as_text() == plain.as_text()
+    assert not any(hasattr(f, "DENSE") for f in (lr, fm, mvm, ffm))
+
+
+# ``reference/ftrl.py::train_step`` on the inputs below, as PR 37's tree (the
+# last before the protocol learned of dense parameters) computes it on the CPU:
+# the logloss, and sha256 over the bytes of every row array it returns
+AS_BEFORE_DENSE_PARAMETERS = {
+    "lr": ("0x1.626b9c0000000p-1", "a5f8acdd947a90ed"),
+    "fm": ("0x1.6bc79a0000000p-1", "bb3bbebaf31816cb"),
+    "mvm": ("0x1.118fd40000000p-1", "0915c9c6ff3033af"),
+    "ffm": ("0x1.5917880000000p-1", "0c24f6597a4ef116"),
+}
+
+
+@pytest.mark.parametrize("family", [lr, fm, mvm, ffm], ids=lambda f: f.__name__.split(".")[-1])
+def test_a_family_without_dense_parameters_gets_the_step_it_always_had(family):
+    """The four families whose parameters are all table rows: on fixed inputs
+    the reference step returns, bit for bit, what it returned before the
+    protocol learned of dense parameters, and ``{}`` for them.  (A change of
+    the reference that is meant to move these numbers moves the pins with
+    it.)"""
+    rng = np.random.default_rng(7)
+    rows = {
+        t: {a: jnp.asarray(rng.normal(0, 0.1, (16, d)), jnp.float32) for a in ("param", "n", "z")}
+        for t, d in family.TABLES.items()
+    }
+    rows = {t: {**r, "n": jnp.abs(r["n"])} for t, r in rows.items()}
+    idx = jnp.asarray(rng.integers(0, 16, (8, 3)), jnp.int32)
+    slots = jnp.asarray(rng.integers(0, 40, (8, 3)), jnp.int32)
+    labels = jnp.asarray(rng.integers(0, 2, 8), jnp.float32)
+    ll, new, dense = ftrl.train_step(
+        family, rows, idx, jnp.ones((8, 3)), labels, jnp.ones(8),
+        tuple(HYPER.items()), slots, 40,
+    )
+    digest = hashlib.sha256()
+    for leaf in jax.tree.leaves(new):
+        digest.update(np.asarray(leaf).tobytes())
+    name = family.__name__.split(".")[-1]
+    assert (float(ll).hex(), digest.hexdigest()[:16]) == AS_BEFORE_DENSE_PARAMETERS[name]
+    assert dense == {}
 
 
 MAX_FIELDS = 4  # a dozen entries a row over four fields: every field sum has terms
@@ -147,10 +192,14 @@ MAX_FIELDS = 4  # a dozen entries a row over four fields: every field sum has te
 # of N(0, 1e-2) give it a deviation of 0.11 and each row's loss one of half
 # that, so the mean over 59 real rows has a deviation of 7e-3: three of them
 # (read: 0.6993 at hot_log2 0, 0.7034 at 5).
-FIRST_LOGLOSS_BAND = {"lr": 2e-3, "fm": 2e-3, "mvm": 2.2e-2}
+# The deep families: a tower of field sums of N(0, 1e-2) through He-drawn
+# layers, a logit of ~1e-2 (read: 0.6937 wide_deep, 0.6925 dcn).
+FIRST_LOGLOSS_BAND = {"lr": 2e-3, "fm": 2e-3, "mvm": 2.2e-2, "wide_deep": 2e-3, "dcn": 2e-3}
+FAMILIES = [("lr", lr), ("fm", fm), ("mvm", mvm), ("wide_deep", wide_deep), ("dcn", dcn)]
+DENSE_FAMILIES = [("wide_deep", wide_deep), ("dcn", dcn)]
 
 
-def _system(model: str, hot_log2: int):
+def _system(model: str, hot_log2: int, **fields):
     from xflow_tpu.config import Config
     from xflow_tpu.io.batch import make_batch
     from xflow_tpu.models import make_model
@@ -161,7 +210,7 @@ def _system(model: str, hot_log2: int):
     cfg = Config(
         model=model, optimizer="ftrl", table_size_log2=12, batch_size=64,
         max_nnz=6, hot_size_log2=hot_log2, hot_nnz=6, num_devices=1, seed=3,
-        max_fields=MAX_FIELDS,
+        max_fields=MAX_FIELDS, **fields,
     )
     mesh = make_mesh(1)
     mdl, opt = make_model(cfg), make_optimizer(cfg)
@@ -189,17 +238,255 @@ def _system(model: str, hot_log2: int):
     return system, batches, cfg
 
 
-@pytest.mark.parametrize("model, family", [("lr", lr), ("fm", fm), ("mvm", mvm)])
+@pytest.mark.parametrize("model, family", FAMILIES)
 @pytest.mark.parametrize("hot_log2", [0, 5])
 def test_system_step_agrees_with_the_reference(model, family, hot_log2):
-    """xflow_tpu's train step — wire, hot/cold split, dense FTRL pass —
-    against the plain reference, three steps running (the second and third
-    from a state that is no longer zero)."""
+    """xflow_tpu's train step — wire, hot/cold split, dense FTRL pass, and
+    for the deep families the autodiff backward and the plain SGD of their
+    dense parameters — against the plain reference, three steps running (the
+    second and third from a state that is no longer the drawn one)."""
     system, batches, cfg = _system(model, hot_log2)
     got = refcheck.check_train_steps(system, family, batches, cfg)
     assert got["ok"], got
     assert all(s["touched_rows"] > 100 for s in got["steps"])
     assert got["steps"][0]["logloss"] == pytest.approx(np.log(2), abs=FIRST_LOGLOSS_BAND[model])
+    # dense numbers in the record and in ``compared`` for a family that owns
+    # dense parameters, and for no other
+    owns = hasattr(family, "DENSE")
+    assert all(("dense" in s) == owns for s in got["steps"])
+    compared = refcheck.dense_compared(got["steps"])
+    if not owns:
+        assert compared == {}
+        return
+    arrays = set(system.state["dense"])
+    assert all(set(s["dense"]) == arrays for s in got["steps"])
+    assert {k for k in compared if k.startswith("dense_rel_err.")} == {
+        f"dense_rel_err.{a}" for a in arrays
+    }
+    assert all(v["value"] <= v["limit"] == refcheck.DENSE_RTOL
+               for k, v in compared.items() if k.startswith("dense_rel_err."))
+    # every array says how many float32 steps of itself its best entry
+    # moved, a reading beside no limit: the biases start at 0 and move by
+    # 1e6 or more, so their number holds their precision
+    assert {k for k in compared if k.startswith("dense_update_ulps.")} == {
+        f"dense_update_ulps.{a}" for a in arrays
+    }
+    assert all(compared[f"dense_update_ulps.{a}"]["value"] > 1 / refcheck.DENSE_RTOL
+               for a in arrays if a.startswith(("b", "cross_b")))
+    assert compared["dense_update_ulps.w1"] == {
+        "value": pytest.approx(compared["dense_update_ulps.w1"]["value"]), "op": ">=", "limit": 0.0,
+    }
+    assert compared["dense_update_max"]["value"] > 0.0
+
+
+def _with_updates_scaled(monkeypatch, array: str, factor: float):
+    """The reference step with ONE dense array's update scaled."""
+    real = ftrl.train_step
+
+    def scaled(*args):
+        ll, rows, dense = real(*args)
+        before = args[-2][array]
+        return ll, rows, {**dense, array: before + factor * (dense[array] - before)}
+
+    monkeypatch.setattr(refcheck.ftrl, "train_step", scaled)
+
+
+@pytest.mark.parametrize("model, family, array", [
+    ("wide_deep", wide_deep, "b1"), ("wide_deep", wide_deep, "b2"),
+    ("dcn", dcn, "b1"), ("dcn", dcn, "b_out"), ("dcn", dcn, "cross_b"),
+])
+def test_a_dense_update_one_percent_off_fails_by_that_array_alone(
+    model, family, array, monkeypatch
+):
+    """A reference whose update of ONE dense array is 1.01 times what it
+    should be: every step fails, by that array's number and by no other."""
+    system, batches, cfg = _system(model, 5)
+    _with_updates_scaled(monkeypatch, array, 1.01)
+    got = refcheck.check_train_steps(system, family, batches, cfg)
+    assert not got["ok"] and not any(s["ok"] for s in got["steps"])
+    for step in got["steps"]:
+        assert step["logloss_err"] <= refcheck.LOGLOSS_ATOL
+        assert max(step["rows_rel_err"].values()) <= refcheck.ROWS_RTOL
+        off = {a for a, d in step["dense"].items() if d["rel_err"] > refcheck.DENSE_RTOL}
+        assert off == {array}
+        assert step["dense"][array]["rel_err"] == pytest.approx(0.01 / 1.01, rel=1e-2)
+
+
+def _with_program_updates_scaled(system, array: str, factor: float):
+    """The program's step with ONE dense array's update scaled: 0 leaves the
+    array as it was, 2 moves it twice."""
+    real = system.step.train
+
+    def train(state, arrays):
+        before = jnp.array(state["dense"][array])  # the step donates its state
+        new, metrics = real(state, arrays)
+        moved = before + factor * (new["dense"][array] - before)
+        return {**new, "dense": {**new["dense"], array: moved}}, metrics
+
+    system.step.train = train
+
+
+# At a cell's size (39 fields, B = 16384, ``sgd_lr`` 1e-3) the first-layer
+# matrix moves by 0.25-3 float32 steps of its LARGEST entry, DCN's ``cross_w``
+# by under half of one (PERF.md section 2).  The toy step's mean is over 59
+# rows, so the same regime is a rate of 5e-5 here.
+MEASURED_SIZE = {"sgd_lr": 5e-5}
+
+
+def _largest_steps(system, array: str, step: dict) -> float:
+    """An array's largest update in float32 steps of its largest entry."""
+    largest = np.float32(np.max(np.abs(np.asarray(system.state["dense"][array]))))
+    return step["dense"][array]["update"] / float(np.spacing(largest))
+
+
+@pytest.mark.parametrize("model, family, array, factor, reads", [
+    ("wide_deep", wide_deep, "w1", 0.0, 1.0), ("wide_deep", wide_deep, "w1", 2.0, 0.1),
+    ("dcn", dcn, "w1", 0.0, 1.0), ("dcn", dcn, "w1", 2.0, 0.1),
+    ("dcn", dcn, "cross_w", 0.0, 1.0),
+])
+def test_an_array_left_as_it_was_or_moved_twice_fails_at_a_cells_update_size(
+    model, family, array, factor, reads
+):
+    """The program leaves ONE dense array where it was (it then reads exactly
+    1, with no rounding allowance) or moves it twice, where the array's
+    largest entries move by about one float32 step of themselves or less, as
+    at a cell's size: the step fails by that array's number alone, because
+    entries near 0 move by many steps of THEIR own.  (``cross_w`` moved twice
+    is not among the cases: where no entry moves by two steps of itself the
+    doubled update rounds to what a sound step leaves.)"""
+    system, batches, cfg = _system(model, 5, **MEASURED_SIZE)
+    _with_program_updates_scaled(system, array, factor)
+    got = refcheck.check_train_steps(system, family, batches[:1], cfg)
+    step = got["steps"][0]
+    assert _largest_steps(system, array, step) < 1.5
+    assert not got["ok"]
+    off = {a for a, d in step["dense"].items() if d["rel_err"] > refcheck.DENSE_RTOL}
+    assert off == {array}
+    assert reads <= step["dense"][array]["rel_err"] <= 1.0
+    assert step["logloss_err"] <= refcheck.LOGLOSS_ATOL
+    assert max(step["rows_rel_err"].values()) <= refcheck.ROWS_RTOL
+
+
+def test_a_weight_array_is_held_to_its_update_not_always_to_its_precision(monkeypatch):
+    """At a cell's update size no entry of ``w1`` moves by 1e4 steps of
+    itself, so a reference update a thousandth off is inside every entry's
+    rounding: the step passes, and ``dense_update_ulps`` says why.  At the
+    toy's own rate the same array moves by over 1e3 steps and the same
+    thousandth is seen."""
+    for rate, seen in ((MEASURED_SIZE["sgd_lr"], False), (1e-3, True)):
+        system, batches, cfg = _system("wide_deep", 5, sgd_lr=rate)
+        _with_updates_scaled(monkeypatch, "w1", 1.001)
+        got = refcheck.check_train_steps(system, wide_deep, batches[:1], cfg)
+        monkeypatch.undo()
+        compared = refcheck.dense_compared(got["steps"])
+        assert got["ok"] is not seen
+        assert (compared["dense_update_ulps.w1"]["value"] > 1e3) is seen
+        off = {k for k, v in compared.items()
+               if k.startswith("dense_rel_err") and v["value"] > v["limit"]}
+        assert off == ({"dense_rel_err.w1"} if seen else set())
+
+
+def test_a_step_that_moves_no_dense_array_proves_nothing_of_them():
+    """Reference updates of exactly 0 in EVERY dense array (here: a rate of 0
+    on both sides, so the program agrees) fail the step, as rows that did not
+    move do."""
+    system, batches, cfg = _system("wide_deep", 5, sgd_lr=0.0)
+    got = refcheck.check_train_steps(system, wide_deep, batches, cfg)
+    assert not got["ok"]
+    assert refcheck.dense_compared(got["steps"])["dense_update_max"]["value"] == 0.0
+    assert all(max(s["rows_rel_err"].values()) <= refcheck.ROWS_RTOL for s in got["steps"])
+
+
+def test_one_dense_array_may_stand_still_in_a_sound_step():
+    """DCN's ``cross_w`` moves by under half a float32 step of its entries at
+    a cell's size, so in float32 it can stand bit for bit where it was, in
+    the program and in the reference alike (on the chip: one seed in twelve).
+    That step is sound; one in which the program then moves the array by two
+    steps is not."""
+    b = {"cross_w": np.full(4, 0.25, np.float32), "b1": np.zeros(4, np.float32)}
+    w = {"cross_w": b["cross_w"].copy(), "b1": np.full(4, -1e-5, np.float32)}
+    sound = refcheck.dense_errors(b, w, w)
+    assert sound["cross_w"] == {"rel_err": 0.0, "update": 0.0, "update_ulps": 0.0}
+    steps = [{"dense": sound}]
+    compared = refcheck.dense_compared(steps)
+    assert compared["dense_rel_err.cross_w"]["value"] == 0.0
+    assert compared["dense_update_ulps.cross_w"]["value"] == 0.0
+    assert compared["dense_update_max"]["value"] == pytest.approx(1e-5)
+    one = np.spacing(np.float32(0.25))
+    for steps_off, err in ((1, 0.0), (2, 1.0)):  # one step is rounding's
+        g = {**w, "cross_w": w["cross_w"] + np.float32(steps_off * one)}
+        assert refcheck.dense_errors(b, g, w)["cross_w"]["rel_err"] == err
+
+
+def test_the_rounding_allowance_is_each_entrys_own_step():
+    """A matrix whose large entry moves by half a float32 step of itself and
+    whose entry near 0 moves by thousands of its own: an error of one step of
+    the LARGE entry is rounding there and a fault in the small one; an array
+    left bit for bit as it was reads exactly 1, allowance or none."""
+    big = np.float32(0.25)
+    one = np.spacing(big)
+    b = {"w1": np.array([big, 1e-6], np.float32)}
+    w = {"w1": b["w1"] - np.array([one, 0.5 * one], np.float32)}
+    assert refcheck.dense_errors(b, w, w)["w1"]["rel_err"] == 0.0
+    assert refcheck.dense_errors(b, w, w)["w1"]["update_ulps"] > 1e3
+    g = {"w1": w["w1"] + np.array([one, 0.0], np.float32)}
+    assert refcheck.dense_errors(b, g, w)["w1"]["rel_err"] == 0.0
+    g = {"w1": w["w1"] + np.array([0.0, one], np.float32)}
+    assert refcheck.dense_errors(b, g, w)["w1"]["rel_err"] == pytest.approx(1.0, rel=1e-3)
+    assert refcheck.dense_errors(b, b, w)["w1"]["rel_err"] == 1.0
+    only_big = {"w1": b["w1"] - np.array([one, 0.0], np.float32)}
+    assert refcheck.dense_errors(b, b, only_big)["w1"]["rel_err"] == 1.0  # no allowance
+
+
+class _Again:
+    """A family under a second identity: ``train_step`` compiles it anew."""
+
+    def __init__(self, family):
+        self.family = family
+
+    def __getattr__(self, name):
+        return getattr(self.family, name)
+
+
+def test_the_dense_step_block_by_block_is_the_step_in_one_block(monkeypatch):
+    """The reference's dense step over four blocks of 16 examples (what it
+    does at a cell's size, ``DENSE_BLOCK`` examples at a time) against the
+    same step in one block of 64: the logloss, every row and every dense
+    array agree to float32's rounding of a sum taken in another order."""
+    rng = np.random.default_rng(8)
+    fields, p = 4, 4 * dcn.EMB_DIM
+    rows = {
+        t: {"param": jnp.asarray(rng.normal(0, 0.1, (32, d)), jnp.float32),
+            "n": jnp.asarray(rng.uniform(0, 1, (32, d)), jnp.float32),
+            "z": jnp.asarray(rng.normal(0, 1, (32, d)), jnp.float32)}
+        for t, d in dcn.TABLES.items()
+    }
+    shapes = {"cross_w": (2, p), "cross_b": (2, p), "w1": (p, 8), "b1": (8,),
+              "w_out": (p + 8, 1), "b_out": (1,)}
+    dense = {k: jnp.asarray(rng.normal(0, 0.3, v), jnp.float32) for k, v in shapes.items()}
+    idx = jnp.asarray(rng.integers(0, 32, (64, 6)), jnp.int32)
+    slots = jnp.asarray(rng.integers(-1, fields + 1, (64, 6)), jnp.int32)
+    x = jnp.asarray(rng.integers(0, 2, (64, 6)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, 2, 64), jnp.float32)
+    weights = jnp.ones(64).at[-5:].set(0.0)
+    args = (rows, idx, x, labels, weights, tuple(HYPER.items()), slots, fields, dense, 0.1)
+    whole = ftrl.train_step(dcn, *args)
+    monkeypatch.setattr(ftrl, "DENSE_BLOCK", 16)
+    blocked = ftrl.train_step(_Again(dcn), *args)
+    for one, four in zip(jax.tree.leaves(whole), jax.tree.leaves(blocked)):
+        np.testing.assert_allclose(four, one, rtol=2e-5, atol=1e-7)
+    assert all(
+        float(jnp.max(jnp.abs(whole[2][k] - dense[k]))) > 1e-4 for k in dense
+    )  # every dense array moved
+
+
+def test_a_family_and_a_program_must_agree_on_what_the_parameters_are():
+    system, batches, cfg = _system("wide_deep", 5)
+    with pytest.raises(ValueError, match="dense parameters"):
+        refcheck.check_train_steps(system, lr, batches, cfg)
+    system, batches, cfg = _system("wide_deep", 5, emb_dim=16)
+    with pytest.raises(ValueError, match="'emb': 16"):
+        refcheck.check_train_steps(system, wide_deep, batches, cfg)
 
 
 def test_the_check_can_fail():
@@ -240,7 +527,7 @@ def test_entries_carry_the_field_ids_hot_section_first():
     assert ((live < 0) | (live >= cfg.max_fields)).any()  # some outside, kept as drawn
 
 
-@pytest.mark.parametrize("model, family", [("lr", lr), ("fm", fm), ("mvm", mvm)])
+@pytest.mark.parametrize("model, family", FAMILIES)
 @pytest.mark.parametrize("hot_log2", [0, 5])
 def test_the_control_is_outside_the_tolerance(model, family, hot_log2):
     """The control (``benchmarks/control.py``: the reference with its gathered
@@ -258,6 +545,17 @@ def test_the_control_is_outside_the_tolerance(model, family, hot_log2):
     assert sound["ok"] and not got["ok"]
     # toy size: sound <= 4.0e-7 (mvm), the control >= 2.8e-6 (lr, hot 2^5)
     assert 2 * worst(sound) < refcheck.ROWS_RTOL < worst(got) / 2
+    if hasattr(family, "DENSE"):
+        # the control rounds the dense operands too: each bias, which float32
+        # resolves, is outside the dense limit on its own (toy size: sound
+        # <= 1e-7, the control >= 4e-4)
+        def dense_worst(check, pick):
+            return pick(
+                max(s["dense"][a]["rel_err"] for s in check["steps"])
+                for a in check["steps"][0]["dense"] if a.startswith(("b", "cross_b"))
+            )
+
+        assert 2 * dense_worst(sound, max) < refcheck.DENSE_RTOL < dense_worst(got, min) / 2
 
 
 @pytest.mark.parametrize("cell, check", [
