@@ -4,6 +4,7 @@ pipeline-health metrics, schema, summarize/compare toolchain."""
 
 import json
 import os
+import re
 import sys
 import time
 
@@ -1206,3 +1207,113 @@ def test_wire_row_counts_the_table_rows_a_step_moves(
         sum(caps) / len(caps) * row_bytes + b * kh * plain_bytes
     )
     assert row["gather_row_bytes_per_step"] < row["scatter_row_bytes_per_step"]
+
+
+# -- the dense half's scope and counters (PR 39) ------------------------------
+
+_DENSE_MODELS = {
+    "dcn": dict(emb_dim=4, hidden_dim=8, cross_layers=2, deep_layers=2),
+    "wide_deep": dict(emb_dim=4, hidden_dim=8),
+    "two_tower": dict(emb_dim=4, hidden_dim=8, tower_dim=4, tower_split_field=4),
+}
+
+
+def _op_scope_rows(toy_dataset, **overrides):
+    cfg = _toy_cfg(toy_dataset, max_fields=8, **overrides)
+    with Trainer(cfg) as t:
+        batch, _, _ = next(iter(t.iter_train_batches(0, 0)))
+        return t.step.op_scopes(t.state, t.step.put_batch(batch))
+
+
+@pytest.mark.parametrize("model", sorted(_DENSE_MODELS))
+def test_a_dense_family_runs_its_dense_half_under_xf_dense(toy_dataset, model):
+    """A family that owns replicated dense parameters opens ``xf.dense``
+    inside ``xf.forward_backward``, and ``op_scopes`` gives an instruction
+    the INNERMOST name of its path: the dense half's operations, forward
+    and backward, map to ``xf.dense``, the field contraction around them
+    stays ``xf.forward_backward``'s, and the dense SGD ``xf.optimizer``'s."""
+    rows = _op_scope_rows(toy_dataset, model=model, **_DENSE_MODELS[model])
+    scopes = {scope for _, _, scope in rows}
+    assert {"xf.dense", "xf.forward_backward", "xf.optimizer"} <= scopes
+    assert scopes <= SCOPES | {"xf.dense", ""}
+
+
+@pytest.mark.parametrize("model, overrides", [
+    ("lr", {}), ("fm", {}), ("mvm", {"max_fields": 8}), ("ffm", {"max_fields": 8}),
+])
+def test_the_measured_families_keep_their_six_scopes(toy_dataset, model, overrides):
+    """LR, FM, MVM and FFM have no dense half: no ``xf.dense`` row, and no
+    instruction whose path nests two different ``xf.`` names, so the
+    innermost-name rule maps them as the first-name rule did."""
+    from xflow_tpu.parallel.step import _SCOPE_RE, abstract_like
+
+    cfg = _toy_cfg(
+        toy_dataset, model=model, hot_size_log2=6, hot_nnz=8, **overrides
+    )
+    with Trainer(cfg) as t:
+        batch, _, _ = next(iter(t.iter_train_batches(0, 0)))
+        arrays = t.step.put_batch(batch)
+        rows = t.step.op_scopes(t.state, arrays)
+        text = t.step.train.lower(
+            abstract_like(t.state), abstract_like(arrays)
+        ).compile().as_text()
+    assert {scope for _, _, scope in rows} <= SCOPES | {""}
+    nested = [
+        path for path in re.findall(r'op_name="([^"]*)"', text)
+        if len(set(_SCOPE_RE.findall(path))) > 1
+    ]
+    assert not nested, nested[:3]
+
+
+def test_scope_of_takes_the_innermost_name():
+    from xflow_tpu.parallel.step import scope_of
+
+    assert scope_of("jit(f)/xf.forward_backward/jvp(xf.dense)/dot_general") == "xf.dense"
+    assert scope_of(
+        "jit(f)/xf.forward_backward/transpose(xf.forward_backward)/jvp(xf.dense)/select_n"
+    ) == "xf.dense"
+    assert scope_of("jit(f)/xf.scatter/xf.scatter/scatter-add") == "xf.scatter"
+    assert scope_of("jit(f)/transpose(jvp(xf.gather))/mul") == "xf.gather"
+    assert scope_of("jit(f)/reduce_sum") == ""
+
+
+@pytest.mark.parametrize("model", sorted(_DENSE_MODELS))
+def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, model):
+    """``dense_param_bytes`` is the bytes of the state's dense arrays and
+    ``dense_matmul_flops_per_step`` the benchmark's own count
+    (``harness/costs.py``) for the products the model declares; for DCN
+    those are the products the benchmark's reference reads off the dense
+    arrays' shapes."""
+    from benchmarks.harness import costs
+    from benchmarks.reference import dcn_criteo
+    from xflow_tpu.obs.schema import OPTIONAL, validate_rows
+
+    metrics = tmp_path / "m.jsonl"
+    cfg = _toy_cfg(
+        toy_dataset, model=model, max_fields=8, epochs=1,
+        metrics_out=str(metrics), **_DENSE_MODELS[model],
+    )
+    with Trainer(cfg) as t:
+        t.train()
+        dense = t.state["dense"]
+        matmuls = t.step.model.dense_matmuls()
+        if model == "dcn":
+            shapes = {name: list(a.shape) for name, a in dense.items()}
+            assert dcn_criteo.matmuls(shapes) == matmuls
+        param_bytes = sum(a.size * 4 for a in dense.values())
+    rows = [json.loads(line) for line in metrics.read_text().splitlines()]
+    assert validate_rows(rows) == []
+    (wire,) = [r for r in rows if r["kind"] == "wire"]
+    assert {"dense_param_bytes", "dense_matmul_flops_per_step"} <= set(OPTIONAL["wire"])
+    assert wire["dense_param_bytes"] == param_bytes
+    want = costs.train_step(
+        {"table_size_log2": cfg.table_size_log2, "batch_size": cfg.batch_size},
+        {"w": 1}, entries_per_step=1.0, hot_share=0.0, matmuls=matmuls,
+    )["flops"]
+    assert wire["dense_matmul_flops_per_step"] == want > 0
+
+
+def test_a_family_without_dense_parameters_books_no_dense_counter(packed_run):
+    (wire, *_) = [r for r in packed_run if r["kind"] == "wire"]
+    assert "dense_param_bytes" not in wire
+    assert "dense_matmul_flops_per_step" not in wire

@@ -238,20 +238,13 @@ def _table_sized_copies(text: str, elements: int) -> list[str]:
     ]
 
 
-def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
-    topo, no_compile_cache
-):
-    """The FM train step over the described 2x2 as the TPU's compiler
-    leaves it (parallel/exchange.py; the benchmark cell's widths, a table
-    and a batch cut to keep the compile short): every collective is the
-    program's or a scalar's, none is issued by a loop's iterations, none
-    has a block's rows.  The TPU runs a reduce-scatter as an all-reduce
-    and a slice, and may continue an all-gather inside a neighbouring
-    loop (``pieces`` > 1): collectives_in counts such a chain once."""
+def _lowered_fm_mesh_step(topo):
+    """(cfg, lowered): the FM train step over the described 2x2 at the
+    widths of the benchmark's fm_tb_x4.train_packed, a table and a batch
+    cut to keep the compile short, on the compact wire."""
     from xflow_tpu.config import Config
     from xflow_tpu.models import make_model
     from xflow_tpu.optim import make_optimizer
-    from xflow_tpu.parallel.exchange import collectives_in
     from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
     from xflow_tpu.parallel.step import TrainStep
 
@@ -288,7 +281,24 @@ def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
         "labels_u8": shaped((b,), jnp.uint8, step._bsharding),
         "weights_u8": shaped((b,), jnp.uint8, step._bsharding),
     }
-    text = step.train.lower(state, arrays).compile().as_text()
+    return cfg, step.train.lower(state, arrays)
+
+
+def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
+    topo, no_compile_cache
+):
+    """The FM train step over the described 2x2 as the TPU's compiler
+    leaves it (parallel/exchange.py; ``_lowered_fm_mesh_step``): every
+    collective is the program's or a scalar's, none is issued by a loop's
+    iterations, none has a block's rows.  The TPU runs a reduce-scatter
+    as an all-reduce and a slice, and may continue an all-gather inside a
+    neighbouring loop (``pieces`` > 1): collectives_in counts such a
+    chain once."""
+    from xflow_tpu.parallel.exchange import collectives_in
+
+    cfg, lowered = _lowered_fm_mesh_step(topo)
+    b = cfg.batch_size
+    text = lowered.compile().as_text()
     # a chip's block of w goes through the FTRL pass on its flat view,
     # whole (8,128) tiles, sharded on its only axis; v's padded rows keep
     # their shape (_optimizer_pass; PERF.md section 6, PR 37), and the
@@ -309,6 +319,50 @@ def test_four_chip_fm_step_compiles_for_v5e_with_its_exchange(
     assert len(found) <= 4 * 2 + 2 + 3 + 2, found  # + the reduce-scatters' fix-ups
 
 
+_U8, _U16, _U32 = np.uint8, np.uint16, np.uint32
+# The dictionary wire's plane capacities of one real batch (seed 1) of each
+# one-chip cell of the benchmark: name -> (shape, dtype).
+LR_PLANES = {
+    "cw_cu": ((53248,), _U32), "cw_cun": ((1,), np.int32),
+    "cw_ci": ((1228800,), _U16), "cw_ct": ((294912,), _U32),
+    "cw_cf": ((184320,), _U8), "cw_cc": ((131072,), _U8),
+    "cw_lb": ((16384,), _U8), "cw_wb": ((16384,), _U8),
+    "cw_h8": ((2293760,), _U8), "cw_hx": ((1490944,), _U8),
+    "cw_hxh": ((745472,), _U8), "cw_hf": ((458752,), _U8),
+    "cw_hc": ((131072,), _U8),
+}
+MVM_PLANES = {
+    "cw_cu": ((43008,), _U32), "cw_cun": ((1,), np.int32),
+    "cw_ci": ((688128,), _U16), "cw_ct": ((262144,), _U32),
+    "cw_cf": ((118784,), _U8), "cw_cc": ((131072,), _U8),
+    "cw_lb": ((16384,), _U8), "cw_wb": ((16384,), _U8),
+    "cw_h8": ((2490368,), _U8), "cw_hx": ((1835008,), _U16),
+    "cw_hxh": ((0,), _U8), "cw_hf": ((524288,), _U8),
+    "cw_hc": ((131072,), _U8),
+    "cw_cs": ((950272,), _U8), "cw_hs": ((4194304,), _U8),
+}
+FFM_PLANES = {
+    "cw_cu": ((53248, 3), _U8), "cw_cun": ((1,), np.int32),
+    "cw_ci": ((118784,), _U16), "cw_ct": ((0, 3), _U8),
+    "cw_cf": ((14848,), _U8), "cw_cc": ((16384,), _U8),
+    "cw_lb": ((2048,), _U8), "cw_wb": ((2048,), _U8),
+    "cw_h8": ((311296,), _U8), "cw_hx": ((229376,), _U16),
+    "cw_hxh": ((0,), _U8), "cw_hf": ((65536,), _U8),
+    "cw_hc": ((16384,), _U8),
+    "cw_cs": ((118784,), _U8), "cw_hs": ((524288,), _U8),
+}
+DCN_PLANES = {
+    "cw_cu": ((40960, 3), _U8), "cw_cun": ((1,), np.int32),
+    "cw_ci": ((344064,), _U16), "cw_ct": ((131072, 3), _U8),
+    "cw_cf": ((59392,), _U8), "cw_cc": ((65536,), _U8),
+    "cw_lb": ((8192,), _U8), "cw_wb": ((8192,), _U8),
+    "cw_h8": ((1245184,), _U8), "cw_hx": ((917504,), _U16),
+    "cw_hxh": ((0,), _U8), "cw_hf": ((262144,), _U8),
+    "cw_hc": ((65536,), _U8),
+    "cw_cs": ((475136,), _U8), "cw_hs": ((2097152,), _U8),
+}
+
+
 def _lowered_cell_step(
     topo, config: str, planes: dict, ships_slots: bool = True
 ):
@@ -317,7 +371,8 @@ def _lowered_cell_step(
     dictionary-wire batch given as plane shapes (``planes``: name ->
     (shape, dtype), the capacities of one real batch; ``ships_slots``:
     whether the family reads field ids, so that its wire ships the slots
-    planes)."""
+    planes).  A family that owns dense replicated parameters is handed
+    the shapes of its ``dense_init``."""
     from benchmarks.harness import manifest
     from xflow_tpu.config import Config
     from xflow_tpu.models import make_model
@@ -339,7 +394,11 @@ def _lowered_cell_step(
     def shaped(shape, dtype, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    rows = meshes.table_sharding(mesh)
+    rows, whole = meshes.table_sharding(mesh), meshes.replicated(mesh)
+    dense = (
+        jax.eval_shape(model.dense_init, jax.random.PRNGKey(0))
+        if hasattr(model, "dense_init") else {}
+    )
     state = {
         "tables": {
             spec.name: {
@@ -348,8 +407,10 @@ def _lowered_cell_step(
             }
             for spec in model.tables()
         },
-        "dense": {},
-        "step": shaped((), jnp.int32, meshes.replicated(mesh)),
+        "dense": {
+            name: shaped(a.shape, a.dtype, whole) for name, a in dense.items()
+        },
+        "step": shaped((), jnp.int32, whole),
     }
     batch = {
         k: shaped(shape, dtype, step._bsharding)
@@ -376,18 +437,10 @@ def mvm_cell_step(topo):
     fields, the dictionary wire's plane capacities of one real batch,
     seed 1) for a described v5e, compiled once (3 min here) for the tests
     that read it."""
-    u8, u16, u32 = np.uint8, np.uint16, np.uint32
     with _without_compile_cache():
-        cfg, _, lowered = _lowered_cell_step(topo, "mvm_ftrl_criteo_tb", {
-            "cw_cu": ((43008,), u32), "cw_cun": ((1,), np.int32),
-            "cw_ci": ((688128,), u16), "cw_ct": ((262144,), u32),
-            "cw_cf": ((118784,), u8), "cw_cc": ((131072,), u8),
-            "cw_lb": ((16384,), u8), "cw_wb": ((16384,), u8),
-            "cw_h8": ((2490368,), u8), "cw_hx": ((1835008,), u16),
-            "cw_hxh": ((0,), u8), "cw_hf": ((524288,), u8),
-            "cw_hc": ((131072,), u8),
-            "cw_cs": ((950272,), u8), "cw_hs": ((4194304,), u8),
-        })
+        cfg, _, lowered = _lowered_cell_step(
+            topo, "mvm_ftrl_criteo_tb", MVM_PLANES
+        )
         return cfg, lowered, lowered.compile()
 
 
@@ -467,16 +520,9 @@ def test_lr_step_runs_its_pass_on_the_flat_view_and_fits_a_v5e(
     arrays, nothing that runs in that scope is left on one-sublane tiles
     (the views are bitcasts), no table-sized copy is made for it, and the
     program's peak is the parent's 4.018 GiB."""
-    u8, u16, u32 = np.uint8, np.uint16, np.uint32
-    cfg, _, lowered = _lowered_cell_step(topo, "lr_ftrl_criteo_tb", {
-        "cw_cu": ((53248,), u32), "cw_cun": ((1,), np.int32),
-        "cw_ci": ((1228800,), u16), "cw_ct": ((294912,), u32),
-        "cw_cf": ((184320,), u8), "cw_cc": ((131072,), u8),
-        "cw_lb": ((16384,), u8), "cw_wb": ((16384,), u8),
-        "cw_h8": ((2293760,), u8), "cw_hx": ((1490944,), u8),
-        "cw_hxh": ((745472,), u8), "cw_hf": ((458752,), u8),
-        "cw_hc": ((131072,), u8),
-    }, ships_slots=False)
+    cfg, _, lowered = _lowered_cell_step(
+        topo, "lr_ftrl_criteo_tb", LR_PLANES, ships_slots=False
+    )
     compiled = lowered.compile()
     text = compiled.as_text()
     t = cfg.table_size
@@ -520,17 +566,9 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
     row gathers (dict_cold_rows): of the padded [B, max_nnz, 1] column
     planes, 160 families of them until PR 35 (0.16 GiB of the peak and
     82 ms of the step), one is left, w's single column."""
-    u8, u16 = np.uint8, np.uint16
-    cfg, step, lowered = _lowered_cell_step(topo, "ffm_ftrl_criteo_tb", {
-        "cw_cu": ((53248, 3), u8), "cw_cun": ((1,), np.int32),
-        "cw_ci": ((118784,), u16), "cw_ct": ((0, 3), u8),
-        "cw_cf": ((14848,), u8), "cw_cc": ((16384,), u8),
-        "cw_lb": ((2048,), u8), "cw_wb": ((2048,), u8),
-        "cw_h8": ((311296,), u8), "cw_hx": ((229376,), u16),
-        "cw_hxh": ((0,), u8), "cw_hf": ((65536,), u8),
-        "cw_hc": ((16384,), u8),
-        "cw_cs": ((118784,), u8), "cw_hs": ((524288,), u8),
-    })
+    cfg, step, lowered = _lowered_cell_step(
+        topo, "ffm_ftrl_criteo_tb", FFM_PLANES
+    )
     assert step._mxu_hot == {"w": True, "v": False}
     text = lowered.as_text().splitlines()
     b, k, f = cfg.batch_size, cfg.max_nnz + cfg.hot_nnz, cfg.max_fields
@@ -559,3 +597,153 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
     assert len(planes) <= 16, sorted(planes)
     peak = _program_peak(compiled)
     assert 13.5 * (1 << 30) < peak < 14.0 * (1 << 30), peak
+
+
+# sha256 of the lowered train program (StableHLO text, the Mosaic kernels'
+# serialized bodies blanked: they embed the checkout's path) of the four
+# configurations the benchmark measured before PR 39, pinned on PR 38's
+# tree BEFORE models/blocks.py was edited.
+MEASURED_PROGRAMS_SHA256 = {
+    "lr_ftrl_criteo_tb": (
+        "658a8546e3166cba2379607b6546d8d0f9595d0614d603c9c623a54a8dc72ec8"
+    ),
+    "mvm_ftrl_criteo_tb": (
+        "4ebc67a21cce24b7ed47f5b2fd0469fcb02f7b3f9f1b099e2c3af48952f59c3a"
+    ),
+    "ffm_ftrl_criteo_tb": (
+        "d868304a65ffa819584c4f38852da8e095e35a6c93ec14c69864a4449d2736f6"
+    ),
+    "fm_ftrl_criteo_tb (cut, 2x2)": (
+        "ade0cf7f34c32ee9e7a4f4c58f6c3fc54563d3563a5b1e0f3fadabf657d6c219"
+    ),
+}
+
+
+def _program_sha256(lowered) -> str:
+    import hashlib
+
+    text = re.sub(
+        r'backend_config = "[^"]*"', 'backend_config = ""', lowered.as_text()
+    )
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_measured_train_programs_lower_to_the_pinned_text(topo):
+    """PR 39 put every matmul of the dense half (models/blocks.py: the MLP
+    blocks, DCN's output product) through one float32 helper, opened the
+    scope xf.dense inside xf.forward_backward and changed which ``xf.``
+    name of a path ``op_scopes`` takes.  LR, FM, MVM and FFM call none of
+    the MLP blocks and open no scope inside another, so their train
+    programs lower to the text PR 38's tree lowered them to.  A PR that
+    means to change one of these programs pins the new digest here and
+    says so; one that does not has found what it changed by accident."""
+    got = {
+        "lr_ftrl_criteo_tb": _lowered_cell_step(
+            topo, "lr_ftrl_criteo_tb", LR_PLANES, ships_slots=False
+        )[2],
+        "mvm_ftrl_criteo_tb": _lowered_cell_step(
+            topo, "mvm_ftrl_criteo_tb", MVM_PLANES
+        )[2],
+        "ffm_ftrl_criteo_tb": _lowered_cell_step(
+            topo, "ffm_ftrl_criteo_tb", FFM_PLANES
+        )[2],
+        "fm_ftrl_criteo_tb (cut, 2x2)": _lowered_fm_mesh_step(topo)[1],
+    }
+    assert {
+        name: _program_sha256(lowered) for name, lowered in got.items()
+    } == MEASURED_PROGRAMS_SHA256
+    # no operation of theirs sits under two DIFFERENT xf. names, so the
+    # innermost name op_scopes takes is the first name it took before
+    from xflow_tpu.parallel.step import _SCOPE_RE
+
+    for name, lowered in got.items():
+        paths = set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+        scoped = [path for path in paths if _SCOPE_RE.search(path)]
+        nested = [p for p in scoped if len(set(_SCOPE_RE.findall(p))) > 1]
+        assert len(scoped) > 50 and not nested, (name, nested[:3])
+
+
+def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
+    topo, no_compile_cache
+):
+    """The DCN train step at the geometry of the benchmark's
+    dcn_tb.train_packed (benchmarks/configs/dcn_ftrl_criteo_tb.json: 2^24
+    rows, w of one column and emb of 26, B=65536, 8 + 32 slots, 40 fields,
+    six cross layers beside two hidden layers of 1024, the dictionary
+    wire's plane capacities of one real batch, seed 1; the dense arrays
+    handed in as shapes) for a described v5e.  Lowered: every dot asks for
+    float32 (Precision.HIGHEST), and among them are the products with each
+    dense matrix forward (``h @ w``), transposed into the activations
+    (``dy @ w.T``) and into the weights (``h.T @ dy``): models/blocks.py's
+    ``dense_dot`` and what autodiff makes of it; at default precision the
+    TPU rounds the operands to bfloat16 and the step misses the
+    benchmark's reference (PERF.md section 7, PR 38).  Both tables go
+    through the MXU head (ops/hot.py: no gather by the hot plane).
+    Compiled: the instructions of the dense half carry ``xf.dense`` in
+    ``op_scopes``' reading (the innermost name), the three products of
+    each hidden layer among them (convolutions, as the TPU's compiler
+    writes a dot); no table-sized copy of emb's state
+    is made; and the program fits with the room the file's ``reduced``
+    argues from, 9.31 GiB of 15.75 (at 2^25 rows the compiler refuses
+    it: 16.47 G)."""
+    from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
+
+    cfg, step, lowered = _lowered_cell_step(
+        topo, "dcn_ftrl_criteo_tb", DCN_PLANES
+    )
+    assert step._mxu_hot == {"w": True, "emb": True}
+    assert (cfg.cross_layers, cfg.deep_layers, cfg.hidden_dim) == (6, 2, 1024)
+    text = lowered.as_text().splitlines()
+    b, h = cfg.batch_size, cfg.hidden_dim
+    p = cfg.max_fields * cfg.emb_dim
+    dots = [line for line in text if "dot_general" in line]
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
+
+    def dot(lhs: str, rhs: str, out: str) -> int:
+        sig = f"(tensor<{lhs}xf32>, tensor<{rhs}xf32>) -> tensor<{out}xf32>"
+        return sum(sig in line for line in dots)
+
+    for k, n in [(p, h), (h, h), (p + h, 1)]:  # w1, w2, w_out
+        assert dot(f"{b}x{k}", f"{k}x{n}", f"{b}x{n}") >= 1, (k, n)  # forward
+        if (k, n) == (h, h):  # dy @ w2.T has the forward's operand types
+            assert dot(f"{b}x{h}", f"{h}x{h}", f"{b}x{h}") == 2
+        else:
+            assert dot(f"{b}x{n}", f"{k}x{n}", f"{b}x{k}") == 1, (k, n)
+    # h.T @ dy, as autodiff orders its operands
+    assert dot(f"{b}x{h}", f"{b}x{p}", f"{h}x{p}") == 1
+    assert dot(f"{b}x{h}", f"{b}x{h}", f"{h}x{h}") == 1
+    assert dot(f"{b}x1", f"{b}x{p + h}", f"1x{p + h}") == 1
+    by_hot_plane = [
+        line for line in text
+        if "stablehlo.gather" in line
+        and f"tensor<{b}x{cfg.hot_nnz}x1xi32>" in line
+    ]
+    assert not by_hot_plane, by_hot_plane
+
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    in_dense = [
+        line for line in hlo.splitlines()
+        if (m := _HLO_OP_NAME_RE.search(line))
+        and scope_of(m.group(1)) == "xf.dense"
+    ]
+    # the six products of the two hidden layers (forward, into the
+    # activations, into the weights; the output's have one column and
+    # compile to reductions)
+    products = sorted(
+        line.split(" = ")[1].split("{")[0] for line in in_dense
+        if " convolution(" in line
+    )
+    assert products == sorted(
+        [f"f32[{b},{h}]"] * 3 + [f"f32[{b},{p}]", f"f32[{p},{h}]", f"f32[{h},{h}]"]
+    ), products
+    assert len(in_dense) > 100
+    assert all("xf.forward_backward" in line for line in in_dense)
+    # (w's 64 MiB flat view may be moved to another memory space whole:
+    # a prefetch, not a layout change)
+    assert not [
+        line for line in _table_sized_copies(hlo, cfg.table_size)
+        if f"f32[{cfg.table_size},{cfg.emb_dim}]" in line
+    ]
+    peak = _program_peak(compiled)
+    assert 9.0 * (1 << 30) < peak < 10.5 * (1 << 30), peak

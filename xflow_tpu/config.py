@@ -65,8 +65,11 @@ class Config:
     # (= the serve-time item-index row width, serve/artifact.py).
     tower_split_field: int = 16
     tower_dim: int = 16
-    # dcn (models/dcn.py): explicit cross-network depth.
+    # dcn (models/dcn.py): explicit cross-network depth, and how many
+    # ReLU layers of hidden_dim its deep half stacks beside it (the
+    # paper's Criteo optimum: 6 cross layers beside 2 deep layers).
     cross_layers: int = 2
+    deep_layers: int = 1
     # Static padded features-per-sample inside the jit step.  Samples with
     # more features than this are truncated (reference has no limit —
     # features-per-sample is whatever the text line holds).
@@ -502,6 +505,8 @@ class Config:
             raise ValueError("tower_dim must be >= 1")
         if self.cross_layers < 1:
             raise ValueError("cross_layers must be >= 1")
+        if self.deep_layers < 1:
+            raise ValueError("deep_layers must be >= 1")
         if self.optimizer not in ("ftrl", "sgd"):
             raise ValueError(f"unknown optimizer {self.optimizer!r}")
         if self.update_mode not in ("dense", "sparse", "sequential"):

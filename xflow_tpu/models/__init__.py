@@ -138,6 +138,7 @@ register_model(ModelFamily(
         emb_dim=cfg.emb_dim,
         hidden=cfg.hidden_dim,
         cross_layers=cfg.cross_layers,
+        deep_layers=cfg.deep_layers,
         max_fields=cfg.max_fields,
         v_init_scale=cfg.v_init_scale,
     ),

@@ -89,6 +89,12 @@ class AutodiffModel:
         """Replicated dense parameter pytree ({} if none)."""
         return {}
 
+    def dense_matmuls(self) -> list[tuple[int, int]]:
+        """``(k, n)`` of every ``[B, k] x [k, n]`` product with a dense
+        parameter in one forward pass ([] if none): what the step books
+        as ``dense.matmul_flops`` (parallel/step.py::_book_wire)."""
+        return []
+
     def logit(
         self,
         rows: dict[str, jax.Array],

@@ -129,28 +129,75 @@ def flatten_tower(field_emb: jax.Array) -> jax.Array:
 # -- MLP blocks (replicated dense params; plain-SGD updated — see
 # parallel/step.py::apply_dense_sgd) -----------------------------------------
 
+# The device scope of a family's dense half (docs/OBSERVABILITY.md): what
+# runs over replicated dense parameters, forward and backward, read apart
+# from the field contraction inside xf.forward_backward.
+DENSE_SCOPE = "xf.dense"
+
+
+def dense_dot(a: jax.Array, w: jax.Array) -> jax.Array:
+    """``a @ w`` for a dense parameter ``w``, in float32 on every
+    backend: THE matmul of the dense half (every MLP block here, DCN's
+    output product), forward and, through autodiff's transposes,
+    backward (``dy @ w.T`` and ``a.T @ dy`` inherit the precision).  At
+    default precision the TPU rounds both operands to bfloat16, and the
+    wide&deep and DCN steps then miss the benchmark's reference by 1e-5
+    of a row and 1e-3 - 1e-2 of a bias's update (PERF.md section 7,
+    PR 38: ``dh @ w1.T`` into ``emb``, ``dy @ w2.T`` into ``b1``,
+    ``x0.T @ dh`` into ``w1``).  Unlike ``field_contract``'s one-hot
+    neither operand is exact in bfloat16, so HIGHEST is what float32
+    costs.  On the CPU precision changes nothing: the bitwise pins of
+    tests/test_models.py hold as they were."""
+    return jnp.matmul(a, w, precision=jax.lax.Precision.HIGHEST)
+
+
+def mlp_stack_init(
+    rng: jax.Array, in_dim: int, hidden: int, layers: int = 1,
+    prefix: str = "",
+) -> dict[str, jax.Array]:
+    """He-init stack of ``layers`` ReLU layers of ``hidden``: in_dim ->
+    hidden -> ... -> hidden, keys ``w1, b1 ... wn, bn``.  Layer 1 draws
+    from ``rng`` itself (the one-layer stack is the draw the families
+    made before there was a stack), layer l > 1 from
+    ``fold_in(rng, l)``."""
+    dense = {}
+    for layer in range(1, layers + 1):
+        fan_in = in_dim if layer == 1 else hidden
+        key = rng if layer == 1 else jax.random.fold_in(rng, layer)
+        dense[f"{prefix}w{layer}"] = jax.random.normal(
+            key, (fan_in, hidden), jnp.float32
+        ) * jnp.sqrt(2.0 / fan_in)
+        dense[f"{prefix}b{layer}"] = jnp.zeros((hidden,), jnp.float32)
+    return dense
+
+
+def mlp_stack(
+    dense: dict, h: jax.Array, layers: int = 1, prefix: str = ""
+) -> jax.Array:
+    """``layers`` ReLU layers ``h <- ReLU(h w_l + b_l)`` -> [B, hidden]:
+    the ONE hidden stack of the dense half.  ``mlp_head`` and
+    ``mlp_tower`` are its one-layer case under a linear output; DCN's
+    deep half is ``Config.deep_layers`` of it."""
+    for layer in range(1, layers + 1):
+        h = jax.nn.relu(
+            dense_dot(h, dense[f"{prefix}w{layer}"])
+            + dense[f"{prefix}b{layer}"]
+        )
+    return h
+
 
 def mlp_head_init(
     rng: jax.Array, in_dim: int, hidden: int
 ) -> dict[str, jax.Array]:
     """He-init 2-layer scalar head (wide&deep's exact dense geometry):
     in_dim -> hidden (ReLU) -> 1."""
-    k1, k2 = jax.random.split(rng)
-    return {
-        "w1": jax.random.normal(k1, (in_dim, hidden), jnp.float32)
-        * jnp.sqrt(2.0 / in_dim),
-        "b1": jnp.zeros((hidden,), jnp.float32),
-        "w2": jax.random.normal(k2, (hidden, 1), jnp.float32)
-        * jnp.sqrt(1.0 / hidden),
-        "b2": jnp.zeros((1,), jnp.float32),
-    }
+    return mlp_tower_init(rng, in_dim, hidden, 1)
 
 
 def mlp_head(dense: dict, h: jax.Array) -> jax.Array:
-    """2-layer ReLU scalar head -> [B] (wide&deep's deep output,
-    verbatim)."""
-    h = jax.nn.relu(h @ dense["w1"] + dense["b1"])
-    return (h @ dense["w2"] + dense["b2"])[:, 0]
+    """2-layer ReLU scalar head -> [B] (wide&deep's deep output): the
+    tower of one output."""
+    return mlp_tower(dense, h)[:, 0]
 
 
 def mlp_tower_init(
@@ -162,10 +209,7 @@ def mlp_tower_init(
     (two_tower's u_/i_ pair)."""
     k1, k2 = jax.random.split(rng)
     return {
-        f"{prefix}w1": jax.random.normal(
-            k1, (in_dim, hidden), jnp.float32
-        ) * jnp.sqrt(2.0 / in_dim),
-        f"{prefix}b1": jnp.zeros((hidden,), jnp.float32),
+        **mlp_stack_init(k1, in_dim, hidden, prefix=prefix),
         f"{prefix}w2": jax.random.normal(
             k2, (hidden, out_dim), jnp.float32
         ) * jnp.sqrt(1.0 / hidden),
@@ -173,10 +217,11 @@ def mlp_tower_init(
     }
 
 
+@jax.named_scope(DENSE_SCOPE)
 def mlp_tower(dense: dict, h: jax.Array, prefix: str = "") -> jax.Array:
     """2-layer ReLU vector tower -> [B, out_dim]."""
-    h = jax.nn.relu(h @ dense[f"{prefix}w1"] + dense[f"{prefix}b1"])
-    return h @ dense[f"{prefix}w2"] + dense[f"{prefix}b2"]
+    h = mlp_stack(dense, h, prefix=prefix)
+    return dense_dot(h, dense[f"{prefix}w2"]) + dense[f"{prefix}b2"]
 
 
 def dot_interaction(u: jax.Array, v: jax.Array) -> jax.Array:

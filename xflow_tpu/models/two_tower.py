@@ -110,6 +110,12 @@ class TwoTowerModel(AutodiffModel):
         ))
         return dense
 
+    def dense_matmuls(self) -> list[tuple[int, int]]:
+        user_in = self.split_field * self.emb_dim
+        item_in = (self.max_fields - self.split_field) * self.emb_dim
+        out = (self.hidden, self.tower_dim + 1)
+        return [(user_in, self.hidden), out, (item_in, self.hidden), out]
+
     def _towers_input(
         self, rows: dict[str, jax.Array], batch: BatchArrays
     ) -> jax.Array:

@@ -64,6 +64,9 @@ class WideDeepModel(AutodiffModel):
             rng, self.max_fields * self.emb_dim, self.hidden
         )
 
+    def dense_matmuls(self) -> list[tuple[int, int]]:
+        return [(self.max_fields * self.emb_dim, self.hidden), (self.hidden, 1)]
+
     def logit(
         self,
         rows: dict[str, jax.Array],

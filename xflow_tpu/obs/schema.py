@@ -417,6 +417,12 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # [T] view: the tables of one column (step.py::_optimizer_pass),
         # 0 where every table is wider or the update touches rows alone
         "flat_pass_elements_per_step": (int, float),
+        # a family with replicated dense parameters only, from shapes:
+        # the bytes of its dense arrays, and 6 B k n operations a step
+        # for every [B, k] x [k, n] product with one of them
+        # (Model.dense_matmuls; parallel/step.py::_book_wire)
+        "dense_param_bytes": (int, float),
+        "dense_matmul_flops_per_step": (int, float),
         # of wire_bytes_per_example, the planes of field ids (slots_u8 /
         # hot_slots_u8, the dictionary wire's cw_cs / cw_hs, the full
         # wire's slots / hot_slots): 0 where none ships, as for a model

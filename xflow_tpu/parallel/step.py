@@ -74,6 +74,21 @@ _HLO_COLLECTIVE_RE = re.compile(
 )
 _SCOPE_RE = re.compile(r"xf\.[A-Za-z0-9_]+")
 
+
+def scope_of(op_name: str) -> str:
+    """The ``xf.*`` scope of an instruction's ``op_name`` path: the
+    INNERMOST one, the last ``xf.<name>`` anywhere in the path (autodiff
+    and scan wrap path components: ``transpose(jvp(xf.dense))``), ``""``
+    where the path has none.  A scope opened inside another is the more
+    specific name for its operations: the dense half ``xf.dense``
+    (models/blocks.py) runs inside ``xf.forward_backward`` and is read
+    apart from it.  Until PR 39 the first name won; no program without a
+    dense half nests two different scopes, so theirs map as they did
+    (tests/test_tpu_compile.py holds the benchmark's to that)."""
+    found = _SCOPE_RE.findall(op_name)
+    return found[-1] if found else ""
+
+
 # The planes of field ids a batch can ship, by wire: the full wire's,
 # the compact wire's (compact_wire_np), the dictionary wire's
 # (io/compact.py::CompactBatch.wire).  The compact and dictionary wires
@@ -712,6 +727,22 @@ class TrainStep:
         # elements a step's whole-array optimizer passes run on the flat
         # view (_optimizer_pass: the tables of one column)
         self._flat_pass_elements = self._count_flat_pass_elements()
+        # what a family with replicated dense parameters holds and does
+        # a step, from shapes (_book_wire): the bytes of its dense
+        # arrays, and the operations of its products with them, forward
+        # 2 B k n each and twice that backward (0 and 0 for a family
+        # whose parameters are all table rows)
+        owns_dense = hasattr(model, "dense_init")
+        dense_shapes = (
+            jax.eval_shape(model.dense_init, jax.random.PRNGKey(0))
+            if owns_dense else {}
+        )
+        self._dense_param_bytes = sum(
+            a.size * a.dtype.itemsize for a in dense_shapes.values()
+        )
+        self._dense_matmul_flops = 6 * cfg.batch_size * sum(
+            k * n for k, n in (model.dense_matmuls() if owns_dense else [])
+        )
         # Hierarchical parameter store (Config.store_mode; store/):
         # under 'tiered' the table state is the store's hot tier + host
         # cold rows, the wire is the store's refs/miss format (the
@@ -824,7 +855,15 @@ class TrainStep:
         to lay its rows out by row gathers: 0 where every table goes
         column by column.  ``flat_pass_elements`` is what the step's
         whole-array optimizer passes ran on the flat view
-        (_count_flat_pass_elements): 0 where no table has one column."""
+        (_count_flat_pass_elements): 0 where no table has one column.
+        A family that owns replicated dense parameters also books, from
+        shapes, ``dense.param_bytes`` (the bytes of its dense arrays)
+        and ``dense.matmul_flops`` (6 B k n for every [B, k] x [k, n]
+        product with one of them, Model.dense_matmuls: forward and the
+        two backward products); a family without books neither."""
+        if self._dense_param_bytes:
+            self.obs.counter("dense.param_bytes", self._dense_param_bytes)
+            self.obs.counter("dense.matmul_flops", self._dense_matmul_flops)
         self.obs.counter("wire.bytes", nbytes)
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")
@@ -1086,11 +1125,11 @@ class TrainStep:
         program compiled for these shapes: the map from what a profiler
         calls a device operation to the ``xf.*`` scope the source gave
         it (docs/OBSERVABILITY.md "Scopes and spans").  ``scope`` is the
-        first ``xf.<name>`` anywhere in the instruction's ``op_name``
-        (autodiff and scan wrap path components), ``""`` where the path
-        has none; on a mesh of more than one device a collective that
-        the compiler left without one is ``xf.exchange``'s (the rule is
-        in the loop below).  Instructions inside a fusion, parameters,
+        innermost ``xf.<name>`` of the instruction's ``op_name``
+        (``scope_of``), ``""`` where the path has none; on a mesh of
+        more than one device a collective that the compiler left
+        without one is ``xf.exchange``'s (the rule is in the loop
+        below).  Instructions inside a fusion, parameters,
         constants and tuple plumbing never run on their own and are
         left out.
 
@@ -1131,8 +1170,7 @@ class TrainStep:
             if not m or _HLO_NEVER_RUNS_RE.search(line):
                 continue
             path = _HLO_OP_NAME_RE.search(line)
-            scope = _SCOPE_RE.search(path.group(1)) if path else None
-            name = scope.group(0) if scope else ""
+            name = scope_of(path.group(1)) if path else ""
             if not name and self._sharded:
                 # On a mesh every collective of the step is the
                 # exchange's, bar the all-reduces of a few scalars that

@@ -64,6 +64,10 @@ def build_parser() -> argparse.ArgumentParser:
         "--cross-layers", type=int, dest="cross_layers",
         help="dcn: explicit cross-network depth",
     )
+    p.add_argument(
+        "--deep-layers", type=int, dest="deep_layers",
+        help="dcn: ReLU layers of --hidden-dim in the deep half",
+    )
     p.add_argument("--max-nnz", type=int, dest="max_nnz")
     p.add_argument("--max-fields", type=int, dest="max_fields")
     p.add_argument("--block-mib", type=int, dest="block_mib")

@@ -1078,12 +1078,12 @@ class Trainer:
                     2,
                 ),
             }
+            batches = max(snap.counters.get("wire.batches", 0), 1)
             if "wire.cold_slots" in snap.counters:
                 # what the batches asked of the [T, D] tables, a batch,
                 # from shapes: indices of the cold gather beside the
                 # padded cold slots (equal unless the step read the
                 # dictionary wire's plan: TrainStep._book_wire)
-                batches = max(snap.counters.get("wire.batches", 0), 1)
                 stats["_wire"]["table_gather_indices_per_step"] = round(
                     snap.counters["wire.table_gather_indices"] / batches
                 )
@@ -1108,6 +1108,16 @@ class Trainer:
                     stats["_wire"]["cold_row_layout_slots_per_step"] = round(
                         snap.counters["wire.cold_row_layout_slots"] / batches
                     )
+            if "dense.param_bytes" in snap.counters:
+                # a family with replicated dense parameters, a batch and
+                # from shapes: their bytes, and the operations of the
+                # products with them (TrainStep._book_wire)
+                stats["_wire"]["dense_param_bytes"] = round(
+                    snap.counters["dense.param_bytes"] / batches
+                )
+                stats["_wire"]["dense_matmul_flops_per_step"] = round(
+                    snap.counters["dense.matmul_flops"] / batches
+                )
             if "exchange.bytes" in snap.counters:
                 # a mesh of more than one device: what the step's pull
                 # and push moved between the chips, from shapes
