@@ -747,3 +747,77 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
     ]
     peak = _program_peak(compiled)
     assert 9.0 * (1 << 30) < peak < 10.5 * (1 << 30), peak
+
+
+def test_serving_program_takes_one_packed_buffer_on_v5e(topo, no_compile_cache):
+    """The serving engine's bucket-512 predict program at the geometry of
+    the benchmark's lr_tb.serve_rows (benchmarks/configs/
+    lr_ftrl_criteo_tb.json: 2^28 rows of one column, 12 + 28 slots, hot
+    2^12, the plain compact wire) for a described v5e.  A request batch
+    crosses as ONE byte buffer (serve/engine.py::_put_packed; PERF.md
+    section 6, PR 40): beside the state the program has a single batch
+    parameter, u8[512, 106] = ckeys 12 x int32 + hot_ckeys_u16 28 x
+    uint16 + labels_u8 + weights_u8, and unpacks the planes itself.
+    Compiled: the unpack copies nothing of the table's size, and the
+    program holds the table and next to nothing else."""
+    from benchmarks.harness import manifest
+    from xflow_tpu.config import Config
+    from xflow_tpu.io.batch import Batch
+    from xflow_tpu.parallel import mesh as meshes
+    from xflow_tpu.parallel.step import pack_wire_np
+    from xflow_tpu.serve.engine import PredictEngine
+
+    doc = manifest.config_file("benchmarks/configs/lr_ftrl_criteo_tb.json")
+    cfg = Config(**{
+        k: v for k, v in manifest.apply_rehearsal(doc, False).items()
+        if k not in manifest.CONFIG_META
+    })
+    mesh = meshes.make_mesh(1, devices=list(topo.devices))
+
+    def shaped(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    t = cfg.table_size
+    state = {
+        "tables": {"w": {"param": shaped(
+            (t, 1), jnp.float32, meshes.table_sharding(mesh)
+        )}},
+        "dense": {},
+        "step": shaped((), jnp.int32, meshes.replicated(mesh)),
+    }
+    # the remap steers requests on the host; the program never sees it
+    engine = PredictEngine(cfg, state, remap=np.zeros(0, np.int32), mesh=mesh)
+    step = engine.step
+    assert step.wire_format == "compact" and step._hot_u16
+    rows, kc, kh = 512, cfg.max_nnz, cfg.hot_nnz
+
+    def plane(k, dtype):
+        return np.zeros((rows, k), dtype)
+
+    wire, _ = step.host_wire_np(Batch(
+        keys=plane(kc, np.int32), slots=plane(kc, np.int32),
+        vals=plane(kc, np.float32), mask=plane(kc, np.float32),
+        labels=np.zeros(rows, np.float32), weights=np.zeros(rows, np.float32),
+        hot_keys=plane(kh, np.int32), hot_slots=plane(kh, np.int32),
+        hot_vals=plane(kh, np.float32), hot_mask=plane(kh, np.float32),
+    ))
+    assert sorted(wire) == [
+        "ckeys", "hot_ckeys_u16", "labels_u8", "weights_u8"
+    ]
+    buf, layout = pack_wire_np(wire)
+    assert buf.shape == (rows, 4 * kc + 2 * kh + 2) == (512, 106)
+    lowered = engine.predict_jit.lower(
+        state, shaped(buf.shape, buf.dtype, step._bsharding), layout=layout
+    )
+    (main,) = [
+        line for line in lowered.as_text().splitlines()
+        if "func.func public @main(" in line
+    ]
+    args = re.findall(r"%arg\d+: (tensor<[^>]*>)", main)
+    # (the state's step scalar is not read, so it is no parameter)
+    assert args == [f"tensor<{t}x1xf32>", "tensor<512x106xui8>"], main
+    compiled = lowered.compile()
+    assert not _table_sized_copies(compiled.as_text(), t)
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes < 1.001 * 4 * t + (1 << 20)
+    assert ma.temp_size_in_bytes < 64 << 20, ma.temp_size_in_bytes

@@ -483,6 +483,11 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "resolve_p50": (int, float),
         "resolve_p99": (int, float),
         "resolve_max": (int, float),
+        # host->device transfer calls a batch's h2d leg made and their
+        # bytes, means over the window's batches (ISSUE 40: 1 call
+        # since the engine packs a request batch into one buffer)
+        "h2d_transfers_mean": (int, float),
+        "h2d_bytes_mean": (int, float),
         "coalesce_p50": (int, float),
         "workers": int,
         "worker_busy_s": (int, float),

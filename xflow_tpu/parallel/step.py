@@ -350,6 +350,55 @@ def batch_to_compact(
     }
 
 
+# One plane's place in a packed wire buffer (pack_wire_np): its name,
+# dtype, trailing shape and first byte in a row.  Hashable: a static
+# argument of the program that unpacks it.
+WireLayout = tuple[tuple[str, str, tuple[int, ...], int], ...]
+
+
+def pack_wire_np(wire: dict) -> tuple[np.ndarray, WireLayout]:
+    """The planes of a wire whose every plane leads with the batch axis
+    (the compact and full wires of ``TrainStep.host_wire_np``; not the
+    dictionary wire's flat planes), laid side by side as the bytes of
+    ONE ``uint8[B, row_bytes]`` buffer, widest element first so that no
+    element straddles its own alignment, with the layout
+    ``unpack_wire`` inverts.  The layout follows from the planes' shapes
+    and dtypes alone.  A request batch is a few KB and every
+    host->device call has a fixed price, so the serving engine ships
+    this buffer in one transfer (serve/engine.py) where the trainer
+    ships large planes one by one on worker threads (put_batch)."""
+    planes = sorted(wire.items(), key=lambda kv: -kv[1].dtype.itemsize)
+    rows = len(planes[0][1])
+    parts = [
+        np.ascontiguousarray(v).view(np.uint8).reshape(rows, -1)
+        for _, v in planes
+    ]
+    offsets = np.cumsum([0] + [p.shape[1] for p in parts])
+    layout = tuple(
+        (k, v.dtype.str, v.shape[1:], int(off))
+        for (k, v), off in zip(planes, offsets)
+    )
+    return np.concatenate(parts, axis=1), layout
+
+
+def unpack_wire(buf: jax.Array, layout: WireLayout) -> BatchArrays:
+    """``pack_wire_np``'s planes back out of its buffer, inside a jitted
+    program: each plane's columns sliced and bitcast to its dtype, bit
+    for bit what the host packed."""
+    out = {}
+    for name, dtype, trail, off in layout:
+        dt = np.dtype(dtype)
+        width = int(np.prod(trail, dtype=np.int64)) * dt.itemsize
+        cols = buf[:, off:off + width]
+        if dt != np.uint8:
+            # [B, n, itemsize] bytes -> [B, n] elements
+            cols = jax.lax.bitcast_convert_type(
+                cols.reshape(len(buf), -1, dt.itemsize), dt
+            )
+        out[name] = cols.reshape(len(buf), *trail)
+    return out
+
+
 def _interleaved_slices(batch: BatchArrays, s: int) -> BatchArrays:
     """Split the batch dim into s scan slices with INTERLEAVED example
     assignment (example i → slice i % s): each slice stays evenly

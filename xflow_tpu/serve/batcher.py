@@ -64,6 +64,10 @@ def stats_row_from_snapshot(snap: Snapshot, workers: int = 1) -> dict:
     def pct(name: str, p: str) -> float:
         return round(snap.hists.get(name, {}).get(p, 0.0), 6)
 
+    def per_batch(name: str) -> float:
+        batches = snap.counters.get("serve.batches", 0)
+        return round(snap.counters.get(name, 0) / max(batches, 1), 3)
+
     def total(name: str) -> float:
         h = snap.hists.get(name, {})
         return round(h.get("mean", 0.0) * h.get("count", 0), 6)
@@ -90,6 +94,10 @@ def stats_row_from_snapshot(snap: Snapshot, workers: int = 1) -> dict:
             for leg in ("h2d", "dispatch", "fetch", "resolve")
             for p in ("p50", "p99", "max")
         },
+        # host->device transfer calls a batch's h2d leg made, and the
+        # bytes they carried (engine.last_h2d)
+        "h2d_transfers_mean": per_batch("serve.h2d_transfers"),
+        "h2d_bytes_mean": per_batch("serve.h2d_bytes"),
         "coalesce_p50": pct("serve.coalesce_seconds", "p50"),
         "workers": workers,
         # the seconds the workers spent inside batches
@@ -549,4 +557,7 @@ class MicroBatcher:
             reg.observe("serve.batch_size", float(len(reqs)))
             for leg, seconds in split.items():
                 reg.observe(f"serve.{leg}_seconds", seconds)
+            # what the h2d leg shipped: transfer calls and their bytes
+            for what, n in (getattr(engine, "last_h2d", None) or {}).items():
+                reg.counter_add(f"serve.h2d_{what}", n)
         reg.observe("serve.resolve_seconds", time.perf_counter() - t2)

@@ -43,6 +43,12 @@ from xflow_tpu.config import Config
 from xflow_tpu.io.batch import Batch, pad_batch_rows, remap_batch
 from xflow_tpu.obs import NULL_OBS, profiler_span
 from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
+from xflow_tpu.parallel.step import (
+    _SLOT_PLANES,
+    TrainStep,
+    pack_wire_np,
+    unpack_wire,
+)
 
 DEFAULT_BUCKETS = (1, 8, 64, 512)
 # default top-k compile width for retrieval engines (attach_item_index):
@@ -89,7 +95,6 @@ class PredictEngine:
         warm: bool = False,
     ):
         from xflow_tpu.models import make_model
-        from xflow_tpu.parallel.step import TrainStep
 
         self.cfg = cfg
         self.digest = digest if digest is not None else cfg.digest()
@@ -164,8 +169,13 @@ class PredictEngine:
         # time), and the explicit binding makes the impls visible to
         # the static memory pass (shapeflow jit-entry discovery →
         # XF014 budgets in memory-budget.json)
-        self.topk_jit = jax.jit(self._topk_impl)
-        self.item_embed_jit = jax.jit(self._item_embed_impl)
+        self.predict_jit = jax.jit(
+            self._predict_impl, static_argnames="layout"
+        )
+        self.topk_jit = jax.jit(self._topk_impl, static_argnames="layout")
+        self.item_embed_jit = jax.jit(
+            self._item_embed_impl, static_argnames="layout"
+        )
         self.warm_seconds = 0.0
         self._parse_fn = None
         # serve-time item index (retrieval families, docs/SERVING.md
@@ -181,6 +191,11 @@ class PredictEngine:
         # clone, so the batcher's registry and the batch span
         # (obs/reqtrace.py) can carve the device phase without a lock.
         self.last_device_phases: dict | None = None
+        # beside it, what the h2d leg of that call shipped: the
+        # host->device transfer calls it made and their bytes
+        # ({"transfers": n, "bytes": b}; serve.h2d_* in the batcher's
+        # registry)
+        self.last_h2d: dict | None = None
         if warm:
             self.warm()
 
@@ -336,10 +351,10 @@ class PredictEngine:
         predict path), ``_compiled`` (AOT executables are immutable
         once built; a rare concurrent non-canonical-shape miss at worst
         compiles twice and last-write-wins), mesh, remap, digest.  What
-        is NOT shared: the ``TrainStep`` wire machinery — ``put_batch``
-        keeps per-instance host staging, and each fleet replica is
-        driven by its own MicroBatcher worker thread, so sharing the
-        step would race."""
+        is NOT shared: the ``TrainStep`` wire machinery and the
+        per-call records of the put (``last_device_phases``,
+        ``last_h2d``) — each fleet replica is driven by its own
+        MicroBatcher worker thread, so sharing them would race."""
         replica = PredictEngine(
             self.cfg,
             self.state,
@@ -544,12 +559,17 @@ class PredictEngine:
         if self.topk_k < 1:
             raise ValueError("topk_k must be >= 1")
 
-    def _topk_impl(self, state, index, arrays):
+    def _predict_impl(self, state, buf, layout):
+        """pctr of one packed request batch (``_put_packed``): the
+        planes out of the buffer, then the trainer's own predict."""
+        return self.step._predict_impl(state, unpack_wire(buf, layout))
+
+    def _topk_impl(self, state, index, buf, layout):
         """User-tower pass + dot-product scan + device top-k — the
         whole retrieval scoring path as ONE jitted program (AOT per
         bucket like predict).  ``index`` [N, D] rides as an argument,
         so a rollout's new index needs zero recompiles."""
-        batch = self.step._expand_wire(arrays)
+        batch = self.step._expand_wire(unpack_wire(buf, layout))
         rows = self.step._gather_model_rows(state["tables"], batch)
         u = self.model.user_embed(
             rows, self.step._model_view(batch), state["dense"]
@@ -558,13 +578,56 @@ class PredictEngine:
         vals, idx = jax.lax.top_k(scores, self.topk_k)
         return vals, idx, u
 
-    def _item_embed_impl(self, state, arrays):
+    def _item_embed_impl(self, state, buf, layout):
         """Item-tower pass [B, D] — export_item_index's batch leg."""
-        batch = self.step._expand_wire(arrays)
+        batch = self.step._expand_wire(unpack_wire(buf, layout))
         rows = self.step._gather_model_rows(state["tables"], batch)
         return self.model.item_embed(
             rows, self.step._model_view(batch), state["dense"]
         )
+
+    def _put_packed(self, batch: Batch, validate: bool):
+        """The engine's own transfer in: the planes the step's wire
+        format ships for ``batch`` (``TrainStep.host_wire_np``; with
+        ``validate``, its compact-wire check of the batch first), packed
+        into one byte buffer (``step.pack_wire_np``) that crosses in ONE
+        host->device call, batch axis sharded over the mesh; the
+        program unpacks it (``step.unpack_wire``).  ``TrainStep.
+        put_batch`` is the trainer's: a call a plane, on worker threads
+        that hide them; on the batcher's worker thread each call is
+        serial latency.  Returns the device buffer and its layout."""
+        step = self.step
+        with self.obs.phase("h2d"):
+            # TrainStep validates compact-wire invariants only on its
+            # FIRST batch (fine for uniform loader traffic); serving
+            # traffic is heterogeneous, so a value-carrying request
+            # after warmup would otherwise have its vals silently
+            # replaced by 1.0 — ``validate`` checks every batch (O(B·K)
+            # numpy, noise next to the device call at serving batch
+            # sizes).
+            wire, _ = step.host_wire_np(batch, check=validate)
+            step._book_wire(
+                sum(int(v.nbytes) for v in wire.values()),
+                batch.num_real(),
+                cold_slots=batch.batch_size * batch.max_nnz,
+                hot_slots=batch.batch_size * batch.hot_nnz,
+                slots_bytes=sum(
+                    int(v.nbytes) for k, v in wire.items()
+                    if k in _SLOT_PLANES
+                ),
+            )
+            host, layout = pack_wire_np(wire)
+            if jax.process_count() > 1:
+                # each host packed its own rows: one global buffer
+                from jax.experimental import multihost_utils
+
+                buf = multihost_utils.host_local_array_to_global_array(
+                    host, self.mesh, step._bsharding.spec
+                )
+            else:
+                buf = jax.device_put(host, step._bsharding)
+        self.last_h2d = {"transfers": 1, "bytes": host.nbytes}
+        return buf, layout
 
     def _put_dispatch_fetch(
         self, key, jitted, batch: Batch, extra=(), *, beat: str,
@@ -575,39 +638,29 @@ class PredictEngine:
         an ``xf.serve_*`` span on the profiler's timeline (obs/__init__
         .py::profiler_span: there with or without a live ``Obs``) and
         a clock of ``last_device_phases``: ``h2d`` the transfer in
-        (with ``validate``, the compact-wire check of the request batch
-        before it), ``dispatch`` the executable's call until it RETURNS
-        (the host enqueueing the program), ``fetch`` the wait for the
-        device and the copy out.  Compiled once per ``key`` (``jitted``
-        lowered over state, ``extra`` leading arrays, the batch); a
-        compile is the phase ``serve_compile`` and no leg's.  ``host_local``
-        gathers a multi-host result to this host's rows first."""
+        (``_put_packed``: with ``validate``, the compact-wire check of
+        the request batch before it), ``dispatch`` the executable's call
+        until it RETURNS (the host enqueueing the program), ``fetch`` the
+        wait for the device and the copy out.  Compiled once per ``key``
+        (``jitted`` lowered over state, ``extra`` leading arrays, the
+        packed batch and its layout); a compile is the phase
+        ``serve_compile`` and no leg's.  ``host_local`` gathers a
+        multi-host result to this host's rows first."""
         t_call = time.perf_counter()
         with profiler_span("serve_h2d"):
-            if validate:
-                # TrainStep validates compact-wire invariants only on
-                # its FIRST batch (fine for uniform loader traffic);
-                # serving traffic is heterogeneous, so a value-carrying
-                # request after warmup would otherwise have its vals
-                # silently replaced by 1.0 — validate every batch
-                # (O(B·K) numpy, noise next to the device call at
-                # serving batch sizes).
-                from xflow_tpu.parallel.step import validate_compact_batch
-
-                validate_compact_batch(batch)
-            # books the 'h2d' phase with a live Obs; ``predict``
-            # matters to a tiered store alone, and serving pins dense
-            arrays = self.step.put_batch(batch, predict=True)
+            buf, layout = self._put_packed(batch, validate)
         t_run = t_h2d = time.perf_counter()
         exe = self._compiled.get(key)
         if exe is None:
             with self.obs.phase("serve_compile"):
-                exe = jitted.lower(self.state, *extra, arrays).compile()
+                exe = jitted.lower(
+                    self.state, *extra, buf, layout=layout
+                ).compile()
             self._compiled[key] = exe
             self.obs.counter("serve.compiles")
             t_run = time.perf_counter()
         with profiler_span("serve_dispatch"):
-            out = exe(self.state, *extra, arrays)
+            out = exe(self.state, *extra, buf)
         t_ret = time.perf_counter()
         with profiler_span("serve_fetch"):
             if host_local and jax.process_count() > 1:
@@ -786,6 +839,6 @@ class PredictEngine:
         dispatch + fetch."""
         key = (batch.batch_size, batch.max_nnz, batch.hot_nnz)
         return self._put_dispatch_fetch(
-            key, self.step.predict, batch, beat="execute",
+            key, self.predict_jit, batch, beat="execute",
             validate=self.step.compact_wire, host_local=True,
         )
