@@ -13,14 +13,17 @@ cannot size such a family (PERF.md section 7); this hands the step the
 shapes of ``model.dense_init`` beside the tables', and the wire planes of
 one real batch of the benchmark's rows (``aot_memory.one_batch``).  Beside
 the memory it reads the compiled text for what PR 34 taught to look for
-before a chip run: table-sized copies of the state, and ``[B, K, 1]``
-column planes of the cold slots.
+before a chip run: table-sized copies of the state, ``[B, K, 1]``
+column planes of the cold slots, and the largest arrays the program
+makes beside its tables (an xDeepFM step whose ``[B, H, m, D]`` pair
+tensor came back whole shows here, on the CPU).
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import re
 import shutil
@@ -113,6 +116,16 @@ def main(argv: list[str] | None = None) -> int:
         if re.search(rf"= \(?f32\[{t},[0-9]+\][^=]* copy(?:-start)?\(", line)
     ]
     planes = set(re.findall(rf"(\S+) = f32\[{b},{cfg.max_nnz},1\]", hlo))
+    # the largest arrays any instruction makes that are not the tables'
+    # own shapes: a CIN's pair tensor come back whole (B * H * m * D
+    # elements, models/blocks.py::cin_stack) would lead this list
+    table_shapes = {(t, spec.dim) for spec in model.tables()} | {(t,)}
+    made: dict[str, int] = {}
+    for kind, dims in re.findall(r" = \(?([a-z]+[0-9]+)\[([0-9,]+)\]", hlo):
+        shape = tuple(map(int, dims.split(",")))
+        if shape not in table_shapes:
+            made[f"{kind}[{dims}]"] = math.prod(shape) * int(re.sub(r"\D", "", kind)) // 8
+    largest = sorted(made.items(), key=lambda kv: -kv[1])[:5]
     print(json.dumps({
         "config": args.config,
         "table_size_log2": cfg.table_size_log2,
@@ -129,6 +142,7 @@ def main(argv: list[str] | None = None) -> int:
         },
         "table_sized_copies": copies,
         "cold_column_planes": len(planes),
+        "largest_temporaries_mib": {k: round(v / 2**20, 1) for k, v in largest},
         "note": "compiled for a described v5e:2x2, not run; one program, "
                 "not what else the process keeps on the device",
     }))

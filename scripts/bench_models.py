@@ -110,6 +110,12 @@ def model_cfgs(base_b: int, accel: bool):
             ("dcn", Config(model="dcn", max_nnz=40, emb_dim=8,
                            hidden_dim=64, cross_layers=2, **common)),
         ],
+        # the same tower again under a small CIN (2 layers of 16 maps)
+        "xdeepfm": [
+            ("xdeepfm", Config(model="xdeepfm", max_nnz=40, emb_dim=8,
+                               hidden_dim=64, cross_layers=2, cin_maps=16,
+                               **common)),
+        ],
     }
     missing = [n for n in model_names() if n not in geometries]
     if missing:

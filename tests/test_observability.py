@@ -1215,6 +1215,7 @@ _DENSE_MODELS = {
     "dcn": dict(emb_dim=4, hidden_dim=8, cross_layers=2, deep_layers=2),
     "wide_deep": dict(emb_dim=4, hidden_dim=8),
     "two_tower": dict(emb_dim=4, hidden_dim=8, tower_dim=4, tower_split_field=4),
+    "xdeepfm": dict(emb_dim=10, hidden_dim=8, cross_layers=3, cin_maps=6, deep_layers=2),
 }
 
 
@@ -1235,7 +1236,47 @@ def test_a_dense_family_runs_its_dense_half_under_xf_dense(toy_dataset, model):
     rows = _op_scope_rows(toy_dataset, model=model, **_DENSE_MODELS[model])
     scopes = {scope for _, _, scope in rows}
     assert {"xf.dense", "xf.forward_backward", "xf.optimizer"} <= scopes
-    assert scopes <= SCOPES | {"xf.dense", ""}
+    assert scopes <= SCOPES | {"xf.dense", "xf.cin", ""}
+    assert ("xf.cin" in scopes) == (model == "xdeepfm")
+
+
+def test_xf_cin_is_the_innermost_name_of_every_cin_operation(toy_dataset):
+    """xDeepFM's CIN runs under ``xf.cin``, a sibling of ``xf.dense`` inside
+    ``xf.forward_backward``, through a loop over slices and a rematerialised
+    backward.  In the compiled step every operation whose path holds
+    ``xf.cin`` has it as the INNERMOST ``xf.`` name, whatever wraps it
+    (``jvp``, ``transpose``, ``while/body``, ``closed_call/checkpoint``),
+    and both kinds are there: the forward's, and the rematerialised
+    backward's inside its loop; the CIN's contractions are among both, and
+    ``op_scopes`` maps the compiled instructions to it.  (The whole step through
+    ``xf.forward_backward``: tests/test_tpu_compile.py reads the same off
+    the program compiled for a v5e at the cell's geometry.)"""
+    from xflow_tpu.parallel.step import _SCOPE_RE, abstract_like, scope_of
+
+    cfg = _toy_cfg(toy_dataset, model="xdeepfm", max_fields=8, **_DENSE_MODELS["xdeepfm"])
+    with Trainer(cfg) as t:
+        batch, _, _ = next(iter(t.iter_train_batches(0, 0)))
+        arrays = t.step.put_batch(batch)
+        text = t.step.train.lower(
+            abstract_like(t.state), abstract_like(arrays)
+        ).compile().as_text()
+        rows = t.step.op_scopes(t.state, arrays)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    cin = [p for p in paths if "xf.cin" in p]
+    assert len(cin) >= 10
+    assert all(scope_of(p) == "xf.cin" for p in cin)
+    assert all(_SCOPE_RE.findall(p)[0] == "xf.forward_backward" for p in cin)
+    forward = [p for p in cin if "jvp(xf.cin)" in p and "transpose" not in p]
+    backward = [p for p in cin if "transpose(jvp(xf.cin))" in p]
+    rematerialised = [p for p in backward if "/checkpoint/" in p]
+    assert forward and backward and rematerialised
+    assert all("while/body" in p for p in rematerialised)
+    # the contractions with a cin_w are the CIN's, forward and backward,
+    # and no product is among what the backward multiplies again
+    dots = [p for p in cin if p.endswith("dot_general")]
+    assert [p for p in dots if p in forward] and [p for p in dots if p in backward]
+    assert not [p for p in dots if "rematted_computation" in p]
+    assert sum(scope == "xf.cin" for _, _, scope in rows) >= 10
 
 
 @pytest.mark.parametrize("model, overrides", [
@@ -1275,6 +1316,10 @@ def test_scope_of_takes_the_innermost_name():
     assert scope_of("jit(f)/xf.scatter/xf.scatter/scatter-add") == "xf.scatter"
     assert scope_of("jit(f)/transpose(jvp(xf.gather))/mul") == "xf.gather"
     assert scope_of("jit(f)/reduce_sum") == ""
+    assert scope_of(
+        "jit(f)/xf.forward_backward/transpose(jvp(xf.cin))/while/body/closed_call/"
+        "checkpoint/rematted_computation/mul"
+    ) == "xf.cin"
 
 
 @pytest.mark.parametrize("model", sorted(_DENSE_MODELS))
@@ -1285,7 +1330,8 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
     those are the products the benchmark's reference reads off the dense
     arrays' shapes."""
     from benchmarks.harness import costs
-    from benchmarks.reference import dcn_criteo
+    from benchmarks.layer_metrics import cin_mxu_roofline
+    from benchmarks.reference import dcn_criteo, xdeepfm_criteo
     from xflow_tpu.obs.schema import OPTIONAL, validate_rows
 
     metrics = tmp_path / "m.jsonl"
@@ -1297,9 +1343,11 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
         t.train()
         dense = t.state["dense"]
         matmuls = t.step.model.dense_matmuls()
+        shapes = {name: list(a.shape) for name, a in dense.items()}
         if model == "dcn":
-            shapes = {name: list(a.shape) for name, a in dense.items()}
             assert dcn_criteo.matmuls(shapes) == matmuls
+        if model == "xdeepfm":
+            assert xdeepfm_criteo.matmuls(shapes) == matmuls
         param_bytes = sum(a.size * 4 for a in dense.values())
     rows = [json.loads(line) for line in metrics.read_text().splitlines()]
     assert validate_rows(rows) == []
@@ -1311,9 +1359,25 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
         {"w": 1}, entries_per_step=1.0, hot_share=0.0, matmuls=matmuls,
     )["flops"]
     assert wire["dense_matmul_flops_per_step"] == want > 0
+    # what else a family hands the step (Model.dense_counters) reaches the
+    # row under its own name: the slice of xDeepFM's CIN, and nothing of any
+    # other family.  The CIN's operations have ONE owner in the program, the
+    # model's dense_matmuls; the benchmark's reader counts them again from
+    # the configuration's fields, and the two agree
+    own = set(wire) - {"dense_param_bytes", "dense_matmul_flops_per_step"}
+    own = {k for k in own if k.startswith("dense_")}
+    if model != "xdeepfm":
+        assert not own
+        return
+    assert own == {"dense_cin_slice_rows"} <= set(OPTIONAL["wire"])
+    assert wire["dense_cin_slice_rows"] == cfg.batch_size  # a toy batch goes whole
+    b, d, m, maps = cfg.batch_size, cfg.emb_dim, cfg.max_fields, cfg.cin_maps
+    assert cin_mxu_roofline.cin_flops(
+        {"batch_size": b, "emb_dim": d, "max_fields": m, "cin_maps": maps,
+         "cross_layers": cfg.cross_layers}
+    ) == 6 * b * sum(k * n for k, n in matmuls[:cfg.cross_layers])
 
 
 def test_a_family_without_dense_parameters_books_no_dense_counter(packed_run):
     (wire, *_) = [r for r in packed_run if r["kind"] == "wire"]
-    assert "dense_param_bytes" not in wire
-    assert "dense_matmul_flops_per_step" not in wire
+    assert not [k for k in wire if k.startswith("dense_")]

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from benchmarks.harness import refcheck
-from benchmarks.reference import dcn_criteo, wide_deep
+from benchmarks.reference import dcn_criteo, wide_deep, xdeepfm_criteo
 from xflow_tpu.config import Config
 from xflow_tpu.io.batch import make_batch
 from xflow_tpu.models import blocks, make_model
@@ -27,6 +27,14 @@ from xflow_tpu.parallel.step import TrainStep, init_state
 
 MAX_FIELDS = 4  # a dozen entries a row over four fields: every field sum has terms
 DCN = {"model": "dcn", "emb_dim": dcn_criteo.EMB_DIM, "hidden_dim": 16, "cross_layers": 3}
+# rows drawn at 0.3: the CIN's gradients are of second to fourth order in the
+# embeddings, and at 0.01 an update of ``cin_w2`` / ``cin_w3`` is under one
+# float32 step of the weight, which the dense measure cannot see
+XDEEPFM = {
+    "model": "xdeepfm", "emb_dim": xdeepfm_criteo.EMB_DIM, "hidden_dim": 16,
+    "cross_layers": 3, "cin_maps": 8, "deep_layers": 2, "v_init_scale": 0.3,
+    "sgd_lr": 0.05,
+}
 
 
 def _system(**fields):
@@ -76,6 +84,8 @@ def _off(step: dict) -> set[str]:
     ({**DCN, "deep_layers": 2, "hot_impl": "mxu"}, dcn_criteo),
     ({**DCN, "deep_layers": 3, "hot_impl": "mxu"}, dcn_criteo),
     ({"model": "wide_deep", "hot_impl": "mxu"}, wide_deep),
+    ({**XDEEPFM, "hot_impl": "seg"}, xdeepfm_criteo),
+    ({**XDEEPFM, "hot_impl": "mxu"}, xdeepfm_criteo),
 ], ids=lambda v: "-".join(
     str(v[k]) for k in ("model", "deep_layers", "hot_impl") if k in v
 ) if isinstance(v, dict) else v.__name__.rsplit(".", 1)[-1])
@@ -91,6 +101,14 @@ def test_program_step_agrees_with_the_dense_reference(fields, family):
     if cfg.model == "dcn":
         stack = {f"{p}{k}" for k in range(1, cfg.deep_layers + 1) for p in "wb"}
         assert arrays == stack | {"cross_w", "cross_b", "w_out", "b_out"}
+    if cfg.model == "xdeepfm":
+        stack = {f"{p}{k}" for k in range(1, cfg.deep_layers + 1) for p in "wb"}
+        cin = {f"cin_w{k}" for k in range(1, cfg.cross_layers + 1)}
+        assert arrays == stack | cin | {"w_out", "b_out"}
+        assert system.state["dense"]["cin_w2"].shape == (8, 8, MAX_FIELDS)
+        # every CIN array moves by enough of its own float32 steps to be seen
+        assert all(s["dense"][a]["update_ulps"] > 50 for s in got["steps"] for a in cin)
+    if cfg.model in ("dcn", "xdeepfm"):
         assert family.matmuls(got["dense_shapes"]) == system.step.model.dense_matmuls()
     for step in got["steps"]:
         assert step["logloss_err"] <= refcheck.LOGLOSS_ATOL
@@ -111,19 +129,52 @@ def _freeze(system, array: str) -> None:
     system.step.train = train
 
 
-@pytest.mark.parametrize("array", ["w1", "w2", "b2", "cross_w", "w_out"])
-def test_a_dense_array_left_as_it_was_fails_by_that_array(array):
+@pytest.mark.parametrize("array, fields, family", [
+    *((a, {**DCN, "deep_layers": 2}, dcn_criteo) for a in ["w1", "w2", "b2", "cross_w", "w_out"]),
+    *((a, XDEEPFM, xdeepfm_criteo) for a in ["cin_w1", "cin_w2", "cin_w3"]),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_a_dense_array_left_as_it_was_fails_by_that_array(array, fields, family):
     """A step that does not move one array of the two-layer program (an
     optimizer that skips it, a gradient that never reaches it) reads exactly
-    1 there in its first step and fails.  (From the second step on the arrays
+    1 there in its first step and fails: DCN's arrays, and each of xDeepFM's
+    three-dimensional CIN weights.  (From the second step on the arrays
     downstream of a frozen one see other gradients too.)"""
-    system, batches, cfg = _system(**DCN, deep_layers=2)
+    system, batches, cfg = _system(**fields)
     _freeze(system, array)
-    got = refcheck.check_train_steps(system, dcn_criteo, batches, cfg)
+    got = refcheck.check_train_steps(system, family, batches, cfg)
     assert not got["ok"] and not any(s["ok"] for s in got["steps"])
     first = got["steps"][0]
     assert _off(first) == {array} and first["dense"][array]["rel_err"] == 1.0
     assert max(first["rows_rel_err"].values()) <= refcheck.ROWS_RTOL
+
+
+def test_the_cells_own_constants_let_the_check_see_the_cin(capsys):
+    """``xdeepfm_tb.train_packed``'s own configuration (its FTRL constants,
+    ``sgd_lr`` and init: what the toy cases above replace by rows drawn at
+    0.3), rehearsed at the paper's widths: after two epochs every ``cin_w``
+    moves by thousands of its own float32 steps a step, and the control
+    (``benchmarks/control.py``: the reference with its operands rounded to
+    bfloat16) is out of ``DENSE_RTOL`` in EVERY one of them.  Under the other
+    configurations' ``beta`` 1 / ``lambda2`` 10 the embeddings stayed at 1e-7
+    - 1e-4, ``cin_w2`` / ``cin_w3`` moved by 1 and 0 steps, and a CIN at
+    default precision passed the cell's check on the chip (PERF.md section 2)."""
+    import json
+
+    from benchmarks import control
+    from benchmarks.harness import manifest
+
+    fields = manifest.config_file("benchmarks/configs/xdeepfm_ftrl_criteo_tb.json")
+    assert fields["beta"] * fields["batch_size"] == 1.0
+    assert fields["lambda2"] * fields["batch_size"] == 10.0
+    argv = ["--workload", "xdeepfm_tb.train_packed", "--seed", "7",
+            "--seconds", "0.5", "--rehearsal"]
+    assert control.main(argv) == 0  # the control failed, as it must
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["checks_failed"] == ["steps_match_reference"]
+    compared = line["compared"]
+    for k in (1, 2, 3):
+        assert compared[f"dense_update_ulps.cin_w{k}"]["value"] >= 1000
+        assert compared[f"dense_rel_err.cin_w{k}"]["value"] > 4 * refcheck.DENSE_RTOL
 
 
 def test_a_stack_without_its_second_layer_fails():
@@ -293,3 +344,151 @@ def test_a_two_layer_stack_survives_checkpoint_and_artifact(toy_dataset, tmp_pat
             trainer.state, trainer.step.put_batch(trainer.prepare_batch(batch))
         )))
         np.testing.assert_allclose(engine.predict(batch), want, atol=1e-6)
+
+
+def _cin_case(b: int = 13, m: int = 8, d: int = 3, maps: int = 5, empty=()):
+    """A tower [b, m, d] (the fields of ``empty`` all zero, as a bucket no
+    row fills) and three layers' weights; values of order 1."""
+    rng = np.random.default_rng(7)
+    tower = rng.normal(0, 0.5, (b, m, d)).astype(np.float32)
+    tower[:, list(empty), :] = 0.0
+    weights = [
+        jnp.asarray(rng.normal(0, 0.3, (maps, h, m)), jnp.float32)
+        for h in (m, maps, maps)
+    ]
+    return weights, jnp.asarray(tower)
+
+
+def _plain_cin(weights, tower):
+    """The CIN as its equation: one einsum a layer, every layer pooled."""
+    xk, pooled = tower, []
+    for w in weights:
+        xk = jnp.einsum("hij,bid,bjd->bhd", w, xk, tower)
+        pooled.append(jnp.sum(xk, axis=-1))
+    return jnp.concatenate(pooled, axis=-1)
+
+
+@pytest.mark.parametrize("slice_rows", [1, 4, 13], ids=["one-row", "uneven", "whole"])
+def test_sliced_cin_equals_the_plain_einsum_in_value_and_gradients(slice_rows):
+    """``blocks.cin_stack`` (slices of the batch through ``lax.map``, pairs
+    along the lanes, each slice's backward rematerialised) against one plain
+    einsum a layer: the pooled maps, and the gradient of every layer's
+    weights and of the tower, for a slice of one row, a slice that does not
+    divide the batch (the last is padded) and the whole batch."""
+    weights, tower = _cin_case()
+    want = _plain_cin(weights, tower)
+    got = blocks.cin_stack(weights, tower, slice_rows)
+    assert got.shape == want.shape == (13, 15)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    mix = jnp.asarray(np.random.default_rng(8).normal(size=want.shape), jnp.float32)
+    grads = [
+        jax.grad(lambda w, t: jnp.sum(f(w, t) * mix), (0, 1))(weights, tower)
+        for f in (lambda w, t: blocks.cin_stack(w, t, slice_rows), _plain_cin)
+    ]
+    for a, b in zip(jax.tree.leaves(grads[0]), jax.tree.leaves(grads[1])):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(a, b, atol=2e-5 * scale, rtol=0)
+
+
+def test_an_empty_field_leaves_its_cin_weights_unmoved():
+    """max_fields counts a bucket more than the rows have fields (40 for
+    39): that row of X^0 is zero, so no gradient reaches the weights that
+    multiply it, on either side: ``w[:, :, j]`` of every layer and
+    ``w1[:, j, :]`` of the first (whose maps ARE the fields)."""
+    weights, tower = _cin_case(empty=(7,))
+    grads = jax.grad(
+        lambda w: jnp.sum(jnp.sin(blocks.cin_stack(w, tower, 4)))
+    )(weights)
+    for g in grads:
+        assert float(jnp.abs(g).max()) > 0.0
+        np.testing.assert_array_equal(g[:, :, 7], 0.0)
+    np.testing.assert_array_equal(grads[0][:, 7, :], 0.0)
+
+
+def test_cin_slice_is_sized_from_shapes_in_whole_lane_widths():
+    """``blocks.cin_slice_rows``: at the paper's Criteo sizes a slice of 128
+    examples (a 41 MB pair tensor under the 64 MiB the block allows), a
+    small batch whole, a narrow layer more lane widths."""
+    assert blocks.cin_slice_rows(16384, 10, 40, 200) == 128
+    assert 4 * 128 * 200 * 40 * 10 <= blocks.CIN_PAIR_BYTES
+    assert blocks.cin_slice_rows(64, 10, 4, 8) == 64
+    assert blocks.cin_slice_rows(16384, 10, 40, 100) == 384
+    model = make_model(Config(
+        model="xdeepfm", emb_dim=10, max_fields=40, cin_maps=200, cross_layers=3
+    ))
+    assert model.cin_slice_rows(16384) == 128
+    assert model.cin_widths() == [(40, 200), (200, 200), (200, 200)]
+
+
+def test_config_refuses_a_cin_without_maps():
+    with pytest.raises(ValueError, match="cin_maps"):
+        Config(model="xdeepfm", cin_maps=0)
+
+
+def test_xdeepfm_survives_checkpoint_and_artifact_and_serves_the_reference(
+    toy_dataset, tmp_path
+):
+    """utils/checkpoint.py, serve/artifact.py and serve/engine.py carry
+    ``state["dense"]`` as a pytree whatever its arrays' ranks: xDeepFM's
+    three-dimensional ``cin_w`` restore bit for bit from the checkpoint, and
+    the engine loaded from the exported artifact scores a raw batch as the
+    trainer does AND as the benchmark's reference's ``logit`` does from the
+    trained state (through train.py's own entry, ``xflow_tpu.train.main``)."""
+    from xflow_tpu import train
+    from xflow_tpu.io.loader import ShardLoader
+    from xflow_tpu.serve.artifact import export_artifact
+    from xflow_tpu.serve.engine import PredictEngine
+    from xflow_tpu.trainer import Trainer
+
+    fields = dict(
+        train_path=toy_dataset.train_prefix, test_path=toy_dataset.test_prefix,
+        model="xdeepfm", emb_dim=xdeepfm_criteo.EMB_DIM, hidden_dim=8,
+        cross_layers=2, cin_maps=6, deep_layers=2, v_init_scale=0.3,
+        sgd_lr=0.05, epochs=2, batch_size=64, table_size_log2=14, max_nnz=24,
+        max_fields=12, num_devices=1, checkpoint_dir=str(tmp_path / "ck"),
+    )
+    cfg = Config(**fields)
+    with Trainer(cfg) as trainer:
+        drawn = jax.device_get(trainer.state["dense"])
+        trainer.train()
+        before = jax.device_get(trainer.state["dense"])
+        assert before["cin_w2"].shape == (6, 6, 12)
+        for k in (1, 2):  # the CIN trained
+            assert float(np.abs(before[f"cin_w{k}"] - drawn[f"cin_w{k}"]).max()) > 0.0
+        with Trainer(cfg) as again:
+            assert again.restore() is not None
+            jax.tree.map(
+                np.testing.assert_array_equal, before,
+                jax.device_get(again.state["dense"]),
+            )
+        art = str(tmp_path / "artifact")
+        export_artifact(trainer, art)
+        engine = PredictEngine.load(art, buckets=(64,), warm=True)
+        loader = ShardLoader(
+            cfg.test_path + "-00000", batch_size=cfg.batch_size,
+            max_nnz=cfg.max_nnz, table_size=cfg.table_size,
+            parse_fn=trainer._parse_fn(),
+        )
+        batch, _ = next(iter(loader.iter_batches()))
+        want = np.asarray(jax.device_get(trainer.step.predict(
+            trainer.state, trainer.step.put_batch(trainer.prepare_batch(batch))
+        )))
+        got = engine.predict(batch)
+        np.testing.assert_allclose(got, want, atol=1e-6)
+        # the reference's logit over the same rows of the trained tables
+        keys, x, slots = refcheck.entries(trainer.prepare_batch(batch))
+        tables = jax.device_get(trainer.state["tables"])
+        rows = {t: jnp.asarray(tables[t]["param"][keys]) for t in tables}
+        ref = xdeepfm_criteo.logit(
+            rows, jnp.asarray(x), jnp.asarray(slots), cfg.max_fields, before
+        )
+        real = batch.weights > 0
+        np.testing.assert_allclose(
+            got[real], np.asarray(jax.nn.sigmoid(ref))[real], atol=2e-6
+        )
+    # the CLI's own entry reaches the family and its one new field
+    args = train.build_parser().parse_args([
+        "--model", "xdeepfm", "--cin-maps", "6", "--cross-layers", "2",
+        "--train", toy_dataset.train_prefix,
+    ])
+    assert (args.model, args.cin_maps, args.cross_layers) == ("xdeepfm", 6, 2)

@@ -8,6 +8,7 @@ at a time may load the TPU's library, so under several test workers only
 the worker that is given this file describes the chip."""
 
 import contextlib
+import math
 import os
 import re
 import types
@@ -360,6 +361,16 @@ DCN_PLANES = {
     "cw_hxh": ((0,), _U8), "cw_hf": ((262144,), _U8),
     "cw_hc": ((65536,), _U8),
     "cw_cs": ((475136,), _U8), "cw_hs": ((2097152,), _U8),
+}
+XDEEPFM_PLANES = {
+    "cw_cu": ((55296,), _U32), "cw_cun": ((1,), np.int32),
+    "cw_ci": ((118784,), _U16), "cw_ct": ((0,), _U32),
+    "cw_cf": ((14848,), _U8), "cw_cc": ((16384,), _U8),
+    "cw_lb": ((2048,), _U8), "cw_wb": ((2048,), _U8),
+    "cw_h8": ((311296,), _U8), "cw_hx": ((229376,), _U16),
+    "cw_hxh": ((0,), _U8), "cw_hf": ((65536,), _U8),
+    "cw_hc": ((16384,), _U8),
+    "cw_cs": ((118784,), _U8), "cw_hs": ((524288,), _U8),
 }
 
 
@@ -747,6 +758,107 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
     ]
     peak = _program_peak(compiled)
     assert 9.0 * (1 << 30) < peak < 10.5 * (1 << 30), peak
+
+
+def test_xdeepfm_step_contracts_pairs_in_float32_a_slice_at_a_time_on_v5e(
+    topo, no_compile_cache
+):
+    """The xDeepFM train step at the geometry of the benchmark's
+    xdeepfm_tb.train_packed (benchmarks/configs/xdeepfm_ftrl_criteo_tb.json:
+    2^25 rows, w of one column and emb of 10, B=16384, 8 + 32 slots, 40
+    fields, three CIN layers of 200 maps beside two hidden layers of 400,
+    the dictionary wire's plane capacities of one real batch, seed 1; the
+    dense arrays handed in as shapes) for a described v5e.  Lowered: every
+    dot asks for float32 (Precision.HIGHEST), the CIN's contractions with a
+    ``cin_w`` among them: three a layer (forward ``[maps, H m] x [H m, N]``,
+    into the pairs, into the weights) less the one no gradient needs, none
+    of them done twice for the rematerialised backward (the checkpoint
+    keeps the products' outputs and multiplies the pairs again); at default
+    precision the TPU rounds both operands to bfloat16.  A layer's pair
+    tensor is ``B H m D`` = 1.31e9 floats whole (5.24 GB): no array of the
+    lowered or the compiled program has as many elements, or a tenth as
+    many, beside the tables' own; the largest the CIN makes is a slice's
+    (128 examples: ``[200, 40, 1280]``).  Compiled: the CIN's instructions,
+    forward, rematerialised and backward, and the two loops over the
+    slices, carry ``xf.cin`` in ``op_scopes``' reading (the innermost
+    name), the contractions (convolutions, as the TPU's compiler writes a
+    dot) among them and none under ``xf.dense``, which keeps the DNN's; no
+    table-sized copy of emb's state is made; and the program fits with the
+    room the file's ``reduced`` argues from, 8.42 GiB of 15.75."""
+    from xflow_tpu.models import blocks
+    from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
+
+    cfg, step, lowered = _lowered_cell_step(
+        topo, "xdeepfm_ftrl_criteo_tb", XDEEPFM_PLANES
+    )
+    assert step._mxu_hot == {"w": True, "emb": True}
+    assert (cfg.cross_layers, cfg.cin_maps) == (3, 200)
+    assert (cfg.deep_layers, cfg.hidden_dim, cfg.emb_dim) == (2, 400, 10)
+    b, m, d, maps = cfg.batch_size, cfg.max_fields, cfg.emb_dim, cfg.cin_maps
+    s = blocks.cin_slice_rows(b, d, m, maps)
+    n = s * d  # a slice's (column, example) pairs, along the lanes
+    assert (s, n) == (128, 1280)
+    text = lowered.as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
+
+    def dot(lhs: str, rhs: str, out: str) -> int:
+        sig = f"(tensor<{lhs}xf32>, tensor<{rhs}xf32>) -> tensor<{out}xf32>"
+        return sum(sig in line for line in dots)
+
+    for h in (m, maps):  # layer 1; layers 2 and 3 share their types
+        k, layers = h * m, 1 if h == m else 2
+        # forward: w [maps, k] x pairs [k, N]
+        assert dot(f"{maps}x{k}", f"{k}x{n}", f"{maps}x{n}") == layers, (h, dots)
+        # into the weights: dX [maps, N] x pairs [k, N] over N
+        assert dot(f"{maps}x{n}", f"{k}x{n}", f"{maps}x{k}") == layers, (h, dots)
+        # into the pairs: dX [maps, N] x w [maps, k] over the maps
+        assert dot(f"{maps}x{n}", f"{maps}x{k}", f"{n}x{k}") == layers, (h, dots)
+
+    def elements(shape: str) -> int:
+        return math.prod(int(x) for x in re.split("[x,]", shape) if x)
+
+    whole = b * maps * m * d
+    table = cfg.table_size * d
+    made = {
+        shape for shape in re.findall(r"tensor<([0-9x]+)xf32>", text)
+        if elements(shape) != table
+    }
+    assert max(map(elements, made)) < whole // 10, sorted(made, key=elements)[-3:]
+
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    arrays = {
+        shape for shape in re.findall(r"= \(?f32\[([0-9,]+)\]", hlo)
+        if elements(shape) not in (table, cfg.table_size)
+    }
+    assert max(map(elements, arrays)) < whole // 10, sorted(arrays, key=elements)[-3:]
+    assert f"{maps},{m},{n}" in arrays  # a slice's pair tensor
+    in_scope: dict[str, list[str]] = {"xf.cin": [], "xf.dense": []}
+    for line in hlo.splitlines():
+        found = _HLO_OP_NAME_RE.search(line)
+        if found and scope_of(found.group(1)) in in_scope:
+            in_scope[scope_of(found.group(1))].append(line)
+    cin, dense = in_scope["xf.cin"], in_scope["xf.dense"]
+    assert all("xf.forward_backward" in line for line in cin + dense)
+    paths = {_HLO_OP_NAME_RE.search(line).group(1) for line in cin}
+    assert [p for p in paths if "/jvp(xf.cin)/while/body/" in p]
+    assert [p for p in paths if "transpose(jvp(xf.cin))/while/body/closed_call/checkpoint" in p]
+    assert sum(" while(" in line for line in cin) == 2  # forward's, backward's
+    # the CIN's contractions: under xf.cin, each with a slice's lanes or
+    # a layer's weights as its result; the DNN's under xf.dense
+    products = [
+        line.split(" = ")[1].split("{")[0] for line in cin if " convolution(" in line
+    ]
+    assert len(products) >= 8, products
+    assert not [line for line in dense if f"{n}]" in line.split("metadata")[0]]
+    assert sum(" convolution(" in line for line in dense) >= 6
+    assert not [
+        line for line in _table_sized_copies(hlo, cfg.table_size)
+        if f"f32[{cfg.table_size},{d}]" in line
+    ]
+    peak = _program_peak(compiled)
+    assert 8.0 * (1 << 30) < peak < 9.0 * (1 << 30), peak
 
 
 def test_serving_program_takes_one_packed_buffer_on_v5e(topo, no_compile_cache):

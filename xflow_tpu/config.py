@@ -70,6 +70,10 @@ class Config:
     # paper's Criteo optimum: 6 cross layers beside 2 deep layers).
     cross_layers: int = 2
     deep_layers: int = 1
+    # xdeepfm (models/xdeepfm.py): feature maps a CIN layer holds; the
+    # CIN's depth is cross_layers, its DNN deep_layers of hidden_dim (the
+    # paper's Criteo setting: 3 layers of 200 beside 2 layers of 400).
+    cin_maps: int = 16
     # Static padded features-per-sample inside the jit step.  Samples with
     # more features than this are truncated (reference has no limit —
     # features-per-sample is whatever the text line holds).
@@ -507,6 +511,8 @@ class Config:
             raise ValueError("cross_layers must be >= 1")
         if self.deep_layers < 1:
             raise ValueError("deep_layers must be >= 1")
+        if self.cin_maps < 1:
+            raise ValueError("cin_maps must be >= 1")
         if self.optimizer not in ("ftrl", "sgd"):
             raise ValueError(f"unknown optimizer {self.optimizer!r}")
         if self.update_mode not in ("dense", "sparse", "sequential"):

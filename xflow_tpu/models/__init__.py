@@ -36,6 +36,7 @@ from xflow_tpu.models.lr import LRModel
 from xflow_tpu.models.mvm import MVMModel
 from xflow_tpu.models.two_tower import TwoTowerModel
 from xflow_tpu.models.wide_deep import WideDeepModel
+from xflow_tpu.models.xdeepfm import XDeepFMModel
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,6 +146,20 @@ register_model(ModelFamily(
     "deep & cross ranker: explicit bounded-degree feature crosses + "
     "MLP over the embedding tower (the cascade's ranking stage)",
 ))
+register_model(ModelFamily(
+    "xdeepfm",
+    lambda cfg: XDeepFMModel(
+        emb_dim=cfg.emb_dim,
+        cin_maps=cfg.cin_maps,
+        cross_layers=cfg.cross_layers,
+        hidden=cfg.hidden_dim,
+        deep_layers=cfg.deep_layers,
+        max_fields=cfg.max_fields,
+        v_init_scale=cfg.v_init_scale,
+    ),
+    "xDeepFM ranker: a compressed interaction network (vector-wise "
+    "crosses of bounded degree) beside an MLP over the embedding tower",
+))
 
 
 __all__ = [
@@ -160,6 +175,7 @@ __all__ = [
     "WideDeepModel",
     "TwoTowerModel",
     "DCNModel",
+    "XDeepFMModel",
     "make_model",
     "model_family",
     "model_names",

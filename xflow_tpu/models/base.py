@@ -95,6 +95,13 @@ class AutodiffModel:
         as ``dense.matmul_flops`` (parallel/step.py::_book_wire)."""
         return []
 
+    def dense_counters(self, batch: int) -> dict[str, int]:
+        """What else of its dense half a family wants on the ``_wire``
+        row, from shapes at this batch size, by counter name
+        (``dense.<what>``; {} if nothing): the step books each once a
+        batch and the trainer writes it as ``dense_<what>``."""
+        return {}
+
     def logit(
         self,
         rows: dict[str, jax.Array],

@@ -11,9 +11,9 @@ ways:
   copy of the pre-refactor implementation, in dense, MXU-hot, and
   tiered store modes);
 * the retrieval/ranking families this substrate enables
-  (models/two_tower.py, models/dcn.py) compose the same blocks into
-  new architectures instead of re-implementing the recipe a sixth and
-  seventh time;
+  (models/two_tower.py, models/dcn.py, models/xdeepfm.py) compose the
+  same blocks into new architectures instead of re-implementing the
+  recipe a sixth and seventh time;
 * future families register in models/__init__.py and pick blocks off
   this shelf.
 
@@ -31,6 +31,8 @@ quirk parity) can call them inside the fused step.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -327,3 +329,101 @@ def ffm_field_interaction(
     emask = eslot[None, None, :] == slot[:, :, None]  # [B, K, E]
     diag = jnp.sum(jnp.where(emask, vx * vx, 0.0), axis=(1, 2))
     return 0.5 * (cross - diag)
+
+
+# -- compressed interaction network (xDeepFM) --------------------------------
+
+# The device scope of the CIN (docs/OBSERVABILITY.md): a sibling of
+# xf.dense inside xf.forward_backward, read apart from it.
+CIN_SCOPE = "xf.cin"
+
+# The most a slice's pair tensor may take: what ``cin_slice_rows`` sizes
+# the slices of the batch from.  Fitted at ONE shape, the paper's Criteo
+# sizes on a v5e (D = 10, m = 40, 200 maps, B = 16384: 312.5 KiB of pairs
+# an example), where it yields the best slice of five measured, forward and
+# backward of the stack alone, ms by examples a slice
+# (scripts/probe_cin_slice.py, PR 43): 64: 156.0, 128: 152.8, 256: 163.0,
+# 512: 175.4, 1024: 175.8.  The byte rule between those points, and at any
+# other width, is a guess that keeps a slice's pairs about that size; a
+# family at other sizes runs the probe at its own.
+CIN_PAIR_BYTES = 64 << 20
+# A slice's examples lie along the lanes of every array of the block, so
+# a slice of part of the batch holds a multiple of the lane width.
+_LANES = 128
+
+
+def cin_slice_rows(batch: int, dim: int, max_fields: int, maps: int) -> int:
+    """How many examples a slice of ``cin_stack`` holds, from shapes: as
+    many as keep the widest layer's pair tensor (``max(maps, max_fields) *
+    max_fields * dim`` floats an example) inside ``CIN_PAIR_BYTES``, in
+    whole lane widths; the whole batch where that is fewer.  THE place
+    the slice is decided: the model hands it to ``cin_stack`` and the
+    step books it (``dense.cin_slice_rows``)."""
+    per_example = 4 * max(maps, max_fields) * max_fields * dim
+    rows = CIN_PAIR_BYTES // per_example // _LANES * _LANES
+    return min(batch, max(rows, _LANES))
+
+
+def cin_layer(w: jax.Array, xk: jax.Array, x0: jax.Array) -> jax.Array:
+    """One CIN layer over a slice: ``w [H', H, m]``, ``xk [H, N]`` the
+    previous layer's maps and ``x0 [m, N]`` the field tower, both with
+    the slice's (embedding column, example) pairs along the last axis ->
+    ``[H', N]``:
+
+        out[h', n] = sum_{i, j} w[h', i, j] * xk[i, n] * x0[j, n]
+
+    The pair tensor ``xk[i, n] * x0[j, n]`` is a float32 multiply and is
+    contracted at once, in float32 on every backend (Precision.HIGHEST,
+    as ``dense_dot``: neither operand is exact in bfloat16).  With N
+    along the lanes ``[H, m, N] -> [H * m, N]`` moves nothing (m is a
+    multiple of 8 at the benchmark's 40 fields)."""
+    h, n = xk.shape
+    pairs = (xk[:, None, :] * x0[None, :, :]).reshape(h * x0.shape[0], n)
+    return jnp.matmul(
+        w.reshape(w.shape[0], -1), pairs, precision=jax.lax.Precision.HIGHEST
+    )
+
+
+@jax.named_scope(CIN_SCOPE)
+def cin_stack(
+    weights: list[jax.Array], tower: jax.Array, slice_rows: int
+) -> jax.Array:
+    """xDeepFM's Compressed Interaction Network over the field tower
+    ``[B, m, D]`` -> the sum-pooled maps of every layer ``[B, sum H_k]``
+    (the paper's equations 6-8 with the identity activation):
+
+        X^k[h, :] = sum_{i, j} W^k[h, i, j] (X^{k-1}[i, :] * X^0[j, :])
+        p^k[h]    = sum_d X^k[h, d]
+
+    with ``weights[k - 1] = W^k [H_k, H_{k-1}, m]`` and ``H_0 = m``.  A
+    layer's pair tensor is ``H_{k-1} * m * D`` floats an example (80 000
+    at the paper's sizes: 5.24 GB a layer at B = 16384), so it exists
+    for ``slice_rows`` examples at a time, forward and backward: the
+    batch goes through ``lax.map`` slice by slice (zero rows pad the
+    last, and pool to zeros that are cut off), and each slice's backward
+    keeps the layers' maps (the products' outputs, ``H_k * D`` floats an
+    example) and multiplies its pairs again (``jax.checkpoint``: a
+    multiply, no product is done twice).  Inside a slice the (column,
+    example) pairs lie along the lanes, column-major, so pooling over D
+    adds D aligned blocks of lanes."""
+    b, m, d = tower.shape
+    s = slice_rows
+    slices = -(-b // s)
+    x0 = jnp.pad(tower, ((0, slices * s - b), (0, 0), (0, 0)))
+    # [slices, m, D * s]: slice c, field j, lane d * s + e <- example c*s + e
+    x0 = x0.reshape(slices, s, m, d).transpose(0, 2, 3, 1).reshape(slices, m, d * s)
+
+    @functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        prevent_cse=False,  # lax.map's loop already keeps the two apart
+    )
+    def one_slice(x0c: jax.Array) -> jax.Array:
+        xk, pooled = x0c, []
+        for w in weights:
+            xk = cin_layer(w, xk, x0c)
+            pooled.append(jnp.sum(xk.reshape(-1, d, s), axis=1))  # [H_k, s]
+        return jnp.concatenate(pooled, axis=0)
+
+    p = jax.lax.map(one_slice, x0)  # [slices, sum H_k, s]
+    return p.transpose(0, 2, 1).reshape(slices * s, -1)[:b]

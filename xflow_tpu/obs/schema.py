@@ -423,6 +423,11 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # (Model.dense_matmuls; parallel/step.py::_book_wire)
         "dense_param_bytes": (int, float),
         "dense_matmul_flops_per_step": (int, float),
+        # a family with a CIN only (Model.dense_counters of
+        # models/xdeepfm.py), from shapes: the examples a slice of the
+        # block holds the pair tensor for (blocks.cin_slice_rows); B over
+        # it is the trips of the CIN's loops a step
+        "dense_cin_slice_rows": (int, float),
         # of wire_bytes_per_example, the planes of field ids (slots_u8 /
         # hot_slots_u8, the dictionary wire's cw_cs / cw_hs, the full
         # wire's slots / hot_slots): 0 where none ships, as for a model

@@ -80,10 +80,13 @@ def scope_of(op_name: str) -> str:
     INNERMOST one, the last ``xf.<name>`` anywhere in the path (autodiff
     and scan wrap path components: ``transpose(jvp(xf.dense))``), ``""``
     where the path has none.  A scope opened inside another is the more
-    specific name for its operations: the dense half ``xf.dense``
-    (models/blocks.py) runs inside ``xf.forward_backward`` and is read
-    apart from it.  Until PR 39 the first name won; no program without a
-    dense half nests two different scopes, so theirs map as they did
+    specific name for its operations: the dense half ``xf.dense`` and
+    the CIN ``xf.cin`` (models/blocks.py) run inside
+    ``xf.forward_backward`` and are read apart from it, the CIN's also
+    through its loop and its rematerialised backward
+    (``transpose(jvp(xf.cin))/while/body/closed_call/checkpoint/...``).
+    Until PR 39 the first name won; no program without a dense half
+    nests two different scopes, so theirs map as they did
     (tests/test_tpu_compile.py holds the benchmark's to that)."""
     found = _SCOPE_RE.findall(op_name)
     return found[-1] if found else ""
@@ -792,6 +795,11 @@ class TrainStep:
         self._dense_matmul_flops = 6 * cfg.batch_size * sum(
             k * n for k, n in (model.dense_matmuls() if owns_dense else [])
         )
+        # what else the family wants booked of its dense half, from
+        # shapes (Model.dense_counters: xDeepFM's slice of the CIN)
+        self._dense_counters: dict[str, int] = (
+            model.dense_counters(cfg.batch_size) if owns_dense else {}
+        )
         # Hierarchical parameter store (Config.store_mode; store/):
         # under 'tiered' the table state is the store's hot tier + host
         # cold rows, the wire is the store's refs/miss format (the
@@ -909,10 +917,16 @@ class TrainStep:
         shapes, ``dense.param_bytes`` (the bytes of its dense arrays)
         and ``dense.matmul_flops`` (6 B k n for every [B, k] x [k, n]
         product with one of them, Model.dense_matmuls: forward and the
-        two backward products); a family without books neither."""
+        two backward products); a family without books neither.  What
+        else a family hands the step (Model.dense_counters) is booked
+        beside them under the family's own names: xDeepFM's
+        ``dense.cin_slice_rows``, the examples a slice of its CIN holds
+        the pair tensor for."""
         if self._dense_param_bytes:
             self.obs.counter("dense.param_bytes", self._dense_param_bytes)
             self.obs.counter("dense.matmul_flops", self._dense_matmul_flops)
+        for name, value in self._dense_counters.items():
+            self.obs.counter(name, value)
         self.obs.counter("wire.bytes", nbytes)
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")

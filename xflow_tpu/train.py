@@ -62,11 +62,15 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--cross-layers", type=int, dest="cross_layers",
-        help="dcn: explicit cross-network depth",
+        help="dcn: explicit cross-network depth; xdeepfm: CIN depth",
     )
     p.add_argument(
         "--deep-layers", type=int, dest="deep_layers",
-        help="dcn: ReLU layers of --hidden-dim in the deep half",
+        help="dcn, xdeepfm: ReLU layers of --hidden-dim in the deep half",
+    )
+    p.add_argument(
+        "--cin-maps", type=int, dest="cin_maps",
+        help="xdeepfm: feature maps a CIN layer holds",
     )
     p.add_argument("--max-nnz", type=int, dest="max_nnz")
     p.add_argument("--max-fields", type=int, dest="max_fields")

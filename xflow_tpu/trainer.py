@@ -1118,6 +1118,15 @@ class Trainer:
                 stats["_wire"]["dense_matmul_flops_per_step"] = round(
                     snap.counters["dense.matmul_flops"] / batches
                 )
+            # what else a family books of its dense half, a batch and
+            # from shapes (Model.dense_counters), under its own names
+            for name in sorted(snap.counters):
+                if name.startswith("dense.") and name not in (
+                    "dense.param_bytes", "dense.matmul_flops"
+                ):
+                    stats["_wire"][name.replace(".", "_")] = round(
+                        snap.counters[name] / batches
+                    )
             if "exchange.bytes" in snap.counters:
                 # a mesh of more than one device: what the step's pull
                 # and push moved between the chips, from shapes
