@@ -39,15 +39,22 @@ def test_factors_rejects_non_pow2():
         hot_factors(1000)
 
 
-@pytest.mark.parametrize("h,d,m", [(256, 1, 1000), (1024, 10, 4097), (4096, 1, 300)])
+# the last two: the heads of the benchmark's mvm_tb / dcn_tb cells (hot
+# 2^14 at D = 10 and 26), M not a multiple of the scan's chunk
+@pytest.mark.parametrize("h,d,m", [
+    (256, 1, 1000), (1024, 10, 4097), (4096, 1, 300),
+    (16384, 10, 5000), (16384, 26, 3000),
+])
 def test_gather_matches_dma(h, d, m):
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.normal(size=(h, d)).astype(np.float32))
-    # include out-of-range sentinel keys (the padding convention)
-    keys = rng.integers(0, h + h // 4, size=m).astype(np.int32)
-    got = hot_gather(w, jnp.asarray(keys))
-    want = dma_gather(w, jnp.asarray(keys))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=0)
+    # include keys outside [0, H) on both sides (the padding convention:
+    # the sentinel is H; a negative key selects nothing either)
+    keys = rng.integers(-(h // 8), h + h // 4, size=m).astype(np.int32)
+    got = np.asarray(jax.jit(hot_gather)(w, jnp.asarray(keys)))
+    want = np.asarray(dma_gather(w, jnp.asarray(keys)))
+    assert got.shape == (m, d) and (keys < 0).any() and (keys >= h).any()
+    assert (got == want).all()  # a selection: bit for bit, not approximately
 
 
 @pytest.mark.parametrize("h,d,m", [(256, 1, 1000), (1024, 10, 4097), (4096, 1, 300)])

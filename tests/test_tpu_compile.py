@@ -208,6 +208,51 @@ def test_dict_cold_rows_compile_for_v5e_at_flagship(
     )
 
 
+@pytest.mark.parametrize("d,m", [(10, 4194304), (26, 2097152)])
+def test_hot_gather_views_its_product_by_a_bitcast_on_v5e(
+    one_chip, no_compile_cache, d, m
+):
+    """ops/hot.py::hot_gather alone at the heads of the benchmark's
+    mvm_tb.train_packed and dcn_tb.train_packed (H = 2^14; D = 10 over
+    4 194 304 slots a step, D = 26 over 2 097 152) for a described v5e.
+    The head is flattened [h1, D * h2] for both directions (PR 44), so
+    the one-hot product [C, D * h2] IS [C, D, h2] in the device's tiles
+    (h2 = 128 fills whole tiles of 8 sublanes; the chunk's slots lie on
+    the lanes): the view is a bitcast.  Flattened [h1, h2 * D], as the
+    gather had it until PR 44, the view was a real ``reshape
+    f32[C,128,D]``, a shuffle of the product in each of the scan's 4 096
+    chunks (8.0 ms of MVM's 271.7 ms step, 9.1 of DCN's 322.0; PERF.md
+    section 6).  And the product still asks for float32
+    (Precision.HIGHEST: the default rounds the table's rows to bfloat16
+    on the way into the MXU)."""
+    from xflow_tpu.ops import hot
+
+    h = 16384
+    h1, h2 = hot.hot_factors(h)
+    c = hot._chunk(h1, h2, d, m)
+    w = jax.ShapeDtypeStruct((h, d), jnp.float32, sharding=one_chip)
+    keys = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    lines = [
+        line.split(", metadata")[0].strip() for line in
+        jax.jit(hot.hot_gather).lower(w, keys).compile().as_text().splitlines()
+    ]
+    views = (f"f32[{c},{h2},{d}]", f"f32[{c},{d},{h2}]")
+    of_view = [
+        line for line in lines
+        if " = " in line and line.split(" = ", 1)[1].startswith(views)
+    ]
+    moved = [line for line in of_view if re.search(r" (reshape|copy)\(", line)]
+    assert not moved, moved
+    assert any(" bitcast(" in line for line in of_view), of_view
+    products = [
+        line for line in lines
+        if " convolution(" in line and f"= f32[{c},{d * h2}]" in line
+    ]
+    assert products and all(
+        "operand_precision={highest,highest}" in line for line in products
+    ), products
+
+
 def _optimizer_passes(text: str, elements: int) -> list[tuple[str, str]]:
     """(results, body) of every fusion of a compiled program that the
     source booked to xf.optimizer and that yields float32 arrays of
@@ -613,19 +658,20 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
 # sha256 of the lowered train program (StableHLO text, the Mosaic kernels'
 # serialized bodies blanked: they embed the checkout's path) of the four
 # configurations the benchmark measured before PR 39, pinned on PR 38's
-# tree BEFORE models/blocks.py was edited.
+# tree BEFORE models/blocks.py was edited and anew by PR 44 (the test's
+# docstring says why).
 MEASURED_PROGRAMS_SHA256 = {
     "lr_ftrl_criteo_tb": (
-        "658a8546e3166cba2379607b6546d8d0f9595d0614d603c9c623a54a8dc72ec8"
+        "e288dfde6bd0a7646d26153ef9b2ad0ba6d6a5056fe6a02cc9440b498b84d5bc"
     ),
     "mvm_ftrl_criteo_tb": (
-        "4ebc67a21cce24b7ed47f5b2fd0469fcb02f7b3f9f1b099e2c3af48952f59c3a"
+        "f75e0ff38053329174a77f6e8c06637b8a09819fa9e367114323c13a4dd13f2f"
     ),
     "ffm_ftrl_criteo_tb": (
-        "d868304a65ffa819584c4f38852da8e095e35a6c93ec14c69864a4449d2736f6"
+        "c40a3f4e9f8eb87e924e7c25a3a14818b91f19dddf1bc6414dc08d2214084d54"
     ),
     "fm_ftrl_criteo_tb (cut, 2x2)": (
-        "ade0cf7f34c32ee9e7a4f4c58f6c3fc54563d3563a5b1e0f3fadabf657d6c219"
+        "c21a3d5d0035f8bcf6f70d3ac856055a1f5ca43b1c6be9ab391d17f30d18b6a3"
     ),
 }
 
@@ -647,7 +693,11 @@ def test_measured_train_programs_lower_to_the_pinned_text(topo):
     the MLP blocks and open no scope inside another, so their train
     programs lower to the text PR 38's tree lowered them to.  A PR that
     means to change one of these programs pins the new digest here and
-    says so; one that does not has found what it changed by accident."""
+    says so; one that does not has found what it changed by accident.
+    PR 44 meant to change all four (every one has a head: the gather
+    scan of ops/hot.py flattens it as [h1, D * h2] and emits a chunk as
+    [D, C]; PERF.md section 6) and pinned the digests anew; PR 39 to 43
+    had left them as they were."""
     got = {
         "lr_ftrl_criteo_tb": _lowered_cell_step(
             topo, "lr_ftrl_criteo_tb", LR_PLANES, ships_slots=False

@@ -16,7 +16,26 @@ Traffic is M*(h1 + h2*D) one-hot elements instead of M DMA descriptors
 (docs/PERF.md "The win" has the v5e rates of the rounds that built it).
 
 One-hot intermediates are built in chunks under ``lax.scan`` so the
-[C, h2*D] temporaries stay within a few MiB regardless of M or D.
+[C, D*h2] temporaries stay within a few MiB regardless of M or D.
+
+ONE flattened order for both directions: the level-2 axis is
+``[h1, D * h2]``, column ``d * h2 + lo``.  The gather flattens the head
+that way once, outside its scan; the scatter accumulates in it and
+reorders once, after its scan.  Why: the TPU computes the product
+``[C, D * h2]`` with the chunk's C slots on the lanes and the columns on
+the sublanes, in tiles of 8.  At the benchmark's heads h2 = 128 is
+sixteen whole tiles, so ``[C, D * h2] -> [C, D, h2]`` moves nothing (the
+compiler makes it a bitcast), whereas with D on the minor side
+(``[C, h2 * D] -> [C, h2, D]``) rows of D = 10 or 26 straddle the tiles
+and the view is a shuffle of the whole product in every chunk: ``reshape
+f32[1024,128,10]`` was 8.0 ms of mvm_tb.train_packed's 271.7 ms step and
+``f32[512,128,26]`` 9.1 of dcn_tb.train_packed's 322.0 (ledger, PR 43).
+For the same reason a chunk of the gather leaves the scan as ``[D, C]``:
+one contiguous piece of the stacked ``[M/C, D, C]``, transposed once
+after the loop; a ``[C, D]`` chunk goes into D planes of the stacked
+result 16 MiB apart, piece by piece (10.6 and 11.7 ms of those steps).
+At D = 1 the two orders are the same array.  tests/test_tpu_compile.py
+holds the bitcast.
 
 Numerics: the gather is *exact* (each one-hot row selects a single W
 element; no accumulation), and the scatter differs from ``.at[].add``
@@ -62,7 +81,7 @@ _PRECISION = jax.lax.Precision.HIGHEST
 
 
 def _chunk(h1: int, h2: int, d: int, m: int) -> int:
-    """Rows per scan chunk: bound the [C, max(h1, h2*D)] temporaries to
+    """Rows per scan chunk: bound the [C, max(h1, D*h2)] temporaries to
     ~2^21 f32 elements (8 MiB), and never pad a small M (e.g. an online-
     inference batch) up to a huge chunk."""
     width = max(h1, h2 * d)
@@ -111,7 +130,8 @@ def hot_gather(
     c = _chunk(h1, h2, d, m)
     m_pad = ((m + c - 1) // c) * c
     kp = _pad_to(keys, m_pad, h)  # sentinel: all-zero one-hot
-    wr = w_hot.reshape(h1, h2 * d)
+    # [h1, (d, h2)]: the module's one flattened order (docstring)
+    wr = w_hot.reshape(h1, h2, d).transpose(0, 2, 1).reshape(h1, d * h2)
     ar1 = jnp.arange(h1, dtype=kp.dtype)
     ar2 = jnp.arange(h2, dtype=kp.dtype)
 
@@ -122,14 +142,13 @@ def hot_gather(
         rows = jnp.dot(
             oh_hi, wr, precision=_PRECISION,
             preferred_element_type=jnp.float32,
-        ).reshape(c, h2, d)
+        ).reshape(c, d, h2)
         oh_lo = (lo[:, None] == ar2[None, :]).astype(jnp.float32)  # [C, h2]
-        return None, jnp.einsum(
-            "chd,ch->cd", rows, oh_lo, precision=_PRECISION
-        )
+        # one selected element plus exact zeros: no rounding
+        return None, (rows * oh_lo[:, None, :]).sum(-1).T  # [D, C]
 
-    _, out = jax.lax.scan(body, None, kp.reshape(-1, c))
-    return out.reshape(m_pad, d)[:m]
+    _, out = jax.lax.scan(body, None, kp.reshape(-1, c))  # [M/C, D, C]
+    return out.transpose(0, 2, 1).reshape(m_pad, d)[:m]
 
 
 @jax.named_scope("xf.scatter")
@@ -187,6 +206,6 @@ def hot_scatter(
     acc, _ = jax.lax.scan(
         body, acc0, (kp.reshape(-1, c), gp.reshape(-1, c, d))
     )
-    # glo flattened [C, d, h2] -> acc is [h1, (d, h2)]; reorder to
+    # acc is [h1, (d, h2)], the module's one flattened order; reorder to
     # [h1, h2, d] so row hi*h2+lo lands at table row `key`.
     return acc.reshape(h1, d, h2).transpose(0, 2, 1).reshape(h1 * h2, d)
