@@ -355,7 +355,7 @@ def phase_train(
         wire and all(r["format"] == "dict" for r in wire),
         f"wire rows: {wire}",
     )
-    want_impl = "mxu" if device["platform"] == "tpu" else "seg"
+    want_impl = "auto" if device["platform"] == "tpu" else "seg"
     check(
         probe.step._hot_impl == want_impl,
         f"hot_impl {probe.step._hot_impl!r}, expected {want_impl!r}",
@@ -475,16 +475,19 @@ def phase_parity(geom: dict, rehearsal: bool, out: dict) -> None:
         keys = rng.integers(0, h, m).astype(np.int32)
         keys[::97] = h + 5  # out-of-range: zero row, nothing scattered
         g = rng.normal(0, 1, (m, d)).astype(np.float32)
-        got = np.asarray(jax.jit(
-            lambda w, k: hot_gather(w, k, impl="mxu")
-        )(w, keys))
         ref = np.where((keys < h)[:, None], w[np.clip(keys, 0, h - 1)], 0)
         ref = ref.astype(np.float32)
-        check(
-            bool((got.view(np.uint32) == ref.view(np.uint32)).all()),
-            f"hot_gather(mxu, float32) D={d} is not bitwise w_hot[keys]: "
-            f"max abs err {np.abs(got - ref).max()}",
-        )
+        # the scan, and what the step runs at this width ("auto": the
+        # scan at D=1, the slice indexed a piece at a time at D=10)
+        for impl in ("mxu", "auto"):
+            got = np.asarray(jax.jit(
+                lambda w, k, impl=impl: hot_gather(w, k, impl=impl)
+            )(w, keys))
+            check(
+                bool((got.view(np.uint32) == ref.view(np.uint32)).all()),
+                f"hot_gather({impl}, float32) D={d} is not bitwise "
+                f"w_hot[keys]: max abs err {np.abs(got - ref).max()}",
+            )
         mxu = np.asarray(jax.jit(
             lambda k, g: hot_scatter(k, g, h, impl="mxu")
         )(keys, g))

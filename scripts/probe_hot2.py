@@ -2,29 +2,42 @@
 ``hot_scatter``, alone) on the chip against the plain gather and
 scatter-add of the same slots, by default at the heads of
 ``mvm_tb.train_packed`` (H = 16384, D = 10, 4 194 304 slots a step) and
-``dcn_tb.train_packed`` (D = 26, 2 097 152): whether the MXU head still
-wins at D = 26, and by how much at 10 (ROADMAP A11; PERF.md section 6).
+``dcn_tb.train_packed`` (D = 26, 2 097 152), and the two gathers alone
+over a sweep of widths at MVM's slots: where the scan's price (0.7 ns a
+slot and column) crosses the plain row's (2-2.6 ns a slot in pieces, 4.2
+gathered whole) is where ``hot.PLAIN_GATHER_MIN_COLUMNS`` belongs
+(ROADMAP A11; PERF.md section 6).
 
-    chiprun -- python scripts/probe_hot2.py [--shapes 16384,10,4194304;16384,26,2097152]
-        [--old HOT.py]
+    chiprun -- python scripts/probe_hot2.py [--shapes "H,D,slots[,g];..."]
+        [--old HOT.py] [--seed N]
 
-Keys are drawn twice: zipf-1.2 ranks as the cells' rows draw them (a
-fifth of the slots on the first row: the scatter-add's worst case), and
-uniform over the head; either way a key beyond the head is the sentinel
-H (a padded slot: about a seventh).  ``--old`` times another tree's
-``ops/hot.py`` (a copy of the file) beside this one's, AFTER it.  Prints
-one JSON object (ms a call and ns a slot by shape, keys and form) and
-writes it to ``chiprun_out/hot_probe.json``.  Exit 1 without a TPU (a CPU
-run times nothing worth writing down), or where a head's gather is not
-bit for bit the plain one, or its scatter further than 1e-4 of the
-largest sum from the sums in float64 (the plain scatter-add's own
-distance is printed beside it: 10^6 float32 adds into one row)."""
+A shape that ends in ``,g`` times the gathers alone, on zipf keys.  Every
+other shape's keys are drawn three ways: ``zipf``, zipf-1.2 ranks over
+the whole head (a fifth of the slots on the first row, ~800 000 adds
+into it: the scatter-add's worst case); ``uniform`` over the head;
+either way a key beyond the head is the sentinel H (a padded slot: about
+a seventh); and, where the shape is a benchmark cell's head, ``cell``:
+the hot plane of that cell's first batch as the benchmark's generator
+and remap make it (``probe_cold_gather.cell_batch``: zipf-1.2 ranks PER
+FIELD, so a row takes at most one add an example, B and not 10^6), a
+masked slot the sentinel, as the step's scatter sees it.  Under ``zipf``
+the plain gather is also timed by the slots it reads at a time
+(``hot._PLAIN_GATHER_SLOTS``).  Under ``cell``
+the plain scatter-add's and the scan's distance from the float64 sums is
+the number that says whether the head's scatter at D = 26 can go the
+plain way under the benchmark's ``ROWS_RTOL`` 1e-6.  ``--old`` times
+another tree's ``ops/hot.py`` (a copy of the file) beside this one's,
+AFTER it.  Prints one JSON object (ms a call and ns a slot by shape,
+keys and form) and writes it to ``chiprun_out/hot_probe.json``.  Exit 1
+without a TPU (a CPU run times nothing worth writing down), or where a
+head's gather is not bit for bit the plain one, or its scatter further
+than 1e-4 of the largest sum from the sums in float64 (the plain
+scatter-add's own distance is printed beside it)."""
 
 from __future__ import annotations
 
 import argparse
 import importlib.util
-import itertools
 import json
 import os
 import sys
@@ -38,7 +51,32 @@ import numpy as np
 
 from xflow_tpu.ops import hot
 
-SHAPES = "16384,10,4194304;16384,26,2097152"  # H, D, slots: MVM's and DCN's heads
+# H, D, slots: MVM's and DCN's heads, then the gathers alone over D at
+# MVM's slots
+SHAPES = "16384,10,4194304;16384,26,2097152;" + ";".join(
+    f"16384,{d},4194304,g" for d in (1, 2, 4, 8, 16, 32)
+)
+# the benchmark configurations whose head a shape is
+CELLS = {
+    (16384, 10, 4194304): "mvm_ftrl_criteo_tb",
+    (16384, 26, 2097152): "dcn_ftrl_criteo_tb",
+}
+
+
+def _keys(dist: str, h: int, m: int, rng, seed: int, config: str | None):
+    """int32 [m] keys of one draw (module docstring); the sentinel is H."""
+    if dist == "cell":
+        from probe_cold_gather import cell_batch
+
+        batch = cell_batch(seed, config)[1].expand()
+        keys = np.where(batch.hot_mask > 0, batch.hot_keys, h).reshape(-1)
+        assert keys.shape == (m,), (keys.shape, m)
+        return keys.astype(np.int32)
+    draws = (
+        rng.zipf(1.2, size=m) - 1 if dist == "zipf"
+        else rng.integers(0, h + h // 6, size=m)
+    )
+    return np.where(draws < h, draws, h).astype(np.int32)
 
 
 def _ms(fn, *args, steps: int) -> tuple[float, jax.Array]:
@@ -50,11 +88,65 @@ def _ms(fn, *args, steps: int) -> tuple[float, jax.Array]:
     return (time.perf_counter() - start) / steps * 1e3, out
 
 
+def _by_piece(w, keys, rows, steps: int) -> dict:
+    """ms a call of the plain gather by the slots it reads at a time
+    (``hot._PLAIN_GATHER_SLOTS``; the last is all of them, no loop: a
+    [slots, D] result of 512 B a slot)."""
+    shipped = hot._PLAIN_GATHER_SLOTS
+    out = {}
+    try:
+        for piece in (4096, 16384, 65536, 262144, keys.shape[0]):
+            hot._PLAIN_GATHER_SLOTS = piece
+            ms, got = _ms(
+                jax.jit(lambda w, k: hot.hot_gather(w, k, impl="seg")),
+                w, keys, steps=steps,
+            )
+            assert bool(jnp.array_equal(got, rows)), piece
+            out[str(piece)] = ms
+    finally:
+        hot._PLAIN_GATHER_SLOTS = shipped
+    return out
+
+
+def _scatters(cell, forms, keys_np, rng, h: int, d: int, steps: int) -> bool:
+    """Adds to ``cell`` the plain scatter-add's and every form's scatter
+    scan's ms a call and distance from the float64 sums (of the largest
+    sum), and the most adds one row takes; whether every scan is within
+    1e-4."""
+    m = len(keys_np)
+    grads_np = rng.normal(size=(m, d)).astype(np.float32)
+    exact = np.stack([  # the sums in float64, on the host
+        np.bincount(keys_np, weights=grads_np[:, j], minlength=h + 1)[:h]
+        for j in range(d)
+    ], axis=1)
+    keys, grads = jnp.asarray(keys_np), jnp.asarray(grads_np)
+
+    def off_exact(sums):
+        return float(np.max(np.abs(np.asarray(sums) - exact)) / np.max(np.abs(exact)))
+
+    plain_scatter = jax.jit(
+        lambda k, g: jnp.zeros((h, d), jnp.float32).at[k].add(g, mode="drop")
+    )
+    s_ms, sums = _ms(plain_scatter, keys, grads, steps=steps)
+    cell["plain"].update(scatter_ms=s_ms, scatter_off_float64=off_exact(sums))
+    cell["plain"]["most_adds_a_row"] = int(np.bincount(keys_np, minlength=h)[:h].max())
+    ok = True
+    for name, mod in forms.items():
+        s_ms, got_sums = _ms(
+            jax.jit(lambda k, g, mod=mod: mod.hot_scatter(k, g, h)),
+            keys, grads, steps=steps,
+        )
+        cell[name].update(scatter_ms=s_ms, scatter_off_float64=off_exact(got_sums))
+        ok &= cell[name]["scatter_off_float64"] <= 1e-4
+    return ok
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--shapes", default=SHAPES)
     ap.add_argument("--old", help="another tree's ops/hot.py, timed after this one's")
     ap.add_argument("--steps", type=int, default=5)
+    ap.add_argument("--seed", type=int, default=1, help="of the cells' batches")
     args = ap.parse_args(argv)
     if jax.devices()[0].platform != "tpu":
         print("no TPU: nothing to time", file=sys.stderr)
@@ -66,52 +158,39 @@ def main(argv: list[str] | None = None) -> int:
         spec.loader.exec_module(forms["old_mxu"])
     out: dict = {"device": jax.devices()[0].device_kind}
     ok = True
-    for shape, dist in itertools.product(args.shapes.split(";"), ("zipf", "uniform")):
-        h, d, m = map(int, shape.split(","))
-        rng = np.random.default_rng(0)
-        draws = (
-            rng.zipf(1.2, size=m) - 1 if dist == "zipf"
-            else rng.integers(0, h + h // 6, size=m)
-        )
-        keys_np = np.where(draws < h, draws, h).astype(np.int32)
-        grads_np = rng.normal(size=(m, d)).astype(np.float32)
-        exact = np.stack([  # the sums in float64, on the host
-            np.bincount(keys_np, weights=grads_np[:, j], minlength=h + 1)[:h]
-            for j in range(d)
-        ], axis=1)
-        keys, grads = jnp.asarray(keys_np), jnp.asarray(grads_np)
-        w = jnp.asarray(rng.normal(size=(h, d)).astype(np.float32))
-
-        def off_exact(sums):
-            return float(np.max(np.abs(np.asarray(sums) - exact)) / np.max(np.abs(exact)))
-
-        plain_gather = jax.jit(lambda w, k: hot.hot_gather(w, k, impl="seg"))
-        plain_scatter = jax.jit(
-            lambda k, g: jnp.zeros((h, d), jnp.float32).at[k].add(g, mode="drop")
-        )
-        g_ms, rows = _ms(plain_gather, w, keys, steps=args.steps)
-        s_ms, sums = _ms(plain_scatter, keys, grads, steps=args.steps)
-        cell = {"plain": {
-            "gather_ms": g_ms, "scatter_ms": s_ms,
-            "scatter_off_float64": off_exact(sums),
-        }}
-        for name, mod in forms.items():
-            g_ms, got_rows = _ms(jax.jit(mod.hot_gather), w, keys, steps=args.steps)
-            s_ms, got_sums = _ms(
-                jax.jit(lambda k, g, mod=mod: mod.hot_scatter(k, g, h)),
-                keys, grads, steps=args.steps,
-            )
-            cell[name] = {
-                "gather_ms": g_ms, "scatter_ms": s_ms,
-                "gather_bit_equal": bool(jnp.array_equal(got_rows, rows)),
-                "scatter_off_float64": off_exact(got_sums),
-            }
-            ok &= cell[name]["gather_bit_equal"]
-            ok &= cell[name]["scatter_off_float64"] <= 1e-4
-        for form in cell.values():
-            form["gather_ns_per_slot"] = form["gather_ms"] * 1e6 / m
-            form["scatter_ns_per_slot"] = form["scatter_ms"] * 1e6 / m
-        out[f"H{h}_D{d}_M{m}_{dist}"] = cell
+    for shape in args.shapes.split(";"):
+        h, d, m, *only = shape.split(",")
+        h, d, m = int(h), int(d), int(m)
+        config = CELLS.get((h, d, m))
+        dists = ("zipf",) if only else ("zipf", "uniform") + ("cell",) * bool(config)
+        for dist in dists:
+            rng = np.random.default_rng(0)
+            keys_np = _keys(dist, h, m, rng, args.seed, config)
+            keys = jnp.asarray(keys_np)
+            w = jnp.asarray(rng.normal(size=(h, d)).astype(np.float32))
+            plain_gather = jax.jit(lambda w, k: hot.hot_gather(w, k, impl="seg"))
+            g_ms, rows = _ms(plain_gather, w, keys, steps=args.steps)
+            cell = {"plain": {"gather_ms": g_ms}}
+            for name, mod in forms.items():
+                g_ms, got_rows = _ms(
+                    jax.jit(lambda w, k, mod=mod: mod.hot_gather(w, k, impl="mxu")),
+                    w, keys, steps=args.steps,
+                )
+                cell[name] = {
+                    "gather_ms": g_ms,
+                    "gather_bit_equal": bool(jnp.array_equal(got_rows, rows)),
+                }
+                ok &= cell[name]["gather_bit_equal"]
+            if not only:
+                ok &= _scatters(cell, forms, keys_np, rng, h, d, args.steps)
+            if not only and dist == "zipf":
+                cell["plain"]["gather_ms_by_piece"] = _by_piece(
+                    w, keys, rows, args.steps
+                )
+            for form in cell.values():
+                for key in [k for k in form if k.endswith("_ms")]:
+                    form[key[:-3] + "_ns_per_slot"] = form[key] * 1e6 / m
+            out[f"H{h}_D{d}_M{m}_{dist}"] = cell
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/hot_probe.json", "w") as f:
         json.dump(out, f, indent=1)

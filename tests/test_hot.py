@@ -1,4 +1,6 @@
-"""Unit tests for the two-level one-hot MXU hot-table path (ops/hot.py).
+"""Unit tests for the hot-table head (ops/hot.py): the two-level one-hot
+MXU scans, and the plain indexing of the head's slice that the gather
+takes from hot.PLAIN_GATHER_MIN_COLUMNS columns up.
 
 Correctness spec: hot_gather(W, k) == W[k] (zero row for k outside
 [0, H)) and hot_scatter(k, g, H) == zeros([H, D]).at[k].add(g) (dropping
@@ -11,7 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from xflow_tpu.ops.hot import hot_factors, hot_gather, hot_scatter
+from xflow_tpu.ops.hot import (
+    PLAIN_GATHER_MIN_COLUMNS,
+    gather_form,
+    hot_factors,
+    hot_gather,
+    hot_scatter,
+)
 
 
 def dma_gather(w, keys):
@@ -55,6 +63,73 @@ def test_gather_matches_dma(h, d, m):
     want = np.asarray(dma_gather(w, jnp.asarray(keys)))
     assert got.shape == (m, d) and (keys < 0).any() and (keys >= h).any()
     assert (got == want).all()  # a selection: bit for bit, not approximately
+
+
+# LR's width, MVM's / FM's / xDeepFM's, DCN's; the plain form in one piece
+# and in pieces that do not divide M
+@pytest.mark.parametrize("piece", [None, 256])
+@pytest.mark.parametrize("d", [1, 10, 26])
+def test_gather_forms_agree_bit_for_bit(monkeypatch, d, piece):
+    """One contract, two exact implementations: the scan ("mxu") and the
+    plain clip-gather of the slice ("seg", which reads
+    hot._PLAIN_GATHER_SLOTS slots at a time) return the same bits, zero
+    rows for keys below 0, at H and beyond it included; "auto" is one of
+    the two."""
+    from xflow_tpu.ops import hot
+
+    h, m = 4096, 3001
+    if piece:
+        monkeypatch.setattr(hot, "_PLAIN_GATHER_SLOTS", piece)
+    assert (m > hot._PLAIN_GATHER_SLOTS) == bool(piece)
+    rng = np.random.default_rng(d)
+    w = jnp.asarray(rng.normal(size=(h, d)).astype(np.float32))
+    keys = rng.integers(0, h, size=m).astype(np.int32)
+    keys[:6] = [-1, -h, h, h + 1, 2 * h, np.iinfo(np.int32).max]
+    keys[-1] = h  # in the last, short piece too
+    got = {
+        impl: np.asarray(
+            jax.jit(lambda w, k, impl=impl: hot_gather(w, k, impl=impl))(
+                w, jnp.asarray(keys)
+            )
+        ).view(np.uint32)
+        for impl in ("mxu", "seg", "auto")
+    }
+    assert got["seg"].shape == (m, d)
+    assert (got["mxu"] == got["seg"]).all() and (got["auto"] == got["seg"]).all()
+    assert not got["seg"][:6].any() and not got["seg"][-1].any()
+    assert got["seg"][6:-1].any()
+
+
+def _primitives(jaxpr) -> set[str]:
+    return {eqn.primitive.name for eqn in jaxpr.eqns}
+
+
+def test_auto_gathers_by_the_scan_at_one_column_and_by_indexing_when_wide():
+    """ops/hot.py::gather_form: "auto" is the scan below
+    PLAIN_GATHER_MIN_COLUMNS columns (D = 1: LR's, FM's and FFM's w, the
+    serving program) and plain indexing of the [H, D] slice from there up
+    (D = 10: MVM, FM's v, xDeepFM; D = 26: DCN); an explicit "mxu" or
+    "seg" is taken at any width.  And hot_gather runs what the rule says:
+    the scan is a ``scan`` and no ``gather``, the plain form a ``gather``
+    and no ``scan``."""
+    assert 1 < PLAIN_GATHER_MIN_COLUMNS <= 10
+    assert [gather_form(d) for d in (1, 10, 26)] == ["mxu", "seg", "seg"]
+    assert gather_form(PLAIN_GATHER_MIN_COLUMNS - 1, "auto") == "mxu"
+    assert gather_form(PLAIN_GATHER_MIN_COLUMNS, "auto") == "seg"
+    for d in (1, 10, 26):
+        assert gather_form(d, "mxu") == "mxu" and gather_form(d, "seg") == "seg"
+    keys = jnp.zeros((100,), jnp.int32)
+    for d, impl, scans in [
+        (1, "auto", True), (10, "auto", False), (26, "auto", False),
+        (1, "seg", False), (10, "mxu", True), (26, "mxu", True),
+    ]:
+        w = jnp.zeros((256, d), jnp.float32)
+        found = _primitives(
+            jax.make_jaxpr(lambda w, k: hot_gather(w, k, impl=impl))(w, keys).jaxpr
+        )
+        assert ("scan" in found, "gather" in found) == (scans, not scans), (
+            d, impl, found
+        )
 
 
 @pytest.mark.parametrize("h,d,m", [(256, 1, 1000), (1024, 10, 4097), (4096, 1, 300)])

@@ -1161,7 +1161,10 @@ def test_wire_row_counts_the_table_rows_a_step_moves(
     a row read and a row written per padded cold slot; and in both the hot
     slots of a table that opted out of the MXU head (FFM's v), which
     ``plain_hot_slots_per_step`` counts: B x hot_nnz for FFM, 0 for the
-    families whose every table rides the head.  And
+    families whose every table rides the head; the slots that do ride it
+    are ``hot_plain_slots_per_step`` / ``hot_scan_slots_per_step`` by the
+    form of their gather (tests/test_tpu_compile.py holds the TPU's
+    split at the benchmark's geometries).  And
     ``cold_row_layout_slots_per_step``: the padded cold slots of every
     table wide enough for the dictionary route to lay its rows out by row
     gathers (dict_cold_rows): 0 for LR, B x max_nnz for FFM (v, not w)."""
@@ -1195,6 +1198,11 @@ def test_wire_row_counts_the_table_rows_a_step_moves(
     row_bytes = 4 * sum(widths.values())
     plain_bytes = 4 * sum(widths[n] for n in plain)
     assert row["plain_hot_slots_per_step"] == b * kh * len(plain)
+    # the tables ON the head, by the form their gather took: off the TPU
+    # every one is indexed ("seg"), none scanned (ops/hot.py::gather_form)
+    assert t.step._hot_impl == "seg"
+    assert row["hot_plain_slots_per_step"] == b * kh * (len(widths) - len(plain))
+    assert row["hot_scan_slots_per_step"] == 0
     assert row["padded_cold_slots_per_step"] == b * kc
     assert by_rows == [
         n for n, d in widths.items() if d >= ROW_LAYOUT_MIN_COLUMNS

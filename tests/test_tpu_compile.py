@@ -208,13 +208,20 @@ def test_dict_cold_rows_compile_for_v5e_at_flagship(
     )
 
 
-@pytest.mark.parametrize("d,m", [(10, 4194304), (26, 2097152)])
+# H, D, slots a step: the heads of lr_tb / mvm_tb / dcn_tb.train_packed
+@pytest.mark.parametrize("h,d,m", [
+    (4096, 1, 3670016), (16384, 10, 4194304), (16384, 26, 2097152),
+])
 def test_hot_gather_views_its_product_by_a_bitcast_on_v5e(
-    one_chip, no_compile_cache, d, m
+    one_chip, no_compile_cache, h, d, m
 ):
-    """ops/hot.py::hot_gather alone at the heads of the benchmark's
-    mvm_tb.train_packed and dcn_tb.train_packed (H = 2^14; D = 10 over
-    4 194 304 slots a step, D = 26 over 2 097 152) for a described v5e.
+    """ops/hot.py::hot_gather's SCAN (``impl="mxu"``) alone at the heads
+    of the benchmark's lr_tb.train_packed (H = 2^12, D = 1, 3 670 016
+    slots a step: the width the step runs it at), mvm_tb.train_packed
+    and dcn_tb.train_packed (H = 2^14; D = 10 over 4 194 304 slots, D =
+    26 over 2 097 152: since PR 45 the step indexes the slice at these
+    widths, and the scan stays the contract a later width under
+    hot.PLAIN_GATHER_MIN_COLUMNS would run) for a described v5e.
     The head is flattened [h1, D * h2] for both directions (PR 44), so
     the one-hot product [C, D * h2] IS [C, D, h2] in the device's tiles
     (h2 = 128 fills whole tiles of 8 sublanes; the chunk's slots lie on
@@ -222,19 +229,20 @@ def test_hot_gather_views_its_product_by_a_bitcast_on_v5e(
     gather had it until PR 44, the view was a real ``reshape
     f32[C,128,D]``, a shuffle of the product in each of the scan's 4 096
     chunks (8.0 ms of MVM's 271.7 ms step, 9.1 of DCN's 322.0; PERF.md
-    section 6).  And the product still asks for float32
+    section 6).  At D = 1 the two orders are one array and no view is
+    left to move.  And the product still asks for float32
     (Precision.HIGHEST: the default rounds the table's rows to bfloat16
     on the way into the MXU)."""
     from xflow_tpu.ops import hot
 
-    h = 16384
     h1, h2 = hot.hot_factors(h)
     c = hot._chunk(h1, h2, d, m)
     w = jax.ShapeDtypeStruct((h, d), jnp.float32, sharding=one_chip)
     keys = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    scan = jax.jit(lambda w, k: hot.hot_gather(w, k, impl="mxu"))
     lines = [
-        line.split(", metadata")[0].strip() for line in
-        jax.jit(hot.hot_gather).lower(w, keys).compile().as_text().splitlines()
+        line.split(", metadata")[0].strip()
+        for line in scan.lower(w, keys).compile().as_text().splitlines()
     ]
     views = (f"f32[{c},{h2},{d}]", f"f32[{c},{d},{h2}]")
     of_view = [
@@ -243,7 +251,7 @@ def test_hot_gather_views_its_product_by_a_bitcast_on_v5e(
     ]
     moved = [line for line in of_view if re.search(r" (reshape|copy)\(", line)]
     assert not moved, moved
-    assert any(" bitcast(" in line for line in of_view), of_view
+    assert d == 1 or any(" bitcast(" in line for line in of_view), of_view
     products = [
         line for line in lines
         if " convolution(" in line and f"= f32[{c},{d * h2}]" in line
@@ -302,7 +310,7 @@ def _lowered_fm_mesh_step(topo):
     mesh = make_mesh(4, devices=list(topo.devices))
     model = make_model(cfg)
     step = TrainStep(model, make_optimizer(cfg), cfg, mesh)
-    assert step._hot_impl == "mxu" and step.wire_format == "compact"
+    assert step._hot_impl == "auto" and step.wire_format == "compact"
 
     def shaped(shape, dtype, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -419,16 +427,9 @@ XDEEPFM_PLANES = {
 }
 
 
-def _lowered_cell_step(
-    topo, config: str, planes: dict, ships_slots: bool = True
-):
-    """The train step of a one-chip benchmark configuration
-    (benchmarks/configs/<config>.json) lowered for a described v5e, its
-    dictionary-wire batch given as plane shapes (``planes``: name ->
-    (shape, dtype), the capacities of one real batch; ``ships_slots``:
-    whether the family reads field ids, so that its wire ships the slots
-    planes).  A family that owns dense replicated parameters is handed
-    the shapes of its ``dense_init``."""
+def _cell_train_step(topo, config: str):
+    """(cfg, step): the TrainStep of a one-chip benchmark configuration
+    (benchmarks/configs/<config>.json) built for a described v5e."""
     from benchmarks.harness import manifest
     from xflow_tpu.config import Config
     from xflow_tpu.models import make_model
@@ -441,11 +442,29 @@ def _lowered_cell_step(
         k: v for k, v in manifest.apply_rehearsal(doc, False).items()
         if k not in manifest.CONFIG_META
     })
-    mesh = meshes.make_mesh(1, devices=list(topo.devices))
-    model = make_model(cfg)
-    step = TrainStep(model, make_optimizer(cfg), cfg, mesh)
+    step = TrainStep(
+        make_model(cfg), make_optimizer(cfg), cfg,
+        meshes.make_mesh(1, devices=list(topo.devices)),
+    )
+    assert step._hot_impl == "auto"
+    return cfg, step
+
+
+def _lowered_cell_step(
+    topo, config: str, planes: dict, ships_slots: bool = True
+):
+    """The train step of a one-chip benchmark configuration
+    (benchmarks/configs/<config>.json) lowered for a described v5e, its
+    dictionary-wire batch given as plane shapes (``planes``: name ->
+    (shape, dtype), the capacities of one real batch; ``ships_slots``:
+    whether the family reads field ids, so that its wire ships the slots
+    planes).  A family that owns dense replicated parameters is handed
+    the shapes of its ``dense_init``."""
+    from xflow_tpu.parallel import mesh as meshes
+
+    cfg, step = _cell_train_step(topo, config)
+    mesh, model = step.mesh, step.model
     assert step.wire_format == "dict" and step._ship_slots == ships_slots
-    assert step._hot_impl == "mxu"
 
     def shaped(shape, dtype, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
@@ -560,6 +579,88 @@ def test_mvm_pass_keeps_its_padded_rows_on_v5e(mvm_cell_step):
     assert not _table_sized_copies(text, t * d)
 
 
+def test_mvm_step_indexes_its_head_and_scans_only_the_scatter_on_v5e(
+    mvm_cell_step
+):
+    """PR 45: at D = 10 the head's GATHER is plain indexing of the
+    [H, D] slice (ops/hot.py::gather_form; 1.5 ns a slot in the step
+    against the scan's 7.2), so the compiled MVM step has no one-hot product
+    ``f32[1024,1280]`` under xf.gather, while the head's SCATTER is still
+    the scan (its product ``f32[128,1280]`` under xf.scatter).  The
+    gather reads the slice, ``f32[16384,10]``, never the table, a piece
+    of hot._PLAIN_GATHER_SLOTS slots at a time, and a piece leaves its
+    loop with the slots on the lanes (``f32[pieces,10,slots]``, 16
+    sublanes for 10 columns): gathered whole, ``f32[4194304,10]`` is one
+    128-lane row a slot, 2 GiB, and put the program's peak at 10.2 GiB
+    for 9.19 (test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e
+    holds the peak)."""
+    from xflow_tpu.ops import hot
+    from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
+
+    cfg, _, compiled = mvm_cell_step
+    h, d = cfg.hot_size, cfg.v_dim
+    m, c = cfg.batch_size * cfg.hot_nnz, hot._PLAIN_GATHER_SLOTS
+    h1, h2 = hot.hot_factors(h)
+    assert (h, d, m, h1, h2) == (16384, 10, 4194304, 128, 128)
+    by_scope: dict[str, list[str]] = {"xf.gather": [], "xf.scatter": []}
+    text = compiled.as_text()
+    for line in text.splitlines():
+        found = _HLO_OP_NAME_RE.search(line)
+        if found and scope_of(found.group(1)) in by_scope:
+            by_scope[scope_of(found.group(1))].append(line.split(", metadata")[0])
+    products = {
+        scope: [
+            line.split(" = ")[1].split("{")[0] for line in lines
+            if " convolution(" in line
+        ]
+        for scope, lines in by_scope.items()
+    }
+    assert products == {"xf.gather": [], "xf.scatter": [f"f32[{h1},{d * h2}]"]}
+    # (the gather is fused: its operand's type is in the fusion's head)
+    pieces = [
+        line for line in _gather_lines(compiled) if f"= f32[{c},{d}]" in line
+    ]
+    assert len(pieces) == 1 and f"slice_sizes={{1,{d}}}" in pieces[0], pieces
+    assert re.search(
+        rf"\(param_[\d.]+: f32\[{h},{d}\], param_[\d.]+: s32\[{c}\]\) -> "
+        rf"f32\[{c},{d}\]", text
+    )
+    assert f"f32[{m // c},{d},{c}]{{2,1,0:" in text
+    assert f"f32[{m},{d}]" not in text
+
+
+# configuration, and the hot slots a step that its gather indexes / scans,
+# a table
+@pytest.mark.parametrize("config,plain,scan", [
+    ("lr_ftrl_criteo_tb", 0, 131072 * 28),
+    ("mvm_ftrl_criteo_tb", 4194304, 0),
+    ("dcn_ftrl_criteo_tb", 2097152, 2097152),
+])
+def test_wire_row_books_the_head_slots_by_the_form_of_their_gather(
+    topo, config, plain, scan
+):
+    """``hot_plain_slots`` / ``hot_scan_slots`` of TrainStep._book_wire
+    (the ``_wire`` row's ``hot_plain_slots_per_step`` /
+    ``hot_scan_slots_per_step``), from shapes, on the step built for a
+    described v5e at the geometry of the benchmark's cells: MVM's one
+    table of ten columns is indexed (4 194 304 / 0), DCN's ``emb`` is
+    indexed and its ``w`` scanned (2 097 152 each), LR's ``w`` scanned
+    (0 / all 3 670 016).  Nothing is lowered."""
+    cfg, step = _cell_train_step(topo, config)
+    booked: dict[str, float] = {}
+    step.obs = types.SimpleNamespace(
+        counter=lambda name, v=1.0: booked.__setitem__(name, v)
+    )
+    b = cfg.batch_size
+    step._book_wire(
+        0, b, cold_slots=b * cfg.max_nnz, hot_slots=b * cfg.hot_nnz
+    )
+    assert (booked["wire.hot_plain_slots"], booked["wire.hot_scan_slots"]) == (
+        plain, scan
+    )
+    assert booked["wire.plain_hot_slots"] == 0  # no table off the head
+
+
 def test_lr_step_runs_its_pass_on_the_flat_view_and_fits_a_v5e(
     topo, no_compile_cache
 ):
@@ -658,20 +759,20 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
 # sha256 of the lowered train program (StableHLO text, the Mosaic kernels'
 # serialized bodies blanked: they embed the checkout's path) of the four
 # configurations the benchmark measured before PR 39, pinned on PR 38's
-# tree BEFORE models/blocks.py was edited and anew by PR 44 (the test's
-# docstring says why).
+# tree BEFORE models/blocks.py was edited, anew by PR 44, and MVM's and
+# FM's again by PR 45 (the test's docstring says why).
 MEASURED_PROGRAMS_SHA256 = {
     "lr_ftrl_criteo_tb": (
         "e288dfde6bd0a7646d26153ef9b2ad0ba6d6a5056fe6a02cc9440b498b84d5bc"
     ),
     "mvm_ftrl_criteo_tb": (
-        "f75e0ff38053329174a77f6e8c06637b8a09819fa9e367114323c13a4dd13f2f"
+        "3a4bddc61a84a8b2ea5cc85afac456d118f9592c3e2344b719fcbda66dde35ac"
     ),
     "ffm_ftrl_criteo_tb": (
         "c40a3f4e9f8eb87e924e7c25a3a14818b91f19dddf1bc6414dc08d2214084d54"
     ),
     "fm_ftrl_criteo_tb (cut, 2x2)": (
-        "c21a3d5d0035f8bcf6f70d3ac856055a1f5ca43b1c6be9ab391d17f30d18b6a3"
+        "1a8e174f5811f4d551ad0a51af66c814e01e49657155944889138ec1c034b32a"
     ),
 }
 
@@ -697,7 +798,13 @@ def test_measured_train_programs_lower_to_the_pinned_text(topo):
     PR 44 meant to change all four (every one has a head: the gather
     scan of ops/hot.py flattens it as [h1, D * h2] and emits a chunk as
     [D, C]; PERF.md section 6) and pinned the digests anew; PR 39 to 43
-    had left them as they were."""
+    had left them as they were.  PR 45 meant to change MVM's and the cut
+    FM mesh's and NOT LR's and FFM's: the head's gather is chosen from
+    the table's width (ops/hot.py::gather_form), plain indexing of the
+    [H, D] slice at D = 10 (MVM's v, FM's v), the scan at D = 1 (LR's w,
+    FM's and FFM's w; FFM's v is off the head), so LR's and FFM's digests
+    are PR 44's, the control that every D = 1 head runs the parent's
+    program, and the other two are pinned anew."""
     got = {
         "lr_ftrl_criteo_tb": _lowered_cell_step(
             topo, "lr_ftrl_criteo_tb", LR_PLANES, ships_slots=False
@@ -739,7 +846,9 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
     ``dense_dot`` and what autodiff makes of it; at default precision the
     TPU rounds the operands to bfloat16 and the step misses the
     benchmark's reference (PERF.md section 7, PR 38).  Both tables go
-    through the MXU head (ops/hot.py: no gather by the hot plane).
+    through the head (ops/hot.py: no gather out of a table by the hot
+    plane; since PR 45 w's hot rows come by the one-hot scan and emb's
+    by indexing the [16384, 26] slice).
     Compiled: the instructions of the dense half carry ``xf.dense`` in
     ``op_scopes``' reading (the innermost name), the three products of
     each hidden layer among them (convolutions, as the TPU's compiler
@@ -780,6 +889,18 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
         and f"tensor<{b}x{cfg.hot_nnz}x1xi32>" in line
     ]
     assert not by_hot_plane, by_hot_plane
+    # emb's hot rows: a piece of the slots at a time out of the [H, 26]
+    # slice; w's: the scan (no gather out of [H, 1])
+    from xflow_tpu.ops.hot import _PLAIN_GATHER_SLOTS
+
+    of_head = [
+        line for line in text
+        if "stablehlo.gather" in line and f"(tensor<{cfg.hot_size}x" in line
+    ]
+    assert len(of_head) == 1 and (
+        f"(tensor<{cfg.hot_size}x{cfg.emb_dim}xf32>, "
+        f"tensor<{_PLAIN_GATHER_SLOTS}x1xi32>)" in of_head[0]
+    ), of_head
 
     compiled = lowered.compile()
     hlo = compiled.as_text()

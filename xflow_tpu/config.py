@@ -425,12 +425,15 @@ class Config:
     wire_dedup: str = "auto"  # {"auto", "off", "on"}
 
     # Hot-path gather/scatter implementation (ops/hot.py): "mxu" = the
-    # two-level one-hot matmul path (the TPU win — ~2-4x over per-slice
-    # DMA on v5e); "seg" = plain gather + segment-sum (the CPU-fast
-    # form: one-hot matmuls are an MXU trick, measured 3.3x slower
-    # than the gather on the CPU backend).  "auto" picks "mxu" on TPU
-    # meshes and "seg" elsewhere.  Numerics: gather is exact either
-    # way; scatter differs only in summation order.
+    # two-level one-hot matmul scans (~2-4x over per-slice DMA INTO THE
+    # TABLE on v5e); "seg" = plain gather + segment-sum of the head's
+    # [H, D] slice (the CPU-fast form: one-hot matmuls are an MXU trick,
+    # measured 3.3x slower than the gather on the CPU backend).  "auto"
+    # picks "seg" off the TPU; on TPU meshes the scatter scan at every
+    # width and, for the gather, the cheaper form for the table's width
+    # (ops/hot.py::gather_form: the scan at D = 1, the slice indexed
+    # from PLAIN_GATHER_MIN_COLUMNS columns up).  Numerics: gather is
+    # exact either way; scatter differs only in summation order.
     hot_impl: str = "auto"  # {"auto", "mxu", "seg"}
 
     # -- hierarchical parameter store (store/; docs/STORE.md) --

@@ -408,6 +408,11 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "gather_row_bytes_per_step": (int, float),
         "scatter_row_bytes_per_step": (int, float),
         "plain_hot_slots_per_step": (int, float),
+        # the hot-plane slots that DID ride the head, a table, by the
+        # form its gather read them in (ops/hot.py::gather_form): plain
+        # indexing of the [H, D] slice, or the one-hot scan
+        "hot_plain_slots_per_step": (int, float),
+        "hot_scan_slots_per_step": (int, float),
         # where the step read the dictionary wire's plan (one device):
         # padded cold slots B * max_nnz times the tables wide enough for
         # the route to lay their rows out by row gathers, 0 where every

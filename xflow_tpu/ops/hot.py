@@ -1,22 +1,41 @@
-"""Two-level one-hot MXU gather/scatter for the frequency-hot table head.
+"""The frequency-hot table head: rows [0, H) gathered and scattered apart
+from the [T, D] table, by two-level one-hot matmuls on the MXU or, where
+that is cheaper, by plain indexing of the [H, D] slice.
 
-XLA TPU gather/scatter cost is per *slice* (~8-14 ns of DMA descriptor
-issue each, independent of slice width — docs/PERF.md), so a step over
-M = B*nnz feature occurrences pays ~18 ns/occurrence of round-trip DMA
-no matter what.  CTR key distributions are zipfian: after the frequency
-remap (io/freq.py) the head of the distribution lives in table rows
-[0, H).  For those occurrences we replace per-slice DMA with two-level
-one-hot matmuls that ride the MXU:
+XLA TPU gather/scatter cost is per *slice* (~8-37 ns of DMA descriptor
+issue each INTO THE WHOLE TABLE, independent of slice width —
+docs/PERF.md), so a step over M = B*nnz feature occurrences pays that
+per occurrence no matter what.  CTR key distributions are zipfian: after
+the frequency remap (io/freq.py) the head of the distribution lives in
+table rows [0, H).  Those occurrences never index the table: the callers
+hand this module the head's own ``[H, D]`` slice.  Two exact forms read
+and sum it:
 
     key = hi * h2 + lo            (H = h1 * h2)
     gather:  rows = ((onehot_hi @ W) . reshape  *  onehot_lo) sum over lo
     scatter: W'   = onehot_hi^T @ (g * onehot_lo)
 
-Traffic is M*(h1 + h2*D) one-hot elements instead of M DMA descriptors
-(docs/PERF.md "The win" has the v5e rates of the rounds that built it).
+the one-hot scans ("mxu"), and plain indexing of the slice ("seg"):
+``w_hot[keys]`` and a segment-sum.  Which one runs:
 
-One-hot intermediates are built in chunks under ``lax.scan`` so the
-[C, D*h2] temporaries stay within a few MiB regardless of M or D.
+  * ``hot_scatter``: the scan at every width on the TPU ("seg" is the
+    CPU's form; anything else, "auto" included, is the scan).  Its sums
+    stand 1e-6 of the largest from float64 where a plain scatter-add of
+    10^6 float32 adds into one row stands 1e-5 (scripts/probe_hot2.py).
+  * ``hot_gather``: a selection, bit for bit the same in both forms, so
+    the price decides (``gather_form``): the scan costs 0.7 ns a slot
+    AND COLUMN, a row out of the small slice 2-4.4 ns at any width.
+    The scan is the D = 1 form (LR's, FM's and FFM's ``w``, the serving
+    program); from ``PLAIN_GATHER_MIN_COLUMNS`` columns up ``"auto"``
+    indexes the slice.  The rounds that built the scan compared it with
+    slices of the WHOLE table; against its own slice it lost at D = 10
+    and 26 (PERF.md section 6, PR 44-45).
+
+The scans: traffic is M*(h1 + h2*D) one-hot elements instead of M DMA
+descriptors (docs/PERF.md "The win" has the v5e rates of the rounds that
+built them).  One-hot intermediates are built in chunks under
+``lax.scan`` so the [C, D*h2] temporaries stay within a few MiB
+regardless of M or D.
 
 ONE flattened order for both directions: the level-2 axis is
 ``[h1, D * h2]``, column ``d * h2 + lo``.  The gather flattens the head
@@ -58,6 +77,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 
 def hot_factors(hot_size: int) -> tuple[int, int]:
@@ -99,6 +119,65 @@ def _pad_to(x: jax.Array, m_pad: int, fill) -> jax.Array:
     return jnp.concatenate([x, jnp.full(pad_shape, fill, x.dtype)])
 
 
+# Columns of a head from which ``hot_gather``'s "auto" indexes the
+# [H, D] slice and stops scanning it.  Two laws, read on a v5e alone at
+# H = 16384 over 4 194 304 slots (scripts/probe_hot2.py; PERF.md section
+# 6, PR 45).  The scan's product is 2 M h1 D h2 operations in three MXU
+# passes: 2.7 ms at D = 1, 6.6 at 2, 11.7 at 4, 22.6 at 8, 30.9 at 10
+# (and 91 at 16, 134 at 32).  A row of the slice is one descriptor
+# whatever its width: 8.3 ms at D = 2, 8.4 at 4 and 8, 10.9 at 10, 10.8
+# at 32, a piece at a time (2.0-2.6 ns a slot; 4.2 gathered whole); one
+# COLUMN is a gather of single elements, 30.3 ms.  They cross between
+# D = 2, where the scan wins, and D = 4, the narrowest the plain form
+# was seen to win at.
+PLAIN_GATHER_MIN_COLUMNS = 4
+
+
+def gather_form(d: int, impl: str = "auto") -> str:
+    """The form ``hot_gather`` runs for a head of ``d`` columns: ``impl``
+    itself where it names one ("mxu", "seg"), and for "auto" the cheaper
+    one on the TPU at that width."""
+    if impl != "auto":
+        return impl
+    return "seg" if d >= PLAIN_GATHER_MIN_COLUMNS else "mxu"
+
+
+# Slots the plain gather reads at a time.  The TPU writes a gathered row
+# of D < 128 columns into a 128-lane row of its own (f32[M, D] in (8,128)
+# tiles: 512 B a slot whatever D), so mvm_tb.train_packed's 4 194 304
+# slots at once were a 2 GiB temporary and 1 GiB more of program peak
+# (compiled for a described v5e, PR 45).  A piece is 16 MiB of that,
+# which the compiler keeps in VMEM (the gather then costs 1.5-2.6 ns a
+# slot, not 4.2), and leaves the loop as [D, C], the slots on the lanes,
+# as a chunk of the scan does (module docstring): the stacked result is
+# the scan's, and so is everything after it.  Alone, pieces of 4 096 to
+# 65 536 slots cost the same to 0.1 ms; 262 144 leave VMEM (+3 ms at
+# D = 10).
+_PLAIN_GATHER_SLOTS = 1 << 15
+
+
+def _plain_gather(w_hot: jax.Array, keys: jax.Array) -> jax.Array:
+    """``w_hot[keys]`` with zero rows for keys outside [0, H), a piece of
+    ``_PLAIN_GATHER_SLOTS`` slots at a time."""
+    h, d = w_hot.shape
+
+    def piece(k):  # [C] -> [C, D]
+        rows = w_hot[jnp.clip(k, 0, h - 1)]
+        ok = (k >= 0) & (k < h)
+        return jnp.where(ok[:, None], rows, 0.0).astype(jnp.float32)
+
+    m, c = keys.shape[0], _PLAIN_GATHER_SLOTS
+    if m <= c:
+        return piece(keys)
+    m_pad = ((m + c - 1) // c) * c
+    lanes = Layout(major_to_minor=(0, 1))  # of [D, C]: the slots minor
+    out = jax.lax.map(
+        lambda k: with_layout_constraint(piece(k).T, lanes),
+        _pad_to(keys, m_pad, h).reshape(-1, c),
+    )  # [M/C, D, C]
+    return out.transpose(0, 2, 1).reshape(m_pad, d)[:m]
+
+
 @jax.named_scope("xf.gather")
 def hot_gather(
     w_hot: jax.Array,
@@ -106,25 +185,25 @@ def hot_gather(
     *,
     impl: str = "mxu",
 ) -> jax.Array:
-    """Gather rows of the hot table via two-level one-hot matmuls.
+    """Gather rows of the hot table's [H, D] slice.
 
     Args:
       w_hot: [H, D] hot-table rows (H a power of two).
       keys: int32 [M]; entries outside [0, H) yield zero rows.
-      impl: "mxu" — the one-hot matmul path (the TPU win this module
-        exists for); "seg" — a plain clip-gather with zero fill.  Same
-        contract, exact either way; "seg" is the CPU-fast form (one-hot
-        matmuls are an MXU trick — measured 3.3x slower than the gather
-        on the CPU backend, docs/PERF.md "Wire format and compaction").
-        TrainStep picks per platform via Config.hot_impl.
+      impl: "mxu" — the two-level one-hot scan; "seg" — a plain
+        clip-gather of the slice with zero fill; "auto" — by the
+        slice's width (``gather_form``).  Same contract, exact every
+        way.  "seg" is also the CPU-fast form (one-hot matmuls are an
+        MXU trick — measured 3.3x slower than the gather on the CPU
+        backend, docs/PERF.md "Wire format and compaction").
+        TrainStep picks per platform via Config.hot_impl: "auto" on
+        the TPU, "seg" elsewhere.
 
     Returns: [M, D] gathered rows, float32.
     """
     h, d = w_hot.shape
-    if impl == "seg":
-        rows = w_hot[jnp.clip(keys, 0, h - 1)]
-        ok = (keys >= 0) & (keys < h)
-        return jnp.where(ok[:, None], rows, 0.0).astype(jnp.float32)
+    if gather_form(d, impl) == "seg":
+        return _plain_gather(w_hot, keys)
     h1, h2 = hot_factors(h)
     m = keys.shape[0]
     c = _chunk(h1, h2, d, m)

@@ -32,6 +32,7 @@ from xflow_tpu.config import Config
 from xflow_tpu.io.batch import Batch
 from xflow_tpu.models.base import BatchArrays, Model
 from xflow_tpu.obs import NULL_OBS
+from xflow_tpu.ops.hot import gather_form
 from xflow_tpu.ops.sparse import (
     consolidate_apply,
     consolidate_plan,
@@ -711,16 +712,27 @@ class TrainStep:
         # shipped at (_settle_planes)
         self._plane_lengths: dict[tuple[int, str], int] = {}
         self._plane_lengths_lock = threading.Lock()
-        # Hot-path implementation (ops/hot.py): one-hot MXU matmuls on
-        # TPU, gather + segment-sum elsewhere (Config.hot_impl) — the
-        # MXU trick measured 3.3x SLOWER than the gather on the CPU
-        # backend (docs/PERF.md "Wire format and compaction").
+        # Hot-path implementation (ops/hot.py; Config.hot_impl).  On the
+        # TPU "auto" stays "auto": the scatter is the one-hot MXU scan at
+        # every width, the gather the scan at D = 1 and plain indexing of
+        # the [H, D] slice from hot.PLAIN_GATHER_MIN_COLUMNS columns up
+        # (hot.gather_form).  Elsewhere gather + segment-sum: the MXU
+        # trick measured 3.3x SLOWER than the gather on the CPU backend
+        # (docs/PERF.md "Wire format and compaction").
         platform = str(self.mesh.devices.ravel()[0].platform)
         self._hot_impl = (
             cfg.hot_impl
-            if cfg.hot_impl != "auto"
-            else ("mxu" if platform == "tpu" else "seg")
+            if cfg.hot_impl != "auto" or platform == "tpu"
+            else "seg"
         )
+        # tables on the head whose hot slots hot_gather reads by plain
+        # indexing, and by the scan (_book_wire)
+        forms = [
+            gather_form(spec.dim, self._hot_impl)
+            for spec in model.tables() if spec.hot
+        ]
+        self._hot_plain_tables = forms.count("seg")
+        self._hot_scan_tables = forms.count("mxu")
         # In-window lane shuffle of the dictionary-wire decode
         # (ops/window.py): Mosaic's one-vreg dynamic_gather on the TPU,
         # the plain minor-axis gather elsewhere.
@@ -905,8 +917,12 @@ class TrainStep:
         out of the MXU head (TableSpec.hot=False), whose hot occurrences
         are plain table rows, and leave the head's own traffic out;
         ``plain_hot_slots`` is those slots, a table: 0 where every table
-        rides the head.  A padded slot counts like a live one (the
-        gather reads row 0 for it; the scatter-add drops it).  Where the
+        rides the head.  The slots that DO ride it are booked by the form
+        hot_gather reads them in, a table (ops/hot.py::gather_form, from
+        the table's width): ``hot_plain_slots`` by plain indexing of the
+        [H, D] slice, ``hot_scan_slots`` by the one-hot scan.  A padded
+        slot counts like a live one (the gather reads row 0 for it; the
+        scatter-add drops it).  Where the
         step reads the ``cold_plan``, ``cold_row_layout_slots`` is the
         padded cold slots of every table wide enough for dict_cold_rows
         to lay its rows out by row gathers: 0 where every table goes
@@ -950,6 +966,12 @@ class TrainStep:
             plain = hot_slots * self._plain_hot_row_bytes
             self.obs.counter(
                 "wire.plain_hot_slots", hot_slots * self._plain_hot_tables
+            )
+            self.obs.counter(
+                "wire.hot_plain_slots", hot_slots * self._hot_plain_tables
+            )
+            self.obs.counter(
+                "wire.hot_scan_slots", hot_slots * self._hot_scan_tables
             )
             self.obs.counter(
                 "wire.gather_row_bytes", indices * self._row_bytes + plain
