@@ -54,8 +54,9 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(REPO, ".bench_cache", "chip_smoke")
 
 SEED = 7
-# the flagship geometry (bench.py, README): T=2^24, B=131072, hot head
-# 2^12 x 32 on the MXU, cold capacity 16 on the DMA path
+# the LR flagship's geometry of rounds 3-5 (docs/PERF.md): T=2^24,
+# B=131072, hot head 2^12 x 32 on the MXU, cold capacity 16 on the DMA
+# path
 FLAGSHIP = dict(table_size_log2=24, batch_size=131072, max_nnz=16,
                 hot_size_log2=12, hot_nnz=32)
 TOY = dict(table_size_log2=16, batch_size=1024, max_nnz=16,

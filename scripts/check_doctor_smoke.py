@@ -11,6 +11,15 @@ path end to end:
   diagnosis — the first-responder command keeps working on the boring
   case, so it can be trusted on the interesting one.
 
+One finding is a reading of the host's clock and not of the program:
+`recompile_suspicion`, the step-time shape of an epoch of SEVEN steps,
+which one step that the scheduler held for 25 ms on a loaded host
+satisfies (it failed the driver's tier-1 run of PR 33 on a tree that
+compiled nothing twice).  A run whose ONLY complaint is that one is run
+again, up to `ATTEMPTS` times; any other finding, a schema violation, a
+`health` row, a watchdog trip or a flight dump fails at once, on
+whichever attempt it shows.
+
 Run from the repo root:
 
     JAX_PLATFORMS=cpu python scripts/check_doctor_smoke.py
@@ -30,11 +39,17 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 
-def main() -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ATTEMPTS = 5
+# doctor findings that a busy host alone produces on a healthy toy run
+TIMING_CODES = {"recompile_suspicion"}
+
+
+def attempt() -> tuple[list[str], list[str], int]:
+    """One toy run: (wrong values, timing findings, metrics rows)."""
     from tests.gen_data import generate_dataset
     from xflow_tpu.config import Config
     from xflow_tpu.obs.__main__ import main as obs_main
+    from xflow_tpu.obs.doctor import diagnose
     from xflow_tpu.obs.schema import load_jsonl, validate_rows
     from xflow_tpu.trainer import Trainer
 
@@ -86,21 +101,48 @@ def main() -> int:
                 "nothing stalled)"
             )
 
+        problems = [
+            d for d in diagnose(rows) if d.severity in ("crit", "warn")
+        ]
+        timing = sorted({d.code for d in problems} & TIMING_CODES)
+        errors.extend(
+            f"`obs doctor` on a healthy run: {d.code}: {d.message[:160]}"
+            for d in problems if d.code not in TIMING_CODES
+        )
+        # the command itself, as an operator runs it: its exit code says
+        # what the findings say
         rc = obs_main(["doctor", metrics])
-        if rc != 0:
+        if rc != (1 if problems else 0):
             errors.append(
-                f"`obs doctor` exited {rc} on a healthy run (expected "
-                "0 / clean diagnosis)"
+                f"`obs doctor` exited {rc} with {len(problems)} finding(s) "
+                "at warn or above"
             )
-        n = len(rows)
+    return errors, timing, len(rows)
 
+
+def main() -> int:
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    for n in range(1, ATTEMPTS + 1):
+        errors, timing, rows = attempt()
+        if errors or not timing:
+            break
+        print(
+            f"attempt {n} of {ATTEMPTS}: only {timing}, a "
+            "reading of the host's clock and not of the program",
+            file=sys.stderr,
+        )
+    if not errors:  # then ``timing`` is the last of ATTEMPTS such runs
+        errors = [
+            f"`obs doctor` raised {code} on each of {ATTEMPTS} healthy runs"
+            for code in timing
+        ]
     for e in errors:
         print(f"FAIL: {e}", file=sys.stderr)
     if errors:
         return 1
     print(
-        f"OK: watchdog armed, 0 trips; {n} metrics rows validated; "
-        "obs doctor reports clean"
+        f"OK: watchdog armed, 0 trips; {rows} metrics rows validated; "
+        f"obs doctor reports clean (attempt {n})"
     )
     return 0
 

@@ -28,6 +28,18 @@ and validate everything the tracing spine promises
 * **schema** — both metrics streams (``reqtrace`` rows included) pass
   obs/schema.py strictly.
 
+Two of these read the host's clock and not the program: whether the
+healthy leg HAS a tail (one request that the scheduler held for 150 ms
+under six test workers failed the driver's tier-1 runs of PRs 33 and 40)
+and how long after the server's span the client's thread woke.  Each has
+an order that must hold on any host, and that is checked as a value: a
+server span lies inside its client's (server sum <= client e2e), and a
+tail that the doctor reports is one the client saw too (the server's
+slowest three are not slower than the client's slowest three).  Past the
+order, a healthy leg that only read as slow is run again on a new fleet,
+up to ``HEALTHY_ATTEMPTS`` times; schema, span trees, errors, recompiles,
+a tail the client did not see and every other value fail at once.
+
 Run from the repo root:
 
     JAX_PLATFORMS=cpu python scripts/check_reqtrace_smoke.py
@@ -52,6 +64,7 @@ if REPO not in sys.path:
 BUCKETS = (8, 64)
 SLOW_SLEEP_S = 0.08  # injected device-side stall, every 8th batch
 PHASE_SUM_TOL = 1e-4  # rounding slack: phases round to 1e-6 s each
+HEALTHY_ATTEMPTS = 5  # healthy legs run before a slow host is believed
 
 
 def main() -> int:
@@ -62,7 +75,11 @@ def main() -> int:
 
     from tests.gen_data import generate_dataset
     from xflow_tpu.config import Config
-    from xflow_tpu.obs.doctor import diagnose
+    from xflow_tpu.obs.doctor import (
+        REQTRACE_SLOW_K,
+        REQTRACE_TAIL_MIN_EXCESS_S,
+        diagnose,
+    )
     from xflow_tpu.obs.reqtrace import PHASES, ReqTraceSink
     from xflow_tpu.obs.schema import load_jsonl, validate_rows
     from xflow_tpu.serve.artifact import export_artifact
@@ -155,60 +172,99 @@ def main() -> int:
                     errors.append(f"{where}: batch {b['batch']} digest odd")
 
         # ---- healthy leg: loadgen, sample=1.0 (every tree emitted) ----
-        healthy = os.path.join(root, "healthy.jsonl")
-        logger = MetricsLogger(healthy, run_header={
-            "run_id": "reqtrace-smoke",
-            "config_digest": "smoke",
-            "rank": 0,
-            "num_hosts": 1,
-        })
-        fleet = ReplicaFleet.load(
-            art, replicas=2, buckets=BUCKETS, metrics_logger=logger,
-            **admission,
-        )
-        fleet.reqtrace = ReqTraceSink(metrics_logger=logger, sample=1.0)
-        fleet.log_load(art)
-        compiles_warm = fleet.engines[0].compile_count
-        summary = run_loadgen(
-            fleet,
-            offered_qps=60.0,
-            duration_s=2.0,
-            concurrency=4,
-            nnz=8,
-            zipf_a=1.3,
-            seed=5,
-            metrics_logger=logger,
-        )
-        if summary["errors"]:
-            errors.append(f"healthy loadgen errors: {summary['errors']}")
-        if summary["requests"] < 20:
-            errors.append(
-                f"healthy loadgen answered only {summary['requests']} "
-                "requests — too few to judge anything"
+        def healthy_leg(path):
+            """A fresh traced fleet under the loadgen: (fleet, logger,
+            client-observed e2e ms of its slowest requests, timing
+            complaints).  Wrong values go to ``errors``."""
+            logger = MetricsLogger(path, run_header={
+                "run_id": "reqtrace-smoke",
+                "config_digest": "smoke",
+                "rank": 0,
+                "num_hosts": 1,
+            })
+            fleet = ReplicaFleet.load(
+                art, replicas=2, buckets=BUCKETS, metrics_logger=logger,
+                **admission,
             )
-        if fleet.engines[0].compile_count != compiles_warm:
-            errors.append(
-                "tracing recompiled the fleet: "
-                f"{compiles_warm} -> {fleet.engines[0].compile_count}"
+            fleet.reqtrace = ReqTraceSink(metrics_logger=logger, sample=1.0)
+            fleet.log_load(art)
+            compiles_warm = fleet.engines[0].compile_count
+            summary = run_loadgen(
+                fleet,
+                offered_qps=60.0,
+                duration_s=2.0,
+                concurrency=4,
+                nnz=8,
+                zipf_a=1.3,
+                seed=5,
+                metrics_logger=logger,
             )
-        exemplars = summary.get("slowest_exemplars") or []
-        if not exemplars:
-            errors.append("serve_bench summary has no slowest_exemplars")
-        with_phases = [e for e in exemplars if "phases_ms" in e]
-        if not with_phases:
-            errors.append(
-                "no slowest exemplar resolved a server-side phase "
-                f"breakdown: {exemplars}"
-            )
-        for e in with_phases:
-            client = e["e2e_ms"]
-            server = sum(e["phases_ms"].values())
-            if abs(client - server) > max(0.10 * client, 2.0):
+            if summary["errors"]:
+                errors.append(f"healthy loadgen errors: {summary['errors']}")
+            if summary["requests"] < 20:
                 errors.append(
-                    f"exemplar {e['trace_id']}: server phase sum "
-                    f"{server:.3f}ms vs client e2e {client:.3f}ms "
-                    "(>10% + 2ms apart)"
+                    f"healthy loadgen answered only {summary['requests']} "
+                    "requests — too few to judge anything"
                 )
+            if fleet.engines[0].compile_count != compiles_warm:
+                errors.append(
+                    "tracing recompiled the fleet: "
+                    f"{compiles_warm} -> {fleet.engines[0].compile_count}"
+                )
+            exemplars = summary.get("slowest_exemplars") or []
+            if not exemplars:
+                errors.append("serve_bench summary has no slowest_exemplars")
+            with_phases = [e for e in exemplars if "phases_ms" in e]
+            if not with_phases:
+                errors.append(
+                    "no slowest exemplar resolved a server-side phase "
+                    f"breakdown: {exemplars}"
+                )
+            timing = []
+            for e in with_phases:
+                client = e["e2e_ms"]
+                server = sum(e["phases_ms"].values())
+                if server > client + 1e3 * PHASE_SUM_TOL:
+                    # the server's span is opened after the client's
+                    # clock starts and closed before the Future resolves
+                    errors.append(
+                        f"exemplar {e['trace_id']}: server phase sum "
+                        f"{server:.3f}ms OVER client e2e {client:.3f}ms"
+                    )
+                elif client - server > max(0.10 * client, 2.0):
+                    timing.append(
+                        f"exemplar {e['trace_id']}: client e2e "
+                        f"{client:.3f}ms, server phase sum {server:.3f}ms "
+                        "(>10% + 2ms later: the client's thread woke late)"
+                    )
+            return fleet, logger, [e["e2e_ms"] for e in exemplars], timing
+
+        def judge_healthy(path, client_ms, timing):
+            """A closed healthy stream: schema and span trees as values,
+            then the doctor: clean, or a tail that the client's own clock
+            saw as well (the host was slow: timing), or a tail it did not
+            see (the doctor is wrong: an error)."""
+            rows = load_jsonl(path)
+            errors.extend(f"healthy schema: {e}" for e in validate_rows(rows))
+            check_trees(rows, "healthy")
+            tail = [d for d in diagnose(rows) if d.code == "reqtrace_tail"]
+            if not tail:
+                return
+            top = sorted(client_ms)[-REQTRACE_SLOW_K:]
+            text = (
+                f"doctor tail-attribution fired on the healthy run: "
+                f"{tail[0].message[:160]}"
+            )
+            if sum(top) / len(top) < 1e3 * REQTRACE_TAIL_MIN_EXCESS_S:
+                errors.append(
+                    f"{text} — and the client's slowest requests took "
+                    f"{top} ms: no such tail was there"
+                )
+            else:
+                timing.append(text)
+
+        healthy = os.path.join(root, "healthy.jsonl")
+        fleet, logger, client_ms, timing = healthy_leg(healthy)
 
         # ---- front door: trace id rides wire + header and echoes ------
         tier = ServeTier(fleet, port=0).start()
@@ -219,6 +275,7 @@ def main() -> int:
         )[0]
         conn = http.client.HTTPConnection("127.0.0.1", tier.port,
                                           timeout=30)
+        t_sent = time.perf_counter()
         conn.request(
             "POST", "/v1/score_packed",
             body=encode_packed_request([row], trace=ctx),
@@ -226,6 +283,7 @@ def main() -> int:
         )
         resp = conn.getresponse()
         payload = resp.read()
+        client_ms.append(1e3 * (time.perf_counter() - t_sent))
         echoed = resp.getheader("X-XFlow-Trace") or ""
         if resp.status != 200:
             errors.append(f"packed trace request HTTP {resp.status}")
@@ -237,6 +295,7 @@ def main() -> int:
                 f"{ctx.trace_id:016x}"
             )
         ctx2 = fleet.reqtrace.mint()
+        t_sent = time.perf_counter()
         conn.request(
             "POST", "/v1/score",
             body=json.dumps({
@@ -251,6 +310,7 @@ def main() -> int:
         )
         resp = conn.getresponse()
         resp.read()
+        client_ms.append(1e3 * (time.perf_counter() - t_sent))
         echoed = resp.getheader("X-XFlow-Trace") or ""
         if not echoed.startswith(f"{ctx2.trace_id:016x}-"):
             errors.append(
@@ -293,14 +353,23 @@ def main() -> int:
         # ---- healthy stream: schema + trees + doctor stays clean ------
         tier.close()  # drains and closes the fleet
         logger.close()
-        hrows = load_jsonl(healthy)
-        errors.extend(f"healthy schema: {e}" for e in validate_rows(hrows))
-        check_trees(hrows, "healthy")
-        tail = [d for d in diagnose(hrows) if d.code == "reqtrace_tail"]
-        if tail:
-            errors.append(
-                f"doctor tail-attribution fired on the healthy run: "
-                f"{tail[0].message[:160]}"
+        judge_healthy(healthy, client_ms, timing)
+        for n in range(2, HEALTHY_ATTEMPTS + 1):
+            if errors or not timing:
+                break
+            print(
+                f"healthy leg {n - 1} of {HEALTHY_ATTEMPTS} read the host's "
+                f"clock, not the program: {timing}", file=sys.stderr,
+            )
+            again = os.path.join(root, f"healthy{n}.jsonl")
+            fleet, logger, client_ms, timing = healthy_leg(again)
+            fleet.close()
+            logger.close()
+            judge_healthy(again, client_ms, timing)
+        if not errors:  # then ``timing`` is the last of that many legs
+            errors.extend(
+                f"on each of {HEALTHY_ATTEMPTS} healthy legs: {t}"
+                for t in timing
             )
 
         # ---- slow leg: injected device stall -> doctor names device ---

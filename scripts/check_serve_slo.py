@@ -1,6 +1,5 @@
 """Serving SLO gate: judge a load-generator run's ``serve_bench`` row
-against explicit SLO thresholds — the serving-tier counterpart of
-scripts/check_bench_regress.py.
+against explicit SLO thresholds.
 
 The load generator (serve/loadgen.py, ``python -m xflow_tpu.serve
 loadgen``) is OPEN-loop: offered traffic arrives on its own clock, so
@@ -16,7 +15,7 @@ a verdict:
 * client-observed ``e2e_p99`` must stay under ``--max-p99-ms`` when
   given (0 disables: absolute latency on a degraded CI container
   measures the box, not the code — pass a bar only where the numbers
-  are trustworthy, exactly the check_bench_regress discipline);
+  are trustworthy);
 * ``achieved_qps / offered_qps_actual`` must reach
   ``--min-achieved-frac`` when given;
 * ``outstanding`` (admitted requests the tier never resolved before

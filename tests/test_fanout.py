@@ -389,47 +389,6 @@ def test_summarize_stream_spread_line(tmp_path, capsys):
     assert "backpressure stall 0.5s" in out
 
 
-def _bench_artifact(path, value, e2e=None, degraded=False):
-    row = {"metric": "m", "value": value, "backend": "cpu"}
-    if e2e is not None:
-        row["e2e_packed_examples_per_sec"] = e2e
-    if degraded:
-        row["degraded"] = True
-    path.write_text(json.dumps({"parsed": row}))
-
-
-def test_bench_regress_gates_e2e_packed(tmp_path, capsys):
-    """check_bench_regress.py's second gate: e2e_packed compares
-    against the best non-degraded prior that MEASURES it; a latest
-    artifact that stopped measuring it fails --strict instead of
-    silently ungating the metric."""
-    import scripts.check_bench_regress as cbr
-
-    _bench_artifact(tmp_path / "BENCH_r01.json", 100.0)  # no e2e metric
-    _bench_artifact(tmp_path / "BENCH_r02.json", 90.0, e2e=5000.0)
-    _bench_artifact(
-        tmp_path / "BENCH_r03.json", 80.0, e2e=9999.0, degraded=True
-    )
-    _bench_artifact(tmp_path / "BENCH_r04.json", 85.0, e2e=5100.0)
-    assert cbr.main(["--root", str(tmp_path)]) == 0
-    out = capsys.readouterr().out
-    # r02 (not the degraded r03's absurd 9999) is the e2e bar
-    assert "e2e_packed_examples_per_sec 5100 within" in out
-    assert "BENCH_r02.json (5000)" in out
-
-    # e2e regression: warn-only default, gates under --strict
-    _bench_artifact(tmp_path / "BENCH_r04.json", 85.0, e2e=1000.0)
-    assert cbr.main(["--root", str(tmp_path)]) == 0
-    assert "input-path regression" in capsys.readouterr().err
-    assert cbr.main(["--root", str(tmp_path), "--strict"]) == 1
-    capsys.readouterr()
-
-    # latest lost the metric entirely while priors measure it
-    _bench_artifact(tmp_path / "BENCH_r04.json", 85.0)
-    assert cbr.main(["--root", str(tmp_path), "--strict"]) == 1
-    assert "missing metric" in capsys.readouterr().err
-
-
 # -- tier-1 gate wiring -----------------------------------------------------
 
 

@@ -660,10 +660,9 @@ def test_doctor_flags_straggler_rank(tmp_path, capsys):
     assert main(["doctor", str(merged)]) == 0
 
 
-def test_doctor_recompile_suspicion_and_degraded_bench(tmp_path, capsys):
+def test_doctor_recompile_suspicion(tmp_path, capsys):
     """Bimodal step times (p99 >> p50, p90 near p50) past epoch 0 read
-    as recompile suspicion; a bench artifact with degraded: true is
-    called out."""
+    as recompile suspicion; smooth ones read clean."""
     from xflow_tpu.obs.__main__ import main
 
     m = tmp_path / "m.jsonl"
@@ -674,28 +673,16 @@ def test_doctor_recompile_suspicion_and_degraded_bench(tmp_path, capsys):
         # comfortably past the BIMODAL_MIN_EXCESS_S noise floor
         _epoch_row(1, p50=0.002, p90=0.0022, p99=0.06),
     ]) + "\n")
-    bench = tmp_path / "BENCH_x.json"
-    bench.write_text(json.dumps({
-        "parsed": {
-            "metric": "x_train_examples_per_sec", "value": 100.0,
-            "degraded": True, "backend": "cpu",
-            "last_good_artifact": "docs/artifacts/a.json",
-        }
-    }))
-    rc = main(["doctor", str(m), "--bench", str(bench)])
+    rc = main(["doctor", str(m)])
     text = capsys.readouterr().out
     assert rc == 1
     assert "recompile_suspicion" in text, text
-    assert "degraded_bench" in text, text
-    # smooth step times + healthy bench: clean
+    # smooth step times: clean
     m.write_text("\n".join(json.dumps(r) for r in [
         _run_header(0), _epoch_row(0), _epoch_row(1),
     ]) + "\n")
-    bench.write_text(json.dumps({"parsed": {
-        "metric": "x", "value": 100.0, "degraded": False,
-    }}))
     capsys.readouterr()
-    assert main(["doctor", str(m), "--bench", str(bench)]) == 0
+    assert main(["doctor", str(m)]) == 0
 
 
 def test_doctor_warmup_exemption_survives_merge(tmp_path, capsys):
@@ -723,14 +710,16 @@ def test_doctor_warmup_exemption_survives_merge(tmp_path, capsys):
 
 
 def test_compare_fail_on_regress(tmp_path, capsys):
-    """Satellite: `obs compare --fail-on-regress FRAC` exits 3 when B
-    fell more than FRAC below A — for bench artifacts and metrics
-    files alike."""
+    """`obs compare --fail-on-regress FRAC` exits 3 when the last run
+    of metrics file B fell more than FRAC below A's in examples/sec."""
     from xflow_tpu.obs.__main__ import main
 
-    a, b = tmp_path / "a.json", tmp_path / "b.json"
-    a.write_text(json.dumps({"parsed": {"metric": "m", "value": 1000.0}}))
-    b.write_text(json.dumps({"parsed": {"metric": "m", "value": 800.0}}))
+    a, b = tmp_path / "a.jsonl", tmp_path / "b.jsonl"
+    for path, seconds in ((a, 1.0), (b, 1.25)):  # 640 and 512 ex/s
+        rows = [_run_header(0)] + [
+            {**_epoch_row(e), "seconds": seconds} for e in (0, 1)
+        ]
+        path.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
     assert main(["compare", str(a), str(b)]) == 0  # no flag: report only
     capsys.readouterr()
     assert main([
@@ -764,72 +753,6 @@ def test_check_doctor_smoke_script():
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "OK" in proc.stdout
-
-
-def test_check_bench_regress_script():
-    """Tier-1 wiring for scripts/check_bench_regress.py: warn-only by
-    default (degraded containers must not hard-fail CI), strict mode
-    gates."""
-    import subprocess
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "scripts", "check_bench_regress.py")],
-        capture_output=True,
-        text=True,
-        timeout=120,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "comparing latest" in proc.stdout or "SKIP" in proc.stdout
-
-
-def _bench_artifact(path, value, degraded=False):
-    row = {
-        "metric": "e2e_packed_examples_per_sec",
-        "value": value,
-        "backend": "cpu" if degraded else "tpu",
-    }
-    if degraded:
-        row["degraded"] = True
-    with open(path, "w") as f:
-        json.dump({"parsed": row}, f)
-
-
-def test_bench_regress_degraded_baseline_skipped(tmp_path, capsys):
-    """Baseline selection contract (BENCH_r05 is committed degraded):
-    degraded rounds never become the bar — the best NON-degraded prior
-    does — and the LATEST artifact is always the one under comparison,
-    so a new bench (the store bench, r06+) lands against the right
-    prior even when the round before it was a broken container."""
-    import scripts.check_bench_regress as cbr
-
-    # r01 good (the true bar), r02 degraded with an absurd value that
-    # would fail any honest comparison, r03 = the latest under test
-    _bench_artifact(tmp_path / "BENCH_r01.json", 100.0)
-    _bench_artifact(tmp_path / "BENCH_r02.json", 99999.0, degraded=True)
-    _bench_artifact(tmp_path / "BENCH_r03.json", 95.0)
-    rc = cbr.main(["--root", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "BENCH_r02" not in out.split("comparing latest")[1].split(":")[0]
-    assert "best prior" in out and "BENCH_r01.json" in out
-    assert "BENCH_r03.json" in out.split("comparing latest")[1]
-
-    # a real regression against the non-degraded bar: warn-only by
-    # default, gating under --strict
-    _bench_artifact(tmp_path / "BENCH_r03.json", 50.0)
-    assert cbr.main(["--root", str(tmp_path)]) == 0
-    err = capsys.readouterr().err
-    assert "WARN" in err and "regression" in err
-    assert cbr.main(["--root", str(tmp_path), "--strict"]) == 1
-
-    # every prior degraded: fall back rather than skip silently
-    _bench_artifact(tmp_path / "BENCH_r01.json", 100.0, degraded=True)
-    _bench_artifact(tmp_path / "BENCH_r03.json", 99000.0)
-    rc = cbr.main(["--root", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "every prior bench artifact is degraded" in out
 
 
 def test_doctor_shed_storm_and_canary_stuck(tmp_path, capsys):

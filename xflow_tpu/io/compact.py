@@ -1,9 +1,10 @@
 """Host-side batch compaction: the CompactBatch form and the dictionary
 wire format (Config.wire_dedup).
 
-BENCH_r05 measured the packed pipeline link-bound at ~130 wire
-bytes/example while compute sustained 6x more examples/sec — the classic
-terabyte-scale-trainer gap, and the classic fix: compress and
+Round 5's CPU bench (CHANGES.md, PR 5) measured the packed pipeline
+link-bound at ~130 wire bytes/example while compute sustained 6x more
+examples/sec — the classic terabyte-scale-trainer gap, and the classic
+fix: compress and
 deduplicate the sparse traffic on the host BEFORE it crosses the link
 (arXiv:2201.05500), exploiting the zipf skew instead of shipping raw
 (key, val) pairs (Parallax, arXiv:1808.02621).  The host is idle

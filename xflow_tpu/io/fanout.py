@@ -1,8 +1,9 @@
 """Parallel sharded input fan-out: N concurrent shard-reader streams
 with a deterministic, serial-order merge (ROADMAP item 1).
 
-One reader stream was the last measured input bottleneck (BENCH_r05:
-compute far ahead of the packed e2e feed): read, parse and host
+One reader stream was the last measured input bottleneck (round 5's
+CPU bench, CHANGES.md PR 5/14: compute far ahead of the packed e2e
+feed): read, parse and host
 compaction all serialized behind a single thread while the device
 waited.  Parallel sharded host feeds are table stakes for sparse CTR
 training at scale — Parallax's sparsity-aware data parallelism

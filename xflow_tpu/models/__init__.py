@@ -3,8 +3,8 @@
 The reference dispatches models by positional index (main.cc:27-45,
 argv[3] '0'→LR '1'→FM '2'→MVM); this repo's five-then-seven families
 used to be re-enumerated as string literals in config validation, the
-CLI choices, the C-ABI docs, and the bench scripts — adding a family
-meant a scavenger hunt.  Now a family registers HERE once:
+CLI choices and the C-ABI docs — adding a family meant a scavenger
+hunt.  Now a family registers HERE once:
 
 * ``build`` — Config -> Model instance (the only constructor callers
   use; serve/engine.py, trainer.py, the C ABI all route through
@@ -16,11 +16,11 @@ meant a scavenger hunt.  Now a family registers HERE once:
   surface with an actionable error instead of scoring garbage.
 
 ``Config.__post_init__`` validates ``cfg.model`` against
-``model_names()``, ``xflow_tpu.train`` builds its ``--model`` choices
-from it, and ``scripts/bench_models.py`` enumerates it (a registered
-family without a bench geometry fails that script loudly) — so a new
-family is config-valid, CLI-reachable, C-ABI-servable, and
-bench-tracked by virtue of this one entry.
+``model_names()`` and ``xflow_tpu.train`` builds its ``--model``
+choices from it — so a new family is config-valid, CLI-reachable and
+C-ABI-servable by virtue of this one entry.  It is MEASURED when a
+``model_config`` PR gives it a configuration and a cell under
+``benchmarks/`` (BENCHMARK.json), not before.
 """
 
 from __future__ import annotations

@@ -1,18 +1,17 @@
 """CLI: ``python -m xflow_tpu.obs <summarize|validate|compare|merge|doctor>``
 
     summarize run.jsonl       phase/throughput/percentile tables per run
-    compare   a b             side-by-side diff: metrics JSONL files
-                              (last run each) or bench artifacts
-                              (BENCH_r*.json); --fail-on-regress FRAC
-                              exits 3 when B's throughput fell more
-                              than FRAC below A's
+    compare   a b             side-by-side diff of two metrics JSONL
+                              files (last run each);
+                              --fail-on-regress FRAC exits 3 when B's
+                              throughput fell more than FRAC below A's
     validate  run.jsonl       strict schema check (exit 1 on violations)
     merge     a.jsonl b.jsonl combine per-host metrics files into one
                               rank-tagged, time-aligned stream
                               (--out FILE, default stdout)
     doctor    run.jsonl       ranked diagnosis of a sick (or healthy)
                               run: stall causes, stragglers, recompile
-                              suspicion (--flight DUMP, --bench JSON);
+                              suspicion (--flight DUMP);
                               exit 0 only when clean
     live      run.jsonl ...   streaming doctor: tail growing metrics
                               files, run the doctor checks plus the
@@ -45,7 +44,7 @@ def main(argv: list[str] | None = None) -> int:
     pv = sub.add_parser("validate", help="strict schema check")
     pv.add_argument("path")
     pc = sub.add_parser(
-        "compare", help="diff two metrics files or bench artifacts"
+        "compare", help="diff two metrics files"
     )
     pc.add_argument("path_a")
     pc.add_argument("path_b")
@@ -55,7 +54,7 @@ def main(argv: list[str] | None = None) -> int:
         default=None,
         metavar="FRAC",
         help="exit 3 when B's throughput is more than FRAC (e.g. 0.05) "
-        "below A's — the scripts/check_bench_regress.py gate",
+        "below A's",
     )
     pm = sub.add_parser(
         "merge", help="combine per-host metrics files into one stream"
@@ -66,9 +65,6 @@ def main(argv: list[str] | None = None) -> int:
     pd.add_argument("path", help="metrics JSONL (single-host or merged)")
     pd.add_argument(
         "--flight", default="", help="flight dump (Config.obs_flight_out)"
-    )
-    pd.add_argument(
-        "--bench", default="", help="bench artifact (BENCH_r*.json)"
     )
     pl = sub.add_parser(
         "live", help="streaming doctor over growing metrics files"
@@ -159,11 +155,7 @@ def main(argv: list[str] | None = None) -> int:
         from xflow_tpu.obs.doctor import doctor
 
         try:
-            text, rc = doctor(
-                args.path,
-                flight_path=args.flight or None,
-                bench_path=args.bench or None,
-            )
+            text, rc = doctor(args.path, flight_path=args.flight or None)
         except (ValueError, OSError) as e:
             print(f"error: {e}", file=sys.stderr)
             return 2

@@ -7,9 +7,9 @@ plus the row's relative ``t``), and rows sort globally by that clock.
 Extra fields are schema-compatible (validators check required fields
 only), so a merged file still passes ``obs validate``.
 
-``doctor`` reads one run stream (merged or single-host), optionally a
-flight dump (obs/flight.py) and a bench artifact, and prints a RANKED
-diagnosis instead of raw JSONL:
+``doctor`` reads one run stream (merged or single-host) and optionally a
+flight dump (obs/flight.py), and prints a RANKED diagnosis instead of
+raw JSONL:
 
 * watchdog ``health`` rows → dominant stall cause with trip counts and
   worst silence;
@@ -38,8 +38,7 @@ diagnosis instead of raw JSONL:
   ``health`` causes: fault storm vs isolated recovery, with
   ``quarantine_budget_exceeded`` (data corruption, not an input
   stall) and unrevived ``replica_evicted`` blamed by name
-  (docs/ROBUSTNESS.md);
-* bench artifact → degraded-bench detection (``degraded: true``).
+  (docs/ROBUSTNESS.md).
 
 Severity ranks ``crit`` > ``warn`` > ``info``; the CLI exits 0 only
 when nothing at ``warn`` or above surfaced — "run one command, get a
@@ -1064,36 +1063,8 @@ def _check_flight(flight: dict) -> list[Diagnosis]:
     return out
 
 
-def _check_bench(bench: dict) -> list[Diagnosis]:
-    parsed = bench.get("parsed") if isinstance(bench, dict) else None
-    row = parsed if isinstance(parsed, dict) else bench
-    if not isinstance(row, dict) or "value" not in row:
-        return [Diagnosis(
-            "info", "bench_unreadable",
-            "bench artifact has no parsed result row — run bench.py "
-            "to completion first",
-        )]
-    if row.get("degraded"):
-        return [Diagnosis(
-            "warn",
-            "degraded_bench",
-            f"degraded bench: {row.get('metric', '?')} = "
-            f"{row.get('value')} measured on backend "
-            f"{row.get('backend', '?')!r} (degraded environment — not "
-            "comparable to the committed trajectory; last good: "
-            f"{row.get('last_good_artifact', '?')})",
-        )]
-    return [Diagnosis(
-        "info", "bench_ok",
-        f"bench: {row.get('metric', '?')} = {row.get('value')} on "
-        f"{row.get('backend', '?')} (not degraded)",
-    )]
-
-
 def diagnose(
-    rows: list[dict],
-    flight: dict | None = None,
-    bench: dict | None = None,
+    rows: list[dict], flight: dict | None = None
 ) -> list[Diagnosis]:
     """Every check, ranked most-severe-first (stable within rank)."""
     findings: list[Diagnosis] = []
@@ -1124,8 +1095,6 @@ def diagnose(
     findings.extend(_check_streams(rows))
     findings.extend(_check_bimodality(rows))
     findings.extend(_check_store(rows))
-    if bench is not None:
-        findings.extend(_check_bench(bench))
     preempted = sum(
         1 for _, e in _epoch_rows(rows) if e.get("preempted")
     )
@@ -1163,22 +1132,14 @@ def format_diagnosis(
     return "\n".join(out)
 
 
-def doctor(
-    path: str,
-    flight_path: str | None = None,
-    bench_path: str | None = None,
-) -> tuple[str, int]:
+def doctor(path: str, flight_path: str | None = None) -> tuple[str, int]:
     """(report text, exit code): 0 clean, 1 when anything at warn or
     above surfaced."""
     from xflow_tpu.obs.flight import load_dump
 
     rows = load_jsonl(path)
     flight = load_dump(flight_path) if flight_path else None
-    bench = None
-    if bench_path:
-        with open(bench_path) as f:
-            bench = json.load(f)
-    findings = diagnose(rows, flight=flight, bench=bench)
+    findings = diagnose(rows, flight=flight)
     text = format_diagnosis(path, rows, findings)
     bad = any(d.severity in ("crit", "warn") for d in findings)
     return text, 1 if bad else 0

@@ -350,54 +350,20 @@ def summarize(path: str) -> str:
     return "\n".join(parts)
 
 
-def load_bench_result(path: str) -> dict | None:
-    """The result row of a committed bench artifact (BENCH_r*.json:
-    one JSON object whose ``parsed`` field holds the metric row), or
-    None when the file isn't one — `compare` uses this to accept bench
-    artifacts next to metrics JSONL."""
-    import json
-
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-    except ValueError:
-        return None  # multi-line JSONL etc. — not a bench artifact
-    if not isinstance(doc, dict):
-        return None
-    row = doc.get("parsed", doc)
-    if isinstance(row, dict) and "value" in row and "metric" in row:
-        return row
-    return None
-
-
-def throughput_of(path: str) -> tuple[float, str]:
-    """(examples/sec, source label) for either file format: a bench
-    artifact's parsed metric value, or the LAST run's throughput in a
-    metrics JSONL file."""
-    bench = load_bench_result(path)
-    if bench is not None:
-        label = bench.get("metric", "bench")
-        if bench.get("degraded"):
-            label += " [degraded]"
-        return float(bench["value"]), str(label)
-    run = _last_run(path)
-    return run.throughput(), "examples/sec (last run)"
-
-
 def check_regress(path_a: str, path_b: str, frac: float) -> str | None:
     """Regression verdict comparing B (candidate) against A (baseline):
     an error string when B's throughput fell more than ``frac`` below
     A's, else None.  ``frac`` is a fraction (0.05 = fail on a >5%
     drop)."""
-    a, label_a = throughput_of(path_a)
-    b, label_b = throughput_of(path_b)
+    a = _last_run(path_a).throughput()
+    b = _last_run(path_b).throughput()
     if a <= 0:
         return None  # no baseline signal — nothing to gate on
     drop = (a - b) / a
     if drop > frac:
         return (
-            f"REGRESS: {path_b} ({label_b}) = {b:.0f} is "
-            f"{100 * drop:.1f}% below {path_a} ({label_a}) = {a:.0f} "
+            f"REGRESS: {path_b} = {b:.0f} examples/sec (last run) is "
+            f"{100 * drop:.1f}% below {path_a} = {a:.0f} "
             f"(--fail-on-regress {frac})"
         )
     return None
@@ -411,31 +377,7 @@ def _last_run(path: str) -> Run:
 
 
 def compare(path_a: str, path_b: str) -> str:
-    """Side-by-side comparison of the LAST run in each file.  Bench
-    artifacts (BENCH_r*.json) compare on their parsed metric row."""
-    ba, bb = load_bench_result(path_a), load_bench_result(path_b)
-    if ba is not None and bb is not None:
-        widths = [34, 14, 14, 8]
-        out = [
-            f"A: {path_a}  ({ba.get('metric', '?')}"
-            f"{' [degraded]' if ba.get('degraded') else ''})",
-            f"B: {path_b}  ({bb.get('metric', '?')}"
-            f"{' [degraded]' if bb.get('degraded') else ''})",
-            "",
-            _fmt_row(["metric", "A", "B", "delta"], widths),
-        ]
-        keys = [
-            k for k in ba
-            if isinstance(ba.get(k), (int, float))
-            and isinstance(bb.get(k), (int, float))
-            and not isinstance(ba[k], bool)
-            and not isinstance(bb[k], bool)
-        ]
-        for k in keys:
-            a, b = float(ba[k]), float(bb[k])
-            d = f"{100.0 * (b - a) / a:+.1f}%" if a else "n/a"
-            out.append(_fmt_row([k, f"{a:g}", f"{b:g}", d], widths))
-        return "\n".join(out)
+    """Side-by-side comparison of the LAST run in each file."""
     ra = _last_run(path_a)
     rb = _last_run(path_b)
     out = [f"A: {path_a}  ({ra.label()})", f"B: {path_b}  ({rb.label()})", ""]

@@ -833,7 +833,8 @@ class TrainStep:
             self.dict_wire = False
         # Observability hook (obs/__init__.py): the trainer swaps in a
         # live Obs; the default NULL_OBS makes every span a shared no-op
-        # object, so direct users (bench.py run()) pay nothing.
+        # object, so direct users (serve/engine.py, chip_smoke.py, the
+        # probes under scripts/) pay nothing.
         self.obs = NULL_OBS
         self.train = jax.jit(self._train_impl, donate_argnums=0)
         self.predict = jax.jit(self._predict_impl)
@@ -1049,8 +1050,9 @@ class TrainStep:
         """The host half of put_batch: the numpy planes that cross the
         link for ``batch`` under this step's wire format, plus the
         CompactBatch when the dict wire ran (None otherwise).  Shared
-        with bench.py's host-feed measurement so the measured per-batch
-        work is by construction exactly the training feed's."""
+        with the serving put (serve/engine.py::_put_packed) and with
+        benchmarks/aot_memory.py, so the planes they ship or size are by
+        construction exactly the training feed's."""
         from xflow_tpu.io.compact import CompactBatch
 
         if isinstance(batch, CompactBatch):

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache, placeable from outside.
 
-Every entry point (train, serve and stream CLIs, bench.py, the model
-and time-to-AUC benches, chip_smoke.py) calls ``enable_compile_cache``
+Every entry point (train, serve and stream CLIs, benchmarks/run.py
+through its harness, chip_smoke.py) calls ``enable_compile_cache``
 before its first compile, so a second run of the same geometry — and a
 second process of the same run — loads its programs instead of
 compiling them.
