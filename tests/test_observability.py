@@ -1147,6 +1147,7 @@ _DENSE_MODELS = {
     "wide_deep": dict(emb_dim=4, hidden_dim=8),
     "two_tower": dict(emb_dim=4, hidden_dim=8, tower_dim=4, tower_split_field=4),
     "xdeepfm": dict(emb_dim=10, hidden_dim=8, cross_layers=3, cin_maps=6, deep_layers=2),
+    "autoint": dict(emb_dim=16, attn_heads=2, attn_dim=4, cross_layers=2),
 }
 
 
@@ -1167,8 +1168,9 @@ def test_a_dense_family_runs_its_dense_half_under_xf_dense(toy_dataset, model):
     rows = _op_scope_rows(toy_dataset, model=model, **_DENSE_MODELS[model])
     scopes = {scope for _, _, scope in rows}
     assert {"xf.dense", "xf.forward_backward", "xf.optimizer"} <= scopes
-    assert scopes <= SCOPES | {"xf.dense", "xf.cin", ""}
+    assert scopes <= SCOPES | {"xf.dense", "xf.cin", "xf.attn", ""}
     assert ("xf.cin" in scopes) == (model == "xdeepfm")
+    assert ("xf.attn" in scopes) == (model == "autoint")
 
 
 def test_xf_cin_is_the_innermost_name_of_every_cin_operation(toy_dataset):
@@ -1208,6 +1210,46 @@ def test_xf_cin_is_the_innermost_name_of_every_cin_operation(toy_dataset):
     assert [p for p in dots if p in forward] and [p for p in dots if p in backward]
     assert not [p for p in dots if "rematted_computation" in p]
     assert sum(scope == "xf.cin" for _, _, scope in rows) >= 10
+
+
+def test_xf_attn_is_the_innermost_name_of_every_attention_operation(toy_dataset):
+    """AutoInt's interacting layers run under ``xf.attn``, a sibling of
+    ``xf.dense`` inside ``xf.forward_backward``, through a loop over slices
+    and a backward that computes each slice's forward again.  In the compiled
+    step every operation whose path holds ``xf.attn`` has it as the INNERMOST
+    ``xf.`` name, whatever wraps it, and all three kinds are there: the
+    forward's inside its loop, the forward done again inside the backward's
+    loop (``checkpoint/rematted_computation``), and the backward's; the
+    products (projections, scores, weighted sums) are among all three, the
+    softmax's ``exp`` among the first two, and ``op_scopes`` maps the
+    compiled instructions to the scope.  The output product stays
+    ``xf.dense``'s."""
+    from xflow_tpu.parallel.step import _SCOPE_RE, abstract_like, scope_of
+
+    cfg = _toy_cfg(toy_dataset, model="autoint", max_fields=8, **_DENSE_MODELS["autoint"])
+    with Trainer(cfg) as t:
+        batch, _, _ = next(iter(t.iter_train_batches(0, 0)))
+        arrays = t.step.put_batch(batch)
+        text = t.step.train.lower(
+            abstract_like(t.state), abstract_like(arrays)
+        ).compile().as_text()
+        rows = t.step.op_scopes(t.state, arrays)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    attn = [p for p in paths if "xf.attn" in p]
+    assert len(attn) >= 20
+    assert all(scope_of(p) == "xf.attn" for p in attn)
+    assert all(_SCOPE_RE.findall(p)[0] == "xf.forward_backward" for p in attn)
+    forward = [p for p in attn if "jvp(xf.attn)" in p and "transpose" not in p]
+    backward = [p for p in attn if "transpose(jvp(xf.attn))" in p]
+    again = [p for p in backward if "rematted_computation" in p]
+    assert forward and backward and again
+    assert all("while/body" in p and "/checkpoint/" in p for p in again)
+    for kind in (forward, again, [p for p in backward if p not in again]):
+        assert [p for p in kind if p.endswith("dot_general")]
+    assert [p for p in forward if p.endswith("/exp")]
+    assert [p for p in again if p.endswith("/exp")]
+    assert sum(scope == "xf.attn" for _, _, scope in rows) >= 20
+    assert sum(scope == "xf.dense" for _, _, scope in rows) >= 2
 
 
 @pytest.mark.parametrize("model, overrides", [
@@ -1251,6 +1293,10 @@ def test_scope_of_takes_the_innermost_name():
         "jit(f)/xf.forward_backward/transpose(jvp(xf.cin))/while/body/closed_call/"
         "checkpoint/rematted_computation/mul"
     ) == "xf.cin"
+    assert scope_of(
+        "jit(f)/xf.forward_backward/transpose(jvp(xf.attn))/while/body/closed_call/"
+        "checkpoint/rematted_computation/exp"
+    ) == "xf.attn"
 
 
 @pytest.mark.parametrize("model", sorted(_DENSE_MODELS))
@@ -1261,8 +1307,8 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
     those are the products the benchmark's reference reads off the dense
     arrays' shapes."""
     from benchmarks.harness import costs
-    from benchmarks.layer_metrics import cin_mxu_roofline
-    from benchmarks.reference import dcn_criteo, xdeepfm_criteo
+    from benchmarks.layer_metrics import attn_mxu_roofline, cin_mxu_roofline
+    from benchmarks.reference import autoint_criteo, dcn_criteo, xdeepfm_criteo
     from xflow_tpu.obs.schema import OPTIONAL, validate_rows
 
     metrics = tmp_path / "m.jsonl"
@@ -1279,6 +1325,8 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
             assert dcn_criteo.matmuls(shapes) == matmuls
         if model == "xdeepfm":
             assert xdeepfm_criteo.matmuls(shapes) == matmuls
+        if model == "autoint":
+            assert autoint_criteo.matmuls(shapes) == matmuls
         param_bytes = sum(a.size * 4 for a in dense.values())
     rows = [json.loads(line) for line in metrics.read_text().splitlines()]
     assert validate_rows(rows) == []
@@ -1291,12 +1339,30 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
     )["flops"]
     assert wire["dense_matmul_flops_per_step"] == want > 0
     # what else a family hands the step (Model.dense_counters) reaches the
-    # row under its own name: the slice of xDeepFM's CIN, and nothing of any
-    # other family.  The CIN's operations have ONE owner in the program, the
+    # row under its own name: the slice of xDeepFM's CIN, the operations,
+    # score bytes and slice of AutoInt's attention, and nothing of any other
+    # family.  The CIN's operations have ONE owner in the program, the
     # model's dense_matmuls; the benchmark's reader counts them again from
     # the configuration's fields, and the two agree
     own = set(wire) - {"dense_param_bytes", "dense_matmul_flops_per_step"}
     own = {k for k in own if k.startswith("dense_")}
+    if model == "autoint":
+        assert own == {
+            "dense_attn_flops", "dense_attn_score_bytes", "dense_attn_slice_rows",
+        } <= set(OPTIONAL["wire"])
+        assert wire["dense_attn_slice_rows"] == cfg.batch_size  # a toy batch goes whole
+        b, m, layers = cfg.batch_size, cfg.max_fields, cfg.cross_layers
+        assert wire["dense_attn_score_bytes"] == 4 * b * layers * 2 * cfg.attn_heads * m * m
+        # the block's operations: the model's declared products less the
+        # output's, and the benchmark reader's own count from the fields
+        assert wire["dense_attn_flops"] == 6 * b * sum(
+            k * n for k, n in matmuls[:-1]
+        ) == attn_mxu_roofline.attn_flops({
+            "batch_size": b, "emb_dim": cfg.emb_dim, "max_fields": m,
+            "attn_heads": cfg.attn_heads, "attn_dim": cfg.attn_dim,
+            "cross_layers": layers,
+        })
+        return
     if model != "xdeepfm":
         assert not own
         return

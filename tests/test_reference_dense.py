@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from benchmarks.harness import refcheck
-from benchmarks.reference import dcn_criteo, wide_deep, xdeepfm_criteo
+from benchmarks.reference import autoint_criteo, dcn_criteo, wide_deep, xdeepfm_criteo
 from xflow_tpu.config import Config
 from xflow_tpu.io.batch import make_batch
 from xflow_tpu.models import blocks, make_model
@@ -34,6 +34,15 @@ XDEEPFM = {
     "model": "xdeepfm", "emb_dim": xdeepfm_criteo.EMB_DIM, "hidden_dim": 16,
     "cross_layers": 3, "cin_maps": 8, "deep_layers": 2, "v_init_scale": 0.3,
     "sgd_lr": 0.05,
+}
+# rows drawn at 0.3 for xDeepFM's reason: the gradient of ``attn_q`` /
+# ``attn_k`` is of third order in the embeddings.  Eight fields, of which the
+# batches fill four and, as out-of-range ids of the other families, two more:
+# fields 5 and 6 are absent from every row
+AUTOINT = {
+    "model": "autoint", "emb_dim": autoint_criteo.EMB_DIM,
+    "attn_heads": autoint_criteo.HEADS, "attn_dim": 4, "cross_layers": 2,
+    "max_fields": 8, "v_init_scale": 0.3, "sgd_lr": 0.05,
 }
 
 
@@ -86,6 +95,8 @@ def _off(step: dict) -> set[str]:
     ({"model": "wide_deep", "hot_impl": "mxu"}, wide_deep),
     ({**XDEEPFM, "hot_impl": "seg"}, xdeepfm_criteo),
     ({**XDEEPFM, "hot_impl": "mxu"}, xdeepfm_criteo),
+    ({**AUTOINT, "hot_impl": "seg"}, autoint_criteo),
+    ({**AUTOINT, "hot_impl": "mxu"}, autoint_criteo),
 ], ids=lambda v: "-".join(
     str(v[k]) for k in ("model", "deep_layers", "hot_impl") if k in v
 ) if isinstance(v, dict) else v.__name__.rsplit(".", 1)[-1])
@@ -108,7 +119,16 @@ def test_program_step_agrees_with_the_dense_reference(fields, family):
         assert system.state["dense"]["cin_w2"].shape == (8, 8, MAX_FIELDS)
         # every CIN array moves by enough of its own float32 steps to be seen
         assert all(s["dense"][a]["update_ulps"] > 50 for s in got["steps"] for a in cin)
-    if cfg.model in ("dcn", "xdeepfm"):
+    if cfg.model == "autoint":
+        layers = range(1, cfg.cross_layers + 1)
+        attn = {f"attn_{p}{k}" for k in layers for p in "qkvr"}
+        assert arrays == attn | {"w_out", "b_out"}
+        assert system.state["dense"]["attn_q1"].shape == (autoint_criteo.EMB_DIM, 8)
+        assert system.state["dense"]["attn_k2"].shape == (8, 8)
+        assert system.state["dense"]["w_out"].shape == (8 * 8, 1)
+        # every projection moves by enough of its own float32 steps to be seen
+        assert all(s["dense"][a]["update_ulps"] > 1000 for s in got["steps"] for a in attn)
+    if cfg.model in ("dcn", "xdeepfm", "autoint"):
         assert family.matmuls(got["dense_shapes"]) == system.step.model.dense_matmuls()
     for step in got["steps"]:
         assert step["logloss_err"] <= refcheck.LOGLOSS_ATOL
@@ -132,12 +152,14 @@ def _freeze(system, array: str) -> None:
 @pytest.mark.parametrize("array, fields, family", [
     *((a, {**DCN, "deep_layers": 2}, dcn_criteo) for a in ["w1", "w2", "b2", "cross_w", "w_out"]),
     *((a, XDEEPFM, xdeepfm_criteo) for a in ["cin_w1", "cin_w2", "cin_w3"]),
+    *((a, AUTOINT, autoint_criteo) for a in ["attn_q2", "attn_k1", "attn_v2", "attn_r1"]),
 ], ids=lambda v: v if isinstance(v, str) else "")
 def test_a_dense_array_left_as_it_was_fails_by_that_array(array, fields, family):
     """A step that does not move one array of the two-layer program (an
     optimizer that skips it, a gradient that never reaches it) reads exactly
-    1 there in its first step and fails: DCN's arrays, and each of xDeepFM's
-    three-dimensional CIN weights.  (From the second step on the arrays
+    1 there in its first step and fails: DCN's arrays, each of xDeepFM's
+    three-dimensional CIN weights, and a query, a key, a value and a residual
+    projection of AutoInt's.  (From the second step on the arrays
     downstream of a frozen one see other gradients too.)"""
     system, batches, cfg = _system(**fields)
     _freeze(system, array)
@@ -492,3 +514,287 @@ def test_xdeepfm_survives_checkpoint_and_artifact_and_serves_the_reference(
         "--train", toy_dataset.train_prefix,
     ])
     assert (args.model, args.cin_maps, args.cross_layers) == ("xdeepfm", 6, 2)
+
+
+# -- AutoInt: field self-attention, a presence mask, a sliced stack -----------
+
+
+def _attention_case(b: int = 13, m: int = 8, d: int = 4, absent=(7,)):
+    """A tower [b, m, d] and its presence [b, m] (the fields of ``absent``
+    lacking from every row, two more lacking from a row each, as a dropped
+    entry leaves them), and two layers of two heads of 4; values of order 1."""
+    rng = np.random.default_rng(7)
+    present = np.ones((b, m), np.float32)
+    present[:, list(absent)] = 0.0
+    present[2, 1] = present[5, 3] = 0.0
+    tower = rng.normal(0, 0.5, (b, m, d)).astype(np.float32) * present[..., None]
+    weights = [
+        tuple(jnp.asarray(rng.normal(0, 0.4, (d_in, 8)), jnp.float32) for _ in "qkvr")
+        for d_in in (d, 8)
+    ]
+    return weights, jnp.asarray(tower), jnp.asarray(present)
+
+
+def _plain_attention(weights, tower, present, heads: int = 2):
+    """The interacting layers as their equations, the whole batch at once,
+    through the REFERENCE's layer (benchmarks/reference/autoint_criteo.py)."""
+    assert heads == autoint_criteo.HEADS
+    e = tower
+    for layer in weights:
+        e = autoint_criteo.layer(*layer, e, present > 0)
+    return e
+
+
+@pytest.mark.parametrize("slice_rows", [1, 4, 13], ids=["one-row", "uneven", "whole"])
+def test_sliced_attention_equals_the_plain_layers_in_value_and_gradients(slice_rows):
+    """``blocks.field_attention_stack`` (slices of the batch through
+    ``lax.map``, each slice's backward rematerialised) against the plain
+    layers over the whole batch: the fields' vectors, and the gradient of
+    every projection and of the tower, within 1e-6 of the largest, for a
+    slice of one row, a slice that does not divide the batch (the last is
+    padded with rows that have no field) and the whole batch."""
+    weights, tower, present = _attention_case()
+    want = _plain_attention(weights, tower, present)
+    got = blocks.field_attention_stack(weights, tower, present, 2, slice_rows)
+    assert got.shape == want.shape == (13, 8, 8)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6 * float(jnp.abs(want).max()))
+    mix = jnp.asarray(np.random.default_rng(8).normal(size=want.shape), jnp.float32)
+    grads = [
+        jax.grad(lambda w, t: jnp.sum(f(w, t) * mix), (0, 1))(weights, tower)
+        for f in (
+            lambda w, t: blocks.field_attention_stack(w, t, present, 2, slice_rows),
+            lambda w, t: _plain_attention(w, t, present),
+        )
+    ]
+    for a, b in zip(jax.tree.leaves(grads[0]), jax.tree.leaves(grads[1])):
+        np.testing.assert_allclose(a, b, atol=1e-6 * float(jnp.abs(b).max()), rtol=0)
+
+
+def _autoint_rows(model, rng, b: int = 6, k: int = 10):
+    """Gathered rows and a batch for ``model``: every row has one entry of
+    each of the first ``max_fields - 1`` fields (the last bucket is empty, as
+    the benchmark's 40th), and row 0 one entry more of field 2."""
+    m = model.max_fields
+    slots = np.tile(np.arange(k) % (m - 1), (b, 1)).astype(np.int32)
+    mask = np.ones((b, k), np.float32)
+    mask[:, m - 1:] = 0.0
+    mask[0, m - 1], slots[0, m - 1] = 1.0, 2
+    batch = {
+        "slots": jnp.asarray(slots), "vals": jnp.ones((b, k), jnp.float32),
+        "mask": jnp.asarray(mask),
+    }
+    rows = {"emb": jnp.asarray(rng.normal(0, 0.5, (b, k, model.emb_dim)), jnp.float32)}
+    return rows, batch
+
+
+def _autoint_model(**fields):
+    return make_model(Config(**{
+        "model": "autoint", "emb_dim": autoint_criteo.EMB_DIM, "attn_heads": 2,
+        "attn_dim": 4, "cross_layers": 2, "max_fields": 8, **fields,
+    }))
+
+
+def test_a_row_that_lacks_a_field_scores_as_the_reference_with_the_entry_deleted():
+    """A row whose entry of field 4 was dropped (value 0: what the capacity
+    rule leaves) scores what the reference scores for the row WITHOUT that
+    entry: field 4 takes no attention weight and adds nothing.  Were its key
+    left in (a zero vector at weight e^0) the logit would be another: the
+    unmasked layer differs by far more than the tolerance."""
+    model = _autoint_model()
+    rng = np.random.default_rng(3)
+    rows, batch = _autoint_rows(model, rng)
+    dense = jax.tree.map(
+        lambda a: a + 0.05, model.dense_init(jax.random.PRNGKey(2))
+    )
+    mask = np.array(batch["mask"])
+    mask[1, 4] = 0.0  # row 1 loses its entry of field 4
+    got = model.logit(rows, {**batch, "mask": jnp.asarray(mask)}, dense)
+    keep = np.array([c for c in range(10) if c != 4])  # deleted, not zeroed
+    x = np.array(batch["vals"] * batch["mask"])
+    want = autoint_criteo.logit(
+        {"emb": rows["emb"][1:2, keep]}, jnp.asarray(x[1:2, keep]),
+        batch["slots"][1:2, keep], model.max_fields, dense,
+    )
+    np.testing.assert_allclose(got[1], want[0], rtol=0, atol=2e-6)
+    whole = autoint_criteo.logit(
+        rows, jnp.asarray(x), batch["slots"], model.max_fields, dense
+    )
+    others = np.array([0, 2, 3, 4, 5])
+    np.testing.assert_allclose(got[others], whole[others], rtol=0, atol=2e-6)
+    assert abs(float(whole[1] - want[0])) > 1e-3  # the field's presence matters
+
+    # the mask left out: every key attended to, the empty bucket's too
+    import unittest.mock
+
+    masked = blocks.field_attention_layer
+
+    def unmasked(wq, wk, wv, wr, e, present, heads):
+        return masked(wq, wk, wv, wr, e, jnp.ones_like(present), heads)
+
+    with unittest.mock.patch.object(blocks, "field_attention_layer", unmasked):
+        off = model.logit(rows, batch, dense)
+    assert float(jnp.abs(off - whole).max()) > 1e-3
+
+
+def test_no_gradient_reaches_the_empty_buckets_slice_of_w_out():
+    """``max_fields`` counts a bucket more than the rows have fields (40 for
+    39): that field's ``e^Res`` is 0 on every row, so its slice of ``w_out``
+    sees no gradient, while every other slice does; and nothing that field
+    holds reaches the logit."""
+    model = _autoint_model()
+    rows, batch = _autoint_rows(model, np.random.default_rng(4))
+    dense = model.dense_init(jax.random.PRNGKey(2))
+    grads = jax.grad(lambda d: jnp.sum(jnp.sin(model.logit(rows, batch, d))))(dense)
+    by_field = np.asarray(grads["w_out"]).reshape(model.max_fields, model.width)
+    np.testing.assert_array_equal(by_field[-1], 0.0)
+    assert (np.abs(by_field[:-1]).max(axis=1) > 0.0).all()
+    assert all(float(jnp.abs(g).max()) > 0.0 for g in grads.values())
+
+
+def test_a_row_with_no_field_at_all_stays_finite():
+    """None in the benchmark's rows, possible in a user's (and what pads the
+    last slice): every key of the row is absent, the softmax has nothing to
+    normalise over, and the row's logit is ``b_out``; value and every
+    gradient stay finite."""
+    model = _autoint_model()
+    rows, batch = _autoint_rows(model, np.random.default_rng(5))
+    mask = np.array(batch["mask"])
+    mask[3] = 0.0
+    batch = {**batch, "mask": jnp.asarray(mask)}
+    dense = {**model.dense_init(jax.random.PRNGKey(2)), "b_out": jnp.full((1,), 0.25)}
+    logit = model.logit(rows, batch, dense)
+    assert float(logit[3]) == 0.25 and bool(jnp.isfinite(logit).all())
+    grads = jax.grad(
+        lambda r, d: jnp.sum(jnp.sin(model.logit(r, batch, d))), (0, 1)
+    )(rows, dense)
+    assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grads))
+    np.testing.assert_array_equal(grads[0]["emb"][3], 0.0)
+
+
+def test_attention_slice_is_sized_from_shapes_in_whole_lane_widths():
+    """``blocks.attn_slice_rows``: at the paper's Criteo sizes a slice's
+    activations (225 KiB an example over three layers) inside
+    ``ATTN_SLICE_BYTES``, a power of two that divides B = 16384 (no padded
+    slice, no copy of the stack's output); a small batch whole; the model
+    hands the same number to the stack and to the step's counters."""
+    rows = blocks.attn_slice_rows(16384, 40, 2, 32, 3)
+    assert rows % 128 == 0 and 16384 % rows == 0
+    assert rows * 4 * 3 * (5 * 40 * 64 + 2 * 2 * 40 * 40) <= blocks.ATTN_SLICE_BYTES
+    assert blocks.attn_slice_rows(64, 8, 2, 4, 2) == 64
+    assert blocks.attn_slice_rows(16384, 20, 2, 32, 3) == 2 * rows == 256  # fewer fields
+    model = _autoint_model(emb_dim=16, attn_dim=32, cross_layers=3, max_fields=40)
+    assert model.attn_slice_rows(16384) == rows
+    assert model.layer_inputs() == [16, 64, 64]
+    counters = model.dense_counters(16384)
+    assert counters == {
+        "dense.attn_flops": 6 * 16384 * 2088960,
+        "dense.attn_score_bytes": 4 * 16384 * 3 * 2 * 2 * 40 * 40,
+        "dense.attn_slice_rows": rows,
+    }
+    assert sum(k * n for k, n in model.dense_matmuls()) == 2091520
+    shapes = jax.eval_shape(model.dense_init, jax.random.PRNGKey(0))
+    assert len(shapes) == 14 and sum(a.size for a in shapes.values()) == 39425
+
+
+def test_config_refuses_attention_without_heads_or_width():
+    for field in ("attn_heads", "attn_dim"):
+        with pytest.raises(ValueError, match="attn_heads and attn_dim"):
+            Config(model="autoint", **{field: 0})
+
+
+def test_the_cells_own_constants_let_the_check_see_the_attention(capsys):
+    """``autoint_tb.train_packed``'s own configuration (its FTRL constants
+    over the batch size as xDeepFM's, ``sgd_lr`` 0.1 and the init: what the
+    toy cases above replace by rows drawn at 0.3), rehearsed at the paper's
+    widths: after two toy epochs every query and key projection already
+    moves by a thousand or more of its own float32 steps a step (on the chip,
+    after a window's training, by 3.9e4 or more: PERF.md section 2; at the
+    paper's 1e-3, Adam's there, by 5 to 90), and the control
+    (``benchmarks/control.py``: the reference with its operands rounded to
+    bfloat16) is out of ``DENSE_RTOL`` in EVERY one of the fourteen arrays."""
+    import json
+
+    from benchmarks import control
+    from benchmarks.harness import manifest
+
+    fields = manifest.config_file("benchmarks/configs/autoint_ftrl_criteo_tb.json")
+    assert fields["beta"] * fields["batch_size"] == 1.0
+    assert fields["lambda2"] * fields["batch_size"] == 10.0
+    assert fields["sgd_lr"] == 0.1
+    argv = ["--workload", "autoint_tb.train_packed", "--seed", "7",
+            "--seconds", "0.5", "--rehearsal"]
+    assert control.main(argv) == 0  # the control failed, as it must
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["checks_failed"] == ["steps_match_reference"]
+    compared = line["compared"]
+    arrays = [k.split(".", 1)[1] for k in compared if k.startswith("dense_rel_err.")]
+    assert len(arrays) == 14
+    for array in arrays:
+        assert compared[f"dense_update_ulps.{array}"]["value"] >= 1000
+        assert compared[f"dense_rel_err.{array}"]["value"] > 4 * refcheck.DENSE_RTOL
+
+
+def test_autoint_survives_checkpoint_and_artifact_and_serves_the_reference(
+    toy_dataset, tmp_path
+):
+    """The family through the rest of the system's normal path: it trains
+    through ``Trainer.train``, its fourteen dense arrays restore bit for bit
+    from the checkpoint, the engine loaded from the exported artifact (whose
+    batches carry the field ids the presence mask reads) scores a raw batch
+    as the trainer does AND as the benchmark's reference's ``logit`` does
+    from the trained state, and train.py's CLI reaches the two new fields."""
+    from xflow_tpu import train
+    from xflow_tpu.io.loader import ShardLoader
+    from xflow_tpu.serve.artifact import export_artifact
+    from xflow_tpu.serve.engine import PredictEngine
+    from xflow_tpu.trainer import Trainer
+
+    cfg = Config(
+        train_path=toy_dataset.train_prefix, test_path=toy_dataset.test_prefix,
+        model="autoint", emb_dim=autoint_criteo.EMB_DIM, attn_heads=2, attn_dim=4,
+        cross_layers=3, v_init_scale=0.3, sgd_lr=0.05, epochs=2, batch_size=64,
+        table_size_log2=14, max_nnz=24, max_fields=12, num_devices=1,
+        checkpoint_dir=str(tmp_path / "ck"),
+    )
+    with Trainer(cfg) as trainer:
+        drawn = jax.device_get(trainer.state["dense"])
+        trainer.train()
+        before = jax.device_get(trainer.state["dense"])
+        assert len(before) == 14 and before["attn_q3"].shape == (8, 8)
+        for name in ("attn_q1", "attn_k2", "attn_v3", "attn_r1", "w_out"):  # it trained
+            assert float(np.abs(before[name] - drawn[name]).max()) > 0.0
+        with Trainer(cfg) as again:
+            assert again.restore() is not None
+            jax.tree.map(
+                np.testing.assert_array_equal, before,
+                jax.device_get(again.state["dense"]),
+            )
+        art = str(tmp_path / "artifact")
+        export_artifact(trainer, art)
+        engine = PredictEngine.load(art, buckets=(64,), warm=True)
+        loader = ShardLoader(
+            cfg.test_path + "-00000", batch_size=cfg.batch_size,
+            max_nnz=cfg.max_nnz, table_size=cfg.table_size,
+            parse_fn=trainer._parse_fn(),
+        )
+        batch, _ = next(iter(loader.iter_batches()))
+        want = np.asarray(jax.device_get(trainer.step.predict(
+            trainer.state, trainer.step.put_batch(trainer.prepare_batch(batch))
+        )))
+        got = engine.predict(batch)
+        np.testing.assert_allclose(got, want, atol=1e-6)
+        keys, x, slots = refcheck.entries(trainer.prepare_batch(batch))
+        tables = jax.device_get(trainer.state["tables"])
+        rows = {t: jnp.asarray(tables[t]["param"][keys]) for t in tables}
+        ref = autoint_criteo.logit(
+            rows, jnp.asarray(x), jnp.asarray(slots), cfg.max_fields, before
+        )
+        real = batch.weights > 0
+        np.testing.assert_allclose(
+            got[real], np.asarray(jax.nn.sigmoid(ref))[real], atol=2e-6
+        )
+    args = train.build_parser().parse_args([
+        "--model", "autoint", "--attn-heads", "4", "--attn-dim", "16",
+        "--cross-layers", "2", "--train", toy_dataset.train_prefix,
+    ])
+    assert (args.model, args.attn_heads, args.attn_dim) == ("autoint", 4, 16)

@@ -74,6 +74,11 @@ class Config:
     # CIN's depth is cross_layers, its DNN deep_layers of hidden_dim (the
     # paper's Criteo setting: 3 layers of 200 beside 2 layers of 400).
     cin_maps: int = 16
+    # autoint (models/autoint.py): heads of an interacting layer and a
+    # head's width; the stack's depth is cross_layers (the paper's Criteo
+    # setting: 3 layers of 2 heads of 32 over embeddings of 16).
+    attn_heads: int = 2
+    attn_dim: int = 8
     # Static padded features-per-sample inside the jit step.  Samples with
     # more features than this are truncated (reference has no limit —
     # features-per-sample is whatever the text line holds).
@@ -516,6 +521,8 @@ class Config:
             raise ValueError("deep_layers must be >= 1")
         if self.cin_maps < 1:
             raise ValueError("cin_maps must be >= 1")
+        if self.attn_heads < 1 or self.attn_dim < 1:
+            raise ValueError("attn_heads and attn_dim must be >= 1")
         if self.optimizer not in ("ftrl", "sgd"):
             raise ValueError(f"unknown optimizer {self.optimizer!r}")
         if self.update_mode not in ("dense", "sparse", "sequential"):

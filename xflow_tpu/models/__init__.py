@@ -28,6 +28,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable
 
+from xflow_tpu.models.autoint import AutoIntModel
 from xflow_tpu.models.base import AutodiffModel, Model, TableSpec
 from xflow_tpu.models.dcn import DCNModel
 from xflow_tpu.models.ffm import FFMModel
@@ -160,6 +161,19 @@ register_model(ModelFamily(
     "xDeepFM ranker: a compressed interaction network (vector-wise "
     "crosses of bounded degree) beside an MLP over the embedding tower",
 ))
+register_model(ModelFamily(
+    "autoint",
+    lambda cfg: AutoIntModel(
+        emb_dim=cfg.emb_dim,
+        attn_heads=cfg.attn_heads,
+        attn_dim=cfg.attn_dim,
+        cross_layers=cfg.cross_layers,
+        max_fields=cfg.max_fields,
+        v_init_scale=cfg.v_init_scale,
+    ),
+    "AutoInt ranker: multi-head self-attention over the fields of a row "
+    "(per-example interactions, softmax over the fields the row has)",
+))
 
 
 __all__ = [
@@ -176,6 +190,7 @@ __all__ = [
     "TwoTowerModel",
     "DCNModel",
     "XDeepFMModel",
+    "AutoIntModel",
     "make_model",
     "model_family",
     "model_names",

@@ -338,18 +338,30 @@ def ffm_field_interaction(
 CIN_SCOPE = "xf.cin"
 
 # The most a slice's pair tensor may take: what ``cin_slice_rows`` sizes
-# the slices of the batch from.  Fitted at ONE shape, the paper's Criteo
+# the slices of the batch from.  Read at ONE shape, the paper's Criteo
 # sizes on a v5e (D = 10, m = 40, 200 maps, B = 16384: 312.5 KiB of pairs
 # an example), where it yields the best slice of five measured, forward and
 # backward of the stack alone, ms by examples a slice
 # (scripts/probe_cin_slice.py, PR 43): 64: 156.0, 128: 152.8, 256: 163.0,
-# 512: 175.4, 1024: 175.8.  The byte rule between those points, and at any
-# other width, is a guess that keeps a slice's pairs about that size; a
-# family at other sizes runs the probe at its own.
+# 512: 175.4, 1024: 175.8.  Between those points and at any other width the
+# rule (``_slice_rows``: as many examples as keep a slice about that size)
+# is unmeasured; a family at other sizes runs the probe at its own.
 CIN_PAIR_BYTES = 64 << 20
-# A slice's examples lie along the lanes of every array of the block, so
-# a slice of part of the batch holds a multiple of the lane width.
+# A slice of part of the batch holds a multiple of the lane width: the
+# CIN lays a slice's examples along the lanes of every array it makes.
 _LANES = 128
+
+
+def _slice_rows(batch: int, example_bytes: int, slice_bytes: int) -> int:
+    """The one byte rule of the two sliced blocks (``cin_stack``,
+    ``field_attention_stack``): as many examples as keep
+    ``example_bytes`` each inside ``slice_bytes``, in whole lane widths and
+    at least one; the whole batch where that is fewer.  The budgets are
+    measured, each at one shape (``CIN_PAIR_BYTES``: xDeepFM's paper sizes,
+    PR 43; ``ATTN_SLICE_BYTES``: AutoInt's, PR 47); the rule between is
+    not."""
+    rows = slice_bytes // example_bytes // _LANES * _LANES
+    return min(batch, max(rows, _LANES))
 
 
 def cin_slice_rows(batch: int, dim: int, max_fields: int, maps: int) -> int:
@@ -360,8 +372,20 @@ def cin_slice_rows(batch: int, dim: int, max_fields: int, maps: int) -> int:
     the slice is decided: the model hands it to ``cin_stack`` and the
     step books it (``dense.cin_slice_rows``)."""
     per_example = 4 * max(maps, max_fields) * max_fields * dim
-    rows = CIN_PAIR_BYTES // per_example // _LANES * _LANES
-    return min(batch, max(rows, _LANES))
+    return _slice_rows(batch, per_example, CIN_PAIR_BYTES)
+
+
+def _to_slices(x: jax.Array, slice_rows: int) -> jax.Array:
+    """``[B, ...] -> [slices, slice_rows, ...]``: the batch cut into whole
+    slices, zero rows padding the last."""
+    slices = -(-x.shape[0] // slice_rows)
+    pad = ((0, slices * slice_rows - x.shape[0]),) + ((0, 0),) * (x.ndim - 1)
+    return jnp.pad(x, pad).reshape(slices, slice_rows, *x.shape[1:])
+
+
+def _from_slices(y: jax.Array, batch: int) -> jax.Array:
+    """``[slices, slice_rows, ...] -> [B, ...]``: the padding cut off."""
+    return y.reshape(y.shape[0] * y.shape[1], *y.shape[2:])[:batch]
 
 
 def cin_layer(w: jax.Array, xk: jax.Array, x0: jax.Array) -> jax.Array:
@@ -408,10 +432,8 @@ def cin_stack(
     adds D aligned blocks of lanes."""
     b, m, d = tower.shape
     s = slice_rows
-    slices = -(-b // s)
-    x0 = jnp.pad(tower, ((0, slices * s - b), (0, 0), (0, 0)))
     # [slices, m, D * s]: slice c, field j, lane d * s + e <- example c*s + e
-    x0 = x0.reshape(slices, s, m, d).transpose(0, 2, 3, 1).reshape(slices, m, d * s)
+    x0 = _to_slices(tower, s).transpose(0, 2, 3, 1).reshape(-1, m, d * s)
 
     @functools.partial(
         jax.checkpoint,
@@ -426,4 +448,123 @@ def cin_stack(
         return jnp.concatenate(pooled, axis=0)
 
     p = jax.lax.map(one_slice, x0)  # [slices, sum H_k, s]
-    return p.transpose(0, 2, 1).reshape(slices * s, -1)[:b]
+    return _from_slices(p.transpose(0, 2, 1), b)
+
+
+# -- field self-attention (AutoInt) -------------------------------------------
+
+# The device scope of the interacting layers (docs/OBSERVABILITY.md): a
+# sibling of xf.dense and xf.cin inside xf.forward_backward.
+ATTN_SCOPE = "xf.attn"
+
+# The most a slice's activations may take as numbers (every layer's four
+# projections, scores, weights and output: what the slice's backward holds
+# at once): what ``attn_slice_rows`` sizes the slices of the batch from.
+# Read at ONE shape, AutoInt's paper sizes on a v5e (m = 40, 3 layers of 2
+# heads of 32, d = 16, B = 16384: 225 KiB an example), where it yields the
+# best slice of five measured, forward and backward of the stack alone, ms
+# by examples a slice (scripts/probe_attn_slice.py, PR 47): 128: 93.8,
+# 256: 96.0, 512: 96.5, 1024: 107.2, 2048: 127.6 (the program's
+# temporaries 0.16, 0.16, 0.28, 0.58, 1.16 GiB).  Flat to 512, so the
+# constant only has to stay under the rise; at another shape the rule
+# (``_slice_rows``) is unmeasured, as the CIN's is.
+ATTN_SLICE_BYTES = 32 << 20
+
+
+def attn_slice_rows(
+    batch: int, max_fields: int, heads: int, head_dim: int, layers: int
+) -> int:
+    """How many examples a slice of ``field_attention_stack`` holds, from
+    shapes: as many as keep the slice's activations (a layer's Q, K, V and
+    residual projection, ``4 m H d'`` floats an example, its scores and
+    weights ``2 H m m`` and its output ``m H d'``, times the layers)
+    inside ``ATTN_SLICE_BYTES``, in whole lane widths; the whole batch
+    where that is fewer.  THE place the slice is decided: the model hands
+    it to the stack and the step books it (``dense.attn_slice_rows``)."""
+    width = heads * head_dim
+    per_example = 4 * layers * (
+        5 * max_fields * width + 2 * heads * max_fields * max_fields
+    )
+    return _slice_rows(batch, per_example, ATTN_SLICE_BYTES)
+
+
+def field_presence(x: jax.Array, slots: jax.Array, num_fields: int) -> jax.Array:
+    """``[B, F]``, 1.0 where the row has an entry of that field and 0.0
+    where it has none: the one-hot ``field_sum_tower`` sums the embeddings
+    by (the same expression: one array in the compiled step), taken over
+    the entries whose value is not 0 (padding, or an entry the capacity
+    rule dropped, ships 0).  No gather: the field ids are in the batch.
+    What a softmax over fields needs and a sum never did: an absent field
+    is a zero row of the tower, which adds nothing to a sum and takes
+    weight e^0 as a key."""
+    onehot = jax.nn.one_hot(slots, num_fields, dtype=x.dtype)  # [B, K, F]
+    return jnp.max(onehot * (x != 0)[..., None].astype(x.dtype), axis=1)
+
+
+def field_attention_layer(
+    wq: jax.Array, wk: jax.Array, wv: jax.Array, wr: jax.Array,
+    e: jax.Array, present: jax.Array, heads: int,
+) -> jax.Array:
+    """One interacting layer over a slice: ``e [s, m, d_l]`` the fields'
+    vectors, ``present [s, m]`` (1.0 / 0.0), the four ``[d_l, H * d']``
+    projections -> ``[s, m, H * d']``:
+
+        psi[h, i, j] = <W_Q^h e_i, W_K^h e_j>
+        alpha[h, i, :] = softmax of psi[h, i, :] over the PRESENT fields j
+        out_i = ReLU([sum_j alpha[h, i, j] W_V^h e_j]_h + W_Res e_i) * present_i
+
+    (AutoInt's equations 5-8; no 1 / sqrt(d'), the paper has none).  The
+    four projections are one product with the weights side by side; the
+    scores and the weighted sum are per-example products of two
+    activations, batched over (example, head).  Every product is float32
+    on every backend (Precision.HIGHEST, as ``dense_dot``: no operand is
+    exact in bfloat16); the softmax is float32 with the row's largest
+    score over the present keys taken off first.  An absent field's key
+    gets -inf, so it takes weight 0, and its own output row is 0; a row
+    with NO present field gives zeros (the sum of its weights is held off
+    0), not NaN."""
+    s, m, _ = e.shape
+    proj = dense_dot(e, jnp.concatenate([wq, wk, wv, wr], axis=1))
+    *qkv, res = jnp.split(proj, 4, axis=-1)
+    q, k, v = (a.reshape(s, m, heads, -1) for a in qkv)
+    hi = jax.lax.Precision.HIGHEST
+    psi = jnp.einsum("sihc,sjhc->shij", q, k, precision=hi)
+    psi = jnp.where(present[:, None, None, :] > 0, psi, -jnp.inf)
+    top = jax.lax.stop_gradient(jnp.max(psi, axis=-1, keepdims=True))
+    ex = jnp.exp(psi - jnp.where(jnp.isfinite(top), top, 0.0))
+    total = jnp.sum(ex, axis=-1, keepdims=True)
+    alpha = ex / jnp.maximum(total, jnp.finfo(ex.dtype).tiny)
+    mixed = jnp.einsum("shij,sjhc->sihc", alpha, v, precision=hi)
+    return jax.nn.relu(mixed.reshape(res.shape) + res) * present[..., None]
+
+
+@jax.named_scope(ATTN_SCOPE)
+def field_attention_stack(
+    weights: list[tuple[jax.Array, jax.Array, jax.Array, jax.Array]],
+    tower: jax.Array, present: jax.Array, heads: int, slice_rows: int,
+) -> jax.Array:
+    """AutoInt's interacting layers over the field tower ``[B, m, d]`` ->
+    the fields' vectors after the last layer ``[B, m, H * d']``;
+    ``weights[l] = (W_Q, W_K, W_V, W_Res)`` of layer l + 1, each
+    ``[d_l, H * d']``; ``present [B, m]`` from ``field_presence``.  A
+    layer's projections, scores and weights are ``5 m H d' + 2 H m m``
+    floats an example (16 000 at the paper's sizes: 3.1 GB over three
+    layers at B = 16384 as numbers, two to three times that in (8,128)
+    tiles), so they exist for ``slice_rows`` examples at a time, forward
+    and backward: the batch goes through ``lax.map`` slice by slice (zero
+    rows with no present field pad the last, give zeros and are cut off),
+    and each slice's backward computes its forward again
+    (``jax.checkpoint``, nothing kept but the slice's tower and
+    presence: a kept projection would be a ``[B, m, H d']`` array
+    again)."""
+    @functools.partial(jax.checkpoint, prevent_cse=False)  # as cin_stack's
+    def one_slice(args: tuple[jax.Array, jax.Array]) -> jax.Array:
+        e, p = args
+        for wq, wk, wv, wr in weights:
+            e = field_attention_layer(wq, wk, wv, wr, e, p, heads)
+        return e
+
+    out = jax.lax.map(
+        one_slice, (_to_slices(tower, slice_rows), _to_slices(present, slice_rows))
+    )
+    return _from_slices(out, tower.shape[0])

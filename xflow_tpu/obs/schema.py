@@ -433,6 +433,14 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # block holds the pair tensor for (blocks.cin_slice_rows); B over
         # it is the trips of the CIN's loops a step
         "dense_cin_slice_rows": (int, float),
+        # a family with interacting layers only (Model.dense_counters of
+        # models/autoint.py), from shapes: the block's operations forward
+        # and backward (the output product left out), the bytes a step's
+        # attention scores and weights WOULD take whole, and the examples
+        # a slice of the block holds them for (blocks.attn_slice_rows)
+        "dense_attn_flops": (int, float),
+        "dense_attn_score_bytes": (int, float),
+        "dense_attn_slice_rows": (int, float),
         # of wire_bytes_per_example, the planes of field ids (slots_u8 /
         # hot_slots_u8, the dictionary wire's cw_cs / cw_hs, the full
         # wire's slots / hot_slots): 0 where none ships, as for a model

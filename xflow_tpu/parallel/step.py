@@ -81,10 +81,11 @@ def scope_of(op_name: str) -> str:
     INNERMOST one, the last ``xf.<name>`` anywhere in the path (autodiff
     and scan wrap path components: ``transpose(jvp(xf.dense))``), ``""``
     where the path has none.  A scope opened inside another is the more
-    specific name for its operations: the dense half ``xf.dense`` and
-    the CIN ``xf.cin`` (models/blocks.py) run inside
-    ``xf.forward_backward`` and are read apart from it, the CIN's also
-    through its loop and its rematerialised backward
+    specific name for its operations: the dense half ``xf.dense``, the
+    CIN ``xf.cin`` and the interacting layers ``xf.attn``
+    (models/blocks.py) run inside ``xf.forward_backward`` and are read
+    apart from it, the CIN's and the attention's also through their loop
+    and their rematerialised backward
     (``transpose(jvp(xf.cin))/while/body/closed_call/checkpoint/...``).
     Until PR 39 the first name won; no program without a dense half
     nests two different scopes, so theirs map as they did
@@ -808,7 +809,8 @@ class TrainStep:
             k * n for k, n in (model.dense_matmuls() if owns_dense else [])
         )
         # what else the family wants booked of its dense half, from
-        # shapes (Model.dense_counters: xDeepFM's slice of the CIN)
+        # shapes (Model.dense_counters: xDeepFM's slice of the CIN, AutoInt's
+        # attention operations, score bytes and slice)
         self._dense_counters: dict[str, int] = (
             model.dense_counters(cfg.batch_size) if owns_dense else {}
         )
@@ -938,7 +940,8 @@ class TrainStep:
         else a family hands the step (Model.dense_counters) is booked
         beside them under the family's own names: xDeepFM's
         ``dense.cin_slice_rows``, the examples a slice of its CIN holds
-        the pair tensor for."""
+        the pair tensor for; AutoInt's ``dense.attn_flops``,
+        ``dense.attn_score_bytes`` and ``dense.attn_slice_rows``."""
         if self._dense_param_bytes:
             self.obs.counter("dense.param_bytes", self._dense_param_bytes)
             self.obs.counter("dense.matmul_flops", self._dense_matmul_flops)

@@ -62,7 +62,8 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--cross-layers", type=int, dest="cross_layers",
-        help="dcn: explicit cross-network depth; xdeepfm: CIN depth",
+        help="dcn: explicit cross-network depth; xdeepfm: CIN depth; "
+        "autoint: interacting layers",
     )
     p.add_argument(
         "--deep-layers", type=int, dest="deep_layers",
@@ -71,6 +72,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--cin-maps", type=int, dest="cin_maps",
         help="xdeepfm: feature maps a CIN layer holds",
+    )
+    p.add_argument(
+        "--attn-heads", type=int, dest="attn_heads",
+        help="autoint: attention heads of an interacting layer",
+    )
+    p.add_argument(
+        "--attn-dim", type=int, dest="attn_dim",
+        help="autoint: width of one attention head",
     )
     p.add_argument("--max-nnz", type=int, dest="max_nnz")
     p.add_argument("--max-fields", type=int, dest="max_fields")
