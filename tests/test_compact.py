@@ -12,6 +12,7 @@ import os
 import numpy as np
 import pytest
 import jax
+import jax.numpy as jnp
 
 from xflow_tpu.config import Config
 from xflow_tpu.io.batch import make_batch
@@ -871,55 +872,210 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
-def test_dict_wire_train_step_scatters_each_table_once():
-    """The dense step's cold scatter has one form: per table ONE
-    scatter-add of the B * max_nnz cold slots into its [T, D] buffer,
-    and no sort or segment-sum ahead of it (merging duplicates first
-    lost on the chip: docs/PERF.md "Cold consolidation").  Traced with
-    the MXU head, whose gradient is matmuls plus one add of rows
+@pytest.mark.parametrize("wire,microbatch", [
+    ("dict", 1), ("dict", 4), ("compact", 1),
+])
+def test_dict_wire_train_step_scatters_each_table_once(wire, microbatch):
+    """The dense step's cold scatter, per table ONE scatter-add into its
+    [T, D] buffer, and how many indices it hands it (PR 48).  A whole
+    dictionary-wire batch: a table wider than one column takes
+    cap(cu) + cap(ct) indices, one per dictionary and tail entry, after
+    ONE scatter-add of the B * max_nnz slots into the [cap(cu), D]
+    dictionary buffer (step.py::dict_cold_grads); the one-column table
+    keeps an index per padded slot.  No KEY is sorted for it: the one
+    sort in the step orders the B * max_nnz POSITIONS under ``is_tail``
+    (a single operand; merging duplicate keys by a sort lost on the chip:
+    docs/PERF.md "Cold consolidation").  The same rows over the plain
+    compact wire, and the dictionary wire cut into microbatch slices
+    (which go without the plan), keep the parent's form: every table an
+    index per padded slot of the batch or slice, no sort at all.  Traced
+    with the MXU head, whose gradient is matmuls plus one add of rows
     [0, H); the "seg" head other backends run is a segment-sum."""
     from xflow_tpu.models import make_model
     from xflow_tpu.optim import make_optimizer
     from xflow_tpu.parallel.mesh import make_mesh
     from xflow_tpu.parallel.step import TrainStep, init_state
 
-    batch, table, hot_size, _ = _decode_case("zipf", "u12", 4)
+    batch, table, hot_size, dict_cap = _decode_case("zipf", "u12", 4)
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
     b, kc = batch.batch_size, batch.max_nnz
+    caps = len(cb.cu) + len(cb.ct)
+    assert len(cb.cu) and len(cb.ct) and caps < b * kc
     cfg = Config(
         model="fm", batch_size=b, max_nnz=kc,
         table_size_log2=table.bit_length() - 1, num_devices=1,
         hot_size_log2=hot_size.bit_length() - 1, hot_nnz=batch.hot_nnz,
-        wire_dedup="on", hot_impl="mxu",
+        wire_dedup="on" if wire == "dict" else "off", hot_impl="mxu",
+        microbatch=microbatch,
     )
     mesh = make_mesh(1)
     model, opt = make_model(cfg), make_optimizer(cfg)
     step = TrainStep(model, opt, cfg, mesh)
-    assert step.dict_wire
+    assert step.wire_format == wire
     state = init_state(model, opt, cfg, mesh)
     eqns = list(_eqns(jax.make_jaxpr(step._train_impl)(
-        state, step.put_batch(batch)
+        state, step.put_batch(cb if wire == "dict" else batch)
     ).jaxpr))
-    assert not [e for e in eqns if e.primitive.name == "sort"]
+    through_dict = wire == "dict" and microbatch == 1
+    sorts = [
+        [v.aval.shape for v in e.invars]
+        for e in eqns if e.primitive.name == "sort"
+    ]
+    assert sorts == ([[(b * kc,)]] if through_dict else []), sorts
     shapes = {
         name: t["param"].shape for name, t in state["tables"].items()
     }
     assert len(shapes) == 2  # w [T, 1] and v [T, D]
+    d = shapes["v"][1]
     adds = [
         tuple(v.aval.shape for v in e.invars[:2])
         for e in eqns if e.primitive.name == "scatter-add"
     ]
-    # nothing accumulates anywhere but in a table's gradient buffer
-    assert {operand for operand, _ in adds} == set(shapes.values()), adds
-    for shape in shapes.values():
-        cold = [i for o, i in adds if o == shape and i[0] == b * kc]
-        assert len(cold) == 1, (shape, adds)
+    # nothing accumulates anywhere but in a table's gradient buffer and,
+    # on the route, in the dictionary's
+    buffers = set(shapes.values())
+    if through_dict:
+        buffers.add((len(cb.cu), d))
+        assert adds.count(((len(cb.cu), d), (b * kc, 1))) == 1, adds
+    assert {operand for operand, _ in adds} == buffers, adds
+    slots = b * kc // microbatch
+    for name, shape in shapes.items():
+        # (the head's rows [0, H) join the buffer as one add at index 0)
+        cold = [i[0] for o, i in adds if o == shape and i != (1,)]
+        want = caps if through_dict and name == "v" else slots
+        assert cold == [want], (name, adds)
 
 
-@pytest.mark.parametrize("wire,microbatch", [
-    ("dict", 1), ("dict", 4), ("compact", 1),
+def _scatter_case(data, hot, d, integers, lane_select):
+    """(the [T, D] buffer the dictionary route leaves, the per-slot
+    scatter-add's, the float64 sums) for one decode case and made-up
+    occurrence gradients, a masked slot's left non-zero.  The planes of
+    the dictionary and of the tail are three and five entries LONGER than
+    the batch needs (zeros, as TrainStep._settle_planes pads them), and
+    without a head one live cold key is 0, the key every masked slot
+    decodes to."""
+    from xflow_tpu.parallel.step import (
+        dict_cold_grads, dict_scatter_plan, expand_dict_wire,
+    )
+
+    batch, table, hot_size, dict_cap = _decode_case(data, hot, 3, table_log2=16)
+    if not hot_size and data != "all_padding":
+        batch.keys[3, 0] = 0  # row 3 is at full K
+    cb = CompactBatch.from_batch(batch, table, hot_size, dict_cap=dict_cap)
+    step = _decode_step(
+        "lr", table, hot_size, batch.batch_size, batch.max_nnz,
+        batch.hot_nnz,
+    )
+    wire = cb.wire(False)
+    for name, extra in (("cw_cu", 3), ("cw_ct", 5)):
+        plane = wire[name]
+        wire[name] = np.pad(
+            plane, [(0, extra)] + [(0, 0)] * (plane.ndim - 1)
+        )
+    m = batch.keys.size
+    rng = np.random.default_rng(d + len(data))
+    occ = (
+        rng.integers(-8, 9, (m, d)) if integers
+        else rng.standard_normal((m, d))
+    ).astype(np.float32)
+
+    def both(w, o):
+        planes = expand_dict_wire(step.cfg, lane_select, w)
+        keys_eff = jnp.where(
+            planes["mask"] > 0, planes["keys"], table
+        ).reshape(-1)
+        zeros = jnp.zeros((table, d), jnp.float32)
+        splan = dict_scatter_plan(planes["cold_plan"], table, lane_select)
+        assert splan["rows"].shape == (len(cb.cu) + 3 + len(cb.ct) + 5,)
+        return (
+            zeros.at[splan["rows"]].add(
+                dict_cold_grads(splan, o), mode="drop"
+            ),
+            zeros.at[keys_eff].add(o, mode="drop"),
+        )
+
+    got, want = jax.device_get(jax.jit(both)(wire, occ))
+    live = batch.mask.reshape(-1) > 0
+    exact = np.zeros((table, d))
+    np.add.at(exact, batch.keys.reshape(-1)[live], occ[live].astype(np.float64))
+    return got, want, exact
+
+
+@pytest.mark.parametrize("d", [1, 10, 26])
+@pytest.mark.parametrize("data", _DECODE_DATA)
+@pytest.mark.parametrize("hot", ["none", "u12"])
+def test_dict_cold_scatter_equals_per_slot_sums(hot, data, d):
+    """The gradient buffer by the dictionary route (the occurrences of a
+    dictionary key summed first, the tail's rows picked in stream order,
+    cap(cu) + cap(ct) indices handed to the table: step.py::
+    dict_scatter_plan, dict_cold_grads) equals the scatter-add per
+    padded slot: exactly on integer-valued gradients, whose sums no
+    order of adds can change, and within 1e-6 of the largest float64
+    sum on random ones.  No dictionary beside a tail, no tail, nothing
+    at all, rows at max_nnz, capacities that are no multiple of 128,
+    planes longer than their content (the padding scatters nowhere: a
+    clipped row would land on row 0, which the no-head cases make a live
+    key), masked slots whose gradient is not zero."""
+    from xflow_tpu.ops import window
+
+    got, want, exact = _scatter_case(
+        data, hot, d, True, window.lane_select_xla
+    )
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, exact)
+    if data != "all_padding":
+        assert np.abs(got).sum() > 0
+    got, want, exact = _scatter_case(
+        data, hot, d, False, window.lane_select_xla
+    )
+    scale = max(np.abs(exact).max(), 1.0)
+    assert np.abs(got - exact).max() <= 1e-6 * scale
+    assert np.abs(want - exact).max() <= 1e-6 * scale
+
+
+@pytest.mark.parametrize("data", ["zipf", "tail_only", "odd_caps"])
+def test_dict_cold_scatter_tpu_form_interpreted(data):
+    """The same as a TPU traces the route: the dictionary index of each
+    slot through the Mosaic lane shuffle (run here by the Pallas TPU
+    interpreter)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from xflow_tpu.ops import window
+
+    with pltpu.force_tpu_interpret_mode():
+        got, want, exact = _scatter_case(
+            data, "u12", 10, True, window.lane_select_tpu
+        )
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, exact)
+
+
+@pytest.mark.parametrize("model", ["fm", "mvm"])
+def test_dict_scatter_route_leaves_the_state_of_the_expanded_batch(model):
+    """Three dense steps on a dictionary-wire batch, whose D > 1 table
+    takes its cold gradients through the dictionary (PR 48), leave every
+    table, the metrics and the trainer's eval where the same steps on
+    the expanded batch over the plain compact wire (a scatter-add per
+    padded slot) leave them: the same float32 adds of the same numbers
+    in another order, so to 1e-6 and not by bits (on this backend, which
+    adds in index order, they are the same bits: a dictionary key's
+    occurrences meet an empty buffer either way)."""
+    got, want = _dict_and_expanded_states(model, 3, "u12")
+    jax.tree.map(
+        lambda a, c: np.testing.assert_allclose(a, c, rtol=1e-6, atol=1e-7),
+        got, want,
+    )
+    tables = got[0]["tables"]
+    assert any(t["param"].shape[1] > 1 for t in tables.values())
+    assert all(np.abs(t["z"]).sum() > 0 for t in tables.values())
+
+
+@pytest.mark.parametrize("wire,microbatch,model", [
+    ("dict", 1, "lr"), ("dict", 4, "lr"), ("compact", 1, "lr"),
+    ("dict", 1, "fm"), ("dict", 4, "fm"),
 ])
 def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
-    toy_dataset, tmp_path, wire, microbatch
+    toy_dataset, tmp_path, wire, microbatch, model
 ):
     """The epoch's ``wire`` row says how far the dictionary route
     engages, from shapes: ``table_gather_indices_per_step`` beside
@@ -927,12 +1083,16 @@ def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
     the dictionary wire's plan hands the table the dictionary's and the
     tail's capacities; a plain-compact batch, and a dictionary-wire
     batch cut into microbatch slices (which go without the plan), a row
-    per padded slot: ratio 1.0."""
+    per padded slot: ratio 1.0.  ``table_scatter_indices_per_step`` is
+    the way back, summed over the tables (PR 48): the same capacities
+    for a table wider than one column where the step read the plan (FM's
+    ``v``), the padded slots for a one-column table (LR's and FM's
+    ``w``) and for every table of every other batch."""
     from xflow_tpu.obs import schema
     from xflow_tpu.trainer import Trainer
 
     cfg = Config(
-        model="lr", train_path=toy_dataset.train_prefix, epochs=1,
+        model=model, train_path=toy_dataset.train_prefix, epochs=1,
         batch_size=64, table_size_log2=14, max_nnz=24, num_devices=1,
         wire_dedup="on" if wire == "dict" else "off",
         microbatch=microbatch, metrics_out=str(tmp_path / "m.jsonl"),
@@ -954,14 +1114,20 @@ def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
         trainer.close()
     row = stats["_wire"]
     slots = row["padded_cold_slots_per_step"]
+    tables = {"lr": 1, "fm": 2}[model]
     assert slots == 64 * 24
     if wire == "dict" and microbatch == 1:
         assert len(caps) == stats["steps"]
         assert row["table_gather_indices_per_step"] == round(
             sum(caps) / len(caps)
         ) < slots
+        # w per padded slot; v, where there is one, per entry
+        assert row["table_scatter_indices_per_step"] == round(
+            slots + (tables - 1) * sum(caps) / len(caps)
+        ) <= tables * slots
     else:
         assert row["table_gather_indices_per_step"] / slots == 1.0
+        assert row["table_scatter_indices_per_step"] == tables * slots
     assert not schema.validate_row({"t": 0.0, "kind": "wire", **row})
 
 

@@ -523,6 +523,87 @@ def mvm_cell_step(topo):
         return cfg, lowered, lowered.compile()
 
 
+@pytest.fixture(scope="module")
+def dcn_cell_step(topo):
+    """(cfg, step, lowered, compiled): the DCN train step at the geometry
+    of the benchmark's dcn_tb.train_packed for a described v5e, compiled
+    once (1 min here) for the tests that read it."""
+    with _without_compile_cache():
+        cfg, step, lowered = _lowered_cell_step(
+            topo, "dcn_ftrl_criteo_tb", DCN_PLANES
+        )
+        return cfg, step, lowered, lowered.compile()
+
+
+@pytest.fixture(scope="module")
+def autoint_cell_step(topo):
+    """(cfg, step, lowered, compiled): the AutoInt train step at the
+    geometry of the benchmark's autoint_tb.train_packed for a described
+    v5e, compiled once (40 s here) for the tests that read it."""
+    with _without_compile_cache():
+        cfg, step, lowered = _lowered_cell_step(
+            topo, "autoint_ftrl_criteo_tb", AUTOINT_PLANES
+        )
+        return cfg, step, lowered, lowered.compile()
+
+
+def _scatters(text: str) -> list[tuple[str, str, str]]:
+    """(operand, indices, updates) types of every scatter of a compiled
+    program, read off the signature of the fused computation that holds
+    it: ``(param_0: f32[T,D], param_1: s32[n], param_2: f32[n,D])``."""
+    found = []
+    for block in text.split("\n\n"):
+        if " scatter(" not in block:
+            continue
+        head = next(
+            line for line in block.splitlines() if line.rstrip().endswith("{")
+        )
+        params = re.findall(r"param_[\d.]+: (\w+\[[\d,]*\])", head)
+        assert len(params) == 3, head
+        found.append(tuple(params))
+    return found
+
+
+# the cell's fixture, its widest table's name, the program peak of the
+# parent's step (PR 47's tree, compiled here for the same described v5e)
+@pytest.mark.parametrize("cell,planes,wide,parent_gib", [
+    ("mvm_cell_step", MVM_PLANES, "v", 9.189),
+    ("dcn_cell_step", DCN_PLANES, "emb", 9.316),
+    ("autoint_cell_step", AUTOINT_PLANES, "emb", 8.018),
+])
+def test_cold_scatter_hands_a_wide_table_an_index_per_dictionary_and_tail_entry_on_v5e(
+    request, cell, planes, wide, parent_gib
+):
+    """PR 48, compiled for a described v5e at the plane capacities of one
+    real batch of mvm_tb.train_packed, dcn_tb.train_packed and
+    autoint_tb.train_packed: the scatter-add into the [T, D] gradient
+    buffer of the cell's wide table takes cap(cu) + cap(ct) indices and
+    as many float32 rows (MVM 305 152 of 1 048 576 padded slots, DCN
+    172 032 of 524 288, AutoInt 55 296 of 131 072), never an operand of
+    a padded plane's height; the padded slots' [B * max_nnz, D]
+    gradients meet only the [cap(cu), D] dictionary buffer
+    (step.py::dict_cold_grads); DCN's one-column ``w`` keeps an index
+    per padded slot on its flat view; and the program's peak stays
+    within 0.2 GiB of the parent's."""
+    cfg, *_, compiled = request.getfixturevalue(cell)
+    cap_u, cap_t = planes["cw_cu"][0][0], planes["cw_ct"][0][0]
+    t, m = cfg.table_size, cfg.batch_size * cfg.max_nnz
+    d = {"v": cfg.v_dim, "emb": cfg.emb_dim}[wide]
+    assert cap_u + cap_t < m
+    scatters = _scatters(compiled.as_text())
+    into_table = [s for s in scatters if s[0] == f"f32[{t},{d}]"]
+    assert into_table == [(
+        f"f32[{t},{d}]", f"s32[{cap_u + cap_t}]", f"f32[{cap_u + cap_t},{d}]"
+    )], scatters
+    by_slot = [s for s in scatters if s[2].startswith(f"f32[{m}")]
+    narrow = [(f"f32[{t}]", f"s32[{m}]", f"f32[{m}]")] if cell == "dcn_cell_step" else []
+    assert by_slot == [
+        (f"f32[{cap_u},{d}]", f"s32[{m}]", f"f32[{m},{d}]")
+    ] + narrow, scatters
+    peak = _program_peak(compiled) / (1 << 30)
+    assert abs(peak - parent_gib) < 0.2, peak
+
+
 def test_mvm_step_contracts_fields_in_float32_and_fits_a_v5e(mvm_cell_step):
     """The MVM train step at the geometry of the benchmark's
     mvm_tb.train_packed for a described v5e (``mvm_cell_step``).
@@ -763,14 +844,15 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
 # sha256 of the lowered train program (StableHLO text, the Mosaic kernels'
 # serialized bodies blanked: they embed the checkout's path) of the four
 # configurations the benchmark measured before PR 39, pinned on PR 38's
-# tree BEFORE models/blocks.py was edited, anew by PR 44, and MVM's and
-# FM's again by PR 45 (the test's docstring says why).
+# tree BEFORE models/blocks.py was edited, anew by PR 44, MVM's and
+# FM's again by PR 45, and MVM's, DCN's and xDeepFM's again by PR 48 (the
+# tests' docstrings say why).
 MEASURED_PROGRAMS_SHA256 = {
     "lr_ftrl_criteo_tb": (
         "e288dfde6bd0a7646d26153ef9b2ad0ba6d6a5056fe6a02cc9440b498b84d5bc"
     ),
     "mvm_ftrl_criteo_tb": (
-        "3a4bddc61a84a8b2ea5cc85afac456d118f9592c3e2344b719fcbda66dde35ac"
+        "2a8d53c47ed6328a8605cd365c3e448c86db0d6bb9f37924f1eba2e367947be5"
     ),
     "ffm_ftrl_criteo_tb": (
         "c40a3f4e9f8eb87e924e7c25a3a14818b91f19dddf1bc6414dc08d2214084d54"
@@ -781,12 +863,13 @@ MEASURED_PROGRAMS_SHA256 = {
     # the two measured programs built on models/blocks.py's dense half,
     # pinned by PR 47 on PR 46's tree BEFORE blocks.py was edited (and equal
     # after: cin_stack's padding and slicing went into two helpers that the
-    # attention block shares)
+    # attention block shares); anew by PR 48, whose cold scatter route
+    # their emb tables take
     "dcn_ftrl_criteo_tb": (
-        "745ca978e48f2790b8f0aa3dae38cdb3bb72033d8d9373e02a0f6a2244791562"
+        "cddc66f4c60728fd1ac8d2531cdf7bd7dcaac008b37e86d7c188f9d7d7db2db9"
     ),
     "xdeepfm_ftrl_criteo_tb": (
-        "d3e4c9327ecb3ef87daf8aecedaecd81336452b5cfbb8a2d042ab7ef6c549bde"
+        "2593f4a8abee8f284e9a4dbdbad8eee6dcfe3d9dfc0e306f5ff0c58fe52dda18"
     ),
 }
 DENSE_PROGRAMS = {
@@ -821,7 +904,15 @@ def test_measured_train_programs_lower_to_the_pinned_text(topo):
     [H, D] slice at D = 10 (MVM's v, FM's v), the scan at D = 1 (LR's w,
     FM's and FFM's w; FFM's v is off the head), so LR's and FFM's digests
     are PR 44's, the control that every D = 1 head runs the parent's
-    program, and the other two are pinned anew."""
+    program, and the other two are pinned anew.  PR 48 meant to change
+    MVM's and NOT LR's, FFM's and the cut FM mesh's: the cold gradients
+    of a whole dictionary-wire batch reach a table of 2 to 64 columns
+    through the batch's dictionary (step.py::dict_cold_grads,
+    DICT_SCATTER_COLUMNS: MVM's v), and a one-column table (LR's w,
+    FFM's w), a 160-column one (FFM's v) and every batch on a mesh keep
+    the scatter-add per padded slot: those three digests are PR 45's and
+    PR 44's, the control that the cells which bypass the route run the
+    parent's program, and MVM's is pinned anew."""
     got = {
         "lr_ftrl_criteo_tb": _lowered_cell_step(
             topo, "lr_ftrl_criteo_tb", LR_PLANES, ships_slots=False
@@ -858,14 +949,17 @@ def test_measured_dense_programs_lower_to_the_pinned_text(topo, config):
     ``field_sum_tower``, ``cin_stack``), which the four digests above do
     not cover: an edit of that file that moves one of them says so here.
     PR 47 added the field-attention block beside them and lifted
-    ``cin_stack``'s padding and slicing into helpers; both programs lower
-    to the text PR 46's tree lowered them to."""
+    ``cin_stack``'s padding and slicing into helpers; both programs
+    lowered to the text PR 46's tree lowered them to.  PR 48 meant to
+    change both (emb, 26 and 10 columns, takes the cold scatter's
+    dictionary route; models/blocks.py is untouched) and pinned them
+    anew."""
     lowered = _lowered_cell_step(topo, config, DENSE_PROGRAMS[config])[2]
     assert _program_sha256(lowered) == MEASURED_PROGRAMS_SHA256[config]
 
 
 def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
-    topo, no_compile_cache
+    dcn_cell_step
 ):
     """The DCN train step at the geometry of the benchmark's
     dcn_tb.train_packed (benchmarks/configs/dcn_ftrl_criteo_tb.json: 2^24
@@ -891,9 +985,7 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
     it: 16.47 G)."""
     from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
 
-    cfg, step, lowered = _lowered_cell_step(
-        topo, "dcn_ftrl_criteo_tb", DCN_PLANES
-    )
+    cfg, step, lowered, compiled = dcn_cell_step
     assert step._mxu_hot == {"w": True, "emb": True}
     assert (cfg.cross_layers, cfg.deep_layers, cfg.hidden_dim) == (6, 2, 1024)
     text = lowered.as_text().splitlines()
@@ -935,7 +1027,6 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
         f"tensor<{_PLAIN_GATHER_SLOTS}x1xi32>)" in of_head[0]
     ), of_head
 
-    compiled = lowered.compile()
     hlo = compiled.as_text()
     in_dense = [
         line for line in hlo.splitlines()
@@ -1066,7 +1157,7 @@ def test_xdeepfm_step_contracts_pairs_in_float32_a_slice_at_a_time_on_v5e(
 
 
 def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
-    topo, no_compile_cache
+    autoint_cell_step
 ):
     """The AutoInt train step at the geometry of the benchmark's
     autoint_tb.train_packed (benchmarks/configs/autoint_ftrl_criteo_tb.json:
@@ -1093,9 +1184,7 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
     from xflow_tpu.models import blocks
     from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
 
-    cfg, step, lowered = _lowered_cell_step(
-        topo, "autoint_ftrl_criteo_tb", AUTOINT_PLANES
-    )
+    cfg, step, lowered, compiled = autoint_cell_step
     assert step._mxu_hot == {"emb": True}
     assert (cfg.cross_layers, cfg.attn_heads, cfg.attn_dim, cfg.emb_dim) == (3, 2, 32, 16)
     b, m, heads, head = cfg.batch_size, cfg.max_fields, cfg.attn_heads, cfg.attn_dim
@@ -1136,7 +1225,6 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
     }
     assert max(map(elements, made)) < scores, sorted(made, key=elements)[-3:]
 
-    compiled = lowered.compile()
     hlo = compiled.as_text()
     arrays = {
         shape for shape in re.findall(r"= \(?f32\[([0-9,]+)\]", hlo)

@@ -400,6 +400,12 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # B * max_nnz (parallel/step.py::_book_wire)
         "table_gather_indices_per_step": (int, float),
         "padded_cold_slots_per_step": (int, float),
+        # the way back, SUMMED over the tables: the indices the cold
+        # scatter-adds hand the [T, D] gradient buffers: the same two
+        # capacities for a table whose gradients leave a dictionary-wire
+        # batch through its dictionary (step.py::dict_cold_grads, a
+        # table wider than one column), the padded slots for every other
+        "table_scatter_indices_per_step": (int, float),
         # beside them, a batch and from shapes: the bytes of [T, D] table
         # rows the step's gathers read and its scatter-adds read and
         # write (the MXU head's own traffic left out), and the hot-plane

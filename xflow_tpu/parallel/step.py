@@ -429,7 +429,8 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
     jitted step: rebuild the padded [B, K] planes from the flat
     tiered streams.  Beside the padded planes it hands on
     ``cold_plan``: what the host's dedup knows about the batch's cold
-    keys, for the cold row gather and nothing else (dict_cold_rows).
+    keys, for the cold row gather (dict_cold_rows) and the cold
+    gradients' way back (dict_scatter_plan) and nothing else.
     The dictionary ``cu`` and the raw tail ``ct`` (int32 table rows,
     at their plane capacities) are between them every distinct table
     row the cold section reads; ``ci`` is each dictionary occurrence's
@@ -535,6 +536,7 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
             "cu": cu, "ct": ct, "ci": ci,
             "is_dict": is_dict, "is_tail": is_tail,
             "di_idx": di_idx, "tail_idx": tail_idx,
+            "n_dict": w["cw_cun"].astype(jnp.int32)[0],
         },
     }
     if "cw_hc" in w:
@@ -640,6 +642,125 @@ def dict_cold_rows(
                 for j in range(param.shape[-1])
             ], axis=1)
     return out
+
+
+# The table rows, by their columns, whose cold gradients leave a
+# dictionary-wire batch through its dictionary (dict_cold_grads): the
+# widths at which the route beat the scatter-add per padded slot at both
+# geometries of scripts/probe_cold_scatter.py's sweep (dict_cold_grads'
+# docstring has the table).  One column stays per slot (9 ns a slot at
+# the table already), and so does a row wider than a lane tile (FFM's
+# 160: the route won at FFM's batch and lost at MVM's).
+DICT_SCATTER_COLUMNS = range(2, 65)
+
+
+def dict_scatter_plan(plan: dict, table_size: int, lane_select) -> dict:
+    """What dict_cold_grads needs of a dictionary-wire batch's
+    ``cold_plan`` (expand_dict_wire), computed once a batch and shared
+    by its tables, as the sentinel-coded key plane is for the per-slot
+    form.  Over the M = B * max_nnz padded positions, flat:
+
+    ``seg`` [M]: the dictionary entry a position's gradient is summed
+    into, ``ci`` at the position's running count of dictionary
+    occurrences (one more monotone_take beside the one that builds the
+    key plane), and cap(cu), out of range, for every other position.
+
+    ``order`` [cap(ct)]: the padded position of the j-th tail entry.
+    ``tail_idx`` rises with the position, so that is the j-th smallest
+    position under ``is_tail``: one single-operand sort, no key is
+    sorted.  Beyond the real tail it reads M.
+
+    ``rows`` [cap(cu) + cap(ct)]: the table row of every dictionary
+    entry, then of every tail entry, with the capacity padding of both
+    planes (beyond ``n_dict``; beyond the count of tail positions)
+    coded as ``table_size``, the cold sentinel that a drop-mode scatter
+    leaves out (TrainStep._cold_keys_eff)."""
+    take = functools.partial(monotone_take, lane_select=lane_select)
+    cu, ct = plan["cu"], plan["ct"]
+    is_dict, is_tail = plan["is_dict"], plan["is_tail"]
+    m, cap_u, cap_t = is_dict.shape[0], cu.shape[0], ct.shape[0]
+    seg = jnp.where(is_dict, take(plan["di_idx"], plan["ci"]), cap_u)
+    position = jnp.arange(m, dtype=jnp.int32)
+    order = jnp.sort(jnp.where(is_tail, position, m))[:cap_t]
+    n_tail = jnp.sum(is_tail.astype(jnp.int32))
+    sentinel = jnp.int32(table_size)
+    rows = jnp.concatenate([
+        jnp.where(
+            jnp.arange(cap_u, dtype=jnp.int32) < plan["n_dict"], cu, sentinel
+        ),
+        jnp.where(
+            jnp.arange(cap_t, dtype=jnp.int32) < n_tail, ct, sentinel
+        ),
+    ])
+    return {"seg": seg, "order": order, "rows": rows}
+
+
+def dict_cold_grads(splan: dict, occ: jax.Array) -> jax.Array:
+    """The cold occurrence gradients ``occ`` [M, D] of a dictionary-wire
+    batch as one row per dictionary entry and per tail entry,
+    [cap(cu) + cap(ct), D], in the order of ``splan["rows"]``
+    (dict_scatter_plan): what the [T, D] gradient buffer is handed in
+    place of a row per padded slot.
+
+    A scatter-add pays per INDEX handed to the table on the TPU, 100 ns
+    at D = 10 and 123 at D = 26 whether the slot is live or masked
+    (PERF.md section 5), and a batch's dictionary and tail are a third
+    of its padded slots.  So the occurrences of a dictionary key are
+    summed first, into a buffer of cap(cu) rows (at most DICT_CAP: the
+    compiler sorts the slots' indices itself and keeps MVM's
+    [43008, 10] in VMEM), the tail's rows are picked out of ``occ`` in
+    stream order by ONE row gather, and the table takes
+    cap(cu) + cap(ct) indices.  Same float32 adds of the same numbers
+    in another order: a dictionary entry's occurrences meet each other
+    before they meet the buffer.
+
+    Measured on a v5e (scripts/probe_cold_scatter.py, one real batch of
+    each cell, alone; PERF.md section 6, PR 48), ms per padded slot /
+    by this route, and the route's parts (table write + dictionary sum
+    + plan + tail rows):
+    MVM   [2^25, 10],  1 048 576 slots, 43 008 + 294 912 entries:
+          105.4 / 52.9 (34.4 + 16.3 + 1.3 + 1.9);
+    DCN   [2^24, 26],  524 288 slots, 40 960 + 131 072:
+          64.1 / 30.8 (21.5 + 8.1 + 0.6 + 1.4);
+    AutoInt [2^25, 16], 131 072 slots, 55 296 + 0:  13.2 / 7.1;
+    FFM   [2^21, 160], 131 072 slots, 53 248 + 0:   22.1 / 17.9;
+    LR    [2^28, 1],   1 572 864 slots, 53 248 + 294 912: 17.8 / 20.6.
+    The table write keeps the table's price an index (101, 125, 103,
+    288, 17 ns); the sum costs 15.6 ns a slot at D = 10 and 26, 11 at
+    16, 21 at 160, 6.7 at D = 1.  Forms that lost, same batches: the
+    sum 32 768 slots at a time under a scan (MVM 21.8, DCN 6.4 against
+    8.1: no one winner), into a [D, cap(cu)] buffer (the same to
+    0.1 ms: the compiler picks the layout either way), as ops/hot.py's
+    one-hot scan at H = 65536 (MVM 26.1, DCN 34.9, AutoInt 7.4); the
+    tail's order by a one-column scatter of positions (4.9 ms against
+    the sort's 1.2); the dictionary's and the tail's rows as two table
+    writes (the same).  Over a sweep of widths on a [2^21, D] table,
+    per slot / route, at MVM's batch: D = 1 7.2 / 12.4, 2 82.5 / 44.9,
+    4 85.3 / 45.9, 8 90.7 / 46.3, 16 109.1 / 53.2, 32 127.9 / 71.5,
+    64 188.0 / 85.1, 160 38.6 / 50.6; at FFM's: 1 0.9 / 1.3,
+    2 10.2 / 5.6, 4 10.7 / 5.8, 8 10.9 / 5.8, 16 12.9 / 6.7,
+    32 16.1 / 8.0, 64 23.9 / 11.6.  From two columns on a slot costs
+    the table 78-180 ns and the route wins by half at every width up
+    to 64; one column is cheaper per slot than any route, and a
+    160-column row goes either way by the batch: DICT_SCATTER_COLUMNS.
+    On the probe's N(0, 1) gradients both forms stand 0.5e-6 to 1.2e-5
+    of the largest sum from the sums in float64 (per slot at most
+    1.2e-5, the route 5.3e-6; at the five cells' own shapes the two
+    read the same to every digit printed)."""
+    d = occ.shape[-1]
+    cap_t = splan["order"].shape[0]
+    cap_u = splan["rows"].shape[0] - cap_t
+    parts = []
+    if cap_u:
+        parts.append(
+            jnp.zeros((cap_u, d), occ.dtype)
+            .at[splan["seg"]].add(occ, mode="drop")
+        )
+    if cap_t:
+        # a position of M (capacity padding) clips to a live row, which
+        # the sentinel in splan["rows"] then drops
+        parts.append(jnp.take(occ, splan["order"], axis=0, mode="clip"))
+    return jnp.concatenate(parts) if parts else jnp.zeros((0, d), occ.dtype)
 
 
 class TrainStep:
@@ -789,6 +910,21 @@ class TrainStep:
                 and cfg.sequential_inner == "hot"
             )
         )
+        # Tables whose cold gradients leave a dictionary-wire batch
+        # through its dictionary (_scatter_local_grads reads the plan of
+        # a WHOLE batch of the dense update, as above; the touched-rows
+        # updates and the hot inner's window end have scatters of their
+        # own): those of DICT_SCATTER_COLUMNS columns (_book_wire)
+        whole_batch_scatter = cfg.microbatch == 1 and not (
+            cfg.update_mode == "sparse"
+            or (
+                cfg.update_mode == "sequential"
+                and cfg.sequential_inner == "sparse"
+            )
+        )
+        self._dict_scatter_tables = whole_batch_scatter * sum(
+            spec.dim in DICT_SCATTER_COLUMNS for spec in model.tables()
+        )
         # elements a step's whole-array optimizer passes run on the flat
         # view (_optimizer_pass: the tables of one column)
         self._flat_pass_elements = self._count_flat_pass_elements()
@@ -911,11 +1047,18 @@ class TrainStep:
         a dictionary-wire batch's ``cold_plan`` (_cold_rows) and the
         padded slots everywhere else.  (The batch's OWN capacities: a
         batch that _settle_planes lengthened ships, and gathers, up to
-        a granule more of padding a plane.)  And what those slots move,
+        a granule more of padding a plane.)  ``table_scatter_indices``
+        is the way back, summed over the tables: the indices the cold
+        scatter-adds hand the [T, D] gradient buffers, the same two
+        capacities for a table whose gradients leave through the
+        dictionary (dict_cold_grads: a whole dictionary-wire batch of
+        the dense update, a table whose width is in DICT_SCATTER_COLUMNS)
+        and the padded slots for every other.  And what those slots move,
         in bytes of table rows: ``gather_row_bytes``, every index the
         step's gathers hand a [T, D] table times that table's row, and
         ``scatter_row_bytes``, a row read and a row written for every
-        padded slot its scatter-adds hand a [T, D] gradient buffer.
+        padded slot whose gradient its scatter-adds owe a [T, D] gradient
+        buffer (the work asked, whichever form does it).
         Both count the ``hot_slots`` (B * hot_nnz) of a table that opted
         out of the MXU head (TableSpec.hot=False), whose hot occurrences
         are plain table rows, and leave the head's own traffic out;
@@ -959,6 +1102,12 @@ class TrainStep:
             indices = len(cb.cu) + len(cb.ct) if through_dict else cold_slots
             self.obs.counter("wire.cold_slots", cold_slots)
             self.obs.counter("wire.table_gather_indices", indices)
+            on_route = self._dict_scatter_tables if through_dict else 0
+            self.obs.counter(
+                "wire.table_scatter_indices",
+                on_route * indices
+                + (len(self._mxu_hot) - on_route) * cold_slots,
+            )
             if through_dict:
                 self.obs.counter(
                     "wire.cold_row_layout_slots",
@@ -1656,6 +1805,17 @@ class TrainStep:
         cfg = self.cfg
         kh = batch["hot_keys"].shape[1] if "hot_keys" in batch else 0
         keys_eff = self._cold_keys_eff(batch)
+        # a whole dictionary-wire batch hands a table of 2 to 64 columns
+        # an index per dictionary and tail entry (dict_cold_grads)
+        through_dict = {
+            name for name, t in tables.items()
+            if "cold_plan" in batch
+            and t["param"].shape[-1] in DICT_SCATTER_COLUMNS
+        }
+        if through_dict:
+            splan = dict_scatter_plan(
+                batch["cold_plan"], cfg.table_size, self._lane_select
+            )
         if kh:
             from xflow_tpu.ops.hot import hot_scatter
 
@@ -1669,9 +1829,13 @@ class TrainStep:
                 # buffer; cold grads keep the DMA scatter path.
                 hot_g = occ[:, :kh].reshape(-1, d)
                 occ = occ[:, kh:]
-            gbuf = self._cold_accumulate(
-                gbufs[name], keys_eff, occ.reshape(-1, d)
-            )
+            occ = occ.reshape(-1, d)
+            if name in through_dict:
+                gbuf = self._cold_accumulate(
+                    gbufs[name], splan["rows"], dict_cold_grads(splan, occ)
+                )
+            else:
+                gbuf = self._cold_accumulate(gbufs[name], keys_eff, occ)
             if kh:
                 if self._mxu_hot[name]:
                     ghot = hot_scatter(

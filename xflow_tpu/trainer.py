@@ -1090,6 +1090,13 @@ class Trainer:
                 stats["_wire"]["padded_cold_slots_per_step"] = round(
                     snap.counters["wire.cold_slots"] / batches
                 )
+                # the way back, summed over the tables: indices the cold
+                # scatter-adds handed the gradient buffers (the padded
+                # slots times the tables unless a table's gradients left
+                # through the dictionary: step.py::dict_cold_grads)
+                stats["_wire"]["table_scatter_indices_per_step"] = round(
+                    snap.counters["wire.table_scatter_indices"] / batches
+                )
                 # and what those slots move in bytes of table rows, with
                 # the hot slots of a table that opted out of the MXU head;
                 # the head's own slots by the form its gather read them in
