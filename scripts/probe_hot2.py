@@ -10,6 +10,7 @@ gathered whole) is where ``hot.PLAIN_GATHER_MIN_COLUMNS`` belongs
 
     chiprun -- python scripts/probe_hot2.py [--shapes "H,D,slots[,g];..."]
         [--old HOT.py] [--seed N]
+    chiprun -- python scripts/probe_hot2.py --scatter [--seed N]
 
 A shape that ends in ``,g`` times the gathers alone, on zipf keys.  Every
 other shape's keys are drawn three ways: ``zipf``, zipf-1.2 ranks over
@@ -32,7 +33,26 @@ keys and form) and writes it to ``chiprun_out/hot_probe.json``.  Exit 1
 without a TPU (a CPU run times nothing worth writing down), or where a
 head's gather is not bit for bit the plain one, or its scatter further
 than 1e-4 of the largest sum from the sums in float64 (the plain
-scatter-add's own distance is printed beside it)."""
+scatter-add's own distance is printed beside it).
+
+``--scatter`` (PR 49) asks the other question alone: where
+``hot.PLAIN_SCATTER_MIN_COLUMNS`` belongs and what the plain scatter
+should look like.  Under the cells' OWN keys (DCN's hot plane for
+2 097 152 slots, MVM's for 4 194 304) it times the scatter scan, the
+plain scatter-add written whole and the shipped plain form
+(``hot_scatter(impl="seg")``: ``hot._PLAIN_SCATTER_SLOTS`` slots at a
+time) over D = 4..64; the same three at AutoInt's and xDeepFM's heads
+(524 288 slots, D = 16 and 10) with each form's distance from the
+float64 sums; and at DCN's and MVM's heads the plain form by the slots
+it adds at a time, in four writings, each with its distance: ``lanes``
+(the shipped one: a piece arrives ``[D, C]``, the slots on the lanes, as
+a chunk of the scan does, and is turned in the loop), ``rows`` (the
+pieces cut out of ``[M, D]`` as they lie: on the TPU one 128-lane row a
+slot, 1 GiB at DCN's head), ``dh`` (the accumulator carried ``[D, H]``)
+and ``two`` (each piece summed into its own zeroed ``[H, D]`` partial,
+the partials added: a shorter chain of adds a row, the fallback if the
+plain form's rows do not hold ``ROWS_RTOL``).  The losers stay here.
+Writes ``chiprun_out/hot_scatter_probe.json``."""
 
 from __future__ import annotations
 
@@ -108,6 +128,20 @@ def _by_piece(w, keys, rows, steps: int) -> dict:
     return out
 
 
+def _float64_sums(keys_np, grads_np, h: int):
+    """The sums in float64, on the host (the sentinel H is the one key
+    outside the head)."""
+    return np.stack([
+        np.bincount(keys_np, weights=grads_np[:, j], minlength=h + 1)[:h]
+        for j in range(grads_np.shape[1])
+    ], axis=1)
+
+
+def _off_float64(sums, exact) -> float:
+    """The largest distance from the float64 sums, of the largest sum."""
+    return float(np.max(np.abs(np.asarray(sums) - exact)) / np.max(np.abs(exact)))
+
+
 def _scatters(cell, forms, keys_np, rng, h: int, d: int, steps: int) -> bool:
     """Adds to ``cell`` the plain scatter-add's and every form's scatter
     scan's ms a call and distance from the float64 sums (of the largest
@@ -115,14 +149,11 @@ def _scatters(cell, forms, keys_np, rng, h: int, d: int, steps: int) -> bool:
     1e-4."""
     m = len(keys_np)
     grads_np = rng.normal(size=(m, d)).astype(np.float32)
-    exact = np.stack([  # the sums in float64, on the host
-        np.bincount(keys_np, weights=grads_np[:, j], minlength=h + 1)[:h]
-        for j in range(d)
-    ], axis=1)
+    exact = _float64_sums(keys_np, grads_np, h)
     keys, grads = jnp.asarray(keys_np), jnp.asarray(grads_np)
 
     def off_exact(sums):
-        return float(np.max(np.abs(np.asarray(sums) - exact)) / np.max(np.abs(exact)))
+        return _off_float64(sums, exact)
 
     plain_scatter = jax.jit(
         lambda k, g: jnp.zeros((h, d), jnp.float32).at[k].add(g, mode="drop")
@@ -141,16 +172,158 @@ def _scatters(cell, forms, keys_np, rng, h: int, d: int, steps: int) -> bool:
     return ok
 
 
+# the heads --scatter reads: H, D, slots -> the cell's configuration
+SCATTER_CELLS = {
+    **CELLS,
+    (16384, 16, 524288): "autoint_ftrl_criteo_tb",
+    (16384, 10, 524288): "xdeepfm_ftrl_criteo_tb",
+}
+SWEEP_COLUMNS = (4, 8, 10, 16, 26, 32, 64)
+PIECES = (4096, 16384, 32768, 65536, 262144)
+
+
+def _shipped(k, g, h: int, c: int):
+    """``hot_scatter(impl="seg")`` at ``c`` slots a piece."""
+    was = hot._PLAIN_SCATTER_SLOTS
+    hot._PLAIN_SCATTER_SLOTS = c
+    try:
+        return hot.hot_scatter(k, g, h, impl="seg")
+    finally:
+        hot._PLAIN_SCATTER_SLOTS = was
+
+
+def _whole(k, g, h: int):
+    """The plain scatter-add written whole: the shipped form at one
+    piece, no loop."""
+    return _shipped(k, g, h, k.shape[0])
+
+
+def _drop(k, h: int):
+    return jnp.where((k >= 0) & (k < h), k, h)
+
+
+def _in_pieces(k, g, h: int, c: int, writing: str):
+    """The losers' writings of the plain scatter-add, ``c`` slots at a
+    time (module docstring); ``c`` divides the slots."""
+    d = g.shape[1]
+    ks = k.reshape(-1, c)
+    if writing == "rows":
+        def body(acc, xs):
+            return acc.at[_drop(xs[0], h)].add(xs[1], mode="drop"), None
+        return jax.lax.scan(
+            body, jnp.zeros((h, d), jnp.float32), (ks, g.reshape(-1, c, d))
+        )[0]
+    lanes = hot.with_layout_constraint(
+        g.reshape(-1, c, d).transpose(0, 2, 1),
+        hot.Layout(major_to_minor=(0, 1, 2)),
+    )  # [M/C, D, C]
+    if writing == "dh":
+        def body(acc, xs):
+            return acc.at[:, _drop(xs[0], h)].add(xs[1], mode="drop"), None
+        return jax.lax.scan(
+            body, jnp.zeros((d, h), jnp.float32), (ks, lanes)
+        )[0].T
+    assert writing == "two", writing
+
+    def body(acc, xs):
+        return acc + _whole(xs[0], xs[1].T, h), None
+    return jax.lax.scan(body, jnp.zeros((h, d), jnp.float32), (ks, lanes))[0]
+
+
+def scatter_probe(seed: int, steps: int) -> tuple[dict, bool]:
+    """The ``--scatter`` run (module docstring): (report, whether every
+    form stands within 1e-4 of the float64 sums)."""
+    from probe_cold_gather import cell_batch
+
+    out: dict = {}
+    ok = True
+    planes = {}
+    for (h, d, m), config in SCATTER_CELLS.items():
+        batch = cell_batch(seed, config)[1].expand()
+        keys_np = np.where(batch.hot_mask > 0, batch.hot_keys, h).reshape(-1)
+        assert keys_np.shape == (m,), (keys_np.shape, m)
+        planes[(h, d, m)] = keys_np.astype(np.int32)
+    rng = np.random.default_rng(0)
+
+    def timed(fn, keys, grads):
+        return _ms(jax.jit(fn), keys, grads, steps=steps)
+
+    # 1. the width sweep, under DCN's keys (2 097 152) and MVM's (4 194 304)
+    for cell in CELLS:
+        h, _, m = cell
+        keys = jnp.asarray(planes[cell])
+        sweep = {}
+        for d in SWEEP_COLUMNS:
+            grads = jax.random.normal(jax.random.PRNGKey(d), (m, d), jnp.float32)
+            row = {}
+            row["scan_ms"], want = timed(
+                lambda k, g: hot.hot_scatter(k, g, h, impl="mxu"), keys, grads
+            )
+            row["whole_ms"], got = timed(lambda k, g: _whole(k, g, h), keys, grads)
+            row["plain_ms"], got2 = timed(
+                lambda k, g: hot.hot_scatter(k, g, h, impl="seg"), keys, grads
+            )
+            scale = float(jnp.max(jnp.abs(want)))
+            row["whole_off_scan"] = float(jnp.max(jnp.abs(got - want))) / scale
+            row["plain_off_scan"] = float(jnp.max(jnp.abs(got2 - want))) / scale
+            ok &= max(row["whole_off_scan"], row["plain_off_scan"]) <= 1e-4
+            for key in ("scan_ms", "whole_ms", "plain_ms"):
+                row[key[:-3] + "_ns_per_slot"] = row[key] * 1e6 / m
+            sweep[str(d)] = row
+        out[f"sweep_M{m}"] = sweep
+
+    # 2. each head under its own keys, with the distance from float64;
+    # 3. DCN's and MVM's by the piece and the writing
+    for (h, d, m), keys_np in planes.items():
+        grads_np = rng.normal(size=(m, d)).astype(np.float32)
+        exact = _float64_sums(keys_np, grads_np, h)
+        keys, grads = jnp.asarray(keys_np), jnp.asarray(grads_np)
+        head = {"most_adds_a_row": int(np.bincount(keys_np, minlength=h)[:h].max())}
+        forms = {
+            "scan": lambda k, g: hot.hot_scatter(k, g, h, impl="mxu"),
+            "whole": lambda k, g: _whole(k, g, h),
+            "plain": lambda k, g: hot.hot_scatter(k, g, h, impl="seg"),
+        }
+        if (h, d, m) in CELLS:
+            for c in PIECES:
+                forms[f"lanes_{c}"] = lambda k, g, c=c: _shipped(k, g, h, c)
+                for writing in ("rows", "dh", "two"):
+                    forms[f"{writing}_{c}"] = (
+                        lambda k, g, c=c, w=writing: _in_pieces(k, g, h, c, w)
+                    )
+        for name, fn in forms.items():
+            ms, sums = timed(fn, keys, grads)
+            head[name] = {
+                "ms": ms, "ns_per_slot": ms * 1e6 / m,
+                "off_float64": _off_float64(sums, exact),
+            }
+            ok &= head[name]["off_float64"] <= 1e-4
+        out[f"H{h}_D{d}_M{m}_cell"] = head
+    return out, ok
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--shapes", default=SHAPES)
     ap.add_argument("--old", help="another tree's ops/hot.py, timed after this one's")
     ap.add_argument("--steps", type=int, default=5)
     ap.add_argument("--seed", type=int, default=1, help="of the cells' batches")
+    ap.add_argument(
+        "--scatter", action="store_true",
+        help="the scatter's widths, pieces and writings alone (PR 49)",
+    )
     args = ap.parse_args(argv)
     if jax.devices()[0].platform != "tpu":
         print("no TPU: nothing to time", file=sys.stderr)
         return 1
+    if args.scatter:
+        out, ok = scatter_probe(args.seed, args.steps)
+        out["device"] = jax.devices()[0].device_kind
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/hot_scatter_probe.json", "w") as f:
+            json.dump(out, f, indent=1)
+        print(json.dumps(out))
+        return 0 if ok else 1
     forms = {"mxu": hot}
     if args.old:
         spec = importlib.util.spec_from_file_location("old_hot", args.old)

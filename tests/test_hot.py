@@ -1,6 +1,7 @@
 """Unit tests for the hot-table head (ops/hot.py): the two-level one-hot
 MXU scans, and the plain indexing of the head's slice that the gather
-takes from hot.PLAIN_GATHER_MIN_COLUMNS columns up.
+takes from hot.PLAIN_GATHER_MIN_COLUMNS columns up and the scatter from
+hot.PLAIN_SCATTER_MIN_COLUMNS.
 
 Correctness spec: hot_gather(W, k) == W[k] (zero row for k outside
 [0, H)) and hot_scatter(k, g, H) == zeros([H, D]).at[k].add(g) (dropping
@@ -15,10 +16,12 @@ import pytest
 
 from xflow_tpu.ops.hot import (
     PLAIN_GATHER_MIN_COLUMNS,
+    PLAIN_SCATTER_MIN_COLUMNS,
     gather_form,
     hot_factors,
     hot_gather,
     hot_scatter,
+    scatter_form,
 )
 
 
@@ -143,6 +146,99 @@ def test_scatter_matches_dma(h, d, m):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
     )
+
+
+def _float64_sums(keys, grads, h):
+    """zeros([H, D]).at[keys].add(grads) in float64 on the host, keys
+    outside [0, H) dropped."""
+    live = (keys >= 0) & (keys < h)
+    return np.stack([
+        np.bincount(keys[live], weights=grads[live, j].astype(np.float64),
+                    minlength=h)
+        for j in range(grads.shape[1])
+    ], axis=1)
+
+
+# (piece, M): one piece (the slots fit it), pieces that divide M, pieces
+# that do not
+@pytest.mark.parametrize("piece,m", [(1 << 15, 3000), (512, 4096), (512, 3001)])
+@pytest.mark.parametrize("d", [1, 10, 16, 26])
+def test_scatter_forms_agree_with_float64_sums(monkeypatch, d, piece, m):
+    """ops/hot.py::hot_scatter: the one-hot scan ("mxu") and the plain
+    scatter-add a piece at a time ("seg") both stand within 1e-5 of the
+    largest sum from the sums in float64, at the widths of the
+    benchmark's heads, sentinel (H), beyond-the-head and NEGATIVE keys
+    dropped (a negative index would count from the end of the slice),
+    whether the slots fit one piece, fill whole pieces or leave a rest."""
+    import xflow_tpu.ops.hot as hot
+
+    monkeypatch.setattr(hot, "_PLAIN_SCATTER_SLOTS", piece)
+    h = 1024
+    rng = np.random.default_rng(d)
+    keys = (rng.zipf(1.3, size=m) - 1).clip(0, h + 10).astype(np.int32)
+    keys[::7] = h  # the sentinel of a padded slot
+    keys[3::11] = -1 - (keys[3::11] % 5)  # negative keys
+    grads = rng.normal(size=(m, d)).astype(np.float32)
+    want = _float64_sums(keys, grads, h)
+    for impl in ("mxu", "seg"):
+        got = np.asarray(
+            jax.jit(lambda k, g, impl=impl: hot_scatter(k, g, h, impl=impl))(
+                jnp.asarray(keys), jnp.asarray(grads)
+            )
+        )
+        assert got.shape == (h, d) and got.dtype == np.float32
+        assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max(), impl
+    # a negative key wraps nowhere
+    dropped = np.asarray(hot_scatter(
+        jnp.asarray(np.full(8, -1, np.int32)), jnp.ones((8, d)), h, impl="seg"
+    ))
+    assert not dropped.any()
+
+
+@pytest.mark.parametrize("impl", ["mxu", "seg", "auto"])
+@pytest.mark.parametrize("d", [1, 26])
+def test_scatter_of_an_empty_head_plane_is_zeros(impl, d):
+    """A batch with no hot slot (hot_nnz 0 rows, or every slot the
+    sentinel) sums to an all-zero [H, D] buffer in every form."""
+    h = 256
+    for keys in (np.zeros((0,), np.int32), np.full((40,), h, np.int32)):
+        got = np.asarray(hot_scatter(
+            jnp.asarray(keys), jnp.ones((len(keys), d), jnp.float32), h,
+            impl=impl,
+        ))
+        assert got.shape == (h, d) and not got.any()
+
+
+def test_scatter_form_is_chosen_from_the_width():
+    """ops/hot.py::scatter_form: "auto" is the scan below
+    PLAIN_SCATTER_MIN_COLUMNS columns (every D = 1 head, and the widths
+    the probe saw no win at) and the plain scatter-add from there up
+    (DCN's emb at D = 26); an explicit "mxu" or "seg" is taken at any
+    width.  The constant lies between 10 and 26 (ISSUE 49) and is not
+    the gather's: the two directions cross at different widths.  And
+    hot_scatter runs what the rule says: a scan with a dot_general in
+    it, or a scatter-add and no dot."""
+    assert 10 <= PLAIN_SCATTER_MIN_COLUMNS <= 26
+    assert PLAIN_SCATTER_MIN_COLUMNS > PLAIN_GATHER_MIN_COLUMNS
+    assert scatter_form(1) == "mxu" and scatter_form(26) == "seg"
+    assert scatter_form(PLAIN_SCATTER_MIN_COLUMNS - 1, "auto") == "mxu"
+    assert scatter_form(PLAIN_SCATTER_MIN_COLUMNS, "auto") == "seg"
+    for d in (1, PLAIN_SCATTER_MIN_COLUMNS - 1, PLAIN_SCATTER_MIN_COLUMNS, 64):
+        assert scatter_form(d, "mxu") == "mxu" and scatter_form(d, "seg") == "seg"
+    keys = jnp.zeros((100,), jnp.int32)
+    for d, impl, plain in [
+        (1, "auto", False), (PLAIN_SCATTER_MIN_COLUMNS - 1, "auto", False),
+        (PLAIN_SCATTER_MIN_COLUMNS, "auto", True), (26, "auto", True),
+        (26, "mxu", False), (1, "seg", True),
+    ]:
+        grads = jnp.zeros((100, d), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda k, g: hot_scatter(k, g, 256, impl=impl)
+        )(keys, grads).jaxpr
+        found = {eqn.primitive.name for eqn in jaxpr.eqns}
+        assert ("scatter-add" in found, bool(_dot_precisions(jaxpr))) == (
+            plain, not plain
+        ), (d, impl, found)
 
 
 def test_gather_f32_is_exact_selection():

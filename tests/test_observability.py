@@ -1126,6 +1126,9 @@ def test_wire_row_counts_the_table_rows_a_step_moves(
     assert t.step._hot_impl == "seg"
     assert row["hot_plain_slots_per_step"] == b * kh * (len(widths) - len(plain))
     assert row["hot_scan_slots_per_step"] == 0
+    # and by the form their scatter summed them in (scatter_form): the same
+    assert row["hot_scatter_plain_slots_per_step"] == row["hot_plain_slots_per_step"]
+    assert row["hot_scatter_scan_slots_per_step"] == 0
     assert row["padded_cold_slots_per_step"] == b * kc
     assert by_rows == [
         n for n, d in widths.items() if d >= ROW_LAYOUT_MIN_COLUMNS

@@ -714,15 +714,15 @@ def test_mvm_step_indexes_its_head_and_scans_only_the_scatter_on_v5e(
     assert f"f32[{m},{d}]" not in text
 
 
-# configuration, and the hot slots a step that its gather indexes / scans,
-# a table
-@pytest.mark.parametrize("config,plain,scan", [
-    ("lr_ftrl_criteo_tb", 0, 131072 * 28),
-    ("mvm_ftrl_criteo_tb", 4194304, 0),
-    ("dcn_ftrl_criteo_tb", 2097152, 2097152),
+# configuration, the hot slots a step that its gather indexes / scans, a
+# table, and those whose gradients its scatter adds plainly / scans
+@pytest.mark.parametrize("config,plain,scan,scatter_plain,scatter_scan", [
+    ("lr_ftrl_criteo_tb", 0, 131072 * 28, 0, 131072 * 28),
+    ("mvm_ftrl_criteo_tb", 4194304, 0, 0, 4194304),
+    ("dcn_ftrl_criteo_tb", 2097152, 2097152, 2097152, 2097152),
 ])
 def test_wire_row_books_the_head_slots_by_the_form_of_their_gather(
-    topo, config, plain, scan
+    topo, config, plain, scan, scatter_plain, scatter_scan
 ):
     """``hot_plain_slots`` / ``hot_scan_slots`` of TrainStep._book_wire
     (the ``_wire`` row's ``hot_plain_slots_per_step`` /
@@ -730,7 +730,11 @@ def test_wire_row_books_the_head_slots_by_the_form_of_their_gather(
     described v5e at the geometry of the benchmark's cells: MVM's one
     table of ten columns is indexed (4 194 304 / 0), DCN's ``emb`` is
     indexed and its ``w`` scanned (2 097 152 each), LR's ``w`` scanned
-    (0 / all 3 670 016).  Nothing is lowered."""
+    (0 / all 3 670 016).  And, PR 49, ``hot_scatter_plain_slots`` /
+    ``hot_scatter_scan_slots`` by the form of their SCATTER
+    (ops/hot.py::scatter_form, a constant of its own): DCN's ``emb``
+    (26 columns) is added plainly and its ``w`` scanned, MVM's ten
+    columns and LR's one stay the scan.  Nothing is lowered."""
     cfg, step = _cell_train_step(topo, config)
     booked: dict[str, float] = {}
     step.obs = types.SimpleNamespace(
@@ -743,6 +747,10 @@ def test_wire_row_books_the_head_slots_by_the_form_of_their_gather(
     assert (booked["wire.hot_plain_slots"], booked["wire.hot_scan_slots"]) == (
         plain, scan
     )
+    assert (
+        booked["wire.hot_scatter_plain_slots"],
+        booked["wire.hot_scatter_scan_slots"],
+    ) == (scatter_plain, scatter_scan)
     assert booked["wire.plain_hot_slots"] == 0  # no table off the head
 
 
@@ -845,8 +853,8 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
 # serialized bodies blanked: they embed the checkout's path) of the four
 # configurations the benchmark measured before PR 39, pinned on PR 38's
 # tree BEFORE models/blocks.py was edited, anew by PR 44, MVM's and
-# FM's again by PR 45, and MVM's, DCN's and xDeepFM's again by PR 48 (the
-# tests' docstrings say why).
+# FM's again by PR 45, MVM's, DCN's and xDeepFM's again by PR 48, and
+# DCN's alone by PR 49 (the tests' docstrings say why).
 MEASURED_PROGRAMS_SHA256 = {
     "lr_ftrl_criteo_tb": (
         "e288dfde6bd0a7646d26153ef9b2ad0ba6d6a5056fe6a02cc9440b498b84d5bc"
@@ -864,9 +872,9 @@ MEASURED_PROGRAMS_SHA256 = {
     # pinned by PR 47 on PR 46's tree BEFORE blocks.py was edited (and equal
     # after: cin_stack's padding and slicing went into two helpers that the
     # attention block shares); anew by PR 48, whose cold scatter route
-    # their emb tables take
+    # their emb tables take; DCN's again by PR 49 (emb's head scatter)
     "dcn_ftrl_criteo_tb": (
-        "cddc66f4c60728fd1ac8d2531cdf7bd7dcaac008b37e86d7c188f9d7d7db2db9"
+        "22461118a9ecac7b78c7f12a043db151d345f5f75359bfd31e7b9491965154d1"
     ),
     "xdeepfm_ftrl_criteo_tb": (
         "2593f4a8abee8f284e9a4dbdbad8eee6dcfe3d9dfc0e306f5ff0c58fe52dda18"
@@ -912,7 +920,12 @@ def test_measured_train_programs_lower_to_the_pinned_text(topo):
     FFM's w), a 160-column one (FFM's v) and every batch on a mesh keep
     the scatter-add per padded slot: those three digests are PR 45's and
     PR 44's, the control that the cells which bypass the route run the
-    parent's program, and MVM's is pinned anew."""
+    parent's program, and MVM's is pinned anew.  PR 49 meant to change
+    NONE of the four: the head's scatter is chosen from the table's
+    width (ops/hot.py::scatter_form), a plain scatter-add from
+    PLAIN_SCATTER_MIN_COLUMNS = 16 columns up, the scan below: LR's and
+    FFM's w (one column) and MVM's and the FM mesh's v (ten) keep the
+    scan, and all four digests are the parent's."""
     got = {
         "lr_ftrl_criteo_tb": _lowered_cell_step(
             topo, "lr_ftrl_criteo_tb", LR_PLANES, ships_slots=False
@@ -953,7 +966,10 @@ def test_measured_dense_programs_lower_to_the_pinned_text(topo, config):
     lowered to the text PR 46's tree lowered them to.  PR 48 meant to
     change both (emb, 26 and 10 columns, takes the cold scatter's
     dictionary route; models/blocks.py is untouched) and pinned them
-    anew."""
+    anew.  PR 49 meant to change DCN's and NOT xDeepFM's: the head's
+    scatter of emb's 26 columns is a plain scatter-add
+    (ops/hot.py::scatter_form), xDeepFM's ten columns keep the scan and
+    PR 48's digest."""
     lowered = _lowered_cell_step(topo, config, DENSE_PROGRAMS[config])[2]
     assert _program_sha256(lowered) == MEASURED_PROGRAMS_SHA256[config]
 
@@ -1053,6 +1069,55 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
     ]
     peak = _program_peak(compiled)
     assert 9.0 * (1 << 30) < peak < 10.5 * (1 << 30), peak
+
+
+# the cell's fixture, the scatter scans its step keeps (one-hot products
+# under xf.scatter), the program peak of the parent's step (PR 48's tree,
+# compiled here for the same described v5e)
+@pytest.mark.parametrize("cell,scans,parent_gib", [
+    ("dcn_cell_step", ["f32[128,128]"], 9.322),
+    ("autoint_cell_step", [], 8.014),
+])
+def test_wide_head_gradients_are_added_plainly_a_piece_at_a_time_on_v5e(
+    request, cell, scans, parent_gib
+):
+    """PR 49: from hot.PLAIN_SCATTER_MIN_COLUMNS = 16 columns the head's
+    SCATTER is a plain scatter-add into the [H, D] slice
+    (ops/hot.py::scatter_form), so the compiled DCN step has no one-hot
+    product ``f32[128,3328]`` under xf.scatter (the scan's, 32.6 of the
+    parent's 229.1 ms step) and keeps ``w``'s ``f32[128,128]`` one (D = 1
+    stays the scan), and AutoInt's (one table, D = 16) has none.
+    ``emb``'s hot gradients meet the ``f32[16384,D]`` slice
+    hot._PLAIN_SCATTER_SLOTS slots at a time, the pieces handed to the
+    loop with the slots on the lanes (``f32[pieces,D,slots]``): as
+    ``[2097152, 26]`` rows DCN's would be one 128-lane row a slot, a
+    1 GiB temporary.  And the program's peak stays within 0.2 GiB of the
+    parent's."""
+    from xflow_tpu.ops import hot
+    from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
+
+    cfg, step, _, compiled = request.getfixturevalue(cell)
+    h, d = cfg.hot_size, cfg.emb_dim
+    m, c = cfg.batch_size * cfg.hot_nnz, hot._PLAIN_SCATTER_SLOTS
+    assert h == 16384 and m % c == 0 and hot.hot_factors(h) == (128, 128)
+    assert hot.scatter_form(d, step._hot_impl) == "seg"
+    assert hot.scatter_form(1, step._hot_impl) == "mxu"
+    text = compiled.as_text()
+    products = [
+        line.split(" = ")[1].split("{")[0] for line in text.splitlines()
+        if " convolution(" in line
+        and (found := _HLO_OP_NAME_RE.search(line))
+        and scope_of(found.group(1)) == "xf.scatter"
+    ]
+    assert products == scans, products
+    into_head = [s for s in _scatters(text) if s[0] == f"f32[{h},{d}]"]
+    assert into_head == [(f"f32[{h},{d}]", f"s32[{c}]", f"f32[{c},{d}]")]
+    # the pieces: slots minor; never a row a slot
+    assert re.search(rf"f32\[{m // c},{d},{c}\]\{{2,[01],[01]:", text)
+    assert f"f32[{m},{d}]{{1,0:" not in text
+    assert f"f32[{m // c},{c},{d}]{{2,1,0:" not in text
+    peak = _program_peak(compiled) / (1 << 30)
+    assert abs(peak - parent_gib) < 0.2, peak
 
 
 def test_xdeepfm_step_contracts_pairs_in_float32_a_slice_at_a_time_on_v5e(

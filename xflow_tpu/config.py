@@ -434,11 +434,13 @@ class Config:
     # TABLE on v5e); "seg" = plain gather + segment-sum of the head's
     # [H, D] slice (the CPU-fast form: one-hot matmuls are an MXU trick,
     # measured 3.3x slower than the gather on the CPU backend).  "auto"
-    # picks "seg" off the TPU; on TPU meshes the scatter scan at every
-    # width and, for the gather, the cheaper form for the table's width
-    # (ops/hot.py::gather_form: the scan at D = 1, the slice indexed
-    # from PLAIN_GATHER_MIN_COLUMNS columns up).  Numerics: gather is
-    # exact either way; scatter differs only in summation order.
+    # picks "seg" off the TPU; on TPU meshes, for each direction, the
+    # cheaper form for the table's width (ops/hot.py::gather_form: the
+    # scan at D = 1, the slice indexed from PLAIN_GATHER_MIN_COLUMNS
+    # columns up; scatter_form: the scan under
+    # PLAIN_SCATTER_MIN_COLUMNS, a plain scatter-add from there up).
+    # Numerics: gather is exact either way; scatter differs only in
+    # summation order.
     hot_impl: str = "auto"  # {"auto", "mxu", "seg"}
 
     # -- hierarchical parameter store (store/; docs/STORE.md) --

@@ -419,6 +419,10 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # indexing of the [H, D] slice, or the one-hot scan
         "hot_plain_slots_per_step": (int, float),
         "hot_scan_slots_per_step": (int, float),
+        # the same slots by the form the head's scatter summed their
+        # gradients in (ops/hot.py::scatter_form, its own constant)
+        "hot_scatter_plain_slots_per_step": (int, float),
+        "hot_scatter_scan_slots_per_step": (int, float),
         # where the step read the dictionary wire's plan (one device):
         # padded cold slots B * max_nnz times the tables wide enough for
         # the route to lay their rows out by row gathers, 0 where every

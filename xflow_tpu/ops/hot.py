@@ -16,12 +16,21 @@ and sum it:
     scatter: W'   = onehot_hi^T @ (g * onehot_lo)
 
 the one-hot scans ("mxu"), and plain indexing of the slice ("seg"):
-``w_hot[keys]`` and a segment-sum.  Which one runs:
+``w_hot[keys]`` and a scatter-add.  Which one runs:
 
-  * ``hot_scatter``: the scan at every width on the TPU ("seg" is the
-    CPU's form; anything else, "auto" included, is the scan).  Its sums
-    stand 1e-6 of the largest from float64 where a plain scatter-add of
-    10^6 float32 adds into one row stands 1e-5 (scripts/probe_hot2.py).
+  * ``hot_scatter``: a sum, so the forms differ in summation order
+    and the price AND the benchmark's row check decide
+    (``scatter_form``).  The scan costs 0.7-1.5 ns a slot and column
+    (its product is at 84 % of its three-pass MXU bound at D = 26), the
+    plain scatter-add 7.4-9.2 ns a slot at any width, one slot after
+    the other.  The scan is the form of every D = 1 head and of D = 10
+    (MVM's, FM's, xDeepFM's: a tie); from ``PLAIN_SCATTER_MIN_COLUMNS``
+    columns up (AutoInt's 16, DCN's 26) ``"auto"`` adds plainly, a
+    piece of the slots at a time.  Under the benchmark's own keys (at
+    most one add a row and example) the plain sums stand 1.5e-6-4.2e-6
+    of the largest from float64 where the scan's stand 0.5e-6-1.9e-6
+    (scripts/probe_hot2.py; under a flat zipf draw, 10^6 adds into one
+    row, ten times).  "seg" is also the CPU's form.
   * ``hot_gather``: a selection, bit for bit the same in both forms, so
     the price decides (``gather_form``): the scan costs 0.7 ns a slot
     AND COLUMN, a row out of the small slice 2-4.4 ns at any width.
@@ -230,6 +239,81 @@ def hot_gather(
     return out.transpose(0, 2, 1).reshape(m_pad, d)[:m]
 
 
+# Columns of a head from which ``hot_scatter``'s "auto" adds the slots'
+# gradients into the [H, D] slice by plain indexing and stops scanning.
+# Two laws again, read on a v5e alone at H = 16384 under the benchmark
+# cells' OWN keys (scripts/probe_hot2.py --scatter; PERF.md section 6,
+# PR 49).  The scan's product is 2 M h1 D h2 operations in three MXU
+# passes and its price grows with D, in steps (the chunk halves as
+# D h2 doubles): over 2 097 152 slots 18.5 ms at D = 4, 23.4 at 8, 14.9
+# at 10, 39.0 at 16, 44.1 at 26, 102 at 32, 204 at 64.  The plain
+# scatter-add takes the slots one after the other, 7.4-9.2 ns a slot at
+# any width: 15.6 ms at D = 4 and 8, 16.3 at 10, 17.3 at 16, 19.4 at 26,
+# 19.0 at 32, 23.4 at 64 (twice each over 4 194 304 slots).  At D = 10
+# (MVM's, FM's and xDeepFM's heads) the scan wins or ties (14.9 / 16.3;
+# 33.5 / 34.0 over MVM's slots; 3.8 / 4.1 over xDeepFM's), at D = 16
+# (AutoInt's) it loses (9.2 / 4.1 over 524 288 slots): 16 is the
+# narrowest width above 10 the plain form was seen to win at.  (It also
+# won at D = 4 and 8, by 3-8 ms; no head is that wide, and one
+# threshold cannot leave 10 out.)
+PLAIN_SCATTER_MIN_COLUMNS = 16
+
+
+def scatter_form(d: int, impl: str = "auto") -> str:
+    """The form ``hot_scatter`` runs for a head of ``d`` columns: ``impl``
+    itself where it names one ("mxu", "seg"), and for "auto" the cheaper
+    one on the TPU at that width."""
+    if impl != "auto":
+        return impl
+    return "seg" if d >= PLAIN_SCATTER_MIN_COLUMNS else "mxu"
+
+
+# Slots the plain scatter adds at a time.  The TPU takes the updates of a
+# scatter as rows, D < 128 columns in a 128-lane row of their own: whole,
+# dcn_tb.train_packed's [2097152, 26] gradients would be a 1 GiB
+# temporary for 218 MB of numbers.  So the slots reach the loop as the
+# scan's chunks do, on the lanes ([M/C, D, C]), and a piece is turned to
+# rows inside it, 16 MiB that the compiler keeps in VMEM beside the
+# [H, D] accumulator.  The piece buys memory, not time: the adds go one
+# after the other whatever the piece (alone at D = 26: 21.3 ms at 4 096
+# slots, 19.4 at 16 384 to 65 536, 19.9 at 262 144; 16.4 written whole,
+# the turn costs 3 ms; 17.9 in dcn_tb.train_packed's step, where the
+# scan read 43.9), and in order, so the sums are those of the plain
+# scatter-add written whole, bit for bit, at every piece.
+_PLAIN_SCATTER_SLOTS = 1 << 15
+
+
+def _plain_scatter(
+    keys: jax.Array, grads: jax.Array, hot_size: int
+) -> jax.Array:
+    """``zeros([H, D]).at[keys].add(grads)`` with keys outside [0, H)
+    dropped, a piece of ``_PLAIN_SCATTER_SLOTS`` slots at a time."""
+    m, d = grads.shape
+    grads = grads.astype(jnp.float32)
+    zeros = jnp.zeros((hot_size, d), jnp.float32)
+
+    def add(acc, k, g):  # [H, D] += [C, D] at [C]
+        # a negative index would count from the end: every key outside
+        # the head goes to H, which "drop" drops
+        k = jnp.where((k >= 0) & (k < hot_size), k, hot_size)
+        return acc.at[k].add(g, mode="drop")
+
+    c = _PLAIN_SCATTER_SLOTS
+    if m <= c:
+        return add(zeros, keys, grads)
+    m_pad = ((m + c - 1) // c) * c
+    lanes = Layout(major_to_minor=(0, 1, 2))  # of [M/C, D, C]: slots minor
+    pieces = with_layout_constraint(
+        _pad_to(grads, m_pad, 0).reshape(-1, c, d).transpose(0, 2, 1), lanes
+    )
+    acc, _ = jax.lax.scan(
+        lambda acc, xs: (add(acc, xs[0], xs[1].T), None),
+        zeros,
+        (_pad_to(keys, m_pad, hot_size).reshape(-1, c), pieces),
+    )
+    return acc
+
+
 @jax.named_scope("xf.scatter")
 def hot_scatter(
     keys: jax.Array,
@@ -238,28 +322,23 @@ def hot_scatter(
     *,
     impl: str = "mxu",
 ) -> jax.Array:
-    """Sum per-occurrence gradients into a dense [H, D] buffer via
-    two-level one-hot matmuls (the MXU replacement for
-    ``zeros([H, D]).at[keys].add(grads)``).
+    """Sum per-occurrence gradients into a dense [H, D] buffer (the
+    head's ``zeros([H, D]).at[keys].add(grads)``).
 
     Args:
       keys: int32 [M]; entries outside [0, H) are dropped.
       grads: float [M, D].
       hot_size: H (power of two).
-      impl: "mxu" (one-hot matmuls) or "seg" (segment-sum into the
-        [H, D] buffer — the CPU-fast form; same sums, summation order
-        differs like the MXU path differs from ``.at[].add``).
+      impl: "mxu" — the two-level one-hot scan; "seg" — a plain
+        scatter-add into the [H, D] buffer, a piece of the slots at a
+        time (also the CPU-fast form); "auto" — by the buffer's width
+        (``scatter_form``).  Same sums; the summation order differs.
 
     Returns: [H, D] float32 gradient sums.
     """
     m, d = grads.shape
-    if impl == "seg":
-        seg = jnp.where(
-            (keys >= 0) & (keys < hot_size), keys, jnp.int32(hot_size)
-        )
-        return jax.ops.segment_sum(
-            grads.astype(jnp.float32), seg, num_segments=hot_size + 1
-        )[:hot_size]
+    if scatter_form(d, impl) == "seg":
+        return _plain_scatter(keys, grads, hot_size)
     h1, h2 = hot_factors(hot_size)
     c = _chunk(h1, h2, d, m)
     m_pad = ((m + c - 1) // c) * c

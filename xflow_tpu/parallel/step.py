@@ -32,7 +32,7 @@ from xflow_tpu.config import Config
 from xflow_tpu.io.batch import Batch
 from xflow_tpu.models.base import BatchArrays, Model
 from xflow_tpu.obs import NULL_OBS
-from xflow_tpu.ops.hot import gather_form
+from xflow_tpu.ops.hot import gather_form, scatter_form
 from xflow_tpu.ops.sparse import (
     consolidate_apply,
     consolidate_plan,
@@ -835,12 +835,14 @@ class TrainStep:
         self._plane_lengths: dict[tuple[int, str], int] = {}
         self._plane_lengths_lock = threading.Lock()
         # Hot-path implementation (ops/hot.py; Config.hot_impl).  On the
-        # TPU "auto" stays "auto": the scatter is the one-hot MXU scan at
-        # every width, the gather the scan at D = 1 and plain indexing of
-        # the [H, D] slice from hot.PLAIN_GATHER_MIN_COLUMNS columns up
-        # (hot.gather_form).  Elsewhere gather + segment-sum: the MXU
-        # trick measured 3.3x SLOWER than the gather on the CPU backend
-        # (docs/PERF.md "Wire format and compaction").
+        # TPU "auto" stays "auto": each direction takes the cheaper form
+        # for the table's width, the one-hot MXU scan under
+        # hot.PLAIN_GATHER_MIN_COLUMNS columns (hot.gather_form) and
+        # under hot.PLAIN_SCATTER_MIN_COLUMNS (hot.scatter_form), plain
+        # indexing of the [H, D] slice from there up.  Elsewhere gather +
+        # scatter-add: the MXU trick measured 3.3x SLOWER than the
+        # gather on the CPU backend (docs/PERF.md "Wire format and
+        # compaction").
         platform = str(self.mesh.devices.ravel()[0].platform)
         self._hot_impl = (
             cfg.hot_impl
@@ -848,13 +850,15 @@ class TrainStep:
             else "seg"
         )
         # tables on the head whose hot slots hot_gather reads by plain
-        # indexing, and by the scan (_book_wire)
-        forms = [
-            gather_form(spec.dim, self._hot_impl)
-            for spec in model.tables() if spec.hot
-        ]
+        # indexing, and by the scan; and the same of hot_scatter's sums
+        # (_book_wire)
+        head = [spec.dim for spec in model.tables() if spec.hot]
+        forms = [gather_form(d, self._hot_impl) for d in head]
         self._hot_plain_tables = forms.count("seg")
         self._hot_scan_tables = forms.count("mxu")
+        forms = [scatter_form(d, self._hot_impl) for d in head]
+        self._hot_scatter_plain_tables = forms.count("seg")
+        self._hot_scatter_scan_tables = forms.count("mxu")
         # In-window lane shuffle of the dictionary-wire decode
         # (ops/window.py): Mosaic's one-vreg dynamic_gather on the TPU,
         # the plain minor-axis gather elsewhere.
@@ -1066,9 +1070,11 @@ class TrainStep:
         rides the head.  The slots that DO ride it are booked by the form
         hot_gather reads them in, a table (ops/hot.py::gather_form, from
         the table's width): ``hot_plain_slots`` by plain indexing of the
-        [H, D] slice, ``hot_scan_slots`` by the one-hot scan.  A padded
-        slot counts like a live one (the gather reads row 0 for it; the
-        scatter-add drops it).  Where the
+        [H, D] slice, ``hot_scan_slots`` by the one-hot scan; and by the
+        form hot_scatter sums their gradients in (scatter_form, its own
+        constant): ``hot_scatter_plain_slots`` / ``hot_scatter_scan_slots``.
+        A padded slot counts like a live one (the gather reads row 0 for
+        it; the scatter-add drops it).  Where the
         step reads the ``cold_plan``, ``cold_row_layout_slots`` is the
         padded cold slots of every table wide enough for dict_cold_rows
         to lay its rows out by row gathers: 0 where every table goes
@@ -1125,6 +1131,14 @@ class TrainStep:
             )
             self.obs.counter(
                 "wire.hot_scan_slots", hot_slots * self._hot_scan_tables
+            )
+            self.obs.counter(
+                "wire.hot_scatter_plain_slots",
+                hot_slots * self._hot_scatter_plain_tables,
+            )
+            self.obs.counter(
+                "wire.hot_scatter_scan_slots",
+                hot_slots * self._hot_scatter_scan_tables,
             )
             self.obs.counter(
                 "wire.gather_row_bytes", indices * self._row_bytes + plain

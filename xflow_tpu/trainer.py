@@ -1100,12 +1100,15 @@ class Trainer:
                 # and what those slots move in bytes of table rows, with
                 # the hot slots of a table that opted out of the MXU head;
                 # the head's own slots by the form its gather read them in
-                # (ops/hot.py::gather_form); and the elements of the
+                # (ops/hot.py::gather_form) and by the form its scatter
+                # summed them in (scatter_form); and the elements of the
                 # one-column tables whose optimizer pass ran on the flat
                 # view (step.py::_optimizer_pass)
                 for name in (
                     "gather_row_bytes", "scatter_row_bytes", "plain_hot_slots",
-                    "hot_plain_slots", "hot_scan_slots", "flat_pass_elements",
+                    "hot_plain_slots", "hot_scan_slots",
+                    "hot_scatter_plain_slots", "hot_scatter_scan_slots",
+                    "flat_pass_elements",
                 ):
                     stats["_wire"][f"{name}_per_step"] = round(
                         snap.counters[f"wire.{name}"] / batches
