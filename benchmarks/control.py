@@ -38,7 +38,9 @@ class InBfloat16:
     """A family of ``reference/`` whose forward and backward see the gathered
     rows, and its dense parameters where it has any, rounded to bfloat16 (a
     gradient that autodiff takes through the rounding comes back rounded
-    too); the FTRL and SGD recurrences stay in float32."""
+    too); the FTRL and SGD recurrences stay in float32.  The ReLU arguments
+    the check looks at (``reference/wide_deep.py::relu_arguments``) are those
+    of the rounded forward: ``logit`` is the one entry both go through."""
 
     def __init__(self, family):
         self.family = family

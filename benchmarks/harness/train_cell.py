@@ -122,7 +122,7 @@ def run(ctx: Ctx, build_corpus) -> Outcome:
         )
         family = manifest.reference(ctx.config_doc["family"])
         ref = refcheck.check_train_steps(
-            trainer, family, batches[: mix["reference_steps"]], cfg,
+            trainer, family, batches[: mix["reference_steps"]], cfg, ctx.meter,
         )
         checks["steps_match_reference"] = ref["ok"]
         dispatched += len(ref["steps"])

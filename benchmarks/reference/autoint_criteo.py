@@ -27,7 +27,11 @@ ends.
 
 A layer runs over a block of ``DENSE_BLOCK`` examples and is wrapped in
 ``jax.checkpoint`` (the same mathematics, its projections and scores computed
-again in the backward) so that the step fits beside a live trainer.
+again in the backward) so that the step fits beside a live trainer.  The
+wrapper ends at the ReLU's argument: ``relu`` itself stays outside it, because
+what it hands the check (``wide_deep.relu_arguments``) has to leave the trace
+it was made in, and a ``[block, M, H d']`` argument kept for the backward is
+42 MB a layer.
 
 Departures from the paper, the program's (``xflow_tpu/models/autoint.py``) and
 this file's alike:
@@ -79,8 +83,9 @@ def presence(x, slots, num_fields: int):
 
 
 @jax.checkpoint
-def layer(wq, wk, wv, wr, e, present):
-    """e [B, M, d_l], present bool [B, M] -> [B, M, H d']."""
+def mixed_fields(wq, wk, wv, wr, e, present):
+    """e [B, M, d_l], present bool [B, M] -> [B, M, H d'], the weighted sums
+    plus the residual: the ReLU's argument."""
     b, m, _ = e.shape
 
     def heads(a):
@@ -90,7 +95,12 @@ def layer(wq, wk, wv, wr, e, present):
     psi = jnp.einsum("bmhc,bkhc->bhmk", q, k)
     alpha = jax.nn.softmax(psi, axis=-1, where=present[:, None, None, :])
     mixed = jnp.einsum("bhmk,bkhc->bmhc", alpha, v).reshape(b, m, -1)
-    return jnp.where(present[..., None], relu(mixed + e @ wr), 0.0)
+    return mixed + e @ wr
+
+
+def layer(wq, wk, wv, wr, e, present):
+    """e [B, M, d_l], present bool [B, M] -> [B, M, H d']."""
+    return relu(mixed_fields(wq, wk, wv, wr, e, present), present[..., None])
 
 
 def logit(rows: dict, x, slots, num_fields: int, dense: dict):

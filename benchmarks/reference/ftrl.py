@@ -45,6 +45,12 @@ matmuls a step has to do, for the roofline's FLOPs
 (the widths are stated once, by the program's state).  For a family without
 ``DENSE`` the two arguments stay out of the compiled program, which is the
 one it always was.
+
+A dense family's ReLUs are ``reference/wide_deep.py::relu`` and nothing else,
+and ``relu_margins`` is how the check (``harness/refcheck.py``) asks, before
+a step, how near 0 each example's ReLU arguments stand and how large the
+call's arguments run: an example nearer a kink than a few float32 steps of
+the largest takes part in that step on neither side.
 """
 
 from __future__ import annotations
@@ -54,6 +60,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from benchmarks.reference.wide_deep import relu_arguments
 
 
 HYPER_KEYS = ("alpha", "beta", "lambda1", "lambda2")  # ftrl.h:17-20
@@ -142,6 +150,38 @@ def _dense_step(family, rows, idx, x, labels, weights, fields, dense, sgd_lr):
     (pushed, grad_dense), p = jax.lax.scan(one, zeros, tuple(map(blocks, planes)))
     new_dense = jax.tree.map(lambda a, g: a - sgd_lr * g, dense, grad_dense)
     return p.reshape(-1), pushed, new_dense
+
+
+@functools.partial(jax.jit, static_argnames=("family", "num_fields"))
+def relu_margins(family, rows, idx, x, slots=None, num_fields=0, dense=None):
+    """How near 0 the ReLUs of a dense family's forward stand in each example,
+    and how large their arguments run: ``(margin [L, B], largest [L])`` for
+    the L calls of ``relu`` in one ``family.logit``, in call order (L = 0
+    where it calls none).  ``margin[l, i]`` is the smallest ``|argument|`` of
+    call l in example i and ``largest[l]`` the largest of call l in the batch,
+    both over the entries that count.  One forward at ``highest``, block by
+    block as ``_dense_step`` takes it: nothing of ``[B, K, D]`` is held
+    whole."""
+    fields = (slots, num_fields) if getattr(family, "USES_FIELDS", False) else ()
+    planes, num_fields = (idx, x) + fields[:1], fields[1:]
+    block = math.gcd(x.shape[0], DENSE_BLOCK)
+
+    def one(blk):
+        idx_b, x_b, *slots_b = blk
+        gathered = {t: r["param"][idx_b] for t, r in rows.items()}
+        with relu_arguments() as seen:
+            family.logit(gathered, x_b, *slots_b, *num_fields, dense)
+        seen = [jnp.abs(a).reshape(block, -1) for a in seen]
+        margin = jnp.asarray([a.min(axis=1) for a in seen]).reshape(len(seen), block)
+        # an entry that does not count is ``inf``
+        largest = [jnp.max(jnp.where(jnp.isfinite(a), a, 0.0)) for a in seen]
+        return margin, jnp.asarray(largest).reshape(len(seen))
+
+    blocks = tuple(a.reshape(-1, block, *a.shape[1:]) for a in planes)
+    with jax.default_matmul_precision("highest"):
+        margin, largest = jax.lax.map(one, blocks)  # [blocks, L, block], [blocks, L]
+    calls = largest.shape[1]
+    return margin.transpose(1, 0, 2).reshape(calls, x.shape[0]), largest.max(axis=0)
 
 
 @functools.partial(

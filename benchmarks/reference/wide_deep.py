@@ -20,6 +20,9 @@ it yet.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+
 import jax.numpy as jnp
 
 EMB_DIM = 8  # Config.emb_dim's default
@@ -39,9 +42,41 @@ def tower(emb, x, slots, num_fields: int):
     return sums.at[row, field].add(ex).reshape(x.shape[0], -1)
 
 
-def relu(a):
-    """Gradient 0 at 0 and below, as ``jax.nn.relu``'s."""
-    return jnp.where(a > 0.0, a, 0.0)
+_SEEN: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "relu_arguments", default=None
+)
+
+
+@contextlib.contextmanager
+def relu_arguments():
+    """Within, every call of ``relu`` leaves its argument in the list this
+    yields, in call order, an entry its ``live`` says does not count as
+    ``inf``: how ``reference/ftrl.py::relu_margins`` sees how near 0 each
+    example's ReLUs stand.  Filled while a function is traced, so open it
+    inside the traced function and return what it holds from there; for the
+    same reason a family keeps ``relu`` OUTSIDE any ``jax.checkpoint`` it wraps
+    a layer in (``reference/autoint_criteo.py``: the wrapper ends where the
+    argument is made), or the argument could not leave the inner trace."""
+    seen: list = []
+    token = _SEEN.set(seen)
+    try:
+        yield seen
+    finally:
+        _SEEN.reset(token)
+
+
+def relu(a, live=None):
+    """Gradient 0 at 0 and below, as ``jax.nn.relu``'s.  EVERY ReLU of every
+    reference family is this function: the check leaves out of its steps the
+    examples in which an argument lies within rounding of 0
+    (``harness/refcheck.py``), and a ReLU it cannot see is one whose flips it
+    would hold against the program.  ``live`` (broadcast against ``a``) marks
+    the entries that reach the logit; the others come out 0, have gradient 0
+    on either side of the kink and are no tie."""
+    seen = _SEEN.get()
+    if seen is not None:
+        seen.append(a if live is None else jnp.where(live, a, jnp.inf))
+    return jnp.where(a > 0.0 if live is None else live & (a > 0.0), a, 0.0)
 
 
 def logit(rows: dict, x, slots, num_fields: int, dense: dict):

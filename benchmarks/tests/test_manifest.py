@@ -279,8 +279,15 @@ def test_a_later_family_with_dense_parameters_is_a_configuration_file(tmp_path):
         assert one["value"] <= one["limit"] == refcheck.DENSE_RTOL
         assert compared[f"dense_update_ulps.{array}"]["value"] > 0.0
     assert compared["dense_update_max"]["value"] > 0.0
+    # its forward calls ``reference/wide_deep.py::relu``, so the share of
+    # examples left out on a kink is on the line beside its limit
+    share = compared["relu_tie_share"]
+    assert share["value"] <= share["limit"] == refcheck.TIE_SHARE_MAX
     with open(os.path.join(root, ".bench_cache", "made_up_wide_deep.train_packed.last.json")) as f:
-        costs = json.load(f)["run"]["costs"]
+        last = json.load(f)["run"]
+    costs = last["costs"]
+    assert all(len(s["relu"]["threshold"]) == 1 for s in last["reference"]["steps"])
+    assert all(s["relu"]["compiles"] == 0 for s in last["reference"]["steps"])
     assert costs["flops"] == 6.0 * 512 * (320 * 64 + 64)
     after = _digests(root)
     assert {k: after[k] for k in before} == before  # no existing file edited

@@ -543,6 +543,8 @@ def test_the_control_is_outside_the_tolerance(model, family, hot_log2):
         return max(max(s["rows_rel_err"].values()) for s in check["steps"])
 
     assert sound["ok"] and not got["ok"]
+    # the control's forward hands out its ReLU arguments as the family's does
+    assert all(("relu" in s) == hasattr(family, "DENSE") for s in got["steps"])
     # toy size: sound <= 4.0e-7 (mvm), the control >= 2.8e-6 (lr, hot 2^5)
     assert 2 * worst(sound) < refcheck.ROWS_RTOL < worst(got) / 2
     if hasattr(family, "DENSE"):
@@ -556,6 +558,287 @@ def test_the_control_is_outside_the_tolerance(model, family, hot_log2):
             )
 
         assert 2 * dense_worst(sound, max) < refcheck.DENSE_RTOL < dense_worst(got, min) / 2
+
+
+class _Nudged:
+    """``wide_deep`` with the biases of the units ``nudged`` lowered by ``by``
+    inside the forward: a reference that stands on the other side of a kink
+    than the program does, as two sound orders of one sum leave them on the
+    chip (a contraction of a thousand inputs or more) and never on the CPU."""
+
+    TABLES, USES_FIELDS, DENSE = wide_deep.TABLES, True, True
+    matmuls = staticmethod(wide_deep.matmuls)
+
+    def __init__(self, nudged=(), by=0.0):
+        self.nudged, self.by = tuple(nudged), by
+
+    def arguments(self, rows, x, slots, num_fields, dense):
+        t = wide_deep.tower(rows["emb"], x, slots, num_fields)
+        down = jnp.zeros_like(dense["b1"]).at[np.asarray(self.nudged, int)].set(self.by)
+        return t @ dense["w1"] + dense["b1"] - down
+
+    def logit(self, rows, x, slots, num_fields, dense):
+        wide = jnp.sum(rows["w"][..., 0] * x, axis=-1)
+        h = wide_deep.relu(self.arguments(rows, x, slots, num_fields, dense))
+        return wide + (h @ dense["w2"] + dense["b2"])[:, 0]
+
+
+def _planted(count: int):
+    """A toy ``wide_deep`` system whose first batch has ``count`` examples
+    with one first-layer argument each (each in a unit of its own) planted,
+    through ``b1``, one float32 step of the layer's largest argument above 0,
+    and the family that sees those units' arguments two such steps lower: the
+    program stands above the kink, the reference below it, by less than the
+    threshold.  -> (system, batch, cfg, family, the step, the examples)."""
+    system, batches, cfg = _system("wide_deep", 5)
+    batch = batches[0]
+    keys, x, slots = refcheck.entries(batch)
+    gathered = {
+        t: np.asarray(a["param"])[keys] for t, a in system.state["tables"].items()
+    }
+    dense = {k: np.asarray(v) for k, v in system.state["dense"].items()}
+    plain = _Nudged()
+    before = np.asarray(plain.arguments(gathered, x, slots, cfg.max_fields, dense))
+    ulp = float(np.spacing(np.abs(before).max()))
+    near = np.where(batch.weights[:, None] > 0, np.abs(before), np.inf)
+    examples, units = [], []
+    b1 = dense["b1"].copy()
+    for _ in range(count):  # the smallest argument, each in an example and a unit of its own
+        i, j = np.unravel_index(np.argmin(near), near.shape)
+        b1[j] += np.float32(ulp) - before[i, j]
+        near[i, :], near[:, j] = np.inf, np.inf
+        examples.append(int(i))
+        units.append(int(j))
+    system.state["dense"]["b1"] = jnp.asarray(b1)
+    after = np.asarray(plain.arguments(gathered, x, slots, cfg.max_fields, {**dense, "b1": b1}))
+    assert float(np.spacing(np.abs(after).max())) == ulp
+    assert all(0.5 * ulp < after[i, j] < 1.5 * ulp for i, j in zip(examples, units))
+    return system, batch, cfg, _Nudged(units, np.float32(2 * ulp)), ulp, examples
+
+
+def _over(step: dict) -> set:
+    """The numbers of a step's record that are over their limits."""
+    out = {f"dense_rel_err.{a}" for a, d in step["dense"].items()
+           if d["rel_err"] > refcheck.DENSE_RTOL}
+    out |= {f"rows_rel_err.{a}" for a, e in step["rows_rel_err"].items()
+            if e > refcheck.ROWS_RTOL}
+    return out | ({"logloss_err"} if step["logloss_err"] > refcheck.LOGLOSS_ATOL else set())
+
+
+def test_an_example_on_a_relus_kink_is_left_out_of_the_step_on_both_sides(monkeypatch):
+    """One unit of one example within rounding of 0, the program above and the
+    reference below: the forward barely moves (the logloss agrees), the
+    backward's gate for that example is 1 on one side and 0 on the other, and
+    the comparison without the tie rule (the parent's: a factor of 0 ties
+    nothing) reads a dense array and a row over their limits.  Under the rule
+    the reference finds that example, and that one alone, from its own
+    forward before the step; weight 0 on both sides, and every reading is
+    under its limit."""
+    system, batch, cfg, family, ulp, examples = _planted(1)
+    monkeypatch.setattr(refcheck, "TIE_FACTOR", 0.0)
+    got = refcheck.check_train_steps(system, family, [batch], cfg)
+    step = got["steps"][0]
+    assert not got["ok"] and step["relu"]["under"] == [0]
+    assert {n.split(".")[0] for n in _over(step)} == {"dense_rel_err", "rows_rel_err"}
+    assert max(d["rel_err"] for d in step["dense"].values()) > 100 * refcheck.DENSE_RTOL
+    monkeypatch.undo()
+
+    system, batch, cfg, family, ulp, examples = _planted(1)
+    got = refcheck.check_train_steps(system, family, [batch], cfg)
+    step = got["steps"][0]
+    assert got["ok"] and _over(step) == set()
+    real = int(batch.weights.sum())
+    assert step["relu"] == {
+        "share": 1 / real, "threshold": [refcheck.TIE_FACTOR * ulp], "under": [1],
+    }
+    assert refcheck.dense_compared(got["steps"])["relu_tie_share"] == {
+        "value": 1 / real, "op": "<=", "limit": refcheck.TIE_SHARE_MAX,
+    }
+    # the batch with a weight set to 0 is the same planes to the program: the
+    # step it takes is the compiled one (on the chip: the window's)
+    import dataclasses
+
+    fewer = dataclasses.replace(batch, weights=batch.weights * (np.arange(64) != examples[0]))
+    shapes = [
+        jax.tree.map(lambda a: (a.shape, a.dtype), system.step.put_batch(b))
+        for b in (batch, fewer)
+    ]
+    assert shapes[0] == shapes[1]
+    # which example: the reference's own margins say
+    keys, x, slots = refcheck.entries(batch)
+    rows = {t: {"param": jnp.asarray(np.asarray(a["param"]))}
+            for t, a in _planted(1)[0].state["tables"].items()}
+    margin, read = ftrl.relu_margins(
+        family, rows, jnp.asarray(keys), jnp.asarray(x), jnp.asarray(slots),
+        cfg.max_fields, _planted(1)[0].state["dense"],
+    )
+    tie, _ = refcheck.relu_ties(np.asarray(margin), np.asarray(read), batch.weights)
+    assert np.flatnonzero(tie).tolist() == examples
+
+
+def test_a_batch_cannot_hide_behind_its_ties():
+    """Seven tie examples of 59 real ones are more than ``TIE_SHARE_MAX``
+    allows: every other number is under its limit and the step still fails."""
+    system, batch, cfg, family, _, examples = _planted(7)
+    got = refcheck.check_train_steps(system, family, [batch], cfg)
+    step = got["steps"][0]
+    assert step["relu"]["under"] == [7] and _over(step) == set()
+    assert step["relu"]["share"] == 7 / int(batch.weights.sum()) > refcheck.TIE_SHARE_MAX
+    assert not got["ok"]
+    share = refcheck.dense_compared(got["steps"])["relu_tie_share"]
+    assert share["value"] > share["limit"]
+    # a forward whose ReLUs are dead, every argument exactly 0, ties whole
+    tie, read = refcheck.relu_ties(np.zeros((2, 8)), np.zeros(2), np.ones(8))
+    assert tie.all() and read["share"] == 1.0
+
+
+class _OwnRelu:
+    """``wide_deep`` with a ReLU that is not ``wide_deep.relu``: the check
+    sees no call (``reference/`` may hold no such family: the scan below)."""
+
+    TABLES, USES_FIELDS, DENSE = wide_deep.TABLES, True, True
+
+    @staticmethod
+    def logit(rows, x, slots, num_fields, dense):
+        wide = jnp.sum(rows["w"][..., 0] * x, axis=-1)
+        t = wide_deep.tower(rows["emb"], x, slots, num_fields)
+        h = jax.nn.relu(t @ dense["w1"] + dense["b1"])
+        return wide + (h @ dense["w2"] + dense["b2"])[:, 0]
+
+
+def test_a_forward_that_never_calls_relu_has_no_ties_and_no_share():
+    """A dense family whose forward calls ``relu`` nowhere is stepped as it
+    always was and its ``compared`` has the parent's keys; so has a family
+    without dense parameters, which is never asked."""
+    system, batches, cfg = _system("wide_deep", 5)
+    got = refcheck.check_train_steps(system, _OwnRelu, batches, cfg)
+    assert got["ok"] and not any("relu" in s for s in got["steps"])
+    compared = refcheck.dense_compared(got["steps"])
+    assert "relu_tie_share" not in compared
+    assert {k.split(".")[0] for k in compared} == {
+        "dense_rel_err", "dense_update_ulps", "dense_update_max",
+    }
+    system, batches, cfg = _system("mvm", 5)
+    got = refcheck.check_train_steps(system, mvm, batches, cfg)
+    assert got["ok"] and not any("relu" in s or "dense" in s for s in got["steps"])
+    assert refcheck.dense_compared(got["steps"]) == {}
+
+
+def test_relu_hands_out_its_arguments_only_while_looked_at():
+    a = jnp.asarray([[1e-9, -2.0], [3.0, 0.0]])
+    live = jnp.asarray([[False, True], [True, True]])
+    with wide_deep.relu_arguments() as seen:
+        out = wide_deep.relu(a, live)
+        wide_deep.relu(a)
+    wide_deep.relu(a)  # nobody looks: nothing is kept
+    assert len(seen) == 2
+    np.testing.assert_array_equal(out, [[0.0, 0.0], [3.0, 0.0]])
+    # an entry that does not reach the logit is no tie, however near 0
+    np.testing.assert_array_equal(seen[0], [[np.inf, -2.0], [3.0, 0.0]])
+    np.testing.assert_array_equal(seen[1], a)
+    np.testing.assert_array_equal(jax.grad(lambda v: wide_deep.relu(v, live).sum())(a),
+                                  [[0.0, 0.0], [1.0, 0.0]])
+
+
+class _ReluUnderCheckpoint(_Nudged):
+    """``wide_deep`` with its ReLU INSIDE a ``jax.checkpoint``: the argument
+    cannot leave the trace it was made in."""
+
+    def logit(self, rows, x, slots, num_fields, dense):
+        hidden = jax.checkpoint(
+            lambda r, v, f, d: wide_deep.relu(self.arguments(r, v, f, num_fields, d))
+        )(rows, x, slots, dense)
+        return (hidden @ dense["w2"] + dense["b2"])[:, 0]
+
+
+def test_a_relu_inside_a_checkpoint_is_an_error_not_a_pass():
+    system, batches, cfg = _system("wide_deep", 5)
+    with pytest.raises(jax.errors.UnexpectedTracerError):
+        refcheck.check_train_steps(system, _ReluUnderCheckpoint(), batches[:1], cfg)
+
+
+def test_a_step_that_took_out_ties_may_compile_nothing():
+    """With the run's meter the check counts the programs compiled by the
+    steps whose batches lost their tie examples: a trainer that had compiled
+    its step reads 0, one that meets the batch fresh fails by that number
+    alone."""
+    from benchmarks.harness import compiles
+
+    meter = compiles.CompileMeter()
+    system, batch, cfg, family, _, _ = _planted(1)
+    got = refcheck.check_train_steps(system, family, [batch], cfg, meter)
+    step = got["steps"][0]
+    assert step["relu"]["compiles"] > 0 and _over(step) == set() and not got["ok"]
+    compared = refcheck.dense_compared(got["steps"])
+    assert compared["relu_step_compiles"]["value"] > compared["relu_step_compiles"]["limit"] == 0
+    # (the second step meets the state as a step leaves it, the last new thing)
+    refcheck.check_train_steps(system, family, [batch], cfg, meter)
+    again = refcheck.check_train_steps(system, family, [batch], cfg, meter)
+    assert again["steps"][0]["relu"]["compiles"] == 0
+    assert refcheck.dense_compared(again["steps"])["relu_step_compiles"]["value"] == 0
+    # without a meter nothing is counted and the line has no such key
+    bare = refcheck.check_train_steps(system, family, [batch], cfg)
+    assert "relu_step_compiles" not in refcheck.dense_compared(bare["steps"])
+
+
+def test_every_relu_of_a_reference_family_is_the_one_the_check_sees():
+    """``reference/*.py`` spells a ReLU nowhere but in ``wide_deep.relu``
+    (no ``x.relu(...)`` of another module, no ``maximum`` or ``clip``
+    against a literal 0, no ``where`` on a comparison with a literal 0 that
+    picks a literal 0), and a bare ``relu`` is the one imported from there:
+    a ReLU the check cannot see is an error, not a pass."""
+    import ast
+    import glob
+    import os
+
+    from benchmarks.harness import manifest
+
+    def zero(node) -> bool:
+        return isinstance(node, ast.Constant) and node.value == 0
+
+    def spelt(call, ours: bool) -> bool:
+        func = call.func
+        what = func.attr if isinstance(func, ast.Attribute) else getattr(func, "id", "")
+        return bool(
+            (what in ("relu", "relu6", "leaky_relu")
+             and (isinstance(func, ast.Attribute) or not ours))
+            or (what in ("maximum", "clip") and any(map(zero, call.args)))
+            or (what == "where" and len(call.args) == 3
+                and isinstance(call.args[0], ast.Compare)
+                and any(map(zero, call.args[0].comparators + [call.args[0].left]))
+                and any(map(zero, call.args[1:])))
+        )
+
+    def calls(tree):
+        return [n for n in ast.walk(tree) if isinstance(n, ast.Call)]
+
+    found = []
+    for path in sorted(glob.glob(os.path.join(manifest.BENCH_DIR, "reference", "*.py"))):
+        name = os.path.basename(path)
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        ours = name == "wide_deep.py" or any(
+            isinstance(n, ast.ImportFrom) and n.module == "benchmarks.reference.wide_deep"
+            and any(a.name == "relu" and a.asname is None for a in n.names)
+            for n in tree.body
+        )
+        inside = {
+            id(c) for n in tree.body
+            if name == "wide_deep.py" and isinstance(n, ast.FunctionDef) and n.name == "relu"
+            for c in ast.walk(n)
+        }
+        found += [
+            (name, c.lineno, ast.unparse(c)) for c in calls(tree)
+            if spelt(c, ours) and id(c) not in inside
+        ]
+    assert found == []
+    # the scan sees what it is for, and lets the shared one through
+    for line in ("jnp.where(a > 0.0, a, 0.0)", "jax.nn.relu(a)", "jnp.maximum(a, 0)",
+                 "jnp.clip(a, 0.0, None)", "relu(a)"):
+        assert spelt(calls(ast.parse(line))[0], ours=False), line
+    for line in ("relu(a)", "relu(a, live)", "jnp.where(live, a, 0.0)",
+                 "jnp.maximum(jnp.sum(w), 1.0)", "jnp.where(x > 30.0, 1.0, p)"):
+        assert not spelt(calls(ast.parse(line))[0], ours=True), line
 
 
 @pytest.mark.parametrize("cell, check", [
