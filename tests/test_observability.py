@@ -1151,6 +1151,7 @@ _DENSE_MODELS = {
     "two_tower": dict(emb_dim=4, hidden_dim=8, tower_dim=4, tower_split_field=4),
     "xdeepfm": dict(emb_dim=10, hidden_dim=8, cross_layers=3, cin_maps=6, deep_layers=2),
     "autoint": dict(emb_dim=16, attn_heads=2, attn_dim=4, cross_layers=2),
+    "fibinet": dict(emb_dim=10, hidden_dim=8, deep_layers=3, senet_reduction=3),
 }
 
 
@@ -1171,9 +1172,10 @@ def test_a_dense_family_runs_its_dense_half_under_xf_dense(toy_dataset, model):
     rows = _op_scope_rows(toy_dataset, model=model, **_DENSE_MODELS[model])
     scopes = {scope for _, _, scope in rows}
     assert {"xf.dense", "xf.forward_backward", "xf.optimizer"} <= scopes
-    assert scopes <= SCOPES | {"xf.dense", "xf.cin", "xf.attn", ""}
+    assert scopes <= SCOPES | {"xf.dense", "xf.cin", "xf.attn", "xf.bilinear", ""}
     assert ("xf.cin" in scopes) == (model == "xdeepfm")
     assert ("xf.attn" in scopes) == (model == "autoint")
+    assert ("xf.bilinear" in scopes) == (model == "fibinet")
 
 
 def test_xf_cin_is_the_innermost_name_of_every_cin_operation(toy_dataset):
@@ -1255,6 +1257,42 @@ def test_xf_attn_is_the_innermost_name_of_every_attention_operation(toy_dataset)
     assert sum(scope == "xf.dense" for _, _, scope in rows) >= 2
 
 
+def test_xf_bilinear_is_the_innermost_name_of_every_block_operation(toy_dataset):
+    """FiBiNET's gate-and-bilinear block runs under ``xf.bilinear``, a sibling
+    of ``xf.dense`` inside ``xf.forward_backward``.  In the compiled step every
+    operation whose path holds ``xf.bilinear`` has it as the INNERMOST ``xf.``
+    name, whatever wraps it (``jvp``, ``transpose``, ``checkpoint``), forward
+    and backward are both there with the fields' products among them, the
+    hidden stack's products stay ``xf.dense``'s, and ``op_scopes`` maps the
+    compiled instructions to it: the rows the ``_scopes`` record ships (the
+    schema holds a scope as a string: ``test_dense_counters_reach_the_wire_row``
+    validates a run of this family)."""
+    from xflow_tpu.parallel.step import _SCOPE_RE, abstract_like, scope_of
+
+    cfg = _toy_cfg(toy_dataset, model="fibinet", max_fields=8, **_DENSE_MODELS["fibinet"])
+    with Trainer(cfg) as t:
+        batch, _, _ = next(iter(t.iter_train_batches(0, 0)))
+        arrays = t.step.put_batch(batch)
+        text = t.step.train.lower(
+            abstract_like(t.state), abstract_like(arrays)
+        ).compile().as_text()
+        rows = t.step.op_scopes(t.state, arrays)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    block = [p for p in paths if "xf.bilinear" in p]
+    assert len(block) >= 10
+    assert all(scope_of(p) == "xf.bilinear" for p in block)
+    assert all(_SCOPE_RE.findall(p)[0] == "xf.forward_backward" for p in block)
+    forward = [p for p in block if "transpose" not in p]
+    backward = [p for p in block if "transpose(jvp(xf.bilinear))" in p]
+    assert forward and backward
+    dots = [p for p in block if p.endswith("dot_general")]
+    assert [p for p in dots if p in forward] and [p for p in dots if p in backward]
+    dense = [p for p in paths if scope_of(p) == "xf.dense"]
+    assert [p for p in dense if p.endswith("dot_general")]
+    assert sum(scope == "xf.bilinear" for _, _, scope in rows) >= 20
+    assert sum(scope == "xf.dense" for _, _, scope in rows) >= 6
+
+
 @pytest.mark.parametrize("model, overrides", [
     ("lr", {}), ("fm", {}), ("mvm", {"max_fields": 8}), ("ffm", {"max_fields": 8}),
 ])
@@ -1300,6 +1338,10 @@ def test_scope_of_takes_the_innermost_name():
         "jit(f)/xf.forward_backward/transpose(jvp(xf.attn))/while/body/closed_call/"
         "checkpoint/rematted_computation/exp"
     ) == "xf.attn"
+    assert scope_of(
+        "jit(f)/xf.forward_backward/transpose(jvp(xf.bilinear))/checkpoint/"
+        "rematted_computation/dot_general"
+    ) == "xf.bilinear"
 
 
 @pytest.mark.parametrize("model", sorted(_DENSE_MODELS))
@@ -1311,7 +1353,9 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
     arrays' shapes."""
     from benchmarks.harness import costs
     from benchmarks.layer_metrics import attn_mxu_roofline, cin_mxu_roofline
-    from benchmarks.reference import autoint_criteo, dcn_criteo, xdeepfm_criteo
+    from benchmarks.reference import (
+        autoint_criteo, dcn_criteo, fibinet_criteo, xdeepfm_criteo,
+    )
     from xflow_tpu.obs.schema import OPTIONAL, validate_rows
 
     metrics = tmp_path / "m.jsonl"
@@ -1330,6 +1374,8 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
             assert xdeepfm_criteo.matmuls(shapes) == matmuls
         if model == "autoint":
             assert autoint_criteo.matmuls(shapes) == matmuls
+        if model == "fibinet":
+            assert fibinet_criteo.matmuls(shapes) == matmuls
         param_bytes = sum(a.size * 4 for a in dense.values())
     rows = [json.loads(line) for line in metrics.read_text().splitlines()]
     assert validate_rows(rows) == []

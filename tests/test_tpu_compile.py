@@ -429,6 +429,9 @@ XDEEPFM_PLANES = {
 # batch (seed 1) ships the same plane capacities
 # (scripts/aot_dense_step.py --config autoint_ftrl_criteo_tb, PR 47)
 AUTOINT_PLANES = XDEEPFM_PLANES
+# so has FiBiNET's, whose sparse half IS xDeepFM's (two tables, w and emb)
+# (scripts/aot_dense_step.py --config fibinet_ftrl_criteo_tb, PR 52)
+FIBINET_PLANES = XDEEPFM_PLANES
 
 
 def _cell_train_step(topo, config: str):
@@ -1319,6 +1322,100 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
     ]
     peak = _program_peak(compiled)
     assert 7.5 * (1 << 30) < peak < 8.75 * (1 << 30), peak
+
+
+def test_fibinet_step_multiplies_pairs_in_float32_without_a_pair_matrix_array_on_v5e(
+    topo, no_compile_cache
+):
+    """The FiBiNET train step at the geometry of the benchmark's
+    fibinet_tb.train_packed (benchmarks/configs/fibinet_ftrl_criteo_tb.json:
+    2^25 rows, w of one column and emb of 10, B=16384, 8 + 32 slots, 40
+    fields, reduction 3, 780 pairs on two towers, three hidden layers of 400,
+    the dictionary wire's plane capacities of one real batch, seed 1; the
+    dense arrays handed in as shapes) for a described v5e.  Lowered: every
+    dot asks for float32 (Precision.HIGHEST), a field's product with the
+    matrices of its pairs among them (``[B, 10] x [10, n 10]``, the first
+    field's n = 39 on both towers, forward and again for the backward); at
+    default precision the TPU rounds both operands to bfloat16.  A tower's
+    pairs times their matrices would be ``B P D D`` = 1.28e9 floats whole
+    (5.1 GB) and the picked pairs ``B P D`` = 1.28e8: no array of the lowered
+    or the compiled program has ``B P D D`` elements, and beside the tables'
+    own none is larger than the pair tensor c ``[B, 2 P D]`` (the first
+    hidden layer's operand, 975 MiB, and its cotangent).  Compiled: no field
+    is cut out of the ``[B, m, D]`` tower as ``[B, 1, D]`` (one 128-lane row
+    an example: 134 MB a field, 10 GiB over both towers and both passes; the
+    fields come out of the flat ``[B, m D]`` tower), the block's
+    instructions carry ``xf.bilinear`` in ``op_scopes``' reading (the
+    innermost name) with its products (convolutions, as the TPU's compiler
+    writes a dot) among them, the hidden stack's stay ``xf.dense``'s, no
+    table-sized copy of emb's state is made, and the program fits with the
+    room the file's ``reduced`` argues from."""
+    from xflow_tpu.models import blocks
+    from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
+
+    cfg, step, lowered = _lowered_cell_step(
+        topo, "fibinet_ftrl_criteo_tb", FIBINET_PLANES
+    )
+    assert step._mxu_hot == {"w": True, "emb": True}
+    assert (cfg.senet_reduction, cfg.deep_layers, cfg.hidden_dim, cfg.emb_dim) == (3, 3, 400, 10)
+    b, m, d, h = cfg.batch_size, cfg.max_fields, cfg.emb_dim, cfg.hidden_dim
+    pairs = blocks.field_pairs(m)
+    assert pairs == 780 and blocks.bilinear_slice_rows(b, d, m) == b  # whole
+    text = lowered.as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
+
+    def dot(lhs: str, rhs: str, out: str) -> int:
+        sig = f"(tensor<{lhs}xf32>, tensor<{rhs}xf32>) -> tensor<{out}xf32>"
+        return sum(sig in line for line in dots)
+
+    n = (m - 1) * d  # the first field's 39 pairs, side by side
+    assert dot(f"{b}x{d}", f"{d}x{n}", f"{b}x{n}") >= 2  # both towers
+    assert dot(f"{b}x{n}", f"{b}x{d}", f"{n}x{d}") + dot(f"{b}x{d}", f"{b}x{n}", f"{d}x{n}") >= 2
+    assert dot(f"{b}x{2 * pairs * d}", f"{2 * pairs * d}x{h}", f"{b}x{h}") == 1
+    # the excitation: 40 gates squeezed to 13 and back
+    assert dot(f"{b}x{m}", f"{m}x{m // 3}", f"{b}x{m // 3}") >= 1
+    assert dot(f"{b}x{m // 3}", f"{m // 3}x{m}", f"{b}x{m}") >= 1
+
+    def elements(shape: str) -> int:
+        return math.prod(int(x) for x in re.split("[x,]", shape) if x)
+
+    c, table = b * 2 * pairs * d, cfg.table_size * d
+    made = {
+        shape for shape in re.findall(r"tensor<([0-9x]+)xf32>", text)
+        if elements(shape) != table
+    }
+    assert max(map(elements, made)) == c, sorted(made, key=elements)[-3:]
+    assert not [shape for shape in made if f"{pairs}x{d}x{d}" in shape and shape.startswith(str(b))]
+
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    arrays = {
+        shape for shape in re.findall(r"= \(?f32\[([0-9,]+)\]", hlo)
+        if elements(shape) not in (table, cfg.table_size)
+    }
+    assert max(map(elements, arrays)) == c, sorted(arrays, key=elements)[-3:]
+    assert f"{b},{pairs},{d},{d}" not in arrays and f"{b},{pairs},{d}" not in arrays
+    assert f"{b},1,{d}" not in arrays  # a field cut out of the 3-D tower
+    in_scope: dict[str, list[str]] = {"xf.bilinear": [], "xf.dense": []}
+    for line in hlo.splitlines():
+        found = _HLO_OP_NAME_RE.search(line)
+        if found and scope_of(found.group(1)) in in_scope:
+            in_scope[scope_of(found.group(1))].append(line)
+    block, dense = in_scope["xf.bilinear"], in_scope["xf.dense"]
+    assert all("xf.forward_backward" in line for line in block + dense)
+    paths = {_HLO_OP_NAME_RE.search(line).group(1) for line in block}
+    assert [p for p in paths if "transpose(jvp(xf.bilinear))" in p]
+    assert [p for p in paths if "transpose" not in p]
+    assert sum(" convolution(" in line for line in block) >= 4 * (m - 1)
+    assert sum(" convolution(" in line for line in dense) >= 9
+    assert not [line for line in block + dense if " while(" in line]
+    assert not [
+        line for line in _table_sized_copies(hlo, cfg.table_size)
+        if f"f32[{cfg.table_size},{d}]" in line
+    ]
+    peak = _program_peak(compiled)
+    assert 8.0 * (1 << 30) < peak < 10.0 * (1 << 30), peak
 
 
 def test_serving_program_takes_one_packed_buffer_on_v5e(topo, no_compile_cache):

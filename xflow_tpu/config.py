@@ -79,6 +79,10 @@ class Config:
     # setting: 3 layers of 2 heads of 32 over embeddings of 16).
     attn_heads: int = 2
     attn_dim: int = 8
+    # fibinet (models/fibinet.py): the SENET reduction ratio r, which squeezes
+    # max_fields gates to max_fields // r and back; its DNN is deep_layers of
+    # hidden_dim (the paper's Criteo setting: r = 3, 3 layers of 400).
+    senet_reduction: int = 3
     # Static padded features-per-sample inside the jit step.  Samples with
     # more features than this are truncated (reference has no limit —
     # features-per-sample is whatever the text line holds).
@@ -525,6 +529,8 @@ class Config:
             raise ValueError("cin_maps must be >= 1")
         if self.attn_heads < 1 or self.attn_dim < 1:
             raise ValueError("attn_heads and attn_dim must be >= 1")
+        if self.senet_reduction < 1:
+            raise ValueError("senet_reduction must be >= 1")
         if self.optimizer not in ("ftrl", "sgd"):
             raise ValueError(f"unknown optimizer {self.optimizer!r}")
         if self.update_mode not in ("dense", "sparse", "sequential"):

@@ -32,6 +32,7 @@ from xflow_tpu.models.autoint import AutoIntModel
 from xflow_tpu.models.base import AutodiffModel, Model, TableSpec
 from xflow_tpu.models.dcn import DCNModel
 from xflow_tpu.models.ffm import FFMModel
+from xflow_tpu.models.fibinet import FiBiNETModel
 from xflow_tpu.models.fm import FMModel
 from xflow_tpu.models.lr import LRModel
 from xflow_tpu.models.mvm import MVMModel
@@ -174,6 +175,20 @@ register_model(ModelFamily(
     "AutoInt ranker: multi-head self-attention over the fields of a row "
     "(per-example interactions, softmax over the fields the row has)",
 ))
+register_model(ModelFamily(
+    "fibinet",
+    lambda cfg: FiBiNETModel(
+        emb_dim=cfg.emb_dim,
+        senet_reduction=cfg.senet_reduction,
+        hidden=cfg.hidden_dim,
+        deep_layers=cfg.deep_layers,
+        max_fields=cfg.max_fields,
+        v_init_scale=cfg.v_init_scale,
+    ),
+    "FiBiNET ranker: a SENET gate a field computed from all of a row's "
+    "fields, and a learned matrix for every pair of fields on the plain and "
+    "on the gated embeddings, under an MLP",
+))
 
 
 __all__ = [
@@ -191,6 +206,7 @@ __all__ = [
     "DCNModel",
     "XDeepFMModel",
     "AutoIntModel",
+    "FiBiNETModel",
     "make_model",
     "model_family",
     "model_names",

@@ -568,3 +568,115 @@ def field_attention_stack(
         one_slice, (_to_slices(tower, slice_rows), _to_slices(present, slice_rows))
     )
     return _from_slices(out, tower.shape[0])
+
+
+# -- SENET gates and pair-indexed bilinear products (FiBiNET) -----------------
+
+# The device scope of the gate-and-bilinear block (docs/OBSERVABILITY.md): a
+# sibling of xf.dense, xf.cin and xf.attn inside xf.forward_backward.
+BILINEAR_SCOPE = "xf.bilinear"
+
+# The most the block's batch-sized arrays may take whole: the pair tensor c,
+# the two towers' left products (made again in the backward) and c's
+# cotangent, ``6 P D`` floats an example.  Read at ONE shape, FiBiNET's paper
+# sizes on a v5e (m = 40, D = 10: 183 KiB an example, 2.9 GiB at B = 16384),
+# where the whole batch is the fastest form measured, forward and backward of
+# the block alone, ms (scripts/probe_bilinear.py, PR 52): whole 16.0 (14.2
+# without its ``jax.checkpoint``), slices of 4096 examples 57.5, of 1024
+# 63.3; a ``[B, m D] x [m D, P D]`` product with the matrices in blocks 44.0
+# and the equation's einsum over picked pairs 49.0.  A slice writes its part
+# of c and a loop gives the compiler no room to fuse across it.  So the
+# budget only has to hold the cell's batch whole; a batch too large for it
+# goes in slices, at that price.
+BILINEAR_WHOLE_BYTES = 4 << 30
+
+
+def field_pairs(max_fields: int) -> int:
+    """``m (m - 1) / 2``: the pairs ``i < j`` of ``m`` fields."""
+    return max_fields * (max_fields - 1) // 2
+
+
+def bilinear_slice_rows(batch: int, dim: int, max_fields: int) -> int:
+    """How many examples a slice of ``senet_bilinear`` holds, from shapes: the
+    whole batch where its arrays (``6 P D`` floats an example) fit
+    ``BILINEAR_WHOLE_BYTES``, else as many as do, in whole lane widths.  THE
+    place the slice is decided: the model hands it to the block."""
+    per_example = 4 * 6 * field_pairs(max_fields) * dim
+    return _slice_rows(batch, per_example, BILINEAR_WHOLE_BYTES)
+
+
+def senet_gates(s1: jax.Array, s2: jax.Array, tower: jax.Array) -> jax.Array:
+    """FiBiNET's SENET layer over the field tower ``[B, m, D]`` -> one gate a
+    field ``[B, m]``, computed from ALL of the example's fields:
+
+        z_i = mean_d e_i[d]                    (squeeze, mean pooling)
+        a   = ReLU(ReLU(z S1) S2)              S1 [m, m // r], S2 [m // r, m]
+
+    (the paper's equations 5-6; no bias).  Both products are float32 on every
+    backend (``dense_dot``).  An absent field's squeeze is 0 and its gate
+    multiplies a zero vector: no presence mask enters."""
+    z = jnp.mean(tower, axis=-1)
+    return jax.nn.relu(dense_dot(jax.nn.relu(dense_dot(z, s1)), s2))
+
+
+def bilinear_pairs(w: jax.Array, tower: jax.Array) -> jax.Array:
+    """FiBiNET's Field-Interaction bilinear layer: ``w [P, D, D]``, a matrix
+    for every pair of fields ``i < j`` in lexicographic order, and the tower
+    ``[B, m, D]`` -> ``[B, P * D]``, pair p = (i, j) in columns ``p D .. (p +
+    1) D``:
+
+        out_p = (e_i W_p) * e_j                (the paper's equation 9, type 3)
+
+    Written field by field, in two-dimensional arrays whose minor axis is
+    hundreds of columns wide (a ``[B, P, D]`` array pads D = 10 to a lane
+    tile of 128 on the TPU, a field cut out of the ``[B, m, D]`` tower as
+    ``[B, 1, D]`` pads its 1 to 128, 134 MB a field where the cut out of the
+    flat tower is 1 MB, and a ``[B, P, D, D]`` array never exists): field
+    i's vector meets the matrices of ALL its pairs ``(i, j > i)`` side by side
+    in one product ``[B, D] x [D, (m - 1 - i) D]``, float32 on every backend
+    (``dense_dot``), and the right factors of those pairs are the flat tower
+    from field ``i + 1`` on, as it lies.  What chose the form:
+    scripts/probe_bilinear.py (``BILINEAR_WHOLE_BYTES`` has its readings)."""
+    b, m, d = tower.shape
+    flat = tower.reshape(b, m * d)
+    out, start = [], 0
+    for i in range(m - 1):
+        n = m - 1 - i
+        # [n, D, D] -> [D, n * D]: field i's matrices side by side
+        side = w[start:start + n].transpose(1, 0, 2).reshape(d, n * d)
+        left = dense_dot(flat[:, i * d:(i + 1) * d], side)
+        out.append(left * flat[:, (i + 1) * d:])
+        start += n
+    return jnp.concatenate(out, axis=-1)
+
+
+@jax.named_scope(BILINEAR_SCOPE)
+def senet_bilinear(
+    s1: jax.Array, s2: jax.Array, wp: jax.Array, wq: jax.Array,
+    tower: jax.Array, slice_rows: int,
+) -> jax.Array:
+    """FiBiNET's interaction over the field tower ``[B, m, D]`` -> the pair
+    tensor ``c [B, 2 P D]``: the bilinear pairs of the embeddings (``wp``)
+    beside those of the SENET-weighted embeddings ``v_i = a_i e_i`` (``wq``;
+    ``senet_gates``).  Whole where ``slice_rows`` holds the batch; else the
+    batch goes through ``lax.map`` slice by slice (zero rows pad the last,
+    give zeros and are cut off).  Either way the backward computes the
+    forward again (``jax.checkpoint``, nothing kept but the tower), as
+    ``field_attention_stack``'s: alone the whole block is 1.8 ms faster with
+    the towers' left products kept, but in the cell's step it is 1.3 ms
+    SLOWER (90.86 against 89.53 ms: the kept products cost more in copies
+    around the block than the second forward does) and 1.07 GiB larger
+    (PERF.md section 6, PR 52)."""
+    def one_slice(e: jax.Array) -> jax.Array:
+        v = senet_gates(s1, s2, e)[..., None] * e
+        return jnp.concatenate(
+            [bilinear_pairs(wp, e), bilinear_pairs(wq, v)], axis=-1
+        )
+
+    if slice_rows >= tower.shape[0]:
+        return jax.checkpoint(one_slice)(tower)
+    out = jax.lax.map(
+        jax.checkpoint(one_slice, prevent_cse=False),
+        _to_slices(tower, slice_rows),
+    )
+    return _from_slices(out, tower.shape[0])

@@ -133,8 +133,10 @@ SCHEMA: dict[str, dict[str, Any]] = {
     # only (trainer.train_epoch -> TrainStep.op_scopes): for every
     # instruction of the compiled train program(s) its name, its result
     # type as a profiler prints it, and the xf.* scope the source gave
-    # it ("" = none) — the map a profile's operation events are joined
-    # with (docs/OBSERVABILITY.md "Scopes and spans")
+    # it ("" = none; the innermost where scopes nest: xf.dense, xf.cin,
+    # xf.attn, xf.bilinear inside xf.forward_backward) — the map a
+    # profile's operation events are joined with (docs/OBSERVABILITY.md
+    # "Scopes and spans")
     "scopes": {
         "t": (int, float),
         "kind": str,

@@ -82,10 +82,11 @@ def scope_of(op_name: str) -> str:
     and scan wrap path components: ``transpose(jvp(xf.dense))``), ``""``
     where the path has none.  A scope opened inside another is the more
     specific name for its operations: the dense half ``xf.dense``, the
-    CIN ``xf.cin`` and the interacting layers ``xf.attn``
-    (models/blocks.py) run inside ``xf.forward_backward`` and are read
-    apart from it, the CIN's and the attention's also through their loop
-    and their rematerialised backward
+    CIN ``xf.cin``, the interacting layers ``xf.attn`` and the
+    gate-and-bilinear block ``xf.bilinear`` (models/blocks.py) run
+    inside ``xf.forward_backward`` and are read apart from it, the
+    CIN's and the attention's also through their loop, and all three
+    through their rematerialised backward
     (``transpose(jvp(xf.cin))/while/body/closed_call/checkpoint/...``).
     Until PR 39 the first name won; no program without a dense half
     nests two different scopes, so theirs map as they did

@@ -67,7 +67,8 @@ def build_parser() -> argparse.ArgumentParser:
     )
     p.add_argument(
         "--deep-layers", type=int, dest="deep_layers",
-        help="dcn, xdeepfm: ReLU layers of --hidden-dim in the deep half",
+        help="dcn, xdeepfm, fibinet: ReLU layers of --hidden-dim in the "
+        "deep half",
     )
     p.add_argument(
         "--cin-maps", type=int, dest="cin_maps",
@@ -80,6 +81,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--attn-dim", type=int, dest="attn_dim",
         help="autoint: width of one attention head",
+    )
+    p.add_argument(
+        "--senet-reduction", type=int, dest="senet_reduction",
+        help="fibinet: the SENET reduction ratio (gates squeezed to "
+        "max_fields // r and back)",
     )
     p.add_argument("--max-nnz", type=int, dest="max_nnz")
     p.add_argument("--max-fields", type=int, dest="max_fields")
