@@ -549,18 +549,27 @@ def test_xdeepfm_survives_checkpoint_and_artifact_and_serves_the_reference(
 # -- AutoInt: field self-attention, a presence mask, a sliced stack -----------
 
 
-def _attention_case(b: int = 13, m: int = 8, d: int = 4, absent=(7,)):
+def _attention_case(
+    b: int = 13, m: int = 8, d: int = 4, absent=(7,), head: int = 4,
+    layers: int = 2, empty=(),
+):
     """A tower [b, m, d] and its presence [b, m] (the fields of ``absent``
     lacking from every row, two more lacking from a row each, as a dropped
-    entry leaves them), and two layers of two heads of 4; values of order 1."""
+    entry leaves them, and the rows of ``empty`` lacking EVERY field), and
+    ``layers`` layers of two heads of ``head``; values of order 1."""
     rng = np.random.default_rng(7)
     present = np.ones((b, m), np.float32)
     present[:, list(absent)] = 0.0
     present[2, 1] = present[5, 3] = 0.0
+    present[list(empty)] = 0.0
     tower = rng.normal(0, 0.5, (b, m, d)).astype(np.float32) * present[..., None]
+    width = 2 * head
     weights = [
-        tuple(jnp.asarray(rng.normal(0, 0.4, (d_in, 8)), jnp.float32) for _ in "qkvr")
-        for d_in in (d, 8)
+        tuple(
+            jnp.asarray(rng.normal(0, 0.4 * np.sqrt(8 / width), (d_in, width)), jnp.float32)
+            for _ in "qkvr"
+        )
+        for d_in in [d] + [width] * (layers - 1)
     ]
     return weights, jnp.asarray(tower), jnp.asarray(present)
 
@@ -575,29 +584,87 @@ def _plain_attention(weights, tower, present, heads: int = 2):
     return e
 
 
-@pytest.mark.parametrize("slice_rows", [1, 4, 13], ids=["one-row", "uneven", "whole"])
-def test_sliced_attention_equals_the_plain_layers_in_value_and_gradients(slice_rows):
+# the paper's Criteo shape of a slice (40 fields, 2 heads of 32, d = 16):
+# the lane form at its real tile.  A projection's gradient there is a
+# float32 sum over 10^4 (example, field) pairs, taken in another order on
+# each side: 3e-6 of the largest where the toy cases hold 1e-6
+_PAPER_SLICE = {"m": 40, "d": 16, "absent": (39,), "head": 32, "layers": 3}
+
+
+@pytest.mark.parametrize("slice_rows,case,tol", [
+    (1, {}, 1e-6), (4, {}, 1e-6), (13, {}, 1e-6),
+    (128, {"b": 256, **_PAPER_SLICE}, 3e-6),
+    (256, {"b": 384, **_PAPER_SLICE}, 3e-6),
+    (72, {"b": 200, "empty": (9, 150), **_PAPER_SLICE}, 3e-6),
+    (4, {"empty": (6,)}, 1e-6),
+], ids=[
+    "one-row", "uneven", "whole", "lanes-128", "lanes-256", "off-lanes",
+    "no-field",
+])
+def test_sliced_attention_equals_the_plain_layers_in_value_and_gradients(
+    slice_rows, case, tol
+):
     """``blocks.field_attention_stack`` (slices of the batch through
-    ``lax.map``, each slice's backward rematerialised) against the plain
-    layers over the whole batch: the fields' vectors, and the gradient of
-    every projection and of the tower, within 1e-6 of the largest, for a
-    slice of one row, a slice that does not divide the batch (the last is
-    padded with rows that have no field) and the whole batch."""
-    weights, tower, present = _attention_case()
-    want = _plain_attention(weights, tower, present)
+    ``lax.map``, the slice's examples along the lanes, each slice's
+    backward rematerialised) against the plain layers over the whole
+    batch: the fields' vectors, and the gradient of every projection and
+    of the tower, within ``tol`` of the largest, for a slice of one row, a
+    slice that does not divide the batch (the last is padded with rows
+    that have no field) and the whole batch; at the paper's Criteo shape
+    for a slice of one lane width, of two (the last padded) and of 72
+    examples (no whole lane width); and with rows that have NO present
+    field, which give zeros and no NaN, and neither take a gradient nor
+    add to one: the plain layers never see them (their softmax has
+    nothing to run over), and the projections' gradients are those of
+    the batch without them."""
+    weights, tower, present = _attention_case(**case)
+    empty = np.array(case.get("empty", ()), int)
+    full = np.setdiff1d(np.arange(tower.shape[0]), empty)
+    want = _plain_attention(weights, tower[full], present[full])
     got = blocks.field_attention_stack(weights, tower, present, 2, slice_rows)
-    assert got.shape == want.shape == (13, 8, 8)
+    assert got.shape == (tower.shape[0], *want.shape[1:])
+    np.testing.assert_allclose(
+        got[full], want, rtol=0, atol=tol * float(jnp.abs(want).max())
+    )
+    np.testing.assert_array_equal(got[empty], 0.0)
+    mix = jnp.asarray(np.random.default_rng(8).normal(size=got.shape), jnp.float32)
+    d_w, d_tower = jax.grad(
+        lambda w, t: jnp.sum(
+            blocks.field_attention_stack(w, t, present, 2, slice_rows) * mix
+        ), (0, 1),
+    )(weights, tower)
+    want_w, want_tower = jax.grad(
+        lambda w, t: jnp.sum(_plain_attention(w, t, present[full]) * mix[full]),
+        (0, 1),
+    )(weights, tower[full])
+    np.testing.assert_array_equal(d_tower[empty], 0.0)
+    for a, b in zip(
+        jax.tree.leaves((d_w, d_tower[full])), jax.tree.leaves((want_w, want_tower))
+    ):
+        np.testing.assert_allclose(a, b, atol=tol * float(jnp.abs(b).max()), rtol=0)
+
+
+@pytest.mark.parametrize("h,r,g,a,s", [
+    (2, 32, 40, 40, 128), (2, 40, 32, 40, 256), (2, 4, 8, 8, 13), (1, 3, 11, 5, 72),
+], ids=["scores", "mix-two-tiles", "toy", "ragged"])
+def test_the_lane_contraction_kernel_is_the_multiply_and_sum(h, r, g, a, s):
+    """``blocks._lane_contract``'s Mosaic kernel (what the TPU runs; here
+    under the Pallas TPU interpreter) against XLA's multiply and sum (what
+    every other backend runs, and what the tests above hold to the plain
+    layers): the scores' shape at the paper's sizes, the weighted sum's
+    over two lane tiles, a toy slice of 13 examples (one block, no whole
+    lane width) and a count of rows that is no multiple of the eight sums
+    the kernel holds at a time; float32 sums in two orders."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rng = np.random.default_rng(9)
+    rows = jnp.asarray(rng.normal(size=(h, r, g, s)), jnp.float32)
+    tiles = jnp.asarray(rng.normal(size=(h, r, a, s)), jnp.float32)
+    want = blocks._lane_contract_xla(rows, tiles)
+    with pltpu.force_tpu_interpret_mode():
+        got = blocks._lane_contract_tpu(rows, tiles)
+    assert got.shape == want.shape == (h, g, a, s)
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-6 * float(jnp.abs(want).max()))
-    mix = jnp.asarray(np.random.default_rng(8).normal(size=want.shape), jnp.float32)
-    grads = [
-        jax.grad(lambda w, t: jnp.sum(f(w, t) * mix), (0, 1))(weights, tower)
-        for f in (
-            lambda w, t: blocks.field_attention_stack(w, t, present, 2, slice_rows),
-            lambda w, t: _plain_attention(w, t, present),
-        )
-    ]
-    for a, b in zip(jax.tree.leaves(grads[0]), jax.tree.leaves(grads[1])):
-        np.testing.assert_allclose(a, b, atol=1e-6 * float(jnp.abs(b).max()), rtol=0)
 
 
 def _autoint_rows(model, rng, b: int = 6, k: int = 10):

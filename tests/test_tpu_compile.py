@@ -1233,24 +1233,38 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
     three interacting layers of 2 heads of 32, the dictionary wire's plane
     capacities of one real batch, seed 1; the dense arrays handed in as
     shapes) for a described v5e.  Lowered: every dot asks for float32
-    (Precision.HIGHEST): the projections (a layer's four side by side,
-    ``[s, 40, d_l] x [d_l, 256]``), the per-example scores and weighted
-    sums (batch dimensions (example, head): products of two activations),
-    and what autodiff makes of them; at default precision the TPU rounds
-    both operands to bfloat16.  A step's scores are ``B H m m`` = 5.2e7
-    floats a layer whole and its projections ``4 B m H d'`` = 1.7e8: no
-    array of the lowered or the compiled program has ``B H m m`` elements
-    or more beside the table's own, but the stack's OUTPUT ``[B, m H d']``
-    (the input of the output product, 160 MiB) and its cotangent.
-    Compiled: the block's instructions, forward, forward done again and
-    backward, and the two loops over the slices, carry ``xf.attn`` in
-    ``op_scopes``' reading (the innermost name), the products
-    (convolutions, as the TPU's compiler writes a dot) among them; the
-    output product is ``xf.dense``'s; no table-sized copy of emb's state is
-    made; and the program fits with the room the file's ``reduced`` argues
-    from, 8.02 GiB of 15.75."""
+    (Precision.HIGHEST); at default precision the TPU rounds both operands
+    to bfloat16.  Inside the stack the only dots are the projections, a
+    layer's four side by side over a slice in the lane form, ``[256, d_l]
+    x [d_l, m, s]`` (``[d_l, m s]`` with the fields kept apart), and what
+    autodiff makes of them, into the weights and into the activations: the
+    per-example scores and weighted sums are float32 multiplies and sums
+    with the slice's ``s`` examples minor-most (``blocks._lane_contract``,
+    on the TPU a Mosaic kernel: eight a layer, two forward, two done again
+    and four backward), so no dot has a batch dimension of ``s`` and no
+    array of the stack has 32, 40 or 64 as its last axis but the slice's
+    output, relaid once to ``[s, m, H d']``.  A
+    step's scores are ``B H m m`` = 5.2e7 floats a layer whole and its
+    projections ``4 B m H d'`` = 1.7e8: no array of the lowered or the
+    compiled program has ``B H m m`` elements or more beside the table's
+    own, but the stack's OUTPUT ``[B, m H d']`` (the input of the output
+    product, 160 MiB) and its cotangent.  Compiled: the block's
+    instructions, forward, forward done again and backward, and the two
+    loops over the slices, carry ``xf.attn`` in ``op_scopes``' reading
+    (the innermost name), the products (convolutions, as the TPU's
+    compiler writes a dot) among them; NO instruction that runs inside
+    either loop has an empty scope (but the backward loop's own counter
+    test), and outside them none that makes an array of the stack's (the
+    slices' tower with its presence row, relaid to the lane form; the
+    output and its relayouts) but the compiler's asynchronous moves
+    between memories; the output product is ``xf.dense``'s; no table-sized
+    copy of emb's state is made; and the program fits with the room the
+    file's ``reduced`` argues from, 8.01 GiB of 15.75."""
     from xflow_tpu.models import blocks
-    from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
+    from xflow_tpu.parallel.step import (
+        _HLO_COMPUTATION_RE, _HLO_FUSED_RE, _HLO_INSTRUCTION_RE,
+        _HLO_NEVER_RUNS_RE, _HLO_OP_NAME_RE, scope_of,
+    )
 
     cfg, step, lowered, compiled = autoint_cell_step
     assert step._mxu_hot == {"emb": True}
@@ -1258,7 +1272,7 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
     b, m, heads, head = cfg.batch_size, cfg.max_fields, cfg.attn_heads, cfg.attn_dim
     width = heads * head
     s = blocks.attn_slice_rows(b, m, heads, head, cfg.cross_layers)
-    assert b % s == 0
+    assert b % s == 0 and s == 128
     text = lowered.as_text()
     dots = [line for line in text.splitlines() if "dot_general" in line]
     assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots
@@ -1267,20 +1281,25 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
         sig = f"(tensor<{lhs}xf32>, tensor<{rhs}xf32>) -> tensor<{out}xf32>"
         return sum(sig in line for line in dots)
 
-    # the projections of layer 1 and of layers 2 and 3, forward (the text
-    # may hold a slice's forward once for both of its runs) ...
-    d = cfg.emb_dim
-    assert dot(f"{s}x{m}x{d}", f"{d}x{4 * width}", f"{s}x{m}x{4 * width}") >= 1
-    assert dot(f"{s}x{m}x{width}", f"{width}x{4 * width}", f"{s}x{m}x{4 * width}") >= 2
+    # the projections of layer 1 and of layers 2 and 3, forward and done
+    # again in the backward ...
+    d, lanes = cfg.emb_dim, f"{m}x{s}"
+    assert dot(f"{d}x{4 * width}", f"{d}x{lanes}", f"{4 * width}x{lanes}") == 2
+    assert dot(f"{width}x{4 * width}", f"{width}x{lanes}", f"{4 * width}x{lanes}") == 4
     # ... into the weights and into the activations
-    assert dot(f"{s}x{m}x{4 * width}", f"{s}x{m}x{d}", f"{4 * width}x{d}") == 1
-    assert dot(f"{s}x{m}x{4 * width}", f"{s}x{m}x{width}", f"{4 * width}x{width}") == 2
-    assert dot(f"{s}x{m}x{4 * width}", f"{width}x{4 * width}", f"{s}x{m}x{width}") == 2
-    # the scores and the weighted sums: batched over (example, head), two
-    # a layer forward and four backward
-    per_example = [line for line in dots if "batching_dims = [0, " in line]
-    assert len(per_example) >= 3 * (2 + 4), len(per_example)
-    assert all(f"tensor<{s}x" in line for line in per_example)
+    assert dot(f"{4 * width}x{lanes}", f"{d}x{lanes}", f"{4 * width}x{d}") == 1
+    assert dot(f"{4 * width}x{lanes}", f"{width}x{lanes}", f"{4 * width}x{width}") == 2
+    assert dot(f"{4 * width}x{lanes}", f"{d}x{4 * width}", f"{lanes}x{d}") == 1
+    assert dot(f"{4 * width}x{lanes}", f"{width}x{4 * width}", f"{lanes}x{width}") == 2
+    # and no other dot of a slice: the scores and the weighted sums are
+    # multiplies and sums, the examples on the lanes
+    assert sum(f"x{lanes}xf32>" in line for line in dots) == 12
+    assert not [line for line in dots if f"tensor<{s}x" in line]
+    scored = f"tensor<{heads}x{m}x{lanes}xf32>"  # [H, j, i, s]
+    assert sum(
+        "stablehlo.exponential" in line and scored in line
+        for line in text.splitlines()
+    ) >= 3
 
     def elements(shape: str) -> int:
         return math.prod(int(x) for x in re.split("[x,]", shape) if x)
@@ -1312,10 +1331,56 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
         p for p in paths
         if "transpose(jvp(xf.attn))/while/body/closed_call/checkpoint/rematted_computation" in p
     ]
-    assert sum(" while(" in line for line in attn) == 2  # forward's, backward's
-    assert sum(" convolution(" in line for line in attn) >= 30
+    loops = [line for line in attn if " while(" in line]
+    assert len(loops) == 2  # forward's, backward's
+    assert sum(" convolution(" in line for line in attn) >= 12
+    kernels = [line for line in attn if '"tpu_custom_call"' in line]
+    assert len(kernels) == 8 * cfg.cross_layers, len(kernels)
+    assert all(
+        re.search(rf"= f32\[{heads},(?:{m}|{head}),{m},{s}\]", line) for line in kernels
+    )
     assert [line for line in attn if "exponential" in line]
     assert dense and not [line for line in dense if " while(" in line]
+    assert [line for line in dense if "jvp(xf.dense)/dot_general" in line]
+
+    # what runs with no scope (as op_scopes reads the program: a fusion's
+    # inside never runs on its own): nothing inside the loops, and of the
+    # stack's own arrays nothing but the compiler's moves between memories
+    fused = set(_HLO_FUSED_RE.findall(hlo))
+    bodies = {re.search(r"body=%?([^ ,)]+)", line).group(1) for line in loops}
+    slices = b // s
+    stack_arrays = re.compile(
+        rf"f32\[(?:{slices},{d + 1},{m},{s}|{slices},{s},{m},{width}"
+        rf"|{b},{m},(?:{d + 1}|{width})|{b},{m * width})\]"
+    )
+    moves = re.compile(r" (?:copy|slice)-(?:start|done)\(|\"ConcatBitcast\"")
+    unscoped_inside, unscoped_arrays, inside, current = [], [], 0, ""
+    for line in hlo.splitlines():
+        head = _HLO_COMPUTATION_RE.match(line)
+        if head:
+            current = head.group(1)
+            continue
+        if (
+            current in fused or not _HLO_INSTRUCTION_RE.match(line)
+            or _HLO_NEVER_RUNS_RE.search(line)
+        ):
+            continue
+        found = _HLO_OP_NAME_RE.search(line)
+        scope = scope_of(found.group(1)) if found else ""
+        inside += current in bodies
+        if scope or " bitcast(" in line:
+            continue
+        if moves.search(line):
+            continue
+        if current in bodies:
+            unscoped_inside.append(line.split(", metadata")[0].strip()[:120])
+        elif stack_arrays.search(line.split(" = ")[1].split("(")[0]):
+            unscoped_arrays.append(line.split(", backend_config")[0].strip()[:160])
+    assert inside > 150, inside
+    assert all(
+        re.match(r"%?compare[.0-9]* = pred\[\]", line) for line in unscoped_inside
+    ), unscoped_inside
+    assert not unscoped_arrays, unscoped_arrays
     assert not [
         line for line in _table_sized_copies(hlo, cfg.table_size)
         if f"f32[{cfg.table_size},{cfg.emb_dim}]" in line

@@ -35,11 +35,15 @@ choose shapes, no code path.
 Memory.  A layer's projections, scores and weights are 16 000 floats an
 example at the paper's sizes: 3.1 GB over three layers at B = 16384 as
 numbers.  The stack holds them for a slice of the batch at a time,
-forward and backward (``blocks.attn_slice_rows``, from shapes).
+forward and backward (``blocks.attn_slice_rows``, from shapes), the
+slice's examples minor-most: on the TPU's 128 lanes, where the fields
+(40), a head (32) or a layer's width (64) would leave most of a vector
+register empty.
 
-Precision.  Every product (the projections, the per-example scores and
-weighted sums, the output) is float32 on the TPU (Precision.HIGHEST),
-and so is the softmax.
+Precision.  The projections (one product a layer, on the MXU) and the
+output are float32 on the TPU (Precision.HIGHEST); the per-example scores
+and weighted sums are float32 multiplies and sums (``blocks._lane_contract``:
+on the TPU a Mosaic kernel), and so is the softmax.
 
 Departures from the paper, shared with
 benchmarks/reference/autoint_criteo.py: FTRL for the table and plain SGD

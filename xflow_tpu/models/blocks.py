@@ -463,11 +463,12 @@ ATTN_SCOPE = "xf.attn"
 # Read at ONE shape, AutoInt's paper sizes on a v5e (m = 40, 3 layers of 2
 # heads of 32, d = 16, B = 16384: 225 KiB an example), where it yields the
 # best slice of five measured, forward and backward of the stack alone, ms
-# by examples a slice (scripts/probe_attn_slice.py, PR 47): 128: 93.8,
-# 256: 96.0, 512: 96.5, 1024: 107.2, 2048: 127.6 (the program's
-# temporaries 0.16, 0.16, 0.28, 0.58, 1.16 GiB).  Flat to 512, so the
-# constant only has to stay under the rise; at another shape the rule
-# (``_slice_rows``) is unmeasured, as the CIN's is.
+# by examples a slice (scripts/probe_attn_slice.py, PR 54, the lane form:
+# one lane width a slice keeps a slice's arrays in VMEM): 128: 62.3,
+# 256: 69.9, 512: 77.1, 1024: 87.1, 2048: 92.5 (the program's temporaries
+# 0.27, 0.22, 0.16, 0.34, 0.69 GiB; PR 47's form read 93.8, 96.0, 96.5,
+# 107.2, 127.6).  At another shape the rule (``_slice_rows``) is
+# unmeasured, as the CIN's is.
 ATTN_SLICE_BYTES = 32 << 20
 
 
@@ -501,41 +502,165 @@ def field_presence(x: jax.Array, slots: jax.Array, num_fields: int) -> jax.Array
     return jnp.max(onehot * (x != 0)[..., None].astype(x.dtype), axis=1)
 
 
+def _lane_contract_xla(rows: jax.Array, tiles: jax.Array) -> jax.Array:
+    """``_lane_contract`` as XLA writes it: one multiply and sum."""
+    return jnp.sum(rows[:, :, :, None, :] * tiles[:, :, None, :, :], axis=1)
+
+
+def _lane_contract_kernel(rows_ref, tiles_ref, out_ref):
+    """One head, one lane tile: ``out[g] = sum_r rows[r, g] * tiles[r]``
+    with eight ``[a, s]`` sums held in registers while ``r`` runs, so a
+    ``[a, s]`` tile is read once for eight rows of ``rows``."""
+    n_r, n_g = rows_ref.shape[:2]
+    for g0 in range(0, n_g, 8):
+        group = range(g0, min(g0 + 8, n_g))
+
+        def add_r(r, sums, group=group):
+            tile = tiles_ref[r]
+            return tuple(
+                acc + rows_ref[r, g:g + 1, :] * tile for acc, g in zip(sums, group)
+            )
+
+        zero = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+        sums = jax.lax.fori_loop(0, n_r, add_r, (zero,) * len(group))
+        for acc, g in zip(sums, group):
+            out_ref[g] = acc
+
+
+def _lane_contract_tpu(rows: jax.Array, tiles: jax.Array) -> jax.Array:
+    """``_lane_contract`` as a Mosaic kernel over (head, lane tile)."""
+    from jax.experimental import pallas as pl
+
+    heads, n_r, n_g, s = rows.shape
+    a = tiles.shape[2]
+    lanes = _LANES if s % _LANES == 0 else s
+
+    def block(*shape):
+        return pl.BlockSpec((None, *shape, lanes), lambda h, j: (h, 0, 0, j))
+
+    return pl.pallas_call(
+        _lane_contract_kernel,
+        grid=(heads, s // lanes),
+        in_specs=[block(n_r, n_g), block(n_r, a)],
+        out_specs=block(n_g, a),
+        out_shape=jax.ShapeDtypeStruct((heads, n_g, a, s), rows.dtype),
+    )(rows, tiles)
+
+
+def _lane_contract(rows: jax.Array, tiles: jax.Array) -> jax.Array:
+    """``rows [H, r, g, s]``, ``tiles [H, r, a, s]`` -> ``[H, g, a, s]``:
+
+        out[h, g, a, :] = sum_r rows[h, r, g, :] * tiles[h, r, a, :]
+
+    THE per-example product of ``field_attention_layer``, a float32
+    multiply and sum with the slice's examples on the lanes: every row
+    ``rows[h, r, g]`` (one number an example) times the ``[a, s]`` tile
+    ``tiles[h, r]``, summed over the MAJOR axis ``r``, so that whole
+    registers are added.  On the TPU a Mosaic kernel, elsewhere XLA's
+    multiply and sum, picked where the program is lowered: XLA's fusion
+    of this sum reads each tile again for every row, 17 - 33 us a call
+    at AutoInt's paper sizes (H = 2, 32 or 40 rows and sums, tiles of
+    ``[40, 128]``) where the kernel, which holds eight sums in registers
+    under one read of a tile, takes 10.4 - 10.9 (PERF.md section 6,
+    PR 54: 24 calls a slice, 79.5 against 55.9 ms a step)."""
+    return jax.lax.platform_dependent(
+        rows, tiles, tpu=_lane_contract_tpu, default=_lane_contract_xla
+    )
+
+
+@jax.custom_vjp
+def _lane_pair(x: jax.Array, y: jax.Array) -> jax.Array:
+    """``x [H, c, a, s]``, ``y [H, c, b, s]`` -> ``[H, b, a, s]``:
+
+        out[h, b, a, :] = sum_c y[h, c, b, :] * x[h, c, a, :]
+
+    every pair of a field of ``y`` with a field of ``x``, summed over a
+    head's width.  With ``_lane_mix`` the per-example products of
+    ``field_attention_layer``.  Each is the other's transpose, and both
+    are ``_lane_contract``, forward and backward, so that every sum runs
+    over a major axis: where autodiff's transpose would sum over the
+    second-minor axis (the 8 fields that share a register) the backward
+    swaps the two field axes of the pair array first, and ``_lane_mix``
+    the fields and the width of ``y``."""
+    return _lane_contract(y, x)
+
+
+@jax.custom_vjp
+def _lane_mix(w: jax.Array, y: jax.Array) -> jax.Array:
+    """``w [H, b, a, s]``, ``y [H, c, b, s]`` -> ``[H, c, a, s]``:
+
+        out[h, c, a, :] = sum_b w[h, b, a, :] * y[h, c, b, :]
+
+    the fields of ``y`` mixed by the pair array ``w``, summed over its
+    major field axis (``_lane_pair`` has the rest)."""
+    return _lane_contract(y.swapaxes(1, 2), w)
+
+
+def _lane_pair_fwd(x, y):
+    return _lane_pair(x, y), (x, y)
+
+
+def _lane_pair_bwd(kept, g):
+    x, y = kept
+    return _lane_mix(g, y), _lane_mix(g.swapaxes(1, 2), x)
+
+
+def _lane_mix_fwd(w, y):
+    return _lane_mix(w, y), (w, y)
+
+
+def _lane_mix_bwd(kept, g):
+    w, y = kept
+    return _lane_pair(g, y), _lane_mix(w.swapaxes(1, 2), g)
+
+
+_lane_pair.defvjp(_lane_pair_fwd, _lane_pair_bwd)
+_lane_mix.defvjp(_lane_mix_fwd, _lane_mix_bwd)
+
+
 def field_attention_layer(
     wq: jax.Array, wk: jax.Array, wv: jax.Array, wr: jax.Array,
     e: jax.Array, present: jax.Array, heads: int,
 ) -> jax.Array:
-    """One interacting layer over a slice: ``e [s, m, d_l]`` the fields'
-    vectors, ``present [s, m]`` (1.0 / 0.0), the four ``[d_l, H * d']``
-    projections -> ``[s, m, H * d']``:
+    """One interacting layer over a slice in the LANE form, the slice's
+    examples minor-most: ``e [d_l, m, s]`` the fields' vectors, ``present
+    [m, s]`` (1.0 / 0.0), the four ``[d_l, H * d']`` projections ->
+    ``[H * d', m, s]``:
 
         psi[h, i, j] = <W_Q^h e_i, W_K^h e_j>
         alpha[h, i, :] = softmax of psi[h, i, :] over the PRESENT fields j
         out_i = ReLU([sum_j alpha[h, i, j] W_V^h e_j]_h + W_Res e_i) * present_i
 
     (AutoInt's equations 5-8; no 1 / sqrt(d'), the paper has none).  The
-    four projections are one product with the weights side by side; the
-    scores and the weighted sum are per-example products of two
-    activations, batched over (example, head).  Every product is float32
-    on every backend (Precision.HIGHEST, as ``dense_dot``: no operand is
-    exact in bfloat16); the softmax is float32 with the row's largest
-    score over the present keys taken off first.  An absent field's key
-    gets -inf, so it takes weight 0, and its own output row is 0; a row
-    with NO present field gives zeros (the sum of its weights is held off
-    0), not NaN."""
-    s, m, _ = e.shape
-    proj = dense_dot(e, jnp.concatenate([wq, wk, wv, wr], axis=1))
-    *qkv, res = jnp.split(proj, 4, axis=-1)
-    q, k, v = (a.reshape(s, m, heads, -1) for a in qkv)
-    hi = jax.lax.Precision.HIGHEST
-    psi = jnp.einsum("sihc,sjhc->shij", q, k, precision=hi)
-    psi = jnp.where(present[:, None, None, :] > 0, psi, -jnp.inf)
-    top = jax.lax.stop_gradient(jnp.max(psi, axis=-1, keepdims=True))
+    four projections are ONE product with the weights side by side,
+    ``[4 H d', d_l] x [d_l, m s]``, float32 on every backend
+    (Precision.HIGHEST as ``dense_dot``: no operand is exact in
+    bfloat16), written with the fields kept apart (``[d_l, m, s] ->
+    [4 H d', m, s]``): as ``[d_l, m s]`` arrays the compiler relays both
+    sides under no scope's name.
+    The scores and the weighted sum, per-example products of two
+    activations, are float32 multiplies summed over ``d'`` and over the
+    keys: with the examples on the lanes every operand of theirs, of the
+    softmax, the residual, the ReLU and the mask fills its vector
+    registers, where ``[s, H, m, m]`` put 40 and ``[s, m, H d']`` 32 or 64
+    on a 128-lane axis.  The scores are held key-major, ``[H, j, i, s]``:
+    the softmax over the keys and the weighted sum then add whole
+    registers.  The softmax is float32 with the row's largest score over
+    the present keys taken off first.  An absent field's key gets -inf, so
+    it takes weight 0, and its own output row is 0; a row with NO present
+    field gives zeros (the sum of its weights is held off 0), not NaN."""
+    _, m, s = e.shape
+    w = jnp.concatenate([wq, wk, wv, wr], axis=1)
+    proj = jnp.einsum("dw,dms->wms", w, e, precision=jax.lax.Precision.HIGHEST)
+    q, k, v, res = proj.reshape(4, heads, -1, m, s)
+    psi = _lane_pair(q, k)
+    psi = jnp.where(present[None, :, None, :] > 0, psi, -jnp.inf)
+    top = jax.lax.stop_gradient(jnp.max(psi, axis=1, keepdims=True))
     ex = jnp.exp(psi - jnp.where(jnp.isfinite(top), top, 0.0))
-    total = jnp.sum(ex, axis=-1, keepdims=True)
+    total = jnp.sum(ex, axis=1, keepdims=True)
     alpha = ex / jnp.maximum(total, jnp.finfo(ex.dtype).tiny)
-    mixed = jnp.einsum("shij,sjhc->sihc", alpha, v, precision=hi)
-    return jax.nn.relu(mixed.reshape(res.shape) + res) * present[..., None]
+    mixed = _lane_mix(alpha, v)
+    return jax.nn.relu(mixed + res).reshape(-1, m, s) * present[None]
 
 
 @jax.named_scope(ATTN_SCOPE)
@@ -549,23 +674,30 @@ def field_attention_stack(
     ``[d_l, H * d']``; ``present [B, m]`` from ``field_presence``.  A
     layer's projections, scores and weights are ``5 m H d' + 2 H m m``
     floats an example (16 000 at the paper's sizes: 3.1 GB over three
-    layers at B = 16384 as numbers, two to three times that in (8,128)
-    tiles), so they exist for ``slice_rows`` examples at a time, forward
-    and backward: the batch goes through ``lax.map`` slice by slice (zero
-    rows with no present field pad the last, give zeros and are cut off),
-    and each slice's backward computes its forward again
-    (``jax.checkpoint``, nothing kept but the slice's tower and
-    presence: a kept projection would be a ``[B, m, H d']`` array
-    again)."""
+    layers at B = 16384), so they exist for ``slice_rows`` examples at a
+    time, forward and backward: the batch goes through ``lax.map`` slice
+    by slice (zero rows with no present field pad the last, give zeros
+    and are cut off), and each slice's backward computes its forward
+    again (``jax.checkpoint``, nothing kept but the slice's tower and
+    presence: a kept projection would be a ``[B, m, H d']`` array again).
+    Inside a slice the examples lie along the lanes
+    (``field_attention_layer``).  The tower is relaid to that form once,
+    the batch's slices together, ``[slices, d + 1, m, s]`` with the
+    presence as each slice's last row (one relayout under this scope: a
+    plane of its own is relaid by the compiler, under no name); the last
+    layer's output is relaid back a slice at a time, ``[s, m, H d']``:
+    the one whole array the stack makes."""
     @functools.partial(jax.checkpoint, prevent_cse=False)  # as cin_stack's
-    def one_slice(args: tuple[jax.Array, jax.Array]) -> jax.Array:
-        e, p = args
+    def one_slice(lanes: jax.Array) -> jax.Array:
+        e, p = lanes[:-1], jax.lax.stop_gradient(lanes[-1])
         for wq, wk, wv, wr in weights:
             e = field_attention_layer(wq, wk, wv, wr, e, p, heads)
-        return e
+        return e.transpose(2, 1, 0)
 
+    # [slices, d + 1, m, s]: a slice's tower and, its last row, its presence
+    lanes = jnp.concatenate([tower, present[..., None]], axis=-1)
     out = jax.lax.map(
-        one_slice, (_to_slices(tower, slice_rows), _to_slices(present, slice_rows))
+        one_slice, _to_slices(lanes, slice_rows).transpose(0, 3, 2, 1)
     )
     return _from_slices(out, tower.shape[0])
 
