@@ -45,7 +45,6 @@ import math
 import os
 import shutil
 import sys
-import threading
 import time
 
 REPO = os.path.dirname(os.path.abspath(__file__))
@@ -84,46 +83,38 @@ def check(cond: bool, what: str) -> None:
 
 
 class CompileMeter:
-    """Seconds and counts of XLA compilations, from JAX's own monitoring
-    events, so every phase can report compile time apart from run time
-    and say how many programs came out of the persistent cache."""
+    """Seconds and counts of XLA compilations, from the program's own
+    compile watch (xflow_tpu/obs/startup.py: JAX's monitoring events,
+    one listener pair a process, installed by enable_compile_cache),
+    so every phase can report compile time apart from run time and say
+    how many programs came out of the persistent cache."""
 
-    def __init__(self) -> None:
-        import jax.monitoring as mon
+    @staticmethod
+    def totals() -> dict:
+        from xflow_tpu.obs import startup
 
-        self._lock = threading.Lock()
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
+        return startup.compile_totals()
 
-    def _on_duration(self, event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                self.seconds += secs
-                self.compiles += 1
-
-    def _on_event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self.cache_hits += 1
+    @property
+    def compiles(self) -> int:
+        return self.totals()["requests"]
 
     @contextlib.contextmanager
     def phase(self, report: dict, name: str):
         print(f"chip_smoke: phase {name} ...", file=sys.stderr, flush=True)
         t0 = time.perf_counter()
-        s0, c0, h0 = self.seconds, self.compiles, self.cache_hits
+        before = self.totals()
         out: dict = {}
         yield out
         wall = time.perf_counter() - t0
-        compile_s = self.seconds - s0
+        after = self.totals()
+        compile_s = after["seconds"] - before["seconds"]
         report[name] = {
             "seconds": round(wall, 2),
             "compile_seconds": round(compile_s, 2),
             "run_seconds": round(wall - compile_s, 2),
-            "compiles": self.compiles - c0,
-            "cache_hits": self.cache_hits - h0,
+            "compiles": after["requests"] - before["requests"],
+            "cache_hits": after["cache_hits"] - before["cache_hits"],
             **out,
         }
 

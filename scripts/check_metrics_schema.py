@@ -71,9 +71,20 @@ def check(path: str) -> list[str]:
 
     kinds = {r.get("kind") for r in rows}
     for expected in ("run_start", "train_epoch", "eval", "shard",
-                     "resource"):
+                     "resource", "startup"):
         if expected not in kinds:
             errors.append(f"toy pipeline emitted no {expected!r} row")
+    # ISSUE 55: one start-up row a trainer, and every epoch row says
+    # what the process compiled during it (OPTIONAL in the schema for
+    # the files from before; emitted by every current epoch)
+    startups = sum(r.get("kind") == "startup" for r in rows)
+    if startups != 1:
+        errors.append(f"{startups} 'startup' rows for one trainer")
+    for r in rows:
+        if r.get("kind") == "train_epoch":
+            for key in ("compiles", "compiles_cached", "compile_seconds"):
+                if key not in r:
+                    errors.append(f"train_epoch {r.get('epoch')} lacks {key!r}")
     unknown = kinds - set(SCHEMA)
     if unknown:
         errors.append(f"kinds missing from SCHEMA: {sorted(unknown)}")

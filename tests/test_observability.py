@@ -998,7 +998,7 @@ def test_op_scopes_reads_the_running_program(toy_dataset, tmp_path, devices):
 def test_null_obs_maps_no_scopes_and_annotates_nothing(toy_dataset, monkeypatch):
     """With metrics_out, obs_trace_out, obs_flight_out and obs_watchdog
     unset the trainer never calls op_scopes and creates no
-    TraceAnnotation; a live Obs does both."""
+    TraceAnnotation but its start-up phases'; a live Obs does both."""
     import xflow_tpu.obs as obs_mod
     from xflow_tpu.parallel.step import TrainStep
 
@@ -1021,7 +1021,10 @@ def test_null_obs_maps_no_scopes_and_annotates_nothing(toy_dataset, monkeypatch)
     with Trainer(_toy_cfg(toy_dataset)) as t:
         assert not t.obs.enabled
         stats = t.train_epoch()
-    assert made == [] and mapped == []
+    # the process's start-up timeline is always on (obs/startup.py): a
+    # handful of annotations a TRAINER, none a step
+    assert all(n.startswith("xf.startup_") for n in made) and mapped == []
+    assert len(made) <= 8
     assert "_scopes" not in stats and "first_batch_wait_s" not in stats
 
     with Trainer(_toy_cfg(toy_dataset, obs_flight_out="unused")) as t:

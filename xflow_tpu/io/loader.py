@@ -398,7 +398,16 @@ class ShardLoader:
             records = packed.iter_compact_batches(f, start_offset)
         else:
             records = packed.iter_batches(f, start_offset)
-        for batch, offset, next_offset in records:
+        while True:
+            # the pull itself (the record's mmap read and, for a padded
+            # consumer of a v2 shard, CompactBatch.expand()), not the
+            # consumer's time between yields: on a stream thread, so
+            # the epoch record books it under ``overlapped``
+            with self.obs.phase("batch_read"):
+                record = next(records, None)
+            if record is None:
+                break
+            batch, offset, next_offset = record
             with self._q_lock:
                 self._blocks_seen += 1
             try:

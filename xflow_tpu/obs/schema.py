@@ -143,6 +143,23 @@ SCHEMA: dict[str, dict[str, Any]] = {
         "epoch": int,
         "ops": list,
     },
+    # one per trainer, after its FIRST train_epoch (trainer.train pops
+    # ``_startup`` off that epoch's stats): the process's start-up
+    # timeline up to its first steady step (obs/startup.py;
+    # docs/OBSERVABILITY.md "Start-up").  origin / at and every
+    # phase's ``start`` are time.perf_counter() readings of the
+    # process; ``phases`` holds {name, start, seconds, thread} records,
+    # nested by time on one thread, newest 256; ``compiles`` holds the
+    # compile watch's totals (requests = compiled OR loaded, cache_hits,
+    # compiled = requests - cache_hits, seconds) and its newest events
+    "startup": {
+        "t": (int, float),
+        "kind": str,
+        "origin": (int, float),
+        "at": (int, float),
+        "phases": list,
+        "compiles": dict,
+    },
     # one per training epoch under store_mode='tiered': hierarchical
     # parameter-store accounting (store/tiered.py; docs/STORE.md).
     # hot_hit_rate is occurrence-weighted (feature occurrences the HBM
@@ -390,6 +407,12 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "hostname": str,
         "pid": int,
     },
+    "startup": {
+        # where /proc says: how long the process had lived when
+        # obs/startup.py was imported (interpreter start and the
+        # imports before it)
+        "process_age_at_origin_s": (int, float),
+    },
     "wire": {
         # meshes of more than one device only: bytes the train step's
         # pull and push handed between the chips, a step, from shapes
@@ -476,6 +499,14 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # sha256s of the hot remap computed this epoch, written beside
         # shard_opens: 1 in a trainer's first packed epoch, 0 after
         "remap_hashes": int,
+        # programs the process asked XLA for during the epoch, from the
+        # compile watch (obs/startup.py): compiled OR loaded from the
+        # persistent cache, of those the loaded ones, and their
+        # seconds.  0 in every steady epoch; an epoch that met a new
+        # shape says so.  Rows from before ISSUE 55 lack them
+        "compiles": int,
+        "compiles_cached": int,
+        "compile_seconds": (int, float),
     },
     # fleet-mode rows only (serve/fleet.py pools N replicas into one
     # registry; rows written before the production tier predate these
@@ -528,6 +559,10 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         "gc_pauses": int,
         "gc_pause_max": (int, float),
         "gc_pause_total": (int, float),
+        # fleet rows since ISSUE 55: the start-up timeline as it stood
+        # at the end of the newest load or committed rollout (the body
+        # of a ``startup`` row; a constant between loads)
+        "startup": dict,
     },
     # scored-and-returned count alongside admitted (completions lag
     # admissions by the in-flight window; rows from before the counter

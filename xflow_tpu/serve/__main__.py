@@ -65,6 +65,7 @@ import time
 
 import numpy as np
 
+from xflow_tpu.obs import startup
 from xflow_tpu.utils.compile_cache import enable_compile_cache
 
 
@@ -782,6 +783,9 @@ def main(argv: list[str] | None = None) -> int:
 
     args = p.parse_args(argv)
     enable_compile_cache()
+    remote = args.cmd == "loadgen" and (args.url or args.binary_addr)
+    if not remote:  # a remote load generator never touches the backend
+        startup.init_backend()
 
     if args.cmd == "score":
         return cmd_score(args)

@@ -75,13 +75,20 @@ def export_artifact(trainer, directory: str) -> str:
 
     Multi-host: COLLECTIVE — all processes call together; each writes
     its own table row ranges (module docstring)."""
-    from xflow_tpu.obs import NULL_OBS
+    from xflow_tpu.obs import NULL_OBS, startup
 
-    state = trainer.state
-    cfg = trainer.cfg
     # book the export's device fetches as an obs phase so a slow export
     # shows up in phase accounting instead of vanishing (XF002)
     obs = getattr(trainer, "obs", None) or NULL_OBS
+    # the whole export on the process's start-up timeline: a serving
+    # process that trains or restores first pays it before its fleet
+    with startup.phase("export_artifact", obs):
+        return _export_artifact(trainer, directory, obs)
+
+
+def _export_artifact(trainer, directory: str, obs) -> str:
+    state = trainer.state
+    cfg = trainer.cfg
     # chaos site: a fault anywhere in the export — the all_ok voting +
     # tmp-dir/rename-aside recovery below is what it exercises (XF018)
     failpoint("artifact.export")

@@ -41,7 +41,7 @@ import jax
 from xflow_tpu.chaos import failpoint
 from xflow_tpu.config import Config
 from xflow_tpu.io.batch import Batch, pad_batch_rows, remap_batch
-from xflow_tpu.obs import NULL_OBS, profiler_span
+from xflow_tpu.obs import NULL_OBS, profiler_span, startup
 from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
 from xflow_tpu.parallel.step import (
     _SLOT_PLANES,
@@ -270,7 +270,17 @@ class PredictEngine:
         mesh).  An item index beside the artifact (export_item_index)
         is attached automatically, arming the ``topk`` mode compiled
         for ``topk_k`` results (default ``DEFAULT_TOPK``, capped at
-        the index size)."""
+        the index size).
+
+        On the process's start-up timeline (obs/startup.py) the load is
+        ``engine_load``, and inside it ``artifact_read`` (manifest and
+        digest check), ``weights_put`` (the shard files read through
+        onto the serving mesh, each range read and handed to the device
+        in one call; a transfer still in flight at its end is waited
+        for by the first warm-up call) and ``bucket_warm``."""
+        import jax.numpy as jnp
+
+        from xflow_tpu.models import make_model
         from xflow_tpu.serve.artifact import (
             REMAP_FILE,
             load_item_index,
@@ -278,68 +288,68 @@ class PredictEngine:
         )
         from xflow_tpu.utils.checkpoint import RangeReader
 
-        # chaos site: artifact-load fault — the manifest/digest refusal
-        # chain below is what it exercises (XF018)
-        failpoint("artifact.load")
-        manifest = load_manifest(directory)
-        cfg = Config.from_json(manifest["config"])
-        digest = manifest["config_digest"]
-        if config is not None and config.digest() != digest:
-            raise ValueError(
-                f"artifact {directory} was exported from config "
-                f"{digest}, but the expected config digests to "
-                f"{config.digest()} — refusing to serve a mismatched "
-                "model"
+        with startup.phase("engine_load", obs if obs is not None else NULL_OBS):
+            # chaos site: artifact-load fault — the manifest/digest
+            # refusal chain below is what it exercises (XF018)
+            failpoint("artifact.load")
+            with startup.phase("artifact_read"):
+                manifest = load_manifest(directory)
+                cfg = Config.from_json(manifest["config"])
+                digest = manifest["config_digest"]
+                if config is not None and config.digest() != digest:
+                    raise ValueError(
+                        f"artifact {directory} was exported from config "
+                        f"{digest}, but the expected config digests to "
+                        f"{config.digest()} — refusing to serve a "
+                        "mismatched model"
+                    )
+            with startup.phase("weights_put"):
+                mesh = make_mesh(num_devices)
+                sharding = table_sharding(mesh)
+                tables: dict[str, Any] = {}
+                for spec in make_model(cfg).tables():
+                    key = f"{spec.name}.param"
+                    meta = manifest["arrays"].get(key)
+                    if meta is None:
+                        raise ValueError(f"artifact {directory} missing {key}")
+                    shape = tuple(meta["shape"])
+                    reader = RangeReader(
+                        directory, key, shape, np.dtype(meta["dtype"])
+                    )
+                    tables[spec.name] = {
+                        "param": jax.make_array_from_callback(
+                            shape, sharding, reader.read
+                        )
+                    }
+                dense: dict[str, Any] = {}
+                for dname in manifest.get("dense", []):
+                    host = np.load(os.path.join(directory, f"dense.{dname}.npy"))
+                    dense[dname] = jax.device_put(host, replicated(mesh))
+                remap = None
+                if manifest.get("remap"):
+                    remap = np.load(os.path.join(directory, REMAP_FILE))
+                state = {
+                    "tables": tables,
+                    "dense": dense,
+                    "step": jnp.asarray(manifest["step"], jnp.int32),
+                }
+            engine = cls(
+                cfg,
+                state,
+                remap=remap,
+                mesh=mesh,
+                buckets=buckets,
+                obs=obs,
+                digest=digest,
+                warm=False,  # warm AFTER the index attach so topk buckets warm too
             )
-        mesh = make_mesh(num_devices)
-        sharding = table_sharding(mesh)
-        import jax.numpy as jnp
-
-        from xflow_tpu.models import make_model
-
-        tables: dict[str, Any] = {}
-        for spec in make_model(cfg).tables():
-            key = f"{spec.name}.param"
-            meta = manifest["arrays"].get(key)
-            if meta is None:
-                raise ValueError(f"artifact {directory} missing {key}")
-            shape = tuple(meta["shape"])
-            reader = RangeReader(
-                directory, key, shape, np.dtype(meta["dtype"])
-            )
-            tables[spec.name] = {
-                "param": jax.make_array_from_callback(
-                    shape, sharding, reader.read
-                )
-            }
-        dense: dict[str, Any] = {}
-        for dname in manifest.get("dense", []):
-            host = np.load(os.path.join(directory, f"dense.{dname}.npy"))
-            dense[dname] = jax.device_put(host, replicated(mesh))
-        remap = None
-        if manifest.get("remap"):
-            remap = np.load(os.path.join(directory, REMAP_FILE))
-        state = {
-            "tables": tables,
-            "dense": dense,
-            "step": jnp.asarray(manifest["step"], jnp.int32),
-        }
-        engine = cls(
-            cfg,
-            state,
-            remap=remap,
-            mesh=mesh,
-            buckets=buckets,
-            obs=obs,
-            digest=digest,
-            warm=False,  # warm AFTER the index attach so topk buckets warm too
-        )
-        index = load_item_index(directory)
-        if index is not None:
-            engine.attach_item_index(index, topk_k=topk_k)
-        if warm:
-            engine.warm()
-        return engine
+            index = load_item_index(directory)
+            if index is not None:
+                engine.attach_item_index(index, topk_k=topk_k)
+            if warm:
+                with startup.phase("bucket_warm"):
+                    engine.warm()
+            return engine
 
     def clone(self) -> "PredictEngine":
         """A replica view over the SAME weights and the SAME compiled

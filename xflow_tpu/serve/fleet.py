@@ -68,7 +68,7 @@ import time
 from concurrent.futures import Future
 from typing import Any, Sequence
 
-from xflow_tpu.obs import GcPauses
+from xflow_tpu.obs import GcPauses, startup
 from xflow_tpu.obs.registry import Histogram, MetricsRegistry
 from xflow_tpu.obs.schema import health_row
 from xflow_tpu.serve.batcher import MicroBatcher, stats_row_from_snapshot
@@ -292,6 +292,11 @@ class ReplicaFleet:
         # close().
         self._gc_pauses = GcPauses(self.registry, "serve")
         self._gc_pauses.install()
+        # the process's start-up timeline (obs/startup.py) as it stood
+        # when this fleet could first serve: every ``serve_stats`` row
+        # carries it, a constant between loads.  Taken again at the end
+        # of load() and of a committed rollout.
+        self._startup = startup.snapshot()
 
     # -- construction -------------------------------------------------------
 
@@ -320,27 +325,28 @@ class ReplicaFleet:
         overridden in ``kw``."""
         from xflow_tpu.serve.engine import PredictEngine
 
-        engine = PredictEngine.load(
-            artifact,
-            num_devices=num_devices,
-            buckets=buckets,
-            obs=obs,
-            warm=warm,
-            topk_k=topk_k,
-        )
-        cfg = engine.cfg
-        kw.setdefault("qos_normal_frac", cfg.serve_qos_normal_frac)
-        kw.setdefault(
-            "qos_best_effort_frac", cfg.serve_qos_best_effort_frac
-        )
-        if "cache" not in kw:
-            if cache_capacity is None:
-                cache_capacity = cfg.serve_cache_capacity
-            if cache_capacity > 0:
-                from xflow_tpu.serve.scache import ScoreCache
+        with startup.phase("fleet_load"):
+            engine = PredictEngine.load(
+                artifact,
+                num_devices=num_devices,
+                buckets=buckets,
+                obs=obs,
+                warm=warm,
+                topk_k=topk_k,
+            )
+            cfg = engine.cfg
+            kw.setdefault("qos_normal_frac", cfg.serve_qos_normal_frac)
+            kw.setdefault(
+                "qos_best_effort_frac", cfg.serve_qos_best_effort_frac
+            )
+            if "cache" not in kw:
+                if cache_capacity is None:
+                    cache_capacity = cfg.serve_cache_capacity
+                if cache_capacity > 0:
+                    from xflow_tpu.serve.scache import ScoreCache
 
-                kw["cache"] = ScoreCache(cache_capacity)
-        fleet = cls(engine, replicas, **kw)
+                    kw["cache"] = ScoreCache(cache_capacity)
+            fleet = cls(engine, replicas, **kw)
         # rollouts load candidates the same way this fleet was loaded
         fleet._load_kw = {
             "num_devices": num_devices,
@@ -348,6 +354,7 @@ class ReplicaFleet:
             "obs": obs,
             "topk_k": topk_k,
         }
+        fleet._startup = startup.snapshot()  # with fleet_load in it
         fleet.log_load(artifact)
         return fleet
 
@@ -915,6 +922,9 @@ class ReplicaFleet:
                 # the fleet lock — XF007).
                 self.cache.set_current(self.servable)
             self._rollout = None
+        snap = startup.snapshot()  # the candidate's load is in it
+        with self._lock:
+            self._startup = snap
         with self._ro_log_lock:
             self._log_rollout("commit", ro, f"health {health}")
         return health
@@ -1044,6 +1054,7 @@ class ReplicaFleet:
             self._class_admitted = {c: 0 for c in QOS_CLASSES}
             self._class_shed = {c: 0 for c in QOS_CLASSES}
             ro = self._rollout
+            row["startup"] = self._startup
         shed["depth"] = self.depth()
         shed["queue_age_s"] = round(self.queue_age_s(), 6)
         if self.metrics_logger is not None:

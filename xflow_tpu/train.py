@@ -23,6 +23,7 @@ import json
 import sys
 
 from xflow_tpu.config import Config
+from xflow_tpu.obs import startup
 from xflow_tpu.trainer import Trainer
 from xflow_tpu.utils.compile_cache import enable_compile_cache
 
@@ -339,6 +340,7 @@ def main(argv: list[str] | None = None) -> int:
     if not cfg.train_path:
         print("error: --train is required", file=sys.stderr)
         return 2
+    startup.init_backend()
     # context manager: metrics JSONL + trace are flushed/closed on every
     # exit path, including exceptions (the logger itself also closes on
     # train()'s own preemption/crash paths)

@@ -32,7 +32,7 @@ from xflow_tpu.io.batch import Batch
 from xflow_tpu.io.loader import ShardLoader, make_parse_fn, shard_path
 from xflow_tpu.io.packed import RemapDigest
 from xflow_tpu.models import make_model
-from xflow_tpu.obs import NULL_OBS
+from xflow_tpu.obs import NULL_OBS, startup
 from xflow_tpu.optim import make_optimizer
 from xflow_tpu.parallel.mesh import make_mesh
 from xflow_tpu.parallel.step import TrainStep, abstract_like, init_state
@@ -47,7 +47,7 @@ from xflow_tpu.utils.metrics import AucAccumulator
 # Loader phases that run on stream/prefetch worker threads: the epoch
 # record books them under ``overlapped`` (they hide behind input_stall),
 # never under ``phases``, which sums to the epoch's wall seconds.
-WORKER_PHASES = ("parse", "pack", "shard_open", "remap_digest")
+WORKER_PHASES = ("parse", "pack", "shard_open", "remap_digest", "batch_read")
 
 
 def _ring_workers(depth: int) -> int:
@@ -78,6 +78,13 @@ class Trainer:
         mesh=None,
         log: Callable[[str], None] | None = None,
     ):
+        # the process's start-up timeline and compile watch
+        # (obs/startup.py): always on, with or without an Obs
+        startup.watch_compiles()
+        with startup.phase("trainer_init"):
+            self._init(cfg, mesh, log)
+
+    def _init(self, cfg: Config, mesh, log) -> None:
         self.cfg = cfg
         self.mesh = mesh if mesh is not None else make_mesh(cfg.num_devices)
         ndev = self.mesh.devices.size
@@ -95,20 +102,26 @@ class Trainer:
                 f"{ndev} device(s) cuts every table into {ndev} row "
                 "block(s)"
             )
-        self.model = make_model(cfg)
-        self.optimizer = make_optimizer(cfg)
-        self.step = TrainStep(self.model, self.optimizer, cfg, self.mesh)
+        with startup.phase("step_build"):
+            self.model = make_model(cfg)
+            self.optimizer = make_optimizer(cfg)
+            self.step = TrainStep(self.model, self.optimizer, cfg, self.mesh)
         # Tiered store (Config.store_mode; store/): device state is the
         # bounded hot tier, NOT a [T, D] table — init_state at the
         # north-star 2^28 geometry would allocate the very buffers the
         # store exists to avoid.
-        if self.step.store is not None:
-            self.state = self.step.store.init_device_state()
-        else:
-            self.state = init_state(
-                self.model, self.optimizer, cfg, self.mesh
-            )
+        with startup.phase("state_init"):
+            if self.step.store is not None:
+                self.state = self.step.store.init_device_state()
+            else:
+                self.state = init_state(
+                    self.model, self.optimizer, cfg, self.mesh
+                )
         self.epoch = 0
+        # the first train_epoch() is start-up too (the train program's
+        # compile or load, the pipeline's first fill, op_scopes): it
+        # runs under phase first_epoch and its stats carry ``_startup``
+        self._first_epoch_done = False
         # (shard_idx, byte_offset) to start the next epoch from; set by
         # restore(), consumed by the first train_epoch() after it.
         self._resume_cursor: tuple[int, int] = (0, 0)
@@ -294,7 +307,8 @@ class Trainer:
         # sample of the training data (identical on every host).
         self.remap = None
         if cfg.hot_size_log2:
-            self._init_remap()
+            with startup.phase("remap_init"):
+                self._init_remap()
             # never written after this: its digest is taken once
             self.remap.flags.writeable = False
         else:
@@ -860,9 +874,21 @@ class Trainer:
         return time.perf_counter() - t0
 
     def train_epoch(self, start_shard: int = 0, start_offset: int = 0) -> dict:
+        if self._first_epoch_done:
+            return self._train_epoch(start_shard, start_offset)
+        self._first_epoch_done = True
+        with startup.phase("first_epoch"):
+            stats = self._train_epoch(start_shard, start_offset)
+        # the process's timeline up to its first steady step: train()
+        # pops it into the ``startup`` metrics row
+        stats["_startup"] = startup.snapshot()
+        return stats
+
+    def _train_epoch(self, start_shard: int, start_offset: int) -> dict:
         cfg = self.cfg
         obs = self.obs
         obs.registry.reset()  # epoch-scoped phase accounting
+        compiles_before = startup.compile_totals()
         t0 = time.time()
         steps = 0
         ckpt_seconds = 0.0
@@ -994,7 +1020,8 @@ class Trainer:
         )
         dt = time.time() - t0
         stats = self._epoch_stats(
-            seen, ll_sum, steps, dt, ckpt_seconds, preempted, ahead
+            seen, ll_sum, steps, dt, ckpt_seconds, preempted, ahead,
+            compiles_before,
         )
         if first_wait is not None:
             stats["first_batch_wait_s"] = round(first_wait, 6)
@@ -1013,6 +1040,7 @@ class Trainer:
         ckpt_seconds: float,
         preempted: bool,
         ahead: bool,
+        compiles_before: dict,
     ) -> dict:
         """Epoch record assembly: throughput (checkpoint time excluded),
         per-phase wall-second accounting, stall fraction, step-time
@@ -1048,6 +1076,13 @@ class Trainer:
             "step_time_p50": round(step_hist.get("p50", 0.0), 6),
             "step_time_p90": round(step_hist.get("p90", 0.0), 6),
             "step_time_p99": round(step_hist.get("p99", 0.0), 6),
+            # programs this process asked XLA for during the epoch
+            # (obs/startup.py): compiled or loaded, of those loaded from
+            # the persistent cache, and their seconds; 0 once steady
+            # (``compiles_before``: the watch's totals at its start)
+            **startup.compile_delta(
+                compiles_before, startup.compile_totals()
+            ),
         }
         occ = snap.hists.get("transfer_ahead_depth")
         if occ:
@@ -1210,6 +1245,7 @@ class Trainer:
                 wire_stats = stats.pop("_wire", None)
                 store_stats = stats.pop("_store", None)
                 scope_stats = stats.pop("_scopes", None)
+                startup_stats = stats.pop("_startup", None)
                 history.append(stats)
                 if self.metrics_logger is not None:
                     self.metrics_logger.log("train_epoch", stats)
@@ -1219,6 +1255,8 @@ class Trainer:
                         self.metrics_logger.log("store", store_stats)
                     if scope_stats is not None:
                         self.metrics_logger.log("scopes", scope_stats)
+                    if startup_stats is not None:
+                        self.metrics_logger.log("startup", startup_stats)
                 self._log_device_mem()
                 if self.epoch % 30 == 0 or self.epoch == self.cfg.epochs - 1:
                     self._log(
@@ -1605,6 +1643,10 @@ class Trainer:
         path and treats an unusable checkpoint as "start fresh"."""
         if not self.cfg.checkpoint_dir:
             return None
+        with startup.phase("restore", self.obs):
+            return self._restore(auto)
+
+    def _restore(self, auto: bool) -> dict | None:
         from xflow_tpu.chaos import ChaosError
         from xflow_tpu.utils.checkpoint import (
             IncompatibleCheckpoint,

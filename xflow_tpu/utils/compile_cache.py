@@ -32,9 +32,15 @@ DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 def enable_compile_cache() -> str | None:
     """Turn the persistent cache on; returns the directory in use (None
     on a CPU-pinned run).  Call after any ``jax_platforms`` update and
-    before the first compile; it initializes no backend."""
+    before the first compile; it initializes no backend.  Also where
+    the process's compile watch is installed (``obs/startup.py``): every
+    entry point passes here before its first compile, CPU-pinned or
+    not."""
     import jax
 
+    from xflow_tpu.obs import startup
+
+    startup.watch_compiles()
     platforms = jax.config.jax_platforms or ""
     if platforms.split(",")[0].strip() == "cpu":
         return None
