@@ -1070,12 +1070,237 @@ def test_dict_scatter_route_leaves_the_state_of_the_expanded_batch(model):
     assert all(np.abs(t["z"]).sum() > 0 for t in tables.values())
 
 
-@pytest.mark.parametrize("wire,microbatch,model", [
-    ("dict", 1, "lr"), ("dict", 4, "lr"), ("compact", 1, "lr"),
-    ("dict", 1, "fm"), ("dict", 4, "fm"),
+def _touched_rows_batches(hot, table_log2=18):
+    """Two dictionary-only batches of one decode case (random cold keys,
+    every one in the dictionary: an EMPTY tail plane) over the same keys,
+    so over the same dictionary and one train program: with a head, some
+    cold keys lie below ``hot_size`` (the head's overflow into the cold
+    section); the second batch gives a fifth of the first's real examples
+    the weight 0, so rows that took a gradient in a step take an exactly
+    zero one in the next."""
+    batch, table, hot_size, _ = _decode_case("full_rows", hot, 3, table_log2)
+    rng = np.random.default_rng(5)
+    if hot_size:
+        spill = (rng.random(batch.keys.shape) < 0.1) & (batch.mask > 0)
+        batch.keys = np.where(
+            spill, rng.integers(0, hot_size, batch.keys.shape), batch.keys
+        ).astype(np.int32)
+    cbs = []
+    for weights in (
+        batch.weights,
+        batch.weights * (rng.random(batch.batch_size) < 0.8),
+    ):
+        batch.weights = weights.astype(np.float32)
+        batch.labels = batch.labels * batch.weights
+        cbs.append(CompactBatch.from_batch(batch, table, hot_size))
+    a, b = cbs
+    assert len(a.ct) == 0 and 0 < a.n_dict < len(a.cu)  # sentinel padding
+    np.testing.assert_array_equal(a.cu, b.cu)
+    keys = batch.keys[batch.mask > 0]
+    assert not hot_size or (keys < hot_size).any()
+    return a, b, table, hot_size
+
+
+# D = 10 and 16; with a head (whose overflow reaches the cold section)
+# and without; one table (mvm's v), and two of which w's one column is
+# not selected (fm)
+@pytest.mark.parametrize("model,d,hot", [
+    ("mvm", 10, "u12"), ("mvm", 16, "none"),
+    ("fm", 10, "none"), ("fm", 16, "u12"),
+])
+def test_touched_rows_pass_leaves_the_dense_pass_state_bit_for_bit(
+    monkeypatch, model, d, hot
+):
+    """Three chained dense steps on dictionary-only batches: a table
+    that the rule selects (step.py::touched_rows_selects: 2 to 64
+    columns, an empty tail plane, large enough for its index count) gets
+    no [T, D] gradient buffer and takes FTRL on the dictionary's rows
+    and on the head alone (TrainStep._touched_rows_pass), and leaves
+    every array of the state, the metrics and the eval BIT FOR BIT where
+    the dense pass over a zeroed buffer leaves them (the same step with
+    the size constant out of reach).  Among the rows: head rows named by
+    the dictionary, capacity padding beyond ``n_dict``, rows whose
+    summed gradient is exactly 0 before any gradient reached them (their
+    ``param`` keeps its initial draw) and after one did (``param``
+    recomputed from ``(z, n)``); fm's ``w`` of one column keeps its
+    buffer in both.  Traced with the MXU head, the form a TPU runs at
+    D = 10.  The "seg" head of other backends is one scatter-add into
+    zeros, and there the two arms stand an ulp apart on a head row with
+    two hot occurrences and a cold one (rows 3 and 105 of this batch, at
+    D = 16): the CPU's compiler writes the dense arm's ``buffer[:H] +
+    scatter(zeros, keys, g)`` as a scatter into the buffer, (cold + h1)
+    + h2 for cold + (h1 + h2)."""
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel import step as step_mod
+    from xflow_tpu.parallel.mesh import make_mesh
+
+    first, second, table, hot_size = _touched_rows_batches(hot)
+    cfg = Config(
+        model=model, v_dim=d, batch_size=first.batch_size,
+        max_nnz=first.max_nnz, table_size_log2=table.bit_length() - 1,
+        num_devices=1, wire_dedup="on", hot_nnz=first.hot_nnz,
+        hot_size_log2=hot_size.bit_length() - 1 if hot_size else 0,
+        hot_impl="mxu",
+    )
+    mesh = make_mesh(1)
+    m, opt = make_model(cfg), make_optimizer(cfg)
+    out = {}
+    for arm in ("touched", "dense"):
+        if arm == "dense":
+            monkeypatch.setattr(
+                step_mod, "TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX", 1 << 40
+            )
+        step = step_mod.TrainStep(m, opt, cfg, mesh)
+        state = step_mod.init_state(m, opt, cfg, mesh)
+        fed = step.put_batch(first)
+        eqns = list(_eqns(jax.make_jaxpr(step._train_impl)(state, fed).jaxpr))
+        into_v = {
+            name: sum(
+                e.primitive.name == name
+                and e.invars[0].aval.shape == (table, d)
+                for e in eqns
+            )
+            for name in ("scatter", "scatter-add")
+        }
+        # param, n, z set at the dictionary's rows and no buffer to add
+        # into; or one add of those rows (and, with a head, one of its H)
+        assert into_v == (
+            {"scatter": 3, "scatter-add": 0} if arm == "touched"
+            else {"scatter": 0, "scatter-add": 1 + bool(hot_size)}
+        ), (arm, into_v)
+        seen = [jax.device_get(state)]
+        for cb in (first, second, first):
+            state, metrics = step.train(state, step.put_batch(cb))
+            seen.append(jax.device_get(state))
+        pctr = step.predict(state, step.put_batch(first))
+        out[arm] = jax.device_get((seen, metrics, pctr))
+    jax.tree.map(
+        lambda a, c: np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint32), np.asarray(c).view(np.uint32)
+        ),
+        out["touched"], out["dense"],
+    )
+    (start, one, two, _), _, _ = out["touched"]
+    expanded = first.expand()
+    rows = np.unique(expanded.keys[expanded.mask > 0])
+    v = {k: [s["tables"]["v"][k][rows] for s in (start, one, two)]
+         for k in ("param", "n", "z")}
+    assert (v["n"][1] > 0).any() and (np.abs(v["z"][2]).sum() > 0)
+    never = (v["n"][1] == 0).all(axis=1)  # live, and no gradient yet
+    assert never.any()
+    np.testing.assert_array_equal(v["param"][1][never], v["param"][0][never])
+    still = ((v["n"][2] == v["n"][1]) & (v["n"][1] > 0)).all(axis=1)
+    assert still.any()  # a zero gradient after a real one
+    np.testing.assert_array_equal(v["z"][2][still], v["z"][1][still])
+    if hot_size:
+        assert (rows < hot_size).any() and (v["n"][1][rows < hot_size] > 0).any()
+
+
+def test_touched_rows_rule_selects_emb_of_the_three_b16384_cells_alone():
+    """step.py::touched_rows_selects over the geometries of the eight
+    benchmark configurations (benchmarks/configs/*.json; the dictionary's
+    and the tail's plane capacities of one real batch of each cell, PERF.md
+    section 5): exactly ``emb`` of xDeepFM, AutoInt and FiBiNET, 55 296
+    indices into [2^25, 10 or 16] and an empty tail.  LR's and every
+    ``w`` has one column, MVM's, DCN's and LR's batches have tails, FFM's
+    ``v`` has 160 columns and is off the head, the FM mesh ships no plan;
+    and a [2^16, 10] table under a dictionary-only batch is too small for
+    its index count: the dense pass is cheaper there."""
+    from benchmarks.harness import manifest
+    from xflow_tpu.models import make_model
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh
+    from xflow_tpu.parallel.step import (
+        TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX, TrainStep, padded_columns,
+        touched_rows_selects,
+    )
+
+    caps = {
+        "lr_ftrl_criteo_tb": (53248, 294912),
+        "fm_ftrl_criteo_tb": None,  # four chips: the compact wire, no plan
+        "mvm_ftrl_criteo_tb": (43008, 294912),
+        "ffm_ftrl_criteo_tb": (53248, 0),
+        "dcn_ftrl_criteo_tb": (40960, 131072),
+        "xdeepfm_ftrl_criteo_tb": (55296, 0),
+        "autoint_ftrl_criteo_tb": (55296, 0),
+        "fibinet_ftrl_criteo_tb": (55296, 0),
+    }
+    selected = {}
+    for name, planes in caps.items():
+        doc = manifest.config_file(f"benchmarks/configs/{name}.json")
+        fields = {
+            k: v for k, v in manifest.apply_rehearsal(doc, False).items()
+            if k not in manifest.CONFIG_META
+        }
+        if planes is None:
+            assert fields["num_devices"] == 4
+            continue
+        cfg = Config(**fields)
+        step = TrainStep(make_model(cfg), make_optimizer(cfg), cfg, make_mesh(1))
+        assert step.dict_wire and cfg.hot_size
+        selected[name] = sorted(step._touched_rows_names(*planes, True))
+    assert selected == {
+        name: ["emb"] if name.split("_")[0] in ("xdeepfm", "autoint", "fibinet")
+        else []
+        for name in selected
+    }
+    assert [padded_columns(d) for d in (1, 8, 10, 16, 26, 160)] == [
+        8, 8, 16, 16, 32, 160
+    ]
+    # the three cells stand at 9 709 padded elements an index
+    assert (1 << 25) * 16 // 55296 == 9709 > TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX
+    assert touched_rows_selects(1 << 25, 10, 55296, 0)
+    assert not touched_rows_selects(1 << 16, 10, 55296, 0)   # a small table
+    assert not touched_rows_selects(1 << 16, 10, 256, 0)
+    assert touched_rows_selects(1 << 17, 10, 256, 0)
+    assert not touched_rows_selects(1 << 25, 10, 55296, 256)  # a tail
+    assert not touched_rows_selects(1 << 28, 1, 53248, 0)     # one column
+    assert not touched_rows_selects(1 << 25, 160, 53248, 0)   # too wide
+
+
+def test_the_benchmark_reads_the_touched_rows_counter_or_nothing():
+    """benchmarks/layer_metrics/touched_rows_indices_per_step.py: the mean
+    of the epochs' ``_wire`` rows that carry the counter, and NOTHING (no
+    raise) on a run of a program older than the counter, which is what the
+    driver's traced run of the parent hands it; its BENCHMARK.json entry
+    lists the three cells whose ``emb`` the rule selects and says what the
+    module says."""
+    import json
+
+    from benchmarks.layer_metrics import touched_rows_indices_per_step as reader
+
+    assert reader.read({}) is None
+    assert reader.read({"epochs": [{"_wire": {"format": "dict"}}, {}]}) is None
+    assert reader.read({"epochs": [
+        {"_wire": {"touched_rows_indices_per_step": 55296}},
+        {"_wire": {"touched_rows_indices_per_step": 55296}},
+        {"seconds": 1.0},
+    ]}) == 55296
+    assert reader.read(
+        {"epochs": [{"_wire": {"touched_rows_indices_per_step": 0}}]}
+    ) == 0
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    entry = doc["per_layer"][-1]
+    assert entry == {
+        "name": "touched_rows_indices_per_step", "unit": reader.UNIT,
+        "better": "higher", "source": reader.SOURCE, "layer": reader.LAYER,
+        "moves": reader.MOVES,
+        "workloads": [
+            "xdeepfm_tb.train_packed", "autoint_tb.train_packed",
+            "fibinet_tb.train_packed",
+        ],
+    }
+
+
+@pytest.mark.parametrize("wire,microbatch,model,table_log2", [
+    ("dict", 1, "lr", 14), ("dict", 4, "lr", 14), ("compact", 1, "lr", 14),
+    ("dict", 1, "fm", 14), ("dict", 4, "fm", 14),
+    ("dict", 1, "fm", 20), ("dict", 4, "fm", 20), ("dict", 1, "lr", 20),
 ])
 def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
-    toy_dataset, tmp_path, wire, microbatch, model
+    toy_dataset, tmp_path, wire, microbatch, model, table_log2
 ):
     """The epoch's ``wire`` row says how far the dictionary route
     engages, from shapes: ``table_gather_indices_per_step`` beside
@@ -1087,25 +1312,34 @@ def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
     the way back, summed over the tables (PR 48): the same capacities
     for a table wider than one column where the step read the plan (FM's
     ``v``), the padded slots for a one-column table (LR's and FM's
-    ``w``) and for every table of every other batch."""
+    ``w``) and for every table of every other batch.  At 2^20 rows FM's
+    ``v`` is large enough for a dictionary of at most 1 536 entries (these
+    batches have no tail) to take the optimizer on the dictionary's rows
+    alone (step.py::touched_rows_selects): it gets no gradient buffer,
+    leaves ``table_scatter_indices_per_step`` and is counted by
+    ``touched_rows_indices_per_step``, the dictionary's capacity as
+    shipped; 0 at 2^14 rows, for LR's one column and under microbatch
+    slices."""
     from xflow_tpu.obs import schema
     from xflow_tpu.trainer import Trainer
 
     cfg = Config(
         model=model, train_path=toy_dataset.train_prefix, epochs=1,
-        batch_size=64, table_size_log2=14, max_nnz=24, num_devices=1,
+        batch_size=64, table_size_log2=table_log2, max_nnz=24, num_devices=1,
         wire_dedup="on" if wire == "dict" else "off",
         microbatch=microbatch, metrics_out=str(tmp_path / "m.jsonl"),
     )
     trainer = Trainer(cfg)
     try:
         assert trainer.step.wire_format == wire
-        caps = []
+        caps, shipped = [], []
         book = trainer.step._book_wire
 
         def spy(nbytes, examples, cb=None, **shapes):
             if cb is not None:
                 caps.append(len(cb.cu) + len(cb.ct))
+                assert len(cb.ct) == 0
+                shipped.append(sum(shapes["plane_caps"]))
             book(nbytes, examples, cb=cb, **shapes)
 
         trainer.step._book_wire = spy
@@ -1121,13 +1355,21 @@ def test_the_wire_row_counts_what_the_cold_gather_asks_of_the_table(
         assert row["table_gather_indices_per_step"] == round(
             sum(caps) / len(caps)
         ) < slots
-        # w per padded slot; v, where there is one, per entry
+        touched = model == "fm" and table_log2 == 20
+        # w per padded slot; v, where there is one, per entry, to its
+        # buffer or, where it has none, to the touched-rows application
         assert row["table_scatter_indices_per_step"] == round(
-            slots + (tables - 1) * sum(caps) / len(caps)
+            slots + (tables - 1 - touched) * sum(caps) / len(caps)
         ) <= tables * slots
+        assert row["touched_rows_indices_per_step"] == round(
+            touched * sum(shipped) / len(shipped)
+        )
+        assert all(a >= b for a, b in zip(shipped, caps))
+        assert touched == bool(row["touched_rows_indices_per_step"])
     else:
         assert row["table_gather_indices_per_step"] / slots == 1.0
         assert row["table_scatter_indices_per_step"] == tables * slots
+        assert row["touched_rows_indices_per_step"] == 0
     assert not schema.validate_row({"t": 0.0, "kind": "wire", **row})
 
 

@@ -292,6 +292,46 @@ def _table_sized_copies(text: str, elements: int) -> list[str]:
     ]
 
 
+def _assert_touched_rows_update(hlo: str, t: int, d: int, cap_u: int) -> None:
+    """PR 57: the dense update of a [t, d] table that the rule selects
+    (step.py::touched_rows_selects: xDeepFM's, AutoInt's and FiBiNET's
+    ``emb`` under a dictionary of ``cap_u`` entries and an empty tail) as
+    the TPU's compiler leaves it.  NO zeroed [T, D] gradient buffer (the
+    parent's ``broadcast`` of 2 GiB, unscoped); under xf.optimizer NO
+    fusion that walks the table (the parent's kLoop FTRL pass over param,
+    n, z and the buffer): of the fusions that yield a table-shaped array,
+    three are the sets of ``cap_u`` rows into param, n and z, and what is
+    left is the head's H rows going back IN PLACE (every table-shaped
+    instruction of its body a dynamic-update-slice of an operand); and
+    the table is gathered at ``cap_u`` rows four times: param, n, z for
+    the update, param once for the forward."""
+    table = rf"f32\[{t},{d}\]"
+    assert not re.findall(rf"= {table}\S* broadcast\(", hlo)
+    writes = [
+        body for results, body in _optimizer_passes(hlo, t)
+        if re.search(table, results)
+    ]
+    sets = [body for body in writes if " scatter(" in body]
+    assert len(sets) == 3, len(sets)
+    assert all(f"s32[{cap_u}]" in body for body in sets)
+    for body in writes:
+        if body in sets:
+            continue
+        made = [
+            line for line in body.splitlines()
+            if re.search(rf"= \(?{table}", line) and " parameter(" not in line
+        ]
+        assert made and all(
+            " dynamic-update-slice(" in line or " tuple(" in line
+            for line in made
+        ), made
+    gathers = re.findall(
+        rf"\(param_[\d.]+: {table}, param_[\d.]+: s32\[{cap_u}\]\) -> "
+        rf"f32\[{cap_u},{d}\]", hlo,
+    )
+    assert len(gathers) == 4, gathers
+
+
 def _lowered_fm_mesh_step(topo):
     """(cfg, lowered): the FM train step over the described 2x2 at the
     widths of the benchmark's fm_tb_x4.train_packed, a table and a batch
@@ -568,14 +608,16 @@ def _scatters(text: str) -> list[tuple[str, str, str]]:
 
 
 # the cell's fixture, its widest table's name, the program peak of the
-# parent's step (PR 47's tree, compiled here for the same described v5e)
-@pytest.mark.parametrize("cell,planes,wide,parent_gib", [
-    ("mvm_cell_step", MVM_PLANES, "v", 9.189),
-    ("dcn_cell_step", DCN_PLANES, "emb", 9.316),
-    ("autoint_cell_step", AUTOINT_PLANES, "emb", 8.018),
+# parent's step (PR 47's tree, compiled here for the same described v5e;
+# AutoInt's less the 2 GiB gradient buffer that PR 57 took from it), the
+# writes into the wide table
+@pytest.mark.parametrize("cell,planes,wide,parent_gib,writes", [
+    ("mvm_cell_step", MVM_PLANES, "v", 9.189, 1),
+    ("dcn_cell_step", DCN_PLANES, "emb", 9.316, 1),
+    ("autoint_cell_step", AUTOINT_PLANES, "emb", 8.018 - 1.6, 3),
 ])
 def test_cold_scatter_hands_a_wide_table_an_index_per_dictionary_and_tail_entry_on_v5e(
-    request, cell, planes, wide, parent_gib
+    request, cell, planes, wide, parent_gib, writes
 ):
     """PR 48, compiled for a described v5e at the plane capacities of one
     real batch of mvm_tb.train_packed, dcn_tb.train_packed and
@@ -587,7 +629,12 @@ def test_cold_scatter_hands_a_wide_table_an_index_per_dictionary_and_tail_entry_
     gradients meet only the [cap(cu), D] dictionary buffer
     (step.py::dict_cold_grads); DCN's one-column ``w`` keeps an index
     per padded slot on its flat view; and the program's peak stays
-    within 0.2 GiB of the parent's."""
+    within 0.2 GiB of the parent's.  AutoInt's batch has no tail, so
+    since PR 57 its ``emb`` has no gradient buffer at all
+    (step.py::touched_rows_selects): the same 55 296 indices reach the
+    table three times, as the sets of param, n and z, and the program
+    needs 1.6 GiB less (8.018 -> 6.416: the 2 GiB buffer, less what now
+    sets the peak)."""
     cfg, *_, compiled = request.getfixturevalue(cell)
     cap_u, cap_t = planes["cw_cu"][0][0], planes["cw_ct"][0][0]
     t, m = cfg.table_size, cfg.batch_size * cfg.max_nnz
@@ -595,7 +642,7 @@ def test_cold_scatter_hands_a_wide_table_an_index_per_dictionary_and_tail_entry_
     assert cap_u + cap_t < m
     scatters = _scatters(compiled.as_text())
     into_table = [s for s in scatters if s[0] == f"f32[{t},{d}]"]
-    assert into_table == [(
+    assert into_table == writes * [(
         f"f32[{t},{d}]", f"s32[{cap_u + cap_t}]", f"f32[{cap_u + cap_t},{d}]"
     )], scatters
     by_slot = [s for s in scatters if s[2].startswith(f"f32[{m}")]
@@ -856,8 +903,9 @@ def test_ffm_step_contracts_fields_in_float32_and_fits_a_v5e(
 # serialized bodies blanked: they embed the checkout's path) of the four
 # configurations the benchmark measured before PR 39, pinned on PR 38's
 # tree BEFORE models/blocks.py was edited, anew by PR 44, MVM's and
-# FM's again by PR 45, MVM's, DCN's and xDeepFM's again by PR 48, and
-# DCN's alone by PR 49 (the tests' docstrings say why).
+# FM's again by PR 45, MVM's, DCN's and xDeepFM's again by PR 48, DCN's
+# alone by PR 49 and xDeepFM's alone by PR 57 (the tests' docstrings say
+# why).
 MEASURED_PROGRAMS_SHA256 = {
     "lr_ftrl_criteo_tb": (
         "e288dfde6bd0a7646d26153ef9b2ad0ba6d6a5056fe6a02cc9440b498b84d5bc"
@@ -875,12 +923,13 @@ MEASURED_PROGRAMS_SHA256 = {
     # pinned by PR 47 on PR 46's tree BEFORE blocks.py was edited (and equal
     # after: cin_stack's padding and slicing went into two helpers that the
     # attention block shares); anew by PR 48, whose cold scatter route
-    # their emb tables take; DCN's again by PR 49 (emb's head scatter)
+    # their emb tables take; DCN's again by PR 49 (emb's head scatter);
+    # xDeepFM's again by PR 57 (emb's optimizer on the dictionary's rows)
     "dcn_ftrl_criteo_tb": (
         "22461118a9ecac7b78c7f12a043db151d345f5f75359bfd31e7b9491965154d1"
     ),
     "xdeepfm_ftrl_criteo_tb": (
-        "2593f4a8abee8f284e9a4dbdbad8eee6dcfe3d9dfc0e306f5ff0c58fe52dda18"
+        "015974fe6e82ab78886fe3adf7c1c41438557f040013b62df99ef1a69cbc7e7e"
     ),
 }
 DENSE_PROGRAMS = {
@@ -928,7 +977,15 @@ def test_measured_train_programs_lower_to_the_pinned_text(topo):
     width (ops/hot.py::scatter_form), a plain scatter-add from
     PLAIN_SCATTER_MIN_COLUMNS = 16 columns up, the scan below: LR's and
     FFM's w (one column) and MVM's and the FM mesh's v (ten) keep the
-    scan, and all four digests are the parent's."""
+    scan, and all four digests are the parent's.  PR 57 meant to change
+    NONE of the four either: the dense update of a table runs on the
+    dictionary's rows alone only under a dictionary-wire batch with an
+    EMPTY tail plane, for a table of 2 to 64 columns large enough for
+    its index count (step.py::touched_rows_selects).  LR's and FFM's w
+    have one column, FFM's v 160 and is off the head, MVM's batch has a
+    tail of 262 144 entries and the mesh ships no plan: the selection is
+    empty, a Python-level set, and the traced programs are the parent's
+    to the instruction."""
     got = {
         "lr_ftrl_criteo_tb": _lowered_cell_step(
             topo, "lr_ftrl_criteo_tb", LR_PLANES, ships_slots=False
@@ -972,7 +1029,13 @@ def test_measured_dense_programs_lower_to_the_pinned_text(topo, config):
     anew.  PR 49 meant to change DCN's and NOT xDeepFM's: the head's
     scatter of emb's 26 columns is a plain scatter-add
     (ops/hot.py::scatter_form), xDeepFM's ten columns keep the scan and
-    PR 48's digest."""
+    PR 48's digest.  PR 57 meant to change xDeepFM's and NOT DCN's:
+    xDeepFM's batch is a dictionary of 55 296 entries with no tail, so
+    emb [2^25, 10] gets no gradient buffer and takes FTRL on those rows
+    and on the head (step.py::touched_rows_selects, _touched_rows_pass;
+    w, one column, keeps its buffer and flat pass); DCN's batch has a
+    tail of 131 072 entries, which rules its emb out before its size is
+    asked, and its digest is PR 49's."""
     lowered = _lowered_cell_step(topo, config, DENSE_PROGRAMS[config])[2]
     assert _program_sha256(lowered) == MEASURED_PROGRAMS_SHA256[config]
 
@@ -1076,13 +1139,16 @@ def test_dcn_step_multiplies_in_float32_under_xf_dense_and_fits_a_v5e(
 
 # the cell's fixture, the scatter scans its step keeps (one-hot products
 # under xf.scatter), the program peak of the parent's step (PR 48's tree,
-# compiled here for the same described v5e)
-@pytest.mark.parametrize("cell,scans,parent_gib", [
-    ("dcn_cell_step", ["f32[128,128]"], 9.322),
-    ("autoint_cell_step", [], 8.014),
+# compiled here for the same described v5e; AutoInt's less the gradient
+# buffer that PR 57 took from it), the dictionary's entries whose sums are
+# folded into the head's (PR 57: AutoInt's alone, whose emb takes its
+# optimizer on the dictionary's rows)
+@pytest.mark.parametrize("cell,scans,parent_gib,folded", [
+    ("dcn_cell_step", ["f32[128,128]"], 9.322, 0),
+    ("autoint_cell_step", [], 8.014 - 1.6, 55296),
 ])
 def test_wide_head_gradients_are_added_plainly_a_piece_at_a_time_on_v5e(
-    request, cell, scans, parent_gib
+    request, cell, scans, parent_gib, folded
 ):
     """PR 49: from hot.PLAIN_SCATTER_MIN_COLUMNS = 16 columns the head's
     SCATTER is a plain scatter-add into the [H, D] slice
@@ -1114,7 +1180,13 @@ def test_wide_head_gradients_are_added_plainly_a_piece_at_a_time_on_v5e(
     ]
     assert products == scans, products
     into_head = [s for s in _scatters(text) if s[0] == f"f32[{h},{d}]"]
-    assert into_head == [(f"f32[{h},{d}]", f"s32[{c}]", f"f32[{c},{d}]")]
+    # a piece of the hot slots at a time; then, where the table has no
+    # gradient buffer, the sums of the dictionary's entries below H
+    # (step.py::_touched_rows_pass; the others carry index H and drop)
+    assert sorted(into_head) == sorted(
+        (f"f32[{h},{d}]", f"s32[{n}]", f"f32[{n},{d}]")
+        for n in (c, folded) if n
+    )
     # the pieces: slots minor; never a row a slot
     assert re.search(rf"f32\[{m // c},{d},{c}\]\{{2,[01],[01]:", text)
     assert f"f32[{m},{d}]{{1,0:" not in text
@@ -1146,8 +1218,11 @@ def test_xdeepfm_step_contracts_pairs_in_float32_a_slice_at_a_time_on_v5e(
     slices, carry ``xf.cin`` in ``op_scopes``' reading (the innermost
     name), the contractions (convolutions, as the TPU's compiler writes a
     dot) among them and none under ``xf.dense``, which keeps the DNN's; no
-    table-sized copy of emb's state is made; and the program fits with the
-    room the file's ``reduced`` argues from, 8.42 GiB of 15.75."""
+    table-sized copy of emb's state is made; emb takes its optimizer on
+    the dictionary's rows with no gradient buffer (PR 57,
+    ``_assert_touched_rows_update``); and the program fits with more than
+    the room the file's ``reduced`` argues from (8.42 GiB of 15.75 with
+    the buffer, 6.9 without)."""
     from xflow_tpu.models import blocks
     from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
 
@@ -1220,8 +1295,11 @@ def test_xdeepfm_step_contracts_pairs_in_float32_a_slice_at_a_time_on_v5e(
         line for line in _table_sized_copies(hlo, cfg.table_size)
         if f"f32[{cfg.table_size},{d}]" in line
     ]
+    _assert_touched_rows_update(
+        hlo, cfg.table_size, d, XDEEPFM_PLANES["cw_cu"][0][0]
+    )
     peak = _program_peak(compiled)
-    assert 8.0 * (1 << 30) < peak < 9.0 * (1 << 30), peak
+    assert 6.5 * (1 << 30) < peak < 7.5 * (1 << 30), peak
 
 
 def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
@@ -1258,8 +1336,11 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
     slices' tower with its presence row, relaid to the lane form; the
     output and its relayouts) but the compiler's asynchronous moves
     between memories; the output product is ``xf.dense``'s; no table-sized
-    copy of emb's state is made; and the program fits with the room the
-    file's ``reduced`` argues from, 8.01 GiB of 15.75."""
+    copy of emb's state is made; emb takes its optimizer on the
+    dictionary's rows with no gradient buffer (PR 57,
+    ``_assert_touched_rows_update``); and the program fits with more than
+    the room the file's ``reduced`` argues from (8.01 GiB of 15.75 with
+    the buffer, 6.42 without)."""
     from xflow_tpu.models import blocks
     from xflow_tpu.parallel.step import (
         _HLO_COMPUTATION_RE, _HLO_FUSED_RE, _HLO_INSTRUCTION_RE,
@@ -1385,8 +1466,11 @@ def test_autoint_step_attends_in_float32_a_slice_at_a_time_on_v5e(
         line for line in _table_sized_copies(hlo, cfg.table_size)
         if f"f32[{cfg.table_size},{cfg.emb_dim}]" in line
     ]
+    _assert_touched_rows_update(
+        hlo, cfg.table_size, cfg.emb_dim, AUTOINT_PLANES["cw_cu"][0][0]
+    )
     peak = _program_peak(compiled)
-    assert 7.5 * (1 << 30) < peak < 8.75 * (1 << 30), peak
+    assert 6.0 * (1 << 30) < peak < 6.75 * (1 << 30), peak
 
 
 def test_fibinet_step_multiplies_pairs_in_float32_without_a_pair_matrix_array_on_v5e(
@@ -1413,8 +1497,11 @@ def test_fibinet_step_multiplies_pairs_in_float32_without_a_pair_matrix_array_on
     instructions carry ``xf.bilinear`` in ``op_scopes``' reading (the
     innermost name) with its products (convolutions, as the TPU's compiler
     writes a dot) among them, the hidden stack's stay ``xf.dense``'s, no
-    table-sized copy of emb's state is made, and the program fits with the
-    room the file's ``reduced`` argues from."""
+    table-sized copy of emb's state is made, emb takes its optimizer on the
+    dictionary's rows with no gradient buffer (PR 57,
+    ``_assert_touched_rows_update``), and the program fits with the room
+    the file's ``reduced`` argues from (its peak is the dense half's, the
+    pair tensor and its cotangent, not the buffer's)."""
     from xflow_tpu.models import blocks
     from xflow_tpu.parallel.step import _HLO_OP_NAME_RE, scope_of
 
@@ -1479,6 +1566,9 @@ def test_fibinet_step_multiplies_pairs_in_float32_without_a_pair_matrix_array_on
         line for line in _table_sized_copies(hlo, cfg.table_size)
         if f"f32[{cfg.table_size},{d}]" in line
     ]
+    _assert_touched_rows_update(
+        hlo, cfg.table_size, d, FIBINET_PLANES["cw_cu"][0][0]
+    )
     peak = _program_peak(compiled)
     assert 8.0 * (1 << 30) < peak < 10.0 * (1 << 30), peak
 

@@ -457,6 +457,13 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # [T] view: the tables of one column (step.py::_optimizer_pass),
         # 0 where every table is wider or the update touches rows alone
         "flat_pass_elements_per_step": (int, float),
+        # the indices the dense update hands the touched-rows
+        # application, summed over the tables that get no [T, D]
+        # gradient buffer: the dictionary's capacity for a table of 2 to
+        # 64 columns, large enough for it, under a whole dictionary-wire
+        # batch with an empty tail (step.py::touched_rows_selects); 0
+        # where no table is selected
+        "touched_rows_indices_per_step": (int, float),
         # a family with replicated dense parameters only, from shapes:
         # the bytes of its dense arrays, and 6 B k n operations a step
         # for every [B, k] x [k, n] product with one of them

@@ -654,6 +654,53 @@ def dict_cold_rows(
 # 160: the route won at FFM's batch and lost at MVM's).
 DICT_SCATTER_COLUMNS = range(2, 65)
 
+# The fewest padded table elements (rows x the width as the TPU pads a
+# row, padded_columns) per index handed to the table at which the dense
+# update of a dictionary-only batch runs the optimizer on the
+# dictionary's rows alone (touched_rows_selects) instead of over a
+# zeroed [T, D] gradient buffer.  From scripts/probe_touched_rows.py on
+# a v5e (PR 56; one real batch of each cell, alone; ms), (a) zero + table
+# write + pass / (b) 3 gathers + FTRL on the rows + 3 sets:
+#   FiBiNET, xDeepFM [2^25, 10], 55 296 + 0 indices:  31.42 / 20.45
+#   MVM [2^25, 10], 43 008 + 294 912 (rows repeat):   59.95 / 123.96
+#   DCN [2^24, 26], 40 960 + 131 072:                 47.43 / 82.61
+# A set costs what an add costs (100 ns an index at D = 10, 123 at 26)
+# and a row gather 23-37 ns, so (b) is ~370 ns an index where (a) is
+# ~103 ns an index + 25.7 ms for 2^29 padded elements (0.048 ns each):
+# the two meet near 5 600 padded elements an index.  The three
+# B = 16 384 cells stand at 9 709; MVM (1 589) and DCN (3 121) are ruled
+# out by their tails before the size is asked.  The line is there for a
+# SMALL table under a dictionary-only batch, where the pass is cheaper.
+TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX = 5600
+
+
+def padded_columns(columns: int) -> int:
+    """The columns a float32 table row takes in the TPU's memory: the
+    width rounded up to a multiple of 8 (10 -> 16, 26 -> 32)."""
+    return -(-columns // 8) * 8
+
+
+def touched_rows_selects(
+    table_rows: int, columns: int, cap_u: int, cap_t: int
+) -> bool:
+    """Whether the dense update of a whole dictionary-wire batch whose
+    dictionary and tail planes hold ``cap_u`` and ``cap_t`` entries runs
+    the optimizer of a [table_rows, columns] table on the dictionary's
+    rows alone (TrainStep._touched_rows_pass), from shapes: a width on
+    the dictionary route (DICT_SCATTER_COLUMNS; one column loses: six
+    arrays at 14.8 ns an index beside a flat pass, PERF.md section 7);
+    an EMPTY tail plane (a tail repeats rows, which a set cannot take,
+    and is what makes the index count large); and a table large enough
+    for its index count (TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX).  What the
+    step adds of its own: the plan's presence and the table's place on
+    the MXU head (TrainStep._touched_rows_tables)."""
+    return (
+        columns in DICT_SCATTER_COLUMNS
+        and cap_t == 0
+        and table_rows * padded_columns(columns)
+        >= TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX * cap_u
+    )
+
 
 def dict_scatter_plan(plan: dict, table_size: int, lane_select) -> dict:
     """What dict_cold_grads needs of a dictionary-wire batch's
@@ -1038,6 +1085,7 @@ class TrainStep:
     def _book_wire(
         self, nbytes: int, examples: int, cb=None, cold_slots: int = 0,
         slots_bytes: int = 0, hot_slots: int = 0,
+        plane_caps: tuple[int, int] | None = None,
     ) -> None:
         """Wire accounting counters behind the trainer's per-epoch
         ``wire`` metrics row (obs/schema.py): bytes that crossed the
@@ -1058,7 +1106,13 @@ class TrainStep:
         capacities for a table whose gradients leave through the
         dictionary (dict_cold_grads: a whole dictionary-wire batch of
         the dense update, a table whose width is in DICT_SCATTER_COLUMNS)
-        and the padded slots for every other.  And what those slots move,
+        and the padded slots for every other; a table that gets no
+        buffer is left out of it and counted by ``touched_rows_indices``:
+        the indices the dense update hands _apply_touched_rows, summed
+        over the tables of _touched_rows_tables, from ``plane_caps``, the
+        dictionary's and the tail's capacities AS SHIPPED (what the
+        traced program sees: _settle_planes may have lengthened them);
+        0 where no table is selected.  And what those slots move,
         in bytes of table rows: ``gather_row_bytes``, every index the
         step's gathers hand a [T, D] table times that table's row, and
         ``scatter_row_bytes``, a row read and a row written for every
@@ -1110,10 +1164,16 @@ class TrainStep:
             self.obs.counter("wire.cold_slots", cold_slots)
             self.obs.counter("wire.table_gather_indices", indices)
             on_route = self._dict_scatter_tables if through_dict else 0
+            touched = len(self._touched_rows_names(
+                *plane_caps, hot_slots > 0
+            )) if on_route and plane_caps else 0
             self.obs.counter(
                 "wire.table_scatter_indices",
-                on_route * indices
+                (on_route - touched) * indices
                 + (len(self._mxu_hot) - on_route) * cold_slots,
+            )
+            self.obs.counter(
+                "wire.touched_rows_indices", touched * sum(plane_caps or ())
             )
             if through_dict:
                 self.obs.counter(
@@ -1309,6 +1369,10 @@ class TrainStep:
             sum(int(v.nbytes) for v in wire.values()),
             batch.num_real(),
             cb=cb,
+            plane_caps=(
+                (len(wire["cw_cu"]), len(wire["cw_ct"]))
+                if cb is not None else None
+            ),
             cold_slots=batch.batch_size * batch.max_nnz,
             hot_slots=batch.batch_size * batch.hot_nnz,
             slots_bytes=sum(
@@ -1816,7 +1880,11 @@ class TrainStep:
     ) -> dict:
         """Accumulate per-occurrence grads into dense [T, D] buffers
         (one per table): scatter-add for the cold section, two-level
-        one-hot MXU matmuls for the hot section (ops/hot.py)."""
+        one-hot MXU matmuls for the hot section (ops/hot.py).  A table
+        that ``gbufs`` holds no buffer for (_touched_rows_tables) gets
+        what _touched_rows_pass takes instead: the dictionary's rows,
+        their summed gradients and the head's [H, D] sum (None without
+        a head), none of them written to a table-sized array."""
         cfg = self.cfg
         kh = batch["hot_keys"].shape[1] if "hot_keys" in batch else 0
         keys_eff = self._cold_keys_eff(batch)
@@ -1845,6 +1913,12 @@ class TrainStep:
                 hot_g = occ[:, :kh].reshape(-1, d)
                 occ = occ[:, kh:]
             occ = occ.reshape(-1, d)
+            if name not in gbufs:
+                ghot = hot_scatter(
+                    hot_keys_eff, hot_g, cfg.hot_size, impl=self._hot_impl
+                ) if kh else None
+                out[name] = (splan["rows"], dict_cold_grads(splan, occ), ghot)
+                continue
             if name in through_dict:
                 gbuf = self._cold_accumulate(
                     gbufs[name], splan["rows"], dict_cold_grads(splan, occ)
@@ -1906,7 +1980,13 @@ class TrainStep:
         # the recurrence runs elementwise over the full table — no sort,
         # no row gather/scatter.  Untouched rows see g=0, for which
         # FTRL/SGD are idempotent (optim docstrings).
-        gbufs = self._zero_gbufs(tables)
+        # A table whose whole cold section is a dictionary of distinct
+        # rows takes the recurrence on those rows and gets no buffer
+        # (_touched_rows_tables: from shapes).
+        touched = self._touched_rows_tables(batch)
+        gbufs = self._zero_gbufs(
+            {n: t for n, t in tables.items() if n not in touched}
+        )
         s = cfg.microbatch
         if s == 1:
             pctr, occ_grads, grad_dense = self._forward_grads(
@@ -1947,21 +2027,58 @@ class TrainStep:
             ll = nll_sum / jnp.maximum(cnt, 1.0)
 
         new_tables = {
-            name: self._optimizer_pass(table, gbufs[name])
+            name: (
+                self._touched_rows_pass(table, *gbufs[name])
+                if name in touched
+                else self._optimizer_pass(table, gbufs[name])
+            )
             for name, table in tables.items()
         }
         return self._finish_step(
             state, new_tables, dense, grad_dense, ll, cnt
         )
 
+    def _touched_rows_tables(self, batch: BatchArrays) -> frozenset[str]:
+        """The tables whose dense update runs on the rows this batch's
+        dictionary names and on nothing else (_touched_rows_pass), a
+        Python-level set from shapes the trace holds; empty for every
+        batch without a ``cold_plan`` (the plain wires, a mesh,
+        microbatch slices), where the traced program is the one it was.
+        touched_rows_selects has the rule and
+        TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX the measurements; beside
+        them a table must ride the MXU head, or there is no head: an
+        opted-out table's hot occurrences are table rows that repeat."""
+        if "cold_plan" not in batch or self.cfg.microbatch != 1:
+            return frozenset()
+        plan = batch["cold_plan"]
+        return self._touched_rows_names(
+            plan["cu"].shape[0], plan["ct"].shape[0], "hot_keys" in batch
+        )
+
+    def _touched_rows_names(
+        self, cap_u: int, cap_t: int, head: bool
+    ) -> frozenset[str]:
+        """_touched_rows_tables from the plane capacities themselves:
+        what _book_wire counts a shipped batch by."""
+        return frozenset(
+            spec.name for spec in self.model.tables()
+            if (self._mxu_hot[spec.name] or not head)
+            and touched_rows_selects(
+                self.cfg.table_size, spec.dim, cap_u, cap_t
+            )
+        )
+
     @jax.named_scope("xf.scatter")
     def _zero_gbufs(self, tables: dict) -> dict:
-        """The zeroed [T, D] gradient buffers of the dense update (one
-        per table), booked with the scatter that fills them."""
+        """The zeroed [T, D] gradient buffers of the dense update, booked
+        with the scatter that fills them: one for every table handed in,
+        which is every table but those of _touched_rows_tables."""
         return {
-            # the [T, D] buffer IS dense mode's design (small-table
-            # form; 'sparse' is the 2^28 form) — budgeted in
-            # memory-budget.json, justified here (xf: ignore[XF010])
+            # the [T, D] buffer IS dense mode's design for a table of one
+            # column, for a batch with a tail or without a dictionary,
+            # and for a table too small for its index count
+            # (touched_rows_selects; 'sparse' is the 2^28 form) — budgeted
+            # in memory-budget.json, justified here (xf: ignore[XF010])
             name: jnp.zeros_like(t["param"]) for name, t in tables.items()
         }
 
@@ -2025,13 +2142,80 @@ class TrainStep:
         """Gather state rows at the consolidated unique keys, run the
         optimizer recurrence, scatter the new rows back (sentinel keys
         clamp on gather and drop on scatter — ops/sparse.py).  The ONE
-        touched-rows application, shared by _sparse_update (both the
-        MXU and opted-out variants) and the hot inner's sparse
-        window-end so the three cannot drift."""
+        touched-rows application, shared by _touched_rows_pass (the
+        hybrid of _sparse_update and of the dense update of a
+        dictionary-only batch), _sparse_update's opted-out variant and
+        the hot inner's sparse window-end so they cannot drift.  A set
+        wants DISTINCT keys: consolidated ones, or a dictionary's."""
         state_rows = {k: gather_rows(arr, ukeys) for k, arr in table.items()}
         new_rows = self.optimizer.update_rows(state_rows, gsum)
         return {
             k: scatter_rows(table[k], ukeys, new_rows[k]) for k in table
+        }
+
+    @jax.named_scope("xf.optimizer")
+    def _touched_rows_pass(
+        self, table: dict, rows: jax.Array, gsum: jax.Array, ghot
+    ) -> dict:
+        """The optimizer recurrence on the DISTINCT table rows ``rows``
+        [n] with their summed gradients ``gsum`` [n, D] and, where the
+        table rides the MXU head, on the head's H rows with ``ghot``
+        [H, D] (None without a head); every other row is left alone.
+        Shared by _sparse_update's hybrid and by the dense update of a
+        dictionary-only batch (_touched_rows_tables), so the two cannot
+        drift.
+
+        Exactly once: a row below H among ``rows`` (the head's overflow
+        into the cold section, io/batch.py::split_hot) has its sum
+        folded into ``ghot`` (index H, out of range for the [H, D]
+        buffer, drops the rest) and is coded as the sentinel T in the
+        list that _apply_touched_rows takes, which clamps it on the
+        gathers and drops it on the sets, as it does the capacity
+        padding that arrives coded so.  Then the head's rows take ONE
+        whole-array update on the [:H] slices and go back by a dynamic
+        update slice (H rows, 115 KB of traffic beside a table pass).
+
+        Against the dense pass over a zeroed [T, D] buffer this is the
+        same state bit for bit wherever ``param`` is what ``(z, n)``
+        give, which is every state this program produced: a cold row
+        meets ONE summed gradient either way, a head row the two-term
+        sum ghot + cold, which commutes, and FTRL at g = 0 keeps ``n``
+        and ``z``, keeps ``param`` where ``n == 0`` and recomputes it
+        from ``(z, n)`` elsewhere, the number the same formula stored at
+        the row's last update (optim/ftrl.py;
+        tests/test_ftrl.py::test_ftrl_zero_grad_is_idempotent).  A
+        RESTORED state whose ``param`` is not what ``(z, n)`` give is
+        repaired by the dense pass and left alone here, as by
+        update_mode='sparse' and by the reference's server, which
+        updates pushed keys only (ftrl.h:54-79).
+
+        What it costs beside the pass (scripts/probe_touched_rows.py on
+        a v5e, PR 56; the table at TOUCHED_ROWS_MIN_ELEMENTS_PER_INDEX):
+        at [2^25, 10] and 55 296 rows three sets of 5.5 ms, three
+        gathers of 1.25 and 0.013 of FTRL, 20.45 ms for the 31.42 of
+        zeroing the buffer (3.27), writing the rows into it (5.63) and
+        the pass over param, n, z and the buffer (22.40).  unique_indices
+        on the sets (20.41), rows in rising order with
+        indices_are_sorted (19.95) and the forward's param rows in place
+        of the third gather (19.17) were not worth their code."""
+        if ghot is None:
+            return self._apply_touched_rows(table, rows, gsum)
+        hsize = self.cfg.hot_size
+        in_hot = rows < hsize
+        new = self._apply_touched_rows(
+            table, jnp.where(in_hot, jnp.int32(self.cfg.table_size), rows), gsum
+        )
+        ghot = ghot.at[jnp.where(in_hot, rows, jnp.int32(hsize))].add(
+            gsum, mode="drop"
+        )
+        new_hot = self.optimizer.update_rows(
+            {k: arr[:hsize] for k, arr in new.items()}, ghot
+        )
+        return {
+            k: jax.lax.dynamic_update_slice_in_dim(
+                new[k], new_hot[k], 0, axis=0
+            )
+            for k in new
         }
 
     @jax.named_scope("xf.optimizer")
@@ -2055,7 +2239,7 @@ class TrainStep:
         < H are folded into the hot gradient buffer and masked out of
         the sparse scatter — every row sees ONE summed-gradient
         update, matching the dense path's gbuf semantics bit-for-bit
-        in structure.
+        in structure (_touched_rows_pass: the one piece of code).
 
         Tables opted OUT of the MXU path (TableSpec.hot=False, e.g.
         FFM's wide v) instead fold their hot-plane occurrences into a
@@ -2064,7 +2248,6 @@ class TrainStep:
         guarantee, no [H, D] buffer."""
         cfg = self.cfg
         kh = batch["hot_keys"].shape[1] if "hot_keys" in batch else 0
-        sentinel = jnp.int32(cfg.table_size)
         keys_eff = self._cold_keys_eff(batch)
         # one shared argsort; every table's gradients ride the same
         # permutation/segments (same sharing as _scatter_grads)
@@ -2073,13 +2256,7 @@ class TrainStep:
         if kh:
             from xflow_tpu.ops.hot import hot_scatter
 
-            hsize = cfg.hot_size
             hot_keys_eff = self._hot_keys_eff(batch)
-            in_hot = ukeys < hsize
-            ukeys_cold = jnp.where(in_hot, sentinel, ukeys)
-            # consolidated cold sums destined for hot rows; index H
-            # (out of range for the [H, D] buffer) drops the rest
-            ukeys_hotpart = jnp.where(in_hot, ukeys, jnp.int32(hsize))
             if not all(self._mxu_hot.values()):
                 # opted-out tables: one combined plan over cold+hot
                 # occurrence keys (shared by every such table)
@@ -2087,8 +2264,6 @@ class TrainStep:
                     [keys_eff, self._hot_keys_eff_dma(batch)]
                 )
                 plan_all = consolidate_plan(keys_all, cfg.table_size)
-        else:
-            ukeys_cold = ukeys
         new_tables = {}
         for name, table in tables.items():
             d = table["param"].shape[-1]
@@ -2108,23 +2283,12 @@ class TrainStep:
                 )
                 continue
             gsum = consolidate_apply(occ.reshape(-1, d), order, seg)
-            new = self._apply_touched_rows(table, ukeys_cold, gsum)
-            if kh:
-                ghot = hot_scatter(
-                    hot_keys_eff, hot_g, hsize,
-                    impl=self._hot_impl,
-                )
-                # non-hot slots carry index H -> dropped; no mask needed
-                ghot = ghot.at[ukeys_hotpart].add(gsum, mode="drop")
-                hot_rows = {k: arr[:hsize] for k, arr in new.items()}
-                new_hot = self.optimizer.update_rows(hot_rows, ghot)
-                new = {
-                    k: jax.lax.dynamic_update_slice_in_dim(
-                        new[k], new_hot[k], 0, axis=0
-                    )
-                    for k in new
-                }
-            new_tables[name] = new
+            ghot = hot_scatter(
+                hot_keys_eff, hot_g, cfg.hot_size, impl=self._hot_impl
+            ) if kh else None
+            new_tables[name] = self._touched_rows_pass(
+                table, ukeys, gsum, ghot
+            )
         return new_tables
 
     def _train_sequential(

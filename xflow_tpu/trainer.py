@@ -1138,12 +1138,16 @@ class Trainer:
                 # (ops/hot.py::gather_form) and by the form its scatter
                 # summed them in (scatter_form); and the elements of the
                 # one-column tables whose optimizer pass ran on the flat
-                # view (step.py::_optimizer_pass)
+                # view (step.py::_optimizer_pass); and the indices the
+                # dense update handed the touched-rows application in
+                # place of a gradient buffer and a table pass
+                # (step.py::_touched_rows_pass; 0 where no table is
+                # selected)
                 for name in (
                     "gather_row_bytes", "scatter_row_bytes", "plain_hot_slots",
                     "hot_plain_slots", "hot_scan_slots",
                     "hot_scatter_plain_slots", "hot_scatter_scan_slots",
-                    "flat_pass_elements",
+                    "flat_pass_elements", "touched_rows_indices",
                 ):
                     stats["_wire"][f"{name}_per_step"] = round(
                         snap.counters[f"wire.{name}"] / batches
