@@ -29,7 +29,7 @@ repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_registry_names_cover_all_families():
     assert set(model_names()) == {
         "lr", "fm", "mvm", "ffm", "wide_deep", "two_tower", "dcn", "xdeepfm",
-        "autoint", "fibinet",
+        "autoint", "fibinet", "dlrm",
     }
 
 

@@ -23,6 +23,21 @@ _RETIRED_FIELDS = {
 }
 
 
+def mlp_widths(text: str, name: str = "widths") -> tuple[int, ...]:
+    """``"512-256-128"`` -> ``(512, 256, 128)``: a stack's layer widths as
+    DLRM's scripts write them (Config.mlp_bottom, Config.mlp_top)."""
+    try:
+        widths = tuple(int(w) for w in text.split("-"))
+    except ValueError:
+        widths = ()
+    if not widths or min(widths) < 1:
+        raise ValueError(
+            f"{name} {text!r}: dash-separated positive layer widths, as "
+            "'512-256-128'"
+        )
+    return widths
+
+
 @dataclasses.dataclass
 class Config:
     # -- model selection (reference: main.cc:27-45, argv[3] '0'/'1'/'2';
@@ -83,6 +98,23 @@ class Config:
     # max_fields gates to max_fields // r and back; its DNN is deep_layers of
     # hidden_dim (the paper's Criteo setting: r = 3, 3 layers of 400).
     senet_reduction: int = 3
+    # Real-valued inputs.  Under hash_mode a token of a field in
+    # [0, numeric_fields) keeps its VALUE (libffm's field:index:value with
+    # one index a numeric field; io/libffm.py, native/src/parser.cc), the
+    # compact and the dictionary wire ship the values as one float32
+    # [B, numeric_fields] plane and a packed-v2 record holds it
+    # (io/compact.py); every other token stays binary.  0 is the
+    # reference's loader (every value discarded, load_data_from_disk.cc:151)
+    # and leaves wires, shards and programs as they were.
+    numeric_fields: int = 0
+    # dlrm (models/dlrm.py): the widths of its two ReLU stacks as the
+    # published scripts write them, dash-separated.  The bottom stack maps
+    # the numeric_fields values to a vector of emb_dim (its last width);
+    # the top stack maps that vector and the pairwise dots to its last
+    # width, under a linear output of 1 (the Criteo-Terabyte setting:
+    # 512-256-128 and 1024-1024-512-256 over embeddings of 128).
+    mlp_bottom: str = "16-8"
+    mlp_top: str = "32-16"
     # Static padded features-per-sample inside the jit step.  Samples with
     # more features than this are truncated (reference has no limit —
     # features-per-sample is whatever the text line holds).
@@ -531,6 +563,29 @@ class Config:
             raise ValueError("attn_heads and attn_dim must be >= 1")
         if self.senet_reduction < 1:
             raise ValueError("senet_reduction must be >= 1")
+        if not 0 <= self.numeric_fields <= 255:
+            raise ValueError("numeric_fields must be in [0, 255]")
+        bottom = mlp_widths(self.mlp_bottom, "mlp_bottom")
+        mlp_widths(self.mlp_top, "mlp_top")  # refuses a malformed string
+        if self.model == "dlrm":
+            if self.numeric_fields < 1:
+                raise ValueError(
+                    "model 'dlrm' reads real-valued inputs: numeric_fields "
+                    "must be >= 1"
+                )
+            if self.max_fields - self.numeric_fields < 2:
+                raise ValueError(
+                    f"model 'dlrm' interacts max_fields - numeric_fields = "
+                    f"{self.max_fields - self.numeric_fields} vectors (the "
+                    "bottom stack's output and one a categorical field): "
+                    "at least 2"
+                )
+            if bottom[-1] != self.emb_dim:
+                raise ValueError(
+                    f"mlp_bottom ends in {bottom[-1]} and emb_dim is "
+                    f"{self.emb_dim}: the bottom stack's output is dotted "
+                    "with the embeddings"
+                )
         if self.optimizer not in ("ftrl", "sgd"):
             raise ValueError(f"unknown optimizer {self.optimizer!r}")
         if self.update_mode not in ("dense", "sparse", "sequential"):

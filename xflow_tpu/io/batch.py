@@ -87,6 +87,53 @@ class Batch:
         return int(self.weights.sum())
 
 
+def numeric_plane(batch: "Batch", numeric_fields: int) -> np.ndarray:
+    """float32 ``[B, numeric_fields]``: the value of the row's entry of each
+    numeric field (a field id below ``numeric_fields``; libffm's
+    ``field:index:value`` with one index a field), 0 where the row has
+    none.  What the compact and the dictionary wire and a packed-v2
+    record ship of a batch's values (io/compact.py, parallel/step.py):
+    every other entry's value is 1 there, and ``values_from_plane``
+    rebuilds the ``[B, K]`` values from the plane and the field ids."""
+    plane = np.zeros((batch.batch_size, numeric_fields), np.float32)
+    for slots, vals, mask in (
+        (batch.hot_slots, batch.hot_vals, batch.hot_mask),
+        (batch.slots, batch.vals, batch.mask),
+    ):
+        r, c = np.nonzero((mask > 0) & (slots >= 0) & (slots < numeric_fields))
+        plane[r, slots[r, c]] = vals[r, c]
+    return plane
+
+
+def values_from_plane(
+    slots: np.ndarray, mask: np.ndarray, plane: np.ndarray | None
+) -> np.ndarray:
+    """``numeric_plane``'s inverse for one section: float32 ``[B, K]``
+    values, the plane's number for a real entry of a numeric field, 1 for
+    every other real entry, 0 for padding.  ``plane`` None: no numeric
+    field, the hash mode's binary values."""
+    if plane is None or not plane.shape[1]:
+        return mask.astype(np.float32)
+    numeric = (slots >= 0) & (slots < plane.shape[1])
+    picked = np.take_along_axis(plane, np.where(numeric, slots, 0), axis=1)
+    return np.where(numeric, picked, np.float32(1.0)) * mask
+
+
+def values_fit_plane(batch: "Batch", numeric_fields: int) -> bool:
+    """Whether a ``[B, numeric_fields]`` plane holds every value of the
+    batch: each real entry outside the numeric fields has value 1, and the
+    entries a row has of one numeric field agree (``numeric_fields`` 0: the
+    hash mode's invariant, value 1 wherever mask 1)."""
+    plane = numeric_plane(batch, numeric_fields) if numeric_fields else None
+    return all(
+        np.array_equal(values_from_plane(slots, mask, plane), vals * mask)
+        for slots, vals, mask in (
+            (batch.hot_slots, batch.hot_vals, batch.hot_mask),
+            (batch.slots, batch.vals, batch.mask),
+        )
+    )
+
+
 @dataclasses.dataclass
 class ParsedBlock:
     """CSR view of one parsed text block (pre-padding)."""

@@ -48,6 +48,14 @@ by where its information actually lives:
   single elements takes 3 (PERF.md section 6, PR 30).
 * **labels/weights ship as bitmaps** (eligibility requires the 0/1
   hash-mode invariant, like the plain compact wire).
+* **values ship only where they are not 1.**  Hash-mode features are
+  binary and their values never ship; with ``numeric_fields`` > 0
+  (Config.numeric_fields: a token of a field below it keeps its value)
+  the batch carries ONE float32 ``[B, numeric_fields]`` plane ``nv``, the
+  value of the row's entry of each numeric field (io/batch.py::
+  numeric_plane), and the device rebuilds the ``[B, K]`` values from it
+  and the field ids.  The plane is ABSENT, not empty, at 0 fields: such a
+  batch's planes, bytes and program are what they were before it existed.
 
 Plane capacities are rounded up to a coarse granule (plane_cap) so a
 steady stream of same-geometry batches maps to ONE set of array shapes
@@ -67,7 +75,12 @@ import dataclasses
 
 import numpy as np
 
-from xflow_tpu.io.batch import Batch
+from xflow_tpu.io.batch import (
+    Batch,
+    numeric_plane,
+    values_fit_plane,
+    values_from_plane,
+)
 
 # Dictionary capacity: u16 occurrence indices, and a [DICT_CAP, D]
 # consolidation buffer small enough to live in cache (CPU) / VMEM-near
@@ -240,6 +253,9 @@ class CompactBatch:
     wb: np.ndarray   # [ceil(B/8)] u8 — weights bitmap
     cs: np.ndarray   # [capC] slots (cold, flat row-major; exact dtype)
     hs: np.ndarray   # [capH] slots (hot, flat row-major)
+    # [B, numeric_fields] f32: the numeric fields' values; None (and
+    # absent from the wire and from a packed record) without such fields
+    nv: np.ndarray | None = None
 
     # -- Batch-compatible surface ------------------------------------------
 
@@ -281,15 +297,17 @@ class CompactBatch:
         dict_cap: int = DICT_CAP,
         check: bool = True,
         strict_layout: bool = False,
+        numeric_fields: int = 0,
     ) -> "CompactBatch":
         """Compact one padded Batch.  Only valid for hash-mode batches
-        (binary vals, 0/1 labels/weights) with per-row counts <= 255 —
+        (binary vals but for the ``numeric_fields`` first fields' entries,
+        0/1 labels/weights) with per-row counts <= 255 —
         everything the loaders produce; callers with heterogeneous
         traffic keep ``check=True`` (the serving engine opts out of
         this wire entirely).  ``strict_layout`` additionally enforces
         the packed-v2 byte-exact contract (see _validate)."""
         if check:
-            _validate(batch, table_size, hot_size, strict_layout)
+            _validate(batch, table_size, hot_size, strict_layout, numeric_fields)
         b, kc = batch.keys.shape
         kh = batch.hot_keys.shape[1]
         cm = batch.mask > 0
@@ -355,6 +373,7 @@ class CompactBatch:
             wb=_pack_bits(batch.weights),
             cs=_flat_plane(cslots, cap_c, sdtype),
             hs=_flat_plane(hslots, cap_h, sdtype),
+            nv=numeric_plane(batch, numeric_fields) if numeric_fields else None,
         )
 
     # -- expansion (exact inverse for loader-produced batches) -------------
@@ -387,16 +406,18 @@ class CompactBatch:
         hc = self.hc.astype(np.int64)
         cm = (np.arange(kc)[None, :] < cc[:, None]).astype(np.float32)
         hm = (np.arange(kh)[None, :] < hc[:, None]).astype(np.float32)
+        slots = unflatten(self.cs[: self.n_cold], cc, kc, np.int32)
+        hot_slots = unflatten(self.hs[: self.n_hot], hc, kh, np.int32)
         return Batch(
             keys=unflatten(keys_flat, cc, kc, np.int32),
-            slots=unflatten(self.cs[: self.n_cold], cc, kc, np.int32),
-            vals=cm.copy(),
+            slots=slots,
+            vals=values_from_plane(slots, cm, self.nv),
             mask=cm,
             labels=self.labels,
             weights=self.weights,
             hot_keys=unflatten(hot_flat, hc, kh, np.int32),
-            hot_slots=unflatten(self.hs[: self.n_hot], hc, kh, np.int32),
-            hot_vals=hm.copy(),
+            hot_slots=hot_slots,
+            hot_vals=values_from_plane(hot_slots, hm, self.nv),
             hot_mask=hm,
         )
 
@@ -441,7 +462,9 @@ class CompactBatch:
         """The numpy planes that cross the link, keyed by the cw_*
         names parallel/step.py::expand_dict_wire decodes.  Slots ship
         (clamped to the u8 ignored-range convention of
-        compact_wire_np) only when the model reads them."""
+        compact_wire_np) only when the model reads them; the values
+        plane ``cw_nv`` only where the batch has one, and then the slots
+        ship with it: the values are rebuilt from the field ids."""
         out = {
             "cw_cu": self.cu,
             "cw_cun": np.asarray([self.n_dict], np.int32),
@@ -457,7 +480,9 @@ class CompactBatch:
                 "cw_h8": self.h8, "cw_hx": self.hx, "cw_hxh": self.hxh,
                 "cw_hf": self.hf, "cw_hc": self.hc,
             })
-        if ship_slots:
+        if self.nv is not None:
+            out["cw_nv"] = self.nv
+        if ship_slots or self.nv is not None:
             out["cw_cs"] = _clamp_slots_u8(self.cs)
             if self.hot_nnz_cap:
                 out["cw_hs"] = _clamp_slots_u8(self.hs)
@@ -482,9 +507,12 @@ def _validate(
     table_size: int,
     hot_size: int,
     strict_layout: bool = False,
+    numeric_fields: int = 0,
 ) -> None:
     """Compaction invariants — the dict wire's eligibility contract:
-    binary features, 0/1 labels/weights, in-range keys, rows no wider
+    binary features (outside the ``numeric_fields`` first fields, whose
+    values the ``nv`` plane holds: io/batch.py::values_fit_plane), 0/1
+    labels/weights, in-range keys, rows no wider
     than the u8 count planes.  ``strict_layout`` additionally requires
     left-compacted rows (no interior mask holes): that is the packed-v2
     BYTE-EXACT round-trip contract (io/packed.py), loader batches
@@ -492,15 +520,12 @@ def _validate(
     semantically lossless — entries re-compact leftward with their
     (key, slot, val) triplets intact, and every model reduces over the
     feature axis permutation-invariantly."""
-    if not (
-        np.array_equal(batch.vals * batch.mask, batch.mask)
-        and np.array_equal(
-            batch.hot_vals * batch.hot_mask, batch.hot_mask
-        )
-    ):
+    if not values_fit_plane(batch, numeric_fields):
         raise ValueError(
             "compact_batch requires binary features (val 1 wherever "
-            "mask 1); use wire_dedup='off' for value-carrying batches"
+            "mask 1) outside the numeric fields, and one value a row "
+            "and numeric field; use wire_dedup='off' for other "
+            "value-carrying batches"
         )
     for arr in (batch.labels, batch.weights):
         if not np.isin(arr, (0.0, 1.0)).all():
@@ -561,6 +586,7 @@ def plane_specs(
     dict_cap: int = DICT_CAP,
     granule_div: int = GRANULE_DIV,
     granule_min: int = GRANULE_MIN,
+    numeric_fields: int = 0,
 ) -> list[tuple[str, tuple, np.dtype]]:
     """(field, shape, dtype) for every CompactBatch plane, in the
     packed-cache v2 record order (io/packed.py).  Deterministic from
@@ -608,6 +634,8 @@ def plane_specs(
     if hot_nnz_cap:
         cap_h = cap(n_hot, b * hot_nnz_cap)
         specs += [("hs", (cap_h,), sdtype)]
+    if numeric_fields:  # last, and absent without numeric fields
+        specs += [("nv", (b, numeric_fields), np.dtype(np.float32))]
     return specs
 
 
@@ -645,6 +673,7 @@ def from_planes(
         lb=planes["lb"], wb=planes["wb"],
         cs=planes["cs"],
         hs=planes.get("hs", np.zeros(0, _SLOT_DTYPES[counts["slots_code"]])),
+        nv=planes.get("nv"),
     )
 
 
@@ -655,9 +684,11 @@ def compact_batch(
     dict_cap: int = DICT_CAP,
     check: bool = True,
     strict_layout: bool = False,
+    numeric_fields: int = 0,
 ) -> CompactBatch:
     """Functional alias for CompactBatch.from_batch (the name the
     native kernel, docs, and bench refer to)."""
     return CompactBatch.from_batch(
-        batch, table_size, hot_size, dict_cap, check, strict_layout
+        batch, table_size, hot_size, dict_cap, check, strict_layout,
+        numeric_fields,
     )

@@ -11,6 +11,11 @@ Behavioral spec is the reference's only production loader,
 * ``fgid`` parses as an integer field/group id;
 * in hash mode the ``fid`` token is hashed **as a string** and the
   value field is discarded — features are implicitly binary (:151);
+  with ``numeric_fields`` > 0 (Config.numeric_fields; not the
+  reference's) a token whose ``fgid`` lies in ``[0, numeric_fields)``
+  keeps its value, parsed and range-checked as in numeric mode: libffm's
+  ``field:index:value`` for a real-valued feature.  Every other token
+  stays binary, and ``numeric_fields`` = 0 is the reference's loader;
 * in numeric mode (reference loaders at :11-57) ``fid`` parses as an
   integer and ``val`` as a float and both are kept.
 
@@ -64,6 +69,7 @@ def parse_block(
     table_size: int,
     hash_mode: bool = True,
     hash_seed: int = 0,
+    numeric_fields: int = 0,
 ) -> ParsedBlock:
     """Parse one block of libffm lines into a CSR ParsedBlock.
 
@@ -102,8 +108,18 @@ def parse_block(
             if not -(2**31) <= fgid < 2**31:
                 continue  # slot arrays are int32; reject, never wrap
             if hash_mode:
+                val = 1.0  # value field discarded: binary features
+                if 0 <= fgid < numeric_fields:
+                    # a numeric field's token keeps its value, under
+                    # numeric mode's finite-in-float32 rule (below)
+                    try:
+                        val = float(pieces[2])
+                    except ValueError:
+                        continue
+                    if not abs(val) < 3.4028235677973366e38:
+                        continue
                 tokens.append(pieces[1])
-                vals.append(1.0)  # value field discarded: binary features
+                vals.append(val)
             else:
                 try:
                     fid = int(pieces[1])
@@ -146,14 +162,17 @@ def parse_block(
 
 
 def parse_file(
-    path: str, table_size: int, hash_mode: bool = True, hash_seed: int = 0
+    path: str, table_size: int, hash_mode: bool = True, hash_seed: int = 0,
+    numeric_fields: int = 0,
 ) -> ParsedBlock:
     """Parse an entire file at once (reference ``load_all_*`` loaders,
     load_data_from_disk.cc:11-33,59-79)."""
     # whole-file test/tool helper — production streaming goes through
     # ShardLoader, which carries the loader.* sites (xf: ignore[XF018])
     with open(path, "rb") as f:
-        return parse_block(f.read(), table_size, hash_mode, hash_seed)
+        return parse_block(
+            f.read(), table_size, hash_mode, hash_seed, numeric_fields
+        )
 
 
 def open_block_stream(path: str, block_mib: int) -> BlockReader:

@@ -76,17 +76,23 @@ def make_parse_fn(
     hash_mode: bool = True,
     hash_seed: int = 0,
     prefer_native: bool = True,
+    numeric_fields: int = 0,
 ) -> ParseFn:
     """Native C++ parser when built/buildable, else the Python one.
-    Both are behaviorally identical (tests/test_native.py)."""
+    Both are behaviorally identical (tests/test_native.py).
+    ``numeric_fields`` (Config.numeric_fields): in hash mode a token of a
+    field below it keeps its value; 0, the default, is the reference's
+    loader, every value discarded."""
     if prefer_native:
         from xflow_tpu import native
 
         if native.available():
             return lambda data: native.native_parse_block(
-                data, table_size, hash_mode, hash_seed
+                data, table_size, hash_mode, hash_seed, numeric_fields
             )
-    return lambda data: parse_block(data, table_size, hash_mode, hash_seed)
+    return lambda data: parse_block(
+        data, table_size, hash_mode, hash_seed, numeric_fields
+    )
 
 
 class ShardLoader:
@@ -114,6 +120,11 @@ class ShardLoader:
         # remap (Trainer: one hash for all its loaders); None = this
         # loader hashes for itself, once
         remap_digest=None,
+        # Config.numeric_fields: what the default parser keeps values
+        # for and what a packed shard must have been packed with.  None
+        # = whatever the shard holds (a reader that only wants the
+        # batches: packed records carry their own values plane)
+        numeric_fields: int | None = None,
     ):
         self.path = path
         self.batch_size = batch_size
@@ -122,9 +133,10 @@ class ShardLoader:
         self.block_bytes = block_mib << 20
         self.hash_mode = hash_mode
         self.hash_seed = hash_seed
+        self.numeric_fields = numeric_fields
         if parse_fn is None:
             parse_fn = lambda data: parse_block(
-                data, table_size, hash_mode, hash_seed
+                data, table_size, hash_mode, hash_seed, numeric_fields or 0
             )
         self.parse_fn = parse_fn
         self.remap = remap
@@ -392,6 +404,7 @@ class ShardLoader:
                 hash_mode=self.hash_mode,
                 hash_seed=self.hash_seed,
                 remap_sha256=digest,
+                numeric_fields=self.numeric_fields,
             )
         flight = self.obs.flight
         if self.emit_compact and meta.get("version", 1) == 2:

@@ -69,6 +69,10 @@ MAGIC = b"XFPB0001"
 # padded [B, K] arrays — ~7x smaller on disk at the flagship geometry,
 # and the steady-state reader hands the trainer PRE-COMPACTED batches,
 # so epochs 2..N pay zero per-batch compaction or wire-packing work.
+# A shard packed with ``numeric_fields`` > 0 (Config.numeric_fields) says
+# so in its header and every record ends in the values plane ``nv``
+# f32[B, numeric_fields] (io/compact.py); a header without the key, every
+# shard written before the key existed among them, means 0: no plane.
 # Records are variable-size (content-sized planes under plane_cap
 # bucketing), each prefixed by a fixed binary counts header; resume
 # offsets are validated by walking the record chain (a packed shard
@@ -155,8 +159,11 @@ def check_compat(
     hash_mode: bool,
     hash_seed: int,
     remap_sha256: str | None,
+    numeric_fields: int | None = None,
 ) -> None:
-    """Raise unless the cache was built for exactly this batch config.
+    """Raise unless the cache was built for exactly this batch config
+    (``numeric_fields`` None: the reader takes whatever values plane the
+    records hold).
     ``remap_sha256`` is ``remap_digest`` of the loader's remap: the
     caller obtains it (``RemapDigest``: hashed once per holder of the
     remap, seconds at 2^28 rows, then a lookup), so that it can time
@@ -170,6 +177,9 @@ def check_compat(
         "hash_mode": bool(hash_mode),
         "remap_sha256": remap_sha256,
     }
+    if numeric_fields is not None:
+        want["numeric_fields"] = int(numeric_fields)
+        meta = {"numeric_fields": 0, **meta}  # the key is absent at 0
     for key, val in want.items():
         if meta.get(key) != val:
             raise ValueError(
@@ -264,6 +274,7 @@ def write_shard_v2(
                     meta["hot_size"],
                     check=n_batches == 0,
                     strict_layout=True,
+                    numeric_fields=meta.get("numeric_fields", 0),
                 )
                 specs = C.plane_specs(
                     batch_size=cb.batch_size,
@@ -277,6 +288,7 @@ def write_shard_v2(
                     n_dict_occ=cb.n_dict_occ,
                     n_hot=cb.n_hot,
                     n_h8=cb.n_h8,
+                    numeric_fields=meta.get("numeric_fields", 0),
                 )
                 if cb.key_bytes != key_bytes or cb.hx16 != hx16:
                     raise ValueError(
@@ -335,17 +347,20 @@ def _iter_records_v2(f: BinaryIO, meta: dict, start_offset: int):
         hx16 = bool(meta["hx16"])
         gdiv = int(meta["granule_div"])
         gmin = int(meta["granule_min"])
+        numeric = int(meta.get("numeric_fields", 0))
     except (KeyError, TypeError, ValueError) as e:
         raise ValueError(
             f"packed shard header meta malformed: {e!r}"
         ) from e
     if b <= 0 or kc < 0 or kh < 0 or dict_cap < 0 or gdiv <= 0 \
-            or gmin < 0 or key_bytes not in (3, 4):
+            or gmin < 0 or key_bytes not in (3, 4) \
+            or not 0 <= numeric <= 255:
         raise ValueError(
             "packed shard header meta out of range "
             f"(batch_size={b} cold_nnz={kc} hot_nnz={kh} "
             f"dict_cap={dict_cap} key_bytes={key_bytes} "
-            f"granule_div={gdiv} granule_min={gmin})"
+            f"granule_div={gdiv} granule_min={gmin} "
+            f"numeric_fields={numeric})"
         )
     try:
         mm: memoryview | bytes | mmap.mmap = mmap.mmap(
@@ -420,6 +435,7 @@ def _iter_records_v2(f: BinaryIO, meta: dict, start_offset: int):
                 dict_cap=dict_cap,
                 granule_div=gdiv,
                 granule_min=gmin,
+                numeric_fields=numeric,
                 **{k: counts[k] for k in (
                     "n_cold", "n_dict", "n_dict_occ", "n_hot", "n_h8"
                 )},
@@ -636,6 +652,7 @@ def convert_shard(
     parse_fn=None,
     fmt: str = "auto",
     remap_sha256: str | None = None,
+    numeric_fields: int = 0,
 ) -> dict:
     """Pack one shard (text or CSR-binary — ShardLoader sniffs) into
     device-ready batches.  ``fmt``: "v1" = padded-array records, "v2" =
@@ -643,7 +660,10 @@ def convert_shard(
     the dict wire), "auto" = v2 whenever the compaction invariants hold
     (hash mode; u8 per-row counts; hot ids fit the tiered encoding).
     ``remap_sha256``: ``remap_digest(remap)`` where the caller already
-    has it (one hash for many shards); None = hashed here."""
+    has it (one hash for many shards); None = hashed here.
+    ``numeric_fields`` (Config.numeric_fields): the fields whose values
+    the shard keeps; a ``parse_fn`` of the caller's has to keep them too
+    (``make_parse_fn(..., numeric_fields=)``)."""
     from xflow_tpu.io.loader import ShardLoader
 
     loader = ShardLoader(
@@ -658,6 +678,7 @@ def convert_shard(
         remap=remap,
         hot_size=hot_size,
         hot_nnz=hot_nnz,
+        numeric_fields=numeric_fields,
     )
     loader.block_bytes = max(1, int(block_mib * (1 << 20)))
     meta = {
@@ -672,6 +693,8 @@ def convert_shard(
             remap_digest(remap) if remap_sha256 is None else remap_sha256
         ),
     }
+    if numeric_fields:  # absent at 0: such a header is what it always was
+        meta["numeric_fields"] = int(numeric_fields)
     if fmt not in ("auto", "v1", "v2"):
         raise ValueError(f"unknown packed format {fmt!r}")
     v2_ok = (
@@ -711,6 +734,11 @@ def main(argv=None) -> int:
     p.add_argument("--hot-nnz", type=int, default=0)
     p.add_argument("--remap", help=".npy hot remap (trainer's remap.npy)")
     p.add_argument("--no-hash", action="store_true")
+    p.add_argument(
+        "--numeric-fields", type=int, default=0,
+        help="fields [0, N) keep their values under hashing "
+        "(Config.numeric_fields)",
+    )
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--block-mib", type=float, default=8)
     p.add_argument(
@@ -739,6 +767,7 @@ def main(argv=None) -> int:
             remap=remap,
             fmt=a.format,
             remap_sha256=digest,
+            numeric_fields=a.numeric_fields,
         )
         print(
             f"{src} -> {dst}: {meta['examples']} examples in "

@@ -191,6 +191,30 @@ register_model(ModelFamily(
 ))
 
 
+def _build_dlrm(cfg) -> Model:
+    # imported where it is built: a process that trains or serves another
+    # family never loads models/dlrm.py
+    from xflow_tpu.config import mlp_widths
+    from xflow_tpu.models.dlrm import DLRMModel
+
+    return DLRMModel(
+        emb_dim=cfg.emb_dim,
+        numeric_fields=cfg.numeric_fields,
+        mlp_bottom=mlp_widths(cfg.mlp_bottom, "mlp_bottom"),
+        mlp_top=mlp_widths(cfg.mlp_top, "mlp_top"),
+        max_fields=cfg.max_fields,
+        v_init_scale=cfg.v_init_scale,
+    )
+
+
+register_model(ModelFamily(
+    "dlrm", _build_dlrm,
+    "DLRM ranker: a ReLU stack over the real-valued fields, embeddings of "
+    "the categorical ones, the dot of every pair of those vectors, a ReLU "
+    "stack over the dots (Config.numeric_fields, mlp_bottom, mlp_top)",
+))
+
+
 __all__ = [
     "AutodiffModel",
     "Model",

@@ -36,6 +36,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from xflow_tpu.models.base import BatchArrays
 
@@ -154,22 +155,25 @@ def dense_dot(a: jax.Array, w: jax.Array) -> jax.Array:
 
 
 def mlp_stack_init(
-    rng: jax.Array, in_dim: int, hidden: int, layers: int = 1,
-    prefix: str = "",
+    rng: jax.Array, in_dim: int, hidden: int | tuple[int, ...],
+    layers: int = 1, prefix: str = "",
 ) -> dict[str, jax.Array]:
-    """He-init stack of ``layers`` ReLU layers of ``hidden``: in_dim ->
-    hidden -> ... -> hidden, keys ``w1, b1 ... wn, bn``.  Layer 1 draws
+    """He-init stack of ReLU layers, keys ``w1, b1 ... wn, bn``:
+    ``layers`` of ``hidden`` (in_dim -> hidden -> ... -> hidden), or, given
+    a tuple, one of each of its widths (DLRM's 512-256-128).  Layer 1 draws
     from ``rng`` itself (the one-layer stack is the draw the families
     made before there was a stack), layer l > 1 from
     ``fold_in(rng, l)``."""
+    widths = (hidden,) * layers if isinstance(hidden, int) else tuple(hidden)
     dense = {}
-    for layer in range(1, layers + 1):
-        fan_in = in_dim if layer == 1 else hidden
+    for layer, (fan_in, width) in enumerate(
+        zip((in_dim,) + widths, widths), start=1
+    ):
         key = rng if layer == 1 else jax.random.fold_in(rng, layer)
         dense[f"{prefix}w{layer}"] = jax.random.normal(
-            key, (fan_in, hidden), jnp.float32
+            key, (fan_in, width), jnp.float32
         ) * jnp.sqrt(2.0 / fan_in)
-        dense[f"{prefix}b{layer}"] = jnp.zeros((hidden,), jnp.float32)
+        dense[f"{prefix}b{layer}"] = jnp.zeros((width,), jnp.float32)
     return dense
 
 
@@ -177,9 +181,11 @@ def mlp_stack(
     dense: dict, h: jax.Array, layers: int = 1, prefix: str = ""
 ) -> jax.Array:
     """``layers`` ReLU layers ``h <- ReLU(h w_l + b_l)`` -> [B, hidden]:
-    the ONE hidden stack of the dense half.  ``mlp_head`` and
-    ``mlp_tower`` are its one-layer case under a linear output; DCN's
-    deep half is ``Config.deep_layers`` of it."""
+    the ONE hidden stack of the dense half, of whatever widths the
+    arrays have.  ``mlp_head`` and ``mlp_tower`` are its one-layer case
+    under a linear output; DCN's deep half is ``Config.deep_layers`` of
+    it, DLRM's two stacks one layer a width of ``Config.mlp_bottom`` /
+    ``mlp_top``."""
     for layer in range(1, layers + 1):
         h = jax.nn.relu(
             dense_dot(h, dense[f"{prefix}w{layer}"])
@@ -224,6 +230,49 @@ def mlp_tower(dense: dict, h: jax.Array, prefix: str = "") -> jax.Array:
     """2-layer ReLU vector tower -> [B, out_dim]."""
     h = mlp_stack(dense, h, prefix=prefix)
     return dense_dot(h, dense[f"{prefix}w2"]) + dense[f"{prefix}b2"]
+
+
+# The device scope of DLRM's pairwise dots (docs/OBSERVABILITY.md): a
+# sibling of xf.dense inside xf.forward_backward.
+INTERACT_SCOPE = "xf.interact"
+
+
+def vector_pairs(vectors: int) -> int:
+    """``n (n - 1) / 2``: the pairs ``i > j`` of ``n`` vectors."""
+    return vectors * (vectors - 1) // 2
+
+
+@jax.named_scope(INTERACT_SCOPE)
+def pairwise_dots(t: jax.Array) -> jax.Array:
+    """DLRM's dot interaction over an example's vectors ``t [B, n, d]``
+    (the bottom stack's output and the fields' embeddings) -> ``[B, n (n -
+    1) / 2]``: the dot product of every pair ``i > j``, no vector with
+    itself, pair (i, j) in column ``i (i - 1) / 2 + j``:
+
+        Z = T T^T ;   out = [Z_ij for i > j]
+
+    (Naumov et al., arXiv:1906.00091, "dot" interaction without
+    self-interaction).  A product of two ACTIVATIONS of one example, as
+    AutoInt's scores: nothing of it is a parameter, and its gradient
+    reaches both stacks and the table.  One batched product on the MXU,
+    float32 on every backend (Precision.HIGHEST: neither operand is exact
+    in bfloat16), then the pairs picked out of the flat ``[B, n n]`` by a
+    constant one-hot ``[n n, P]`` product, at HIGHEST an exact pick (as
+    ``field_pick``'s).  What chose the form (scripts/probe_interact.py, at
+    DLRM's Criteo-Terabyte sizes on a v5e, ms for the dense half around
+    the block, PR 58): this one 35.0; the rows' lower parts cut out of
+    ``[B, n, n]`` and laid side by side 39.6; one gather with P constant
+    indices 36.7; the examples on the lanes and ``_lane_pair``'s kernel
+    34.8, which in the cell's whole step is 1.4 ms SLOWER (104.03 against
+    102.63 ms: its relayouts land in the program around it)."""
+    n = t.shape[1]
+    z = jnp.einsum("bid,bjd->bij", t, t, precision=jax.lax.Precision.HIGHEST)
+    i, j = np.tril_indices(n, -1)
+    pick = np.zeros((n * n, len(i)), np.float32)
+    pick[i * n + j, np.arange(len(i))] = 1.0
+    return jnp.matmul(
+        z.reshape(len(t), n * n), pick, precision=jax.lax.Precision.HIGHEST
+    )
 
 
 def dot_interaction(u: jax.Array, v: jax.Array) -> jax.Array:

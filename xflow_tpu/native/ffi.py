@@ -62,13 +62,14 @@ def _bind(lib: ctypes.CDLL) -> None:
         ctypes.c_int64,
         ctypes.c_uint64,
     ]
-    lib.xf_parse_block.restype = ctypes.c_int64
-    lib.xf_parse_block.argtypes = [
+    lib.xf_parse_block_values.restype = ctypes.c_int64
+    lib.xf_parse_block_values.argtypes = [
         ctypes.c_char_p,  # data
         ctypes.c_int64,  # len
         ctypes.c_int64,  # table_size
         ctypes.c_int,  # hash_mode
         ctypes.c_uint64,  # seed
+        ctypes.c_int64,  # numeric_fields
         ctypes.POINTER(ctypes.c_float),  # labels
         ctypes.c_int64,  # max_rows
         ctypes.POINTER(ctypes.c_int64),  # row_ptr
@@ -165,6 +166,7 @@ def native_parse_block(
     table_size: int,
     hash_mode: bool = True,
     hash_seed: int = 0,
+    numeric_fields: int = 0,
 ) -> ParsedBlock:
     """Drop-in replacement for io.libffm.parse_block (parity enforced by
     tests/test_native.py)."""
@@ -190,12 +192,13 @@ def native_parse_block(
     slots = np.empty(max_nnz, dtype=np.int32)
     vals = np.empty(max_nnz, dtype=np.float32)
     out_nnz = np.zeros(1, dtype=np.int64)
-    n_rows = lib.xf_parse_block(
+    n_rows = lib.xf_parse_block_values(
         data,
         len(data),
         table_size,
         1 if hash_mode else 0,
         hash_seed,
+        numeric_fields,
         _ptr(labels, ctypes.c_float),
         max_rows,
         _ptr(row_ptr, ctypes.c_int64),

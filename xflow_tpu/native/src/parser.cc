@@ -16,8 +16,11 @@
 //     skipped; binarized y > 1e-7 -> 1
 //   * feature token must be fgid:fid:val with integer fgid; in hash
 //     mode fid is hashed as a string (MurmurHash64A, seed given) and
-//     val is DISCARDED (features binary, vals=1); in numeric mode fid
-//     must parse as integer and val as float, both kept
+//     val is DISCARDED (features binary, vals=1), except that a token
+//     whose fgid lies in [0, numeric_fields) keeps its value, parsed and
+//     range-checked as in numeric mode (xf_parse_block_values; 0 fields:
+//     the reference's loader); in numeric mode fid must parse as integer
+//     and val as float, both kept
 //   * malformed tokens are skipped, not fatal
 //   * keys reduced modulo table_size; table_size == 0 keeps FULL keys
 //     (the 64-bit hash as two's-complement int64 / the raw fid) for the
@@ -153,11 +156,15 @@ uint64_t xf_murmur64(const char* data, int64_t len, uint64_t seed) {
 // max_rows / max_nnz; returns the number of parsed samples, or -1 if a
 // capacity would overflow (caller should re-bound and retry).
 // row_ptr has max_rows+1 slots; *out_nnz receives the total nnz.
-int64_t xf_parse_block(const char* data, int64_t len, int64_t table_size,
-                       int hash_mode, uint64_t seed, float* labels,
-                       int64_t max_rows, int64_t* row_ptr, int64_t* keys,
-                       int32_t* slots, float* vals, int64_t max_nnz,
-                       int64_t* out_nnz) {
+// numeric_fields: in hash mode a token of a field in [0, numeric_fields)
+// keeps its value (a malformed or non-finite one skips the token).
+int64_t xf_parse_block_values(const char* data, int64_t len,
+                              int64_t table_size, int hash_mode,
+                              uint64_t seed, int64_t numeric_fields,
+                              float* labels, int64_t max_rows,
+                              int64_t* row_ptr, int64_t* keys, int32_t* slots,
+                              float* vals, int64_t max_nnz,
+                              int64_t* out_nnz) {
   int64_t n_rows = 0;
   int64_t nnz = 0;
   row_ptr[0] = 0;
@@ -199,13 +206,20 @@ int64_t xf_parse_block(const char* data, int64_t len, int64_t table_size,
           int32_t fgid;
           if (parse_fgid(q, c1, &fgid)) {
             if (hash_mode) {
+              float val = 1.0f;  // value field discarded: binary features
+              if (fgid >= 0 && fgid < numeric_fields &&
+                  !(parse_float_full(c2 + 1, t_end, &val) &&
+                    std::isfinite(val))) {
+                q = t_end;
+                continue;  // a numeric field's value must parse: skip token
+              }
               if (nnz == max_nnz) return -1;
               uint64_t h = murmur64a(c1 + 1, c2 - c1 - 1, seed);
               keys[nnz] = static_cast<int64_t>(
                   table_size > 0 ? h % static_cast<uint64_t>(table_size)
                                  : h);
               slots[nnz] = fgid;
-              vals[nnz] = 1.0f;  // value field discarded: binary features
+              vals[nnz] = val;
               ++nnz;
             } else {
               int64_t fid;
@@ -238,6 +252,17 @@ int64_t xf_parse_block(const char* data, int64_t len, int64_t table_size,
   }
   *out_nnz = nnz;
   return n_rows;
+}
+
+// The reference's loader: every hash-mode value discarded.
+int64_t xf_parse_block(const char* data, int64_t len, int64_t table_size,
+                       int hash_mode, uint64_t seed, float* labels,
+                       int64_t max_rows, int64_t* row_ptr, int64_t* keys,
+                       int32_t* slots, float* vals, int64_t max_nnz,
+                       int64_t* out_nnz) {
+  return xf_parse_block_values(data, len, table_size, hash_mode, seed, 0,
+                               labels, max_rows, row_ptr, keys, slots, vals,
+                               max_nnz, out_nnz);
 }
 
 // Packs samples [start, end) of a parsed CSR block into padded

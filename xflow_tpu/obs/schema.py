@@ -489,6 +489,11 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # whose uses_slots is false on the compact or dictionary wire;
         # absent from files older than the counter wire.slots_bytes
         "slots_bytes_per_example": (int, float),
+        # of wire_bytes_per_example, the plane of the numeric fields'
+        # values (Config.numeric_fields: nvals on the compact wire, cw_nv
+        # on the dictionary wire; 4 bytes a field): ABSENT where the
+        # batches shipped none, every configuration without numeric fields
+        "values_bytes_per_example": (int, float),
     },
     "train_epoch": {
         # single-host runs under trainer._transfer_ahead only

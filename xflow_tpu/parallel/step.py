@@ -102,6 +102,11 @@ def scope_of(op_name: str) -> str:
 _SLOT_PLANES = frozenset({
     "slots", "hot_slots", "slots_u8", "hot_slots_u8", "cw_cs", "cw_hs",
 })
+# The plane of the numeric fields' values (Config.numeric_fields), by wire:
+# the compact wire's (compact_wire_np) and the dictionary wire's
+# (io/compact.py::CompactBatch.wire).  The full wire ships every value in
+# its vals planes and has no such plane.
+_VALUE_PLANES = frozenset({"nvals", "cw_nv"})
 # The dictionary wire's planes whose length is a plane_cap capacity (the
 # others count the batch's rows): TrainStep._settle_planes.
 _CAPACITY_PLANES = frozenset({
@@ -242,21 +247,24 @@ def batch_to_arrays(batch: Batch) -> BatchArrays:
     return out
 
 
-def validate_compact_batch(batch: Batch) -> None:
-    """Compact-wire invariants: binary features (val 1 wherever mask 1)
-    and 0/1 labels/weights.  Loader-produced hash-mode batches satisfy
+def validate_compact_batch(batch: Batch, numeric_fields: int = 0) -> None:
+    """Compact-wire invariants: binary features (val 1 wherever mask 1;
+    with ``numeric_fields`` > 0 an entry of a field below it may hold any
+    value, one a row and field: the ``nvals`` plane ships it) and 0/1
+    labels/weights.  Loader-produced hash-mode batches satisfy
     them by construction, so put_batch validates only the FIRST batch
     per TrainStep — full [B,K] scans on every batch would burn the host
     CPU the compact format exists to relieve."""
     import numpy as np
 
-    if not (
-        np.array_equal(batch.vals * batch.mask, batch.mask)
-        and np.array_equal(batch.hot_vals * batch.hot_mask, batch.hot_mask)
-    ):
+    from xflow_tpu.io.batch import values_fit_plane
+
+    if not values_fit_plane(batch, numeric_fields):
         raise ValueError(
             "compact wire requires binary features (val 1 wherever "
-            "mask 1); set wire_mode='full' for value-carrying batches"
+            "mask 1) outside the numeric fields and one value a row and "
+            "numeric field; set wire_mode='full' for other "
+            "value-carrying batches"
         )
     for arr in (batch.labels, batch.weights):
         if not np.isin(arr, (0.0, 1.0)).all():
@@ -267,7 +275,8 @@ def validate_compact_batch(batch: Batch) -> None:
 
 
 def compact_wire_np(
-    batch: Batch, ship_slots: bool = False, hot_u16: bool = False
+    batch: Batch, ship_slots: bool = False, hot_u16: bool = False,
+    numeric_fields: int = 0,
 ) -> dict:
     """The numpy (host) half of the compact wire: sentinel-coded int32
     keys + uint8 labels/weights, plus a uint8 slots plane for models
@@ -286,10 +295,16 @@ def compact_wire_np(
     shared out-of-range semantics: every slot consumer drops fields >=
     max_fields via a one-hot row of zeros (mvm.py:76, ffm.py:11,
     wide_deep.py:73), so with max_fields <= 255 (enforced at TrainStep
-    init) a clamped slot lands in the ignored range either way."""
+    init) a clamped slot lands in the ignored range either way.
+
+    numeric_fields > 0 (Config.numeric_fields): one float32 plane
+    ``nvals [B, numeric_fields]``, the value of the row's entry of each
+    numeric field (io/batch.py::numeric_plane), from which and the field
+    ids _expand_wire rebuilds the values; absent at 0, where the wire is
+    what it always was."""
     import numpy as np
 
-    from xflow_tpu.io.batch import narrow_keys_i32
+    from xflow_tpu.io.batch import narrow_keys_i32, numeric_plane
 
     def sentinel(keys, mask):
         # narrow THROUGH the audited choke point (XF011): loader-built
@@ -315,6 +330,8 @@ def compact_wire_np(
         "labels_u8": batch.labels.astype(np.uint8),
         "weights_u8": batch.weights.astype(np.uint8),
     }
+    if numeric_fields:
+        out["nvals"] = numeric_plane(batch, numeric_fields)
     if ship_slots:
         out["slots_u8"] = slots_u8(batch.slots)
     if batch.hot_nnz:
@@ -329,10 +346,26 @@ def compact_wire_np(
     return out
 
 
-def _checked(batch: Batch, check: bool) -> Batch:
+def _checked(batch: Batch, check: bool, numeric_fields: int = 0) -> Batch:
     if check:
-        validate_compact_batch(batch)
+        validate_compact_batch(batch, numeric_fields)
     return batch
+
+
+def values_from_numeric(
+    plane: jax.Array, slots: jax.Array, mask: jax.Array
+) -> jax.Array:
+    """The ``[B, K]`` values of one section of a batch whose wire shipped
+    a values plane ``[B, numeric_fields]`` (``nvals`` / ``cw_nv``): the
+    plane's number for a real entry of a numeric field, 1 for every other
+    real entry, 0 for padding; io/batch.py::values_from_plane on the
+    device.  One select a numeric field over the ``[B, K]`` plane, each
+    example's number broadcast along its entries: exact, and no gather
+    with an index an entry (the TPU prices a gather per index)."""
+    vals = mask
+    for field in range(plane.shape[1]):
+        vals = jnp.where(slots == field, plane[:, field:field + 1] * mask, vals)
+    return vals
 
 
 def batch_to_compact(
@@ -340,6 +373,7 @@ def batch_to_compact(
     check: bool = True,
     ship_slots: bool = False,
     hot_u16: bool = False,
+    numeric_fields: int = 0,
 ) -> BatchArrays:
     """Compact wire (Config.wire_mode): sentinel-coded keys + uint8
     labels/weights — ~16x fewer bytes/entry than the full format for
@@ -349,10 +383,12 @@ def batch_to_compact(
     reconstructs vals/mask (and zero slots when none shipped) on
     device."""
     if check:
-        validate_compact_batch(batch)
+        validate_compact_batch(batch, numeric_fields)
     return {
         k: jnp.asarray(v)
-        for k, v in compact_wire_np(batch, ship_slots, hot_u16).items()
+        for k, v in compact_wire_np(
+            batch, ship_slots, hot_u16, numeric_fields
+        ).items()
     }
 
 
@@ -467,7 +503,12 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
 
     Every plane capacity is static (plane_cap bucketing), so one
     steady batch geometry is one compiled program; the per-batch
-    real counts arrive as the cc/hc count planes."""
+    real counts arrive as the cc/hc count planes.
+
+    A batch with numeric fields (Config.numeric_fields) also ships
+    ``cw_nv [B, numeric_fields]``, their values: ``vals`` / ``hot_vals``
+    are then rebuilt from it and the field ids (values_from_numeric);
+    without the plane they are the masks, as they always were."""
     kc = cfg.max_nnz
     b = w["cw_cc"].shape[0]
     take = functools.partial(monotone_take, lane_select=lane_select)
@@ -566,6 +607,12 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
         )
         out["hot_vals"] = hmask
         out["hot_mask"] = hmask
+    if "cw_nv" in w:  # a batch with numeric fields: their values
+        out["vals"] = values_from_numeric(w["cw_nv"], out["slots"], cmask)
+        if "cw_hc" in w:
+            out["hot_vals"] = values_from_numeric(
+                w["cw_nv"], out["hot_slots"], out["hot_mask"]
+            )
     return out
 
 
@@ -830,7 +877,12 @@ class TrainStep:
         # vals (hash mode).  Slot-reading models additionally need
         # max_fields <= 255 so the u8 slots plane's clamp stays inside
         # the models' ignored range (compact_wire_np docstring).
-        self._ship_slots = bool(getattr(model, "uses_slots", True))
+        # A batch with numeric fields ships its field ids whatever the
+        # model reads: the values are rebuilt from them
+        # (values_from_numeric).
+        self._ship_slots = bool(
+            getattr(model, "uses_slots", True) or cfg.numeric_fields
+        )
         # hot ids fit u16 with the 0xFFFF sentinel only below 2^15
         # rows (compact_wire_np docstring)
         self._hot_u16 = bool(
@@ -1086,12 +1138,16 @@ class TrainStep:
         self, nbytes: int, examples: int, cb=None, cold_slots: int = 0,
         slots_bytes: int = 0, hot_slots: int = 0,
         plane_caps: tuple[int, int] | None = None,
+        values_bytes: int = 0,
     ) -> None:
         """Wire accounting counters behind the trainer's per-epoch
         ``wire`` metrics row (obs/schema.py): bytes that crossed the
         link, examples they carried, how many of the bytes were the
         planes of field ids (``slots_bytes``: _SLOT_PLANES, shipped for
-        a model that reads them), and — dict wire — the cold
+        a model that reads them), how many the plane of the numeric
+        fields' values (``values_bytes``: _VALUE_PLANES; booked only where
+        one shipped, so the row of a batch without numeric fields is what
+        it was), and — dict wire — the cold
         occurrence/unique-touch compaction the host performed.  Beside
         them, from shapes, what the batch asks of the [T, D] tables:
         its ``cold_slots`` padded cold slots (B * max_nnz) and the
@@ -1155,6 +1211,8 @@ class TrainStep:
         self.obs.counter("wire.examples", examples)
         self.obs.counter("wire.batches")
         self.obs.counter("wire.slots_bytes", slots_bytes)
+        if values_bytes:
+            self.obs.counter("wire.values_bytes", values_bytes)
         if cb is not None:
             self.obs.counter("wire.cold_occ", cb.n_cold)
             self.obs.counter("wire.cold_touched", cb.cold_touched)
@@ -1269,6 +1327,7 @@ class TrainStep:
             # the put_batch latch: racing streams at worst BOTH validate
             # their first batch — extra checking (xf: ignore[XF008])
             check=not self._compact_validated,
+            numeric_fields=self.cfg.numeric_fields,
         )
         self._compact_validated = True  # same latch; xf: ignore[XF008]
         return cb
@@ -1292,14 +1351,15 @@ class TrainStep:
         if self.dict_wire and self._dict_geometry_ok(batch):
             cb = CompactBatch.from_batch(
                 batch, self.cfg.table_size, self.cfg.hot_size,
-                check=check,
+                check=check, numeric_fields=self.cfg.numeric_fields,
             )
             return self._settle_planes(cb.wire(self._ship_slots)), cb
         if self.compact_wire:
             return compact_wire_np(
-                _checked(batch, check),
+                _checked(batch, check, self.cfg.numeric_fields),
                 ship_slots=self._ship_slots,
                 hot_u16=self._hot_u16,
+                numeric_fields=self.cfg.numeric_fields,
             ), None
         wire = {
             "keys": batch.keys, "slots": batch.slots,
@@ -1377,6 +1437,9 @@ class TrainStep:
             hot_slots=batch.batch_size * batch.hot_nnz,
             slots_bytes=sum(
                 int(v.nbytes) for k, v in wire.items() if k in _SLOT_PLANES
+            ),
+            values_bytes=sum(
+                int(v.nbytes) for k, v in wire.items() if k in _VALUE_PLANES
             ),
         )
         arrays = {k: jnp.asarray(v) for k, v in wire.items()}
@@ -1521,7 +1584,9 @@ class TrainStep:
     @jax.named_scope("xf.wire_decode")
     def _expand_wire(self, batch: BatchArrays) -> BatchArrays:
         """Inverse of batch_to_compact, inside the jitted step: padding
-        is key == -1; real entries have val = mask = 1 (hash mode);
+        is key == -1; real entries have val = mask = 1 (hash mode; with a
+        values plane ``nvals`` the numeric fields' entries take its
+        numbers);
         slots widen from the u8 plane when the model reads them, else
         reconstruct as zeros.  Dictionary-wire batches (cw_* planes,
         Config.wire_dedup) decode through expand_dict_wire instead."""
@@ -1563,6 +1628,12 @@ class TrainStep:
             )
             out["hot_vals"] = hmask
             out["hot_mask"] = hmask
+        if "nvals" in batch:  # numeric fields: their values (compact_wire_np)
+            out["vals"] = values_from_numeric(batch["nvals"], out["slots"], mask)
+            if hot is not None:
+                out["hot_vals"] = values_from_numeric(
+                    batch["nvals"], out["hot_slots"], hmask
+                )
         return out
 
     def _gather_model_rows(

@@ -45,6 +45,7 @@ from xflow_tpu.obs import NULL_OBS, profiler_span, startup
 from xflow_tpu.parallel.mesh import make_mesh, replicated, table_sharding
 from xflow_tpu.parallel.step import (
     _SLOT_PLANES,
+    _VALUE_PLANES,
     TrainStep,
     pack_wire_np,
     unpack_wire,
@@ -506,6 +507,7 @@ class PredictEngine:
                 cfg.hash_mode,
                 cfg.seed,
                 prefer_native=cfg.native_parser,
+                numeric_fields=cfg.numeric_fields,
             )
         data = "".join(
             line if line.endswith("\n") else line + "\n" for line in lines
@@ -624,6 +626,10 @@ class PredictEngine:
                 slots_bytes=sum(
                     int(v.nbytes) for k, v in wire.items()
                     if k in _SLOT_PLANES
+                ),
+                values_bytes=sum(
+                    int(v.nbytes) for k, v in wire.items()
+                    if k in _VALUE_PLANES
                 ),
             )
             host, layout = pack_wire_np(wire)

@@ -88,6 +88,21 @@ def build_parser() -> argparse.ArgumentParser:
         help="fibinet: the SENET reduction ratio (gates squeezed to "
         "max_fields // r and back)",
     )
+    p.add_argument(
+        "--numeric-fields", type=int, dest="numeric_fields",
+        help="fields [0, N) are real-valued: their tokens keep the value "
+        "of field:index:value under hashing (dlrm reads them)",
+    )
+    p.add_argument(
+        "--mlp-bottom", dest="mlp_bottom",
+        help="dlrm: widths of the stack over the numeric values, "
+        "dash-separated, ending in --emb-dim (512-256-128)",
+    )
+    p.add_argument(
+        "--mlp-top", dest="mlp_top",
+        help="dlrm: widths of the stack over the interaction, "
+        "dash-separated (1024-1024-512-256); a linear output follows",
+    )
     p.add_argument("--max-nnz", type=int, dest="max_nnz")
     p.add_argument("--max-fields", type=int, dest="max_fields")
     p.add_argument("--block-mib", type=int, dest="block_mib")

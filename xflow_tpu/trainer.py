@@ -526,6 +526,7 @@ class Trainer:
             cfg.hash_mode,
             cfg.seed,
             prefer_native=cfg.native_parser,
+            numeric_fields=cfg.numeric_fields,
         )
 
     def _loader(self, path: str) -> ShardLoader:
@@ -542,6 +543,7 @@ class Trainer:
             remap=self.remap,
             hot_size=cfg.hot_size,
             hot_nnz=cfg.hot_nnz,
+            numeric_fields=cfg.numeric_fields,
             obs=self.obs,
             # v2 packed shards skip expansion AND re-compaction when
             # the step consumes the dict wire (io/compact.py)
@@ -1113,6 +1115,14 @@ class Trainer:
                     2,
                 ),
             }
+            if "wire.values_bytes" in snap.counters:
+                # and the plane of the numeric fields' values
+                # (Config.numeric_fields): absent where none shipped
+                stats["_wire"]["values_bytes_per_example"] = round(
+                    snap.counters["wire.values_bytes"]
+                    / max(snap.counters.get("wire.examples", 0), 1),
+                    2,
+                )
             batches = max(snap.counters.get("wire.batches", 0), 1)
             if "wire.cold_slots" in snap.counters:
                 # what the batches asked of the [T, D] tables, a batch,
