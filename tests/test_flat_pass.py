@@ -1,8 +1,11 @@
 """The whole-array optimizer pass over a table of ONE column runs on the
 table's flat view (parallel/step.py::_optimizer_pass; PERF.md section 6,
 PR 37): a view and two barriers, so the state it leaves is
-``optimizer.update_rows`` bit for bit; a wider table keeps its shape; and
-the epoch's ``wire`` row says how many elements went flat."""
+``optimizer.update_rows`` bit for bit; the dense update's pass over a
+wide table that the chip keeps rows-minor is held to that layout (PR 59:
+seven layout constraints, the same state bit for bit); every other table
+keeps its shape; and the epoch's ``wire`` row says how many elements went
+flat and how many stayed on the resident layout."""
 
 import json
 import types
@@ -19,20 +22,31 @@ from xflow_tpu.optim import make_optimizer
 from xflow_tpu.optim.ftrl import FTRL
 from xflow_tpu.optim.sgd import SGD
 from xflow_tpu.parallel.mesh import make_mesh
-from xflow_tpu.parallel.step import TrainStep
+from xflow_tpu.parallel.step import TrainStep, resident_pass_selects
 
 
-def _pass(optimizer):
+def _pass(optimizer, resident=False):
     holder = types.SimpleNamespace(optimizer=optimizer)
-    return lambda table, g: TrainStep._optimizer_pass(holder, table, g)
+    return lambda table, g: TrainStep._optimizer_pass(
+        holder, table, g, resident=resident
+    )
 
 
-@pytest.mark.parametrize("d", [1, 10])
+# width, whether the caller is the dense update on one device, the arm:
+# FFM's 160 columns stay on the resident layout only there; DLRM's 128
+# fill a lane tile and MVM's 10 go column by column, whoever calls
+@pytest.mark.parametrize("d, resident, arm", [
+    (1, False, "flat"), (1, True, "flat"), (10, False, "plain"),
+    (10, True, "plain"), (128, True, "plain"), (160, False, "plain"),
+    (160, True, "resident"),
+])
 @pytest.mark.parametrize("optimizer", [FTRL(), SGD()], ids=["ftrl", "sgd"])
-def test_the_pass_is_update_rows_bit_for_bit(optimizer, d):
+def test_the_pass_is_update_rows_bit_for_bit(optimizer, d, resident, arm):
     """Three chained passes against update_rows on the same arrays: rows
     no gradient has ever reached (n' = 0 keeps its initial value), rows
-    touched once and then handed a zero, rows touched every time."""
+    touched once and then handed a zero, rows touched every time, and,
+    under FTRL, entries whose |z'| <= lambda1 (a gradient under 5e-5:
+    the new weight is exactly 0)."""
     rng = np.random.default_rng(11)
     t = 4096 + 24  # no multiple of a 1024-element tile
     param = jnp.asarray(rng.normal(0, 1e-2, (t, d)), jnp.float32)
@@ -45,7 +59,8 @@ def test_the_pass_is_update_rows_bit_for_bit(optimizer, d):
             g[t // 2: 3 * t // 4] = 0.0  # touched by the first pass alone
         grads.append(jnp.asarray(g, jnp.float32))
     want, got = table, table
-    direct, flat = jax.jit(optimizer.update_rows), jax.jit(_pass(optimizer))
+    shipped = _pass(optimizer, resident)
+    direct, flat = jax.jit(optimizer.update_rows), jax.jit(shipped)
     for g in grads:
         want, got = direct(want, g), flat(got, g)
     assert set(got) == set(want)
@@ -54,9 +69,19 @@ def test_the_pass_is_update_rows_bit_for_bit(optimizer, d):
         np.testing.assert_array_equal(got[name], want[name], err_msg=name)
     np.testing.assert_array_equal(got["param"][: t // 2], param[: t // 2])
     assert not np.array_equal(got["param"][t // 2:], param[t // 2:])
-    # the view is held by a barrier on each side, and only at one column
-    text = str(jax.make_jaxpr(_pass(optimizer))(table, grads[0]))
-    assert text.count("optimization_barrier") == (2 if d == 1 else 0), text
+    if isinstance(optimizer, FTRL):
+        clipped = (np.asarray(got["n"]) > 0) & (np.asarray(got["param"]) == 0)
+        assert clipped[t // 2:].any() and not clipped[: t // 2].any()
+    # the view is held by a barrier on each side, and only at one column;
+    # the resident layout by a constraint on the four operands and the
+    # three results (two under SGD, which keeps no n and z), and only for
+    # the dense update's wide table
+    text = str(jax.make_jaxpr(shipped)(table, grads[0]))
+    assert text.count("optimization_barrier") == (2 if arm == "flat" else 0)
+    assert text.count("layout_constraint") == (
+        2 * len(table) + 1 if arm == "resident" else 0
+    ), text
+    assert (arm == "resident") == (resident and resident_pass_selects(d))
 
 
 @pytest.mark.parametrize("devices", [1, 4])
@@ -71,7 +96,7 @@ def test_three_steps_end_where_update_rows_applied_directly_does(
     got = _trained(model, devices, 5, "seg")
     monkeypatch.setattr(
         TrainStep, "_optimizer_pass",
-        lambda self, table, g: self.optimizer.update_rows(table, g),
+        lambda self, table, g, **_: self.optimizer.update_rows(table, g),
     )
     want = _trained(model, devices, 5, "seg")
     assert set(got) == ({"w"} if model == "lr" else {"w", "v"})
@@ -122,7 +147,9 @@ def test_the_wire_row_carries_the_flat_pass(
 ):
     """``flat_pass_elements_per_step`` of the epoch's ``wire`` row: T for
     LR, for FM on a mesh (all four blocks of w, none of v) and for FFM
-    (w), 0 for MVM, whose one table is ten columns wide."""
+    (w), 0 for MVM, whose one table is ten columns wide.  Beside it
+    ``resident_pass_elements_per_step``: FFM's v (20 fields x 4 = 80
+    columns here), trained through the arm, and 0 for the others."""
     from xflow_tpu.obs.schema import OPTIONAL, validate_rows
     from xflow_tpu.trainer import Trainer
 
@@ -141,3 +168,7 @@ def test_the_wire_row_carries_the_flat_pass(
     assert "flat_pass_elements_per_step" in OPTIONAL["wire"]
     row = next(r for r in rows if r["kind"] == "wire")
     assert row["flat_pass_elements_per_step"] == tables << 14
+    assert row["resident_pass_elements_per_step"] == sum(
+        d for d in widths if resident_pass_selects(d) and devices == 1
+    ) << 14
+    assert bool(row["resident_pass_elements_per_step"]) == (model == "ffm")

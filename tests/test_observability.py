@@ -1430,3 +1430,68 @@ def test_dense_counters_reach_the_wire_row_from_shapes(toy_dataset, tmp_path, mo
 def test_a_family_without_dense_parameters_books_no_dense_counter(packed_run):
     (wire, *_) = [r for r in packed_run if r["kind"] == "wire"]
     assert not [k for k in wire if k.startswith("dense_")]
+
+
+# -- the pass on the resident layout (PR 59) ----------------------------------
+
+_DLRM_128 = dict(
+    emb_dim=128, numeric_fields=3, max_fields=8, mlp_bottom="16-128",
+    mlp_top="32-16",
+)
+
+
+# tables of T = 2^12 rows; by hand: the elements of every table of 64
+# columns or more that (8,128) tiles pad less rows-minor (D rounded up to
+# 8 under D rounded up to 128), where the dense update makes its one pass
+# a step on one device
+@pytest.mark.parametrize("model, overrides, devices, elements", [
+    ("lr", {}, 1, 0),                                     # w [T, 1]
+    ("fm", {}, 1, 0),                                     # v [T, 10]
+    ("mvm", {}, 1, 0),                                    # v [T, 10]
+    ("ffm", {"max_fields": 40, "ffm_v_dim": 4}, 1, 4096 * 160),
+    ("ffm", {"max_fields": 4, "ffm_v_dim": 16}, 1, 4096 * 64),
+    ("ffm", {"max_fields": 32, "ffm_v_dim": 4}, 1, 0),    # 128: a lane tile
+    ("dlrm", _DLRM_128, 1, 0),                            # emb [T, 128]
+    ("ffm", {"max_fields": 40, "ffm_v_dim": 4}, 4, 0),    # a mesh
+    ("ffm", {"max_fields": 40, "ffm_v_dim": 4, "update_mode": "sequential",
+             "microbatch": 4, "sequential_inner": "dense"}, 1, 0),
+    ("ffm", {"max_fields": 40, "ffm_v_dim": 4, "update_mode": "sparse",
+             "hot_size_log2": 0, "hot_nnz": 0}, 1, 0),
+])
+def test_wire_row_counts_the_elements_of_the_resident_pass(
+    model, overrides, devices, elements
+):
+    """``resident_pass_elements`` of TrainStep._book_wire (the ``_wire``
+    row's ``resident_pass_elements_per_step``, optional in the schema),
+    from shapes: the elements of the tables whose dense pass
+    _optimizer_pass holds to the layout the chip keeps the state in
+    (step.py::resident_pass_selects).  FFM's v at libffm's 40 x 4 = 160
+    columns and nothing else the benchmark measures: LR's, FM's and MVM's
+    tables are narrower, DLRM's 128 columns fill a lane tile; and nothing
+    on a mesh or where the update makes no one pass a step."""
+    import types
+
+    from xflow_tpu.models import make_model
+    from xflow_tpu.obs.schema import OPTIONAL
+    from xflow_tpu.optim import make_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh
+    from xflow_tpu.parallel.step import TrainStep
+
+    cfg = Config(**{
+        **dict(
+            model=model, optimizer="ftrl", table_size_log2=12, batch_size=64,
+            max_nnz=6, hot_size_log2=5, hot_nnz=6, num_devices=devices,
+        ),
+        **overrides,
+    })
+    step = TrainStep(
+        make_model(cfg), make_optimizer(cfg), cfg, make_mesh(devices)
+    )
+    booked: dict[str, float] = {}
+    step.obs = types.SimpleNamespace(
+        counter=lambda name, v=1.0: booked.__setitem__(name, v)
+    )
+    b = cfg.batch_size
+    step._book_wire(0, b, cold_slots=b * cfg.max_nnz, hot_slots=b * cfg.hot_nnz)
+    assert booked["wire.resident_pass_elements"] == elements
+    assert "resident_pass_elements_per_step" in OPTIONAL["wire"]

@@ -38,15 +38,18 @@ def test_dlrm_step_multiplies_in_float32_on_todays_routes_and_fits_a_v5e(topo):
     (``ROW_LAYOUT_MIN_COLUMNS``), its cold gradients go back an index a padded
     slot (128 is outside ``DICT_SCATTER_COLUMNS``), the head reads and sums it
     plainly, and the dense update keeps its ``[T, 128]`` gradient buffer and
-    pass (``touched_rows_selects`` stops at 64).  Compiled: the interaction's
+    pass (``touched_rows_selects`` stops at 64), which no layout constraint
+    touches (``resident_pass_selects``, PR 59: 128 columns fill a lane tile,
+    the chip keeps the state columns-minor and the pass reads it as it
+    lies).  Compiled: the interaction's
     instructions carry ``xf.interact`` in ``op_scopes``' reading, the stacks'
     ``xf.dense``; no table-sized copy of the state is made; and the program
     fits with the room the file's ``reduced`` argues from (8.23 GiB of 15.75;
     at 2^23 rows the compiler refuses it: 16.15 G)."""
     from xflow_tpu.ops import hot
     from xflow_tpu.parallel.step import (
-        _HLO_OP_NAME_RE, DICT_SCATTER_COLUMNS, ROW_LAYOUT_MIN_COLUMNS, scope_of,
-        touched_rows_selects,
+        _HLO_OP_NAME_RE, DICT_SCATTER_COLUMNS, ROW_LAYOUT_MIN_COLUMNS,
+        resident_pass_selects, scope_of, touched_rows_selects,
     )
 
     cfg, step, lowered = _lowered_cell_step(topo, "dlrm_ftrl_criteo_tb", DLRM_PLANES)
@@ -61,6 +64,7 @@ def test_dlrm_step_multiplies_in_float32_on_todays_routes_and_fits_a_v5e(topo):
         DLRM_PLANES["cw_cu"][0][0], DLRM_PLANES["cw_ct"][0][0], True
     )
     assert not touched_rows_selects(cfg.table_size, d, 20480, 0)
+    assert not resident_pass_selects(d) and not step._resident_pass_tables
     text = lowered.as_text()
     dots = [line for line in text.splitlines() if "dot_general" in line]
     assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots), dots

@@ -464,6 +464,12 @@ OPTIONAL: dict[str, dict[str, Any]] = {
         # batch with an empty tail (step.py::touched_rows_selects); 0
         # where no table is selected
         "touched_rows_indices_per_step": (int, float),
+        # elements of the tables whose whole-table optimizer pass the
+        # dense update held to the layout the chip keeps the state in: a
+        # table of 64 columns or more that (8,128) tiles pad less
+        # rows-minor, on one device (step.py::resident_pass_selects);
+        # FFM's v, 0 where no table is selected
+        "resident_pass_elements_per_step": (int, float),
         # a family with replicated dense parameters only, from shapes:
         # the bytes of its dense arrays, and 6 B k n operations a step
         # for every [B, k] x [k, n] product with one of them

@@ -26,6 +26,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 from xflow_tpu.config import Config
@@ -622,6 +623,39 @@ def expand_dict_wire(cfg, lane_select, w: BatchArrays) -> BatchArrays:
 ROW_LAYOUT_MIN_COLUMNS = 64
 
 
+def resident_pass_selects(columns: int) -> bool:
+    """Whether the dense update's whole-table optimizer pass over a
+    float32 [T, columns] table is held to the layout the TPU keeps the
+    state in (TrainStep._optimizer_pass's third arm), from the width
+    alone: the state is kept rows-minor while the step reads and writes
+    it by rows.
+
+    The TPU keeps a table-sized f32[T, D] whichever way (8,128) tiles
+    pad it less.  Rows-minor (``{0,1:T(8,128)}``: the T rows on the
+    lanes, D rounded up to 8 sublanes, padded_columns) unless rounding D
+    up to 128 lanes costs no more, then columns-minor
+    (``{1,0:T(8,128)}``, a row contiguous).  Compiled for a described
+    v5e, f32[2^21, D] as a program's donated argument (PR 59): D = 8,
+    10, 26, 64, 96, 120, 129, 160, 192, 320 rows-minor; 127, 128, 250,
+    256, 384 columns-minor.  A table of ROW_LAYOUT_MIN_COLUMNS or more
+    is gathered by whole rows and scattered into by whole rows, which
+    XLA runs columns-minor, and it then moves the elementwise pass
+    between the scatter's buffer and the program's outputs onto that
+    layout too and relays ``param``, ``n`` and ``z`` in and out: FFM's
+    v [2^21, 160], 160 -> 256 columns and 2 GiB an array for 1.25, six
+    table-sized copies a step (31.6 of 114.5 ms, ledger, PR 58;
+    _optimizer_pass has what the compiler makes of the step either way).
+    A narrower table goes column by column and is rows-minor throughout
+    (MVM's [2^25, 10]: one fusion, no copy); a width the lanes do not
+    pad (DLRM's 128) is kept columns-minor and its pass reads the state
+    as it lies (no table-sized copy on its ledger line): neither is
+    selected, and a constraint there would CAUSE the copies."""
+    return (
+        columns >= ROW_LAYOUT_MIN_COLUMNS
+        and padded_columns(columns) < -(-columns // 128) * 128
+    )
+
+
 def dict_cold_rows(
     plan: dict, params: dict[str, jax.Array], lane_select
 ) -> dict[str, jax.Array]:
@@ -1032,6 +1066,21 @@ class TrainStep:
         # elements a step's whole-array optimizer passes run on the flat
         # view (_optimizer_pass: the tables of one column)
         self._flat_pass_elements = self._count_flat_pass_elements()
+        # and on the layout the chip keeps the state in (_optimizer_pass:
+        # the dense update's pass over a table resident_pass_selects
+        # names, on one device), a table: its elements
+        one_pass_a_step = not self._sharded and not (
+            cfg.update_mode == "sparse"
+            or (
+                cfg.update_mode == "sequential"
+                and (cfg.microbatch > 1 or cfg.sequential_inner == "sparse")
+            )
+        )
+        self._resident_pass_tables = {
+            spec.name: cfg.table_size * spec.dim
+            for spec in model.tables()
+            if one_pass_a_step and resident_pass_selects(spec.dim)
+        }
         # what a family with replicated dense parameters holds and does
         # a step, from shapes (_book_wire): the bytes of its dense
         # arrays, and the operations of its products with them, forward
@@ -1191,7 +1240,12 @@ class TrainStep:
         to lay its rows out by row gathers: 0 where every table goes
         column by column.  ``flat_pass_elements`` is what the step's
         whole-array optimizer passes ran on the flat view
-        (_count_flat_pass_elements): 0 where no table has one column.
+        (_count_flat_pass_elements): 0 where no table has one column;
+        ``resident_pass_elements`` the elements of the tables whose
+        dense pass is held to the layout the chip keeps them in
+        (resident_pass_selects; a table the touched-rows update took
+        makes no pass): 335 544 320 for FFM's v [2^21, 160], 0 for
+        every other measured configuration.
         A family that owns replicated dense parameters also books, from
         shapes, ``dense.param_bytes`` (the bytes of its dense arrays)
         and ``dense.matmul_flops`` (6 B k n for every [B, k] x [k, n]
@@ -1222,9 +1276,10 @@ class TrainStep:
             self.obs.counter("wire.cold_slots", cold_slots)
             self.obs.counter("wire.table_gather_indices", indices)
             on_route = self._dict_scatter_tables if through_dict else 0
-            touched = len(self._touched_rows_names(
+            touched_names = self._touched_rows_names(
                 *plane_caps, hot_slots > 0
-            )) if on_route and plane_caps else 0
+            ) if on_route and plane_caps else frozenset()
+            touched = len(touched_names)
             self.obs.counter(
                 "wire.table_scatter_indices",
                 (on_route - touched) * indices
@@ -1240,6 +1295,14 @@ class TrainStep:
                 )
             self.obs.counter(
                 "wire.flat_pass_elements", self._flat_pass_elements
+            )
+            self.obs.counter(
+                "wire.resident_pass_elements",
+                sum(
+                    elements
+                    for name, elements in self._resident_pass_tables.items()
+                    if name not in touched_names
+                ),
             )
             plain = hot_slots * self._plain_hot_row_bytes
             self.obs.counter(
@@ -2101,7 +2164,9 @@ class TrainStep:
             name: (
                 self._touched_rows_pass(table, *gbufs[name])
                 if name in touched
-                else self._optimizer_pass(table, gbufs[name])
+                else self._optimizer_pass(
+                    table, gbufs[name], resident=not self._sharded
+                )
             )
             for name, table in tables.items()
         }
@@ -2154,9 +2219,14 @@ class TrainStep:
         }
 
     @jax.named_scope("xf.optimizer")
-    def _optimizer_pass(self, table: dict, g: jax.Array) -> dict:
+    def _optimizer_pass(
+        self, table: dict, g: jax.Array, resident: bool = False
+    ) -> dict:
         """The optimizer recurrence over whole arrays: the dense [T, D]
-        pass, and the [H, D] head of the hot sequential inner.
+        pass, and the [H, D] head of the hot sequential inner.  Three
+        arms, chosen from the arrays' shape; in each the same operations
+        per element in the same order: the new state is bit for bit
+        update_rows(table, g).
 
         A table of ONE column runs it on the flat [T] view of the same
         bytes.  The TPU lays an f32[T, 1] out in tiles of one sublane
@@ -2167,13 +2237,48 @@ class TrainStep:
         fusion over whole tiles reads 670 (PERF.md section 6, PR 37).
         The recurrence is elementwise, so XLA would cancel the reshapes
         through it and put the fusion back on [T, 1]: the barriers on
-        both sides hold the view.  Same operations per element in the
-        same order: the new state is bit for bit update_rows(table, g).
-        A table of more columns keeps its shape, and must: its rows are
-        padded in memory (10 -> 16, 160 -> 256 columns), so its flat
-        view is a copy of the state, and [T / 1024, 1024] is a trap for
+        both sides hold the view.
+
+        A table that the chip keeps rows-minor while the step gathers
+        and scatters its rows columns-minor (resident_pass_selects:
+        FFM's v [2^21, 160]) runs it with ``param``, ``n``, ``z``, the
+        gradient buffer and the three results held to the resident
+        layout (with_layout_constraint inside the program, as
+        ops/hot.py holds its pieces; never a format on the jit's
+        boundary, which an executable loaded from the persistent
+        compile cache does not honour: PERF.md section 6, PR 34), so
+        that XLA leaves the fusion on the bytes the state lies in:
+        ``n`` and ``z`` are never relaid and the new ``param`` is
+        written where it lives.  ``resident`` is the caller's word that
+        the arrays ARE the program's state on one device, arguments in
+        and results out: the dense update's one pass a step.  Compiled
+        for a described v5e (PR 59;
+        tests/test_tpu_compile.py::test_ffm_pass_runs_on_the_resident_layout_on_v5e):
+        six table-sized copies -> two, the pass's results
+        ``{1,0:T(8,128)}`` (2 GiB each) -> ``{0,1:T(8,128)}`` (1.25),
+        the program 13.78 -> 7.65 GiB.  PR 37's view between barriers
+        does not carry to 160 columns (``a.T`` pins a shape, not a
+        layout: XLA gives the VIEW the padded layout, three copies stay
+        and the program is refused at 17.78 GiB).  A carry of the
+        sequential inners, a block under a mesh and an [H, D] head
+        slice keep the arm below: no cell puts a wide table there and
+        no compile has been read.
+
+        Every other table keeps its shape, and must: a narrow table's
+        rows are padded in memory (10 -> 16 columns), so its flat view
+        is a copy of the state, and [T / 1024, 1024] is a trap for
         T x 1 too (reduces over the unit axis and three table-sized
         copies: CHANGES.md, PR 37)."""
+        if resident and resident_pass_selects(g.shape[-1]):
+            rows_minor = Layout(major_to_minor=(1, 0))
+
+            def held(a):
+                return with_layout_constraint(a, rows_minor)
+
+            new = self.optimizer.update_rows(
+                {k: held(a) for k, a in table.items()}, held(g)
+            )
+            return {k: held(a) for k, a in new.items()}
         if g.ndim != 2 or g.shape[-1] != 1:
             return self.optimizer.update_rows(table, g)
         rows, flat_g = jax.lax.optimization_barrier(

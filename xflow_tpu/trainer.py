@@ -1152,12 +1152,15 @@ class Trainer:
                 # dense update handed the touched-rows application in
                 # place of a gradient buffer and a table pass
                 # (step.py::_touched_rows_pass; 0 where no table is
-                # selected)
+                # selected); and the elements of the tables whose dense
+                # pass ran on the layout the chip keeps them in
+                # (step.py::resident_pass_selects)
                 for name in (
                     "gather_row_bytes", "scatter_row_bytes", "plain_hot_slots",
                     "hot_plain_slots", "hot_scan_slots",
                     "hot_scatter_plain_slots", "hot_scatter_scan_slots",
                     "flat_pass_elements", "touched_rows_indices",
+                    "resident_pass_elements",
                 ):
                     stats["_wire"][f"{name}_per_step"] = round(
                         snap.counters[f"wire.{name}"] / batches
